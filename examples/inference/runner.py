@@ -22,8 +22,10 @@ Subcommands:
   (admission queue, bucketed right-sized inserts, fused K-step multi-slot
   decode — ``ServeEngine``): throughput + queueing/latency report.
 
-Run (13B dims, TP8):
-    python examples/inference/runner.py benchmark --tp 8
+Run (13B dims over every attached device; --tp N to choose):
+    python examples/inference/runner.py benchmark
+One 16 GB chip (7B widths, depth cut to fit):
+    python examples/inference/runner.py serve --preset llama2_7b --num_layers 16 --paged
 CI smoke:
     python examples/inference/runner.py benchmark --tiny
 """
@@ -43,7 +45,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from neuronx_distributed_tpu.inference import CausalLM, Sampler
-from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM, llama2_13b
+from neuronx_distributed_tpu.kernels import mode
+from neuronx_distributed_tpu.models import llama as llama_presets
+from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.trainer import (
     initialize_parallel_model,
     neuronx_distributed_config,
@@ -63,7 +67,28 @@ def _model_cls(args):
     return LlamaForCausalLM
 
 
+# the published Llama shapes models/llama.py ships, by the name --preset takes
+LLAMA_PRESETS = ("llama2_7b", "llama2_13b", "llama2_70b", "llama3_8b",
+                 "llama31_8b", "llama3_70b")
+
+
+def _tp(args) -> int:
+    """--tp, else every device this process sees (--tiny: the 2-way smoke)."""
+    return args.tensor_parallel_size or (2 if args.tiny else jax.device_count())
+
+
 def build_config(args):
+    """The family's config at --tiny or published widths; ``--num_layers``
+    cuts DEPTH only (what one chip holds), never a width."""
+    cfg = _family_config(args)
+    if getattr(args, "num_layers", None):
+        import dataclasses
+
+        cfg = dataclasses.replace(cfg, num_layers=args.num_layers)
+    return cfg
+
+
+def _family_config(args):
     family = args.model
     if family in ("mixtral", "dbrx"):
         from neuronx_distributed_tpu.models.mixtral import MixtralConfig, dbrx, mixtral_8x7b
@@ -84,16 +109,19 @@ def build_config(args):
             num_heads=4, num_kv_heads=4, max_seq_len=256, dtype=jnp.float32,
             use_flash_attention=False,
         )
-    return llama2_13b(
+    preset = getattr(llama_presets, getattr(args, "preset", None) or "llama2_13b")
+    return preset(
         max_seq_len=args.max_seq_len, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
         remat_policy=None,
     )
 
 
-def build_model(args):
-    cfg = build_config(args)
+def build_model(args, cfg=None):
+    """(CausalLM, config) for the flags in ``args``; ``cfg`` replaces the
+    config the flags name (chip_smoke.py's rehearsal shrinks the widths)."""
+    cfg = cfg or build_config(args)
     nxd_config = neuronx_distributed_config(
-        tensor_parallel_size=args.tensor_parallel_size or (2 if args.tiny else 8)
+        tensor_parallel_size=_tp(args)
     )
     ids = jnp.zeros((1, 8), jnp.int32)
     if args.hf_checkpoint:
@@ -112,8 +140,7 @@ def build_model(args):
         cfg = dataclasses.replace(
             fam.config_from_hf(args.hf_checkpoint), max_seq_len=args.max_seq_len,
             dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
-            # pallas kernels only lower on real TPU (same gate as build_config)
-            use_flash_attention=jax.default_backend() == "tpu",
+            use_flash_attention=mode.flash_where_compiled(logger.info),
         )
         if not ps.model_parallel_is_initialized():
             ps.initialize_model_parallel(
@@ -264,7 +291,7 @@ def cmd_benchmark(args) -> None:
 
     report = {
         "model": args.model + ("_tiny" if args.tiny else ""),
-        "tp": args.tensor_parallel_size or (2 if args.tiny else 8),
+        "tp": _tp(args),
         "batch": lm.max_batch,
         "prompt_len": prompt_len,
         "context_encoding": percentiles(ttft),
@@ -398,7 +425,7 @@ def cmd_medusa(args) -> None:
             "medusa supports none of --hf_checkpoint/--quantize/--sample "
             "(random heads, greedy posterior)")
     cfg = build_config(args)
-    tp = args.tensor_parallel_size or (2 if args.tiny else 8)
+    tp = _tp(args)
     if not ps.model_parallel_is_initialized():
         ps.initialize_model_parallel(tensor_model_parallel_size=tp)
     mm = MedusaLlamaForCausalLM(
@@ -454,7 +481,7 @@ def cmd_serve(args) -> None:
     # or vocab count that does not divide TP would silently fall back to
     # replicated leaves (degraded capacity), which a `--tp N` request
     # should refuse loudly instead
-    tp = args.tensor_parallel_size or (2 if args.tiny else 8)
+    tp = _tp(args)
     if tp > 1:
         cfg0 = build_config(args)
         for dim_name, dim in (("num_kv_heads", cfg0.num_kv_heads),
@@ -835,8 +862,14 @@ def main(argv=None) -> None:
     for name in ("generate", "benchmark", "speculate", "medusa",
                  "check-accuracy", "serve"):
         p = sub.add_parser(name)
-        p.add_argument("--tensor_parallel_size", "--tp", type=int, default=None)
+        p.add_argument("--tensor_parallel_size", "--tp", type=int, default=None,
+                       help="default: every device this process sees")
         p.add_argument("--tiny", action="store_true")
+        p.add_argument("--preset", choices=LLAMA_PRESETS, default="llama2_13b",
+                       help="--model llama: which published shape to build")
+        p.add_argument("--num_layers", type=int, default=None,
+                       help="cut the model's depth to what the chip holds "
+                            "(widths stay the published ones)")
         p.add_argument("--hf_checkpoint", type=str, default=None)
         p.add_argument("--max_seq_len", type=int, default=4096)
         p.add_argument("--max_batch", type=int, default=1)
@@ -1135,6 +1168,12 @@ def main(argv=None) -> None:
         from common import force_cpu_mesh
 
         force_cpu_mesh()
+    else:
+        from neuronx_distributed_tpu.utils.compile_cache import (
+            place_compile_cache,
+        )
+
+        place_compile_cache()
     {"generate": cmd_generate, "benchmark": cmd_benchmark,
      "speculate": cmd_speculate, "medusa": cmd_medusa,
      "check-accuracy": cmd_check_accuracy, "serve": cmd_serve}[args.cmd](args)

@@ -7,7 +7,6 @@ hand-issued ``xm.*`` ops, ``lax.ppermute`` pipeline p2p, Pallas kernels for
 flash attention, and optimizer-state sharding for ZeRO-1.
 """
 
-from neuronx_distributed_tpu import compat as _compat  # noqa: F401  (must run first)
 from neuronx_distributed_tpu.parallel import mesh as parallel_state  # noqa: F401
 from neuronx_distributed_tpu.parallel.mesh import (  # noqa: F401
     initialize_model_parallel,

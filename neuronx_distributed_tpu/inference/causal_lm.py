@@ -244,6 +244,18 @@ class CausalLM:
                         f"got {page_dtype!r}")
                 over["page_dtype"] = page_dtype
             if paged_attn_kernel:
+                from neuronx_distributed_tpu.inference.paged_kernel import (
+                    paged_kernel_refusal,
+                )
+
+                c = self.config
+                why = paged_kernel_refusal(
+                    int(page_size), c.num_heads,
+                    c.num_kv_heads * c.kv_size_multiplier, c.head_dim_,
+                    jnp.int8 if page_dtype == "int8"
+                    else (page_dtype or c.dtype))
+                if why:
+                    raise ValueError(f"paged_attn_kernel refused: {why}")
                 over["paged_attn_kernel"] = True
             self.config = dataclasses.replace(self.config, **over)
         elif page_dtype or paged_attn_kernel:

@@ -11,10 +11,14 @@ tables DIRECTLY.
 
 Per query row the grid walks that slot's pages only — the block table is
 a scalar-prefetch operand (``pltpu.PrefetchScalarGridSpec``), so each
-``(batch, kv_head, page)`` grid step's BlockSpec index_map resolves
+``(batch, page)`` grid step's BlockSpec index_map resolves
 ``block_table[b, j]`` BEFORE the kernel body runs and the pipeline
-fetches exactly one physical page tile ``(page_size, head_dim)`` from
-the pool per step. No logical slab is ever built:
+fetches exactly one physical page ``(page_size, n_kv, head_dim)`` from
+the pool per step — every local kv head of it, because Mosaic tiles the
+pool's last two dims ``(n_kv, head_dim)`` and refuses a block that
+squeezes the head axis out of them (the first compile for a v5e; an
+earlier ``(batch, kv_head, page)`` grid only ever ran interpreted). No
+logical slab is ever built:
 
 * block-sparse over the table — pages whose first position lies beyond
   the row's query position are skipped (``@pl.when`` on the running-max
@@ -34,11 +38,12 @@ happens INSIDE the tile right before the QK^T dot — extending
 quantization/core.py's "int8 is what HBM holds, the convert fuses into
 the consuming matmul" convention from weights to KV pages.
 
-Runs in Pallas interpret mode off-TPU (``_interpret``), so the tier-1
-exactness matrix (tests/test_paged_kernel.py) drives the REAL kernel on
-the CPU mesh; on TPU the same code lowers to Mosaic. GQA never repeats
-K/V in HBM: queries reshape to ``(b, n_kv, group, head_dim)`` and the
-grid is over kv heads, the flash_attn.py compact-KV argument.
+Runs in Pallas interpret mode off-TPU (``kernels/mode.py``), so the
+tier-1 exactness matrix (tests/test_paged_kernel.py) drives the REAL
+kernel on the CPU mesh; on TPU the same code lowers to Mosaic. GQA never
+repeats K/V in HBM: queries reshape to ``(b, n_kv, group, head_dim)`` and
+the kernel walks the kv heads of the page it holds, the flash_attn.py
+compact-KV argument.
 
 Numerics contract: fp32 pages produce logits within online-softmax
 reassociation distance of the gather reference (token STREAMS are
@@ -57,13 +62,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from neuronx_distributed_tpu.kernels import mode
+
 # flash_attn.py's mask value: large-finite so masked lanes never breed NaNs
 NEG_INF = -1e30
 
 
-def _interpret() -> bool:
-    """Interpret off-TPU (CPU CI runs the real kernel semantics)."""
-    return jax.default_backend() != "tpu"
+# Largest double-buffered K+V page footprint Mosaic was shown to accept on
+# a v5e (16 MiB of scoped VMEM by default): 2 pools x 2 pipeline buffers x
+# (256, 32, 128) bf16. At twice that the compiler answers "Ran out of
+# memory in memory space vmem" (tests/test_aot_tpu_compile.py holds the
+# shapes that pass).
+_PAGE_TILE_VMEM_BYTES = 8 * 1024 * 1024
 
 
 def paged_kernel_supported(s_new: int, page_size: int, n_heads: int,
@@ -74,6 +84,24 @@ def paged_kernel_supported(s_new: int, page_size: int, n_heads: int,
     group. Mirrors ``flash_supported``'s role for the prefill kernel."""
     return (s_new == 1 and page_size >= 1 and n_kv_heads >= 1
             and n_heads % n_kv_heads == 0)
+
+
+def paged_kernel_refusal(page_size: int, n_heads: int, n_kv_heads: int,
+                         head_dim: int, pool_dtype) -> Optional[str]:
+    """Why a model with these page dims cannot take the kernel path, or
+    None. Asked once, at model construction (``CausalLM``), so a decode
+    step never finds out mid-serve and quietly gathers instead."""
+    if not paged_kernel_supported(1, page_size, n_heads, n_kv_heads):
+        return (f"n_heads {n_heads} is not a multiple of n_kv_heads "
+                f"{n_kv_heads}")
+    tile = 4 * page_size * n_kv_heads * head_dim * jnp.dtype(pool_dtype).itemsize
+    if tile > _PAGE_TILE_VMEM_BYTES:
+        return (f"one grid step holds K and V pages of ({page_size}, "
+                f"{n_kv_heads}, {head_dim}) {jnp.dtype(pool_dtype).name} "
+                f"double-buffered = {tile} bytes of VMEM, over the "
+                f"{_PAGE_TILE_VMEM_BYTES} the TPU compiler accepts; use a "
+                f"smaller page_size")
+    return None
 
 
 def quantize_kv_pages(w: jax.Array):
@@ -102,18 +130,23 @@ def dequantize_kv_pages(q: jax.Array, scale: jax.Array,
 def _decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                    o_ref, m_scr, l_scr, acc_scr, *, page_size, pages_per_seq,
                    quantized, sm_scale):
-    """One (batch row, kv head, page) grid step.
+    """One (batch row, page) grid step over ALL local kv heads.
 
     Refs (post scalar-prefetch): ``bt_ref`` (b, pages_per_seq) block
-    table and ``cl_ref`` (b,) query positions in SMEM; ``q_ref`` (group,
-    hd); ``k_ref``/``v_ref`` (page_size, hd) — ONE physical page tile,
-    already routed through the block table by the index_map; ``ks_ref``/
-    ``vs_ref`` (1, 1) per-(page, head) scales (int8 pools); ``o_ref``
-    (group, hd). Scratch carries the online softmax across the page axis
-    (TPU grids iterate the innermost axis sequentially per core, so VMEM
-    scratch persists — flash_attn.py's forward discipline)."""
+    table and ``cl_ref`` (b,) query positions in SMEM; ``q_ref`` (n_kv,
+    group, hd); ``k_ref``/``v_ref`` (page_size, n_kv, hd) — ONE physical
+    page, already routed through the block table by the index_map;
+    ``ks_ref``/``vs_ref`` (1, n_kv) that page's per-head scales (int8
+    pools); ``o_ref`` (n_kv, group, hd). The block spans the whole head
+    axis because Mosaic tiles an array's LAST TWO dims — here (n_kv, hd)
+    — and refuses a block that squeezes one of them; heads are walked by
+    a static loop instead of a grid axis. Scratch carries the online
+    softmax across the page axis (TPU grids iterate the innermost axis
+    sequentially per core, so VMEM scratch persists — flash_attn.py's
+    forward discipline)."""
     bi = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
+    n_kv, g, _ = q_ref.shape
 
     @pl.when(j == 0)
     def _init():
@@ -127,32 +160,32 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     # entirely masked — skip its flops; the accumulators pass through.
     @pl.when(j * page_size <= qpos)
     def _accumulate():
-        g = q_ref.shape[0]
-        q = q_ref[...].astype(jnp.float32)              # (g, hd)
-        k = k_ref[...].astype(jnp.float32)              # (ps, hd)
-        v = v_ref[...].astype(jnp.float32)
-        if quantized:
-            # in-tile dequant: int8 page * per-(page, head) fp32 scale
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale       # (g, ps)
         kpos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (g, page_size), 1)
         valid = kpos <= qpos
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev, l_prev = m_scr[...], l_scr[...]          # (g, 1) each
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # exp under the mask, not of the mask: exp(NEG_INF - m) can be
-        # exp(0)=1 when a whole row is masked — zero it explicitly
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        for h in range(n_kv):
+            q = q_ref[h].astype(jnp.float32)                # (g, hd)
+            k = k_ref[:, h, :].astype(jnp.float32)          # (ps, hd)
+            v = v_ref[:, h, :].astype(jnp.float32)
+            if quantized:
+                # in-tile dequant: int8 page * per-(page, head) fp32 scale
+                k = k * ks_ref[:, h:h + 1]
+                v = v * vs_ref[:, h:h + 1]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale   # (g, ps)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev, l_prev = m_scr[h], l_scr[h]              # (g, 1) each
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # exp under the mask, not of the mask: exp(NEG_INF - m) can be
+            # exp(0)=1 when a whole row is masked — zero it explicitly
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
     @pl.when(j == pages_per_seq - 1)
     def _finalize():
@@ -204,35 +237,34 @@ def paged_decode_attention(
     # h reads kv head h // group, so the (n_kv, group) reshape is exact.
     q3 = q[:, 0].reshape(b, n_kv, group, hd)
     if quantized:
-        ks2 = k_scale.reshape(num_pages, n_kv).astype(jnp.float32)
-        vs2 = v_scale.reshape(num_pages, n_kv).astype(jnp.float32)
-        scale_idx = lambda bi, hi, j, bt, cl: (bt[bi, j], hi)  # noqa: E731
+        ks3 = k_scale.reshape(num_pages, 1, n_kv).astype(jnp.float32)
+        vs3 = v_scale.reshape(num_pages, 1, n_kv).astype(jnp.float32)
+        scale_idx = lambda bi, j, bt, cl: (bt[bi, j], 0, 0)  # noqa: E731
     else:
-        ks2 = vs2 = jnp.ones((1, 1), jnp.float32)
-        scale_idx = lambda bi, hi, j, bt, cl: (0, 0)  # noqa: E731
+        ks3 = vs3 = jnp.ones((1, 1, n_kv), jnp.float32)
+        scale_idx = lambda bi, j, bt, cl: (0, 0, 0)  # noqa: E731
 
+    # the paged indirection: the PAGE axis block index comes from the
+    # scalar-prefetched table — one whole pool page per grid step
+    page_spec = pl.BlockSpec((None, page_size, n_kv, hd),
+                             lambda bi, j, bt, cl: (bt[bi, j], 0, 0, 0))
+    row_spec = pl.BlockSpec((None, n_kv, group, hd),
+                            lambda bi, j, bt, cl: (bi, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n_kv, pages_per_seq),
+        grid=(b, pages_per_seq),
         in_specs=[
-            pl.BlockSpec((None, None, group, hd),
-                         lambda bi, hi, j, bt, cl: (bi, hi, 0, 0)),
-            # the paged indirection: the PAGE axis block index comes from
-            # the scalar-prefetched table — one pool tile per grid step,
-            # head axis split so tiles never cross the TP head shard
-            pl.BlockSpec((None, page_size, None, hd),
-                         lambda bi, hi, j, bt, cl: (bt[bi, j], 0, hi, 0)),
-            pl.BlockSpec((None, page_size, None, hd),
-                         lambda bi, hi, j, bt, cl: (bt[bi, j], 0, hi, 0)),
-            pl.BlockSpec((1, 1), scale_idx),
-            pl.BlockSpec((1, 1), scale_idx),
+            row_spec,
+            page_spec,
+            page_spec,
+            pl.BlockSpec((None, 1, n_kv), scale_idx),
+            pl.BlockSpec((None, 1, n_kv), scale_idx),
         ],
-        out_specs=pl.BlockSpec((None, None, group, hd),
-                               lambda bi, hi, j, bt, cl: (bi, hi, 0, 0)),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),   # running max
-            pltpu.VMEM((group, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((group, hd), jnp.float32),  # weighted-V accumulator
+            pltpu.VMEM((n_kv, group, 1), jnp.float32),   # running max
+            pltpu.VMEM((n_kv, group, 1), jnp.float32),   # running denominator
+            pltpu.VMEM((n_kv, group, hd), jnp.float32),  # weighted-V acc
         ],
     )
     out = pl.pallas_call(
@@ -242,9 +274,9 @@ def paged_decode_attention(
             sm_scale=sm_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, group, hd), q.dtype),
-        interpret=_interpret(),
+        interpret=mode.interpret_kernels(),
     )(block_table.astype(jnp.int32), cache_len.astype(jnp.int32),
-      q3, k_pages, v_pages, ks2, vs2)
+      q3, k_pages, v_pages, ks3, vs3)
     return out.reshape(b, 1, n_q, hd)
 
 

@@ -605,11 +605,12 @@ class LlamaAttention(nn.Module):
         if ps:
             from neuronx_distributed_tpu.inference.paged_kernel import (
                 paged_decode_attention,
-                paged_kernel_supported,
             )
 
-            if (cfg.paged_attn_kernel and chunk_mask is None
-                    and paged_kernel_supported(s_new, ps, q.shape[2], n_kv)):
+            # single-token steps only (prefill/chunk widths amortize the
+            # gather); whether THIS model's pages fit the kernel was
+            # settled at construction (CausalLM, paged_kernel_refusal)
+            if cfg.paged_attn_kernel and chunk_mask is None and s_new == 1:
                 # fused paged decode (inference/paged_kernel.py): attend
                 # straight off the POST-write pool through the block
                 # table — no logical slab is ever materialized, which is

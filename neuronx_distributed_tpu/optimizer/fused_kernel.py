@@ -23,12 +23,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from neuronx_distributed_tpu.kernels import mode
+
 _W = 1024          # lane-dim width of the flattened view (8 sublanes x 128)
 _MAX_ROWS = 128    # rows per block: 4 fp32 refs x 0.5 MB + outputs < VMEM
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _kernel(s_ref, g_ref, mu_ref, nu_ref, ms_ref,
@@ -86,7 +84,7 @@ def fused_adamw_leaf(g, mu, nu, ms, scalars, *, b1, b2, eps, wd, p_dtype):
         ],
         # mu/nu/master update in place (operand i=2,3,4 -> output 0,1,2)
         input_output_aliases={2: 0, 3: 1, 4: 2},
-        interpret=_interpret(),
+        interpret=mode.interpret_kernels(),
     )(scalars, g2, mu2, nu2, ms2)
     mu_n, nu_n, ms_n, p_n = out
     return (mu_n.reshape(mu.shape), nu_n.reshape(nu.shape),

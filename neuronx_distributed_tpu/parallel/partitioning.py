@@ -34,16 +34,23 @@ ACT_CP = P(DP_AXES, "cp", None)        # sequence sharded over CP (ring attentio
 
 def constrain(x: jax.Array, spec: P) -> jax.Array:
     """``with_sharding_constraint`` against the global mesh; no-op when
-    parallel state is uninitialized (single-device unit tests) or when
-    tracing inside a manual (shard_map/pmap) region — constraints are GSPMD
-    hints and there is no GSPMD inside full-manual regions (the compat
-    shim's full-manual fallback routes partial-manual callers here)."""
+    parallel state is uninitialized (single-device unit tests).
+
+    Inside a ``shard_map`` region the constraint is a GSPMD hint over the
+    mesh axes the region left automatic: the pipeline engines are manual
+    over ``pp`` only, so the TP/SP/DP specs of the layers they run still
+    apply there. A spec that names a manual axis has nothing to say —
+    the region already holds that axis's local shard (ring attention and
+    the flash kernel's region are manual over every axis) — and is
+    dropped."""
     if not ps.model_parallel_is_initialized():
         return x
-    from jax._src import core as _core
-
-    if _core.get_axis_env().axis_sizes:
-        return x
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    if manual:
+        named = {a for entry in spec if entry is not None
+                 for a in ((entry,) if isinstance(entry, str) else entry)}
+        if named & manual or manual >= set(ps.get_mesh().axis_names):
+            return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(ps.get_mesh(), spec))
 
 

@@ -43,9 +43,12 @@ def create_train_state(model: ParallelModel, optimizer: NxDOptimizer) -> TrainSt
     opt_state = jax.jit(
         optimizer.init, out_shardings=_opt_state_shardings(model, optimizer)
     )(model.trainable_params)
-    return TrainState(
-        step=jnp.zeros((), jnp.int32), params=model.trainable_params, opt_state=opt_state
-    )
+    # the counter is born on the mesh like everything else in the state: the
+    # step hands it back replicated over the mesh, and an input whose
+    # sharding differs from the first call's makes jit trace and compile the
+    # whole step a second time
+    step = jax.device_put(jnp.zeros((), jnp.int32), NamedSharding(model.mesh, P()))
+    return TrainState(step=step, params=model.trainable_params, opt_state=opt_state)
 
 
 def _opt_state_shardings(model: ParallelModel, optimizer: NxDOptimizer):

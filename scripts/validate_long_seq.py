@@ -125,11 +125,9 @@ def main(argv=None) -> int:
         }))
     if args.cp:
         for seq in (args.seqs or [16384]):
-            # fresh subprocess per row with retry: the CP kernel's runtime
-            # is HBM-placement sensitive and the slow mode is sticky per
-            # process (PROFILE.md r5 CP note) — a process-level re-roll is
-            # the only mitigation that reliably recovers the fast mode.
-            # The row records its own cp_attempts.
+            # measured in this process (the chip belongs to one): the CP
+            # kernel's runtime is HBM-placement sensitive (PROFILE.md r5 CP
+            # note) and measure_cp_ratio re-rolls operand allocations
             row = measure_cp_ratio_isolated(seq)
             row["passed"] = passed_cp = row["cp_vs_sp_throughput"] >= 0.7
             ok &= passed_cp

@@ -1,0 +1,143 @@
+"""Every Pallas kernel of the main path compiles for a TPU v5e — checked
+here, without a chip.
+
+The TPU compiler is installed with jaxlib and compiles for a device that is
+DESCRIBED, not attached (``jax.experimental.topologies``). Interpret mode,
+which every other kernel test runs in, cannot see what Mosaic refuses: the
+paged decode kernel passed all of them and was refused at every shape for
+squeezing the second-minor dimension out of its K/V blocks. These cases
+hold the shapes the chip smoke runs (Llama-2-7B widths); each asserts that
+the compiled program contains the kernel as a ``tpu_custom_call``. Nothing
+executes, so nothing here says the results are right or fast — that is
+``chip_smoke.py``'s job, on the chip.
+
+The file name sorts first so the tier-1 clock always reaches it.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from neuronx_distributed_tpu.inference import CausalLM
+from neuronx_distributed_tpu.inference.paged_kernel import (
+    paged_decode_attention,
+    paged_kernel_refusal,
+)
+from neuronx_distributed_tpu.kernels import mode
+from neuronx_distributed_tpu.kernels.flash_attn import (
+    default_attention_blocks,
+    flash_attention,
+)
+from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from neuronx_distributed_tpu.optimizer.fused_kernel import fused_adamw_leaf
+
+HEAD_DIM = 128
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one described v5e chip."""
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to test
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _mosaic_not_interpreter(monkeypatch):
+    """The process's backend is the CPU, so ``kernels/mode.py`` would pick
+    the interpreter: steer it here, in the test. The persistent compilation
+    cache goes off around these compiles — an entry written for a described
+    device cannot be read back without a chip and would warn on every later
+    run."""
+    monkeypatch.setattr(mode, "interpret_kernels", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _kernel_calls(fn, *avals) -> int:
+    return jax.jit(fn).lower(*avals).compile().as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("n_kv", [32, 8], ids=["mha32", "gqa32_8"])
+def test_flash_attention_fwd_bwd(chip, n_kv):
+    b, h, s = 8, 32, 2048
+    blk_q, blk_k = default_attention_blocks(s)
+    q = jax.ShapeDtypeStruct((b, h, s, HEAD_DIM), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((b, n_kv, s, HEAD_DIM), jnp.bfloat16,
+                              sharding=chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, block_q=blk_q, block_k=blk_k)
+        return out.astype(jnp.float32).sum()
+
+    # forward, dK/dV and dQ kernels
+    assert _kernel_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) >= 3
+
+
+@pytest.mark.parametrize("pages", ["bf16", "int8"])
+@pytest.mark.parametrize("n_kv", [32, 8], ids=["mha32", "gqa32_8"])
+def test_paged_decode_attention(chip, n_kv, pages):
+    b, h, page, num_pages, pages_per_seq = 8, 32, 16, 640, 64
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    pool = aval((num_pages, page, n_kv, HEAD_DIM),
+                jnp.int8 if pages == "int8" else jnp.bfloat16)
+    args = [aval((b, 1, h, HEAD_DIM), jnp.bfloat16), pool, pool,
+            aval((b, pages_per_seq), jnp.int32), aval((b,), jnp.int32)]
+    if pages == "int8":
+        scale = aval((num_pages, 1, n_kv, 1), jnp.float32)
+
+        def fn(q, k, v, bt, cl, ks, vs):
+            return paged_decode_attention(q, k, v, bt, cl, k_scale=ks,
+                                          v_scale=vs)
+
+        assert _kernel_calls(fn, *args, scale, scale) == 1
+    else:
+        assert _kernel_calls(paged_decode_attention, *args) == 1
+
+
+def test_fused_adamw_leaf(chip):
+    leaf = (4096, 11008)
+
+    def aval(dtype, shape=leaf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def fn(g, mu, nu, ms, scalars):
+        return fused_adamw_leaf(g, mu, nu, ms, scalars, b1=0.9, b2=0.95,
+                                eps=1e-8, wd=0.01, p_dtype=jnp.bfloat16)
+
+    f32 = jnp.float32
+    assert _kernel_calls(fn, aval(jnp.bfloat16), aval(f32), aval(f32),
+                         aval(f32), aval(f32, (1, 4))) == 1
+
+
+def test_paged_kernel_refused_at_construction_not_mid_serve():
+    """A page too large for the kernel's VMEM budget is refused when the
+    model is built, with the reason — not by a decode step quietly taking
+    the gather path. (The bound's two sides were found by compiling:
+    ``paged_kernel._PAGE_TILE_VMEM_BYTES``.)"""
+    assert paged_kernel_refusal(256, 32, 32, HEAD_DIM, jnp.bfloat16) is None
+    assert paged_kernel_refusal(512, 32, 32, HEAD_DIM, jnp.int8) is None
+    assert "VMEM" in paged_kernel_refusal(512, 32, 32, HEAD_DIM, jnp.bfloat16)
+    assert "multiple" in paged_kernel_refusal(16, 6, 4, HEAD_DIM, jnp.bfloat16)
+    cfg = LlamaConfig(vocab_size=128, hidden_size=4096, num_heads=32,
+                      num_kv_heads=32, num_layers=1, max_seq_len=1024)
+    with pytest.raises(ValueError, match="paged_attn_kernel refused"):
+        CausalLM(cfg, None, LlamaForCausalLM, buckets=(128,), max_batch=1,
+                 page_size=512, paged_attn_kernel=True)

@@ -125,10 +125,6 @@ def test_pp2_tp2_matches_baseline(baseline):
     assert losses[-1] < losses[0]
 
 
-@pytest.mark.xfail(strict=False, reason=(
-    "jax<0.5 shard_map cannot transpose the replicated scalar inputs of "
-    "the combined 1F1B program (_SpecError in the grad path); the "
-    "compat full-manual fallback covers forward/combined calls only"))
 def test_pp2_vpp_1f1b_matches_pp2_gpipe_exactly():
     """Cross-engine interference check: the table-driven interleaved-1F1B
     trajectory must equal the gpipe-interleaved trajectory bit-for-bit-ish —

@@ -206,10 +206,6 @@ def test_context_parallel_matches_dense():
     np.testing.assert_allclose(float(loss), float(golden_loss), rtol=1e-5)
 
 
-@pytest.mark.xfail(strict=False, reason=(
-    "~0.2% loss drift under the compat full-manual fallback for the "
-    "ring-attention region on jax<0.5 (partial-auto shard_map is "
-    "broken there); passes at rtol=1e-5 on a partial-auto-capable jax"))
 def test_context_parallel_zigzag_matches_dense():
     """Zigzag CP: feeding zigzag-permuted (ids, labels) with
     cp_layout='zigzag' reproduces the dense loss — RoPE positions, the

@@ -487,9 +487,6 @@ def test_interleaved_1f1b_train_step():
     assert np.isfinite(l0) and float(metrics["loss"]) < l0
 
 
-@pytest.mark.xfail(strict=False, reason=(
-    "jax<0.5 shard_map grad-transpose _SpecError (see the vpp combo "
-    "xfail in test_combinatorial.py)"))
 def test_interleaved_1f1b_activation_memory_flat_in_microbatches():
     """VERDICT r3 weak #5 / missing #2: the interleaved engine needs the same
     memory bound 1F1B has. The table-driven interleaved-1F1B stash is sized
@@ -531,9 +528,6 @@ def test_interleaved_1f1b_activation_memory_flat_in_microbatches():
         f"{grow_1f1b} vs gpipe-interleaved {grow_gpipe}")
 
 
-@pytest.mark.xfail(strict=False, reason=(
-    "jax<0.5 shard_map grad-transpose _SpecError (see the vpp combo "
-    "xfail in test_combinatorial.py)"))
 def test_1f1b_activation_memory_flat_in_microbatches():
     """THE 1F1B property: activation footprint is bounded by the fixed 2*pp
     stash — independent of microbatch count — while the GPipe-shaped engine

@@ -64,6 +64,9 @@ def _train(tp, zero1, steps=5, use_master=True):
     for _ in range(steps):
         state, metrics = step(state, {"x": x, "y": y}, rng)
         losses.append(float(metrics["loss"]))
+    # one program for the whole run: a state leaf born with another sharding
+    # than the step hands back (the counter once was) compiles the step twice
+    assert step._cache_size() == 1
     ps.destroy_model_parallel()
     return losses
 

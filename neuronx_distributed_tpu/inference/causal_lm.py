@@ -756,30 +756,34 @@ class CausalLM:
                     cache, tok, counts, lengths, done, gstate = carry
                 else:
                     cache, tok, counts, lengths, done = carry
-                sub = jax.vmap(jax.random.fold_in)(slot_keys, counts)
+                with jax.named_scope("sampler"):
+                    sub = jax.vmap(jax.random.fold_in)(slot_keys, counts)
                 logits, mut = self.model.apply(
                     self._ad_vars(params, cache, ad), tok, mutable=["cache"]
                 )
-                allowed = None
-                if gr:
-                    allowed = self.grammar_allowed(
-                        gtree, gidx, gstate, gbudget, counts)
-                nxt = slot_sampler(logits[:, 0, :], sub, temperature, greedy,
-                                   allowed=allowed)
-                done_before = done
-                out = jnp.where(done | ~active, jnp.int32(pad_token_id), nxt)
-                done = done | (active & (eos_ids >= 0) & (nxt == eos_ids))
-                if gr:
-                    # frozen rows keep their state; live grammar rows step
-                    # to next[state, emitted] and latch done on an
-                    # accept-terminal landing (the grammar's EOS)
-                    adv = gactive & active & ~done_before
-                    new_state = gtree["next"][gidx, gstate, nxt]
-                    gstate = jnp.where(adv, new_state, gstate)
-                    done = done | (adv & gtree["terminal"][gidx, gstate])
-                counts = counts + 1
-                lengths = lengths + 1
-                done = done | (active & (lengths + 1 >= max_len))
+                with jax.named_scope("sampler"):
+                    allowed = None
+                    if gr:
+                        allowed = self.grammar_allowed(
+                            gtree, gidx, gstate, gbudget, counts)
+                    nxt = slot_sampler(logits[:, 0, :], sub, temperature,
+                                       greedy, allowed=allowed)
+                with jax.named_scope("bookkeeping"):
+                    done_before = done
+                    out = jnp.where(done | ~active, jnp.int32(pad_token_id),
+                                    nxt)
+                    done = done | (active & (eos_ids >= 0) & (nxt == eos_ids))
+                    if gr:
+                        # frozen rows keep their state; live grammar rows
+                        # step to next[state, emitted] and latch done on an
+                        # accept-terminal landing (the grammar's EOS)
+                        adv = gactive & active & ~done_before
+                        new_state = gtree["next"][gidx, gstate, nxt]
+                        gstate = jnp.where(adv, new_state, gstate)
+                        done = done | (adv & gtree["terminal"][gidx, gstate])
+                    counts = counts + 1
+                    lengths = lengths + 1
+                    done = done | (active & (lengths + 1 >= max_len))
                 carry = ((mut["cache"], nxt[:, None], counts, lengths, done,
                           gstate) if gr else
                          (mut["cache"], nxt[:, None], counts, lengths, done))
@@ -1021,7 +1025,8 @@ class CausalLM:
                         tables[None], (leaf.shape[0], rows, ppseq))
                 return leaf  # the pool itself is batch-independent
 
-            row_cache = jax.tree_util.tree_map_with_path(as_rows, cache)
+            with jax.named_scope("cache_rows"):
+                row_cache = jax.tree_util.tree_map_with_path(as_rows, cache)
             logits, mut = self.model.apply(
                 self._ad_vars(params, row_cache, ad), ids,
                 mutable=["cache"])
@@ -1047,8 +1052,10 @@ class CausalLM:
                     return out
                 return new  # mutated pool leaves
 
-            return logits, self._shard_out(
-                jax.tree_util.tree_map_with_path(back, cache, mut["cache"]))
+            with jax.named_scope("table_write"):
+                return logits, self._shard_out(
+                    jax.tree_util.tree_map_with_path(back, cache,
+                                                     mut["cache"]))
 
         self._paged_insert[key] = self._time_compile(
             f"paged_insert_r{rows}_b{bucket}",

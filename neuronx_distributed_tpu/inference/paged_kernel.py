@@ -275,6 +275,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, group, hd), q.dtype),
         interpret=mode.interpret_kernels(),
+        name="paged_decode",
     )(block_table.astype(jnp.int32), cache_len.astype(jnp.int32),
       q3, k_pages, v_pages, ks3, vs3)
     return out.reshape(b, 1, n_q, hd)

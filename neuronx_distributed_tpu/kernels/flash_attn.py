@@ -259,6 +259,7 @@ def _fwd(q, k, v, qpos, kpos, sm_scale, block_q, block_k, group, num_q_heads):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=mode.interpret_kernels(),
+        name="flash_fwd",
     )(q, k, v, qpos, kpos)
     return out, lse
 
@@ -334,6 +335,7 @@ def flash_block_grads(q, k, v, do, lse, delta, qpos, kpos, sm_scale,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=mode.interpret_kernels(),
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta, qpos, kpos)
 
     dq_kernel = functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, kv_blocks=kv_blocks)
@@ -354,6 +356,7 @@ def flash_block_grads(q, k, v, do, lse, delta, qpos, kpos, sm_scale,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=mode.interpret_kernels(),
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta, qpos, kpos)
     return dq, dk, dv
 

@@ -499,109 +499,110 @@ class LlamaAttention(nn.Module):
                                     scaling=cfg.rope_scaling)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
-        if ps:
-            # write through the block table: logical slot -> physical page.
-            # Writes at slots >= max_seq_len are DROPPED, matching the slab
-            # path's out-of-bounds scatter (the overflow latch freezes a row
-            # instead of letting its writes wrap onto a neighbour).
-            from neuronx_distributed_tpu.inference.partition import (
-                constrain_named,
-            )
-
-            table = bt.value                                       # (b, ppseq)
-            if quantized:
-                # int8 pages: dequant-modify-requant over the W-page
-                # window this step touches (the narrowest logical span
-                # covering slots idx..idx+s_new-1 at any alignment).
-                # Absmax is a PAGE property, so inserting even one token
-                # re-derives the whole page's scale from its fp values.
-                W = (s_new + ps - 1) // ps + 1
-                first = idx // ps                                  # (b,)
-                lpage = (first[:, None]
-                         + jnp.arange(W, dtype=jnp.int32)[None, :])  # (b, W)
-                from neuronx_distributed_tpu.inference.paged_kernel import (
-                    dequantize_kv_pages,
-                    quantize_kv_pages,
+        with jax.named_scope("kv_write"):
+            if ps:
+                # write through the block table: logical slot -> physical page.
+                # Writes at slots >= max_seq_len are DROPPED, matching the slab
+                # path's out-of-bounds scatter (the overflow latch freezes a row
+                # instead of letting its writes wrap onto a neighbour).
+                from neuronx_distributed_tpu.inference.partition import (
+                    constrain_named,
                 )
 
-                phys_w = jnp.take_along_axis(
-                    table, jnp.clip(lpage, 0, ppseq - 1), axis=1)  # (b, W)
-                kw = dequantize_kv_pages(ck.value[phys_w], cks.value[phys_w])
-                vw = dequantize_kv_pages(cv.value[phys_w], cvs.value[phys_w])
-                kw = kw.reshape(b, W * ps, n_kv, hd)
-                vw = vw.reshape(b, W * ps, n_kv, hd)
-                # window-relative slots; >= max_seq_len drops like the fp
-                # scatter (overflow latch / chunk pad tails past the end)
-                rel = jnp.where(slots < cfg.max_seq_len,
-                                slots - first[:, None] * ps, W * ps)
-                kw = kw.at[rows, rel].set(k.astype(jnp.float32), mode="drop")
-                vw = vw.at[rows, rel].set(v.astype(jnp.float32), mode="drop")
-                # zero positions at/above the row's new length: stale
-                # bytes in a reused page are behind the mask for READS,
-                # but here they would inflate the fresh absmax scale
-                wpos = (first[:, None] * ps
-                        + jnp.arange(W * ps, dtype=jnp.int32)[None, :])
-                live = (wpos < (idx + s_new)[:, None])[..., None, None]
-                kw = jnp.where(live, kw, 0.0).reshape(b, W, ps, n_kv, hd)
-                vw = jnp.where(live, vw, 0.0).reshape(b, W, ps, n_kv, hd)
-                # requantize: absmax per (page, kv head)
-                kq, k_sc = quantize_kv_pages(kw)
-                vq, v_sc = quantize_kv_pages(vw)
-                # write back ONLY pages this step actually touched: an
-                # untouched window page maps through table entries that
-                # may still be 0 — i.e. ANOTHER row's live physical page
-                # — so a blind window write-back would corrupt it.
-                last = jnp.minimum(idx + s_new - 1, cfg.max_seq_len - 1) // ps
-                touched = (lpage <= last[:, None]) & (lpage < ppseq)
-                dest = jnp.where(touched, phys_w, npages)          # (b, W)
-                ck.value = constrain_named(
-                    "cached_key", ck.value.at[dest].set(kq, mode="drop"))
-                cv.value = constrain_named(
-                    "cached_value", cv.value.at[dest].set(vq, mode="drop"))
-                cks.value = constrain_named(
-                    "cached_key_scale",
-                    cks.value.at[dest].set(k_sc, mode="drop"))
-                cvs.value = constrain_named(
-                    "cached_value_scale",
-                    cvs.value.at[dest].set(v_sc, mode="drop"))
-            else:
-                page_of = jnp.clip(slots // ps, 0, ppseq - 1)
-                phys = jnp.take_along_axis(table, page_of, axis=1)  # (b, s_new)
-                flat = jnp.where(slots < cfg.max_seq_len,
-                                 phys * ps + slots % ps, npages * ps)
-                kf = ck.value.reshape(npages * ps, n_kv, hd)
-                vf = cv.value.reshape(npages * ps, n_kv, hd)
-                kf = kf.at[flat].set(k.astype(kf.dtype), mode="drop")
-                vf = vf.at[flat].set(v.astype(vf.dtype), mode="drop")
-                # pin the pool's serving spec at the write (n_kv over 'tp'
-                # under a mesh, no-op otherwise): page-axis scatters/gathers
-                # never cross the head shard, so the whole paged hot path
-                # stays local per shard (inference/partition.py)
-                ck.value = constrain_named(
-                    "cached_key", kf.reshape(npages, ps, n_kv, hd))
-                cv.value = constrain_named(
-                    "cached_value", vf.reshape(npages, ps, n_kv, hd))
-            k_all = v_all = None  # gather deferred: the kernel may skip it
-        else:
-            # mode="drop" pins the out-of-bounds semantics the overflow
-            # latch and late chunked-prefill extends rely on (a chunk whose
-            # pad tail runs past max_seq_len must discard those writes, not
-            # clamp them onto the last slot) — this is jax's default for
-            # scatters, made explicit so the contract can't drift
-            from neuronx_distributed_tpu.inference.partition import (
-                constrain_named,
-            )
+                table = bt.value                                       # (b, ppseq)
+                if quantized:
+                    # int8 pages: dequant-modify-requant over the W-page
+                    # window this step touches (the narrowest logical span
+                    # covering slots idx..idx+s_new-1 at any alignment).
+                    # Absmax is a PAGE property, so inserting even one token
+                    # re-derives the whole page's scale from its fp values.
+                    W = (s_new + ps - 1) // ps + 1
+                    first = idx // ps                                  # (b,)
+                    lpage = (first[:, None]
+                             + jnp.arange(W, dtype=jnp.int32)[None, :])  # (b, W)
+                    from neuronx_distributed_tpu.inference.paged_kernel import (
+                        dequantize_kv_pages,
+                        quantize_kv_pages,
+                    )
 
-            # same serving-spec pin as the paged pool: the slab's n_kv
-            # axis shards over 'tp' and the row scatter is shard-local
-            ck.value = constrain_named(
-                "cached_key", ck.value.at[rows, slots].set(
-                    k.astype(ck.value.dtype), mode="drop"))
-            cv.value = constrain_named(
-                "cached_value", cv.value.at[rows, slots].set(
-                    v.astype(cv.value.dtype), mode="drop"))
-            k_all, v_all = ck.value, cv.value
-        ci.value = idx + s_new
+                    phys_w = jnp.take_along_axis(
+                        table, jnp.clip(lpage, 0, ppseq - 1), axis=1)  # (b, W)
+                    kw = dequantize_kv_pages(ck.value[phys_w], cks.value[phys_w])
+                    vw = dequantize_kv_pages(cv.value[phys_w], cvs.value[phys_w])
+                    kw = kw.reshape(b, W * ps, n_kv, hd)
+                    vw = vw.reshape(b, W * ps, n_kv, hd)
+                    # window-relative slots; >= max_seq_len drops like the fp
+                    # scatter (overflow latch / chunk pad tails past the end)
+                    rel = jnp.where(slots < cfg.max_seq_len,
+                                    slots - first[:, None] * ps, W * ps)
+                    kw = kw.at[rows, rel].set(k.astype(jnp.float32), mode="drop")
+                    vw = vw.at[rows, rel].set(v.astype(jnp.float32), mode="drop")
+                    # zero positions at/above the row's new length: stale
+                    # bytes in a reused page are behind the mask for READS,
+                    # but here they would inflate the fresh absmax scale
+                    wpos = (first[:, None] * ps
+                            + jnp.arange(W * ps, dtype=jnp.int32)[None, :])
+                    live = (wpos < (idx + s_new)[:, None])[..., None, None]
+                    kw = jnp.where(live, kw, 0.0).reshape(b, W, ps, n_kv, hd)
+                    vw = jnp.where(live, vw, 0.0).reshape(b, W, ps, n_kv, hd)
+                    # requantize: absmax per (page, kv head)
+                    kq, k_sc = quantize_kv_pages(kw)
+                    vq, v_sc = quantize_kv_pages(vw)
+                    # write back ONLY pages this step actually touched: an
+                    # untouched window page maps through table entries that
+                    # may still be 0 — i.e. ANOTHER row's live physical page
+                    # — so a blind window write-back would corrupt it.
+                    last = jnp.minimum(idx + s_new - 1, cfg.max_seq_len - 1) // ps
+                    touched = (lpage <= last[:, None]) & (lpage < ppseq)
+                    dest = jnp.where(touched, phys_w, npages)          # (b, W)
+                    ck.value = constrain_named(
+                        "cached_key", ck.value.at[dest].set(kq, mode="drop"))
+                    cv.value = constrain_named(
+                        "cached_value", cv.value.at[dest].set(vq, mode="drop"))
+                    cks.value = constrain_named(
+                        "cached_key_scale",
+                        cks.value.at[dest].set(k_sc, mode="drop"))
+                    cvs.value = constrain_named(
+                        "cached_value_scale",
+                        cvs.value.at[dest].set(v_sc, mode="drop"))
+                else:
+                    page_of = jnp.clip(slots // ps, 0, ppseq - 1)
+                    phys = jnp.take_along_axis(table, page_of, axis=1)  # (b, s_new)
+                    flat = jnp.where(slots < cfg.max_seq_len,
+                                     phys * ps + slots % ps, npages * ps)
+                    kf = ck.value.reshape(npages * ps, n_kv, hd)
+                    vf = cv.value.reshape(npages * ps, n_kv, hd)
+                    kf = kf.at[flat].set(k.astype(kf.dtype), mode="drop")
+                    vf = vf.at[flat].set(v.astype(vf.dtype), mode="drop")
+                    # pin the pool's serving spec at the write (n_kv over 'tp'
+                    # under a mesh, no-op otherwise): page-axis scatters/gathers
+                    # never cross the head shard, so the whole paged hot path
+                    # stays local per shard (inference/partition.py)
+                    ck.value = constrain_named(
+                        "cached_key", kf.reshape(npages, ps, n_kv, hd))
+                    cv.value = constrain_named(
+                        "cached_value", vf.reshape(npages, ps, n_kv, hd))
+                k_all = v_all = None  # gather deferred: the kernel may skip it
+            else:
+                # mode="drop" pins the out-of-bounds semantics the overflow
+                # latch and late chunked-prefill extends rely on (a chunk whose
+                # pad tail runs past max_seq_len must discard those writes, not
+                # clamp them onto the last slot) — this is jax's default for
+                # scatters, made explicit so the contract can't drift
+                from neuronx_distributed_tpu.inference.partition import (
+                    constrain_named,
+                )
+
+                # same serving-spec pin as the paged pool: the slab's n_kv
+                # axis shards over 'tp' and the row scatter is shard-local
+                ck.value = constrain_named(
+                    "cached_key", ck.value.at[rows, slots].set(
+                        k.astype(ck.value.dtype), mode="drop"))
+                cv.value = constrain_named(
+                    "cached_value", cv.value.at[rows, slots].set(
+                        v.astype(cv.value.dtype), mode="drop"))
+                k_all, v_all = ck.value, cv.value
+            ci.value = idx + s_new
         if ps:
             from neuronx_distributed_tpu.inference.paged_kernel import (
                 paged_decode_attention,
@@ -616,10 +617,11 @@ class LlamaAttention(nn.Module):
                 # table — no logical slab is ever materialized, which is
                 # the whole perf point of this branch. The gather below
                 # stays as the bit-exactness reference oracle.
-                o = paged_decode_attention(
-                    q, ck.value, cv.value, table, idx,
-                    k_scale=cks.value if quantized else None,
-                    v_scale=cvs.value if quantized else None)
+                with jax.named_scope("attend"):
+                    o = paged_decode_attention(
+                        q, ck.value, cv.value, table, idx,
+                        k_scale=cks.value if quantized else None,
+                        v_scale=cvs.value if quantized else None)
                 return self._o_proj(o.reshape(b, s_new, -1), aidx)
             # in-scan gather: the (b, max_seq_len) logical view the
             # attention below consumes. Stale bytes in reused pages sit
@@ -627,32 +629,34 @@ class LlamaAttention(nn.Module):
             # zeros (masked scores are -1e30 -> exactly-zero probs), so
             # attention over the view is bit-identical to the contiguous
             # path.
-            lpos = jnp.arange(cfg.max_seq_len)
-            pg = table[:, lpos // ps]                         # (b, S)
-            all_flat = pg * ps + (lpos % ps)[None, :]
-            kf = ck.value.reshape(npages * ps, n_kv, hd)
-            vf = cv.value.reshape(npages * ps, n_kv, hd)
-            k_all, v_all = kf[all_flat], vf[all_flat]
-            if quantized:
-                # dequantize the logical view with each slot's page scale
-                ks2 = cks.value.reshape(npages, n_kv)[pg]     # (b, S, n_kv)
-                vs2 = cvs.value.reshape(npages, n_kv)[pg]
-                k_all = (k_all.astype(jnp.float32)
-                         * ks2[..., None]).astype(cfg.dtype)
-                v_all = (v_all.astype(jnp.float32)
-                         * vs2[..., None]).astype(cfg.dtype)
+            with jax.named_scope("kv_gather"):
+                lpos = jnp.arange(cfg.max_seq_len)
+                pg = table[:, lpos // ps]                         # (b, S)
+                all_flat = pg * ps + (lpos % ps)[None, :]
+                kf = ck.value.reshape(npages * ps, n_kv, hd)
+                vf = cv.value.reshape(npages * ps, n_kv, hd)
+                k_all, v_all = kf[all_flat], vf[all_flat]
+                if quantized:
+                    # dequantize the logical view with each slot's page scale
+                    ks2 = cks.value.reshape(npages, n_kv)[pg]     # (b, S, n_kv)
+                    vs2 = cvs.value.reshape(npages, n_kv)[pg]
+                    k_all = (k_all.astype(jnp.float32)
+                             * ks2[..., None]).astype(cfg.dtype)
+                    v_all = (v_all.astype(jnp.float32)
+                             * vs2[..., None]).astype(cfg.dtype)
         if chunk_mask is not None:
             # prefix slots (< idx) fully visible; chunk slots by tree mask
-            s_max = cfg.max_seq_len
-            kslot = jnp.arange(s_max)[None, None, :]              # (1,1,S)
-            prefix = kslot < idx[:, None, None]                   # (b,1,S)
-            rel = kslot - idx[:, None, None]                      # (b,1,S)
-            in_chunk = (rel >= 0) & (rel < s_new)
-            rel_c = jnp.broadcast_to(jnp.clip(rel, 0, s_new - 1), (b, s_new, s_max))
-            cm = jnp.broadcast_to(chunk_mask.astype(bool)[None], (b, s_new, s_new))
-            tree = jnp.take_along_axis(cm, rel_c.astype(jnp.int32), axis=2)
-            mask = prefix | (in_chunk & tree)
-            o = cached_attention(q, k_all, v_all, idx, mask=mask)
+            with jax.named_scope("attend"):
+                s_max = cfg.max_seq_len
+                kslot = jnp.arange(s_max)[None, None, :]              # (1,1,S)
+                prefix = kslot < idx[:, None, None]                   # (b,1,S)
+                rel = kslot - idx[:, None, None]                      # (b,1,S)
+                in_chunk = (rel >= 0) & (rel < s_new)
+                rel_c = jnp.broadcast_to(jnp.clip(rel, 0, s_new - 1), (b, s_new, s_max))
+                cm = jnp.broadcast_to(chunk_mask.astype(bool)[None], (b, s_new, s_new))
+                tree = jnp.take_along_axis(cm, rel_c.astype(jnp.int32), axis=2)
+                mask = prefix | (in_chunk & tree)
+                o = cached_attention(q, k_all, v_all, idx, mask=mask)
             o = o.reshape(b, s_new, -1)
             return self._o_proj(o, aidx)
         # prefill/chunk attention: the Pallas kernel with per-slot position
@@ -670,21 +674,22 @@ class LlamaAttention(nn.Module):
             and s_new >= 128
             and flash_supported(s_new, cfg.max_seq_len, blk_q, cfg_blk_k)
         )
-        if use_flash:
-            o = attention(
-                q.transpose(0, 2, 1, 3),
-                k_all.transpose(0, 2, 1, 3),
-                v_all.transpose(0, 2, 1, 3),
-                causal=False,
-                use_flash=True,
-                block_q=blk_q,
-                block_k=cfg_blk_k,
-                q_positions=positions,
-                kv_positions=None,  # default iota: j <= q position
-            )
-            o = o.transpose(0, 2, 1, 3)
-        else:
-            o = cached_attention(q, k_all, v_all, idx)
+        with jax.named_scope("attend"):
+            if use_flash:
+                o = attention(
+                    q.transpose(0, 2, 1, 3),
+                    k_all.transpose(0, 2, 1, 3),
+                    v_all.transpose(0, 2, 1, 3),
+                    causal=False,
+                    use_flash=True,
+                    block_q=blk_q,
+                    block_k=cfg_blk_k,
+                    q_positions=positions,
+                    kv_positions=None,  # default iota: j <= q position
+                )
+                o = o.transpose(0, 2, 1, 3)
+            else:
+                o = cached_attention(q, k_all, v_all, idx)
         o = o.reshape(b, s_new, -1)
         return self._o_proj(o, aidx)
 

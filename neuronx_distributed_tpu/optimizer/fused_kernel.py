@@ -85,6 +85,7 @@ def fused_adamw_leaf(g, mu, nu, ms, scalars, *, b1, b2, eps, wd, p_dtype):
         # mu/nu/master update in place (operand i=2,3,4 -> output 0,1,2)
         input_output_aliases={2: 0, 3: 1, 4: 2},
         interpret=mode.interpret_kernels(),
+        name="fused_adamw",
     )(scalars, g2, mu2, nu2, ms2)
     mu_n, nu_n, ms_n, p_n = out
     return (mu_n.reshape(mu.shape), nu_n.reshape(nu.shape),

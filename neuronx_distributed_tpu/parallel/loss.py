@@ -28,24 +28,27 @@ def parallel_cross_entropy(
     over TP under GSPMD; ``labels``: (...) int32. Returns per-token loss with
     ``ignore_index`` positions zeroed (mask by multiply, reference
     loss_functions.py:58-76)."""
-    vocab = logits.shape[-1]
-    logits = logits.astype(jnp.float32)
-    # stable logsumexp; the max/sum reductions over the sharded vocab axis are
-    # where GSPMD inserts the two TP all-reduces of the reference (:30-49)
-    m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
-    shifted = logits - m
-    lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1)) + jnp.squeeze(m, -1)
-    one_hot = jax.nn.one_hot(labels, vocab, dtype=logits.dtype)
-    label_logit = jnp.sum(one_hot * logits, axis=-1)
-    loss = lse - label_logit
-    if label_smoothing > 0.0:
-        # smoothed target: (1-eps) * one_hot + eps/vocab (reference :78-99)
-        mean_logit = jnp.mean(logits, axis=-1)
-        loss = (1.0 - label_smoothing) * loss + label_smoothing * (lse - mean_logit)
-    if ignore_index is not None:
-        mask = (labels != ignore_index).astype(loss.dtype)
-        loss = loss * mask
-    return loss
+    with jax.named_scope("loss"):
+        vocab = logits.shape[-1]
+        logits = logits.astype(jnp.float32)
+        # stable logsumexp; the max/sum reductions over the sharded vocab axis
+        # are where GSPMD inserts the two TP all-reduces of the reference
+        # (:30-49)
+        m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
+        shifted = logits - m
+        lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1)) + jnp.squeeze(m, -1)
+        one_hot = jax.nn.one_hot(labels, vocab, dtype=logits.dtype)
+        label_logit = jnp.sum(one_hot * logits, axis=-1)
+        loss = lse - label_logit
+        if label_smoothing > 0.0:
+            # smoothed target: (1-eps) * one_hot + eps/vocab (reference :78-99)
+            mean_logit = jnp.mean(logits, axis=-1)
+            loss = ((1.0 - label_smoothing) * loss
+                    + label_smoothing * (lse - mean_logit))
+        if ignore_index is not None:
+            mask = (labels != ignore_index).astype(loss.dtype)
+            loss = loss * mask
+        return loss
 
 
 def parallel_cross_entropy_mean(
@@ -56,7 +59,8 @@ def parallel_cross_entropy_mean(
 ) -> jax.Array:
     """Mean loss over non-ignored tokens."""
     loss = parallel_cross_entropy(logits, labels, label_smoothing, ignore_index)
-    if ignore_index is None:
-        return jnp.mean(loss)
-    denom = jnp.maximum(jnp.sum((labels != ignore_index).astype(jnp.float32)), 1.0)
-    return jnp.sum(loss) / denom
+    with jax.named_scope("loss"):
+        if ignore_index is None:
+            return jnp.mean(loss)
+        denom = jnp.maximum(jnp.sum((labels != ignore_index).astype(jnp.float32)), 1.0)
+        return jnp.sum(loss) / denom

@@ -155,93 +155,102 @@ def make_train_step(
                 loss_acc, grads_acc = carry
                 mb, r = mb_rng
                 loss_i, grads_i = grad_fn(state.params, mb, r)
-                return (loss_acc + loss_i.astype(jnp.float32),
-                        jax.tree.map(lambda a, g: a + g.astype(jnp.float32),
-                                     grads_acc, grads_i)), None
+                with jax.named_scope("grad_accumulate"):
+                    return (loss_acc + loss_i.astype(jnp.float32),
+                            jax.tree.map(
+                                lambda a, g: a + g.astype(jnp.float32),
+                                grads_acc, grads_i)), None
 
             zeros = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
             (loss, grads32), _ = jax.lax.scan(
                 accum, (jnp.float32(0.0), zeros),
                 (micro, jax.random.split(rng, grad_accum_steps)))
-            loss = loss / grad_accum_steps
-            grads = jax.tree.map(
-                lambda g, p: (g / grad_accum_steps).astype(p.dtype),
-                grads32, state.params)
+            with jax.named_scope("grad_accumulate"):
+                loss = loss / grad_accum_steps
+                grads = jax.tree.map(
+                    lambda g, p: (g / grad_accum_steps).astype(p.dtype),
+                    grads32, state.params)
         else:
             loss, grads = grad_fn(state.params, batch, rng)
         metrics = {"loss": loss}
         fused = hasattr(optimizer.tx, "update_and_params")
         scale = None
         if optimizer.grad_clipping:
-            if fused:
-                # fused path: compute the norm (one read pass) but fold the
-                # clip SCALE into the optimizer's grad cast — the clipped
-                # grad tree is never written to HBM
-                from neuronx_distributed_tpu.parallel.grads import get_grad_norm
+            with jax.named_scope("grad_clip"):
+                if fused:
+                    # fused path: compute the norm (one read pass) but fold
+                    # the clip SCALE into the optimizer's grad cast — the
+                    # clipped grad tree is never written to HBM
+                    from neuronx_distributed_tpu.parallel.grads import (
+                        get_grad_norm,
+                    )
 
-                grad_norm = get_grad_norm(grads)
-                # same coefficient as clip_grads_with_norm (grads.py); the
-                # scale is applied in the optimizer's fp32 grad cast, skipping
-                # the classic path's bf16 round-trip of the scaled grads
-                scale = jnp.clip(
-                    optimizer.max_grad_norm / (grad_norm + 1e-6), max=1.0)
-            else:
-                grads, grad_norm = clip_grad_norm(grads, optimizer.max_grad_norm)
+                    grad_norm = get_grad_norm(grads)
+                    # same coefficient as clip_grads_with_norm (grads.py);
+                    # the scale is applied in the optimizer's fp32 grad cast,
+                    # skipping the classic path's bf16 round-trip of the
+                    # scaled grads
+                    scale = jnp.clip(
+                        optimizer.max_grad_norm / (grad_norm + 1e-6), max=1.0)
+                else:
+                    grads, grad_norm = clip_grad_norm(
+                        grads, optimizer.max_grad_norm)
             metrics["grad_norm"] = grad_norm
-        if fused and use_kernel:
-            # single-pass Pallas kernel per leaf, under shard_map (GSPMD
-            # cannot partition a pallas_call): every device updates its own
-            # STATE shard. ZeRO-1 state is more sharded than the params, so
-            # the wrapper performs the operational ZeRO dataflow explicitly:
-            # slice this device's state-shard of the (replicated-over-DP)
-            # grads, update, then all-gather the new param shards back to
-            # the param layout — the same reduce-scatter/all-gather schedule
-            # GSPMD derives on the declarative path.
-            specs_p = jax.tree.map(lambda s: s.spec, param_shardings)
-            specs_s = jax.tree.map(lambda s: s.spec, opt_shardings)
+        with jax.named_scope("optimizer_update"):
+            if fused and use_kernel:
+                # single-pass Pallas kernel per leaf, under shard_map (GSPMD
+                # cannot partition a pallas_call): every device updates its own
+                # STATE shard. ZeRO-1 state is more sharded than the params, so
+                # the wrapper performs the operational ZeRO dataflow explicitly:
+                # slice this device's state-shard of the (replicated-over-DP)
+                # grads, update, then all-gather the new param shards back to
+                # the param layout — the same reduce-scatter/all-gather schedule
+                # GSPMD derives on the declarative path.
+                specs_p = jax.tree.map(lambda s: s.spec, param_shardings)
+                specs_s = jax.tree.map(lambda s: s.spec, opt_shardings)
 
-            def to_state_shard(path, g):
-                plan = _kernel_plan.get(jax.tree_util.keystr(path))
-                if plan is None:
-                    return g
-                d, axes = plan
-                n, idx = 1, jnp.int32(0)
-                for ax in axes:
-                    n *= jax.lax.axis_size(ax)
-                    idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
-                shard = g.shape[d] // n
-                return jax.lax.dynamic_slice_in_dim(g, idx * shard, shard, d)
+                def to_state_shard(path, g):
+                    plan = _kernel_plan.get(jax.tree_util.keystr(path))
+                    if plan is None:
+                        return g
+                    d, axes = plan
+                    n, idx = 1, jnp.int32(0)
+                    for ax in axes:
+                        n *= jax.lax.axis_size(ax)
+                        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
+                    shard = g.shape[d] // n
+                    return jax.lax.dynamic_slice_in_dim(g, idx * shard, shard, d)
 
-            def to_param_shard(path, p):
-                plan = _kernel_plan.get(jax.tree_util.keystr(path))
-                if plan is None:
-                    return p
-                d, axes = plan
-                return jax.lax.all_gather(p, axes, axis=d, tiled=True)
+                def to_param_shard(path, p):
+                    plan = _kernel_plan.get(jax.tree_util.keystr(path))
+                    if plan is None:
+                        return p
+                    d, axes = plan
+                    return jax.lax.all_gather(p, axes, axis=d, tiled=True)
 
-            def local_update(g, s, p, sc):
-                g = jax.tree_util.tree_map_with_path(to_state_shard, g)
-                p_dt = jax.tree_util.tree_map_with_path(to_state_shard, p)
-                new_p, new_s = optimizer.tx.update_and_params_local(
-                    g, s, p_dt, scale=sc)
-                return jax.tree_util.tree_map_with_path(to_param_shard, new_p), new_s
+                def local_update(g, s, p, sc):
+                    g = jax.tree_util.tree_map_with_path(to_state_shard, g)
+                    p_dt = jax.tree_util.tree_map_with_path(to_state_shard, p)
+                    new_p, new_s = optimizer.tx.update_and_params_local(
+                        g, s, p_dt, scale=sc)
+                    return jax.tree_util.tree_map_with_path(to_param_shard, new_p), new_s
 
-            new_params, new_opt_state = jax.shard_map(
-                local_update,
-                mesh=mesh,
-                in_specs=(specs_p, specs_s, specs_p, P()),
-                out_specs=(specs_p, specs_s),
-                check_vma=False,
-            )(grads, state.opt_state, state.params,
-              jnp.float32(1.0) if scale is None else scale)
-        elif fused:
-            new_params, new_opt_state = optimizer.tx.update_and_params(
-                grads, state.opt_state, state.params, scale=scale)
-        else:
-            updates, new_opt_state = optimizer.tx.update(
-                grads, state.opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
+                new_params, new_opt_state = jax.shard_map(
+                    local_update,
+                    mesh=mesh,
+                    in_specs=(specs_p, specs_s, specs_p, P()),
+                    out_specs=(specs_p, specs_s),
+                    check_vma=False,
+                )(grads, state.opt_state, state.params,
+                  jnp.float32(1.0) if scale is None else scale)
+            elif fused:
+                new_params, new_opt_state = optimizer.tx.update_and_params(
+                    grads, state.opt_state, state.params, scale=scale)
+            else:
+                updates, new_opt_state = optimizer.tx.update(
+                    grads, state.opt_state, state.params)
+                new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(step=state.step + 1, params=new_params, opt_state=new_opt_state)
         return new_state, metrics
 

@@ -1,0 +1,140 @@
+"""The names a device trace is read by are in the compiled programs.
+
+``benchmark/trace_parts.py`` tells which part of the model an op belongs to
+from the op's jax name stack. Flax names every module and method; the regions
+it does not name carry a ``jax.named_scope`` (one vocabulary, listed below by
+file), and every ``pallas_call`` a ``name``. Both are metadata only. This
+lowers the tiny fused decode, the tiny paged insert and a tiny train step and
+reads the names back from the compiled HLO, so that a refactoring that drops
+one fails here and not in a trace on the chip.
+"""
+
+import collections
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from flax.core import meta
+
+from benchmark import trace_parts
+from neuronx_distributed_tpu.inference import CausalLM
+from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from neuronx_distributed_tpu.trainer import (
+    create_train_state,
+    initialize_parallel_model,
+    initialize_parallel_optimizer,
+    make_train_step,
+    neuronx_distributed_config,
+)
+
+# scope names by the file that opens them (PERF.md section 3 lists the same)
+SCOPES = {
+    "inference/causal_lm.py fused_fn": ["sampler", "bookkeeping"],
+    "inference/causal_lm.py insert_fn": ["cache_rows", "table_write"],
+    "models/llama.py _decode_attention": ["kv_write", "kv_gather", "attend"],
+    "trainer/step.py": ["grad_accumulate", "grad_clip", "optimizer_update"],
+    "parallel/loss.py": ["loss"],
+}
+KERNELS = ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode", "fused_adamw"]
+
+TINY = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2, num_heads=4,
+            num_kv_heads=2, kv_size_multiplier=1, max_seq_len=256, dtype=jnp.float32,
+            use_flash_attention=True, remat_policy=None)
+OP_NAME = re.compile(r'op_name="([^"]*)"')
+# instructions that execute nothing of their own
+PLUMBING = re.compile(r"\s(parameter|constant|tuple|get-tuple-element|bitcast|iota)\(")
+TABLE = trace_parts.load_table()
+
+
+def census(compiled):
+    """(set of scope components, instructions by part) of a compiled program."""
+    components, parts = set(), collections.Counter()
+    for line in compiled.as_text().splitlines():
+        if " = " not in line or PLUMBING.search(line):
+            continue
+        m = OP_NAME.search(line)
+        tf_op = m.group(1) + ":" if m else ""          # the trace's form: a colon ends it
+        components.update(trace_parts.scope(tf_op, TABLE))
+        parts[trace_parts.part_of({"tf_op": tf_op}, TABLE)] += 1
+    return components, parts
+
+
+def unnamed_share(parts) -> float:
+    return parts["unnamed"] / sum(parts.values())
+
+
+@pytest.fixture(scope="module")
+def params():
+    cfg = LlamaConfig(**TINY)
+    return meta.unbox(LlamaForCausalLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+
+
+def serving_lm(params, **kw):
+    return CausalLM(LlamaConfig(**TINY), params, LlamaForCausalLM, buckets=(128,), max_batch=2,
+                    page_size=16, **kw)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["gather", "paged_kernel"])
+def test_fused_decode_names_its_regions(params, kernel):
+    lm = serving_lm(params, paged_attn_kernel=kernel)
+    components, parts = census(lm.compile_session_decode_fused(4))
+    want = SCOPES["inference/causal_lm.py fused_fn"] + ["kv_write", "attend"]
+    want += ["paged_decode"] if kernel else ["kv_gather"]
+    assert set(want) <= components
+    for part in ("sampler", "bookkeeping", "kv_write", "attend", "attn_proj", "ffn", "norm",
+                 "embed_head"):
+        assert parts[part] > 0, part
+    # what is left without a name: the scan's own plumbing and what the
+    # compiler adds (12 % of the instructions here; 40 % before the scopes)
+    assert unnamed_share(parts) < 0.2, parts
+
+
+def test_paged_insert_names_its_regions(params):
+    components, parts = census(serving_lm(params)._paged_insert_programs(2, 128))
+    want = (SCOPES["inference/causal_lm.py insert_fn"]
+            + SCOPES["models/llama.py _decode_attention"] + ["flash_fwd"])
+    assert set(want) <= components
+    assert parts["cache_write"] > 0 and parts["kv_gather"] > 0
+    assert unnamed_share(parts) < 0.1, parts
+
+
+def test_train_step_names_its_regions():
+    # a vocabulary whose embedding leaves a device 8 x 1024 elements of
+    # optimizer state: the smallest leaf the AdamW kernel takes
+    cfg = LlamaConfig(**dict(TINY, vocab_size=2048, max_seq_len=128, remat_policy="attention"))
+    nxd = neuronx_distributed_config(
+        tensor_parallel_size=2,
+        optimizer_config={"zero_one_enabled": True, "grad_clipping": True, "max_grad_norm": 1.0},
+        mixed_precision_config={"use_master_weights": True})
+    ids = np.random.RandomState(0).randint(1, 128, (8, 128)).astype(np.int32)
+    model = initialize_parallel_model(nxd, lambda: LlamaForCausalLM(cfg), jnp.asarray(ids))
+    opt = initialize_parallel_optimizer(nxd, model, learning_rate=1e-3, weight_decay=0.0)
+    state = create_train_state(model, opt)
+
+    def loss_fn(p, batch, rng):
+        return model.module.apply({"params": p}, batch["ids"], batch["labels"],
+                                  method=LlamaForCausalLM.loss)
+
+    step = make_train_step(model, opt, loss_fn, grad_accum_steps=2, optimizer_kernel=True)
+    batch = {"ids": ids, "labels": ids}
+    components, parts = census(step.lower(state, batch, jax.random.key(0)).compile())
+    want = (SCOPES["trainer/step.py"] + SCOPES["parallel/loss.py"]
+            + ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "fused_adamw"])
+    assert set(want) <= components, sorted(components)
+    for part in ("loss", "optimizer", "attention", "ffn", "collective"):
+        assert part == "collective" or parts[part] > 0, part
+    assert unnamed_share(parts) < 0.2, parts
+
+
+def test_the_vocabulary_is_the_parts_table():
+    """Every scope this test knows is claimed by a row of scope_parts.json
+    (not left to ``named_other``), and no two kernels share a name."""
+    for where, names in SCOPES.items():
+        under = "layers/block/attention/attention._decode_attention/" if "llama" in where else ""
+        for name in names:
+            part = trace_parts.part_of({"tf_op": f"jit(f)/while/body/{under}{name}/add:"}, TABLE)
+            assert part not in ("named_other", "unnamed", "attention"), (name, part)
+    assert len(set(KERNELS)) == len(KERNELS)

@@ -233,8 +233,8 @@ def paged_decode_attention(
     if quantized and v_scale is None:
         raise ValueError("int8 pools carry BOTH k_scale and v_scale")
 
-    # GQA grouping matches cached_attention's repeat(axis=2): query head
-    # h reads kv head h // group, so the (n_kv, group) reshape is exact.
+    # the same GQA grouping as cached_attention's (n_kv, group) view of the
+    # query heads: query head h reads kv head h // group.
     q3 = q[:, 0].reshape(b, n_kv, group, hd)
     if quantized:
         ks3 = k_scale.reshape(num_pages, 1, n_kv).astype(jnp.float32)

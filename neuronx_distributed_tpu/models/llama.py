@@ -277,30 +277,34 @@ def cached_attention(q, k_cache, v_cache, cache_len, sm_scale=None, mask=None):
     bottom-aligned causal semantics (examples/inference/modules/
     attention_base.py; SURVEY §2.2 inference examples row).
 
+    Grouped by KV head: query head ``h`` reads KV head ``h // group`` through
+    a (b, s_new, n_kv, group, d) view of ``q``, so K and V are read once, in
+    the dtype the cache holds (widened inside the matmul, never in memory);
+    accumulation, scores, mask and softmax are float32.
+
     An explicit ``mask`` (b, s_new, S_max) overrides the positional default —
     Medusa tree steps attend by tree ancestry, not linear position
     (reference ``medusa_attn_mask``, utils/medusa_utils.py:59-73)."""
     b, s_new, n, d = q.shape
-    n_kv = k_cache.shape[2]
-    if n != n_kv:
-        k_cache = jnp.repeat(k_cache, n // n_kv, axis=2)
-        v_cache = jnp.repeat(v_cache, n // n_kv, axis=2)
+    s_max, n_kv = k_cache.shape[1:3]
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
-    s_max = k_cache.shape[1]
     cache_len = jnp.asarray(cache_len)
     if cache_len.ndim == 0:
         cache_len = jnp.broadcast_to(cache_len, (b,))
-    scores = jnp.einsum("bind,bjnd->bnij", q.astype(jnp.float32),
-                        k_cache.astype(jnp.float32)) * sm_scale
+    # HIGHEST: the MXU would round a float32 operand (the probabilities, a
+    # float32 model's q) to bf16; bf16 operands are exact in one pass
+    exact = dict(preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST)
+    scores = jnp.einsum("bikgd,bjkd->bkgij", q.reshape(b, s_new, n_kv, n // n_kv, d),
+                        k_cache, **exact) * sm_scale
     if mask is None:
         qpos = cache_len[:, None] + jnp.arange(s_new)[None, :]  # (b, s_new)
         kpos = jnp.arange(s_max)
         mask = kpos[None, None, :] <= qpos[..., None]           # (b, s_new, s_max)
-    scores = jnp.where(mask[:, None], scores, -1e30)
+    scores = jnp.where(mask[:, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bnij,bjnd->bind", probs, v_cache.astype(jnp.float32))
-    return out.astype(q.dtype)
+    out = jnp.einsum("bkgij,bjkd->bikgd", probs, v_cache, **exact)
+    return out.reshape(b, s_new, n, d).astype(q.dtype)
 
 
 def _adapter_idx(mdl: nn.Module, batch: int) -> jax.Array:

@@ -14,13 +14,17 @@ executes, so nothing here says the results are right or fast — that is
 The file name sorts first so the tier-1 clock always reaches it.
 """
 
+import dataclasses
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 
 import jax
 import jax.numpy as jnp
 import pytest
+from flax.core import meta
 from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
@@ -110,6 +114,52 @@ def test_paged_decode_attention(chip, n_kv, pages):
         assert _kernel_calls(fn, *args, scale, scale) == 1
     else:
         assert _kernel_calls(paged_decode_attention, *args) == 1
+
+
+def test_gather_decode_step_holds_no_widened_slab(chip):
+    """The single-token decode step of the default (gather) path at the
+    long-context cell's shape: GQA 32/8, head 128, bf16 pages of 16, batch 8,
+    ``max_seq_len`` 4096, two scanned layers. ``cached_attention`` contracts
+    grouped by KV head in the cache's dtype, so the compiled step holds no
+    float32 array as large as the gathered slab (K and V repeated per query
+    head and widened are two ``f32[8,4096,32,128]`` a layer, 537 MB each),
+    and its temporaries stay under what it needs: the page pools copied once
+    (they ride the layer scan's carry), the gathered K/V pair, and the
+    compiler's head-major copy of that pair (538.5 MB measured; 1 343 MB
+    with the repeat)."""
+    b, s_max, n_kv, page = 8, 4096, 8, 16
+    cfg = dataclasses.replace(
+        LlamaConfig(vocab_size=256, hidden_size=32 * HEAD_DIM,
+                    intermediate_size=1024, num_heads=32, num_kv_heads=n_kv,
+                    num_layers=2, max_seq_len=s_max, dtype=jnp.bfloat16,
+                    param_dtype=jnp.bfloat16),
+        decode=True, remat_policy=None, page_size=page,
+        page_pool_pages=b * s_max // page)
+    model = LlamaForCausalLM(cfg)
+    token = jnp.zeros((b, 1), jnp.int32)
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        meta.unbox(jax.eval_shape(
+            lambda: model.init(jax.random.key(0), token))))
+
+    def step(params, cache, ids):
+        return model.apply({"params": params, "cache": cache}, ids,
+                           mutable=["cache"])
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        variables["params"], variables["cache"],
+        jax.ShapeDtypeStruct(token.shape, token.dtype,
+                             sharding=chip)).compile()
+    slab = b * s_max * n_kv * HEAD_DIM
+    widened = {m.group(0) for m in re.finditer(r"f32\[([0-9,]+)\]",
+                                               compiled.as_text())
+               if math.prod(map(int, m.group(1).split(","))) >= slab}
+    assert not widened, widened
+    slab_pair = 2 * slab * 2                      # K and V, bf16
+    pools = cfg.num_layers * slab_pair            # the pool is slab-sized here
+    margin = slab_pair // 4
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < pools + 2 * slab_pair + margin)
 
 
 def test_fused_adamw_leaf(chip):

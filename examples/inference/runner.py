@@ -59,11 +59,16 @@ logger = get_logger("nxd.examples.inference")
 
 def _model_cls(args):
     """Model family selector (reference ships run_llama.py / run_mixtral.py /
-    run_dbrx.py as separate scripts; one flag here)."""
+    run_dbrx.py as separate scripts; one flag here; OLMoE is Mixtral's
+    stack with QK-norm and an un-renormalised router, models/olmoe.py)."""
     if args.model in ("mixtral", "dbrx"):
         from neuronx_distributed_tpu.models.mixtral import MixtralForCausalLM
 
         return MixtralForCausalLM
+    if args.model == "olmoe":
+        from neuronx_distributed_tpu.models.olmoe import OlmoeForCausalLM
+
+        return OlmoeForCausalLM
     return LlamaForCausalLM
 
 
@@ -103,6 +108,17 @@ def _family_config(args):
         preset = dbrx if family == "dbrx" else mixtral_8x7b
         return preset(max_seq_len=args.max_seq_len, dtype=jnp.bfloat16,
                       param_dtype=jnp.bfloat16, remat_policy=None)
+    if family == "olmoe":
+        from neuronx_distributed_tpu.models.olmoe import OlmoeConfig, olmoe_1b_7b
+
+        if args.tiny:
+            return OlmoeConfig(
+                vocab_size=512, hidden_size=64, intermediate_size=32, num_layers=2,
+                num_heads=4, num_kv_heads=4, max_seq_len=256, dtype=jnp.float32,
+                use_flash_attention=False, num_experts=16, top_k=4,
+            )
+        return olmoe_1b_7b(max_seq_len=args.max_seq_len, dtype=jnp.bfloat16,
+                           param_dtype=jnp.bfloat16, remat_policy=None)
     if args.tiny:
         return LlamaConfig(
             vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -1161,7 +1177,7 @@ def main(argv=None) -> None:
                             "corrupt_page_prob/seed")
         p.add_argument("--quantize", action="store_true",
                        help="serve int8 weight-only quantized params")
-        p.add_argument("--model", choices=["llama", "mixtral", "dbrx"],
+        p.add_argument("--model", choices=["llama", "mixtral", "dbrx", "olmoe"],
                        default="llama")
     args = parser.parse_args(argv)
     if args.tiny:

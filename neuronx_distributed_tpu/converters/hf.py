@@ -17,6 +17,9 @@ Family-specific layouts handled:
   ``(E, H, I)`` tensors sharded ``(ep, None, tp)`` (reference
   ``convert_full_state_to_tp`` stacks the same way for its fused
   ``expert_mlps`` module).
+* **olmoe** — Mixtral's stacking under OLMoE's key names
+  (``mlp.gate``, ``mlp.experts.{e}.{gate,up,down}_proj``) plus the flat
+  QK-norm scales ``self_attn.{q,k}_norm.weight``.
 * **gpt_neox** — HF NeoX fuses QKV **head-interleaved**:
   ``query_key_value.weight`` is ``(N·3·D, H)`` ordered ``[q_h, k_h, v_h]``
   per head ``h`` — NOT ``[Q; K; V]`` blocks. Biases everywhere, biased
@@ -80,49 +83,111 @@ def mixtral_config_from_hf(path: str):
     )
 
 
+# HF key templates of a sparse-expert FFN: (router, one expert matrix, the
+# HF names of our gate/up/down). One stacking routine serves both families.
+_MIXTRAL_MOE = ("model.layers.{i}.block_sparse_moe.gate.weight",
+                "model.layers.{i}.block_sparse_moe.experts.{e}.{w}.weight",
+                (("w1", "gate"), ("w3", "up"), ("w2", "down")))
+_OLMOE_MOE = ("model.layers.{i}.mlp.gate.weight",
+              "model.layers.{i}.mlp.experts.{e}.{w}.weight",
+              (("gate_proj", "gate"), ("up_proj", "up"), ("down_proj", "down")))
+
+
+def _moe_from_hf(hf: Dict[str, np.ndarray], L: int, E: int, keys) -> PyTree:
+    """Experts stacked to the fused 3D layout: (L, E, in, out) from L x E
+    torch (out, in) matrices; the router (L, hidden, E)."""
+    router, expert, names = keys
+    return {
+        "router": {"kernel": np.stack([_np(hf[router.format(i=i)]).T for i in range(L)])},
+        "experts": {ours: np.stack([
+            np.stack([_np(hf[expert.format(i=i, e=e, w=w)]).T for e in range(E)])
+            for i in range(L)]) for w, ours in names},
+    }
+
+
+def _moe_to_hf(out: Dict[str, np.ndarray], moe: PyTree, L: int, E: int, keys, dtype) -> None:
+    router, expert, names = keys
+    for i in range(L):
+        out[router.format(i=i)] = _np(moe["router"]["kernel"][i], dtype).T
+        for e in range(E):
+            for w, ours in names:
+                out[expert.format(i=i, e=e, w=w)] = _np(moe["experts"][ours][i, e], dtype).T
+
+
 def hf_to_nxd_mixtral(hf: Dict[str, np.ndarray], config,
                       dtype: Optional[Any] = None) -> PyTree:
     """Attention/embed/norm mapping as Llama; experts stacked to the fused 3D
     layout (reference checkpoint_converter.py Mixtral subclass role)."""
     cfg = config
-    L, E = cfg.num_layers, cfg.num_experts
-    dt = dtype or cfg.param_dtype
     # reuse the Llama attention/embed mapping (MixtralConfig IS a LlamaConfig;
     # the dense-mlp keys are absent so hf_to_nxd_llama skips them)
     base = hf_to_nxd_llama(
         {k: v for k, v in hf.items() if "block_sparse_moe" not in k},
         cfg, dtype=np.float32)
-    block = base["model"]["layers"]["block"]
-
-    def expert_stack(i, w):  # (E, in, out) from E torch (out, in) mats
-        return np.stack([
-            _np(hf[f"model.layers.{i}.block_sparse_moe.experts.{e}.{w}.weight"]).T
-            for e in range(E)])
-
-    block["moe"] = {
-        "router": {"kernel": np.stack([
-            _np(hf[f"model.layers.{i}.block_sparse_moe.gate.weight"]).T
-            for i in range(L)])},
-        "experts": {
-            "gate": np.stack([expert_stack(i, "w1") for i in range(L)]),
-            "up": np.stack([expert_stack(i, "w3") for i in range(L)]),
-            "down": np.stack([expert_stack(i, "w2") for i in range(L)]),
-        },
-    }
-    return _to_jnp(base, dt)
+    base["model"]["layers"]["block"]["moe"] = _moe_from_hf(
+        hf, cfg.num_layers, cfg.num_experts, _MIXTRAL_MOE)
+    return _to_jnp(base, dtype or cfg.param_dtype)
 
 
 def nxd_to_hf_mixtral(params: PyTree, config, dtype: Any = np.float32) -> Dict[str, np.ndarray]:
     cfg = config
     out = nxd_to_hf_llama(_drop_moe(params), cfg, dtype=dtype)
-    moe = params["model"]["layers"]["block"]["moe"]
-    for i in range(cfg.num_layers):
-        out[f"model.layers.{i}.block_sparse_moe.gate.weight"] = _np(
-            moe["router"]["kernel"][i], dtype).T
-        for e in range(cfg.num_experts):
-            for hf_name, ours in (("w1", "gate"), ("w3", "up"), ("w2", "down")):
-                out[f"model.layers.{i}.block_sparse_moe.experts.{e}.{hf_name}.weight"] = \
-                    _np(moe["experts"][ours][i, e], dtype).T
+    _moe_to_hf(out, params["model"]["layers"]["block"]["moe"], cfg.num_layers,
+               cfg.num_experts, _MIXTRAL_MOE, dtype)
+    return out
+
+
+# ----------------------------------------------------------------------- olmoe
+
+def olmoe_config_from_hf(path: str):
+    from neuronx_distributed_tpu.models.olmoe import OlmoeConfig
+
+    hc = _read_hf_config(path)
+    return OlmoeConfig(
+        vocab_size=hc["vocab_size"],
+        hidden_size=hc["hidden_size"],
+        intermediate_size=hc["intermediate_size"],
+        num_layers=hc["num_hidden_layers"],
+        num_heads=hc["num_attention_heads"],
+        num_kv_heads=hc.get("num_key_value_heads", hc["num_attention_heads"]),
+        max_seq_len=hc.get("max_position_embeddings", 4096),
+        rope_theta=hc.get("rope_theta", 10000.0),
+        rms_norm_eps=hc.get("rms_norm_eps", 1e-5),
+        tie_word_embeddings=hc.get("tie_word_embeddings", False),
+        qkv_clip=hc.get("clip_qkv"),
+        num_experts=hc["num_experts"],
+        top_k=hc["num_experts_per_tok"],
+        norm_topk_prob=hc.get("norm_topk_prob", False),
+    )
+
+
+_OLMOE_QK_NORM = (("q_norm", "model.layers.{i}.self_attn.q_norm.weight"),
+                  ("k_norm", "model.layers.{i}.self_attn.k_norm.weight"))
+
+
+def hf_to_nxd_olmoe(hf: Dict[str, np.ndarray], config,
+                    dtype: Optional[Any] = None) -> PyTree:
+    """Llama's attention/embed/norm mapping, the flat QK-norm scales beside
+    the projections, and Mixtral's expert stacking under OLMoE's key names."""
+    cfg = config
+    base = hf_to_nxd_llama({k: v for k, v in hf.items() if ".mlp." not in k},
+                           cfg, dtype=np.float32)
+    block = base["model"]["layers"]["block"]
+    for ours, key in _OLMOE_QK_NORM:
+        block["attention"][ours] = np.stack(
+            [_np(hf[key.format(i=i)]) for i in range(cfg.num_layers)])
+    block["moe"] = _moe_from_hf(hf, cfg.num_layers, cfg.num_experts, _OLMOE_MOE)
+    return _to_jnp(base, dtype or cfg.param_dtype)
+
+
+def nxd_to_hf_olmoe(params: PyTree, config, dtype: Any = np.float32) -> Dict[str, np.ndarray]:
+    cfg = config
+    out = nxd_to_hf_llama(_drop_moe(params), cfg, dtype=dtype)
+    block = params["model"]["layers"]["block"]
+    for ours, key in _OLMOE_QK_NORM:
+        for i in range(cfg.num_layers):
+            out[key.format(i=i)] = _np(block["attention"][ours][i], dtype)
+    _moe_to_hf(out, block["moe"], cfg.num_layers, cfg.num_experts, _OLMOE_MOE, dtype)
     return out
 
 
@@ -525,6 +590,7 @@ class Family(NamedTuple):
 FAMILIES: Dict[str, Family] = {
     "llama": Family(llama_config_from_hf, hf_to_nxd_llama, nxd_to_hf_llama),
     "mixtral": Family(mixtral_config_from_hf, hf_to_nxd_mixtral, nxd_to_hf_mixtral),
+    "olmoe": Family(olmoe_config_from_hf, hf_to_nxd_olmoe, nxd_to_hf_olmoe),
     "gpt_neox": Family(neox_config_from_hf, hf_to_nxd_neox, nxd_to_hf_neox),
     "bert": Family(bert_config_from_hf, hf_to_nxd_bert, nxd_to_hf_bert),
     "dbrx": Family(dbrx_config_from_hf, hf_to_nxd_dbrx, nxd_to_hf_dbrx),
@@ -539,6 +605,8 @@ def detect_family(hf_keys) -> str:
         return "mixtral"
     if any("norm_attn_norm" in k for k in keys):  # DBRX-unique submodule
         return "dbrx"
+    if any(".mlp.experts." in k for k in keys) and any(".self_attn.q_norm." in k for k in keys):
+        return "olmoe"
     if any(k.startswith("gpt_neox.") for k in keys):
         return "gpt_neox"
     if any(k.startswith("bert.") for k in keys):

@@ -141,6 +141,17 @@ def _scatter_cache_rows(old: PyTree, fresh: PyTree, slots: jax.Array,
     return jax.tree_util.tree_map_with_path(upd, old, fresh)
 
 
+def _routing_sums(stats: PyTree, live: jax.Array) -> jax.Array:
+    """``(3,) int32`` of one decode step: expert slots touched by a live row
+    (summed over layers), assignments of live rows, layer steps with a live
+    row — from the ``(layers, rows, experts)`` choice masks ``MoE`` sows."""
+    chosen = jnp.concatenate([c.reshape(-1, *c.shape[-2:])
+                              for c in jax.tree.leaves(stats)])
+    chosen = chosen & live[None, :, None]
+    return jnp.stack([jnp.sum(jnp.any(chosen, axis=1)), jnp.sum(chosen),
+                      chosen.shape[0] * jnp.any(live)]).astype(jnp.int32)
+
+
 def infer_prompt_lengths(prompt_ids: np.ndarray, pad_token_id: int = 0) -> np.ndarray:
     """Length of each right-padded prompt = 1 + rightmost non-pad position.
     Robust to ``pad_token_id`` occurring INSIDE a prompt (only the trailing
@@ -340,6 +351,9 @@ class CausalLM:
         # the engine "compile" lane.
         self.compile_ms: Dict[str, float] = {}
         self.tracer = None
+        # a config with experts: the fused session decode counts what its
+        # router chose (three sums, one more output; see its docstring)
+        self.moe_stats = getattr(self.config, "num_experts", 0) > 1
 
     # --- compilation (reference ModelBuilder.trace over CTX/TKG) ---------
 
@@ -733,6 +747,15 @@ class CausalLM:
         block t's OUTPUT without a host fetch, so the final carried state
         must surface as a device value (the sync path ignores it). Cached
         per ``(steps, slot_sampler, pad)``.
+
+        A model with experts (``self.moe_stats``) returns one more value,
+        LAST: ``(3,) int32`` sums over the block's steps and the layers of
+        what the router chose for the rows that were live (active and not
+        done) at each step — expert slots touched (experts with a live row),
+        assignments (live rows x top-k x layers) and layer steps with a live
+        row. ``touched / (layer_steps x experts)`` is the share of the expert
+        weights a sparse read would have needed. A dense model's program is
+        unchanged.
         """
         if steps < 1:
             raise ValueError(f"steps must be >= 1, got {steps}")
@@ -742,6 +765,7 @@ class CausalLM:
             return self._session_fused[key]
         max_len = self.config.max_seq_len
         n_ad = 2 if self.lora else 0
+        moe = self.moe_stats
 
         def fused_fn(params, cache, tok, slot_keys, counts, lengths, active,
                      done, eos_ids, temperature, greedy, *tail):
@@ -752,6 +776,8 @@ class CausalLM:
                 gactive = gidx > 0
 
             def body(carry, _):
+                if moe:
+                    *carry, mstats = carry
                 if gr:
                     cache, tok, counts, lengths, done, gstate = carry
                 else:
@@ -759,7 +785,8 @@ class CausalLM:
                 with jax.named_scope("sampler"):
                     sub = jax.vmap(jax.random.fold_in)(slot_keys, counts)
                 logits, mut = self.model.apply(
-                    self._ad_vars(params, cache, ad), tok, mutable=["cache"]
+                    self._ad_vars(params, cache, ad), tok,
+                    mutable=["cache", "moe_stats"] if moe else ["cache"]
                 )
                 with jax.named_scope("sampler"):
                     allowed = None
@@ -770,6 +797,9 @@ class CausalLM:
                                        greedy, allowed=allowed)
                 with jax.named_scope("bookkeeping"):
                     done_before = done
+                    if moe:
+                        mstats = mstats + _routing_sums(mut["moe_stats"],
+                                                        active & ~done)
                     out = jnp.where(done | ~active, jnp.int32(pad_token_id),
                                     nxt)
                     done = done | (active & (eos_ids >= 0) & (nxt == eos_ids))
@@ -787,21 +817,25 @@ class CausalLM:
                 carry = ((mut["cache"], nxt[:, None], counts, lengths, done,
                           gstate) if gr else
                          (mut["cache"], nxt[:, None], counts, lengths, done))
-                return carry, out
+                return ((*carry, mstats) if moe else carry), out
 
             init = ((cache, tok, counts, lengths, done, gstate0) if gr
                     else (cache, tok, counts, lengths, done))
+            if moe:
+                init = (*init, jnp.zeros((3,), jnp.int32))
             carry, toks = jax.lax.scan(body, init, None, length=steps)
             cache, tok, _counts, lengths, done = carry[:5]
+            last = self._replicate_out((carry[-1],)) if moe else ()
             # row outputs pinned replicated: the async loop feeds block
             # t+1's inputs from these COMMITTED values (and edits them with
             # eager staged-override ops), so they must come back in exactly
             # the layout the lowered row inputs require — see repl_args
             if gr:
                 return (*self._replicate_out((toks,)), self._shard_out(cache),
-                        *self._replicate_out((tok, lengths, done, carry[5])))
+                        *self._replicate_out((tok, lengths, done, carry[5])),
+                        *last)
             return (*self._replicate_out((toks,)), self._shard_out(cache),
-                    *self._replicate_out((tok, lengths, done)))
+                    *self._replicate_out((tok, lengths, done)), *last)
 
         b = self.max_batch
         self._session_fused[key] = self._time_compile(

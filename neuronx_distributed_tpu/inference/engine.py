@@ -320,6 +320,10 @@ _STAT_KEYS = (
     # per-request Completion lists
     "completed", "generated_tokens", "ontime_tokens", "deadline_misses",
     "queue_blocks_sum", "ttft_blocks_sum",
+    # what the router chose for the live rows of the fused decode blocks
+    # (models with experts; CausalLM.compile_session_decode_fused): expert
+    # slots touched, assignments, layer steps, each summed over steps x layers
+    "moe_experts_touched", "moe_assignments", "moe_layer_steps",
 )
 
 
@@ -3543,14 +3547,28 @@ class ServeEngine:
         the async loop fetches block t while the counter already reads t+1.
         The fetch/dispatch span pairing on this lane is the measured half
         of the zero-host-blocking contract (``interblock_gaps``)."""
+        get = jax.device_get if isinstance(arr, tuple) else np.asarray
         if not self.tracer.enabled:
-            return np.asarray(arr)
+            return get(arr)
         t0 = time.perf_counter()
-        out = np.asarray(arr)
+        out = get(arr)
         self.tracer.complete("fetch", (self.lane, "dispatch"), t0,
                              time.perf_counter(),
                              block=self.blocks if block is None else block)
         return out
+
+    def _count_routing(self, sums, block: Optional[int] = None) -> None:
+        """One fused block's routing sums into ``stats`` and onto a counter
+        track (the share of the expert slots the live rows touched)."""
+        touched, assigned, layer_steps = (int(x) for x in sums)
+        self.stats["moe_experts_touched"] += touched
+        self.stats["moe_assignments"] += assigned
+        self.stats["moe_layer_steps"] += layer_steps
+        if self.tracer.enabled and layer_steps:
+            self.tracer.counter(
+                "moe_experts_touched_share", (self.lane, "blocks"),
+                touched / (layer_steps * self.lm.config.num_experts),
+                block=self.blocks if block is None else block)
 
     def step_block(self) -> bool:
         """One scheduling round: drain recovery replays, admit (expire/shed
@@ -3665,6 +3683,10 @@ class ServeEngine:
             self.session.lengths = self.session.lengths + self.block_steps
             self.stats["program_calls"] += 1
             self.stats["host_fetches"] += 1
+            if self.lm.moe_stats:      # the routing sums ride the same fetch
+                toks, sums = self._fetch((toks, outs[-1]))
+                self._count_routing(sums)
+                return toks
             return self._fetch(toks)
         out = np.zeros((self.block_steps, self.lm.max_batch), np.int64)
         done = self._done.copy()
@@ -3922,6 +3944,7 @@ class ServeEngine:
             self.session.cache = outs[1]
             rec = {"toks": outs[0], "nxt": outs[2], "done": outs[4],
                    "gstate": outs[5] if self.grammar else None,
+                   "moe": outs[-1] if self.lm.moe_stats else None,
                    "rids": rids, "block": self.blocks}
         self._staged.clear()
         # the device increments lengths/counts unconditionally for every
@@ -3953,7 +3976,12 @@ class ServeEngine:
         its new occupant. The live done-latch gate discards a finished
         row's over-produced tokens, exactly like sync's mid-block
         post-EOS discard."""
-        toks = self._fetch(rec["toks"], block=rec["block"])
+        if rec.get("moe") is not None:
+            toks, sums = self._fetch((rec["toks"], rec["moe"]),
+                                     block=rec["block"])
+            self._count_routing(sums, block=rec["block"])
+        else:
+            toks = self._fetch(rec["toks"], block=rec["block"])
         self.stats["host_fetches"] += 1
         now = time.perf_counter()
         rids = rec["rids"]

@@ -38,6 +38,7 @@ from neuronx_distributed_tpu.parallel.loss import (
     parallel_cross_entropy,
     parallel_cross_entropy_mean,
 )
+from neuronx_distributed_tpu.parallel.mesh import TP_AXIS
 from neuronx_distributed_tpu.parallel.partitioning import ACT_FULL, ACT_SP, constrain
 
 Dtype = Any
@@ -90,6 +91,10 @@ class LlamaConfig:
     tie_word_embeddings: bool = False
     # clamp q/k/v projections to [-qkv_clip, qkv_clip] (DBRX's clip_qkv)
     qkv_clip: Optional[float] = None
+    # OLMoE's QK-norm: RMSNorm (eps ``rms_norm_eps``) of the q and of the k
+    # projection over ALL heads together, before the split into heads, the
+    # clip and the rotary. Off: no parameter and no op.
+    qk_norm: bool = False
     decode: bool = False  # KV-cache inference mode (cache collection)
     # CE loss sequence-chunking (long-seq memory lever): the head matmul +
     # CE run per chunk of this many tokens when seq exceeds it (None = 4096)
@@ -377,6 +382,10 @@ class LlamaAttention(nn.Module):
                 dv = jnp.repeat(dv, cfg.kv_size_multiplier, axis=2)
             k = k + dk.astype(k.dtype)
             v = v + dv.astype(v.dtype)
+        if cfg.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = self._qk_norm("q_norm", q, cfg.num_heads, 1)
+                k = self._qk_norm("k_norm", k, cfg.num_kv_heads, cfg.kv_size_multiplier)
         if cfg.qkv_clip is not None:  # DBRX clip_qkv (applied pre-RoPE)
             q = jnp.clip(q, -cfg.qkv_clip, cfg.qkv_clip)
             k = jnp.clip(k, -cfg.qkv_clip, cfg.qkv_clip)
@@ -411,6 +420,25 @@ class LlamaAttention(nn.Module):
             )
         o = o.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[1], -1)
         return self._o_proj(o, aidx)
+
+    def _qk_norm(self, name, y, heads, repeat):
+        """RMSNorm in float32 over the flattened (heads, head_dim) axes of
+        ``y`` (b, s, heads * repeat, d): the mean of squares crosses the head
+        shards under a TP mesh. The scale is stored flat, as published, and
+        sharded as the heads are (compact under ``kv_size_multiplier``, like
+        the K kernel: copies of a head leave the mean as it was)."""
+        cfg = self.config
+        hd = cfg.head_dim_
+        scale = self.param(
+            name, nn.with_partitioning(nn.initializers.ones_init(),
+                                       (TP_AXIS if repeat == 1 else None,)),
+            (heads * hd,), cfg.param_dtype).reshape(heads, hd)
+        if repeat > 1:
+            scale = jnp.repeat(scale, repeat, axis=0)
+        yf = y.astype(jnp.float32)
+        var = jnp.mean(jnp.square(yf), axis=(-2, -1), keepdims=True)
+        y = (yf * jax.lax.rsqrt(var + cfg.rms_norm_eps)).astype(y.dtype)
+        return y * scale.astype(y.dtype)
 
     def _o_proj(self, o, aidx=None):
         cfg = self.config
@@ -796,11 +824,12 @@ class LlamaModel(nn.Module):
         # scan over layers: one compiled body, params stacked on a leading
         # (unsharded) layer axis. "losses" carries per-layer sown aux losses
         # (MoE variants), "adapters" the per-layer LoRA pool stacks (multi-
-        # LoRA serving); unused collections in variable_axes are harmless.
+        # LoRA serving), "moe_stats" the per-layer routing choices of a decode
+        # step; unused collections in variable_axes are harmless.
         self.layers = nn.scan(
             _LayerStep,
             variable_axes={"params": 0, "cache": 0, "losses": 0,
-                           "adapters": 0},
+                           "adapters": 0, "moe_stats": 0},
             split_rngs={"params": True},
             length=cfg.num_layers,
             in_axes=nn.broadcast,

@@ -8,6 +8,15 @@ attention too), each decoder layer's MLP replaced by the MoE block with
 top-k routing, load-balancing aux loss summed into the training loss, and
 token-generation inference dispatching to selective expert loading
 (``moe/expert_mlps.py``).
+
+What the dispatch rule (``moe/layer.py``, ``T * top_k / E`` against
+``selective_loading_threshold`` 0.5) does by shape: Mixtral (8 experts,
+top-2) decodes all-experts from 2 rows up, and 4 rows touch every expert
+anyway; DBRX (16, top-4) likewise from 2 rows; OLMoE (``models/olmoe.py``: 64,
+top-8) decodes all-experts from 4 rows up, reading all 64 experts where 8 rows
+choose at most 64 and 3 about 21. Every prefill is all-experts: ``E / top_k``
+= 4 x (Mixtral, DBRX) or 8 x (OLMoE) the expert FLOPs the routing needs.
+ROADMAP S4 (one dropless grouped matmul) replaces the rule.
 """
 
 from __future__ import annotations
@@ -33,6 +42,7 @@ from neuronx_distributed_tpu.parallel.loss import parallel_cross_entropy_mean
 class MixtralConfig(LlamaConfig):
     num_experts: int = 8
     top_k: int = 2
+    norm_topk_prob: bool = True  # HF's key; OLMoE: false (models/olmoe.py)
     moe_mode: str = "capacity_factor"  # training/ctx: "capacity_factor" | "all_experts"
     capacity_factor: float = 1.25
     router: str = "top_k"
@@ -80,6 +90,7 @@ class MixtralDecoderLayer(nn.Module):
             hidden_size=cfg.hidden_size,
             intermediate_size=cfg.intermediate_size,
             top_k=cfg.top_k,
+            norm_topk_prob=cfg.norm_topk_prob,
             router=cfg.router,
             mode=cfg.moe_mode,
             capacity_factor=cfg.capacity_factor,

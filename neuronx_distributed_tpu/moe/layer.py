@@ -27,6 +27,7 @@ class MoE(nn.Module):
     hidden_size: int
     intermediate_size: int
     top_k: int = 2
+    norm_topk_prob: bool = True        # top_k router: renormalise the kept k
     router: str = "top_k"              # "top_k" | "sinkhorn"
     mode: str = "capacity_factor"      # "capacity_factor" | "all_experts"
     capacity_factor: float = 1.25
@@ -38,7 +39,14 @@ class MoE(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
     # inference dispatch (reference expert_mlps.py:297 forward): token-gen
     # steps (seq==1) use selective loading when T*top_k/E is below the
-    # threshold, else all_experts; context encoding keeps `mode`
+    # threshold, else all_experts; context encoding keeps `mode`, and never
+    # drops (capacity_factor -> all_experts). At Mixtral's 8 experts top-2
+    # that is all-experts from 2 rows up, which costs nothing: 4 rows touch
+    # every expert. At OLMoE's 64 experts top-8 it is all-experts from 4 rows
+    # up too (4 * 8 / 64 = 0.5), where 8 rows choose at most 64 and about 3
+    # live rows about 21 of the 64 it reads; and every prefill does
+    # E / top_k = 8 x the expert FLOPs the routing needs (Mixtral: 4 x).
+    # ROADMAP S4 replaces the rule; `olmoe-1b-7b.chat` shows its cost.
     inference: bool = False
     selective_loading_threshold: float = 0.5
 
@@ -53,12 +61,17 @@ class MoE(nn.Module):
         flat = x.reshape(b * s, h)
 
         if self.router == "top_k":
-            router = RouterTopK(self.num_experts, top_k=self.top_k, name="router")
+            router = RouterTopK(self.num_experts, top_k=self.top_k,
+                                norm_topk_prob=self.norm_topk_prob, name="router")
         elif self.router == "sinkhorn":
             router = RouterSinkhorn(self.num_experts, name="router")
         else:
             raise ValueError(f"unknown router {self.router!r}")
         combine, logits = router(flat)
+        if self.inference and s == 1 and self.is_mutable_collection("moe_stats"):
+            # the (rows, experts) choices of a decode step, for the fused
+            # session decode's routing counter (inference/causal_lm.py)
+            self.sow("moe_stats", "chosen", combine > 0)
 
         mode = self.mode
         if self.inference:

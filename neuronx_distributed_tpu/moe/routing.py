@@ -19,10 +19,13 @@ from neuronx_distributed_tpu.parallel.layers import default_kernel_init
 class RouterTopK(nn.Module):
     """Softmax top-k router. Returns (combine_weights, logits) where
     ``combine_weights`` is (T, E) with exactly ``top_k`` nonzeros per row,
-    renormalized to sum 1 (reference RouterTopK, routing.py:89-121)."""
+    renormalized to sum 1 (reference RouterTopK, routing.py:89-121) unless
+    ``norm_topk_prob`` is off (HF's key: OLMoE keeps the chosen softmax
+    probabilities as they are, so a row sums to less than one)."""
 
     num_experts: int
     top_k: int = 2
+    norm_topk_prob: bool = True
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -37,6 +40,8 @@ class RouterTopK(nn.Module):
         topv, topi = jax.lax.top_k(probs, self.top_k)
         mask = jnp.sum(jax.nn.one_hot(topi, self.num_experts, dtype=probs.dtype), axis=-2)
         gates = probs * mask
+        if not self.norm_topk_prob:
+            return gates, logits
         denom = jnp.sum(gates, axis=-1, keepdims=True)
         combine = gates / jnp.maximum(denom, 1e-9)
         return combine, logits

@@ -162,6 +162,56 @@ def test_gather_decode_step_holds_no_widened_slab(chip):
             < pools + 2 * slab_pair + margin)
 
 
+def test_olmoe_decode_step_and_insert_hold_the_all_experts_temporaries(chip):
+    """Two scanned layers at OLMoE-1B-7B's published widths (hidden 2048,
+    16 x 128 MHA with QK-norm, 64 experts of 1024, top-8, vocab 50304), bf16,
+    pages of 16, batch 8, ``max_seq_len`` 1024: the single-token decode step
+    and the model's part of an 8 x 512 paged insert. ``MoE`` runs every
+    expert in both (``moe/layer.py``: 8 x 8 / 64 = 1.0 is not under the 0.5
+    threshold; a prefill never drops), so the insert holds the
+    ``(64, 4096, 1024)`` gated activations of all 64 experts (512 MiB in
+    bf16) where the routing needs an eighth of them; the compiler fuses gate,
+    up and silu into that one array and the ``(64, 4096, 2048)`` expert
+    outputs (1 GiB) into the weighted sum, so they are never held. Recorded
+    here (temporaries by ``memory_analysis()``): decode step 323 MiB (the
+    page pools copied once, as in the case above), insert 660 MiB. The
+    bounds keep a change that holds more (the expert outputs, or a float32
+    copy of the activations) from passing unseen; ROADMAP S4's grouped
+    dispatch should take the insert under the activations' 512 MiB."""
+    from neuronx_distributed_tpu.models.olmoe import OlmoeForCausalLM, olmoe_1b_7b
+
+    b, s_max, page = 8, 1024, 16
+    cfg = dataclasses.replace(
+        olmoe_1b_7b(num_layers=2, max_seq_len=s_max, dtype=jnp.bfloat16,
+                    param_dtype=jnp.bfloat16),
+        decode=True, remat_policy=None, page_size=page,
+        page_pool_pages=b * s_max // page + b)
+    model = OlmoeForCausalLM(cfg)
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        meta.unbox(jax.eval_shape(
+            lambda: model.init(jax.random.key(0), jnp.zeros((b, 1), jnp.int32)))))
+    assert variables["params"]["model"]["layers"]["block"]["attention"]["q_norm"].shape == (2, 2048)
+
+    def step(params, cache, ids):
+        return model.apply({"params": params, "cache": cache}, ids,
+                           mutable=["cache"])
+
+    def temporaries(width):
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(
+            variables["params"], variables["cache"],
+            jax.ShapeDtypeStruct((b, width), jnp.int32, sharding=chip)).compile()
+        assert "qk_norm" in compiled.as_text()
+        return compiled.memory_analysis().temp_size_in_bytes
+
+    mib = 2 ** 20
+    decode, insert = temporaries(1), temporaries(512)
+    print(f"olmoe temporaries: decode {decode / mib:.0f} MiB, insert {insert / mib:.0f} MiB")
+    activations = 64 * b * 512 * 1024 * 2
+    assert decode < 512 * mib
+    assert activations < insert < 2 * activations
+
+
 def test_fused_adamw_leaf(chip):
     leaf = (4096, 11008)
 

@@ -8,6 +8,7 @@ every transpose/reshape/stack and the RoPE/RMSNorm conventions line up, so
 real Llama weights can enter the framework (VERDICT r1 missing #4).
 """
 
+import dataclasses
 import json
 import os
 
@@ -139,9 +140,12 @@ from neuronx_distributed_tpu.converters.hf import (  # noqa: E402
     hf_to_nxd_bert,
     hf_to_nxd_mixtral,
     hf_to_nxd_neox,
+    hf_to_nxd_olmoe,
     nxd_to_hf_bert,
     nxd_to_hf_mixtral,
     nxd_to_hf_neox,
+    nxd_to_hf_olmoe,
+    olmoe_config_from_hf,
 )
 
 MIXTRAL_HC = dict(
@@ -149,6 +153,12 @@ MIXTRAL_HC = dict(
     num_attention_heads=4, num_key_value_heads=2, max_position_embeddings=64,
     rms_norm_eps=1e-5, rope_theta=10000.0, tie_word_embeddings=False,
     num_local_experts=4, num_experts_per_tok=2,
+)
+OLMOE_HC = dict(
+    vocab_size=96, hidden_size=32, intermediate_size=16, num_hidden_layers=2,
+    num_attention_heads=4, num_key_value_heads=4, max_position_embeddings=64,
+    rms_norm_eps=1e-5, rope_theta=10000.0, tie_word_embeddings=False,
+    num_experts=8, num_experts_per_tok=3, norm_topk_prob=False, clip_qkv=None,
 )
 NEOX_HC = dict(
     vocab_size=96, hidden_size=32, intermediate_size=64, num_hidden_layers=2,
@@ -174,6 +184,21 @@ def hf_mixtral():
 
     torch.manual_seed(0)
     m = HFM(HFC(**MIXTRAL_HC, attention_dropout=0.0))
+    m.eval()
+    return m
+
+
+@pytest.fixture(scope="module")
+def hf_olmoe():
+    import torch
+    from transformers import OlmoeConfig as HFC, OlmoeForCausalLM as HFM
+
+    torch.manual_seed(0)
+    m = HFM(HFC(**OLMOE_HC, attention_dropout=0.0))
+    with torch.no_grad():           # norm scales of one would hide a misplaced one
+        for name, p in m.named_parameters():
+            if "norm" in name:
+                p.mul_(1.0 + 0.3 * torch.randn_like(p))
     m.eval()
     return m
 
@@ -234,6 +259,41 @@ def test_mixtral_roundtrip_exact(hf_mixtral):
         if "rotary_emb" in k:
             continue
         np.testing.assert_array_equal(back[k], v, err_msg=k)
+
+
+def _olmoe_cfg(tmp_path):
+    (tmp_path / "config.json").write_text(json.dumps(OLMOE_HC))
+    cfg = olmoe_config_from_hf(str(tmp_path))
+    assert (cfg.num_experts, cfg.top_k, cfg.norm_topk_prob, cfg.qk_norm) == (8, 3, False, True)
+    return dataclasses.replace(cfg, moe_mode="all_experts", use_flash_attention=False,
+                               remat_policy=None, dtype=jnp.float32, param_dtype=jnp.float32)
+
+
+def test_olmoe_logit_parity(hf_olmoe, tmp_path):
+    """The published modelling code itself (transformers ``modeling_olmoe.py``):
+    QK-norm over all heads, top-3 of 8 not renormalised."""
+    import torch
+
+    from neuronx_distributed_tpu.models.olmoe import OlmoeForCausalLM
+
+    cfg = _olmoe_cfg(tmp_path)
+    params = hf_to_nxd_olmoe(_state(hf_olmoe), cfg)
+    ids = np.random.RandomState(0).randint(0, 96, (2, 16))
+    with torch.no_grad():
+        want = hf_olmoe(torch.from_numpy(ids)).logits.numpy()
+    got = np.asarray(
+        OlmoeForCausalLM(cfg).apply({"params": params}, jnp.asarray(ids)))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def test_olmoe_roundtrip_exact(hf_olmoe, tmp_path):
+    cfg = _olmoe_cfg(tmp_path)
+    hf_state = _state(hf_olmoe)
+    assert detect_family(hf_state) == "olmoe" and "olmoe" in FAMILIES
+    back = nxd_to_hf_olmoe(hf_to_nxd_olmoe(hf_state, cfg), cfg)
+    assert set(back) == {k for k in hf_state if "rotary_emb" not in k}
+    for k in back:
+        np.testing.assert_array_equal(back[k], hf_state[k], err_msg=k)
 
 
 def test_neox_logit_parity(hf_neox):

@@ -34,6 +34,7 @@ SCOPES = {
     "inference/causal_lm.py fused_fn": ["sampler", "bookkeeping"],
     "inference/causal_lm.py insert_fn": ["cache_rows", "table_write"],
     "models/llama.py _decode_attention": ["kv_write", "kv_gather", "attend"],
+    "models/llama.py LlamaAttention": ["qk_norm"],
     "trainer/step.py": ["grad_accumulate", "grad_clip", "optimizer_update"],
     "parallel/loss.py": ["loss"],
 }
@@ -92,6 +93,29 @@ def test_fused_decode_names_its_regions(params, kernel):
     assert unnamed_share(parts) < 0.2, parts
 
 
+def test_olmoe_decode_names_qk_norm_router_and_experts():
+    """The tiny OLMoE decode step carries ``qk_norm`` (the one scope PR 26
+    added) and flax's own ``moe/router`` and ``moe/experts``, which
+    ``scope_parts.json`` already has rows for; a dense model's step carries
+    none of the three."""
+    from neuronx_distributed_tpu.models.olmoe import OlmoeConfig, OlmoeForCausalLM
+
+    cfg = OlmoeConfig(**dict(TINY, num_kv_heads=4, intermediate_size=32, num_experts=16,
+                             top_k=4, use_flash_attention=False))
+    olmoe = meta.unbox(OlmoeForCausalLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    lm = CausalLM(cfg, olmoe, OlmoeForCausalLM, buckets=(128,), max_batch=2, page_size=16)
+    components, parts = census(lm.compile_session_decode_fused(4))
+    assert {"qk_norm", "kv_write", "kv_gather", "attend"} <= components
+    assert parts["attention"] > 0 and parts["router"] > 0 and parts["experts"] > 0
+    assert unnamed_share(parts) < 0.2, parts
+
+
+def test_dense_decode_has_no_qk_norm_scope(params):
+    components, parts = census(serving_lm(params).compile_session_decode_fused(4))
+    assert "qk_norm" not in components and parts["router"] == 0 and parts["experts"] == 0
+
+
 def test_paged_insert_names_its_regions(params):
     components, parts = census(serving_lm(params)._paged_insert_programs(2, 128))
     want = (SCOPES["inference/causal_lm.py insert_fn"]
@@ -133,8 +157,11 @@ def test_the_vocabulary_is_the_parts_table():
     """Every scope this test knows is claimed by a row of scope_parts.json
     (not left to ``named_other``), and no two kernels share a name."""
     for where, names in SCOPES.items():
-        under = "layers/block/attention/attention._decode_attention/" if "llama" in where else ""
+        under = ("layers/block/attention/attention._decode_attention/" if "_decode" in where
+                 else "layers/block/attention/" if "llama" in where else "")
         for name in names:
             part = trace_parts.part_of({"tf_op": f"jit(f)/while/body/{under}{name}/add:"}, TABLE)
-            assert part not in ("named_other", "unnamed", "attention"), (name, part)
+            # qk_norm has no row of its own: it is attention's, by flax's module name
+            allowed = ("attention",) if name == "qk_norm" else ()
+            assert part not in {"named_other", "unnamed", "attention"} - set(allowed), (name, part)
     assert len(set(KERNELS)) == len(KERNELS)

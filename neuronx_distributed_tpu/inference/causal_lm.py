@@ -8,7 +8,12 @@ Two compiled programs over ONE weight set (the reference's CTX/TKG split):
 * ``prefill`` per sequence bucket: full-sequence forward writing the KV
   cache, returns all logits;
 * ``decode``: single-token step, cache donated in/out (the reference aliases
-  KV state via metaneff IO aliasing; donation is the PJRT equivalent).
+  KV state via metaneff IO aliasing; donation is the PJRT equivalent). The
+  K/V leaves are ONE buffer each from the donated argument to the result:
+  the fused programs' step loop carries the cache, the model's layer loop
+  carries its K/V leaves (``models/llama.py::KVLayerView``), and every layer
+  writes its rows in place. No program here may slice a layer's pool out of
+  that buffer or copy it (``tests/test_kv_carry_structure.py``).
 
 Continuous batching: the KV cache is a fixed pool of ``max_batch`` slots with
 per-slot lengths (``cache_index`` vector); ``insert`` prefills one or more
@@ -231,7 +236,8 @@ class CausalLM:
         self.config = dataclasses.replace(
             config, decode=True, sequence_parallel=False, remat_policy=None,
         )
-        # paged KV mode: per-layer page pools + block-table sessions
+        # paged KV mode: a page pool per layer (one stacked leaf) + block-table
+        # sessions
         # (inference/paged_cache.py). The pool defaults to slab parity plus
         # the per-slot scratch pages; pass a smaller pool for the HBM win —
         # admission then defers under pool pressure instead of OOMing.
@@ -598,7 +604,9 @@ class CausalLM:
                              eos_token_id: Optional[int] = None,
                              pad_token_id: int = 0):
         """Compile ``steps`` decode iterations as ONE device program
-        (``lax.scan`` over the single-token step, cache donated through).
+        (``lax.scan`` over the single-token step; the donated cache is the
+        scan's carry, and its K/V leaves the carry of the model's layer scan
+        inside it: one buffer, updated in place, argument to result).
 
         Rationale: step decode pays one program dispatch per token; at small
         per-layer cost that fixed dispatch dominates (the ~5 ms/token decode
@@ -694,9 +702,10 @@ class CausalLM:
         :meth:`compile_decode_fused`, with the per-slot serving state carried
         ON-DEVICE so the whole slot pool advances K tokens per dispatch.
 
-        The scan body carries ``(cache, tok, counts, lengths, done)`` and
-        closes over the block-invariant ``slot_keys``/``active``/``eos_ids``/
-        ``temperature``/``greedy`` row arrays (membership and per-request
+        The scan body carries ``(cache, tok, counts, lengths, done)`` (the
+        cache donated and aliased to the result, as in
+        :meth:`compile_decode_fused`) and closes over the block-invariant
+        ``slot_keys``/``active``/``eos_ids``/``temperature``/``greedy`` row arrays (membership and per-request
         samplers change only at block boundaries, where the scheduler passes
         refreshed arrays — they ride the dispatch, costing no extra host op):
 
@@ -1040,8 +1049,9 @@ class CausalLM:
         the rows' block tables (prefix-hit TTFT = suffix prefill only), (b)
         writes the fresh K/V straight into the session's page pool (no
         separate scatter pass — the pool is global, so the prefill IS the
-        scatter), and (c) updates the session-width cache_index/block_table
-        rows at ``slots``."""
+        scatter; the donated pool is the layer scan's carry, so only the
+        rows written move), and (c) updates the session-width
+        cache_index/block_table rows at ``slots``."""
         key = (rows, bucket)
         if key in self._paged_insert:
             return self._paged_insert[key]

@@ -301,6 +301,20 @@ _KV_PAGE_LEAVES = (
     "['cached_key_scale']", "['cached_value_scale']",
 )
 
+
+def _page_payload(data: Dict[str, np.ndarray], path: str):
+    """What a page payload holds for the cache leaf at ``path`` (None:
+    nothing). Payloads are keyed by the leaf's whole tree path, and a parked
+    record or a handoff may come from a build whose K/V leaves sat under
+    another prefix (beside ``cache_index`` until PR 27): there the leaf's own
+    name, the path's last component, finds it."""
+    if path in data:
+        return data[path]
+    name = path[path.rindex("['"):]
+    named = [k for k in data if k.endswith(name)]
+    return data[named[0]] if len(named) == 1 else None
+
+
 _STAT_KEYS = (
     "blocks", "decode_blocks", "inserts", "inserted_requests",
     "program_calls", "host_fetches", "deferred_admissions",
@@ -2294,10 +2308,9 @@ class ServeEngine:
         replaces the session cache between blocks, same discipline as
         ``_set_block_tables``)."""
         def fix(path, leaf):
-            p = jax.tree_util.keystr(path)
-            if p in data:
-                return leaf.at[:, int(page)].set(
-                    jnp.asarray(data[p], leaf.dtype))
+            got = _page_payload(data, jax.tree_util.keystr(path))
+            if got is not None:
+                return leaf.at[:, int(page)].set(jnp.asarray(got, leaf.dtype))
             return leaf
 
         from neuronx_distributed_tpu.inference.partition import repin
@@ -2346,12 +2359,12 @@ class ServeEngine:
 
         def fix(path, leaf):
             p = jax.tree_util.keystr(path)
-            if p in datas[0]:
-                stacked = jnp.stack(
-                    [jnp.asarray(d[p], leaf.dtype) for d in datas]
-                    + [jnp.asarray(datas[-1][p], leaf.dtype)] * pad, axis=1)
-                return leaf.at[:, idx].set(stacked)
-            return leaf
+            rows = [_page_payload(d, p) for d in datas]
+            if rows[0] is None:
+                return leaf
+            return leaf.at[:, idx].set(jnp.stack(
+                [jnp.asarray(r, leaf.dtype) for r in rows + rows[-1:] * pad],
+                axis=1))
 
         from neuronx_distributed_tpu.inference.partition import repin
 

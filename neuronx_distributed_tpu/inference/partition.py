@@ -15,7 +15,7 @@ The sharding story, per collection:
 
 * **KV pools/slabs** (``cached_key``/``cached_value``): the KV-head axis
   (``-2`` in every layout — paged ``(L, npages, ps, n_kv, hd)``, slab
-  ``(L, b, S, n_kv, hd)``, and their per-layer in-model forms) shards
+  ``(L, b, S, n_kv, hd)``, and any reshape that keeps the last two) shards
   over ``tp``, matching the GQA QKV projection's head split. Attention
   gathers index the PAGE axis, so every gather stays local per shard;
   one logical page id maps to one slice per shard and the host-side
@@ -153,10 +153,10 @@ def shard_out(tree: PyTree) -> PyTree:
 
 
 def constrain_named(name: str, x: jax.Array) -> jax.Array:
-    """In-graph pin for ONE named leaf — the per-layer form the model's
-    attention cache writes use (``cached_key``/``cached_value`` without
-    the layer-stack axis; the axis-from-the-right spec rule makes the
-    same derivation apply). No-op off-mesh."""
+    """In-graph pin for ONE named leaf — what the model's attention cache
+    writes use on the ``cached_key``/``cached_value`` stack the layer scan
+    carries (the axis-from-the-right spec rule makes the derivation the
+    same whatever leads the KV-head axis). No-op off-mesh."""
     from neuronx_distributed_tpu.parallel import mesh as ps
 
     if not ps.model_parallel_is_initialized():

@@ -100,7 +100,8 @@ class LlamaConfig:
     # CE run per chunk of this many tokens when seq exceeds it (None = 4096)
     loss_chunk_size: Optional[int] = None
     # paged KV cache (serving, decode=True only): per-layer page pool of
-    # ``page_pool_pages`` pages x ``page_size`` tokens; slot positions
+    # ``page_pool_pages`` pages x ``page_size`` tokens (all layers' pools are
+    # one stacked leaf that the layer scan carries: KVLayerView); slot positions
     # resolve through per-slot block tables that RIDE THE CACHE COLLECTION,
     # so compiled programs keep their signatures (inference/paged_cache.py).
     # None = the contiguous max_batch x max_seq_len slab. page_size must
@@ -343,14 +344,85 @@ def _lora_pool_delta(mdl: nn.Module, cfg: LlamaConfig, name: str,
     return d * s[idx][:, None, None]
 
 
+def kv_leaf_shapes(cfg: LlamaConfig, batch: int) -> dict:
+    """``{leaf name: (shape, dtype)}`` of ONE layer's KV storage in the
+    ``cache`` collection: the paged pool ``(page_pool_pages, page_size, n_kv,
+    hd)`` (int8 pages bring their per-(page, kv-head) fp32 scales as sibling
+    leaves, n_kv at axis -2 like the pools, so partition specs, page-IO
+    framing, handoff CRCs and donation cover them without special cases;
+    all-zero scales dequantize unwritten pages to exact zeros), or the
+    contiguous ``(batch, max_seq_len, n_kv, hd)`` slab. ``LlamaModel``
+    declares each leaf once, stacked ``(num_layers, *shape)``."""
+    n_kv = cfg.num_kv_heads * cfg.kv_size_multiplier
+    hd = cfg.head_dim_
+    if not cfg.page_size:
+        slab = ((batch, cfg.max_seq_len, n_kv, hd), cfg.dtype)
+        return {"cached_key": slab, "cached_value": slab}
+    npages = cfg.page_pool_pages
+    if cfg.page_dtype == "int8":
+        pool = ((npages, cfg.page_size, n_kv, hd), jnp.int8)
+        scale = ((npages, 1, n_kv, 1), jnp.float32)
+        return {"cached_key": pool, "cached_value": pool,
+                "cached_key_scale": scale, "cached_value_scale": scale}
+    pool = ((npages, cfg.page_size, n_kv, hd),
+            jnp.dtype(cfg.page_dtype or cfg.dtype))
+    return {"cached_key": pool, "cached_value": pool}
+
+
+class KVLayerView:
+    """One layer's window on the KV leaves, and the ONE place that says how
+    the cache is threaded through the layer loop.
+
+    The leaves are stacked ``(L, rows, ...)`` (rows: pages of the pool, batch
+    rows of the slab) and are the layer scan's CARRY, not its scanned input
+    and output: no layer ever holds a pool of its own, each writes its few
+    rows of the one buffer in place and reads through the block table. A
+    carry is also what the fused decode's step loop holds, so the argument
+    the program was given (donated) is the buffer both loops update and the
+    result it returns. Scanned (``variable_axes``), every layer would slice
+    its pool out of the stack and write all of it back, and the step loop
+    would copy the stack between the scan's input and output: time that
+    follows the pages held, not the tokens live (PERF.md, PR 27).
+
+    ``flat(name)`` is the leaf as ``(L * rows, ...)``, a free reshape in
+    which this layer's rows start at ``first_row(rows)``: adding that to a
+    block table's page ids (or to the slab's row ids) is all a layer does
+    differently from owning its pool, and a write to be dropped goes past
+    the end of the WHOLE stack, not of the layer's share (which is the next
+    layer's first page)."""
+
+    def __init__(self, layer: jax.Array, leaves: dict):
+        self.layer = layer        # () int32: index of this layer in the stack
+        self.leaves = dict(leaves)
+
+    def first_row(self, rows: int) -> jax.Array:
+        return self.layer * rows
+
+    def flat(self, name: str) -> jax.Array:
+        leaf = self.leaves[name]
+        return leaf.reshape(leaf.shape[0] * leaf.shape[1], *leaf.shape[2:])
+
+    def put(self, name: str, value: jax.Array) -> None:
+        """Store the updated leaf (any reshape of it), pinned to its serving
+        spec (n_kv over 'tp' under a mesh, no-op otherwise): row-axis
+        scatters and gathers never cross the head shard, so the whole hot
+        path stays local to a shard (inference/partition.py)."""
+        from neuronx_distributed_tpu.inference.partition import constrain_named
+
+        self.leaves[name] = constrain_named(
+            name, value.reshape(self.leaves[name].shape))
+
+
 class LlamaAttention(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope, chunk_ctx=None) -> jax.Array:
+    def __call__(self, x: jax.Array, rope, chunk_ctx=None,
+                 kv: Optional[KVLayerView] = None) -> jax.Array:
         """``chunk_ctx`` (decode only): ``(chunk_mask (s,s) bool,
         chunk_positions (s,) int32)`` for Medusa tree steps — intra-chunk
-        visibility by tree ancestry and RoPE positions by tree depth."""
+        visibility by tree ancestry and RoPE positions by tree depth.
+        ``kv`` (decode only): this layer's view of the carried KV leaves."""
         cfg = self.config
         hd = cfg.head_dim_
         q, k, v = GQAQKVColumnParallelLinear(
@@ -391,7 +463,7 @@ class LlamaAttention(nn.Module):
             k = jnp.clip(k, -cfg.qkv_clip, cfg.qkv_clip)
             v = jnp.clip(v, -cfg.qkv_clip, cfg.qkv_clip)
         if cfg.decode:
-            return self._decode_attention(x, q, k, v, chunk_ctx, aidx)
+            return self._decode_attention(x, q, k, v, kv, chunk_ctx, aidx)
         cos, sin = rope  # computed once in LlamaModel, broadcast through scan
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
@@ -452,50 +524,38 @@ class LlamaAttention(nn.Module):
                                      aidx).astype(y.dtype)
         return y
 
-    def _decode_attention(self, x, q, k, v, chunk_ctx=None, aidx=None):
+    def _decode_attention(self, x, q, k, v, kv, chunk_ctx=None, aidx=None):
         """KV-cached path (flax ``cache`` collection; the reference keeps KV
         state in aliased runtime buffers, model_base.py KV management —
-        donation of the cache collection is the TPU analogue)."""
+        donation of the cache collection is the TPU analogue). The K/V
+        leaves themselves come through ``kv`` (:class:`KVLayerView`): the
+        stack of every layer's, carried by the layer loop."""
         cfg = self.config
         b = x.shape[0]
         s_new = x.shape[1]
         n_kv = k.shape[2]
         hd = cfg.head_dim_
         ps = cfg.page_size
+        if kv is None:
+            raise ValueError(
+                "decode-mode attention reads and writes the KV leaves that "
+                "LlamaModel declares and its layer scan carries; apply it "
+                "through LlamaModel (or pass a KVLayerView)")
         if ps:
-            # paged KV (PagedAttention layout, TPU-shaped): the layer owns a
-            # page POOL instead of a per-slot slab; per-slot block tables are
-            # a cache-collection leaf, so the host swaps them between blocks
+            # paged KV (PagedAttention layout, TPU-shaped): a page POOL
+            # instead of a per-slot slab; per-slot block tables are a
+            # cache-collection leaf, so the host swaps them between blocks
             # without touching any program signature and the K-step session
             # scan carries them as loop-invariant state (in-scan gather).
-            npages = cfg.page_pool_pages
+            # ``npages`` counts the pages of the whole stack and the table
+            # below holds ids in it: from here on the code reads as if the
+            # layer owned one pool of that size.
+            npages = kv.flat("cached_key").shape[0]
             ppseq = cfg.max_seq_len // ps
             quantized = cfg.page_dtype == "int8"
-            pool_dtype = (jnp.int8 if quantized
-                          else jnp.dtype(cfg.page_dtype or cfg.dtype))
-            ck = self.variable("cache", "cached_key",
-                               jnp.zeros, (npages, ps, n_kv, hd), pool_dtype)
-            cv = self.variable("cache", "cached_value",
-                               jnp.zeros, (npages, ps, n_kv, hd), pool_dtype)
             bt = self.variable("cache", "block_table",
                                lambda: jnp.zeros((b, ppseq), jnp.int32))
-            cks = cvs = None
-            if quantized:
-                # per-(page, kv-head) fp32 absmax scales as SIBLING pool
-                # leaves: n_kv at axis -2 like the pools themselves, so
-                # the whole cache-collection plumbing (partition specs,
-                # page-IO framing, handoff CRCs, donation) extends to
-                # them without special cases. All-zero init dequantizes
-                # unwritten pages to exact zeros, same as the fp pool.
-                cks = self.variable("cache", "cached_key_scale", jnp.zeros,
-                                    (npages, 1, n_kv, 1), jnp.float32)
-                cvs = self.variable("cache", "cached_value_scale", jnp.zeros,
-                                    (npages, 1, n_kv, 1), jnp.float32)
-        else:
-            ck = self.variable("cache", "cached_key",
-                               jnp.zeros, (b, cfg.max_seq_len, n_kv, hd), cfg.dtype)
-            cv = self.variable("cache", "cached_value",
-                               jnp.zeros, (b, cfg.max_seq_len, n_kv, hd), cfg.dtype)
+            table = bt.value + kv.first_row(cfg.page_pool_pages)   # (b, ppseq)
         # per-slot lengths: continuous batching reorders/restarts slots
         # independently (reference model_wrapper.py:207 seq_ids machinery)
         ci = self.variable("cache", "cache_index",
@@ -537,11 +597,6 @@ class LlamaAttention(nn.Module):
                 # Writes at slots >= max_seq_len are DROPPED, matching the slab
                 # path's out-of-bounds scatter (the overflow latch freezes a row
                 # instead of letting its writes wrap onto a neighbour).
-                from neuronx_distributed_tpu.inference.partition import (
-                    constrain_named,
-                )
-
-                table = bt.value                                       # (b, ppseq)
                 if quantized:
                     # int8 pages: dequant-modify-requant over the W-page
                     # window this step touches (the narrowest logical span
@@ -559,8 +614,10 @@ class LlamaAttention(nn.Module):
 
                     phys_w = jnp.take_along_axis(
                         table, jnp.clip(lpage, 0, ppseq - 1), axis=1)  # (b, W)
-                    kw = dequantize_kv_pages(ck.value[phys_w], cks.value[phys_w])
-                    vw = dequantize_kv_pages(cv.value[phys_w], cvs.value[phys_w])
+                    kw = dequantize_kv_pages(kv.flat("cached_key")[phys_w],
+                                             kv.flat("cached_key_scale")[phys_w])
+                    vw = dequantize_kv_pages(kv.flat("cached_value")[phys_w],
+                                             kv.flat("cached_value_scale")[phys_w])
                     kw = kw.reshape(b, W * ps, n_kv, hd)
                     vw = vw.reshape(b, W * ps, n_kv, hd)
                     # window-relative slots; >= max_seq_len drops like the fp
@@ -587,33 +644,19 @@ class LlamaAttention(nn.Module):
                     last = jnp.minimum(idx + s_new - 1, cfg.max_seq_len - 1) // ps
                     touched = (lpage <= last[:, None]) & (lpage < ppseq)
                     dest = jnp.where(touched, phys_w, npages)          # (b, W)
-                    ck.value = constrain_named(
-                        "cached_key", ck.value.at[dest].set(kq, mode="drop"))
-                    cv.value = constrain_named(
-                        "cached_value", cv.value.at[dest].set(vq, mode="drop"))
-                    cks.value = constrain_named(
-                        "cached_key_scale",
-                        cks.value.at[dest].set(k_sc, mode="drop"))
-                    cvs.value = constrain_named(
-                        "cached_value_scale",
-                        cvs.value.at[dest].set(v_sc, mode="drop"))
+                    for name, upd in (("cached_key", kq), ("cached_value", vq),
+                                      ("cached_key_scale", k_sc),
+                                      ("cached_value_scale", v_sc)):
+                        kv.put(name, kv.flat(name).at[dest].set(upd, mode="drop"))
                 else:
                     page_of = jnp.clip(slots // ps, 0, ppseq - 1)
                     phys = jnp.take_along_axis(table, page_of, axis=1)  # (b, s_new)
                     flat = jnp.where(slots < cfg.max_seq_len,
                                      phys * ps + slots % ps, npages * ps)
-                    kf = ck.value.reshape(npages * ps, n_kv, hd)
-                    vf = cv.value.reshape(npages * ps, n_kv, hd)
-                    kf = kf.at[flat].set(k.astype(kf.dtype), mode="drop")
-                    vf = vf.at[flat].set(v.astype(vf.dtype), mode="drop")
-                    # pin the pool's serving spec at the write (n_kv over 'tp'
-                    # under a mesh, no-op otherwise): page-axis scatters/gathers
-                    # never cross the head shard, so the whole paged hot path
-                    # stays local per shard (inference/partition.py)
-                    ck.value = constrain_named(
-                        "cached_key", kf.reshape(npages, ps, n_kv, hd))
-                    cv.value = constrain_named(
-                        "cached_value", vf.reshape(npages, ps, n_kv, hd))
+                    for name, upd in (("cached_key", k), ("cached_value", v)):
+                        by_slot = kv.flat(name).reshape(npages * ps, n_kv, hd)
+                        kv.put(name, by_slot.at[flat].set(
+                            upd.astype(by_slot.dtype), mode="drop"))
                 k_all = v_all = None  # gather deferred: the kernel may skip it
             else:
                 # mode="drop" pins the out-of-bounds semantics the overflow
@@ -621,19 +664,14 @@ class LlamaAttention(nn.Module):
                 # pad tail runs past max_seq_len must discard those writes, not
                 # clamp them onto the last slot) — this is jax's default for
                 # scatters, made explicit so the contract can't drift
-                from neuronx_distributed_tpu.inference.partition import (
-                    constrain_named,
-                )
-
-                # same serving-spec pin as the paged pool: the slab's n_kv
-                # axis shards over 'tp' and the row scatter is shard-local
-                ck.value = constrain_named(
-                    "cached_key", ck.value.at[rows, slots].set(
-                        k.astype(ck.value.dtype), mode="drop"))
-                cv.value = constrain_named(
-                    "cached_value", cv.value.at[rows, slots].set(
-                        v.astype(cv.value.dtype), mode="drop"))
-                k_all, v_all = ck.value, cv.value
+                first = kv.first_row(b)
+                for name, upd in (("cached_key", k), ("cached_value", v)):
+                    slab = kv.flat(name)            # (L * b, S, n_kv, hd)
+                    kv.put(name, slab.at[first + rows, slots].set(
+                        upd.astype(slab.dtype), mode="drop"))
+                k_all, v_all = (
+                    jax.lax.dynamic_slice_in_dim(kv.flat(name), first, b)
+                    for name in ("cached_key", "cached_value"))
             ci.value = idx + s_new
         if ps:
             from neuronx_distributed_tpu.inference.paged_kernel import (
@@ -651,9 +689,10 @@ class LlamaAttention(nn.Module):
                 # stays as the bit-exactness reference oracle.
                 with jax.named_scope("attend"):
                     o = paged_decode_attention(
-                        q, ck.value, cv.value, table, idx,
-                        k_scale=cks.value if quantized else None,
-                        v_scale=cvs.value if quantized else None)
+                        q, kv.flat("cached_key"), kv.flat("cached_value"),
+                        table, idx,
+                        k_scale=kv.flat("cached_key_scale") if quantized else None,
+                        v_scale=kv.flat("cached_value_scale") if quantized else None)
                 return self._o_proj(o.reshape(b, s_new, -1), aidx)
             # in-scan gather: the (b, max_seq_len) logical view the
             # attention below consumes. Stale bytes in reused pages sit
@@ -662,20 +701,19 @@ class LlamaAttention(nn.Module):
             # attention over the view is bit-identical to the contiguous
             # path.
             with jax.named_scope("kv_gather"):
-                lpos = jnp.arange(cfg.max_seq_len)
-                pg = table[:, lpos // ps]                         # (b, S)
-                all_flat = pg * ps + (lpos % ps)[None, :]
-                kf = ck.value.reshape(npages * ps, n_kv, hd)
-                vf = cv.value.reshape(npages * ps, n_kv, hd)
-                k_all, v_all = kf[all_flat], vf[all_flat]
+                # by whole pages, one (page, n_kv, hd) run of the buffer per
+                # table entry: the same rows a gather by slot would bring
+                k_all, v_all = (kv.flat(name)[table]          # (b, ppseq, ps, ..)
+                                for name in ("cached_key", "cached_value"))
                 if quantized:
-                    # dequantize the logical view with each slot's page scale
-                    ks2 = cks.value.reshape(npages, n_kv)[pg]     # (b, S, n_kv)
-                    vs2 = cvs.value.reshape(npages, n_kv)[pg]
-                    k_all = (k_all.astype(jnp.float32)
-                             * ks2[..., None]).astype(cfg.dtype)
-                    v_all = (v_all.astype(jnp.float32)
-                             * vs2[..., None]).astype(cfg.dtype)
+                    # dequantize the logical view with each page's scale
+                    k_all, v_all = (
+                        (pages.astype(jnp.float32)
+                         * kv.flat(name)[table]).astype(cfg.dtype)
+                        for pages, name in ((k_all, "cached_key_scale"),
+                                            (v_all, "cached_value_scale")))
+                k_all, v_all = (pages.reshape(b, cfg.max_seq_len, n_kv, hd)
+                                for pages in (k_all, v_all))
         if chunk_mask is not None:
             # prefix slots (< idx) fully visible; chunk slots by tree mask
             with jax.named_scope("attend"):
@@ -768,10 +806,10 @@ class LlamaDecoderLayer(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope, chunk_ctx=None) -> jax.Array:
+    def __call__(self, x: jax.Array, rope, chunk_ctx=None, kv=None) -> jax.Array:
         cfg = self.config
         h = cfg.make_norm(name="input_norm")(x)
-        x = x + LlamaAttention(cfg, name="attention")(h, rope, chunk_ctx)
+        x = x + LlamaAttention(cfg, name="attention")(h, rope, chunk_ctx, kv)
         h = cfg.make_norm(name="post_attn_norm")(x)
         return x + LlamaMLP(cfg, name="mlp")(h)
 
@@ -791,21 +829,31 @@ def _remat_policy(name: Optional[str]):
 class _LayerStep(nn.Module):
     """Scan body: one (optionally remat-wrapped) decoder layer returning the
     ``(carry, ys)`` pair ``nn.scan`` expects. ``layer_cls`` parameterizes the
-    decoder block so variants (Mixtral's MoE layer) reuse the whole stack."""
+    decoder block so variants (Mixtral's MoE layer) reuse the whole stack.
+    The carry is ``(x, kv)``: ``kv`` is None outside decode mode (nothing
+    more is carried than the hidden states), else ``(layer index, KV
+    leaves)``, which the block's attention updates through a
+    :class:`KVLayerView`."""
 
     config: LlamaConfig
     layer_cls: Any = None  # default LlamaDecoderLayer (set below)
 
     @nn.compact
-    def __call__(self, x, rope, chunk_ctx=None):
+    def __call__(self, carry, rope, chunk_ctx=None):
         cfg = self.config
+        x, kv = carry
         cls = self.layer_cls or LlamaDecoderLayer
         policy = _remat_policy(cfg.remat_policy)
-        if policy is not None:
+        if policy is not None and kv is None:  # nothing differentiates a decode
             cls = nn.remat(cls, policy=policy, prevent_cse=False)
-        if chunk_ctx is None:  # 2-arg layer variants (Mixtral) stay compatible
-            return cls(cfg, name="block")(x, rope), None
-        return cls(cfg, name="block")(x, rope, chunk_ctx), None
+        block = cls(cfg, name="block")
+        # 2-arg layer variants (Mixtral) stay compatible
+        args = (x, rope) if chunk_ctx is None else (x, rope, chunk_ctx)
+        if kv is None:
+            return (block(*args), None), None
+        layer, leaves = kv
+        view = KVLayerView(layer, leaves)
+        return (block(*args, kv=view), (layer + 1, view.leaves)), None
 
 
 class LlamaModel(nn.Module):
@@ -825,7 +873,10 @@ class LlamaModel(nn.Module):
         # (unsharded) layer axis. "losses" carries per-layer sown aux losses
         # (MoE variants), "adapters" the per-layer LoRA pool stacks (multi-
         # LoRA serving), "moe_stats" the per-layer routing choices of a decode
-        # step; unused collections in variable_axes are harmless.
+        # step; unused collections in variable_axes are harmless. Of the
+        # "cache" only the small per-layer leaves (cache_index, block_table)
+        # are scanned: the K/V leaves are declared in __call__, above the
+        # scan, and ride its carry (KVLayerView).
         self.layers = nn.scan(
             _LayerStep,
             variable_axes={"params": 0, "cache": 0, "losses": 0,
@@ -837,6 +888,7 @@ class LlamaModel(nn.Module):
         )(cfg, self.layer_cls)
         self.final_norm = cfg.make_norm()
 
+    @nn.compact
     def __call__(self, input_ids: jax.Array, chunk_ctx=None) -> jax.Array:
         cfg = self.config
         if input_ids.shape[1] > cfg.max_seq_len:
@@ -866,10 +918,20 @@ class LlamaModel(nn.Module):
             x = constrain(x, ACT_CP)  # seq stays cp-sharded through the stack
         else:
             x = constrain(x, ACT_SP if cfg.sequence_parallel else ACT_FULL)
-        if chunk_ctx is None:
-            x, _ = self.layers(x, rope)
-        else:
-            x, _ = self.layers(x, rope, chunk_ctx)
+        kv, pools = None, {}
+        if cfg.decode:
+            # one buffer per K/V leaf for the whole stack, in and out of the
+            # layer loop as its carry: written in place at [layer, row]
+            pools = {
+                name: self.variable("cache", name, jnp.zeros,
+                                    (cfg.num_layers, *shape), dtype)
+                for name, (shape, dtype) in kv_leaf_shapes(
+                    cfg, input_ids.shape[0]).items()}
+            kv = (jnp.int32(0), {n: p.value for n, p in pools.items()})
+        args = (rope,) if chunk_ctx is None else (rope, chunk_ctx)
+        (x, kv), _ = self.layers((x, kv), *args)
+        for name, pool in pools.items():
+            pool.value = kv[1][name]
         return self.final_norm(x)
 
     def attend(self, x: jax.Array) -> jax.Array:

@@ -80,10 +80,10 @@ class MixtralDecoderLayer(nn.Module):
     config: MixtralConfig
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope) -> jax.Array:
+    def __call__(self, x: jax.Array, rope, kv=None) -> jax.Array:
         cfg = self.config
         h = cfg.make_norm(name="input_norm")(x)
-        x = x + LlamaAttention(cfg, name="attention")(h, rope)
+        x = x + LlamaAttention(cfg, name="attention")(h, rope, kv=kv)
         h = cfg.make_norm(name="post_attn_norm")(x)
         moe_out = MoE(
             num_experts=cfg.num_experts,

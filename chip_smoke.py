@@ -222,7 +222,7 @@ def kernel_variant(lm, cfg, sizes: Sizes, page_dtype):
 
 
 def make_trace(cfg, seed: int, n: int = NUM_REQUESTS):
-    from neuronx_distributed_tpu.inference.engine import synthetic_trace
+    from neuronx_distributed_tpu.inference.replay import synthetic_trace
 
     return synthetic_trace(n, cfg.vocab_size, prompt_lens=PROMPT_LENS,
                            max_new_tokens=NEW_TOKENS, seed=seed)
@@ -233,7 +233,7 @@ def serve(lm, trace, seed: int, fused: bool = True):
     import jax
 
     from neuronx_distributed_tpu.inference import ServeEngine
-    from neuronx_distributed_tpu.inference.engine import run_trace
+    from neuronx_distributed_tpu.inference.replay import run_trace
 
     eng = ServeEngine(lm, block_steps=BLOCK_STEPS, fused=fused,
                       rng=jax.random.key(seed))

@@ -1,4 +1,4 @@
-"""Llama inference runner + latency benchmark (BASELINE config #5).
+"""Llama inference runner + latency benchmark.
 
 TPU-native counterpart of the reference's ``examples/inference/runner.py``
 (649 LoC — trace / load-traced / generate / benchmark / check-accuracy) and
@@ -483,14 +483,15 @@ def cmd_serve(args) -> None:
     fused path is measured against (token streams are bit-identical)."""
     import os
 
-    from neuronx_distributed_tpu.inference.engine import (
-        ServeEngine, run_trace, synthetic_trace,
+    from neuronx_distributed_tpu.inference.engine import ServeEngine
+    from neuronx_distributed_tpu.inference.replay import (
+        run_router_trace,
+        run_trace,
+        synthetic_trace,
     )
     from neuronx_distributed_tpu.inference.faults import resolve_fault_plan
 
-    from neuronx_distributed_tpu.inference.router import (
-        Router, run_router_trace,
-    )
+    from neuronx_distributed_tpu.inference.router import Router
 
     # TP-sharded serving (serve --tp N): the mesh is built by build_model;
     # gate the divisibility constraints HERE, before any compile — a head
@@ -684,8 +685,9 @@ def cmd_serve(args) -> None:
         crash_at = ([(args.crash_replica_at, args.replicas - 1)]
                     if args.crash_replica_at is not None else ())
         if args.disagg:
-            from neuronx_distributed_tpu.inference.disagg import (
-                DisaggRouter, run_disagg_trace,
+            from neuronx_distributed_tpu.inference.disagg import DisaggRouter
+            from neuronx_distributed_tpu.inference.replay import (
+                run_disagg_trace,
             )
 
             if not lm.paged:

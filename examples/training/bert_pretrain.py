@@ -1,4 +1,4 @@
-"""BERT-large TP+DP MLM/NSP pretraining (BASELINE config #2).
+"""BERT-large TP+DP MLM/NSP pretraining.
 
 TPU-native counterpart of the reference's
 ``examples/training/tp_dp_bert_large_hf_pretrain_hdf5.py`` (846 LoC): the

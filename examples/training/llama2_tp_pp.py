@@ -1,4 +1,4 @@
-"""Llama-2 70B TP×PP pretraining (BASELINE config #4).
+"""Llama-2 70B TP×PP pretraining.
 
 TPU-native counterpart of the reference's
 ``examples/training/llama/tp_pp_llama_hf_pretrain/run_llama2_70B_tp_pp.sh``

@@ -1,4 +1,4 @@
-"""Llama-2 7B TP+ZeRO-1+SP pretraining (BASELINE config #3).
+"""Llama-2 7B TP+ZeRO-1+SP pretraining.
 
 TPU-native counterpart of the reference's
 ``examples/training/llama/tp_zero1_llama_hf_pretrain`` scripts

@@ -12,9 +12,8 @@ nearly broken) at runtime first:
   seams (the PR 5 storm page-leak and PR 10/13 unpin-seam classes);
 * wall-clock / unseeded-rng / bare-set-iteration in scheduling decisions
   (the virtual-block-clock replay guarantees);
-* drift between the bench headline surface, the regression-gate rule
-  table, the committed artifacts, the fault plan and the observability
-  names tests assert on.
+* drift between the fault plan, the observability names and the tests
+  that assert on them.
 
 The engine is deliberately boring: each rule is a callable over a
 :class:`RepoCtx` yielding :class:`Finding`\\ s; waivers are explicit and
@@ -31,7 +30,7 @@ In-file (preferred — the justification lives next to the code):
     something_flagged()  # nxdcheck: waive <rule-id> -- <justification>
 
 or on the line directly above the finding. Repo-level (for findings
-whose justification spans files, e.g. surface-drift basis exemptions):
+whose justification spans files):
 ``neuronx_distributed_tpu/analysis/waivers.txt`` lines of the form
 
     <rule-id> <relpath> <qualname-or-*> -- <justification>
@@ -154,9 +153,9 @@ def parse_inline_waivers(source: str) -> Dict[int, Tuple[set, str]]:
 
 
 class RepoCtx:
-    """Lazy repo view the rules share: parsed package files plus ast/json
-    access to repo-level surfaces (bench.py, scripts/, tests/, committed
-    artifacts). Built once per run; building it is the dominant cost."""
+    """Lazy repo view the rules share: parsed package files plus ast
+    access to repo-level surfaces (scripts/, tests/). Built once per run;
+    building it is the dominant cost."""
 
     def __init__(self, root: Path, package: str = "neuronx_distributed_tpu"):
         self.root = Path(root)

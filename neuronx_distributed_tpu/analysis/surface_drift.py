@@ -1,33 +1,13 @@
-"""Rule ``surface-drift``: the string registries that tie bench, gate,
-artifacts, fault plans and observability together must stay reconciled.
+"""Rule ``surface-drift``: the string registries that tie fault plans,
+observability and the tests that assert on them must stay reconciled.
 
-These surfaces only work as a system: a HEADLINE key gates regressions
-only if ``scripts/bench_regress.py`` knows its direction AND a committed
-baseline actually carries it; a ``FaultPlan`` probability field is chaos
-coverage only if an injector reads it and a test drives it; a stats/lane
-name a test asserts on is a guarantee only while a producer still emits
-it (the registry-backed stats view defaults to 0, so producer renames
-fail SILENTLY — the assert keeps passing on a dead counter). Each
+These surfaces only work as a system: a ``FaultPlan`` probability field is
+chaos coverage only if an injector reads it and a test drives it; a
+stats/lane name a test asserts on is a guarantee only while a producer
+still emits it (the registry-backed stats view defaults to 0, so producer
+renames fail SILENTLY — the assert keeps passing on a dead counter). Each
 sub-check below is one edge of that graph:
 
-* ``headline-rule``: every gating HEADLINE_KEYS entry full-matches a
-  bench_regress RULES pattern (else it lands verdict "info" and never
-  gates, in either direction). Non-numeric sentinels (``*_error``,
-  ``*_basis``, ``metric``, ``train_measured``) are exempt.
-* ``headline-artifact``: the newest committed ``BENCH_r0*.json`` embeds
-  ``headline_keys`` identical to bench.py's, and every SERVING-basis
-  headline key (``serve_* / router_* / soak_* / paged_* / adapter_* /
-  grammar_* / tier_*`` — the bench_cpu_basis coverage) is present in its
-  parsed report: a serving key absent from every committed baseline
-  compares as ``new_key`` forever and is effectively ungated.
-* ``headline-producer``: every SERVING-basis headline key is actually
-  PRODUCED by bench.py — a literal ``out["key"] = ...`` store (or an
-  f-string store whose literal head prefixes the key) somewhere outside
-  the HEADLINE_KEYS declaration itself. A key that is declared and
-  carried by the baseline but that no section writes anymore gates
-  forever on a fossilized number (the regress compare sees
-  old-vs-missing as ``removed_key``, but only after the NEXT refresh —
-  this catches the rename at the commit that makes it).
 * ``faultplan``: every ``FaultPlan`` ``*_prob`` field is referenced by
   an injector call site in the package (outside faults.py) and
   mentioned in at least one test.
@@ -39,16 +19,10 @@ sub-check below is one edge of that graph:
 from __future__ import annotations
 
 import ast
-import json
-import re
-from pathlib import Path
-from typing import Dict, Iterator, List, Optional, Set, Tuple
+from typing import Iterator, List, Optional, Set, Tuple
 
 from .core import Finding, RepoCtx, Rule
 
-NONNUMERIC_KEY = re.compile(r"(_error|_basis)$|^(metric|train_measured)$")
-SERVING_KEY = re.compile(
-    r"^(serve_|router_|soak_|paged_|adapter_|grammar_|tier_)")
 TRACER_METHODS = {"instant", "span", "counter"}
 
 
@@ -68,111 +42,6 @@ def _literal_assign(tree: ast.AST, name: str) -> Optional[object]:
                 except ValueError:
                     return None
     return None
-
-
-def _newest_artifact(root: Path) -> Optional[Tuple[Path, dict]]:
-    best: Optional[Tuple[int, Path, dict]] = None
-    for p in sorted(root.glob("BENCH_r*.json")):
-        try:
-            doc = json.loads(p.read_text())
-        except (OSError, json.JSONDecodeError):
-            continue
-        parsed = doc.get("parsed")
-        if not isinstance(parsed, dict) or "headline_keys" not in parsed:
-            continue
-        n = doc.get("n", 0)
-        if best is None or n > best[0]:
-            best = (n, p, parsed)
-    if best is None:
-        return None
-    return best[1], best[2]
-
-
-def _check_bench_surface(ctx: RepoCtx) -> Iterator[Finding]:
-    bench = ctx.maybe_file("bench.py")
-    regress = ctx.maybe_file("scripts/bench_regress.py")
-    if bench is None or regress is None:
-        return
-    headline = _literal_assign(bench.tree, "HEADLINE_KEYS")
-    rules = _literal_assign(regress.tree, "RULES")
-    if headline is None:
-        yield Finding("surface-drift", bench.rel, 1, "<module>",
-                      "HEADLINE_KEYS is not a literal tuple/list "
-                      "(bench_regress ast-parses it — keep it literal)")
-        return
-    if rules is None:
-        yield Finding("surface-drift", regress.rel, 1, "<module>",
-                      "RULES is not a literal list (direction table must "
-                      "stay statically auditable)")
-        return
-    pats = [str(r[0]) for r in rules]
-    for key in headline:
-        key = str(key)
-        if NONNUMERIC_KEY.search(key):
-            continue
-        if not any(re.fullmatch(p, key) for p in pats):
-            yield Finding(
-                "surface-drift", bench.rel, 1, "HEADLINE_KEYS",
-                f"headline key '{key}' matches no bench_regress RULES "
-                f"pattern — it reports as 'info' and never gates")
-    # headline-producer: a serving headline key must have a producing
-    # store in bench.py. HEADLINE_KEYS itself is a tuple of constants —
-    # never a Subscript store — so the declaration can't self-satisfy.
-    produced: Set[str] = set()
-    produced_prefixes: List[str] = []
-    for node in ast.walk(bench.tree):
-        if (isinstance(node, ast.Subscript)
-                and isinstance(node.ctx, ast.Store)):
-            if (isinstance(node.slice, ast.Constant)
-                    and isinstance(node.slice.value, str)):
-                produced.add(node.slice.value)
-            elif (isinstance(node.slice, ast.JoinedStr)
-                    and node.slice.values):
-                head = node.slice.values[0]
-                if (isinstance(head, ast.Constant)
-                        and isinstance(head.value, str) and head.value):
-                    produced_prefixes.append(head.value)
-    for key in headline:
-        key = str(key)
-        if NONNUMERIC_KEY.search(key) or not SERVING_KEY.match(key):
-            continue
-        if key in produced:
-            continue
-        if any(key.startswith(p) for p in produced_prefixes):
-            continue
-        yield Finding(
-            "surface-drift", bench.rel, 1, "HEADLINE_KEYS",
-            f"serving headline key '{key}' has no producing store in "
-            f"bench.py (no literal out['{key}'] = ... outside the "
-            f"HEADLINE_KEYS declaration) — it gates forever on the "
-            f"baseline's fossilized value")
-    art = _newest_artifact(ctx.root)
-    if art is None:
-        return
-    apath, parsed = art
-    rel = apath.name
-    embedded = {str(k) for k in parsed.get("headline_keys", [])}
-    current = {str(k) for k in headline}
-    for k in sorted(embedded - current):
-        yield Finding(
-            "surface-drift", rel, 0, "headline_keys",
-            f"committed artifact {rel} gates on '{k}' which bench.py no "
-            f"longer declares (retired key lingering in the baseline)")
-    for k in sorted(current - embedded):
-        yield Finding(
-            "surface-drift", rel, 0, "headline_keys",
-            f"headline key '{k}' missing from {rel}'s embedded "
-            f"headline_keys — regenerate the baseline")
-    for k in sorted(current):
-        if NONNUMERIC_KEY.search(k) or not SERVING_KEY.match(k):
-            continue
-        if k not in parsed:
-            yield Finding(
-                "surface-drift", rel, 0, "parsed",
-                f"serving headline key '{k}' absent from the newest "
-                f"committed baseline {rel} — it compares as new_key "
-                f"forever and is effectively ungated (refresh via "
-                f"scripts/bench_cpu_basis.py)")
 
 
 def _check_faultplan(ctx: RepoCtx) -> Iterator[Finding]:
@@ -317,14 +186,13 @@ def _check_observability_names(ctx: RepoCtx) -> Iterator[Finding]:
 
 
 def check(ctx: RepoCtx) -> Iterator[Finding]:
-    yield from _check_bench_surface(ctx)
     yield from _check_faultplan(ctx)
     yield from _check_observability_names(ctx)
 
 
 RULE = Rule(
     id="surface-drift",
-    doc="HEADLINE_KEYS / bench_regress rules / committed artifacts / "
-        "FaultPlan fields / observability names stay cross-consistent",
+    doc="FaultPlan fields and observability names stay consistent with "
+        "the tests that assert on them",
     check=check,
 )

@@ -1,7 +1,9 @@
 """Inference stack (reference ``trace/`` + ``examples/inference/modules``;
 SURVEY §3.5): AOT builder with shape router, KV-cached CausalLM serving,
 samplers, the continuous-batching engine (``engine.py``). Speculative
-decoding in ``speculative.py``."""
+decoding in ``speculative.py``. The replay driver (``replay.py``: load
+generator and serving reports) sits above the engine, the router and the
+disaggregated fleet and is loaded only when one of its names is asked for."""
 
 from neuronx_distributed_tpu.inference.adapters import (  # noqa: F401
     AdapterLoadError,
@@ -19,9 +21,6 @@ from neuronx_distributed_tpu.inference.engine import (  # noqa: F401
     ReplicaLoad,
     Request,
     ServeEngine,
-    run_trace,
-    synthetic_trace,
-    synthetic_trace_stream,
 )
 from neuronx_distributed_tpu.inference.schedq import (  # noqa: F401
     AdmissionQueue,
@@ -48,12 +47,10 @@ from neuronx_distributed_tpu.inference.faults import (  # noqa: F401
 from neuronx_distributed_tpu.inference.router import (  # noqa: F401
     NoLiveReplicas,
     Router,
-    run_router_trace,
 )
 from neuronx_distributed_tpu.inference.disagg import (  # noqa: F401
     DisaggRouter,
     KVHandoff,
-    run_disagg_trace,
 )
 from neuronx_distributed_tpu.inference.model_builder import ModelBuilder, NxDModel  # noqa: F401
 from neuronx_distributed_tpu.inference.paged_cache import (  # noqa: F401
@@ -63,3 +60,13 @@ from neuronx_distributed_tpu.inference.paged_cache import (  # noqa: F401
     RadixPrefixIndex,
 )
 from neuronx_distributed_tpu.inference.sampling import Sampler, SlotSampler  # noqa: F401
+
+_REPLAY_NAMES = ("run_trace", "synthetic_trace", "synthetic_trace_stream",
+                 "run_router_trace", "run_disagg_trace")
+
+
+def __getattr__(name):
+    if name in _REPLAY_NAMES:
+        from neuronx_distributed_tpu.inference import replay
+        return getattr(replay, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -107,23 +107,6 @@ def _set_cache_index_rows(cache: PyTree, slot_ids, lengths) -> PyTree:
     return jax.tree_util.tree_map_with_path(fix, cache)
 
 
-def _merge_cache_slots(old: PyTree, new: PyTree, sel: jax.Array,
-                       new_len: jax.Array) -> PyTree:
-    """Full-width cache merge (the pre-scatter insert path, kept as the
-    bench comparison baseline): selected batch rows take the freshly
-    prefilled state, unselected rows keep their in-flight state. The
-    ``jnp.where`` copies EVERY cache byte — O(cache) HBM traffic per insert,
-    which is what ``_scatter_cache_rows`` replaces with O(inserted rows)."""
-
-    def merge(path, o, n):
-        if jax.tree_util.keystr(path).endswith("['cache_index']"):
-            return jnp.where(sel[None, :], new_len[None, :].astype(o.dtype), o)
-        shape = (1, -1) + (1,) * (o.ndim - 2)
-        return jnp.where(sel.reshape(shape), n, o)
-
-    return jax.tree_util.tree_map_with_path(merge, old, new)
-
-
 def _scatter_cache_rows(old: PyTree, fresh: PyTree, slots: jax.Array,
                         new_len: jax.Array, rows: int) -> PyTree:
     """Scatter ``rows`` freshly prefilled cache rows into the session cache

@@ -267,9 +267,9 @@ class ConversationParkStore:
         return json.loads(self.storage.load_text(f"{conv}/{_MANIFEST}"))
 
     def parked_bytes(self, rid: int) -> int:
-        """Total durable bytes of one parked conversation (manifest sum) —
-        the bench's resident-bytes-per-idle-conversation denominator lives
-        on disk, not in device/host memory."""
+        """Total durable bytes of one parked conversation (manifest sum):
+        what an idle conversation holds lives on disk, not in device/host
+        memory."""
         m = self.manifest(rid)
         return sum(int(f["bytes"]) for f in m["files"].values())
 

@@ -60,9 +60,8 @@ so raw wall-clock token gaps still contain the co-scheduled prefill
 workers' time. The report therefore ALSO derives a per-worker DECODE CLOCK
 (each decode worker's own per-block wall seconds, adoption cost included)
 — the timeline a dedicated decode host would actually deliver — and the
-bench's ``serve_itl_p99_ms_disagg`` / ``serve_decode_stall_ms_longprompt_
-disagg`` keys read that clock, with the in-process wall numbers kept in
-the sidecar for the caveat trail.
+report's ``itl_*`` / ``decode_stall_excess_ms`` keys read that clock, with
+the in-process wall numbers kept beside them for the caveat trail.
 """
 
 from __future__ import annotations
@@ -79,7 +78,6 @@ from neuronx_distributed_tpu.inference.router import (
     NoLiveReplicas,
     Router,
     _Entry,
-    run_router_trace,
 )
 
 
@@ -414,96 +412,3 @@ class DisaggRouter(Router):
             "handoffs_in_flight": len(self._handoffs),
         }
         return out
-
-
-def decode_clock_itl(router: DisaggRouter,
-                     long_prompt_cutoff: Optional[int] = None) -> dict:
-    """Decode-side latency surface on the per-worker clock: each stream's
-    token i is stamped with its home decode worker's CUMULATIVE wall
-    seconds through the block that delivered it (that worker's dispatches,
-    fetches, and adoption writes only — not the co-scheduled prefill
-    workers this single-threaded harness interleaves). Returns delivery-gap
-    percentiles plus the long-prompt interference verdict:
-    ``decode_stall_excess_ms`` — the worst gap a SHORT request saw beyond
-    the run's median gap (``long_prompt_cutoff`` defaults to the longest
-    prompt in the run, so "short" = everything shorter than the tail). On
-    a fleet where prompts never touch decode workers this is ≈ 0 — the
-    number chunked prefill could only bound, eliminated."""
-    tok_blocks: Dict[int, List[int]] = {}
-    for rid, evs in router.tracer.by_request().items():
-        tok_blocks[rid] = [ev["block"] for ev in evs
-                           if ev["name"] == "tok" and ev["block"] is not None]
-    cum = {j: np.cumsum(np.asarray(w, np.float64))
-           for j, w in enumerate(router._eng_block_wall)}
-    gaps_ms: List[float] = []
-    handoff_gaps_ms: List[float] = []
-    short_max: List[float] = []
-    all_max: List[float] = []
-    plens = {c.request_id: c.prompt_len for c in router.completed}
-    if long_prompt_cutoff is None:
-        long_prompt_cutoff = max(plens.values(), default=0)
-    for c in router.completed:
-        j = router._decode_home.get(c.request_id)
-        blocks = tok_blocks.get(c.request_id)
-        if j is None or not blocks or cum[j].size == 0:
-            continue
-        ts = np.asarray([cum[j][min(b, cum[j].size - 1)] for b in blocks])
-        g_all = np.diff(ts) * 1e3
-        if g_all.size:
-            # the token0→token1 gap is MIGRATION latency, not decode ITL:
-            # token 0 lands early on the prefill side and the stream then
-            # waits for adoption + a decode slot — that wait is reported
-            # separately (and attributed to the 'migration' phase); the
-            # steady-state decode surface starts at token 1
-            handoff_gaps_ms.append(float(g_all[0]))
-            g = g_all[1:]
-        else:
-            g = g_all
-        g = g[g > 0.0]
-        gaps_ms.extend(g.tolist())
-        if g.size:
-            all_max.append(float(g.max()))
-            if c.prompt_len < long_prompt_cutoff:
-                short_max.append(float(g.max()))
-    p50 = round(float(np.percentile(gaps_ms, 50)), 3) if gaps_ms else None
-    p99 = round(float(np.percentile(gaps_ms, 99)), 3) if gaps_ms else None
-    if not short_max:
-        short_max = all_max      # uniform-length trace: no tail to exclude
-    excess = None
-    if short_max and p50 is not None:
-        excess = round(max(0.0, max(short_max) - p50), 3)
-    return {
-        "itl_p50_ms_decode_clock": p50,
-        "itl_p99_ms_decode_clock": p99,
-        "decode_stall_excess_ms": excess,
-        "handoff_gap_ms_p99": (
-            round(float(np.percentile(handoff_gaps_ms, 99)), 3)
-            if handoff_gaps_ms else None),
-    }
-
-
-def run_disagg_trace(router: DisaggRouter, trace: List[dict],
-                     max_blocks: Optional[int] = None) -> dict:
-    """Drive a synthetic trace through the disaggregated fleet; returns
-    ``run_router_trace``'s report plus the disaggregation surface: roles,
-    the handoff lifecycle counters, and the decode-clock latency numbers
-    (see :func:`decode_clock_itl` for the clock's basis — the in-process
-    wall ``itl_*`` keys remain in the report for the caveat trail)."""
-    report = run_router_trace(router, trace, max_blocks=max_blocks)
-    long_lens = [len(item["prompt"]) for item in trace]
-    cutoff = max(long_lens) if long_lens else None
-    report.update({
-        "disagg": True,
-        "prefill_replicas": router.prefill_replicas,
-        "decode_replicas": len(router.engines) - router.prefill_replicas,
-        "handoffs_sent": router.stats["handoffs_sent"],
-        "handoffs_adopted": router.stats["handoffs_adopted"],
-        "handoffs_degraded": router.stats["handoffs_degraded"],
-        "handoffs_deferred": router.stats["handoffs_deferred"],
-        "handoff_pages": router.stats["handoff_pages"],
-        "adopted_pages": sum(
-            eng.session.paged.stats["adopted_pages"]
-            for eng in router.engines if eng.session.paged is not None),
-    })
-    report.update(decode_clock_itl(router, long_prompt_cutoff=cutoff))
-    return report

@@ -28,11 +28,11 @@ Scheduler responsibilities (all host-side, between blocks):
   iteration-level scheduling above. A one-shot insert of a long prompt
   stalls every live token stream for the whole prefill; chunking bounds the
   per-round prefill work, so inter-token latency during an insert stays
-  near the no-insert baseline (``bench_serving``'s
-  ``serve_decode_stall_ms_longprompt`` pair measures exactly this). No
-  token is emitted until the final chunk; in paged mode pages are allocated
-  chunk-by-chunk (``PagedKVCache.begin/extend/finish_chunked``) and pool
-  pressure mid-prefill rolls the whole admission back atomically;
+  near the no-insert baseline (the replay report's
+  ``decode_stall_excess_ms`` measures exactly this). No token is emitted
+  until the final chunk; in paged mode pages are allocated chunk-by-chunk
+  (``PagedKVCache.begin/extend/finish_chunked``) and pool pressure
+  mid-prefill rolls the whole admission back atomically;
 * retire-on-EOS / budget / cache-room — finished slots are retired at block
   boundaries and immediately reusable; ``cancel`` retires a request in ANY
   state (queued / mid-prefill / decoding);
@@ -100,7 +100,7 @@ import os
 import time
 from collections import deque
 from collections.abc import MutableMapping
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple, Union
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -112,7 +112,6 @@ from neuronx_distributed_tpu.observability import (
     SLOMonitor,
     Tracer,
 )
-from neuronx_distributed_tpu.observability.tracer import interblock_gaps
 from neuronx_distributed_tpu.observability import attribution as _attribution
 from neuronx_distributed_tpu.inference.adapters import (
     AdapterLoadError,
@@ -191,8 +190,8 @@ class Completion:
     # wall perf_counter stamp per emitted token (the block fetch that
     # surfaced it) — the replay/recovery bookkeeping's record of what was
     # already delivered; the inter-token-latency REPORT reads the tracer's
-    # token events instead (run_trace — single source of truth with the
-    # Perfetto export)
+    # token events instead (replay.run_trace — single source of truth with
+    # the Perfetto export)
     token_ts: Optional[np.ndarray] = None
     cancelled: bool = False
     # deadline surface: ``expired`` = the ENGINE cut the request off when
@@ -3453,7 +3452,7 @@ class ServeEngine:
         self._emitted.add(req.request_id)
         # delivery-gap surface: tokens of one fused fetch share a stamp, so
         # only cross-delivery gaps (ts advanced) are observed — the user-
-        # experienced inter-token latency, same filter run_trace applies
+        # experienced inter-token latency (replay.run_trace's filter)
         last = self._last_tok_ts.get(req.request_id)
         if last is not None and ts > last:
             self._m_itl.observe((ts - last) * 1e3)
@@ -4248,683 +4247,3 @@ class ServeEngine:
             os.remove(snapshot_path)   # clean drain: nothing to recover
         self._sync_compile_metrics()
         return self.completed
-
-
-def synthetic_trace_stream(num_requests: int, vocab_size: int, *,
-                           prompt_lens=(8, 16), max_new_tokens: int = 16,
-                           mean_interarrival_blocks: float = 0.5,
-                           eos_token_id: Optional[int] = None,
-                           shared_prefix_len: int = 0,
-                           prefix_families: int = 1,
-                           long_prompt_frac: float = 0.0,
-                           long_prompt_len: int = 0,
-                           ttft_deadline_ms: Optional[float] = None,
-                           deadline_ms: Optional[float] = None,
-                           tenants: int = 0,
-                           tenant_skew: float = 1.0,
-                           adapters: int = 0,
-                           adapter_skew: float = 1.0,
-                           grammar_frac: float = 0.0,
-                           grammars: Sequence[str] = (),
-                           diurnal: float = 0.0,
-                           diurnal_period_blocks: int = 64,
-                           burst_every: int = 0,
-                           burst_mult: float = 4.0,
-                           seed: int = 0) -> Iterator[dict]:
-    """STREAMED deterministic synthetic arrival trace (virtual time in
-    blocks): a generator yielding one request dict at a time — no
-    materialized request list, so a 1M-request soak holds O(1) trace
-    memory (the ROADMAP #18 down-payment; ``synthetic_trace`` below is the
-    list-materializing wrapper every existing caller keeps using, and
-    ``run_router_trace`` accepts the raw generator, submitting each
-    request only when the clock reaches its arrival).
-
-    Arrival-rate modulation (ISSUE 12 — the autoscaling workload shapes;
-    both default OFF, and OFF is draw-for-draw identical to the historic
-    trace for any seed):
-
-    * ``diurnal`` in [0, 1): the instantaneous arrival rate is scaled by
-      ``1 + diurnal * sin(2*pi*t / diurnal_period_blocks)`` — a smooth
-      day/night load curve on the virtual clock (peak early in each
-      period, trough in the second half). The mean stays
-      ``mean_interarrival_blocks``-ish; the POINT is that a fixed fleet
-      provisioned for the peak idles through the trough.
-    * ``burst_every`` > 0: during the first quarter of every
-      ``burst_every``-block window, arrivals come ``burst_mult``x faster —
-      the square-wave flash-crowd shape that exercises scale-up patience
-      and cooldown (a one-block spike must not spawn a replica; a
-      sustained burst must).
-    """
-    import math
-    if not 0.0 <= diurnal < 1.0:
-        raise ValueError(f"diurnal must be in [0, 1), got {diurnal}")
-    if diurnal_period_blocks < 1:
-        raise ValueError(f"diurnal_period_blocks must be >= 1, got "
-                         f"{diurnal_period_blocks}")
-    if burst_every < 0:
-        raise ValueError(f"burst_every must be >= 0, got {burst_every}")
-    if burst_mult <= 0:
-        raise ValueError(f"burst_mult must be > 0, got {burst_mult}")
-    if long_prompt_frac < 0 or long_prompt_frac > 1:
-        raise ValueError(f"long_prompt_frac must be in [0, 1], got {long_prompt_frac}")
-    if long_prompt_frac > 0 and long_prompt_len < 1:
-        raise ValueError("long_prompt_frac > 0 needs long_prompt_len >= 1")
-    if tenants < 0:
-        raise ValueError(f"tenants must be >= 0, got {tenants}")
-    if tenant_skew < 0:
-        raise ValueError(f"tenant_skew must be >= 0, got {tenant_skew}")
-    if adapters < 0:
-        raise ValueError(f"adapters must be >= 0, got {adapters}")
-    if adapter_skew < 0:
-        raise ValueError(f"adapter_skew must be >= 0, got {adapter_skew}")
-    if not 0.0 <= grammar_frac <= 1.0:
-        raise ValueError(f"grammar_frac must be in [0, 1], got {grammar_frac}")
-    if grammar_frac > 0 and not grammars:
-        raise ValueError("grammar_frac > 0 needs grammars=(names...)")
-    if prefix_families < 1:
-        raise ValueError(f"prefix_families must be >= 1, got {prefix_families}")
-    long_every = round(1 / long_prompt_frac) if long_prompt_frac > 0 else 0
-    rs = np.random.RandomState(seed)
-    prefixes = [rs.randint(1, vocab_size,
-                           (shared_prefix_len,)).astype(np.int32)
-                for _ in range(prefix_families)]
-    tenant_p = None
-    if tenants:
-        w = 1.0 / np.arange(1, tenants + 1, dtype=np.float64) ** tenant_skew
-        tenant_p = w / w.sum()
-    # structured-decoding labels ride their OWN stream (like adapters):
-    # adding grammar labels never shifts the tenant/adapter/arrival draws,
-    # and grammar_frac=0 is draw-for-draw identical to the historic trace
-    grammar_rs = np.random.RandomState(seed + 0x67)
-    grammar_count = 0
-    adapter_p = None
-    adapter_rs = np.random.RandomState(seed + 0x5A)   # independent stream
-    if adapters:
-        wa = 1.0 / np.arange(1, adapters + 1,
-                             dtype=np.float64) ** adapter_skew
-        adapter_p = wa / wa.sum()
-    t = 0.0
-    for i in range(num_requests):
-        # instantaneous rate modulation (both factors 1.0 when off — the
-        # exponential draw then consumes the identical scale, keeping the
-        # stream draw-for-draw equal to the historic trace)
-        rate = 1.0
-        if diurnal > 0:
-            rate *= max(1.0 + diurnal * math.sin(
-                2.0 * math.pi * t / diurnal_period_blocks), 0.05)
-        if burst_every and int(t) % burst_every < max(1, burst_every // 4):
-            rate *= burst_mult
-        t += rs.exponential(mean_interarrival_blocks / rate)
-        s = int(prompt_lens[i % len(prompt_lens)])
-        if long_every and i % long_every == long_every - 1:
-            s = int(long_prompt_len)
-        tail = rs.randint(1, vocab_size, (s,)).astype(np.int32)
-        if tenant_p is not None:
-            trace_tenant = f"t{int(rs.choice(tenants, p=tenant_p))}"
-        prefix = prefixes[(i // 4) % prefix_families]
-        item = {
-            "prompt": np.concatenate([prefix, tail]) if shared_prefix_len else tail,
-            "max_new_tokens": max_new_tokens,
-            "eos_token_id": eos_token_id,
-            "arrival_block": int(t),
-            # per-request SLO budgets (None = none): the overload bench
-            # attaches these to measure deadline-miss rate and goodput
-            "ttft_deadline_ms": ttft_deadline_ms,
-            "deadline_ms": deadline_ms,
-        }
-        if tenant_p is not None:
-            item["tenant"] = trace_tenant
-        if adapter_p is not None:
-            item["adapter"] = \
-                f"a{int(adapter_rs.choice(adapters, p=adapter_p))}"
-        if grammar_frac > 0 and grammar_rs.random_sample() < grammar_frac:
-            # cycle the grammar names over the CONSTRAINED subsequence so
-            # every grammar sees traffic at any frac (pool churn included)
-            item["grammar"] = grammars[grammar_count % len(grammars)]
-            grammar_count += 1
-        yield item
-
-
-def synthetic_trace(num_requests: int, vocab_size: int,
-                    **kw) -> List[dict]:
-    """Deterministic synthetic arrival trace (virtual time in blocks):
-    exponential inter-arrivals, prompt lengths cycled through
-    ``prompt_lens`` — the multi-tenant workload shape the serving bench and
-    the ``runner.py serve`` entrypoint replay. This is the materializing
-    wrapper over :func:`synthetic_trace_stream` (same knobs, same draws —
-    see there for the streamed form and the ``diurnal``/``burst_every``
-    arrival-rate modulation). ``shared_prefix_len > 0``
-    prepends a common random prefix of that many tokens to every prompt
-    (the system-prompt / few-shot-header workload shape the paged engine's
-    prefix cache exists for; prompt_lens then size the per-request tail);
-    ``prefix_families > 1`` rotates through that many DISTINCT prefixes in
-    runs of four consecutive requests (A A A A B B B B A ...) — the
-    working-set-larger-than-the-pool workload the host tier exists for:
-    the idle family's prefix goes cold, spills, and must restore (or
-    re-prefill) when its run comes around again.
-
-    ``long_prompt_frac > 0`` makes the prompt-length distribution heavy-
-    tailed: every ``round(1/frac)``-th request (never the first, so decode
-    traffic is already live when the first long prompt arrives) carries a
-    ``long_prompt_len``-token prompt instead — the prefill/decode
-    interference workload ``prefill_chunk_tokens`` exists for.
-
-    ``tenants > 0`` labels each request with a tenant drawn from a
-    Zipf-skewed distribution over ``t0..t<tenants-1>`` (P(rank k) ∝
-    1/(k+1)^tenant_skew — t0 is the heavy hitter; skew 0 is uniform): the
-    multi-tenant burst workload the Router's weighted fair queueing and
-    tenant-aware shedding exist for. ``run_trace``/``run_router_trace``
-    then report the per-tenant latency/goodput surface.
-
-    ``adapters > 0`` labels each request with an adapter name drawn from
-    its own Zipf distribution over ``a0..a<adapters-1>`` (independent
-    stream — adding adapter labels never shifts the tenant draws): the
-    every-user-their-own-fine-tune workload of the multi-LoRA pool. Low
-    ``adapter_skew`` spreads traffic across adapters (pool churn when the
-    pool holds fewer), high skew concentrates it (a0 stays hot). The
-    caller must ``register_adapter`` every name the trace uses."""
-    return list(synthetic_trace_stream(num_requests, vocab_size, **kw))
-
-
-def per_tenant_report(completions: List[Completion],
-                      tok_ts: Dict[int, np.ndarray], wall_s: float,
-                      rejected_tenants: Sequence[str] = ()) -> Dict[str, dict]:
-    """Per-tenant latency/goodput table (shared by :func:`run_trace` and the
-    Router's report): delivery-gap ITL percentiles, TTFT, goodput (tokens of
-    in-deadline streams only), and the shed/expiry counts — the isolation
-    surface the fairness bench asserts on (one tenant's burst must not move
-    another tenant's p99)."""
-    rej = list(rejected_tenants)
-    tenants = sorted({c.tenant for c in completions} | set(rej))
-    out: Dict[str, dict] = {}
-    for t in tenants:
-        comps = [c for c in completions if c.tenant == t]
-        gaps: List[float] = []
-        for c in comps:
-            ts = tok_ts.get(c.request_id, np.zeros((0,)))
-            g = np.diff(ts) * 1e3 if ts.size > 1 else np.zeros((0,))
-            gaps.extend(g[g > 0.0].tolist())
-        ontime = sum(len(c.tokens) for c in comps
-                     if not (c.deadline_missed or c.expired or c.cancelled))
-        out[t] = {
-            "requests": len(comps),
-            # structured share per tenant (zero on free-form-only tenants)
-            "constrained_requests": sum(1 for c in comps
-                                        if c.grammar is not None),
-            "generated_tokens": int(sum(len(c.tokens) for c in comps)),
-            "itl_p50_ms": round(float(np.percentile(gaps, 50)), 3)
-            if gaps else None,
-            "itl_p99_ms": round(float(np.percentile(gaps, 99)), 3)
-            if gaps else None,
-            "ttft_blocks_mean": round(float(np.mean(
-                [c.ttft_blocks for c in comps])), 2) if comps else None,
-            "ttft_blocks_p99": int(np.percentile(
-                [c.ttft_blocks for c in comps], 99)) if comps else None,
-            "goodput_tokens_per_sec": (round(ontime / wall_s, 1)
-                                       if wall_s > 0 else None),
-            "rejected": rej.count(t),
-            "expired": sum(1 for c in comps if c.expired),
-            "deadline_missed": sum(1 for c in comps if c.deadline_missed),
-        }
-    return out
-
-
-def interblock_gap_report(tracer: "Tracer", lanes: List[Any]) -> dict:
-    """Summarise the dispatch-side pipeline health across one or more
-    engine lanes (ROADMAP #22). Two distinct idle surfaces come out of the
-    same dispatch/fetch spans:
-
-    - ``interblock_gap_ms_*``: fetch(t) end -> dispatch(t+1) start — time
-      the DEVICE sat idle while the host ran the scheduling pass. This is
-      the number the async loop drives to ~0 (dispatch t+1 precedes
-      fetch t, so the gap is 0 by construction).
-    - ``fetch_blocked_ms_*``: the fetch span itself — time the HOST sat
-      blocked waiting on the device. Sync pays scheduling + fetch serially;
-      async pays only the residue of whatever device work the overlapped
-      scheduling pass didn't cover.
-
-    Returns ``{}`` when no paired spans exist (untraced engines, sim-only
-    runs with < 2 decode blocks).
-    """
-    gaps: List[float] = []
-    blocked: List[float] = []
-    for lane in lanes:
-        g, b = interblock_gaps(tracer, lane)
-        gaps.extend(g)
-        blocked.extend(b)
-    if not gaps and not blocked:
-        return {}
-    out: dict = {}
-    if gaps:
-        out.update({
-            "interblock_gap_ms_p50": round(float(np.percentile(gaps, 50)), 3),
-            "interblock_gap_ms_p99": round(float(np.percentile(gaps, 99)), 3),
-            "interblock_gap_ms_mean": round(float(np.mean(gaps)), 3),
-        })
-    if blocked:
-        out.update({
-            "fetch_blocked_ms_p50": round(float(np.percentile(blocked, 50)), 3),
-            "fetch_blocked_ms_mean": round(float(np.mean(blocked)), 3),
-        })
-    return out
-
-
-def run_trace(engine: ServeEngine, trace: List[dict],
-              max_blocks: Optional[int] = None,
-              snapshot_path: Optional[str] = None) -> dict:
-    """Submit a synthetic trace and drive the engine to completion; returns
-    the serving report (throughput, latency-in-blocks percentiles, wall
-    TTFT/inter-token-latency surface, host-op accounting, and — when the
-    trace carries deadlines or the engine bounds its queue — the overload
-    surface: rejected/expired counts, deadline-miss rate, goodput) used by
-    ``runner.py serve`` and the bench.
-
-    The wall latency surface (inter-token delivery gaps, per-request max
-    stall) is computed from the TRACER's per-request token events — the
-    same single source of truth the Perfetto export and
-    :meth:`ServeEngine.request_timeline` read — so this entrypoint turns
-    tracing on when the engine was built without it. Callers measuring the
-    untraced fast path (the tracing-overhead bench) drive ``engine.run()``
-    directly.
-
-    STREAMING MODE (``ServeEngine(keep_completions=False)``): the trace
-    may be a raw generator — requests submit only when the virtual clock
-    reaches their arrival, completions fold into counters and the engine's
-    log-bucket latency histograms as they finish, and the report is built
-    entirely from those aggregates (percentiles are histogram upper
-    edges; no per-request lists, no tracer requirement) — the memory-
-    bounded path million-request soaks run (ROADMAP #18)."""
-    if not getattr(engine, "keep_completions", True):
-        return _run_trace_streaming(engine, trace, max_blocks=max_blocks,
-                                    snapshot_path=snapshot_path)
-    if not isinstance(trace, (list, tuple)):
-        # single-engine runs materialize a streamed trace (the streamed
-        # submit-at-arrival path lives in run_router_trace)
-        trace = list(trace)
-    if not engine.tracer.enabled:
-        engine.tracer.enabled = True
-    tenant_of: Dict[int, str] = {}
-    for item in trace:
-        out = engine.submit(item["prompt"], item["max_new_tokens"],
-                            eos_token_id=item.get("eos_token_id"),
-                            arrival_block=item.get("arrival_block", 0),
-                            ttft_deadline_ms=item.get("ttft_deadline_ms"),
-                            deadline_ms=item.get("deadline_ms"),
-                            tenant=item.get("tenant", "default"),
-                            adapter=item.get("adapter"),
-                            grammar=item.get("grammar"))
-        rid = out.request_id if isinstance(out, Rejected) else out
-        tenant_of[rid] = item.get("tenant", "default")
-    t0 = time.perf_counter()
-    completions = engine.run(max_blocks=max_blocks,
-                             snapshot_path=snapshot_path)
-    # conversation tier (--park-idle-blocks): the drain above leaves
-    # auto-parked conversations durable but incomplete (parked streams
-    # never block drain). Resume each — the finite trace's stand-in for
-    # the user's return — and drain again until the trace is fully
-    # served. "park_deferred" is a retry-later verdict (the next drain
-    # frees the slot/pool it was waiting on); any other Rejected is
-    # terminal and already accounted in engine.rejected.
-    if getattr(engine, "park_idle_blocks", 0):
-        dead = set()
-        while True:
-            pending = [r for r in engine.parked_ids() if r not in dead]
-            if not pending:
-                break
-            resumed = 0
-            for rid in pending:
-                out = engine.submit(resume=rid)
-                if isinstance(out, Rejected):
-                    if out.reason != "park_deferred":
-                        dead.add(rid)
-                else:
-                    resumed += 1
-            if not resumed and not engine.step_block():
-                break  # nothing resumable and the clock is drained
-            # run() returns the engine's CUMULATIVE finish-order list, so
-            # re-binding (not +=) keeps each request counted once
-            completions = engine.run(max_blocks=max_blocks,
-                                     snapshot_path=snapshot_path)
-    wall_s = time.perf_counter() - t0
-    total_tokens = int(sum(len(c.tokens) for c in completions))
-    decode_blocks = max(engine.stats["decode_blocks"], 1)
-    # wall-clock latency surface: per-request TTFT (virtual blocks — wall
-    # arrivals would be backend-racy) and inter-token gaps from the
-    # tracer's per-token delivery stamps. A fused block DELIVERS its K
-    # tokens in one fetch (identical stamps), so the user-experienced
-    # inter-token latency is the gap between successive deliveries —
-    # intra-delivery zero gaps are excluded. A long-prompt one-shot insert
-    # shows up as ONE huge delivery gap on every concurrently-decoding
-    # request; chunked prefill bounds it, which is what pulls itl_p99 back
-    # toward the no-insert per-block baseline.
-    tok_ts = {
-        rid: np.asarray([ev["ts"] for ev in evs if ev["name"] == "tok"],
-                        np.float64)
-        for rid, evs in engine.tracer.by_request().items()}
-    per_request = []
-    gaps_ms: List[float] = []
-    for c in completions:
-        ts = tok_ts.get(c.request_id, np.zeros((0,)))
-        g = np.diff(ts) * 1e3 if ts.size > 1 else np.zeros((0,))
-        g = g[g > 0.0]
-        gaps_ms.extend(g.tolist())
-        per_request.append({
-            "request_id": c.request_id,
-            "prompt_len": c.prompt_len,
-            "generated": int(len(c.tokens)),
-            "ttft_blocks": c.ttft_blocks,
-            "max_itl_gap_ms": round(float(g.max()), 2) if g.size else 0.0,
-        })
-    report = {
-        "requests_completed": len(completions),
-        "total_generated_tokens": total_tokens,
-        "wall_s": round(wall_s, 4),
-        "tokens_per_sec": round(total_tokens / wall_s, 1) if wall_s > 0 else None,
-        "blocks": engine.stats["blocks"],
-        "decode_blocks": engine.stats["decode_blocks"],
-        "block_steps": engine.block_steps,
-        "fused": engine.fused,
-        "inserts": engine.stats["inserts"],
-        "inserted_requests": engine.stats["inserted_requests"],
-        "program_calls": engine.stats["program_calls"],
-        "host_fetches": engine.stats["host_fetches"],
-        # the dispatch contract the fused path exists for: decode-side host
-        # ops (program call + fetch) per K-token block of the whole pool;
-        # 2.0 with fused=True, 2*K with fused=False (inserts accounted
-        # separately above)
-        "host_ops_per_block": round(
-            (engine.stats["program_calls"] + engine.stats["host_fetches"])
-            / decode_blocks, 2),
-        # pipeline surface: device idle between blocks (the async loop's
-        # target metric) and host time blocked in fetches — see
-        # interblock_gap_report for the span pairing
-        "async_loop": engine.async_loop,
-        **interblock_gap_report(engine.tracer, [engine.lane]),
-        "queue_blocks_mean": round(float(np.mean(
-            [c.queue_blocks for c in completions])), 2) if completions else None,
-        "decode_blocks_mean": round(float(np.mean(
-            [c.decode_blocks for c in completions])), 2) if completions else None,
-        # chunked-prefill surface (zeros when prefill_chunk_tokens == 0)
-        "prefill_chunk_tokens": engine.prefill_chunk_tokens,
-        "chunk_program_calls": engine.stats["chunk_program_calls"],
-        "prefill_chunk_tokens_done": engine.stats["prefill_chunk_tokens_done"],
-        "prefill_aborts": engine.stats["prefill_aborts"],
-        # latency surface
-        "ttft_blocks_mean": round(float(np.mean(
-            [c.ttft_blocks for c in completions])), 2) if completions else None,
-        "ttft_blocks_max": int(max(c.ttft_blocks for c in completions))
-        if completions else None,
-        "itl_p50_ms": round(float(np.percentile(gaps_ms, 50)), 3)
-        if gaps_ms else None,
-        "itl_p99_ms": round(float(np.percentile(gaps_ms, 99)), 3)
-        if gaps_ms else None,
-        "max_itl_gap_ms": round(float(np.max(gaps_ms)), 2)
-        if gaps_ms else None,
-        "per_request": per_request,
-    }
-    # overload / robustness surface: rejected-by-shedding, expired-by-
-    # deadline, miss rate over ALL submissions (shed counts as a miss — a
-    # rejected client got nothing, exactly like a blown deadline, just
-    # cheaply and immediately), and GOODPUT: only tokens of requests that
-    # completed within their deadlines count
-    submitted = len(trace)
-    rejected = len(engine.rejected)
-    expired = sum(1 for c in completions if c.expired)
-    missed = sum(1 for c in completions if c.deadline_missed)
-    has_deadlines = any(item.get("deadline_ms") or item.get("ttft_deadline_ms")
-                        for item in trace)
-    ontime_tokens = sum(
-        len(c.tokens) for c in completions
-        if not (c.deadline_missed or c.expired or c.cancelled))
-    report.update({
-        "rejected": rejected,
-        "expired": expired,
-        "shed_evictions": engine.stats["shed_evictions"],
-        "max_queue": engine.max_queue,
-        "shed_policy": engine.shed_policy,
-        "deadline_miss_rate": (round((rejected + missed) / submitted, 4)
-                               if has_deadlines and submitted else None),
-        "goodput_tokens_per_sec": (round(ontime_tokens / wall_s, 1)
-                                   if wall_s > 0 else None),
-        "dispatch_retries": engine.stats["dispatch_retries"],
-        "corrupt_page_replays": engine.stats["corrupt_page_replays"],
-        "restored_requests": engine.stats["restored_requests"],
-        # tracing surface: how much of the timeline survives in the ring
-        # buffer (dropped > 0 means the export window is partial)
-        "trace_events": len(engine.tracer.events()),
-        "trace_events_dropped": engine.tracer.dropped,
-    })
-    if engine.park_store is not None:
-        # conversation-tier surface: parked_remaining > 0 means the trace
-        # ended with conversations still durable on disk (their bytes are
-        # the tier's footprint — device and host hold ZERO for them)
-        report.update({
-            "park_idle_blocks": engine.park_idle_blocks,
-            "parked": engine.stats["parked"],
-            "resumed": engine.stats["resumed"],
-            "park_replays": engine.stats["park_replays"],
-            "park_rejects": engine.stats["park_rejects"],
-            "parked_remaining": len(engine.parked_ids()),
-            "parked_bytes": int(sum(
-                engine.park_store.parked_bytes(r)
-                for r in engine.park_store.list_parked())),
-        })
-    # per-tenant isolation surface (present whenever the trace labels
-    # tenants): the aggregate numbers above hide exactly the thing a quota
-    # system exists to protect — whose p99 a burst moved
-    if any(t != "default" for t in tenant_of.values()):
-        report["per_tenant"] = per_tenant_report(
-            completions, tok_ts, wall_s,
-            [tenant_of.get(r.request_id, "default")
-             for r in engine.rejected])
-    if getattr(engine, "grammar", False):
-        # structured-decoding surface (ISSUE 13): the constrained share of
-        # the trace and its latency split vs the free-form tenants riding
-        # the same pool — the "masking must not stall the pool" evidence —
-        # plus the pool's load/evict/repair cycle and finish reasons
-        gpool = engine.session.grammars
-
-        def _split(pred):
-            comps = [c for c in completions if pred(c)]
-            gaps: List[float] = []
-            for c in comps:
-                ts = tok_ts.get(c.request_id, np.zeros((0,)))
-                gg = np.diff(ts) * 1e3 if ts.size > 1 else np.zeros((0,))
-                gaps.extend(gg[gg > 0.0].tolist())
-            return {
-                "requests": len(comps),
-                "itl_p50_ms": round(float(np.percentile(gaps, 50)), 3)
-                if gaps else None,
-                "itl_p99_ms": round(float(np.percentile(gaps, 99)), 3)
-                if gaps else None,
-                "ttft_blocks_mean": round(float(np.mean(
-                    [c.ttft_blocks for c in comps])), 2) if comps else None,
-            }
-
-        constrained = [c for c in completions if c.grammar is not None]
-        report["structured"] = {
-            "constrained_requests": len(constrained),
-            "constrained_share": (round(len(constrained) / len(completions),
-                                        3) if completions else None),
-            "constrained": _split(lambda c: c.grammar is not None),
-            "freeform": _split(lambda c: c.grammar is None),
-            "finish_reasons": {
-                r: sum(1 for c in completions if c.finish_reason == r)
-                for r in sorted({c.finish_reason for c in completions})},
-            "grammar_slots": gpool.n_slots,
-            "grammars_resident": sorted(gpool.resident),
-            "grammar_loads": gpool.stats["loads"],
-            "grammar_evictions": gpool.stats["evictions"],
-            "grammar_hits": gpool.stats["hits"],
-            "grammar_repairs": gpool.stats["repairs"],
-            "grammar_rejects": engine.stats["grammar_rejects"],
-            "grammar_load_retries": engine.stats["grammar_load_retries"],
-            "grammar_bytes_per_slot": gpool.grammar_bytes(),
-            "grammar_compile_ms": {
-                n: gpool.compile_ms_of(n) for n in sorted(gpool._registry)},
-        }
-    if getattr(engine, "lora", False):
-        # multi-LoRA surface: pool residency + the load/evict/repair cycle
-        # — the "one compiled program, any adapter mix" evidence
-        pool = engine.session.adapters
-        report.update({
-            "multilora": True,
-            "adapter_slots": pool.n_slots,
-            "adapters_resident": sorted(pool.resident),
-            "adapter_loads": pool.stats["loads"],
-            "adapter_evictions": pool.stats["evictions"],
-            "adapter_hits": pool.stats["hits"],
-            "adapter_repairs": pool.stats["repairs"],
-            "adapter_load_failures": pool.stats["load_failures"],
-            "adapter_rejects": engine.stats["adapter_rejects"],
-            "adapter_load_retries": engine.stats["adapter_load_retries"],
-            "adapter_bytes_per_slot": pool.adapter_bytes(),
-        })
-    if engine._injector is not None:
-        report["fault_stats"] = dict(engine._injector.stats)
-    pkv = getattr(engine.session, "paged", None)
-    if pkv is not None:
-        kv = engine.lm.kv_cache_bytes()
-        report.update({
-            "paged": True,
-            "page_size": pkv.page_size,
-            "page_pool_pages": pkv.num_pages,
-            # storage + kernel knobs (ISSUE 17): what the pool bytes
-            # below were measured under
-            "page_dtype": engine._page_dtype(),
-            "paged_attn_kernel": bool(
-                getattr(engine.lm.config, "paged_attn_kernel", False)),
-            "prefix_queries": pkv.stats["prefix_queries"],
-            "prefix_hits": pkv.stats["prefix_hits"],
-            "prefix_hit_tokens": pkv.stats["prefix_hit_tokens"],
-            "pages_in_use_peak": pkv.stats["pages_in_use_peak"],
-            "evicted_pages": pkv.stats["evicted_pages"],
-            "deferred_admissions": engine.stats["deferred_admissions"],
-            "kv_hbm_bytes": kv["kv_bytes"],
-            "kv_hbm_bytes_global": kv["kv_bytes_global"],
-            "kv_slab_hbm_bytes": kv["kv_slab_bytes"],
-            "kv_hbm_vs_slab": round(kv["kv_bytes"] / kv["kv_slab_bytes"], 3),
-        })
-        from neuronx_distributed_tpu.inference.partition import (
-            sharded_fraction, tp_degree,
-        )
-        report.update({
-            # TP-sharded serving surface: per-chip vs global KV bytes is
-            # the capacity-multiplication evidence (ISSUE 16)
-            "tp_degree": tp_degree(),
-            "kv_sharded_fraction": round(
-                sharded_fraction(engine.session.cache), 3),
-        })
-        if pkv.tier is not None:
-            # host-tier surface: the spill/restore/repair cycle plus what
-            # is resident right now — the "pool pressure became latency,
-            # not sheds" evidence
-            report.update({
-                "host_tier_pages": pkv.tier.max_pages,
-                "tier_pages_resident": pkv.tier_pages(),
-                "tier_bytes_resident": pkv.tier_bytes(),
-                "tier_spilled_pages": pkv.stats["tier_spilled_pages"],
-                "tier_restored_pages": pkv.stats["tier_restored_pages"],
-                "tier_hits": pkv.stats["tier_hits"],
-                "tier_restore_failures": pkv.stats["tier_restore_failures"],
-                "tier_repaired_pages": pkv.stats["tier_repaired_pages"],
-                "tier_restore_ms_p99": (
-                    round(float(np.percentile(pkv._restore_ms, 99)), 3)
-                    if pkv._restore_ms else None),
-            })
-    return report
-
-
-def _submit_item(submit, item) -> None:
-    """Submit one synthetic-trace dict through ``submit`` (the engine's or
-    the router's) — the one place the trace-item schema is interpreted."""
-    submit(item["prompt"], item["max_new_tokens"],
-           eos_token_id=item.get("eos_token_id"),
-           arrival_block=item.get("arrival_block", 0),
-           ttft_deadline_ms=item.get("ttft_deadline_ms"),
-           deadline_ms=item.get("deadline_ms"),
-           tenant=item.get("tenant", "default"),
-           adapter=item.get("adapter"),
-           grammar=item.get("grammar"))
-
-
-def _run_trace_streaming(engine: ServeEngine, trace,
-                         max_blocks: Optional[int] = None,
-                         snapshot_path: Optional[str] = None) -> dict:
-    """Memory-bounded run_trace (``keep_completions=False``): submit at
-    arrival off a raw iterator, report entirely from the stats counters
-    and log-bucket histograms — O(in-flight) host memory regardless of
-    trace length, zero tracer requirement (ROADMAP #18)."""
-    if snapshot_path is not None:
-        raise ValueError("streaming runs do not snapshot (keep_completions"
-                         "=False drops the per-request record the snapshot"
-                         " would serialize)")
-    it = iter(trace)
-    nxt = next(it, None)
-    submitted = 0
-    has_deadlines = False
-    t0 = time.perf_counter()
-    n = 0
-    while True:
-        while (nxt is not None
-               and int(nxt.get("arrival_block", 0)) <= engine.blocks):
-            _submit_item(engine.submit, nxt)
-            submitted += 1
-            has_deadlines = has_deadlines or bool(
-                nxt.get("deadline_ms") or nxt.get("ttft_deadline_ms"))
-            nxt = next(it, None)
-        more = engine.step_block()
-        n += 1
-        if max_blocks is not None and n >= max_blocks:
-            break
-        if not more and nxt is None:
-            break
-    engine._sync_compile_metrics()
-    wall_s = time.perf_counter() - t0
-    st = engine.stats
-    completed = int(st["completed"])
-    total_tokens = int(st["generated_tokens"])
-    decode_blocks = max(int(st["decode_blocks"]), 1)
-    itl = engine._m_itl
-    rejected = int(st["rejected"])
-    missed = int(st["deadline_misses"])
-    return {
-        "streaming": True,
-        "percentile_basis": "log-bucket histogram upper edges",
-        "requests_submitted": submitted,
-        "requests_completed": completed,
-        "total_generated_tokens": total_tokens,
-        "wall_s": round(wall_s, 4),
-        "tokens_per_sec": (round(total_tokens / wall_s, 1)
-                           if wall_s > 0 else None),
-        "goodput_tokens_per_sec": (
-            round(int(st["ontime_tokens"]) / wall_s, 1)
-            if wall_s > 0 else None),
-        "sched_overhead_us_per_request": (
-            round(wall_s * 1e6 / completed, 2) if completed else None),
-        "blocks": int(st["blocks"]),
-        "decode_blocks": int(st["decode_blocks"]),
-        "block_steps": engine.block_steps,
-        "fused": engine.fused,
-        "inserts": int(st["inserts"]),
-        "inserted_requests": int(st["inserted_requests"]),
-        "host_ops_per_block": round(
-            (int(st["program_calls"]) + int(st["host_fetches"]))
-            / decode_blocks, 2),
-        "queue_blocks_mean": (round(int(st["queue_blocks_sum"])
-                                    / completed, 2) if completed else None),
-        "ttft_blocks_mean": (round(int(st["ttft_blocks_sum"])
-                                   / completed, 2) if completed else None),
-        "itl_p50_ms": (round(itl.percentile(50), 3)
-                       if itl.count else None),
-        "itl_p99_ms": (round(itl.percentile(99), 3)
-                       if itl.count else None),
-        "rejected": rejected,
-        "expired": int(st["expired"]),
-        "shed_evictions": int(st["shed_evictions"]),
-        "deadline_miss_rate": (
-            round((rejected + missed) / submitted, 4)
-            if has_deadlines and submitted else None),
-        "deferred_admissions": int(st["deferred_admissions"]),
-        "dispatch_retries": int(st["dispatch_retries"]),
-    }

@@ -29,7 +29,7 @@ Placement (per block, over the arrived backlog in fairness order):
   exhaustion) is honored: the request re-queues with the verdict's
   ``retry_after_blocks`` backoff (capped), up to ``max_requeues`` times
   before the rejection surfaces to the client;
-* **round_robin** — the measurement baseline the bench compares against.
+* **round_robin** — the measurement baseline the other policies are compared against.
 
 Per-tenant fairness (start-time fair queueing over token cost):
 
@@ -57,7 +57,7 @@ Replica failure (the chaos seam) and graceful drain:
   router's own per-request (prompt, generated) delivery records — both
   resume bit-identical (the rng contract above); queued/mid-prefill work
   simply re-places. The failover wall cost is recorded
-  (``last_failover_ms``) — it is the bench's ``serve_failover_replay_ms``;
+  (``last_failover_ms``);
 * ``drain(replica)`` is the rolling-restart primitive: placement stops,
   queued + mid-prefill + pending-replay requests migrate to peers
   (mid-prefill unwinds atomically through the abort machinery — zero
@@ -83,11 +83,9 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import time
-from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
-import numpy as np
 
 from neuronx_distributed_tpu.inference.engine import (
     Completion,
@@ -95,8 +93,6 @@ from neuronx_distributed_tpu.inference.engine import (
     ReplicaLoad,
     Request,
     ServeEngine,
-    interblock_gap_report,
-    per_tenant_report,
 )
 from neuronx_distributed_tpu.inference.faults import FaultInjector, FaultPlan
 from neuronx_distributed_tpu.inference.schedq import PendingQueue
@@ -162,7 +158,7 @@ class Router:
     shed_policy, block_time_ms, ...) is forwarded to every replica, so the
     fleet is homogeneous; ``placement`` picks the routing policy
     ('affinity' — prefix-affinity with least-loaded fallback, the default —
-    'least_loaded', or 'round_robin', the bench baseline). ``faults``
+    'least_loaded', or 'round_robin', the comparison baseline). ``faults``
     arms the shared :class:`FaultInjector` at every replica's
     engine seams AND the router's replica-crash seam."""
 
@@ -1434,256 +1430,3 @@ class Router:
                 **eng.load_summary().to_dict(),
             })
         return out
-
-
-def run_router_trace(router: Router, trace,
-                     max_blocks: Optional[int] = None) -> dict:
-    """Submit a synthetic trace to the Router and drive the fleet to
-    completion; returns the serving report in ``run_trace``'s shape plus
-    the router surface (per-replica states, placements, failovers, drains)
-    and the per-tenant isolation table. Turns tracing on (the wall
-    ITL surface reads the shared tracer's token events) exactly like
-    ``run_trace``.
-
-    ``trace`` is a list (submitted up-front, the historic shape) or ANY
-    iterator — e.g. the raw :func:`synthetic_trace_stream` generator: the
-    streamed form pulls one item at a time and submits it only once the
-    shared clock reaches its arrival block, so the request list is never
-    materialized (ROADMAP #18 down-payment) and the run keeps the clock
-    alive through arrival gaps — the idle valleys autoscaling scales down
-    into. Token streams are identical either way (the per-request rng
-    contract); WFQ tags and wall accounting differ slightly in basis
-    (streamed submission happens inside the timed loop).
-
-    STREAMING REPORT (``Router(keep_completions=False)``): tracing is NOT
-    force-enabled, no per-request lists are materialized anywhere — the
-    report reads the harvest aggregates and the per-replica log-bucket
-    latency histograms merged explicitly (percentiles are bucket upper
-    edges). The memory-bounded mode the 1M-request soak runs
-    (``scripts/soak.py`` — ROADMAP #18)."""
-    streaming = not getattr(router, "keep_completions", True)
-    if not streaming and not router.tracer.enabled:
-        router.tracer.enabled = True
-    # O(1)-per-request bookkeeping (tenant label + deadline flag) — the
-    # report's denominator; deliberately NOT the items themselves
-    meta: List[Tuple[str, bool]] = []
-    counts = {"submitted": 0, "deadlines": False}
-
-    def _submit(item):
-        router.submit(item["prompt"], item["max_new_tokens"],
-                      eos_token_id=item.get("eos_token_id"),
-                      arrival_block=item.get("arrival_block", 0),
-                      ttft_deadline_ms=item.get("ttft_deadline_ms"),
-                      deadline_ms=item.get("deadline_ms"),
-                      tenant=item.get("tenant", "default"),
-                      adapter=item.get("adapter"),
-                      grammar=item.get("grammar"))
-        counts["submitted"] += 1
-        counts["deadlines"] = counts["deadlines"] or bool(
-            item.get("deadline_ms") or item.get("ttft_deadline_ms"))
-        if not streaming:
-            meta.append((item.get("tenant", "default"),
-                         bool(item.get("deadline_ms")
-                              or item.get("ttft_deadline_ms"))))
-
-    if isinstance(trace, (list, tuple)):
-        for item in trace:
-            _submit(item)
-        t0 = time.perf_counter()
-        completions = router.run(max_blocks=max_blocks)
-        wall_s = time.perf_counter() - t0
-    else:
-        it = iter(trace)
-        nxt = next(it, None)
-        t0 = time.perf_counter()
-        n = 0
-        while True:
-            while (nxt is not None
-                   and int(nxt.get("arrival_block", 0)) <= router.blocks):
-                _submit(nxt)
-                nxt = next(it, None)
-            more = router.step_block()
-            n += 1
-            if max_blocks is not None and n >= max_blocks:
-                break
-            if not more and nxt is None:
-                break
-        completions = router.completed
-        wall_s = time.perf_counter() - t0
-    if streaming:
-        return _streaming_router_report(router, wall_s,
-                                        counts["submitted"],
-                                        counts["deadlines"])
-    total_tokens = int(sum(len(c.tokens) for c in completions))
-    tok_ts = {
-        rid: np.asarray([ev["ts"] for ev in evs if ev["name"] == "tok"],
-                        np.float64)
-        for rid, evs in router.tracer.by_request().items()}
-    gaps_ms: List[float] = []
-    for c in completions:
-        ts = tok_ts.get(c.request_id, np.zeros((0,)))
-        g = np.diff(ts) * 1e3 if ts.size > 1 else np.zeros((0,))
-        gaps_ms.extend(g[g > 0.0].tolist())
-    submitted = len(meta)
-    rejected = len(router.rejected)
-    expired = sum(1 for c in completions if c.expired)
-    missed = sum(1 for c in completions if c.deadline_missed)
-    has_deadlines = any(flag for _t, flag in meta)
-    ontime_tokens = sum(
-        len(c.tokens) for c in completions
-        if not (c.deadline_missed or c.expired or c.cancelled))
-    report = {
-        "replicas": len(router.engines),
-        "placement": router.placement,
-        "requests_completed": len(completions),
-        "total_generated_tokens": total_tokens,
-        "wall_s": round(wall_s, 4),
-        "tokens_per_sec": (round(total_tokens / wall_s, 1)
-                           if wall_s > 0 else None),
-        "goodput_tokens_per_sec": (round(ontime_tokens / wall_s, 1)
-                                   if wall_s > 0 else None),
-        "blocks": router.blocks,
-        "rejected": rejected,
-        "expired": expired,
-        "deadline_miss_rate": (round((rejected + missed) / submitted, 4)
-                               if has_deadlines and submitted else None),
-        "itl_p50_ms": round(float(np.percentile(gaps_ms, 50)), 3)
-        if gaps_ms else None,
-        "itl_p99_ms": round(float(np.percentile(gaps_ms, 99)), 3)
-        if gaps_ms else None,
-        "ttft_blocks_mean": round(float(np.mean(
-            [c.ttft_blocks for c in completions])), 2)
-        if completions else None,
-        # pipeline surface aggregated over every replica lane that ever
-        # dispatched (parked replicas contribute no spans)
-        "async_loop": any(getattr(e, "async_loop", False)
-                          for e in router.engines if e is not None),
-        **interblock_gap_report(
-            router.tracer,
-            [e.lane for e in router.engines if e is not None]),
-        # provisioned capacity actually consumed (replica-blocks): the
-        # denominator of the autoscale-vs-fixed goodput-per-capacity key
-        "replica_blocks": router.stats["replica_blocks"],
-        "placements": router.stats["placements"],
-        "affinity_placements": router.stats["affinity_placements"],
-        "requeues": router.stats["requeues"],
-        "crashes": router.stats["crashes"],
-        "failovers": router.stats["failovers"],
-        "failed_over_requests": router.stats["failed_over_requests"],
-        "drains": router.stats["drains"],
-        "last_failover_ms": router.last_failover_ms,
-        "last_drain_ms": router.last_drain_ms,
-        "replica_states": router.replica_states(),
-        "trace_events": len(router.tracer.events()),
-        "trace_events_dropped": router.tracer.dropped,
-    }
-    tiered = [eng.session.paged for eng in router.engines
-              if eng.paged and eng.session.paged is not None
-              and eng.session.paged.tier is not None]
-    if tiered:
-        # fleet-aggregate host-tier surface (per-replica residency is in
-        # replica_states): spills/restores/repairs summed across replicas
-        report.update({
-            "tier_pages_resident": sum(p.tier_pages() for p in tiered),
-            "tier_spilled_pages": sum(
-                p.stats["tier_spilled_pages"] for p in tiered),
-            "tier_restored_pages": sum(
-                p.stats["tier_restored_pages"] for p in tiered),
-            "tier_restore_failures": sum(
-                p.stats["tier_restore_failures"] for p in tiered),
-            "tier_repaired_pages": sum(
-                p.stats["tier_repaired_pages"] for p in tiered),
-        })
-    lora_engines = [eng for eng in router.engines
-                    if getattr(eng, "lora", False)]
-    if lora_engines:
-        # fleet-aggregate multi-LoRA surface (per-replica residency is in
-        # replica_states): loads/evictions/repairs summed across replicas
-        report.update({
-            "multilora": True,
-            "adapter_loads": sum(
-                eng.session.adapters.stats["loads"] for eng in lora_engines),
-            "adapter_evictions": sum(
-                eng.session.adapters.stats["evictions"]
-                for eng in lora_engines),
-            "adapter_repairs": sum(
-                eng.session.adapters.stats["repairs"]
-                for eng in lora_engines),
-            "adapter_rejects": sum(
-                int(eng.stats["adapter_rejects"]) for eng in lora_engines),
-        })
-    tenants = {t for t, _flag in meta}
-    if tenants != {"default"}:
-        report["per_tenant"] = per_tenant_report(
-            completions, tok_ts, wall_s,
-            [router._tenant_of.get(r.request_id, "default")
-             for r in router.rejected])
-    if router._injector is not None:
-        report["fault_stats"] = dict(router._injector.stats)
-    if router.autoscaler is not None:
-        # elastic-fleet surface: the deterministic scale-event log plus
-        # warm/cold spawn counts and scale-up time-to-ready blocks
-        report["autoscale"] = router.autoscaler.report(router)
-    return report
-
-
-def _streaming_router_report(router: Router, wall_s: float,
-                             submitted: int, has_deadlines: bool) -> dict:
-    """Memory-bounded fleet report (``keep_completions=False``): built from
-    the harvest aggregates and the per-replica latency histograms merged
-    bucket-wise — no per-request lists, no tracer (ROADMAP #18)."""
-    agg = router._agg
-    completed = agg["completed"]
-    total_tokens = agg["tokens"]
-    itls = [eng._m_itl for eng in router.engines]
-    ttfts = [eng._m_ttft for eng in router.engines]
-    itl = itls[0].merged(*itls[1:]) if itls else None
-    ttft = ttfts[0].merged(*ttfts[1:]) if ttfts else None
-    rejected = int(router.stats["rejected"])
-    report = {
-        "streaming": True,
-        "percentile_basis": "log-bucket histogram upper edges",
-        "replicas": len(router.engines),
-        "placement": router.placement,
-        "requests_submitted": submitted,
-        "requests_completed": completed,
-        "total_generated_tokens": total_tokens,
-        "wall_s": round(wall_s, 4),
-        "tokens_per_sec": (round(total_tokens / wall_s, 1)
-                           if wall_s > 0 else None),
-        "goodput_tokens_per_sec": (
-            round(agg["ontime_tokens"] / wall_s, 1) if wall_s > 0 else None),
-        # the ROADMAP #18 deliverable: total host wall over completed
-        # requests — with a sim lm there is no device time to hide behind,
-        # so this IS the scheduler+bookkeeping cost per request
-        "sched_overhead_us_per_request": (
-            round(wall_s * 1e6 / completed, 2) if completed else None),
-        "blocks": router.blocks,
-        "rejected": rejected,
-        "expired": agg["expired"],
-        "cancelled": agg["cancelled"],
-        "deadline_miss_rate": (
-            round((rejected + agg["missed"]) / submitted, 4)
-            if has_deadlines and submitted else None),
-        "itl_p50_ms": (round(itl.percentile(50), 3)
-                       if itl is not None and itl.count else None),
-        "itl_p99_ms": (round(itl.percentile(99), 3)
-                       if itl is not None and itl.count else None),
-        "ttft_ms_p99": (round(ttft.percentile(99), 3)
-                        if ttft is not None and ttft.count else None),
-        "ttft_blocks_mean": (round(agg["ttft_blocks_sum"] / completed, 2)
-                             if completed else None),
-        "queue_blocks_mean": (round(agg["queue_blocks_sum"] / completed, 2)
-                              if completed else None),
-        "replica_blocks": router.stats["replica_blocks"],
-        "placements": router.stats["placements"],
-        "affinity_placements": router.stats["affinity_placements"],
-        "requeues": router.stats["requeues"],
-        "crashes": router.stats["crashes"],
-        "failovers": router.stats["failovers"],
-        "drains": router.stats["drains"],
-        "replicas_active": len(router._live_replicas()),
-    }
-    if router.autoscaler is not None:
-        report["autoscale"] = router.autoscaler.report(router)
-    return report

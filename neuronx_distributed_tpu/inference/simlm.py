@@ -118,7 +118,7 @@ class SimCausalLM:
                   if self.paged else self.max_batch * self.config.max_seq_len)
         slab = self.max_batch * self.config.max_seq_len
         # host-only sim: no mesh, so per-chip == global (the real lm's
-        # kv_bytes_global key — run_trace's paged report reads it)
+        # kv_bytes_global key — replay.run_trace's paged report reads it)
         return {"kv_bytes": tokens * self._kv_token_bytes,
                 "kv_bytes_global": tokens * self._kv_token_bytes,
                 "kv_slab_bytes": slab * self._kv_token_bytes}

@@ -66,7 +66,8 @@ class BertConfig:
 
 
 def bert_large(**over) -> BertConfig:
-    """L24_A16_H1024 — the reference example's target size (BASELINE config #2)."""
+    """L24_A16_H1024 — the reference example's target size
+    (``tp_dp_bert_large_hf_pretrain_hdf5.py``)."""
     return BertConfig(**{**dict(hidden_size=1024, intermediate_size=4096,
                                 num_layers=24, num_heads=16), **over})
 

@@ -197,7 +197,7 @@ class LlamaConfig:
         return shrink(dq, sq), shrink(dk, sk)
 
 
-# presets mirroring the reference's example configs (BASELINE.md ladder)
+# presets mirroring the reference's example configs
 def _preset(base, over):
     return LlamaConfig(**{**base, **over})
 

@@ -209,9 +209,7 @@ def merge_block(m, se, acc, o_i, lse_i):
     state: ``o_i`` (b*h, s, d), ``lse_i`` lane-broadcast (b*h, s, LANES) from
     :func:`flash_block_forward`; state ``m``/``se`` (b*h, s) fp32, ``acc``
     (b*h, s, d) fp32. Fully-future blocks carry ``lse == NEG_INF`` so their
-    weight ``exp(lse - m_new)`` is exactly 0. Shared by the ring op and the
-    CP microbench (scripts/validate_long_seq.py) so the bench times the very
-    recurrence the op runs."""
+    weight ``exp(lse - m_new)`` is exactly 0."""
     lse_c = lse_i[:, :, 0]
     m_new = jnp.maximum(m, lse_c)
     c_old = jnp.exp(m - m_new)

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 The entry points (``chip_smoke.py``, ``examples/inference/runner.py``, the
-training examples' ``setup_example``, ``bench.py``) call
+training examples' ``setup_example``) call
 :func:`place_compile_cache` once, before their first compile. Placement
 belongs to whoever runs the program: when ``JAX_COMPILATION_CACHE_DIR`` is
 exported JAX already reads it and nothing is set here. Otherwise the cache

@@ -6,13 +6,12 @@
 
 Runs the ``neuronx_distributed_tpu.analysis`` rule engine over the repo:
 host-sync-in-traced-code, cache-boundary replication, resource
-pin/release pairing, determinism discipline, and bench/fault/
-observability surface drift. STDLIB-ONLY, no jax import — milliseconds
+pin/release pairing, determinism discipline, and fault/observability
+surface drift. STDLIB-ONLY, no jax import — milliseconds
 of ``ast.parse``, wired into tier-1 so a contract regression fails the
 suite before a chaos run has to find it.
 
-Output protocol (the repo's artifact discipline, matching
-``scripts/bench_regress.py``): human-readable finding lines on stderr,
+Output protocol: human-readable finding lines on stderr,
 ONE compact JSON summary as the last stdout line (``--json`` adds the
 full findings list to stdout above it). Exit 0 = clean (no unwaived
 findings), 1 = unwaived findings, 2 = internal/usage error.

@@ -87,13 +87,11 @@ def run_soak(num_requests: int, replicas: int = 100, *,
              max_samples: int = 2048) -> dict:
     """One soak run; returns the report dict (streaming router report +
     the RSS surface). Pure host work — safe at 1M requests."""
-    from neuronx_distributed_tpu.inference.engine import (
+    from neuronx_distributed_tpu.inference.replay import (
+        run_router_trace,
         synthetic_trace_stream,
     )
-    from neuronx_distributed_tpu.inference.router import (
-        Router,
-        run_router_trace,
-    )
+    from neuronx_distributed_tpu.inference.router import Router
     from neuronx_distributed_tpu.inference.simlm import SimCausalLM
 
     vocab = 32000

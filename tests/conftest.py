@@ -24,7 +24,8 @@ import jax  # noqa: E402
 # programs), and skipping XLA's optimization passes cuts wall clock ~40%
 # without changing any outcome — every exactness test compares two programs
 # compiled under the SAME flags, so the equality claims are unaffected.
-# bench.py runs outside pytest and keeps full optimization.
+# Programs run outside pytest (benchmark/run.py, chip_smoke.py, the
+# examples) keep full optimization.
 jax.config.update("jax_disable_most_optimizations", True)
 # No compilation cache directory is set here (utils/compile_cache.py is for
 # the entry points): the tier-1 run starts from a fresh checkout, so a

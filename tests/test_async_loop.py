@@ -34,7 +34,7 @@ from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM, Sampler, ServeEngine
 from neuronx_distributed_tpu.inference.disagg import DisaggRouter
-from neuronx_distributed_tpu.inference.engine import run_trace, synthetic_trace
+from neuronx_distributed_tpu.inference.replay import run_trace, synthetic_trace
 from neuronx_distributed_tpu.inference.faults import FaultPlan
 from neuronx_distributed_tpu.inference.router import Router
 from neuronx_distributed_tpu.inference.simlm import SimCausalLM

@@ -40,7 +40,7 @@ from neuronx_distributed_tpu.inference import (
     ServeEngine,
     run_router_trace,
 )
-from neuronx_distributed_tpu.inference.engine import (
+from neuronx_distributed_tpu.inference.replay import (
     synthetic_trace,
     synthetic_trace_stream,
 )

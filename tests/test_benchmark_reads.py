@@ -1,0 +1,204 @@
+"""What the chip benchmark reads FROM THE PROGRAM is still produced by it.
+
+``benchmark/drivers/*.py``, ``benchmark/layer_metrics/*.py``,
+``benchmark/trace_parts.py`` and ``benchmark/trace_reduce.py`` read the
+program by name: ``engine.stats`` keys, the paged pool's counters,
+``Completion`` fields, engine and ``CausalLM`` attributes, the names of the
+three jitted functions a device trace is reduced by. A rename fails
+silently there: ``_StatsView`` defaults a missing key to 0, a renamed
+jitted function zeroes ``decode.step_ms`` / ``prefill.ms_per_call`` only
+under trace on the chip, and the one CPU rehearsal of the serving driver
+(``tests/benchmark/test_bm_files.py``) sees neither. Here every such name is
+one case, asserted PRODUCED (present and non-trivial) by one tiny paged,
+prefix-cached, fused ``ServeEngine`` run, one tiny OLMoE run for the routing
+counters and one lowered train step. The table lives in this file;
+``test_every_name_is_still_read`` keeps it from outliving what it guards.
+"""
+
+import re
+import types
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from flax.core import meta
+
+from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
+from neuronx_distributed_tpu.inference.engine import Rejected
+from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from neuronx_distributed_tpu.models.olmoe import OlmoeConfig, OlmoeForCausalLM
+from neuronx_distributed_tpu.trainer import (
+    create_train_state,
+    initialize_parallel_model,
+    initialize_parallel_optimizer,
+    make_train_step,
+    neuronx_distributed_config,
+)
+
+BENCHMARK = Path(__file__).resolve().parent.parent / "benchmark"
+TINY = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2, num_heads=4,
+            num_kv_heads=2, kv_size_multiplier=1, max_seq_len=64, dtype=jnp.float32,
+            use_flash_attention=False, remat_policy=None)
+BUDGET, BLOCK_STEPS = 6, 4
+
+
+def _serve(cfg, model_cls, prompts):
+    """The serving driver's calls, at toy size: ``CausalLM`` paged with the
+    prefix cache on, ``compile()``, a traced fused engine, ``submit`` /
+    ``step_block`` until everything drained."""
+    params = meta.unbox(model_cls(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    lm = CausalLM(cfg, params, model_cls, buckets=(16, 32), max_batch=2, page_size=4,
+                  prefix_cache=True)
+    lm.compile()
+    engine = ServeEngine(lm, block_steps=BLOCK_STEPS, rng=jax.random.key(0), trace=True)
+    ids = [engine.submit(p, max_new_tokens=BUDGET, arrival_block=engine.blocks)
+           for p in prompts]
+    while engine.step_block():
+        pass
+    return types.SimpleNamespace(lm=lm, engine=engine, ids=ids)
+
+
+@pytest.fixture(scope="module")
+def run():
+    rng = np.random.RandomState(0)
+    head = rng.randint(1, 128, (8,)).astype(np.int32)
+    # the last two share their first eight tokens (two whole pages) with the
+    # first: the prefix cache has something to hit
+    prompts = [np.concatenate([head, rng.randint(1, 128, (n,)).astype(np.int32)])
+               for n in (4, 12, 5, 6)]
+    got = _serve(LlamaConfig(**TINY), LlamaForCausalLM, prompts)
+    got.completion = got.engine.completed[0]
+    got.fused = got.lm.compile_session_decode_fused(
+        got.engine.block_steps, got.engine.slot_sampler, got.engine.pad_token_id)
+    got.insert = got.lm._paged_insert_programs(1, 16)
+    # an engine that sheds, over the same programs: what submit() hands the
+    # driver in place of an id
+    shedding = ServeEngine(got.lm, block_steps=BLOCK_STEPS, max_queue=0, rng=jax.random.key(0))
+    got.shed = [shedding.submit(p, max_new_tokens=2) for p in prompts]
+    got.shedding = shedding
+    return got
+
+
+@pytest.fixture(scope="module")
+def moe_run():
+    cfg = OlmoeConfig(**dict(TINY, num_kv_heads=4, intermediate_size=32, num_experts=8, top_k=2))
+    rng = np.random.RandomState(1)
+    return _serve(cfg, OlmoeForCausalLM,
+                  [rng.randint(1, 128, (n,)).astype(np.int32) for n in (6, 9)])
+
+
+@pytest.fixture(scope="module")
+def train_step_text():
+    cfg = LlamaConfig(**dict(TINY, max_seq_len=16))
+    nxd = neuronx_distributed_config(tensor_parallel_size=1)
+    ids = np.random.RandomState(0).randint(1, 128, (2, 16)).astype(np.int32)
+    model = initialize_parallel_model(nxd, lambda: LlamaForCausalLM(cfg), jnp.asarray(ids))
+    opt = initialize_parallel_optimizer(nxd, model, learning_rate=1e-3, weight_decay=0.0)
+    state = create_train_state(model, opt)
+
+    def loss_fn(p, batch, rng):
+        return model.module.apply({"params": p}, batch["ids"], batch["labels"],
+                                  method=LlamaForCausalLM.loss)
+
+    step = make_train_step(model, opt, loss_fn)
+    return step.lower(state, {"ids": ids, "labels": ids}, jax.random.key(0)).as_text()
+
+
+def _positive_stat(key):
+    return lambda r: r.engine.stats[key] > 0
+
+
+def _dispatch_spans(r):
+    """The spans ``engine.host_ms_per_block`` subtracts: complete events of
+    the tracer's dispatch lane, with a start and a duration."""
+    spans = [e for e in r.engine.tracer.events() if e["ph"] == "X" and e["lane"][1] == "dispatch"]
+    return spans and all(e["dur"] >= 0 and "ts" in e for e in spans)
+
+
+def _stamps(r):
+    """One stamp per token, non-decreasing, for every completion."""
+    return all(c.token_ts is not None and len(c.token_ts) == len(c.tokens)
+               and bool(np.all(np.diff(np.asarray(c.token_ts)) >= 0))
+               for c in r.engine.completed)
+
+
+# (the name as the benchmark's sources spell it, what "produced" means)
+SERVING_READS = [
+    ('stats["blocks"]', _positive_stat("blocks")),
+    ('stats["decode_blocks"]', _positive_stat("decode_blocks")),
+    ('stats["inserts"]', _positive_stat("inserts")),
+    ('stats["inserted_requests"]', lambda r: r.engine.stats["inserted_requests"] == len(r.ids)),
+    ('stats["generated_tokens"]',
+     lambda r: r.engine.stats["generated_tokens"] == BUDGET * len(r.ids)),
+    ('stats["pages_in_use_peak"]',
+     lambda r: 0 < r.engine.session.paged.stats["pages_in_use_peak"]
+     <= r.lm.config.page_pool_pages),
+    ('stats["prefix_hits"]', lambda r: r.engine.session.paged.stats["prefix_hits"] > 0),
+    ("engine.stats.items()",
+     lambda r: {"blocks", "generated_tokens"} <= {k for k, _ in r.engine.stats.items()}),
+    ("c.tokens", lambda r: all(len(c.tokens) == BUDGET for c in r.engine.completed)),
+    ("c.token_ts", _stamps),
+    ("c.request_id", lambda r: sorted(c.request_id for c in r.engine.completed) == sorted(r.ids)),
+    ("c.finish_reason", lambda r: isinstance(r.completion.finish_reason, str)
+     and r.completion.finish_reason != ""),
+    ("c.expired", lambda r: r.completion.expired is False),
+    ("c.cancelled", lambda r: r.completion.cancelled is False),
+    ("engine.completed", lambda r: len(r.engine.completed) == len(r.ids)),
+    ("engine.rejected", lambda r: r.engine.rejected == [] and len(r.shedding.rejected) > 0),
+    ("'reason'", lambda r: any(isinstance(s, Rejected) and s.reason for s in r.shed)),
+    ("engine.blocks", lambda r: r.engine.blocks == r.engine.stats["blocks"] > 0),
+    ("engine.block_steps", lambda r: r.engine.block_steps == BLOCK_STEPS),
+    ("engine.tracer.events()", _dispatch_spans),
+    ("engine.session.paged", lambda r: r.engine.session.paged is not None),
+    ("engine.slot_sampler", lambda r: r.engine.slot_sampler is not None),
+    ("engine.pad_token_id", lambda r: isinstance(r.engine.pad_token_id, int)),
+    ("lm.compile_ms", lambda r: len(r.lm.compile_ms) >= 2
+     and all(ms > 0 for ms in r.lm.compile_ms.values())),
+    ("lm.buckets", lambda r: tuple(r.lm.buckets) == (16, 32)),
+    ("lm.max_batch", lambda r: r.lm.max_batch == 2),
+    ("lm._bucket_for", lambda r: (r.lm._bucket_for(9), r.lm._bucket_for(17)) == (16, 32)),
+    ("lm.config.page_pool_pages", lambda r: r.lm.config.page_pool_pages > 0),
+    ('lm.kv_cache_bytes()["kv_bytes"]', lambda r: r.lm.kv_cache_bytes()["kv_bytes"] > 0),
+    ("memory_analysis()", lambda r: all(
+        getattr(r.fused.memory_analysis(), f) >= 0
+        for f in ("temp_size_in_bytes", "argument_size_in_bytes", "output_size_in_bytes"))),
+    ("jit_fused_fn", lambda r: "jit_fused_fn" in r.fused.as_text().split("\n", 1)[0]),
+    ("jit_insert_fn", lambda r: "jit_insert_fn" in r.insert.as_text().split("\n", 1)[0]),
+]
+MOE_READS = ["moe_experts_touched", "moe_assignments", "moe_layer_steps"]
+TRAIN_READS = ["jit_step_fn"]
+
+
+@pytest.mark.parametrize("name,produced", SERVING_READS, ids=[n for n, _ in SERVING_READS])
+def test_serving_read_is_produced(run, name, produced):
+    assert produced(run), name
+
+
+@pytest.mark.parametrize("key", MOE_READS)
+def test_routing_counter_is_produced(run, moe_run, key):
+    """Counted by the fused decode of a model with experts, and only there."""
+    assert moe_run.engine.stats[key] > 0
+    assert run.engine.stats[key] == 0
+    if key == "moe_assignments":      # at least one live row chose top_k experts a layer step
+        assert moe_run.engine.stats[key] >= moe_run.engine.stats["moe_layer_steps"] * 2
+
+
+@pytest.mark.parametrize("name", TRAIN_READS)
+def test_train_step_module_name(train_step_text, name):
+    assert re.search(rf"module @{name}\b", train_step_text)
+
+
+def test_every_name_is_still_read():
+    """Each name of the tables occurs in the benchmark's sources: a reader
+    that stopped reading a name takes its case away with it."""
+    files = [*sorted((BENCHMARK / "drivers").glob("*.py")),
+             *sorted((BENCHMARK / "layer_metrics").glob("*.py")),
+             BENCHMARK / "trace_parts.py", BENCHMARK / "trace_reduce.py"]
+    text = "\n".join(f.read_text() for f in files)
+    names = [n for n, _ in SERVING_READS] + MOE_READS + TRAIN_READS
+    assert len(set(names)) == len(names)
+    missing = [n for n in names if n not in text]
+    assert not missing, missing

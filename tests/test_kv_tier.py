@@ -37,7 +37,7 @@ from neuronx_distributed_tpu.inference import (
     Sampler,
     ServeEngine,
 )
-from neuronx_distributed_tpu.inference.engine import run_trace, synthetic_trace
+from neuronx_distributed_tpu.inference.replay import run_trace, synthetic_trace
 from neuronx_distributed_tpu.inference.paged_cache import (
     HostPageTier,
     TierCorruption,

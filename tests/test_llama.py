@@ -151,7 +151,7 @@ def test_tp_flash_shard_map_path():
 def test_chunked_loss_matches_plain_exactly():
     """Long-seq CE chunking (head matmul + CE per sequence chunk under
     remat) must match the whole-sequence loss in value AND grads — the 32k
-    memory lever cannot change numerics (scripts/validate_long_seq.py gate)."""
+    memory lever cannot change numerics."""
     cfg_kw = {**TINY, "max_seq_len": 64, "remat_policy": None}
     ids = _ids((2, 64), 7)
     labels = np.array(_ids((2, 64), 8))

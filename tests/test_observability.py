@@ -33,11 +33,8 @@ import pytest
 from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
-from neuronx_distributed_tpu.inference.engine import (
-    _STAT_KEYS,
-    run_trace,
-    synthetic_trace,
-)
+from neuronx_distributed_tpu.inference.engine import _STAT_KEYS
+from neuronx_distributed_tpu.inference.replay import run_trace, synthetic_trace
 from neuronx_distributed_tpu.inference.faults import FaultPlan
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.observability import (

@@ -21,7 +21,7 @@ import pytest
 from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM, Sampler, ServeEngine
-from neuronx_distributed_tpu.inference.engine import run_trace, synthetic_trace
+from neuronx_distributed_tpu.inference.replay import run_trace, synthetic_trace
 from neuronx_distributed_tpu.inference.paged_cache import (
     PageAllocator,
     PagedKVCache,

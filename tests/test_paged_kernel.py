@@ -36,7 +36,7 @@ from neuronx_distributed_tpu.inference import (
     Sampler,
     ServeEngine,
 )
-from neuronx_distributed_tpu.inference.engine import run_trace
+from neuronx_distributed_tpu.inference.replay import run_trace
 from neuronx_distributed_tpu.inference.paged_kernel import (
     dequantize_kv_pages,
     paged_decode_attention,

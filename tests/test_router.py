@@ -37,7 +37,7 @@ from neuronx_distributed_tpu.inference import (
     ServeEngine,
     run_router_trace,
 )
-from neuronx_distributed_tpu.inference.engine import synthetic_trace
+from neuronx_distributed_tpu.inference.replay import synthetic_trace
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.observability import validate_chrome_trace
 

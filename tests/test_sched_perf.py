@@ -23,13 +23,14 @@ import pytest
 from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
-from neuronx_distributed_tpu.inference.engine import (
-    Request,
+from neuronx_distributed_tpu.inference.engine import Request
+from neuronx_distributed_tpu.inference.replay import (
+    run_router_trace,
     run_trace,
     synthetic_trace,
     synthetic_trace_stream,
 )
-from neuronx_distributed_tpu.inference.router import Router, run_router_trace
+from neuronx_distributed_tpu.inference.router import Router
 from neuronx_distributed_tpu.inference.schedq import (
     AdmissionQueue,
     PendingQueue,

@@ -14,6 +14,10 @@ test (block_steps=4 throughout, so the whole file compiles a single session
 program; program caches live on the lm and are shared across engines).
 """
 
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,7 +25,7 @@ import pytest
 from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM, Sampler, ServeEngine
-from neuronx_distributed_tpu.inference.engine import run_trace, synthetic_trace
+from neuronx_distributed_tpu.inference.replay import run_trace, synthetic_trace
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
 TINY = dict(
@@ -348,3 +352,27 @@ def test_arrival_trace_throughput_fused_beats_stepwise():
     assert reports[True]["host_ops_per_block"] == 2.0
     assert reports[False]["host_ops_per_block"] == 16.0
     assert reports[True]["program_calls"] * 8 == reports[False]["program_calls"]
+
+
+def test_replay_driver_sits_above_engine_router_and_disagg():
+    """The load generator and the reports are one module ABOVE the serving
+    stack: loading the engine, the router, the disaggregated fleet or the
+    package does not load ``inference.replay``; asking the package for one
+    of the replay names does."""
+    code = (
+        "import sys\n"
+        "import neuronx_distributed_tpu.inference as inf\n"
+        "import neuronx_distributed_tpu.inference.engine\n"
+        "import neuronx_distributed_tpu.inference.router\n"
+        "import neuronx_distributed_tpu.inference.disagg\n"
+        "replay = 'neuronx_distributed_tpu.inference.replay'\n"
+        "assert replay not in sys.modules\n"
+        "from neuronx_distributed_tpu.inference import (run_trace, synthetic_trace,\n"
+        "    synthetic_trace_stream, run_router_trace, run_disagg_trace)\n"
+        "assert replay in sys.modules\n"
+        "assert {f.__module__ for f in (run_trace, synthetic_trace, synthetic_trace_stream,\n"
+        "    run_router_trace, run_disagg_trace)} == {replay}\n"
+        "assert not hasattr(inf, 'no_such_name')\n")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                          timeout=300, cwd=Path(__file__).resolve().parent.parent)
+    assert proc.returncode == 0, proc.stderr[-2000:]

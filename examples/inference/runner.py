@@ -103,7 +103,6 @@ def _family_config(args):
                 vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=2,
                 num_heads=4, num_kv_heads=4, max_seq_len=256, dtype=jnp.float32,
                 use_flash_attention=False, num_experts=4, top_k=2,
-                selective_loading_threshold=1.5,
             )
         preset = dbrx if family == "dbrx" else mixtral_8x7b
         return preset(max_seq_len=args.max_seq_len, dtype=jnp.bfloat16,

@@ -130,12 +130,14 @@ def _scatter_cache_rows(old: PyTree, fresh: PyTree, slots: jax.Array,
 
 
 def _routing_sums(stats: PyTree, live: jax.Array) -> jax.Array:
-    """``(3,) int32`` of one decode step: expert slots touched by a live row
-    (summed over layers), assignments of live rows, layer steps with a live
-    row — from the ``(layers, rows, experts)`` choice masks ``MoE`` sows."""
+    """``(3,) int32`` of one model call: expert slots touched by a real token
+    (summed over layers), assignments of real tokens, layers run with a real
+    token in them — from the ``(layers, tokens, experts)`` choice masks ``MoE``
+    sows and ``live``, which tokens are real (a decode step's live rows, an
+    insert's prompt positions), in any shape of ``tokens`` elements."""
     chosen = jnp.concatenate([c.reshape(-1, *c.shape[-2:])
                               for c in jax.tree.leaves(stats)])
-    chosen = chosen & live[None, :, None]
+    chosen = chosen & live.reshape(-1)[None, :, None]
     return jnp.stack([jnp.sum(jnp.any(chosen, axis=1)), jnp.sum(chosen),
                       chosen.shape[0] * jnp.any(live)]).astype(jnp.int32)
 
@@ -186,6 +188,9 @@ class DecodeSession:
     # device-resident grammar pool (inference/grammar.py) — per SESSION,
     # same residency economics as the adapter pool
     grammars: Optional[Any] = None
+    # a model with experts: the last paged insert's routing sums, still on
+    # the device (CausalLM._paged_insert_programs); None for a dense model
+    insert_routing: Optional[jax.Array] = None
 
 
 class CausalLM:
@@ -745,9 +750,9 @@ class CausalLM:
         what the router chose for the rows that were live (active and not
         done) at each step — expert slots touched (experts with a live row),
         assignments (live rows x top-k x layers) and layer steps with a live
-        row. ``touched / (layer_steps x experts)`` is the share of the expert
-        weights a sparse read would have needed. A dense model's program is
-        unchanged.
+        row. The same rows are what the model is told is real (``live``), so
+        ``touched / (layer_steps x experts)`` is the share of the expert
+        weights the block read. A dense model's program is unchanged.
         """
         if steps < 1:
             raise ValueError(f"steps must be >= 1, got {steps}")
@@ -776,8 +781,11 @@ class CausalLM:
                     cache, tok, counts, lengths, done = carry
                 with jax.named_scope("sampler"):
                     sub = jax.vmap(jax.random.fold_in)(slot_keys, counts)
+                live = active & ~done if moe else None
                 logits, mut = self.model.apply(
                     self._ad_vars(params, cache, ad), tok,
+                    # dead rows choose no expert (moe/layer.py)
+                    **({"live": live[:, None]} if moe else {}),
                     mutable=["cache", "moe_stats"] if moe else ["cache"]
                 )
                 with jax.named_scope("sampler"):
@@ -790,8 +798,7 @@ class CausalLM:
                 with jax.named_scope("bookkeeping"):
                     done_before = done
                     if moe:
-                        mstats = mstats + _routing_sums(mut["moe_stats"],
-                                                        active & ~done)
+                        mstats = mstats + _routing_sums(mut["moe_stats"], live)
                     out = jnp.where(done | ~active, jnp.int32(pad_token_id),
                                     nxt)
                     done = done | (active & (eos_ids >= 0) & (nxt == eos_ids))
@@ -1034,11 +1041,19 @@ class CausalLM:
         separate scatter pass — the pool is global, so the prefill IS the
         scatter; the donated pool is the layer scan's carry, so only the
         rows written move), and (c) updates the session-width
-        cache_index/block_table rows at ``slots``."""
+        cache_index/block_table rows at ``slots``.
+
+        A model with experts (``self.moe_stats``) is told which tokens are
+        real (each row's ``new_len - starts`` suffix; the bucket's padding
+        chooses no expert) and returns one more value, LAST: ``(4,) int32``,
+        the three sums of the fused session decode taken over the real tokens
+        (expert slots touched, assignments, layers run) and the grouped rows
+        the experts ran, real or not (layers x rows x bucket x top_k)."""
         key = (rows, bucket)
         if key in self._paged_insert:
             return self._paged_insert[key]
         ppseq = self.config.max_seq_len // self.config.page_size
+        moe = self.moe_stats
 
         def insert_fn(params, cache, ids, tables, slots, starts, new_len,
                       *ad):
@@ -1054,9 +1069,21 @@ class CausalLM:
 
             with jax.named_scope("cache_rows"):
                 row_cache = jax.tree_util.tree_map_with_path(as_rows, cache)
+            # a row's own suffix is real, the bucket's padding is not
+            live = (jnp.arange(bucket)[None, :] < (new_len - starts)[:, None]
+                    if moe else None)
             logits, mut = self.model.apply(
                 self._ad_vars(params, row_cache, ad), ids,
-                mutable=["cache"])
+                **({"live": live} if moe else {}),
+                mutable=["cache", "moe_stats"] if moe else ["cache"])
+            sums = ()
+            if moe:
+                with jax.named_scope("bookkeeping"):
+                    grouped_rows = (self.config.num_layers * rows * bucket
+                                    * self.config.top_k)
+                    sums = self._replicate_out((jnp.concatenate([
+                        _routing_sums(mut["moe_stats"], live),
+                        jnp.full((1,), grouped_rows, jnp.int32)]),))
 
             def back(path, old, new):
                 p = jax.tree_util.keystr(path)
@@ -1080,9 +1107,9 @@ class CausalLM:
                 return new  # mutated pool leaves
 
             with jax.named_scope("table_write"):
-                return logits, self._shard_out(
+                return (logits, self._shard_out(
                     jax.tree_util.tree_map_with_path(back, cache,
-                                                     mut["cache"]))
+                                                     mut["cache"])), *sums)
 
         self._paged_insert[key] = self._time_compile(
             f"paged_insert_r{rows}_b{bucket}",
@@ -1212,10 +1239,11 @@ class CausalLM:
             if tables is None:
                 raise ValueError("paged extend needs per-row block tables")
             prog = self._paged_insert_programs(rows, bucket)
-            logits, cache = prog(
+            logits, cache, *sums = prog(
                 self.params, session.cache, jnp.asarray(ids),
                 jnp.asarray(tables, jnp.int32), jnp.asarray(slot_ids),
                 jnp.asarray(starts), jnp.asarray(new_len), *ad)
+            session.insert_routing = sums[0] if sums else None
         else:
             prog = self._chunk_extend_programs(rows, bucket)
             logits, cache = prog(
@@ -1269,7 +1297,7 @@ class CausalLM:
                            for i in range(rows)])
         try:
             prog = self._paged_insert_programs(rows, bucket)
-            logits, cache = prog(
+            logits, cache, *sums = prog(
                 self.params, session.cache, jnp.asarray(ids),
                 jnp.asarray(tables), jnp.asarray(slot_ids),
                 jnp.asarray(starts), jnp.asarray(lengths, np.int32),
@@ -1286,6 +1314,7 @@ class CausalLM:
                 pkv.rollback(p)
             raise
         session.cache = cache
+        session.insert_routing = sums[0] if sums else None
         for i in range(rows):
             pkv.commit(int(slot_ids[i]), plans[i],
                        prompt_ids[i, : lengths[i]].tolist(), ns=nss[i])

@@ -337,6 +337,13 @@ _STAT_KEYS = (
     # (models with experts; CausalLM.compile_session_decode_fused): expert
     # slots touched, assignments, layer steps, each summed over steps x layers
     "moe_experts_touched", "moe_assignments", "moe_layer_steps",
+    # the same three over the real tokens of the paged inserts (bucket
+    # padding chooses nothing; CausalLM._paged_insert_programs), and the
+    # grouped rows the inserts' experts ran, real or not: assignments / rows
+    # is the share that was real work, touched / layer_calls the experts an
+    # insert's layer read
+    "moe_insert_experts_touched", "moe_insert_assignments",
+    "moe_insert_layer_calls", "moe_insert_rows",
 )
 
 
@@ -1863,6 +1870,11 @@ class ServeEngine:
             first_dev = self.slot_sampler(
                 logits, sub, jnp.asarray(temps), jnp.asarray(greedy))
             first = None if defer else np.asarray(first_dev)
+        # the insert's routing sums (a model with experts, paged) come to the
+        # host where its first tokens do: here, or with the deferred record
+        routing = None if self._sim else self.session.insert_routing
+        if routing is not None and not defer:
+            self._count_insert_routing(np.asarray(routing))
         now = time.perf_counter()
         for i, (r, slot) in enumerate(zip(group, slot_ids)):
             r.start_block = self.blocks
@@ -1892,6 +1904,7 @@ class ServeEngine:
                 self._first_pending.append({
                     "slot": slot, "rid": r.request_id, "idx": i,
                     "fut": first_dev, "block": self.blocks,
+                    "routing": routing if i == 0 else None,
                     "val": None if first is None else int(first[i])})
                 if self._sim:
                     self._tok[slot] = int(first[i])
@@ -3582,6 +3595,12 @@ class ServeEngine:
                 touched / (layer_steps * self.lm.config.num_experts),
                 block=self.blocks if block is None else block)
 
+    def _count_insert_routing(self, sums) -> None:
+        """One paged insert's routing sums into ``stats``."""
+        for name, x in zip(("experts_touched", "assignments", "layer_calls",
+                            "rows"), sums):
+            self.stats["moe_insert_" + name] += int(x)
+
     def step_block(self) -> bool:
         """One scheduling round: drain recovery replays, admit (expire/shed
         first), spend the prefill-chunk budget, advance every active slot
@@ -4028,6 +4047,8 @@ class ServeEngine:
                 continue
             tok = (int(p["val"]) if p["fut"] is None
                    else int(np.asarray(p["fut"])[p["idx"]]))
+            if p.get("routing") is not None:
+                self._count_insert_routing(np.asarray(p["routing"]))
             slot = p["slot"]
             req = self.slots[slot]
             if req is None or req.request_id != p["rid"]:

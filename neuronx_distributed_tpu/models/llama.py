@@ -833,13 +833,16 @@ class _LayerStep(nn.Module):
     The carry is ``(x, kv)``: ``kv`` is None outside decode mode (nothing
     more is carried than the hidden states), else ``(layer index, KV
     leaves)``, which the block's attention updates through a
-    :class:`KVLayerView`."""
+    :class:`KVLayerView`. ``live`` (b, s) bool, where a serving program gives
+    it, tells a block with experts which tokens are real (``moe/layer.py``),
+    and ``stack`` is what ``LlamaModel.layer_stack`` hands every layer whole;
+    only a block that asked for them takes them."""
 
     config: LlamaConfig
     layer_cls: Any = None  # default LlamaDecoderLayer (set below)
 
     @nn.compact
-    def __call__(self, carry, rope, chunk_ctx=None):
+    def __call__(self, carry, rope, chunk_ctx=None, live=None, stack=None):
         cfg = self.config
         x, kv = carry
         cls = self.layer_cls or LlamaDecoderLayer
@@ -849,11 +852,14 @@ class _LayerStep(nn.Module):
         block = cls(cfg, name="block")
         # 2-arg layer variants (Mixtral) stay compatible
         args = (x, rope) if chunk_ctx is None else (x, rope, chunk_ctx)
+        kwargs = {k: v for k, v in (("live", live), ("stack", stack))
+                  if v is not None}
         if kv is None:
-            return (block(*args), None), None
+            return (block(*args, **kwargs), None), None
         layer, leaves = kv
         view = KVLayerView(layer, leaves)
-        return (block(*args, kv=view), (layer + 1, view.leaves)), None
+        return (block(*args, kv=view, **kwargs),
+                (layer + 1, view.leaves)), None
 
 
 class LlamaModel(nn.Module):
@@ -889,7 +895,8 @@ class LlamaModel(nn.Module):
         self.final_norm = cfg.make_norm()
 
     @nn.compact
-    def __call__(self, input_ids: jax.Array, chunk_ctx=None) -> jax.Array:
+    def __call__(self, input_ids: jax.Array, chunk_ctx=None,
+                 live=None) -> jax.Array:
         cfg = self.config
         if input_ids.shape[1] > cfg.max_seq_len:
             raise ValueError(
@@ -928,11 +935,26 @@ class LlamaModel(nn.Module):
                 for name, (shape, dtype) in kv_leaf_shapes(
                     cfg, input_ids.shape[0]).items()}
             kv = (jnp.int32(0), {n: p.value for n, p in pools.items()})
-        args = (rope,) if chunk_ctx is None else (rope, chunk_ctx)
+        stack = self.layer_stack() if cfg.decode else None
+        args = (rope, chunk_ctx, live, stack)
+        while args[-1] is None:     # dense models, training: (rope,) as ever
+            args = args[:-1]
         (x, kv), _ = self.layers((x, kv), *args)
         for name, pool in pools.items():
             pool.value = kv[1][name]
         return self.final_norm(x)
+
+    def layer_stack(self):
+        """Parameters of ALL layers that the block class wants whole in every
+        layer, beside the layer's own slice of them (its ``layer_stack``
+        picks them out of the stacked block parameters; ``MixtralDecoderLayer``:
+        the experts' weights, for a kernel that indexes ``[layer, expert]``).
+        None: nothing, as for every dense block."""
+        pick = getattr(self.layer_cls, "layer_stack", None)
+        if pick is None:
+            return None
+        params = nn.meta.unbox(self.layers.variables.get("params", {}))
+        return pick(params.get("block", {}))
 
     def attend(self, x: jax.Array) -> jax.Array:
         """Tied-embedding logits (``tie_word_embeddings``)."""
@@ -971,14 +993,16 @@ class LlamaForCausalLM(nn.Module):
             return self.model.attend(x)
         return self.lm_head(x)
 
-    def _hidden(self, input_ids: jax.Array) -> jax.Array:
-        x = self.model(input_ids)
+    def _hidden(self, input_ids: jax.Array, live=None) -> jax.Array:
+        x = self.model(input_ids, **({} if live is None else {"live": live}))
         if self.config.sequence_parallel:
             x = constrain(x, ACT_FULL)
         return x
 
-    def __call__(self, input_ids: jax.Array) -> jax.Array:
-        return self._head(self._hidden(input_ids))
+    def __call__(self, input_ids: jax.Array, live=None) -> jax.Array:
+        """``live`` (b, s) bool: which tokens are real, for a model with
+        experts under a serving program (``_LayerStep``); else None."""
+        return self._head(self._hidden(input_ids, live))
 
     def loss(self, input_ids: jax.Array, labels: jax.Array,
              ignore_index: int = -100) -> jax.Array:

@@ -5,23 +5,23 @@ Capability-parity with the reference's Mixtral support
 ``examples/inference/mixtral`` serving stack over ``modules/moe``): same
 GQA attention as Llama (reused directly — the reference subclasses its Llama
 attention too), each decoder layer's MLP replaced by the MoE block with
-top-k routing, load-balancing aux loss summed into the training loss, and
-token-generation inference dispatching to selective expert loading
-(``moe/expert_mlps.py``).
+top-k routing, load-balancing aux loss summed into the training loss.
 
-What the dispatch rule (``moe/layer.py``, ``T * top_k / E`` against
-``selective_loading_threshold`` 0.5) does by shape: Mixtral (8 experts,
-top-2) decodes all-experts from 2 rows up, and 4 rows touch every expert
-anyway; DBRX (16, top-4) likewise from 2 rows; OLMoE (``models/olmoe.py``: 64,
-top-8) decodes all-experts from 4 rows up, reading all 64 experts where 8 rows
-choose at most 64 and 3 about 21. Every prefill is all-experts: ``E / top_k``
-= 4 x (Mixtral, DBRX) or 8 x (OLMoE) the expert FLOPs the routing needs.
-ROADMAP S4 (one dropless grouped matmul) replaces the rule.
+Serving (``config.decode``) runs the experts as one dropless grouped matmul
+over the (token, expert) assignments sorted by expert, in prefill and in
+decode (``moe/layer.py``, ``moe/expert_mlps.py::forward_grouped``,
+``kernels/grouped_matmul.py``): Mixtral (8 experts, top-2), DBRX (16, top-4)
+and OLMoE (``models/olmoe.py``: 64, top-8) read the experts their real tokens
+chose and do ``top_k / E`` of the all-experts FLOPs. Which tokens are real
+comes down the stack as ``live`` (b, s): the serving programs of
+``inference/causal_lm.py`` know it (live decode rows, a prompt's own
+positions), and a token that is not real chooses nothing.
 """
 
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Mapping
 from typing import Any
 
 import jax
@@ -43,12 +43,11 @@ class MixtralConfig(LlamaConfig):
     num_experts: int = 8
     top_k: int = 2
     norm_topk_prob: bool = True  # HF's key; OLMoE: false (models/olmoe.py)
-    moe_mode: str = "capacity_factor"  # training/ctx: "capacity_factor" | "all_experts"
+    moe_mode: str = "capacity_factor"  # | "all_experts"; serving: moe/layer.py
     capacity_factor: float = 1.25
     router: str = "top_k"
     aux_loss_coef: float = 0.01
     z_loss_coef: float = 0.0
-    selective_loading_threshold: float = 0.5
     # DBRX serves through this stack with bias-free LayerNorms instead of
     # RMSNorm (HF DbrxBlock norm_1/norm_2/norm_f are nn.LayerNorm(bias=False))
     norm_type: str = "rmsnorm"  # | "layernorm"
@@ -80,7 +79,8 @@ class MixtralDecoderLayer(nn.Module):
     config: MixtralConfig
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope, kv=None) -> jax.Array:
+    def __call__(self, x: jax.Array, rope, kv=None, live=None,
+                 stack=None) -> jax.Array:
         cfg = self.config
         h = cfg.make_norm(name="input_norm")(x)
         x = x + LlamaAttention(cfg, name="attention")(h, rope, kv=kv)
@@ -100,10 +100,25 @@ class MixtralDecoderLayer(nn.Module):
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
             inference=cfg.decode,
-            selective_loading_threshold=cfg.selective_loading_threshold,
             name="moe",
-        )(h)
+        )(h, live, None if stack is None else (kv.layer, stack))
         return x + moe_out
+
+    @staticmethod
+    def layer_stack(block_params):
+        """The expert weights of ALL layers, ``(L, E, ...)`` each, out of the
+        stacked parameters of the scanned blocks (``LlamaModel.layer_stack``):
+        handed to every layer whole, beside its index, so that the grouped
+        kernel reads ``[layer, expert]`` blocks out of the stack. The layer's
+        own slice of it would reach a kernel as a copy of all ``E`` experts,
+        every layer of every step (measured on the v5e: 4.2 of 5.5 s of
+        OLMoE's decode blocks). None where there is nothing to hand over: at
+        init, or with int8 leaves (``ExpertMLPs`` then runs all_experts, whose
+        einsums read the slice in place)."""
+        experts = block_params.get("moe", {}).get("experts", {})
+        if not experts or any(isinstance(w, Mapping) for w in experts.values()):
+            return None
+        return dict(experts)
 
 
 class MixtralModel(LlamaModel):

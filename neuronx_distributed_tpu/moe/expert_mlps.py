@@ -16,28 +16,123 @@ TPU-native re-design (GShard/Switch dispatch algebra under GSPMD):
   all-to-all the reference issues by hand (``mappings.py:311-338``).
 * **all_experts mode**: every expert computes every token, outputs weighted
   by the combine matrix — no dropping, O(E) FLOPs, for small E or goldens.
-* **selective loading mode** (reference ``forward_selective_loading``,
-  expert_mlps.py:267): token generation has few tokens, so only the chosen
-  ``top_k`` experts' weights are gathered from HBM per token — the decode
-  step reads ``T*k`` expert weight slices instead of all ``E`` (HBM
-  bandwidth is the decode bottleneck). The reference's per-token Python loop
-  becomes one batched gather + einsum; the same
-  ``T*top_k/E < threshold`` dispatch rule picks selective vs all-experts
-  (expert_mlps.py:297 ``forward``'s inference branch).
+* **grouped mode** (serving, prefill and decode alike): the dropless form.
+  The ``top_k`` (token, expert) assignments are sorted by expert, the tokens'
+  activations gathered in that order, and gate/up/down run as ONE grouped
+  matmul each (``kernels/grouped_matmul.py``) that reads an expert's weights
+  only where some row chose it: a decode step moves the touched experts'
+  bytes, a prefill does ``top_k / E`` of the all-experts FLOPs. Tokens that
+  are not real (dead decode rows, bucket padding) choose nothing. The
+  reference's ``forward_selective_loading`` (expert_mlps.py:267, a per-token
+  copy of whole expert matrices) and its ``T*top_k/E`` threshold are what
+  this replaces.
 """
 
 from __future__ import annotations
 
+import functools
+from collections.abc import Mapping
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from neuronx_distributed_tpu.kernels.grouped_matmul import (
+    group_visits,
+    grouped_matmul,
+    row_tile,
+)
+from neuronx_distributed_tpu.kernels import mode as kernel_mode
 from neuronx_distributed_tpu.parallel.layers import default_kernel_init
 from neuronx_distributed_tpu.parallel.mesh import EP_AXIS, TP_AXIS
 from neuronx_distributed_tpu.parallel.partitioning import constrain
+
+
+def _silu_gated(gate: jax.Array, up: jax.Array) -> jax.Array:
+    return nn.silu(gate) * up
+
+
+def sort_by_expert(combine: jax.Array, top_k: int,
+                   live: Optional[jax.Array] = None):
+    """The ``top_k`` (token, expert) assignments of ``combine (T, E)`` sorted
+    by expert (a stable sort: by token within an expert), those of tokens
+    that are not ``live`` (T,) last and in no group. Returns ``weight (T, k)``,
+    ``order (M,)`` (row ``r`` of the sorted list is assignment ``order[r]``,
+    token ``order[r] // top_k``), ``place (M,)`` (its inverse) and
+    ``group_sizes (E,)``; ``M = T * top_k``.
+
+    A counting sort: an assignment's place is its expert's first row plus the
+    number of earlier tokens that chose that expert. ``lax.sort`` gives the
+    same permutation and the TPU compiler needs 14 s for it at an OLMoE
+    8 x 512 insert's 32 768 keys, in each of a cell's 16 insert programs."""
+    T, E = combine.shape
+    k, C = top_k, E + 1                # column E: no expert, a dead token's
+    weight, expert = lax.top_k(combine, k)                         # (T, k)
+    if live is not None:
+        expert = lax.select(lax.broadcast_in_dim(live, (T, k), (0,)), expert,
+                            lax.full_like(expert, E))
+    # (lax, not jnp, from here on: these few dozen equations are traced and
+    # lowered anew for every serving program, and a jnp call costs three)
+    zeros = lax.full((T, k, C), 0, jnp.int32)
+    chose = lax.eq(lax.broadcast_in_dim(expert, (T, k, C), (0, 1)),
+                   lax.broadcast_in_dim(np.arange(C, dtype=np.int32), (T, k, C), (2,)))
+    counts = lax.reduce_sum(lax.select(chose, lax.full_like(zeros, 1), zeros), (1,))
+    upto = lax.cumsum(counts, axis=0)                              # (T, C), with this token
+    sizes = lax.index_in_dim(upto, T - 1, 0, keepdims=False)
+    first_row = lax.sub(lax.cumsum(sizes, axis=0), sizes)
+    before = lax.add(lax.sub(upto, counts), lax.broadcast_in_dim(first_row, (T, C), (1,)))
+    place = lax.reduce_sum(
+        lax.select(chose, lax.broadcast_in_dim(before, (T, k, C), (0, 2)), zeros), (2,))
+    # a token's choices are distinct experts; those of a dead token are all
+    # column E, and keep their order within the token
+    place = lax.add(place, lax.select(
+        lax.eq(expert, np.int32(E)),
+        lax.broadcast_in_dim(np.arange(k, dtype=np.int32), (T, k), (1,)),
+        lax.full((T, k), 0, jnp.int32))).reshape(T * k)
+    order = jnp.zeros((T * k,), jnp.int32).at[place].set(
+        np.arange(T * k, dtype=np.int32),
+        mode="promise_in_bounds", unique_indices=True)
+    return weight, order, place, lax.slice_in_dim(sizes, 0, E)
+
+
+def token_class(tokens: int) -> int:
+    """The token count a grouped call is padded to: the next power of two, 8
+    at least. What is traced for one class serves every program of it."""
+    return max(8, 1 << (tokens - 1).bit_length())
+
+
+@functools.partial(jax.jit, static_argnames=("top_k", "glu", "dtype", "interpret"))
+def _grouped_experts(x, combine, live, layer, gate, up, down, *, top_k, glu,
+                     dtype, interpret):
+    """``ExpertMLPs.forward_grouped`` on a whole token class: ``x (T, H)``,
+    ``combine (T, E)``, ``live (T,)``, the weight stacks ``(L, E, ...)`` and
+    this layer's index. A function of the module, jitted, so that its trace
+    (some two hundred equations with the kernels' bodies, a second of Python
+    on a serving host) is kept by shape and dtype: a serving cell's 18
+    programs (one per insert shape) hold seven token classes between them."""
+    T, H = x.shape
+    weight, order, place, group_sizes = sort_by_expert(combine, top_k, live)
+    tm, rows = row_tile(T * top_k)
+    visits = group_visits(group_sizes, rows, tm)
+    xs = x.astype(dtype).at[jax.lax.div(order, np.int32(top_k))].get(  # (M, H)
+        mode="promise_in_bounds")
+    xs = jnp.pad(xs, ((0, rows - T * top_k), (0, 0)))      # to whole m tiles
+    # gate and up share the rows and meet in the kernel's last step, on its
+    # float32 sums: neither (M, I) product is written out
+    a = grouped_matmul(xs, (gate, up) if glu else (gate,), layer, visits, tm,
+                       _silu_gated if glu else nn.gelu, interpret=interpret)
+    out = grouped_matmul(a, (down,), layer, visits, tm, interpret=interpret)
+    # back to (token, choice) order; the rows of no group hold whatever the
+    # kernel's buffer held, so they are selected away, not scaled
+    out = out.at[place].get(mode="promise_in_bounds", unique_indices=True
+                            ).reshape(T, top_k, H)
+    out = jnp.where(live[:, None, None], out, 0)
+    return jnp.einsum("tkh,tk->th", out.astype(jnp.float32),
+                      weight.astype(jnp.float32))
 
 
 class ExpertMLPs(nn.Module):
@@ -48,7 +143,7 @@ class ExpertMLPs(nn.Module):
     intermediate_size: int
     glu: bool = True
     capacity_factor: float = 1.25
-    mode: str = "capacity_factor"  # "capacity_factor" | "all_experts"
+    mode: str = "capacity_factor"  # | "all_experts" | "grouped" (serving)
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
 
@@ -123,50 +218,49 @@ class ExpertMLPs(nn.Module):
         out = self._mlp(h)                                         # (E, T, H)
         return jnp.einsum("eth,te->th", out, combine.astype(out.dtype)).astype(x.dtype)
 
-    # --- selective loading (token-gen inference) -------------------------
+    # --- grouped (serving: dropless, only the chosen experts) -------------
 
-    def forward_selective(self, x: jax.Array, combine: jax.Array,
-                          top_k: int) -> jax.Array:
-        """Gather only the chosen experts' weights per token (reference
-        forward_selective_loading, expert_mlps.py:267-297 — its per-token
-        loop is a batched take+einsum here). ``combine`` must have exactly
-        ``top_k`` nonzeros per row (the router guarantees it); no tokens are
-        dropped, so the result equals all_experts exactly."""
-        in_dtype = x.dtype
-        aff, idx = jax.lax.top_k(combine, top_k)                   # (T, k)
-        x = x.astype(self.dtype)
+    def forward_grouped(self, x: jax.Array, combine: jax.Array, top_k: int,
+                        live: Optional[jax.Array] = None,
+                        stack=None) -> jax.Array:
+        """x: (T, H); combine: (T, E) with ``top_k`` nonzeros a row; ``live``
+        (T,) bool says which tokens are real (None: all). Every real
+        (token, expert) assignment is computed, none dropped, so the result
+        is all_experts' up to the order of additions; a token that is not
+        real comes out exactly zero and no expert is read on its behalf.
 
-        def take_expert(w):
-            # int8 serving: gather the INT8 rows (half the HBM gather
-            # traffic), dequantize only the gathered (T, k, ...) slice
-            from collections.abc import Mapping
-
-            if isinstance(w, Mapping) and "qweight" in w:
-                qw = jnp.take(w["qweight"], idx, axis=0)
-                sc = w["scale"]  # per-tensor scale is 0-d: no expert axis
-                sc = jnp.take(sc, idx, axis=0) if sc.ndim else sc
-                return (qw.astype(jnp.float32) * sc).astype(self.dtype)
-            return jnp.take(w, idx, axis=0).astype(self.dtype)
-
-        wg = take_expert(self.w_gate)                              # (T, k, H, I)
-        wd = take_expert(self.w_down)                              # (T, k, I, H)
-        g = jnp.einsum("th,tkhi->tki", x, wg)
-        if self.glu:
-            wu = take_expert(self.w_up)
-            a = nn.silu(g) * jnp.einsum("th,tkhi->tki", x, wu)
-        else:
-            a = nn.gelu(g)
-        out_k = jnp.einsum("tki,tkih->tkh", a, wd)                 # (T, k, H)
-        return jnp.einsum("tkh,tk->th", out_k, aff.astype(out_k.dtype)).astype(in_dtype)
+        ``stack = (layer, {"gate", "up", "down"})`` gives the weights of the
+        whole layer stack, ``(L, E, ...)`` each, and this layer's index: under
+        a layer scan this module's own weights are a slice of that stack,
+        which a kernel could only be handed as a copy of all ``E`` experts
+        (``models/mixtral.py::MixtralDecoderLayer.layer_stack``)."""
+        T, H = x.shape
+        if stack is None or stack[1]["gate"].dtype != self.dtype:
+            # (a stack kept in another dtype would be cast whole, every layer)
+            stack = (0, {"gate": self.w_gate.astype(self.dtype)[None],
+                         "down": self.w_down.astype(self.dtype)[None],
+                         "up": self.w_up.astype(self.dtype)[None] if self.glu else None})
+        layer, w = stack
+        pad = token_class(T) - T        # padding tokens are not live
+        live = jnp.ones((T,), bool) if live is None else live
+        out = _grouped_experts(
+            jnp.pad(x, ((0, pad), (0, 0))), jnp.pad(combine, ((0, pad), (0, 0))),
+            jnp.pad(live, (0, pad)), jnp.asarray(layer, jnp.int32),
+            w["gate"], w["up"], w["down"], top_k=top_k, glu=self.glu,
+            dtype=jnp.dtype(self.dtype), interpret=kernel_mode.interpret_kernels())
+        return out[:T].astype(x.dtype)
 
     def __call__(self, x: jax.Array, combine: jax.Array,
-                 top_k: Optional[int] = None) -> jax.Array:
-        if self.mode == "selective":
+                 top_k: Optional[int] = None,
+                 live: Optional[jax.Array] = None, stack=None) -> jax.Array:
+        # int8 leaves ({"qweight", "scale"}) keep all_experts, whose einsums
+        # fuse the dequantisation: no cell serves them
+        if self.mode == "grouped" and not isinstance(self.w_gate, Mapping):
             if top_k is None:
-                raise ValueError("selective mode needs the router's top_k")
-            return self.forward_selective(x, combine, top_k)
+                raise ValueError("grouped mode needs the router's top_k")
+            return self.forward_grouped(x, combine, top_k, live, stack)
         if self.mode == "capacity_factor":
             return self.forward_capacity_factor(x, combine)
-        if self.mode == "all_experts":
+        if self.mode in ("all_experts", "grouped"):
             return self.forward_all_experts(x, combine)
         raise ValueError(f"unknown expert mode {self.mode!r}")

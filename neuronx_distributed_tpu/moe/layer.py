@@ -8,6 +8,8 @@ The aux (load-balancing) loss is returned through a flax variable collection
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
@@ -37,21 +39,26 @@ class MoE(nn.Module):
     z_loss_coef: float = 0.0
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
-    # inference dispatch (reference expert_mlps.py:297 forward): token-gen
-    # steps (seq==1) use selective loading when T*top_k/E is below the
-    # threshold, else all_experts; context encoding keeps `mode`, and never
-    # drops (capacity_factor -> all_experts). At Mixtral's 8 experts top-2
-    # that is all-experts from 2 rows up, which costs nothing: 4 rows touch
-    # every expert. At OLMoE's 64 experts top-8 it is all-experts from 4 rows
-    # up too (4 * 8 / 64 = 0.5), where 8 rows choose at most 64 and about 3
-    # live rows about 21 of the 64 it reads; and every prefill does
-    # E / top_k = 8 x the expert FLOPs the routing needs (Mixtral: 4 x).
-    # ROADMAP S4 replaces the rule; `olmoe-1b-7b.chat` shows its cost.
+    # serving (prefill and decode alike) runs the experts as ONE dropless
+    # grouped matmul over the (token, expert) assignments sorted by expert
+    # (ExpertMLPs.forward_grouped): only the experts some real token chose
+    # are read, none of the assignments is dropped. It takes the place of
+    # `capacity_factor`, which would drop (a dropped assignment corrupts the
+    # KV cache for the whole generation); a `mode` of "all_experts" is kept
+    # as asked, the golden the grouped form is compared with. What the code
+    # can see decides the rest: on a mesh with ep > 1 (sorting along the
+    # sharded expert axis would all-gather the weights) or tp > 1 (the kernel
+    # is not partitioned) serving runs all_experts, as do int8 leaves.
     inference: bool = False
-    selective_loading_threshold: float = 0.5
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, live: Optional[jax.Array] = None,
+                 stack=None) -> jax.Array:
+        """``live`` (b, s) bool, serving only: which tokens are real (a live
+        decode row, a prompt's own positions). The rest choose no expert and
+        come out zero; None means all are real. ``stack``: the expert weights
+        of the whole layer stack and this layer's index, where the layers are
+        a scan (``ExpertMLPs.forward_grouped``)."""
         # exit SP: routing needs the full sequence (reference model.py:112-127)
         if self.sequence_parallel:
             x = constrain(x, ACT_FULL)
@@ -68,43 +75,29 @@ class MoE(nn.Module):
         else:
             raise ValueError(f"unknown router {self.router!r}")
         combine, logits = router(flat)
-        if self.inference and s == 1 and self.is_mutable_collection("moe_stats"):
-            # the (rows, experts) choices of a decode step, for the fused
-            # session decode's routing counter (inference/causal_lm.py)
+        if self.inference and self.is_mutable_collection("moe_stats"):
+            # the (tokens, experts) choices of the router, whatever `live`
+            # says, for the routing counters of the fused session decode and
+            # the paged insert (inference/causal_lm.py::_routing_sums)
             self.sow("moe_stats", "chosen", combine > 0)
 
         mode = self.mode
-        if self.inference:
+        if self.inference and mode == "capacity_factor":
             from neuronx_distributed_tpu.parallel import mesh as ps
 
-            ep = (ps.get_expert_model_parallel_size()
-                  if ps.model_parallel_is_initialized() else 1)
-            if s == 1:  # token generation (static shapes)
-                tokens = b * s
-                use_selective = (
-                    tokens * self.top_k / self.num_experts
-                    < self.selective_loading_threshold
-                    # selective gathers along the EP-sharded expert axis, which
-                    # GSPMD would service by all-gathering ALL expert weights —
-                    # defeating the point (the reference likewise excludes EP
-                    # from token-gen inference, SURVEY §2.3)
-                    and ep == 1
-                )
-                mode = "selective" if use_selective else "all_experts"
-            elif mode == "capacity_factor":
-                # context encoding must not drop tokens: a dropped assignment
-                # would corrupt the KV cache for the whole generation. The
-                # reference's serving configs run full capacity for the same
-                # reason (capacity_factor=None -> all_experts).
-                mode = "all_experts"
+            on_mesh = ps.model_parallel_is_initialized() and (
+                ps.get_expert_model_parallel_size() > 1
+                or ps.get_tensor_model_parallel_size() > 1)
+            mode = "all_experts" if on_mesh else "grouped"
         experts = ExpertMLPs(
             num_experts=self.num_experts, hidden_size=h,
             intermediate_size=self.intermediate_size, glu=self.glu,
             capacity_factor=self.capacity_factor, mode=mode,
             dtype=self.dtype, param_dtype=self.param_dtype, name="experts",
         )
-        out = experts(flat, combine.astype(flat.dtype),
-                      top_k=self.top_k).reshape(b, s, h)
+        out = experts(flat, combine.astype(flat.dtype), top_k=self.top_k,
+                      live=None if live is None else live.reshape(b * s),
+                      stack=stack).reshape(b, s, h)
 
         aux = self.aux_loss_coef * load_balancing_loss(logits, combine, self.num_experts)
         if self.z_loss_coef:

@@ -167,54 +167,78 @@ def test_gather_decode_step_holds_no_widened_slab(chip):
     assert "bf16[1," + pool not in compiled.as_text()
 
 
-def test_olmoe_decode_step_and_insert_hold_the_all_experts_temporaries(chip):
-    """Two scanned layers at OLMoE-1B-7B's published widths (hidden 2048,
-    16 x 128 MHA with QK-norm, 64 experts of 1024, top-8, vocab 50304), bf16,
-    pages of 16, batch 8, ``max_seq_len`` 1024: the single-token decode step
-    and the model's part of an 8 x 512 paged insert. ``MoE`` runs every
-    expert in both (``moe/layer.py``: 8 x 8 / 64 = 1.0 is not under the 0.5
-    threshold; a prefill never drops), so the insert holds the
-    ``(64, 4096, 1024)`` gated activations of all 64 experts (512 MiB in
-    bf16) where the routing needs an eighth of them; the compiler fuses gate,
-    up and silu into that one array and the ``(64, 4096, 2048)`` expert
-    outputs (1 GiB) into the weighted sum, so they are never held. Recorded
-    here (temporaries by ``memory_analysis()``): decode step 1 MiB, insert
-    514 MiB (323 and 660 MiB while the page pools rode the layer scan as its
-    xs/ys and were copied once). The bounds keep a change that holds more (the expert outputs, or a float32
-    copy of the activations) from passing unseen; ROADMAP S4's grouped
-    dispatch should take the insert under the activations' 512 MiB."""
+def _moe_config(family, **kw):
+    from neuronx_distributed_tpu.models.mixtral import MixtralForCausalLM, mixtral_8x7b
     from neuronx_distributed_tpu.models.olmoe import OlmoeForCausalLM, olmoe_1b_7b
 
+    preset, cls = {"olmoe": (olmoe_1b_7b, OlmoeForCausalLM),
+                   "mixtral": (mixtral_8x7b, MixtralForCausalLM)}[family]
+    return preset(**kw), cls
+
+
+@pytest.mark.parametrize("width", [1, 512], ids=["decode_step", "insert_8x512"])
+@pytest.mark.parametrize("family", ["olmoe", "mixtral"])
+def test_moe_serving_runs_the_grouped_kernel_and_holds_less(chip, family, width):
+    """Two scanned layers at OLMoE-1B-7B's (hidden 2048, 16 x 128 MHA with
+    QK-norm, 64 experts of 1024, top-8) and Mixtral-8x7B's (hidden 4096, 32/8
+    GQA, 8 experts of 14336, top-2) published widths, bf16, pages of 16, batch
+    8, ``max_seq_len`` 1024: the decode step and the model's part of an
+    8 x 512 paged insert, told which tokens are real (``live``) as the
+    serving programs tell them. Serving runs the experts as the grouped
+    matmul (``kernels/grouped_matmul.py``: gate, up and the activation are
+    one Mosaic call, down another), so (a) nothing in the program has the all-experts shape
+    ``(E, tokens, I)`` (OLMoE's insert held 512 MiB of it, Mixtral's 0.94
+    GB); (b) nothing has the shape of ONE layer's expert weights either: the
+    kernel is handed the whole stack and indexes ``[layer, expert]``
+    (``MixtralModel.layer_stack``), where a layer's slice would be copied
+    out, all experts of it, at every layer of every step; (c) the
+    temporaries are no larger than those of the same program with
+    ``moe_mode="all_experts"``, which is what every serving program ran
+    before PR 29 (MiB, grouped / all_experts: printed below)."""
     b, s_max, page = 8, 1024, 16
-    cfg = dataclasses.replace(
-        olmoe_1b_7b(num_layers=2, max_seq_len=s_max, dtype=jnp.bfloat16,
-                    param_dtype=jnp.bfloat16),
-        decode=True, remat_policy=None, page_size=page,
-        page_pool_pages=b * s_max // page + b)
-    model = OlmoeForCausalLM(cfg)
-    variables = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
-        meta.unbox(jax.eval_shape(
-            lambda: model.init(jax.random.key(0), jnp.zeros((b, 1), jnp.int32)))))
-    assert variables["params"]["model"]["layers"]["block"]["attention"]["q_norm"].shape == (2, 2048)
+    temporaries = {}
+    for moe_mode in ("capacity_factor", "all_experts"):
+        preset, cls = _moe_config(
+            family, num_layers=2, max_seq_len=s_max, dtype=jnp.bfloat16,
+            param_dtype=jnp.bfloat16, moe_mode=moe_mode)
+        cfg = dataclasses.replace(preset, decode=True, remat_policy=None,
+                                  page_size=page,
+                                  page_pool_pages=b * s_max // page + b)
+        model = cls(cfg)
+        variables = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+            meta.unbox(jax.eval_shape(lambda: model.init(
+                jax.random.key(0), jnp.zeros((b, 1), jnp.int32)))))
 
-    def step(params, cache, ids):
-        return model.apply({"params": params, "cache": cache}, ids,
-                           mutable=["cache"])
+        def step(params, cache, ids, live):
+            return model.apply({"params": params, "cache": cache}, ids,
+                               live=live, mutable=["cache"])
 
-    def temporaries(width):
         compiled = jax.jit(step, donate_argnums=(1,)).lower(
             variables["params"], variables["cache"],
-            jax.ShapeDtypeStruct((b, width), jnp.int32, sharding=chip)).compile()
-        assert "qk_norm" in compiled.as_text()
-        return compiled.memory_analysis().temp_size_in_bytes
-
+            jax.ShapeDtypeStruct((b, width), jnp.int32, sharding=chip),
+            jax.ShapeDtypeStruct((b, width), jnp.bool_, sharding=chip)).compile()
+        text = compiled.as_text()
+        if family == "olmoe":
+            assert "qk_norm" in text
+        all_experts_shape = f"[{cfg.num_experts},{b * width},{cfg.intermediate_size}]"
+        if moe_mode == "all_experts":
+            assert all_experts_shape in text        # the check below can see it
+        else:
+            assert text.count("tpu_custom_call") >= 2     # gate + up + act, down
+            assert all_experts_shape not in text
+            for weights in ((cfg.hidden_size, cfg.intermediate_size),
+                            (cfg.intermediate_size, cfg.hidden_size)):
+                assert "[{},{},{}]".format(cfg.num_experts, *weights) not in text
+        temporaries[moe_mode] = compiled.memory_analysis().temp_size_in_bytes
     mib = 2 ** 20
-    decode, insert = temporaries(1), temporaries(512)
-    print(f"olmoe temporaries: decode {decode / mib:.0f} MiB, insert {insert / mib:.0f} MiB")
-    activations = 64 * b * 512 * 1024 * 2
-    assert decode < 64 * mib
-    assert activations < insert < activations + 128 * mib
+    print(f"{family} width {width} temporaries: grouped "
+          f"{temporaries['capacity_factor'] / mib:.0f} MiB, all_experts "
+          f"{temporaries['all_experts'] / mib:.0f} MiB")
+    assert temporaries["capacity_factor"] <= temporaries["all_experts"] + mib
+    if width > 1:   # less than ONE array of the all-experts activations
+        assert temporaries["capacity_factor"] < (
+            cfg.num_experts * b * width * cfg.intermediate_size * 2)
 
 
 def test_fused_adamw_leaf(chip):

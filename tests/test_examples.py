@@ -813,7 +813,7 @@ def test_inference_runner_medusa_tiny(capsys):
 
 def test_inference_runner_mixtral_tiny(capsys):
     """MoE serving through the shared runner (reference run_mixtral.py):
-    decode steps hit the selective-loading expert path."""
+    prefill and decode steps run the grouped expert path."""
     import runner
 
     runner.main(["generate", "--tiny", "--model", "mixtral",

@@ -492,11 +492,12 @@ def test_session_overflow_guard():
     lm.step(s2, cur)  # fresh session: no overflow
 
 
-def test_moe_selective_decode_matches_all_experts():
-    """VERDICT r2 weak #4: the MoE decode path (selective expert loading)
-    must generate EXACTLY what all-experts mode generates — selective gathers
-    the same top-k experts' weights, so no numerics may drift across the
-    whole KV-cached generation."""
+def test_moe_grouped_decode_matches_all_experts():
+    """VERDICT r2 weak #4: the MoE serving path (one grouped matmul over the
+    assignments sorted by expert, prefill and decode) must generate what
+    all-experts mode generates: the same products of the same top-k experts,
+    added in another order, so a greedy token may differ only where the two
+    leading logits are within the summation noise of each other."""
     from flax.core import meta
 
     from neuronx_distributed_tpu.inference import CausalLM
@@ -508,19 +509,25 @@ def test_moe_selective_decode_matches_all_experts():
                 top_k=2, remat_policy=None)
     ids = np.asarray(jax.random.randint(jax.random.PRNGKey(3), (1, 8), 1, 127),
                      np.int32)
-    # T*k/E for single-token decode = 1*2/4 = 0.5: threshold 1.5 -> selective,
-    # threshold 0.0 -> all_experts
-    cfg_sel = MixtralConfig(**base, selective_loading_threshold=1.5)
-    cfg_all = MixtralConfig(**base, selective_loading_threshold=0.0)
-    model = MixtralForCausalLM(cfg_sel)
+    # serving turns the default (capacity_factor, which would drop) into the
+    # grouped form; "all_experts" is kept as asked (moe/layer.py)
+    cfg_grouped = MixtralConfig(**base)
+    cfg_all = MixtralConfig(**base, moe_mode="all_experts")
+    model = MixtralForCausalLM(cfg_grouped)
     params = meta.unbox(model.init(jax.random.PRNGKey(0), jnp.asarray(ids)))["params"]
 
-    toks = {}
-    for name, cfg in (("selective", cfg_sel), ("all_experts", cfg_all)):
+    toks, logits = {}, {}
+    for name, cfg in (("grouped", cfg_grouped), ("all_experts", cfg_all)):
         lm = CausalLM(cfg, params, MixtralForCausalLM, buckets=(8,), max_batch=1)
         out = lm.generate(ids, max_new_tokens=10)
         toks[name] = np.asarray(out.tokens[0][: int(out.lengths[0])])
-    np.testing.assert_array_equal(toks["selective"], toks["all_experts"])
+        session = lm.start_session()
+        logits[name] = np.asarray(lm.insert(session, [0], ids), np.float32)
+    # float32 against float32: 1e-5 of the largest logit, and the greedy
+    # tokens equal (no two leading logits of this stream are that close)
+    assert (np.abs(logits["grouped"] - logits["all_experts"]).max()
+            <= 1e-5 * np.abs(logits["all_experts"]).max())
+    np.testing.assert_array_equal(toks["grouped"], toks["all_experts"])
 
 
 def test_fused_decode_matches_stepwise():

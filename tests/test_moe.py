@@ -188,35 +188,34 @@ def test_mixtral_train_step_with_aux_loss():
     assert losses[-1] < losses[0]
 
 
-def test_selective_loading_matches_all_experts_exactly():
-    """Token-gen (seq=1) with T*top_k/E below threshold dispatches to
-    selective loading; no dropping occurs, so output must equal all_experts
-    bit-for-bit (reference forward dispatch, expert_mlps.py:297)."""
+def test_grouped_matches_all_experts_in_a_decode_step():
+    """A serving decode step (seq=1) runs the grouped form; nothing is
+    dropped, so it equals all_experts up to the order of additions."""
     from flax.core import meta
 
     from neuronx_distributed_tpu.models.mixtral import MixtralForCausalLM
 
     tok = jax.random.randint(jax.random.PRNGKey(0), (2, 1), 0, 127)
-    cfg_sel = _mixtral_cfg(decode=True, selective_loading_threshold=1.5)
-    cfg_all = _mixtral_cfg(decode=True, selective_loading_threshold=0.0)
-    ms, ma = MixtralForCausalLM(cfg_sel), MixtralForCausalLM(cfg_all)
-    variables = ms.init(jax.random.PRNGKey(0), tok)
+    cfg_grp = _mixtral_cfg(decode=True)
+    cfg_all = _mixtral_cfg(decode=True, moe_mode="all_experts")
+    mg, ma = MixtralForCausalLM(cfg_grp), MixtralForCausalLM(cfg_all)
+    variables = mg.init(jax.random.PRNGKey(0), tok)
     params = meta.unbox(variables)["params"]
     cache = meta.unbox(variables)["cache"]
-    o_s, _ = ms.apply({"params": params, "cache": cache}, tok, mutable=["cache"])
+    o_g, _ = mg.apply({"params": params, "cache": cache}, tok, mutable=["cache"])
     o_a, _ = ma.apply({"params": params, "cache": cache}, tok, mutable=["cache"])
-    np.testing.assert_allclose(np.asarray(o_s), np.asarray(o_a), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(o_g), np.asarray(o_a), rtol=1e-5, atol=1e-6)
 
 
 def test_mixtral_generate():
-    """KV-cached generation through the CausalLM serving stack (token-gen
-    decode steps hit the selective-loading path)."""
+    """KV-cached generation through the CausalLM serving stack (prefill and
+    token-gen decode steps run the grouped expert path)."""
     from flax.core import meta
 
     from neuronx_distributed_tpu.inference import CausalLM
     from neuronx_distributed_tpu.models.mixtral import MixtralForCausalLM
 
-    cfg = _mixtral_cfg(selective_loading_threshold=1.5)
+    cfg = _mixtral_cfg()
     ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 1, 127)
     model = MixtralForCausalLM(cfg)
     params = meta.unbox(model.init(jax.random.PRNGKey(0), ids))["params"]
@@ -224,6 +223,84 @@ def test_mixtral_generate():
     result = lm.generate(np.asarray(ids), max_new_tokens=4)
     assert result.tokens.shape == (1, 4)
     assert (result.lengths == 4).all()
+
+
+# --- the grouped (serving) form against all_experts ------------------------
+
+def _serving_moe(E, k, glu, norm, mode, dtype):
+    from neuronx_distributed_tpu.moe import MoE
+
+    return MoE(num_experts=E, hidden_size=32, intermediate_size=64, top_k=k,
+               norm_topk_prob=norm, glu=glu, mode=mode, inference=True,
+               dtype=dtype, param_dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all_real", "masked"])
+@pytest.mark.parametrize("shape", [(8, 1), (2, 512)], ids=["T8", "T2x512"])
+@pytest.mark.parametrize("norm", [True, False], ids=["renorm", "as_is"])
+@pytest.mark.parametrize("glu", [True, False], ids=["glu", "gelu"])
+@pytest.mark.parametrize("E,k", [(8, 2), (64, 8), (4, 1)])
+def test_grouped_equals_all_experts(E, k, glu, norm, shape, masked):
+    """Serving's grouped matmul (the Pallas kernel, interpreted here) against
+    the all_experts golden on the same parameters: bf16 operands, float32
+    accumulation, the same products. The golden rounds gate and up to bf16
+    before the activation and its output before the weighted sum, where the
+    kernel keeps float32 up to its one store, so they agree within two bf16
+    roundings (2 ** -6 of the largest output); a token that is not real
+    comes out exactly zero, and finite. Two experts get a router column
+    that no token can choose, so groups of no rows are always among them."""
+    b, s = shape
+    x = jax.random.normal(jax.random.PRNGKey(E + s), (b, s, 32), jnp.float32)
+    live = (jax.random.uniform(jax.random.PRNGKey(7), (b, s)) < 0.6) if masked else None
+    grouped = _serving_moe(E, k, glu, norm, "capacity_factor", jnp.bfloat16)
+    golden = _serving_moe(E, k, glu, norm, "all_experts", jnp.bfloat16)
+    params = golden.init(jax.random.PRNGKey(1), x)["params"]
+    kernel = params["router"]["kernel"]
+    params["router"]["kernel"] = kernel.at[:, 1].set(-10.0 * jnp.abs(kernel[:, 1])
+                                                     ).at[:, E - 1].set(kernel[:, 0] - 1.0)
+    want = golden.apply({"params": params}, x)
+    got = jax.jit(lambda p, x, live: grouped.apply({"params": p}, x, live))(params, x, live)
+    assert got.dtype == want.dtype and np.isfinite(np.asarray(got, np.float32)).all()
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    real = np.ones((b, s), bool) if live is None else np.asarray(live)
+    assert np.abs(got - want)[real].max() <= 2 ** -6 * np.abs(want).max()
+    assert (got[~real] == 0).all()
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all_real", "masked"])
+@pytest.mark.parametrize("E,k,T", [(8, 2, 8), (64, 8, 8), (4, 1, 1024), (8, 2, 1000)])
+def test_group_sizes_sum_to_the_live_assignments(E, k, T, masked):
+    """The sort that feeds the kernel: group sizes count the real tokens'
+    choices and nothing else, the order lists each expert's rows together,
+    and the visits cover every row of a group exactly once."""
+    from neuronx_distributed_tpu.kernels.grouped_matmul import group_visits, row_tile
+    from neuronx_distributed_tpu.moe.expert_mlps import sort_by_expert
+
+    rs = np.random.RandomState(T + E)
+    chosen = np.stack([rs.choice(E, k, replace=False) for _ in range(T)])
+    combine = np.zeros((T, E), np.float32)
+    np.put_along_axis(combine, chosen, rs.uniform(0.1, 1.0, (T, k)), axis=1)
+    live = rs.uniform(size=T) < 0.5 if masked else np.ones(T, bool)
+    _, order, place, sizes = sort_by_expert(
+        jnp.asarray(combine), k, jnp.asarray(live) if masked else None)
+    order, place, sizes = np.asarray(order), np.asarray(place), np.asarray(sizes)
+    assert sizes.sum() == live.sum() * k
+    np.testing.assert_array_equal(sizes, (combine[live] > 0).sum(0))
+    np.testing.assert_array_equal(order[place], np.arange(T * k))
+    ends = np.cumsum(sizes)
+    for e in range(E):                       # group e's rows chose expert e
+        toks = order[ends[e] - sizes[e]: ends[e]] // k
+        assert live[toks].all() and (combine[toks, e] > 0).all()
+    tm, rows = row_tile(T * k)
+    visits = jax.tree.map(np.asarray, group_visits(jnp.asarray(sizes), rows, tm))
+    covered = np.zeros(rows, int)
+    for v in range(int(visits.count)):
+        g, t = visits.group[v], visits.tile[v]
+        lo, hi = max(visits.offsets[g], t * tm), min(visits.offsets[g + 1], (t + 1) * tm)
+        assert hi > lo                       # no visit without a row
+        covered[lo:hi] += 1
+    assert (covered[: sizes.sum()] == 1).all() and (covered[sizes.sum():] == 0).all()
+    assert (np.diff(visits.tile[: int(visits.count)]) >= 0).all()  # revisits are consecutive
 
 
 def test_ep_sharded_checkpoint_roundtrip(tmp_path):

@@ -216,8 +216,8 @@ def test_int8_direct_in_layer_dequant():
 def test_int8_moe_expert_quantization():
     """MoE int8 serving: the fused expert tensors (leaves gate/up/down)
     quantize by default, the router stays float (routing is the most
-    quantization-sensitive op), and both selective-loading and all-experts
-    decode paths consume the quantized tree directly."""
+    quantization-sensitive op), and the all-experts path (which serving keeps
+    for quantized leaves) consumes the quantized tree directly."""
     from flax.core import meta
 
     from neuronx_distributed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM

@@ -38,7 +38,8 @@ SCOPES = {
     "trainer/step.py": ["grad_accumulate", "grad_clip", "optimizer_update"],
     "parallel/loss.py": ["loss"],
 }
-KERNELS = ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode", "fused_adamw"]
+KERNELS = ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode", "fused_adamw",
+           "grouped_matmul"]
 
 TINY = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2, num_heads=4,
             num_kv_heads=2, kv_size_multiplier=1, max_seq_len=256, dtype=jnp.float32,
@@ -93,11 +94,14 @@ def test_fused_decode_names_its_regions(params, kernel):
     assert unnamed_share(parts) < 0.2, parts
 
 
-def test_olmoe_decode_names_qk_norm_router_and_experts():
-    """The tiny OLMoE decode step carries ``qk_norm`` (the one scope PR 26
-    added) and flax's own ``moe/router`` and ``moe/experts``, which
-    ``scope_parts.json`` already has rows for; a dense model's step carries
-    none of the three."""
+@pytest.mark.parametrize("program", ["fused_decode", "paged_insert"])
+def test_olmoe_names_qk_norm_router_and_experts(program):
+    """The tiny OLMoE decode block and paged insert carry ``qk_norm`` (the one
+    scope PR 26 added) and flax's own ``moe/router`` and ``moe/experts``,
+    which ``scope_parts.json`` has rows for; the grouped matmul (PR 29: the
+    kernel and the sort, gathers and weighted sum around it) is called inside
+    ``ExpertMLPs``, so all of it is under ``moe/experts`` and none of it
+    without a part. A dense model's step carries none of the three."""
     from neuronx_distributed_tpu.models.olmoe import OlmoeConfig, OlmoeForCausalLM
 
     cfg = OlmoeConfig(**dict(TINY, num_kv_heads=4, intermediate_size=32, num_experts=16,
@@ -105,9 +109,15 @@ def test_olmoe_decode_names_qk_norm_router_and_experts():
     olmoe = meta.unbox(OlmoeForCausalLM(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
     lm = CausalLM(cfg, olmoe, OlmoeForCausalLM, buckets=(128,), max_batch=2, page_size=16)
-    components, parts = census(lm.compile_session_decode_fused(4))
-    assert {"qk_norm", "kv_write", "kv_gather", "attend"} <= components
+    compiled = (lm.compile_session_decode_fused(4) if program == "fused_decode"
+                else lm._paged_insert_programs(2, 128))
+    components, parts = census(compiled)
+    assert {"qk_norm", "kv_write", "kv_gather", "attend", "grouped_matmul"} <= components
     assert parts["attention"] > 0 and parts["router"] > 0 and parts["experts"] > 0
+    for line in compiled.as_text().splitlines():
+        m = OP_NAME.search(line)
+        if m and "grouped_matmul" in m.group(1):
+            assert trace_parts.part_of({"tf_op": m.group(1) + ":"}, TABLE) == "experts", line
     assert unnamed_share(parts) < 0.2, parts
 
 

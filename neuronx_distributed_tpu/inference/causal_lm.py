@@ -169,6 +169,23 @@ def percentile_ms(ts, q) -> Optional[float]:
 
 
 @dataclasses.dataclass
+class FirstToken:
+    """What an insert program samples each row's first token with
+    (``CausalLM.insert(first=...)``): row ``i`` draws token index 0 of its
+    request's stream, under ``fold_in(fold_in(rng, request_ids[i]), 0)``, and
+    ``fold_in(rng, request_ids[i])`` becomes its slot's entry of the
+    session's ``slot_keys``. Host arrays: they ride the program's call."""
+
+    rng: jax.Array                  # the scheduler's base key
+    request_ids: np.ndarray         # (rows,) uint32
+    temperature: np.ndarray         # (rows,) float32
+    greedy: np.ndarray              # (rows,) bool
+    sampler: SlotSampler = SlotSampler()
+    # (rows, vocab) bool support mask of a grammar lm's rows (None: all True)
+    allowed: Optional[np.ndarray] = None
+
+
+@dataclasses.dataclass
 class DecodeSession:
     """Continuous-batching session: the KV cache plus host-side per-slot
     accounting (so the overflow guard travels with the session — multiple
@@ -191,6 +208,12 @@ class DecodeSession:
     # a model with experts: the last paged insert's routing sums, still on
     # the device (CausalLM._paged_insert_programs); None for a dense model
     insert_routing: Optional[jax.Array] = None
+    # (max_batch,) typed keys, one request key a slot: an insert program
+    # writes its rows' entries (donated in, like the cache), the fused
+    # session decode samples row j's token t under fold_in(slot_keys[j], t)
+    slot_keys: Optional[jax.Array] = None
+    # the last insert's sampled first tokens, (rows,) int32, on the device
+    first_tokens: Optional[jax.Array] = None
 
 
 class CausalLM:
@@ -314,6 +337,7 @@ class CausalLM:
                     f"vocab_size {config.vocab_size}")
             self.grammar_tokens = tuple(grammar_tokens)
         self._adapter_avals_cache: Optional[PyTree] = None
+        self._cache_avals_cache: Optional[PyTree] = None
         self._identity_adapters_cache: Optional[PyTree] = None
         self._identity_grammars_cache: Optional[PyTree] = None
         self.params = params
@@ -332,9 +356,9 @@ class CausalLM:
         self._decode = None
         self._decode_fused = {}
         self._session_fused = {}
-        self._insert_prefill = {}   # (rows, bucket) -> right-sized prefill
-        self._insert_scatter = {}   # rows -> donated row-scatter program
+        self._slab_insert = {}      # (rows, bucket) -> donated slab insert
         self._paged_insert = {}     # (rows, bucket) -> donated paged insert
+        self._default_first_rng: Optional[jax.Array] = None
         self._chunk_extend = {}     # (rows, bucket) -> donated chunk-prefill extend
         # observability: wall time of every AOT lower+compile, keyed by a
         # stable program signature ("session_fused_k8", "insert_r2_b128",
@@ -457,7 +481,7 @@ class CausalLM:
         if not self.lora:
             return ()
         tree = pool.tree if pool is not None else self._identity_adapters()
-        return (tree, jnp.asarray(np.asarray(idx, np.int32)))
+        return (tree, np.asarray(idx, np.int32))
 
     # --- structured-decoding plumbing ------------------------------------
     # Grammar-enabled session programs take a trailing ``*gr`` quad —
@@ -668,7 +692,11 @@ class CausalLM:
         (tp-sharded KV heads, replicated control leaves): left unannotated,
         GSPMD may assign the compiled program arbitrary cache input layouts
         (observed: batch over 'edp' whenever max_batch divides it), which
-        then reject the session cache at call time."""
+        then reject the session cache at call time. Computed once: it traces
+        the whole model, and every program's lowering and every session asks
+        for it (a serving cell builds 8 to 16 insert programs)."""
+        if self._cache_avals_cache is not None:
+            return self._cache_avals_cache
         ids0 = jnp.zeros((self.max_batch, self.buckets[0]), jnp.int32)
 
         def prefill_shape(params, ids):
@@ -680,7 +708,8 @@ class CausalLM:
             return mut["cache"]
 
         avals = jax.eval_shape(prefill_shape, self.params, ids0)
-        return shard_avals(avals)
+        self._cache_avals_cache = shard_avals(avals)
+        return self._cache_avals_cache
 
     def compile_session_decode_fused(self, steps: int,
                                      slot_sampler: Optional[SlotSampler] = None,
@@ -947,6 +976,8 @@ class CausalLM:
             cache=zeros_like_avals(cache),
             lengths=np.zeros((self.max_batch,), np.int64),
             active=np.zeros((self.max_batch,), bool),
+            slot_keys=repl_args(
+                jax.random.split(jax.random.key(0), self.max_batch))[0],
         )
         if self.paged:
             session.paged = PagedKVCache(
@@ -972,49 +1003,123 @@ class CausalLM:
                 f"slot ids {slot_ids.tolist()} out of range [0, {self.max_batch})"
             )
 
-    def _insert_programs(self, rows: int, bucket: int):
-        """Lazily compile the RIGHT-SIZED insert pair for ``rows`` inserted
-        prompts: a prefill at batch width ``rows`` (prefill FLOPs scale with
-        what was actually inserted, not ``max_batch``) and a donated
-        row-scatter into the session cache (O(rows) HBM traffic — the
-        full-cache ``jnp.where`` merge it replaces copies every cache byte
-        per insert)."""
-        pkey = (rows, bucket)
-        if pkey not in self._insert_prefill:
-            if rows == self.max_batch and bucket in self._prefill:
-                self._insert_prefill[pkey] = self._prefill[bucket]
-            else:
-                def prefill_fn(params, ids, *ad):
-                    logits, mut = self.model.apply(
-                        self._ad_vars(params, None, ad), ids,
-                        mutable=["cache"])
-                    # boundary pin like every cache-returning program:
-                    # the scatter's own constraint used to be the only
-                    # cover here, but these fresh rows ARE cache avals
-                    # crossing a program boundary (no-op off-mesh, and
-                    # the reshard is O(rows) either way)
-                    return logits, self._shard_out(mut["cache"])
+    # --- the first token, inside the insert program -----------------------
+    # Both insert programs end the same way (_first_token): the head ran
+    # over each row's last real position only, the rows' request keys
+    # fold_in(rng, request_id) go into the session's slot_keys, and token
+    # index 0 of each request's stream is sampled under fold_in(key, 0).
+    # The scheduler's insert is then ONE program call and ONE fetch. What
+    # the sampler needs rides the call as one dict of host arrays
+    # (FirstToken), with a (rows, vocab) support mask only in a grammar lm.
 
-                ids0 = jnp.zeros((rows, bucket), jnp.int32)
-                self._insert_prefill[pkey] = self._time_compile(
-                    f"insert_prefill_r{rows}_b{bucket}",
-                    lambda: jax.jit(prefill_fn)
-                    .lower(self.params, ids0, *self._ad_lower(rows))
-                    .compile())
-        if rows not in self._insert_scatter:
-            # pin the scatter OUTPUT to the serving specs: a plain jit
-            # would let GSPMD propagate whatever layout the scatter math
-            # prefers onto the session cache — which the AOT-compiled
-            # session programs (lowered on the serving-spec cache avals)
-            # then reject at their next call. The constraint reshards
-            # only the inserted rows (O(rows)), keeping the insert contract.
-            constrain = self._shard_out
-            self._insert_scatter[rows] = jax.jit(
-                lambda old, fresh, slots, new_len: constrain(
-                    _scatter_cache_rows(old, fresh, slots, new_len, rows)),
-                donate_argnums=(0,),
-            )
-        return self._insert_prefill[pkey], self._insert_scatter[rows]
+    def _first_token(self, logits, slot_keys, slots, first, sampler):
+        """Traced tail of an insert program: ``(first tokens (rows,) int32,
+        slot_keys with the rows' request keys at slots)``, replicated. The
+        derivation and the sampler are the eager ones the scheduler ran on
+        the host before (so is every stream), under scope ``sampler``."""
+        with jax.named_scope("sampler"):
+            # both derivations at one shape: the second reuses the first's
+            # trace and lowering (set-up time is Python time; an unrolled
+            # threefry is ~250 lines of a TPU program)
+            fold_in = jax.vmap(jax.random.fold_in)
+            ids = first["request_ids"]
+            keys = fold_in(jnp.broadcast_to(first["rng"], ids.shape), ids)
+            sub = fold_in(keys, jnp.zeros_like(ids))
+            slot_keys = slot_keys.at[slots].set(keys)
+            # the keys are written BEFORE the sampler runs, and the barrier
+            # keeps that order. With the key rows live beside the sampler's
+            # (rows, vocab) temporaries the TPU compiler (libtpu 0.0.34)
+            # dies in its memory-space assignment (a null dereference in the
+            # best-fit repacker) on OLMoE-1B-7B's 8 x 128 insert: seen on the
+            # chip, reproduced and cured by compiling every (rows, bucket)
+            # insert of every cell for a described v5e. Values are untouched.
+            slot_keys, logits, sub = jax.lax.optimization_barrier(
+                (slot_keys, logits, sub))
+            tokens = sampler(logits, sub, first["temperature"],
+                             first["greedy"], allowed=first.get("allowed"))
+            return self._replicate_out((tokens, slot_keys))
+
+    def _first_lower(self, rows: int) -> tuple:
+        """Lowering avals ``(slot_keys, first)`` of an insert program's
+        sampling inputs, replicated under a mesh."""
+        key = jax.eval_shape(jax.random.key, 0).dtype
+        first = {"rng": jax.ShapeDtypeStruct((), key),
+                 "request_ids": jax.ShapeDtypeStruct((rows,), jnp.uint32),
+                 "temperature": jax.ShapeDtypeStruct((rows,), jnp.float32),
+                 "greedy": jax.ShapeDtypeStruct((rows,), jnp.bool_)}
+        if self.grammar:
+            first["allowed"] = jax.ShapeDtypeStruct(
+                (rows, self.config.vocab_size), jnp.bool_)
+        return repl_avals(
+            (jax.ShapeDtypeStruct((self.max_batch,), key), first))
+
+    def _first_args(self, rows: int, first: Optional[FirstToken]) -> tuple:
+        """``(sampler, first dict)`` for an insert program's call. Without
+        sampling inputs (a caller that reads the logits): the default
+        sampler, so the scheduler's programs are shared, greedy rows, zero
+        request ids."""
+        if first is None:
+            if self._default_first_rng is None:
+                self._default_first_rng = jax.random.key(0)
+            first = FirstToken(
+                self._default_first_rng, np.zeros((rows,), np.uint32),
+                np.ones((rows,), np.float32), np.ones((rows,), bool))
+        args = {"rng": first.rng,
+                "request_ids": np.asarray(first.request_ids, np.uint32),
+                "temperature": np.asarray(first.temperature, np.float32),
+                "greedy": np.asarray(first.greedy, bool)}
+        if self.grammar:
+            args["allowed"] = (
+                np.ones((rows, self.config.vocab_size), bool)
+                if first.allowed is None else np.asarray(first.allowed, bool))
+        return first.sampler, args
+
+    @staticmethod
+    def _insert_key(rows: int, bucket: int, sampler: Optional[SlotSampler]):
+        """One insert program per (rows, bucket); an engine-wide top-k/top-p
+        sampler (static in the program) has programs of its own."""
+        if sampler is None or sampler == SlotSampler():
+            return (rows, bucket)
+        return (rows, bucket, sampler)
+
+    def _insert_programs(self, rows: int, bucket: int,
+                         sampler: Optional[SlotSampler] = None):
+        """Lazily compile the RIGHT-SIZED slab insert for ``rows`` prompts:
+        ONE donated program that prefills at batch width ``rows`` (prefill
+        FLOPs scale with what was actually inserted, not ``max_batch``),
+        scatters the fresh rows into the session cache per slot (O(rows) HBM
+        traffic) and ends in :meth:`_first_token`. ``(params, cache,
+        slot_keys, first, ids, slots, new_len[, *ad]) -> (first tokens,
+        last-position logits (rows, vocab), cache, slot_keys)``."""
+        key = self._insert_key(rows, bucket, sampler)
+        if key in self._slab_insert:
+            return self._slab_insert[key]
+        sampler = sampler or SlotSampler()
+
+        def insert_fn(params, cache, slot_keys, first, ids, slots, new_len,
+                      *ad):
+            logits, mut = self.model.apply(
+                self._ad_vars(params, None, ad), ids,
+                jnp.maximum(new_len - 1, 0), method="last_logits",
+                mutable=["cache"])
+            tokens, slot_keys = self._first_token(
+                logits, slot_keys, slots, first, sampler)
+            # pinned to the serving specs like every returned cache: the
+            # AOT session programs reject any other layout; the constraint
+            # reshards only the inserted rows
+            return tokens, logits, self._shard_out(_scatter_cache_rows(
+                cache, mut["cache"], slots, new_len, rows)), slot_keys
+
+        self._slab_insert[key] = self._time_compile(
+            f"insert_r{rows}_b{bucket}",
+            lambda: jax.jit(insert_fn, donate_argnums=(1, 2))
+            .lower(self.params, self._cache_avals(), *self._first_lower(rows),
+                   jnp.zeros((rows, bucket), jnp.int32),
+                   jnp.zeros((rows,), jnp.int32),
+                   jnp.zeros((rows,), jnp.int32),
+                   *self._ad_lower(rows))
+            .compile())
+        return self._slab_insert[key]
 
     def _replicate_out(self, tree: PyTree) -> PyTree:
         """Inside-jit constraint forcing every leaf fully replicated when a
@@ -1032,7 +1137,8 @@ class CausalLM:
         outputs; inference/partition.py is the one spec source)."""
         return shard_out(tree)
 
-    def _paged_insert_programs(self, rows: int, bucket: int):
+    def _paged_insert_programs(self, rows: int, bucket: int,
+                               sampler: Optional[SlotSampler] = None):
         """Lazily compile the paged insert for ``rows`` prompts at suffix
         width ``bucket``: ONE donated program that (a) prefills the suffix
         tokens at their own batch width, reading shared prefix pages through
@@ -1040,8 +1146,12 @@ class CausalLM:
         writes the fresh K/V straight into the session's page pool (no
         separate scatter pass — the pool is global, so the prefill IS the
         scatter; the donated pool is the layer scan's carry, so only the
-        rows written move), and (c) updates the session-width
-        cache_index/block_table rows at ``slots``.
+        rows written move), (c) updates the session-width
+        cache_index/block_table rows at ``slots``, and (d) ends in
+        :meth:`_first_token`: the head over each row's last real position
+        (``new_len - starts - 1``) only. ``(params, cache, slot_keys, first,
+        ids, tables, slots, starts, new_len[, *ad]) -> (first tokens,
+        last-position logits (rows, vocab), cache, slot_keys[, sums])``.
 
         A model with experts (``self.moe_stats``) is told which tokens are
         real (each row's ``new_len - starts`` suffix; the bucket's padding
@@ -1049,14 +1159,15 @@ class CausalLM:
         the three sums of the fused session decode taken over the real tokens
         (expert slots touched, assignments, layers run) and the grouped rows
         the experts ran, real or not (layers x rows x bucket x top_k)."""
-        key = (rows, bucket)
+        key = self._insert_key(rows, bucket, sampler)
         if key in self._paged_insert:
             return self._paged_insert[key]
+        sampler = sampler or SlotSampler()
         ppseq = self.config.max_seq_len // self.config.page_size
         moe = self.moe_stats
 
-        def insert_fn(params, cache, ids, tables, slots, starts, new_len,
-                      *ad):
+        def insert_fn(params, cache, slot_keys, first, ids, tables, slots,
+                      starts, new_len, *ad):
             def as_rows(path, leaf):
                 p = jax.tree_util.keystr(path)
                 if p.endswith("['cache_index']"):
@@ -1074,8 +1185,11 @@ class CausalLM:
                     if moe else None)
             logits, mut = self.model.apply(
                 self._ad_vars(params, row_cache, ad), ids,
-                **({"live": live} if moe else {}),
+                jnp.maximum(new_len - starts - 1, 0),
+                **({"live": live} if moe else {}), method="last_logits",
                 mutable=["cache", "moe_stats"] if moe else ["cache"])
+            tokens, slot_keys = self._first_token(
+                logits, slot_keys, slots, first, sampler)
             sums = ()
             if moe:
                 with jax.named_scope("bookkeeping"):
@@ -1107,14 +1221,15 @@ class CausalLM:
                 return new  # mutated pool leaves
 
             with jax.named_scope("table_write"):
-                return (logits, self._shard_out(
+                return (tokens, logits, self._shard_out(
                     jax.tree_util.tree_map_with_path(back, cache,
-                                                     mut["cache"])), *sums)
+                                                     mut["cache"])),
+                        slot_keys, *sums)
 
         self._paged_insert[key] = self._time_compile(
             f"paged_insert_r{rows}_b{bucket}",
-            lambda: jax.jit(insert_fn, donate_argnums=(1,))
-            .lower(self.params, self._cache_avals(),
+            lambda: jax.jit(insert_fn, donate_argnums=(1, 2))
+            .lower(self.params, self._cache_avals(), *self._first_lower(rows),
                    jnp.zeros((rows, bucket), jnp.int32),
                    jnp.zeros((rows, ppseq), jnp.int32),
                    jnp.zeros((rows,), jnp.int32),
@@ -1194,7 +1309,8 @@ class CausalLM:
     def extend(self, session: "DecodeSession", slot_ids: np.ndarray,
                chunk_ids: np.ndarray, lengths: np.ndarray,
                starts: np.ndarray, tables: Optional[np.ndarray] = None,
-               adapter_slots: Optional[np.ndarray] = None) -> jax.Array:
+               adapter_slots: Optional[np.ndarray] = None,
+               first: Optional[FirstToken] = None) -> jax.Array:
         """Chunked-prefill extension: write ``lengths[i]`` new prompt tokens
         per slot at positions ``starts[i]..starts[i]+lengths[i]`` (the
         tentpole primitive behind ``ServeEngine(prefill_chunk_tokens=...)``).
@@ -1207,8 +1323,10 @@ class CausalLM:
         prefills at arbitrary ``starts`` through caller-provided block
         tables — pass ``tables`` covering everything written through this
         chunk; the engine drives page allocation chunk-by-chunk via
-        ``PagedKVCache.begin/extend/finish_chunked``). Contiguous mode runs
-        the gather/extend/scatter program of :meth:`_chunk_extend_programs`.
+        ``PagedKVCache.begin/extend/finish_chunked``), which also writes the
+        rows' request keys into ``session.slot_keys`` (``first``, as
+        :meth:`insert` takes it). Contiguous mode runs the
+        gather/extend/scatter program of :meth:`_chunk_extend_programs`.
         """
         if self._decode is None:
             self.compile()
@@ -1238,19 +1356,18 @@ class CausalLM:
                                  "start_session() (no paged state attached)")
             if tables is None:
                 raise ValueError("paged extend needs per-row block tables")
-            prog = self._paged_insert_programs(rows, bucket)
-            logits, cache, *sums = prog(
-                self.params, session.cache, jnp.asarray(ids),
-                jnp.asarray(tables, jnp.int32), jnp.asarray(slot_ids),
-                jnp.asarray(starts), jnp.asarray(new_len), *ad)
+            sampler, first = self._first_args(rows, first)
+            prog = self._paged_insert_programs(rows, bucket, sampler)
+            (session.first_tokens, logits, session.cache, session.slot_keys,
+             *sums) = prog(
+                self.params, session.cache, session.slot_keys, first, ids,
+                np.asarray(tables, np.int32), slot_ids, starts, new_len, *ad)
             session.insert_routing = sums[0] if sums else None
-        else:
-            prog = self._chunk_extend_programs(rows, bucket)
-            logits, cache = prog(
-                self.params, session.cache, jnp.asarray(ids),
-                jnp.asarray(slot_ids), jnp.asarray(starts),
-                jnp.asarray(new_len), *ad)
-        session.cache = cache
+            session.lengths[slot_ids] = new_len
+            return logits
+        prog = self._chunk_extend_programs(rows, bucket)
+        logits, session.cache = prog(
+            self.params, session.cache, ids, slot_ids, starts, new_len, *ad)
         session.lengths[slot_ids] = new_len
         last = jnp.asarray(np.maximum(lengths - 1, 0))
         return logits[jnp.arange(rows), last]
@@ -1259,7 +1376,8 @@ class CausalLM:
                       prompt_ids: np.ndarray, lengths: np.ndarray,
                       reserve_tokens,
                       adapter_slots: Optional[np.ndarray] = None,
-                      ns: Optional[Sequence[Optional[str]]] = None) -> jax.Array:
+                      ns: Optional[Sequence[Optional[str]]] = None,
+                      first: Optional[FirstToken] = None) -> jax.Array:
         """Paged admission: per-row prefix lookup + page allocation (host),
         then ONE suffix-width prefill-and-scatter program. ``reserve_tokens``
         (scalar or per-row) bounds the decode room reserved in pages —
@@ -1296,11 +1414,12 @@ class CausalLM:
         tables = np.stack([pkv.table_for(int(slot_ids[i]), plans[i])
                            for i in range(rows)])
         try:
-            prog = self._paged_insert_programs(rows, bucket)
-            logits, cache, *sums = prog(
-                self.params, session.cache, jnp.asarray(ids),
-                jnp.asarray(tables), jnp.asarray(slot_ids),
-                jnp.asarray(starts), jnp.asarray(lengths, np.int32),
+            sampler, first = self._first_args(rows, first)
+            prog = self._paged_insert_programs(rows, bucket, sampler)
+            (session.first_tokens, logits, session.cache, session.slot_keys,
+             *sums) = prog(
+                self.params, session.cache, session.slot_keys, first, ids,
+                tables, slot_ids, starts, lengths,
                 *self._ad_args(session.adapters,
                                adapter_slots if adapter_slots is not None
                                else np.zeros((rows,), np.int32)))
@@ -1313,22 +1432,21 @@ class CausalLM:
             for p in plans:
                 pkv.rollback(p)
             raise
-        session.cache = cache
         session.insert_routing = sums[0] if sums else None
         for i in range(rows):
             pkv.commit(int(slot_ids[i]), plans[i],
                        prompt_ids[i, : lengths[i]].tolist(), ns=nss[i])
         session.lengths[slot_ids] = lengths
         session.active[slot_ids] = True
-        last = jnp.asarray(np.maximum(suffix - 1, 0))
-        return logits[jnp.arange(rows), last]
+        return logits
 
     def insert(self, session: "DecodeSession", slot_ids: np.ndarray,
                prompt_ids: np.ndarray, lengths: Optional[np.ndarray] = None,
                pad_token_id: int = 0,
                reserve_tokens: Optional[Any] = None,
                adapter_slots: Optional[np.ndarray] = None,
-               ns: Optional[Sequence[Optional[str]]] = None) -> jax.Array:
+               ns: Optional[Sequence[Optional[str]]] = None,
+               first: Optional[FirstToken] = None) -> jax.Array:
         """Prefill ``slot_ids`` with new prompts; every OTHER slot's cache
         rows and lengths are preserved (they may be mid-generation).
 
@@ -1338,7 +1456,13 @@ class CausalLM:
         HBM traffic scale with ``len(slot_ids)``, not ``max_batch`` (the
         reference prefills its full CTX batch per insert; the old path here
         did too, plus a whole-cache ``jnp.where`` copy).
-        Returns ``next_token_logits (len(slot_ids), vocab)``."""
+
+        ONE program call, and nothing else on the device: the program runs
+        the head over each row's last real position only, samples the rows'
+        first tokens as ``first`` says (left on the device as
+        ``session.first_tokens``) and writes their request keys into
+        ``session.slot_keys``. Returns ``next_token_logits (len(slot_ids),
+        vocab)``, on the device too."""
         if self._decode is None:
             self.compile()
         slot_ids = np.asarray(slot_ids, np.int32)
@@ -1360,23 +1484,24 @@ class CausalLM:
                                  "start_session() (no paged state attached)")
             return self._insert_paged(session, slot_ids, prompt_ids, lengths,
                                       reserve_tokens,
-                                      adapter_slots=adapter_slots, ns=ns)
+                                      adapter_slots=adapter_slots, ns=ns,
+                                      first=first)
         bucket = self._bucket_for(s)
         rows = len(slot_ids)
-        prefill, scatter = self._insert_programs(rows, bucket)
+        sampler, first = self._first_args(rows, first)
+        prog = self._insert_programs(rows, bucket, sampler)
         ids = np.zeros((rows, bucket), np.int32)
         ids[:, :s] = prompt_ids
-        logits, fresh = prefill(
-            self.params, jnp.asarray(ids),
+        (session.first_tokens, logits, session.cache,
+         session.slot_keys) = prog(
+            self.params, session.cache, session.slot_keys, first, ids,
+            slot_ids, lengths,
             *self._ad_args(session.adapters,
                            adapter_slots if adapter_slots is not None
                            else np.zeros((rows,), np.int32)))
-        session.cache = scatter(session.cache, fresh,
-                                jnp.asarray(slot_ids), jnp.asarray(lengths))
         session.lengths[slot_ids] = lengths
         session.active[slot_ids] = True
-        last = jnp.asarray(np.maximum(lengths - 1, 0))
-        return logits[jnp.arange(rows), last]
+        return logits
 
     def step(self, session: "DecodeSession", tokens: np.ndarray,
              adapter_slots: Optional[np.ndarray] = None) -> jax.Array:

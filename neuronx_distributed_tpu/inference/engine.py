@@ -119,6 +119,7 @@ from neuronx_distributed_tpu.inference.adapters import (
 )
 from neuronx_distributed_tpu.inference.causal_lm import (
     CausalLM,
+    FirstToken,
     _set_block_tables,
     _set_cache_index_rows,
 )
@@ -344,6 +345,12 @@ _STAT_KEYS = (
     # insert's layer read
     "moe_insert_experts_touched", "moe_insert_assignments",
     "moe_insert_layer_calls", "moe_insert_rows",
+    # the insert's twins of program_calls / host_fetches: compiled-program
+    # calls that admitted requests (an insert; each chunk extend of a chunked
+    # or replayed admission) and fetches of their first tokens. An insert is
+    # one of each, so (calls + fetches) / inserts reads 2.0 where every
+    # admission was a one-shot insert and more where one went by chunks
+    "insert_program_calls", "insert_host_fetches",
 )
 
 
@@ -704,10 +711,9 @@ class ServeEngine:
         self._temp = np.zeros((b,), np.float32)
         self._greedy = np.ones((b,), bool)
         self._tok = np.zeros((b,), np.int32)
-        # per-slot request keys + generated-token counters (the device
-        # samples row j's step under fold_in(slot_keys[j], counts[j]))
-        self._slot_keys = (None if self._sim
-                           else jax.random.split(self.rng, b))
+        # per-slot generated-token counters (the device samples row j's step
+        # under fold_in(slot_keys[j], counts[j]); the request keys are the
+        # session's, see _slot_keys)
         self._gen_counts = np.zeros((b,), np.int32)
         # async pipeline state (async_loop=True): at most ONE in-flight
         # dispatched-but-unfetched block record rides _inflight between
@@ -1054,6 +1060,44 @@ class ServeEngine:
 
     def _req_key(self, request_id: int) -> jax.Array:
         return jax.random.fold_in(self.rng, request_id)
+
+    # the per-slot request keys live with the session: an insert program
+    # writes its rows' entries on the device (CausalLM._first_token)
+    @property
+    def _slot_keys(self) -> Optional[jax.Array]:
+        return getattr(self.session, "slot_keys", None)
+
+    @_slot_keys.setter
+    def _slot_keys(self, keys: jax.Array) -> None:
+        self.session.slot_keys = keys
+
+    def _first_inputs(self, group: Sequence[Request]) -> Optional[FirstToken]:
+        """What the insert program samples ``group``'s first tokens with:
+        each request's key stream (token index 0 of it), its sampler knobs
+        and, in an engine built with grammars, the budget-aware mask of each
+        grammar's START state. Host arrays; None in simulation."""
+        if self._sim:
+            return None
+        n = len(group)
+        return FirstToken(
+            self.rng, np.asarray([r.request_id for r in group], np.uint32),
+            np.asarray([r.temperature for r in group], np.float32),
+            np.asarray([r.greedy for r in group], bool),
+            sampler=self.slot_sampler,
+            allowed=self._grammar_allowed_rows(group, [0] * n, [0] * n))
+
+    def _fetch_first(self, first_dev, routing) -> np.ndarray:
+        """An insert's ONE fetch: its sampled first tokens and, from a model
+        with experts, its routing sums (counted here), together."""
+        t0 = time.perf_counter()
+        first, sums = jax.device_get((first_dev, routing))
+        if self.tracer.enabled:
+            self.tracer.complete("insert_fetch", (self.lane, "dispatch"), t0,
+                                 time.perf_counter(), block=self.blocks)
+        self.stats["insert_host_fetches"] += 1
+        if sums is not None:
+            self._count_insert_routing(sums)
+        return first
 
     def _free_slots(self) -> List[int]:
         return [i for i, r in enumerate(self.slots) if r is None]
@@ -1824,57 +1868,46 @@ class ServeEngine:
         aslots = (np.asarray([self._adapter_slot(r) for r in group], np.int32)
                   if self.lora else None)
         tier_before = self._tier_marker()
-        logits = self._dispatch("insert", lambda: self.lm.insert(
+        # ONE program call: the prompt's KV, the rows' request keys into
+        # slot_keys and their first tokens (token index 0 of each request's
+        # own key stream, fold_in(req_key, 0): the derivation the chunked
+        # path's final chunk and both decode modes use; constrained too, by
+        # each grammar's START state), all inside it. The inputs ride the
+        # call as host arrays, and nothing else runs on the device for this
+        # admission (host-only simulation: the stub's token function is the
+        # whole sampling path).
+        self._dispatch("insert", lambda: self.lm.insert(
             self.session, np.asarray(slot_ids, np.int32), ids, lengths=lens,
             pad_token_id=self.pad_token_id,
             reserve_tokens=reserve if self.paged else None,
             adapter_slots=aslots,
             # adapter namespace for the radix walk — prefix KV reuse is
             # scoped per adapter (cross-adapter reuse = wrong tokens)
-            ns=[r.adapter for r in group] if self.paged else None))
+            ns=[r.adapter for r in group] if self.paged else None,
+            first=self._first_inputs(group)))
         self._note_tier_restore(group, tier_before)
         self.stats["inserts"] += 1
         self.stats["inserted_requests"] += rows
-        temps = np.asarray([r.temperature for r in group], np.float32)
-        greedy = np.asarray([r.greedy for r in group], bool)
+        self.stats["insert_program_calls"] += 1
         # async pipeline: fetching the sampled first tokens here would block
         # on the insert program, which chains AFTER the in-flight decode
         # block (session.cache is its donated output future) — serializing
-        # the very overlap the loop exists for. Leave the sampler result on
+        # the very overlap the loop exists for. Leave the program's output on
         # device; _settle_firsts records the host values at the next harvest
         # (the designated sync point). A prefill worker never defers: it has
         # no decode pipeline and _handoff_group needs the token NOW.
         defer = self.async_loop and self.role != "prefill"
-        first_dev = None
+        first_dev = routing = None
         if self._sim:
-            # host-only simulation: the stub's deterministic token
-            # function replaces the whole jax sampling path (no XLA)
-            keys = None
             first = np.asarray(self.lm.sim_first_tokens(
                 [r.request_id for r in group], [0] * rows), np.int64)
         else:
-            # first token per inserted request: token index 0 of each
-            # request's own key stream (fold_in(req_key, 0) — the same
-            # derivation the chunked path's final chunk and both decode
-            # modes use)
-            keys = jnp.stack([self._req_key(r.request_id) for r in group])
-            sub = jax.vmap(jax.random.fold_in)(keys,
-                                               jnp.zeros((rows,), jnp.int32))
-            # first tokens are constrained too: budget-aware mask from each
-            # grammar's START state, pre-applied host-side (no-op when the
-            # whole group is free-form — the sampler call and its compiled
-            # eager shapes stay byte-identical to a grammarless engine)
-            logits = self._mask_logits(
-                logits, self._grammar_allowed_rows(group, [0] * rows,
-                                                   [0] * rows))
-            first_dev = self.slot_sampler(
-                logits, sub, jnp.asarray(temps), jnp.asarray(greedy))
-            first = None if defer else np.asarray(first_dev)
-        # the insert's routing sums (a model with experts, paged) come to the
-        # host where its first tokens do: here, or with the deferred record
-        routing = None if self._sim else self.session.insert_routing
-        if routing is not None and not defer:
-            self._count_insert_routing(np.asarray(routing))
+            first_dev = self.session.first_tokens
+            # the insert's routing sums (a model with experts, paged) come
+            # to the host with its first tokens: here, or with the deferred
+            # record
+            routing = self.session.insert_routing
+            first = None if defer else self._fetch_first(first_dev, routing)
         now = time.perf_counter()
         for i, (r, slot) in enumerate(zip(group, slot_ids)):
             r.start_block = self.blocks
@@ -1888,10 +1921,8 @@ class ServeEngine:
             self._active[slot] = True
             self._done[slot] = False
             self._eos[slot] = -1 if r.eos_token_id is None else r.eos_token_id
-            self._temp[slot] = temps[i]
-            self._greedy[slot] = greedy[i]
-            if not self._sim:
-                self._slot_keys = self._slot_keys.at[slot].set(keys[i])
+            self._temp[slot] = r.temperature
+            self._greedy[slot] = r.greedy
             self._gen_counts[slot] = 1
             self._adapter_idx[slot] = 0 if aslots is None else aslots[i]
             self._gidx[slot] = self._grammar_slot(r)
@@ -1904,7 +1935,7 @@ class ServeEngine:
                 self._first_pending.append({
                     "slot": slot, "rid": r.request_id, "idx": i,
                     "fut": first_dev, "block": self.blocks,
-                    "routing": routing if i == 0 else None,
+                    "routing": routing,
                     "val": None if first is None else int(first[i])})
                 if self._sim:
                     self._tok[slot] = int(first[i])
@@ -1991,8 +2022,10 @@ class ServeEngine:
             logits = self._dispatch("extend", lambda: self.lm.extend(
                 self.session, np.asarray([slot], np.int32), ids,
                 np.asarray([n], np.int32), np.asarray([st.written], np.int32),
-                tables=tables, adapter_slots=aslots))
+                tables=tables, adapter_slots=aslots,
+                first=self._first_inputs([req])))
             self.stats["chunk_program_calls"] += 1
+            self.stats["insert_program_calls"] += 1
             self.stats["prefill_chunk_tokens_done"] += n
             st.written += n
             budget -= n
@@ -2036,7 +2069,10 @@ class ServeEngine:
             # would stall the pipeline
             first_dev = self.slot_sampler(
                 logits, sub, jnp.asarray(temps), jnp.asarray(greedy))
-            first = None if defer else int(np.asarray(first_dev)[0])
+            first = None
+            if not defer:
+                first = int(np.asarray(first_dev)[0])
+                self.stats["insert_host_fetches"] += 1
         req.first_token_block = self.blocks
         self._observe_first_token(req, slot, time.perf_counter(),
                                   chunked=True)
@@ -2216,7 +2252,9 @@ class ServeEngine:
                     np.asarray([n], np.int32), np.asarray([w], np.int32),
                     tables=tables,
                     adapter_slots=(np.asarray([aslot], np.int32)
-                                   if self.lora else None)))
+                                   if self.lora else None),
+                    first=self._first_inputs([req])))
+                self.stats["insert_program_calls"] += 1
                 written += n
         except BaseException:
             # atomic unwind: every page hold released, device table reset —
@@ -2252,6 +2290,7 @@ class ServeEngine:
                 logits, self._grammar_allowed_rows([req], [rstate], [g]))
             tok = int(np.asarray(self.slot_sampler(
                 logits, sub, jnp.asarray(temps), jnp.asarray(greedy)))[0])
+            self.stats["insert_host_fetches"] += 1
         now = time.perf_counter()
         if req.start_block is None:
             req.start_block = self.blocks
@@ -4040,15 +4079,19 @@ class ServeEngine:
         if not self._first_pending:
             return
         keep: List[dict] = []
+        fetched: Dict[int, np.ndarray] = {}     # one fetch an admission
         now = time.perf_counter()
         for p in self._first_pending:
             if before_block is not None and p["block"] > before_block:
                 keep.append(p)
                 continue
-            tok = (int(p["val"]) if p["fut"] is None
-                   else int(np.asarray(p["fut"])[p["idx"]]))
-            if p.get("routing") is not None:
-                self._count_insert_routing(np.asarray(p["routing"]))
+            if p["fut"] is None:
+                tok = int(p["val"])
+            else:
+                if id(p["fut"]) not in fetched:
+                    fetched[id(p["fut"])] = self._fetch_first(
+                        p["fut"], p.get("routing"))
+                tok = int(fetched[id(p["fut"])][p["idx"]])
             slot = p["slot"]
             req = self.slots[slot]
             if req is None or req.request_id != p["rid"]:

@@ -167,7 +167,7 @@ class SimCausalLM:
 
     def insert(self, session: SimSession, slot_ids, prompt_ids,
                lengths=None, pad_token_id: int = 0, reserve_tokens=None,
-               adapter_slots=None, ns=None):
+               adapter_slots=None, ns=None, first=None):
         """Paged admission with the REAL plan/commit lifecycle (page holds,
         prefix registration, atomic rollback on pool pressure) and zero
         device work; the contiguous branch is pure length bookkeeping.
@@ -206,7 +206,7 @@ class SimCausalLM:
         return None
 
     def extend(self, session: SimSession, slot_ids, ids, new_len, starts,
-               tables=None, adapter_slots=None):
+               tables=None, adapter_slots=None, first=None):
         """Chunk-extend accounting: the chunk's page allocation already
         happened in ``PagedKVCache.extend_chunked`` (the engine drives it
         exactly like the real path); nothing device-side to do."""

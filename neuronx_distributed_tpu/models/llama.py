@@ -1004,6 +1004,15 @@ class LlamaForCausalLM(nn.Module):
         experts under a serving program (``_LayerStep``); else None."""
         return self._head(self._hidden(input_ids, live))
 
+    def last_logits(self, input_ids: jax.Array, last: jax.Array,
+                    live=None) -> jax.Array:
+        """``(b, vocab)`` logits of ONE position a row, ``last`` (b,): the
+        head runs over those ``b`` hidden states only. What a serving insert
+        reads of a prompt (``CausalLM._first_token``); the head over the
+        other positions is work nobody reads."""
+        x = self._hidden(input_ids, live)
+        return self._head(x[jnp.arange(x.shape[0]), last][:, None])[:, 0]
+
     def loss(self, input_ids: jax.Array, labels: jax.Array,
              ignore_index: int = -100) -> jax.Array:
         cfg = self.config

@@ -241,6 +241,45 @@ def test_moe_serving_runs_the_grouped_kernel_and_holds_less(chip, family, width)
             cfg.num_experts * b * width * cfg.intermediate_size * 2)
 
 
+def test_mixtral_insert_holds_no_logits_but_the_last_positions(chip):
+    """The 8 x 512 paged insert of ``mixtral-8x7b.score`` as ``CausalLM``
+    builds it (published widths, two layers, bf16, pages of 16, batch 8,
+    ``max_seq_len`` 1024), for the described v5e. The head runs over each
+    row's last real position only and the first token is sampled inside the
+    program, so (a) nothing in it has the shape of all positions' logits,
+    ``(8, 512, 32000)``: not an output (0.24 GiB in bf16 before PR 32; what
+    the engine read of it was 8 rows), not a temporary; (b) what it returns
+    beside the donated cache and keys is the ``(8, 32000)`` logits, eight
+    tokens and four sums; (c) the kernels are still in it."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from neuronx_distributed_tpu.parallel import mesh
+
+    mesh.initialize_model_parallel(tensor_model_parallel_size=1,
+                                   devices=list(chip.device_set))
+    repl = NamedSharding(mesh.get_mesh(), PartitionSpec())
+    cfg, cls = _moe_config("mixtral", num_layers=2, max_seq_len=1024,
+                           dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
+        meta.unbox(jax.eval_shape(lambda: cls(cfg).init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))))["params"])
+    rows, bucket = 8, 512
+    lm = CausalLM(cfg, params, cls, buckets=(128, bucket), max_batch=rows,
+                  page_size=16)
+    compiled = lm._paged_insert_programs(rows, bucket)
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_insert_fn")
+    assert f"[{rows},{bucket},{cfg.vocab_size}]" not in text
+    assert f"[{rows},{cfg.vocab_size}]" in text        # the check can see it
+    memory = compiled.memory_analysis()
+    returned = memory.output_size_in_bytes - memory.alias_size_in_bytes
+    print(f"mixtral 8 x 512 insert returns {returned / 2 ** 10:.0f} KiB beside "
+          f"the cache, temporaries {memory.temp_size_in_bytes / 2 ** 20:.0f} MiB")
+    assert returned < 2 * rows * cfg.vocab_size * 2
+    assert text.count("tpu_custom_call") >= 3    # flash forward, two grouped matmuls
+
+
 def test_fused_adamw_leaf(chip):
     leaf = (4096, 11008)
 

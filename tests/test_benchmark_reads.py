@@ -173,6 +173,10 @@ MOE_READS = ["moe_experts_touched", "moe_assignments", "moe_layer_steps"]
 # in every record's `engine_stats`; no metric of BENCHMARK.json reads them yet
 MOE_INSERT_STATS = ["moe_insert_experts_touched", "moe_insert_assignments",
                     "moe_insert_layer_calls", "moe_insert_rows"]
+# the insert's twins of program_calls / host_fetches (PR 32): in every record's
+# `engine_stats` the same way; (calls + fetches) / inserts reads 2.0 where every
+# admission was one program and one fetch
+INSERT_HOST_OPS = ["insert_program_calls", "insert_host_fetches"]
 TRAIN_READS = ["jit_step_fn"]
 
 
@@ -206,6 +210,24 @@ def test_insert_routing_counter_is_produced(run, moe_run, key):
         assert 0 < stats[key] <= stats["moe_insert_layer_calls"] * 8
     # the share of the grouped rows that was real work can be read
     assert 0 < stats["moe_insert_assignments"] / stats["moe_insert_rows"] < 1
+
+
+@pytest.mark.parametrize("key", INSERT_HOST_OPS)
+def test_insert_host_op_counter_is_produced(run, moe_run, key):
+    """One of each an insert, dense or with experts (whose routing sums come
+    with the first tokens, in the same fetch); in ``engine.stats.items()``,
+    which is what the driver writes into the record."""
+    for r in (run, moe_run):
+        assert r.engine.stats[key] == r.engine.stats["inserts"] > 0
+        assert key in dict(r.engine.stats.items())
+
+
+def test_paged_insert_program_is_built_from_rows_and_bucket(run):
+    """``benchmark/aot_check.py`` builds a cell's insert programs with two
+    arguments, and gets the engine's: the same object, keyed (rows, bucket)."""
+    assert run.lm._paged_insert_programs(1, 16) is run.insert
+    assert run.lm._paged_insert[(1, 16)] is run.insert
+    assert hasattr(run.insert, "memory_analysis") and hasattr(run.insert, "as_text")
 
 
 @pytest.mark.parametrize("name", TRAIN_READS)

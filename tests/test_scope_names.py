@@ -32,7 +32,7 @@ from neuronx_distributed_tpu.trainer import (
 # scope names by the file that opens them (PERF.md section 3 lists the same)
 SCOPES = {
     "inference/causal_lm.py fused_fn": ["sampler", "bookkeeping"],
-    "inference/causal_lm.py insert_fn": ["cache_rows", "table_write"],
+    "inference/causal_lm.py insert_fn": ["cache_rows", "table_write", "sampler"],
     "models/llama.py _decode_attention": ["kv_write", "kv_gather", "attend"],
     "models/llama.py LlamaAttention": ["qk_norm"],
     "trainer/step.py": ["grad_accumulate", "grad_clip", "optimizer_update"],
@@ -132,6 +132,8 @@ def test_paged_insert_names_its_regions(params):
             + SCOPES["models/llama.py _decode_attention"] + ["flash_fwd"])
     assert set(want) <= components
     assert parts["cache_write"] > 0 and parts["kv_gather"] > 0
+    # the first token is sampled inside the program (PR 32), under `sampler`
+    assert parts["sampler"] > 0 and parts["embed_head"] > 0
     assert unnamed_share(parts) < 0.1, parts
 
 

@@ -211,11 +211,11 @@ def test_right_sized_insert_touches_only_inserted_rows(lm):
     jax.tree_util.tree_map_with_path(check, before, after)
     # right-sized programs keyed by (rows, bucket): the 1-row inserts above
     # must NOT have compiled a max_batch-wide prefill
-    assert (1, 8) in lm._insert_prefill and 1 in lm._insert_scatter
+    assert (1, 8) in lm._slab_insert
     # a 2-row insert batches through its own width
     lm.retire(session, [0, 1])
     lm.insert(session, [0, 2], p[0:2])
-    assert (2, 8) in lm._insert_prefill
+    assert (2, 8) in lm._slab_insert
 
 
 def test_bucketed_admission_batches_one_insert(lm):

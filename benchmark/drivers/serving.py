@@ -221,9 +221,18 @@ class Window:
         self.trace_from = max(0.0, seconds - float(ctx.mix.get("trace_s", 6)))
         self.traced_from = self.traced_to = None     # the profiler's stretch, window clock
         self._window_annotation = None
+        self.trace_written = (0.0, 0.0)      # (when the profiler was stopped, how long its write-out took)
+
+    def clock(self, t: float) -> float:
+        """``time.perf_counter()`` value ``t`` on the window's clock, which
+        stands still while the profiler writes its trace out (30 s and more at
+        a chat cell's rate): the loop can serve nobody then, and the stall is
+        the harness's own, not the program's."""
+        stopped, took = self.trace_written
+        return t - self.t0 - (took if took and t > stopped else 0.0)
 
     def now(self) -> float:
-        return time.perf_counter() - self.t0
+        return self.clock(time.perf_counter())
 
     def submit(self, req: traffic.Req, due: float) -> None:
         with annotate("submit", self.traced):
@@ -247,7 +256,7 @@ class Window:
             row = self.by_id.pop(c.request_id, None)
             if row is None:
                 continue
-            row["stamps"] = [float(t) - self.t0 for t in (c.token_ts if c.token_ts is not None else [])]
+            row["stamps"] = [self.clock(float(t)) for t in (c.token_ts if c.token_ts is not None else [])]
             if c.expired or c.cancelled or len(c.tokens) < row["want"]:
                 row.update(failed=True, why=f"short:{c.finish_reason}:{len(c.tokens)}/{row['want']}")
             done.append(row)
@@ -255,10 +264,10 @@ class Window:
 
     def step(self) -> bool:
         self._trace_edge()
-        a = time.perf_counter()
+        a = self.now()
         with annotate("step_block", self.traced):
             worked = self.engine.step_block()
-        self.block_spans.append((a - self.t0, time.perf_counter() - self.t0, bool(worked)))
+        self.block_spans.append((a, self.now(), bool(worked)))
         return worked
 
     def sleep_until(self, t: float) -> None:
@@ -285,7 +294,9 @@ class Window:
 
             self._window_annotation.__exit__(None, None, None)
             self.traced_to = self.now()
+            stopped = time.perf_counter()
             jax.profiler.stop_trace()
+            self.trace_written = (stopped, time.perf_counter() - stopped)
             self.trace_state = "done"
 
     def drain(self) -> None:
@@ -399,11 +410,13 @@ def run(ctx: Context) -> dict:
              end_to_end=None if ctx.rehearse else e2e)
     record = {
         "correct": reference["ok"], "reference": reference,
+        "compared": {"logit_gap_median": [reference["relative_median"], reference["tolerance_median"]],
+                     "logit_gap_max": [reference["relative_max"], reference["tolerance_any"]]},
         "attempted": len(rows), "failed": len(failed),
         "setup_s": setup_s, "end_to_end": e2e, "rows": rows,
         "block_spans": w.block_spans,
-        "traced": [w.traced_from, w.traced_to],
-        "host_spans": [dict(e, ts=e["ts"] - w.t0) for e in engine.tracer.events()
+        "traced": [w.traced_from, w.traced_to], "trace_write_s": w.trace_written[1],
+        "host_spans": [dict(e, ts=w.clock(e["ts"])) for e in engine.tracer.events()
                        if e["ph"] == "X"] if ctx.traced else [],
         "engine_stats": {k: int(v) for k, v in engine.stats.items()},
         "engine": {"block_steps": engine.block_steps, "max_batch": lm.max_batch,

@@ -152,6 +152,7 @@ def run(ctx: Context) -> dict:
              step_ms_p50=None if ctx.rehearse or not step_ms else float(np.median(step_ms)))
     return {
         "correct": correct, "reference": checks,
+        "compared": {"loss_gap": [rel, ref_cfg["tolerance"]]},
         "attempted": len(losses), "failed": 0 if finite else sum(not math.isfinite(x) for x in losses),
         "setup_s": setup_s, "end_to_end": e2e, "step_ms": step_ms, "losses": losses,
         "tokens_per_step": tokens_per_step,

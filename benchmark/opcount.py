@@ -13,7 +13,7 @@ are counted at ``bytes_per_el`` (2: bf16).
 
 from __future__ import annotations
 
-from typing import Iterable
+from typing import Iterable, Optional
 
 
 def _heads(cfg: dict):
@@ -76,15 +76,21 @@ def kv_bytes_per_token(cfg: dict, bytes_per_el: int = 2) -> int:
 # ------------------------------------------------------------------- decode
 
 def decode_step_bytes(cfg: dict, rows: float, context_tokens: float,
-                      bytes_per_el: int = 2) -> float:
+                      bytes_per_el: int = 2, experts_read: Optional[float] = None) -> float:
     """Bytes one decode step over ``rows`` streams must read: every layer's
-    attention and router weights, the experts the rows' tokens can choose
-    (``min(experts, rows * k)`` - all 8 of Mixtral's from 4 rows up), the
-    output head, and the cached keys and values of ``context_tokens`` tokens
-    (summed over the rows)."""
+    attention and router weights, the experts of a layer the step READ
+    (``experts_read``: ``moe_experts_touched`` a live layer-step, which the
+    program counts since PR 26), the output head, and the cached keys and
+    values of ``context_tokens`` tokens (summed over the rows). Without
+    ``experts_read`` the experts are those the rows' tokens CAN choose,
+    ``min(experts, rows * k)`` - all 8 of Mixtral's from 4 rows up: an upper
+    bound, since two rows often choose the same expert (the fallback for a
+    record without the counter, and the dense models' one feed-forward)."""
     e, k = experts(cfg)
+    if experts_read is None or e == 1:
+        experts_read = min(e, max(rows, 1.0) * k)
     per_layer = (attention_params(cfg) + router_params(cfg)
-                 + min(e, max(rows, 1.0) * k) * expert_params(cfg))
+                 + min(e, experts_read) * expert_params(cfg))
     weights = cfg["num_hidden_layers"] * per_layer + head_params(cfg)
     return weights * bytes_per_el + context_tokens * kv_bytes_per_token(cfg, bytes_per_el)
 

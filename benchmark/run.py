@@ -270,11 +270,16 @@ def main(argv=None) -> int:
                 return 1
             result["metrics"][m["name"]] = {"value": float(e2e[m["name"]]), "unit": m["unit"]}
 
+    # each number compared beside its limit: last on the line and last on stderr
+    result["compared"] = {name: {"value": float(value), "limit": float(limit)}
+                          for name, (value, limit) in (record.get("compared") or {}).items()}
     OUT.mkdir(exist_ok=True)
     slim = {k: v for k, v in record.items() if k not in ("host_spans", "config", "mix")}
     (OUT / f"{cell['name']}.json").write_text(json.dumps(
         {"result": result, "record": slim}, default=str))
     ctx.emit("done", wall_s=round(ctx.since_start(), 1), **watch.counts())
+    for name, pair in result["compared"].items():
+        print(f"benchmark: compared {name} {pair['value']} limit {pair['limit']}", file=sys.stderr)
     print(json.dumps(result), flush=True)
     return 0 if result["correct"] else 1
 

@@ -306,20 +306,20 @@ def prefill_experts_share(record):
 
 
 def decode_bytes_over_needed(record):
-    """``bytes_accessed`` of the fused decode's executed ops per decode step,
-    over the bytes ``opcount.decode_step_bytes`` says a step needs (rows and
-    context as ``decode.roofline_share`` takes them: read back from it)."""
+    """``bytes_accessed`` of the fused decode's executed ops per LIVE decode
+    step, over the bytes ``opcount.decode_step_bytes`` says such a step needs
+    (rows, context and experts read as ``decode.roofline_share`` takes them:
+    read back from it)."""
+    from benchmark import decode_steps
     from benchmark.run import read_layer_metric
 
-    trace = record.get("device_trace") or {}
-    moved = trace.get("bytes_accessed", {}).get(DECODE)
-    calls = trace.get("module_calls", {}).get(DECODE)
+    moved = (record.get("device_trace") or {}).get("bytes_accessed", {}).get(DECODE)
+    ran = decode_steps.traced_decode(record)
     share = read_layer_metric("decode.roofline_share", record)
-    step_ms = read_layer_metric("decode.step_ms", record)
-    if not moved or not calls or not share or not step_ms:
+    if not moved or ran is None or not share:
         return None
-    need = share / 100.0 * record["peaks"]["hbm_bytes_per_s"] * step_ms / 1e3
-    return moved / (calls * record["engine"]["block_steps"]) / need
+    need = share / 100.0 * record["peaks"]["hbm_bytes_per_s"] * ran["step_s"]
+    return moved / ran["live_steps"] / need
 
 
 def train_matmul_share(record):

@@ -7,11 +7,18 @@ generated requests.
 
 Steadiness: every draw is STRATIFIED. A run of n requests takes the n
 quantiles ``(i + 0.5) / n`` of each stated distribution (prompt lengths,
-answer lengths, the exponential gaps of a Poisson process) and lets the seed
-permute them and draw the token ids. Every seed therefore offers the same
-multiset of lengths and gaps - the same amount of work over the same span -
-in another order, which is what lets two runs on different seeds agree to a
-few percent while each is still a sample of the stated distribution.
+answer lengths, the exponential gaps of a Poisson process), so every run
+offers the same multiset of lengths and gaps: the same amount of work over the
+same span. An open-loop window also offers them in the same ORDER whatever the
+seed: one permutation of the gaps and of the lengths, drawn from
+``ORDER_SEED``; ``--seed`` draws the token ids here and the weights in the
+driver. An open loop at four fifths of its knee is not steady under a
+reordering of the same work: six seeds' orders moved a window's time per
+token by 4 % in ``olmoe-1b-7b.chat``, median and mean alike, against a bound
+of 4 % (PERF.md section 6, PR 34). ``ORDER_SEED`` is a seed of PR 34's rate
+sweeps, so the knees the mixes record were read under this very order. A
+closed loop's stream is ordered by the seed: a saturated system has no queue
+for the order to move.
 """
 
 from __future__ import annotations
@@ -26,6 +33,9 @@ from typing import Iterator, List, Optional
 import numpy as np
 
 HERE = Path(__file__).resolve().parent
+
+# orders every open-loop window (see above); 2147490101 is a seed of PR 34's sweeps
+ORDER_SEED = 2147490101
 
 
 @dataclasses.dataclass
@@ -133,14 +143,15 @@ def _prompts(mix: dict, lengths: np.ndarray, vocab: int,
 
 def open_loop(mix: dict, vocab: int, seed: int, seconds: float,
               rate_per_s: Optional[float] = None) -> List[Req]:
-    """Every request of an open-loop window, sorted by due time."""
-    rng = np.random.RandomState(seed)
+    """Every request of an open-loop window, sorted by due time: gaps and
+    lengths in ``ORDER_SEED``'s order, token ids from ``seed``."""
+    order = np.random.RandomState(ORDER_SEED)
     due = arrival_times(mix.get("arrivals") or {}, rate_per_s or mix["rate_per_s"],
-                        seconds, rng)
+                        seconds, order)
     n = due.size
-    plen = stratified_lengths(mix["prompt_tokens"], n, rng)
-    alen = stratified_lengths(mix["answer_tokens"], n, rng)
-    prompts = _prompts(mix, plen, vocab, rng)
+    plen = stratified_lengths(mix["prompt_tokens"], n, order)
+    alen = stratified_lengths(mix["answer_tokens"], n, order)
+    prompts = _prompts(mix, plen, vocab, np.random.RandomState(seed))
     return [Req(float(due[i]), prompts[i], int(alen[i])) for i in range(n)]
 
 

@@ -179,3 +179,94 @@ def test_new_config_mix_cell_and_layer_metric_are_files_and_entries_only(tmp_pat
                                          root=tmp_path / "benchmark") > 0
     finally:
         sys.path.remove(str(tmp_path))
+
+
+# ------------------------------------------- the open-loop mixes, since PR 34
+
+OPEN_MIXES = sorted({w["traffic"] for w in OPEN_LOOP})
+
+
+@pytest.mark.parametrize("name", OPEN_MIXES)
+def test_open_loop_mix_states_a_rate_its_own_sweep_gives(name):
+    """``rate_per_s`` is 0.8 x ``knee_per_s``; where several configurations run
+    the file, ``knees_per_s`` gives each one's knee, ``knee_per_s`` is the
+    lowest and ``rate_bound`` names whose it is."""
+    mix = traffic.load_mix(name)
+    assert mix["rate_per_s"] == pytest.approx(0.8 * mix["knee_per_s"], rel=1e-6)
+    knees = mix.get("knees_per_s")
+    if knees:
+        assert mix["knee_per_s"] == min(knees.values())
+        assert knees[mix["rate_bound"]] == mix["knee_per_s"]
+        configs = {w["config"] for w in OPEN_LOOP if w["traffic"] == name}
+        assert set(knees) == configs
+    assert set(mix["limits"]) == {"ttft_ms", "tpot_ms"} and all(v > 0 for v in mix["limits"].values())
+    assert "PR 34" in mix["swept"] and "0.8 x" in mix["swept"]
+
+
+@pytest.mark.parametrize("name", OPEN_MIXES)
+def test_open_loop_window_holds_enough_requests_to_judge_a_median(name):
+    """A chat window holds at least 100 requests and the long-context one at
+    least 40 (18 before PR 34): the judged statistic is taken over them."""
+    mix = traffic.load_mix(name)
+    n = round(mix["rate_per_s"] * BENCH["run_seconds"])
+    assert n >= (40 if mix["max_seq_len"] > 1024 else 100), n
+    reqs = traffic.open_loop(mix, 1000, seed=3, seconds=float(BENCH["run_seconds"]))
+    assert len(reqs) == n
+
+
+@pytest.mark.parametrize("name", OPEN_MIXES)
+def test_the_sweeps_rule_is_what_the_mix_records(name):
+    """Engine options and lengths are as before PR 34: only rate, knee, limits
+    and ``swept`` moved."""
+    mix = traffic.load_mix(name)
+    assert mix["loop"] == "open" and mix["arrivals"] == {"process": "poisson"}
+    assert mix["drain_s"] in (30, 60) and mix["trace_s"] in (6, 8)
+    assert "knee = highest rate" in mix["swept"].lower()
+    assert mix["rehearsal"]["rate_per_s"] > 0
+
+
+def test_the_dense_control_runs_the_moe_cells_file_letter_for_letter():
+    by_name = {w["name"]: w for w in BENCH["workloads"]}
+    assert by_name["mistral-7b-v0.3.chat"]["traffic"] == by_name["mixtral-8x7b.chat"]["traffic"]
+    assert by_name["mistral-7b-v0.3.chat"]["config"] != by_name["mixtral-8x7b.chat"]["config"]
+
+
+@pytest.mark.parametrize("cell", OPEN_LOOP, ids=lambda w: w["name"])
+def test_an_open_loop_cells_why_states_its_rate(cell):
+    mix = traffic.load_mix(cell["traffic"])
+    assert f"{mix['rate_per_s']:g}/s" in cell["why"], cell["why"]
+    assert "all experts" not in cell["why"] and "reads all" not in cell["why"]
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"] + BENCH["workloads"], ids=lambda c: c["name"])
+def test_no_why_describes_the_program_before_the_grouped_expert_matmul(entry):
+    for stale in ("all-experts", "runs all experts", "8 x the expert FLOPs", "4 x the needed"):
+        assert stale not in entry["why"], entry["why"]
+
+
+# ------------------------------- the judged time per token: as before PR 34
+
+def test_the_median_time_per_token_is_judged_in_the_four_open_loop_cells_and_no_other():
+    """PR 34 re-swept the rates and left ``end_to_end`` as it was: the median
+    over requests under 4 %, the four open-loop cells, and the per-layer
+    metrics of those cells move it."""
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    four = sorted(w["name"] for w in OPEN_LOOP)
+    assert len(four) == 4 and sorted(e2e["tpot_ms_p50"]["workloads"]) == four
+    assert e2e["tpot_ms_p50"]["bound"] == 0.04 and e2e["tpot_ms_p50"]["source"] == "host_clock"
+    assert (e2e["tpot_ms_p50"]["unit"], e2e["tpot_ms_p50"]["better"]) == ("ms", "lower")
+    assert set(e2e) == {"tpot_ms_p50", "tokens_per_s", "setup_s"}
+    assert "tpot_ms_p50" not in {m["name"] for m in BENCH["per_layer"]}
+    assert {m["moves"] for m in BENCH["per_layer"]} == set(e2e)
+    assert (e2e["tokens_per_s"]["bound"], e2e["setup_s"]["bound"]) == (0.015, 0.1)
+
+
+@pytest.mark.parametrize("metric", ["ttft_ms_p90", "engine.delivery_gap_ms_p99"])
+def test_a_tail_is_listed_where_the_window_leaves_ten_samples_beyond_it(metric):
+    """A 90th percentile wants 100 requests: every chat window holds them
+    since PR 34 (OLMoE's held 82), the long-context one (65) does not."""
+    listed = set(next(m for m in BENCH["per_layer"] if m["name"] == metric)["workloads"])
+    for cell in OPEN_LOOP:
+        mix = traffic.load_mix(cell["traffic"])
+        n = round(mix["rate_per_s"] * BENCH["run_seconds"])
+        assert (cell["name"] in listed) == (n >= 100), (cell["name"], n)

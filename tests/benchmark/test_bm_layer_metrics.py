@@ -40,7 +40,8 @@ def serving_record():
         "device_trace": {"devices": 1, "window_s": 4.0, "busy_s": 3.0,
                          "category_s": {"mosaic": 0.6, "other": 2.4, "collective": 0.0},
                          "module_s": {"jit_fused_fn": 2.4, "jit_insert_fn": 0.5},
-                         "module_calls": {"jit_fused_fn": 5.0, "jit_insert_fn": 2.0}},
+                         # three decode blocks, fetched at 1.0, 2.0 and 3.5 (the rows' stamps)
+                         "module_calls": {"jit_fused_fn": 3.0, "jit_insert_fn": 2.0}},
     }
 
 
@@ -51,7 +52,7 @@ SERVING = {
     "engine.host_ms_per_block": 30.0,            # blocks: 100 - 70 = 30 ms and 200 - 170 = 30 ms
     "engine.batch_occupancy": 100.0 * 320 / (10 * 8 * 8),
     "engine.slo_attainment": 100.0 / 3,          # only the first meets both; the failed one misses
-    "decode.step_ms": 2.4 / 5 / 8 * 1e3,
+    "decode.step_ms": 2.4 / 4 * 1e3,             # live steps: 2 + 1 + 1 of the three blocks' 24
     "prefill.ms_per_call": 250.0,
     "cache.temp_over_pool": 2.5,
     "cache.pool_used_peak": 50.0,
@@ -79,12 +80,11 @@ def test_roofline_shares_use_needed_bytes_and_flops():
 
     rec = serving_record()
     cfg = rec["config"]
-    # two streams alive for 1.8 s and 1.5 s of the 4 s traced, contexts 1002 and 501
+    # one row live in each of the 4 live steps; it read 1001 + 1002, 1003 and 501 cached tokens
     share = harness.read_layer_metric("decode.roofline_share", rec)
-    step_s = 2.4 / 5 / 8
-    lo = opcount.decode_step_bytes(cfg, 0.7, 0.7 * 500) / 819e9 / step_s * 100
-    hi = opcount.decode_step_bytes(cfg, 1.0, 1.0 * 1100) / 819e9 / step_s * 100
-    assert lo < share < hi
+    step_s = 2.4 / 4
+    need = opcount.decode_step_bytes(cfg, 1.0, (1001 + 1002 + 1003 + 501) / 4)
+    assert share == pytest.approx(need / 819e9 / step_s * 100)
     got = harness.read_layer_metric("prefill.roofline_share", rec)
     assert got == pytest.approx(100 * opcount.prefill_flops(cfg, [1000, 500]) / 197e12 / 0.5)
 

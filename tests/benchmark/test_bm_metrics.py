@@ -50,3 +50,19 @@ def test_tokens_per_s_counts_whole_groups_completed_inside_the_window():
     rows = [row(0, [1.0], prompt=400), row(0, [2.0], prompt=300), row(0, [5.5], prompt=500)]
     e2e = metrics.serving_end_to_end(rows, seconds=5.0)
     assert e2e["tokens_per_s"] == pytest.approx(700 / 2.0)  # the third finished after the close
+
+
+def test_tpot_median_is_over_the_good_requests_of_two_tokens_or_more():
+    """The judged time per token: the median of the per-request values. A
+    failed request and a one-token answer (no gap to time) give none."""
+    rows = [row(0, [1.0, 1.1, 1.2]),                        # 100 ms a token
+            row(0, [1.0, 1.0, 1.0, 1.9]),                   # 300 ms: 0.9 s over 3 gaps
+            row(0, [2.0]),                                  # one token: no time per token
+            row(0, [0.1, 5.0], failed=True),                # failed: left out
+            row(0, [], failed=True)]
+    e2e = metrics.serving_end_to_end(rows, seconds=10)
+    assert e2e["tpot_ms_p50"] == pytest.approx(200.0)
+    rows.append(row(0, [1.0, 1.8]))                         # 800 ms: the median moves to the middle one
+    assert metrics.serving_end_to_end(rows, seconds=10)["tpot_ms_p50"] == pytest.approx(300.0)
+    none = metrics.serving_end_to_end([row(0, [1.0]), row(0, [], failed=True)], seconds=10)
+    assert none["tpot_ms_p50"] is None and "tpot_ms_mean" not in none

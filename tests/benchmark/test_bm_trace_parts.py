@@ -254,12 +254,12 @@ def decode_record():
         "device_trace": {
             "devices": 1, "window_s": 4.0, "busy_s": 3.0,
             "module_s": {"jit_fused_fn": 2.4, "jit_insert_fn": 0.5},
-            "module_calls": {"jit_fused_fn": 5.0, "jit_insert_fn": 2.0},
+            "module_calls": {"jit_fused_fn": 2.0, "jit_insert_fn": 2.0},
             "part_s": {"jit_fused_fn": {"attention": 0.6, "kv_gather": 0.5, "attend": 0.1, "attn_proj": 0.2,
                                         "ffn": 0.48, "router": 0.06, "experts": 0.06, "sampler": 0.024,
                                         "unnamed": 0.3},
                        "jit_insert_fn": {"experts": 0.45, "attention": 0.05}},
-            "bytes_accessed": {"jit_fused_fn": 4.0e11},
+            "bytes_accessed": {"jit_fused_fn": 3.0e10},
         },
     }
 
@@ -279,11 +279,12 @@ def test_serving_share(name):
 
 def test_bytes_over_needed_divides_by_what_the_roofline_share_needs():
     rec = decode_record()
-    step_s = 2.4 / 5 / 8
+    step_s = 2.4 / 3                   # the row's stamps: blocks at 1.0 (two tokens) and 2.0 (one)
+    assert harness.read_layer_metric("decode.step_ms", rec) == pytest.approx(step_s * 1e3)
     share = harness.read_layer_metric("decode.roofline_share", rec)
     need = share / 100 * PEAKS["hbm_bytes_per_s"] * step_s
-    assert tp.SHARES["decode.bytes_over_needed"](rec) == pytest.approx(4.0e11 / 40 / need)
-    assert 0.3 < tp.SHARES["decode.bytes_over_needed"](rec) < 3      # ~14 GB needed, 10 GB moved
+    assert tp.SHARES["decode.bytes_over_needed"](rec) == pytest.approx(3.0e10 / 3 / need)
+    assert 0.3 < tp.SHARES["decode.bytes_over_needed"](rec) < 3    # ~14 GB needed a live step, 10 GB moved
 
 
 def train_record():

@@ -11,7 +11,7 @@ from benchmark import traffic
 MIXES = sorted(p.stem for p in (Path(traffic.HERE) / "traffic").glob("*.json"))
 
 
-def test_same_seed_same_requests_other_seed_other_order():
+def test_same_seed_same_requests_other_seed_same_order_other_tokens():
     mix = traffic.load_mix("chat-short")
     a = traffic.open_loop(mix, 32000, 3, 45)
     b = traffic.open_loop(mix, 32000, 3, 45)
@@ -19,16 +19,59 @@ def test_same_seed_same_requests_other_seed_other_order():
     assert [r.due_s for r in a] == [r.due_s for r in b]
     assert all((x.prompt == y.prompt).all() and x.max_new_tokens == y.max_new_tokens
                for x, y in zip(a, b))
-    # another seed: the same multiset of lengths and gaps, in another order
-    assert [r.prompt.size for r in a] != [r.prompt.size for r in c]
-    assert sorted(r.prompt.size for r in a) == sorted(r.prompt.size for r in c)
-    assert sorted(r.max_new_tokens for r in a) == sorted(r.max_new_tokens for r in c)
-    # the n - 1 gaps of each are the n stratified gaps less the one the seed put first
+    # another seed: the same lengths and gaps in the same order (PR 34), other token ids
+    assert [r.due_s for r in a] == [r.due_s for r in c]
+    assert [r.prompt.size for r in a] == [r.prompt.size for r in c]
+    assert [r.max_new_tokens for r in a] == [r.max_new_tokens for r in c]
+    assert any((x.prompt != y.prompt).any() for x, y in zip(a, c))
+    # the n - 1 gaps are the n stratified gaps less the one the order put first
     n = len(a)
     every = set(np.round(-np.log(1 - (np.arange(n) + 0.5) / n) * 45 / n
                          / np.mean(-np.log(1 - (np.arange(n) + 0.5) / n)), 9))
-    for reqs in (a, c):
-        assert len(every - set(np.round(np.diff([r.due_s for r in reqs]), 9))) == 1
+    assert len(every - set(np.round(np.diff([r.due_s for r in a]), 9))) == 1
+
+
+OPEN = [m for m in MIXES if traffic.load_mix(m).get("loop") == "open"]
+
+
+@pytest.mark.parametrize("name", OPEN)
+def test_an_open_loop_mix_gives_every_seed_the_same_trace_and_other_tokens(name, monkeypatch):
+    """Since PR 34 ``traffic.ORDER_SEED`` orders the gaps and lengths of every
+    open-loop window: ``--seed`` draws the token ids (and the weights) only,
+    and no mix carries a knob for it."""
+    mix = traffic.load_mix(name)
+    assert "order" not in mix
+    a = traffic.open_loop(mix, 32000, 3, 51)
+    c = traffic.open_loop(mix, 32000, 2147492001, 51)
+    assert [r.due_s for r in a] == [r.due_s for r in c]
+    assert [r.prompt.size for r in a] == [r.prompt.size for r in c]
+    assert [r.max_new_tokens for r in a] == [r.max_new_tokens for r in c]
+    assert any((x.prompt != y.prompt).any() for x, y in zip(a, c))
+    # another order seed: the same multiset in another order
+    monkeypatch.setattr(traffic, "ORDER_SEED", traffic.ORDER_SEED + 1)
+    other = traffic.open_loop(mix, 32000, 3, 51)
+    assert [r.max_new_tokens for r in a] != [r.max_new_tokens for r in other]
+    assert sorted(r.max_new_tokens for r in a) == sorted(r.max_new_tokens for r in other)
+    assert sorted(r.prompt.size for r in a) == sorted(r.prompt.size for r in other)
+
+
+@pytest.mark.parametrize("name,rate", [("chat-short", 4.5), ("chat-short-olmoe", 4.0),
+                                       ("longctx-decode", 1.6), ("longctx-decode", 1.8)])
+def test_the_order_is_the_one_the_sweep_ran_under_its_seed(name, rate):
+    """PR 34's sweeps let each seed order its own window; one of their seeds is
+    ``ORDER_SEED``. So a window of any ``--seed`` offers, at a swept rate, the
+    very gaps and lengths of that sweep window: the knee was read under the
+    order the cell runs."""
+    mix = traffic.load_mix(name)
+    rng = np.random.RandomState(2147490101)         # as the sweep's generator drew them
+    due = traffic.arrival_times(mix["arrivals"], rate, 51, rng)
+    plen = traffic.stratified_lengths(mix["prompt_tokens"], due.size, rng)
+    alen = traffic.stratified_lengths(mix["answer_tokens"], due.size, rng)
+    got = traffic.open_loop(mix, 32000, 7, 51, rate)
+    assert traffic.ORDER_SEED == 2147490101 and len(got) == round(rate * 51)
+    assert [r.due_s for r in got] == list(due)
+    assert [r.prompt.size for r in got] == list(plen)
+    assert [r.max_new_tokens for r in got] == list(alen)
 
 
 @pytest.mark.parametrize("name", ["chat-short", "longctx-decode"])

@@ -134,12 +134,24 @@ def _routing_sums(stats: PyTree, live: jax.Array) -> jax.Array:
     (summed over layers), assignments of real tokens, layers run with a real
     token in them — from the ``(layers, tokens, experts)`` choice masks ``MoE``
     sows and ``live``, which tokens are real (a decode step's live rows, an
-    insert's prompt positions), in any shape of ``tokens`` elements."""
+    insert's prompt positions), in any shape of ``tokens`` elements. The
+    experts are those HELD and the layers those with experts. Where the
+    layer holds a share of a wider router's experts (``moe/layer.py``) a
+    fourth sum follows: every pick of the real tokens, absent experts'
+    included (``(layers, tokens)`` counts sown as ``routed``)."""
+    by_name: Dict[str, list] = {"chosen": [], "routed": []}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(stats)[0]:
+        name = next(k for k in by_name if f"['{k}']" in jax.tree_util.keystr(path))
+        by_name[name].append(leaf)
     chosen = jnp.concatenate([c.reshape(-1, *c.shape[-2:])
-                              for c in jax.tree.leaves(stats)])
+                              for c in by_name["chosen"]])
     chosen = chosen & live.reshape(-1)[None, :, None]
-    return jnp.stack([jnp.sum(jnp.any(chosen, axis=1)), jnp.sum(chosen),
-                      chosen.shape[0] * jnp.any(live)]).astype(jnp.int32)
+    sums = [jnp.sum(jnp.any(chosen, axis=1)), jnp.sum(chosen),
+            chosen.shape[0] * jnp.any(live)]
+    if by_name["routed"]:
+        routed = jnp.concatenate([r.reshape(-1, r.shape[-1]) for r in by_name["routed"]])
+        sums.append(jnp.sum(jnp.where(live.reshape(-1)[None, :], routed, 0)))
+    return jnp.stack(sums).astype(jnp.int32)
 
 
 def infer_prompt_lengths(prompt_ids: np.ndarray, pad_token_id: int = 0) -> np.ndarray:
@@ -372,6 +384,10 @@ class CausalLM:
         # a config with experts: the fused session decode counts what its
         # router chose (three sums, one more output; see its docstring)
         self.moe_stats = getattr(self.config, "num_experts", 0) > 1
+        # three routing sums, or four where the layer holds a share of the
+        # routed experts (``_routing_sums``)
+        routed = getattr(self.config, "router_experts", None)
+        self.moe_sums = 4 if routed and routed != self.config.num_experts else 3
 
     # --- compilation (reference ModelBuilder.trace over CTX/TKG) ---------
 
@@ -850,7 +866,7 @@ class CausalLM:
             init = ((cache, tok, counts, lengths, done, gstate0) if gr
                     else (cache, tok, counts, lengths, done))
             if moe:
-                init = (*init, jnp.zeros((3,), jnp.int32))
+                init = (*init, jnp.zeros((self.moe_sums,), jnp.int32))
             carry, toks = jax.lax.scan(body, init, None, length=steps)
             cache, tok, _counts, lengths, done = carry[:5]
             last = self._replicate_out((carry[-1],)) if moe else ()
@@ -1193,8 +1209,10 @@ class CausalLM:
             sums = ()
             if moe:
                 with jax.named_scope("bookkeeping"):
-                    grouped_rows = (self.config.num_layers * rows * bucket
-                                    * self.config.top_k)
+                    expert_layers = self.config.num_layers - getattr(
+                        self.config, "first_k_dense", 0)
+                    grouped_rows = (expert_layers * rows * bucket
+                                    * min(self.config.top_k, self.config.num_experts))
                     sums = self._replicate_out((jnp.concatenate([
                         _routing_sums(mut["moe_stats"], live),
                         jnp.full((1,), grouped_rows, jnp.int32)]),))

@@ -338,6 +338,10 @@ _STAT_KEYS = (
     # (models with experts; CausalLM.compile_session_decode_fused): expert
     # slots touched, assignments, layer steps, each summed over steps x layers
     "moe_experts_touched", "moe_assignments", "moe_layer_steps",
+    # every pick of those rows' routers, picks of experts held elsewhere
+    # included (moe/layer.py's share of a wider expert layer); equal to
+    # moe_assignments where every expert is held
+    "moe_assignments_routed",
     # the same three over the real tokens of the paged inserts (bucket
     # padding chooses nothing; CausalLM._paged_insert_programs), and the
     # grouped rows the inserts' experts ran, real or not: assignments / rows
@@ -3624,10 +3628,11 @@ class ServeEngine:
     def _count_routing(self, sums, block: Optional[int] = None) -> None:
         """One fused block's routing sums into ``stats`` and onto a counter
         track (the share of the expert slots the live rows touched)."""
-        touched, assigned, layer_steps = (int(x) for x in sums)
+        touched, assigned, layer_steps, *routed = (int(x) for x in sums)
         self.stats["moe_experts_touched"] += touched
         self.stats["moe_assignments"] += assigned
         self.stats["moe_layer_steps"] += layer_steps
+        self.stats["moe_assignments_routed"] += routed[0] if routed else assigned
         if self.tracer.enabled and layer_steps:
             self.tracer.counter(
                 "moe_experts_touched_share", (self.lane, "blocks"),
@@ -3636,9 +3641,11 @@ class ServeEngine:
 
     def _count_insert_routing(self, sums) -> None:
         """One paged insert's routing sums into ``stats``."""
-        for name, x in zip(("experts_touched", "assignments", "layer_calls",
-                            "rows"), sums):
+        *routing, rows = sums        # (3 or 4 routing sums, grouped rows)
+        for name, x in zip(("experts_touched", "assignments", "layer_calls"),
+                           routing):
             self.stats["moe_insert_" + name] += int(x)
+        self.stats["moe_insert_rows"] += int(rows)
 
     def step_block(self) -> bool:
         """One scheduling round: drain recovery replays, admit (expire/shed

@@ -20,10 +20,12 @@ RowParallel down MLP, sequence-parallel norms) re-designed for TPU:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+import math
+from typing import Any, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from neuronx_distributed_tpu.ops.attention import attention
@@ -55,6 +57,43 @@ class RopeScaling:
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_position_embeddings: int = 8192
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling as DeepSeek-V2 publishes it (HF ``rope_scaling``
+    ``type: "yarn"``, ``DeepseekV2YarnRotaryEmbedding``): each rotary dim
+    blends its own frequency with the same over ``factor``, by a linear ramp
+    between the dims whose wavelength makes ``beta_fast`` and ``beta_slow``
+    turns in ``original_max_position_embeddings`` positions; cos and sin are
+    scaled by ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``."""
+
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def get_mscale(factor: float, mscale: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+    def frequencies(self, dim: int, theta: float):
+        """``(inv_freq (dim / 2,) float32, amplitude of cos and sin)``."""
+        orig = self.original_max_position_embeddings
+
+        def correction_dim(turns):
+            return dim * math.log(orig / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+        low = max(math.floor(correction_dim(self.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(self.beta_slow)), dim - 1)
+        own = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+        ramp = np.clip((np.arange(dim // 2) - low) / (max(high - low, 0.001)), 0.0, 1.0)
+        inv_freq = own / self.factor * ramp + own * (1.0 - ramp)
+        amplitude = (self.get_mscale(self.factor, self.mscale)
+                     / self.get_mscale(self.factor, self.mscale_all_dim))
+        return inv_freq.astype(np.float32), amplitude
 
 
 @dataclasses.dataclass(frozen=True)
@@ -241,11 +280,17 @@ def llama3_70b(**over) -> LlamaConfig:
 
 def rotary_embedding(positions: jax.Array, head_dim: int, theta: float,
                      dtype=jnp.float32,
-                     scaling: Optional[RopeScaling] = None,
+                     scaling: Union[RopeScaling, YarnScaling, None] = None,
                      ) -> Tuple[jax.Array, jax.Array]:
     """cos/sin tables for the given positions, (seq, head_dim/2).
     ``scaling`` applies the Llama-3.1 piecewise frequency stretch (matches
-    transformers' ``_compute_llama3_parameters``)."""
+    transformers' ``_compute_llama3_parameters``), or YaRN's blend with its
+    amplitude on cos and sin (:class:`YarnScaling`)."""
+    if isinstance(scaling, YarnScaling):
+        inv_freq, amplitude = scaling.frequencies(head_dim, theta)
+        angles = positions.astype(jnp.float32)[..., None] * inv_freq
+        return ((jnp.cos(angles) * amplitude).astype(dtype),
+                (jnp.sin(angles) * amplitude).astype(dtype))
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
     if scaling is not None:
         s = scaling
@@ -352,7 +397,16 @@ def kv_leaf_shapes(cfg: LlamaConfig, batch: int) -> dict:
     framing, handoff CRCs and donation cover them without special cases;
     all-zero scales dequantize unwritten pages to exact zeros), or the
     contiguous ``(batch, max_seq_len, n_kv, hd)`` slab. ``LlamaModel``
-    declares each leaf once, stacked ``(num_layers, *shape)``."""
+    declares each leaf once, stacked ``(num_layers, *shape)``.
+
+    A configuration whose attention caches something else says so itself
+    (``cfg.kv_leaf_shapes(batch)``; ``models/deepseek_v2.py``: ONE latent leaf
+    under the name ``cached_key``, head axis 1, and no value leaf). Whatever
+    finds pages by leaf name (page IO, partition specs, ``kv_cache_bytes``)
+    goes by the names chosen here."""
+    own = getattr(cfg, "kv_leaf_shapes", None)
+    if own is not None:
+        return own(batch)
     n_kv = cfg.num_kv_heads * cfg.kv_size_multiplier
     hd = cfg.head_dim_
     if not cfg.page_size:
@@ -868,6 +922,9 @@ class LlamaModel(nn.Module):
 
     config: LlamaConfig
     layer_cls: Any = None
+    # block of the ``config.first_k_dense`` leading layers (DeepSeek's dense
+    # layers before its expert layers); unused where the config has none
+    dense_layer_cls: Any = None
 
     def setup(self):
         cfg = self.config
@@ -883,15 +940,25 @@ class LlamaModel(nn.Module):
         # "cache" only the small per-layer leaves (cache_index, block_table)
         # are scanned: the K/V leaves are declared in __call__, above the
         # scan, and ride its carry (KVLayerView).
-        self.layers = nn.scan(
-            _LayerStep,
-            variable_axes={"params": 0, "cache": 0, "losses": 0,
-                           "adapters": 0, "moe_stats": 0},
-            split_rngs={"params": True},
-            length=cfg.num_layers,
-            in_axes=nn.broadcast,
-            metadata_params={nn.meta.PARTITION_NAME: None},
-        )(cfg, self.layer_cls)
+        def stack(length, layer_cls):
+            return nn.scan(
+                _LayerStep,
+                variable_axes={"params": 0, "cache": 0, "losses": 0,
+                               "adapters": 0, "moe_stats": 0},
+                split_rngs={"params": True},
+                length=length,
+                in_axes=nn.broadcast,
+                metadata_params={nn.meta.PARTITION_NAME: None},
+            )(cfg, layer_cls)
+
+        # leading layers of another kind are a scan of their own, BEFORE the
+        # main one; the carry (layer index, K/V leaves) runs through both, so
+        # they take rows 0..k-1 of the same stacked pool. Without them the
+        # parameter tree and the programs are what they always were.
+        first_k = getattr(cfg, "first_k_dense", 0)
+        if first_k:
+            self.dense_layers = stack(first_k, self.dense_layer_cls)
+        self.layers = stack(cfg.num_layers - first_k, self.layer_cls)
         self.final_norm = cfg.make_norm()
 
     @nn.compact
@@ -939,7 +1006,11 @@ class LlamaModel(nn.Module):
         args = (rope, chunk_ctx, live, stack)
         while args[-1] is None:     # dense models, training: (rope,) as ever
             args = args[:-1]
-        (x, kv), _ = self.layers((x, kv), *args)
+        carry = (x, kv)
+        if getattr(cfg, "first_k_dense", 0):
+            carry, _ = self.dense_layers(
+                carry, *((rope,) if chunk_ctx is None else (rope, chunk_ctx)))
+        (x, kv), _ = self.layers(carry, *args)
         for name, pool in pools.items():
             pool.value = kv[1][name]
         return self.final_norm(x)
@@ -974,10 +1045,11 @@ class LlamaForCausalLM(nn.Module):
 
     config: LlamaConfig
     layer_cls: Any = None  # decoder-block override (e.g. Mixtral's MoE layer)
+    dense_layer_cls: Any = None  # block of ``config.first_k_dense`` leading layers
 
     def setup(self):
         cfg = self.config
-        self.model = LlamaModel(cfg, self.layer_cls)
+        self.model = LlamaModel(cfg, self.layer_cls, self.dense_layer_cls)
         if not cfg.tie_word_embeddings:
             # logits matmul runs in the compute dtype (bf16 MXU rate); the
             # vocab-parallel CE upcasts to fp32 for the softmax/LSE math

@@ -57,7 +57,7 @@ def _silu_gated(gate: jax.Array, up: jax.Array) -> jax.Array:
 
 
 def sort_by_expert(combine: jax.Array, top_k: int,
-                   live: Optional[jax.Array] = None):
+                   live: Optional[jax.Array] = None, share: bool = False):
     """The ``top_k`` (token, expert) assignments of ``combine (T, E)`` sorted
     by expert (a stable sort: by token within an expert), those of tokens
     that are not ``live`` (T,) last and in no group. Returns ``weight (T, k)``,
@@ -68,12 +68,20 @@ def sort_by_expert(combine: jax.Array, top_k: int,
     A counting sort: an assignment's place is its expert's first row plus the
     number of earlier tokens that chose that expert. ``lax.sort`` gives the
     same permutation and the TPU compiler needs 14 s for it at an OLMoE
-    8 x 512 insert's 32 768 keys, in each of a cell's 16 insert programs."""
+    8 x 512 insert's 32 768 keys, in each of a cell's 16 insert programs.
+
+    ``share``: ``combine`` is the held share of a wider router's choices
+    (``moe/layer.py``), so a token has up to ``top_k`` nonzero weights here
+    and often none. A choice of weight zero fell on an absent expert: it
+    joins no group, exactly as a dead token's."""
     T, E = combine.shape
     k, C = top_k, E + 1                # column E: no expert, a dead token's
     weight, expert = lax.top_k(combine, k)                         # (T, k)
     if live is not None:
         expert = lax.select(lax.broadcast_in_dim(live, (T, k), (0,)), expert,
+                            lax.full_like(expert, E))
+    if share:
+        expert = lax.select(lax.gt(weight, lax.full_like(weight, 0)), expert,
                             lax.full_like(expert, E))
     # (lax, not jnp, from here on: these few dozen equations are traced and
     # lowered anew for every serving program, and a jnp call costs three)
@@ -89,10 +97,14 @@ def sort_by_expert(combine: jax.Array, top_k: int,
         lax.select(chose, lax.broadcast_in_dim(before, (T, k, C), (0, 2)), zeros), (2,))
     # a token's choices are distinct experts; those of a dead token are all
     # column E, and keep their order within the token
+    dead = lax.eq(expert, np.int32(E))
+    if share:      # some of a token's choices: their rank among its dead ones
+        rank = lax.sub(lax.cumsum(lax.convert_element_type(dead, jnp.int32), axis=1),
+                       lax.full((T, k), 1, jnp.int32))
+    else:
+        rank = lax.broadcast_in_dim(np.arange(k, dtype=np.int32), (T, k), (1,))
     place = lax.add(place, lax.select(
-        lax.eq(expert, np.int32(E)),
-        lax.broadcast_in_dim(np.arange(k, dtype=np.int32), (T, k), (1,)),
-        lax.full((T, k), 0, jnp.int32))).reshape(T * k)
+        dead, rank, lax.full((T, k), 0, jnp.int32))).reshape(T * k)
     order = jnp.zeros((T * k,), jnp.int32).at[place].set(
         np.arange(T * k, dtype=np.int32),
         mode="promise_in_bounds", unique_indices=True)
@@ -105,9 +117,10 @@ def token_class(tokens: int) -> int:
     return max(8, 1 << (tokens - 1).bit_length())
 
 
-@functools.partial(jax.jit, static_argnames=("top_k", "glu", "dtype", "interpret"))
+@functools.partial(jax.jit, static_argnames=("top_k", "glu", "dtype", "interpret",
+                                              "share"))
 def _grouped_experts(x, combine, live, layer, gate, up, down, *, top_k, glu,
-                     dtype, interpret):
+                     dtype, interpret, share=False):
     """``ExpertMLPs.forward_grouped`` on a whole token class: ``x (T, H)``,
     ``combine (T, E)``, ``live (T,)``, the weight stacks ``(L, E, ...)`` and
     this layer's index. A function of the module, jitted, so that its trace
@@ -115,7 +128,7 @@ def _grouped_experts(x, combine, live, layer, gate, up, down, *, top_k, glu,
     on a serving host) is kept by shape and dtype: a serving cell's 18
     programs (one per insert shape) hold seven token classes between them."""
     T, H = x.shape
-    weight, order, place, group_sizes = sort_by_expert(combine, top_k, live)
+    weight, order, place, group_sizes = sort_by_expert(combine, top_k, live, share)
     tm, rows = row_tile(T * top_k)
     visits = group_visits(group_sizes, rows, tm)
     xs = x.astype(dtype).at[jax.lax.div(order, np.int32(top_k))].get(  # (M, H)
@@ -130,7 +143,9 @@ def _grouped_experts(x, combine, live, layer, gate, up, down, *, top_k, glu,
     # kernel's buffer held, so they are selected away, not scaled
     out = out.at[place].get(mode="promise_in_bounds", unique_indices=True
                             ).reshape(T, top_k, H)
-    out = jnp.where(live[:, None, None], out, 0)
+    # (under ``share`` a choice of an absent expert sits in no group either)
+    real = (live[:, None, None] & (weight > 0)[:, :, None]) if share else live[:, None, None]
+    out = jnp.where(real, out, 0)
     return jnp.einsum("tkh,tk->th", out.astype(jnp.float32),
                       weight.astype(jnp.float32))
 
@@ -222,7 +237,7 @@ class ExpertMLPs(nn.Module):
 
     def forward_grouped(self, x: jax.Array, combine: jax.Array, top_k: int,
                         live: Optional[jax.Array] = None,
-                        stack=None) -> jax.Array:
+                        stack=None, share: bool = False) -> jax.Array:
         """x: (T, H); combine: (T, E) with ``top_k`` nonzeros a row; ``live``
         (T,) bool says which tokens are real (None: all). Every real
         (token, expert) assignment is computed, none dropped, so the result
@@ -233,7 +248,11 @@ class ExpertMLPs(nn.Module):
         whole layer stack, ``(L, E, ...)`` each, and this layer's index: under
         a layer scan this module's own weights are a slice of that stack,
         which a kernel could only be handed as a copy of all ``E`` experts
-        (``models/mixtral.py::MixtralDecoderLayer.layer_stack``)."""
+        (``models/mixtral.py::MixtralDecoderLayer.layer_stack``).
+
+        ``share``: ``combine`` holds the held experts' columns of a wider
+        router's choices; a row has at most ``top_k`` nonzeros and those that
+        fell elsewhere are nobody's here (:func:`sort_by_expert`)."""
         T, H = x.shape
         if stack is None or stack[1]["gate"].dtype != self.dtype:
             # (a stack kept in another dtype would be cast whole, every layer)
@@ -246,19 +265,21 @@ class ExpertMLPs(nn.Module):
         out = _grouped_experts(
             jnp.pad(x, ((0, pad), (0, 0))), jnp.pad(combine, ((0, pad), (0, 0))),
             jnp.pad(live, (0, pad)), jnp.asarray(layer, jnp.int32),
-            w["gate"], w["up"], w["down"], top_k=top_k, glu=self.glu,
-            dtype=jnp.dtype(self.dtype), interpret=kernel_mode.interpret_kernels())
+            w["gate"], w["up"], w["down"], top_k=min(top_k, self.num_experts),
+            glu=self.glu, dtype=jnp.dtype(self.dtype),
+            interpret=kernel_mode.interpret_kernels(), share=share)
         return out[:T].astype(x.dtype)
 
     def __call__(self, x: jax.Array, combine: jax.Array,
                  top_k: Optional[int] = None,
-                 live: Optional[jax.Array] = None, stack=None) -> jax.Array:
+                 live: Optional[jax.Array] = None, stack=None,
+                 share: bool = False) -> jax.Array:
         # int8 leaves ({"qweight", "scale"}) keep all_experts, whose einsums
         # fuse the dequantisation: no cell serves them
         if self.mode == "grouped" and not isinstance(self.w_gate, Mapping):
             if top_k is None:
                 raise ValueError("grouped mode needs the router's top_k")
-            return self.forward_grouped(x, combine, top_k, live, stack)
+            return self.forward_grouped(x, combine, top_k, live, stack, share)
         if self.mode == "capacity_factor":
             return self.forward_capacity_factor(x, combine)
         if self.mode in ("all_experts", "grouped"):

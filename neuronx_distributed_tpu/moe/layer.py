@@ -50,6 +50,20 @@ class MoE(nn.Module):
     # sharded expert axis would all-gather the weights) or tp > 1 (the kernel
     # is not partitioned) serving runs all_experts, as do int8 leaves.
     inference: bool = False
+    # the share of a wider expert layer that lives here (expert parallelism
+    # seen from ONE chip; models/deepseek_v2.py): the router is
+    # ``router_experts`` wide and picks ``top_k`` of ALL of them, this module
+    # holds experts ``experts_held_first .. + num_experts`` and computes their
+    # part of the result; a pick that fell on an absent expert is dropped
+    # before the sort as a dead row's is, and what the absent experts would
+    # have added is left out (no exchange, nothing stands in for them). None:
+    # every expert is held, today's layer.
+    router_experts: Optional[int] = None
+    experts_held_first: int = 0
+    # DeepSeek's group-limited selection and route scale (moe/routing.py)
+    n_group: int = 1
+    topk_group: int = 1
+    route_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x: jax.Array, live: Optional[jax.Array] = None,
@@ -67,19 +81,33 @@ class MoE(nn.Module):
             raise ValueError(f"input hidden dim {h} != configured hidden_size {self.hidden_size}")
         flat = x.reshape(b * s, h)
 
+        routed = self.router_experts or self.num_experts
+        share = routed != self.num_experts     # only some of the routed are held
         if self.router == "top_k":
-            router = RouterTopK(self.num_experts, top_k=self.top_k,
-                                norm_topk_prob=self.norm_topk_prob, name="router")
+            router = RouterTopK(routed, top_k=self.top_k,
+                                norm_topk_prob=self.norm_topk_prob,
+                                n_group=self.n_group, topk_group=self.topk_group,
+                                route_scale=self.route_scale, name="router")
         elif self.router == "sinkhorn":
-            router = RouterSinkhorn(self.num_experts, name="router")
+            router = RouterSinkhorn(routed, name="router")
         else:
             raise ValueError(f"unknown router {self.router!r}")
         combine, logits = router(flat)
+        picks = combine
+        if share:
+            combine = jax.lax.slice_in_dim(
+                combine, self.experts_held_first,
+                self.experts_held_first + self.num_experts, axis=1)
         if self.inference and self.is_mutable_collection("moe_stats"):
-            # the (tokens, experts) choices of the router, whatever `live`
+            # the (tokens, experts held) choices of the router, whatever `live`
             # says, for the routing counters of the fused session decode and
-            # the paged insert (inference/causal_lm.py::_routing_sums)
+            # the paged insert (inference/causal_lm.py::_routing_sums); beside
+            # them, where only a share is held, each token's picks among ALL
+            # the routed experts
             self.sow("moe_stats", "chosen", combine > 0)
+            if share:
+                self.sow("moe_stats", "routed",
+                         jnp.sum(picks > 0, axis=-1, dtype=jnp.int32))
 
         mode = self.mode
         if self.inference and mode == "capacity_factor":
@@ -97,9 +125,9 @@ class MoE(nn.Module):
         )
         out = experts(flat, combine.astype(flat.dtype), top_k=self.top_k,
                       live=None if live is None else live.reshape(b * s),
-                      stack=stack).reshape(b, s, h)
+                      stack=stack, share=share).reshape(b, s, h)
 
-        aux = self.aux_loss_coef * load_balancing_loss(logits, combine, self.num_experts)
+        aux = self.aux_loss_coef * load_balancing_loss(logits, picks, routed)
         if self.z_loss_coef:
             aux = aux + self.z_loss_coef * router_z_loss(logits)
         self.sow("losses", "moe_aux_loss", aux)

@@ -21,11 +21,21 @@ class RouterTopK(nn.Module):
     ``combine_weights`` is (T, E) with exactly ``top_k`` nonzeros per row,
     renormalized to sum 1 (reference RouterTopK, routing.py:89-121) unless
     ``norm_topk_prob`` is off (HF's key: OLMoE keeps the chosen softmax
-    probabilities as they are, so a row sums to less than one)."""
+    probabilities as they are, so a row sums to less than one).
+
+    ``n_group > 1`` is DeepSeek-V2's ``group_limited_greedy``: the experts are
+    ``n_group`` groups of consecutive experts, a group scores the largest
+    probability among its own, the ``topk_group`` best groups stay and the
+    top-k is taken inside them. ``route_scale`` multiplies the weights
+    (``routed_scaling_factor``). The scores are the softmax's, in float32,
+    whatever the selection."""
 
     num_experts: int
     top_k: int = 2
     norm_topk_prob: bool = True
+    n_group: int = 1
+    topk_group: int = 1
+    route_scale: float = 1.0
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -37,14 +47,30 @@ class RouterTopK(nn.Module):
                        self.param_dtype)
         logits = (x.astype(jnp.float32) @ w.astype(jnp.float32))
         probs = jax.nn.softmax(logits, axis=-1)
-        topv, topi = jax.lax.top_k(probs, self.top_k)
+        eligible = probs
+        if self.n_group > 1:
+            with jax.named_scope("router_groups"):
+                eligible = probs * group_limit(probs, self.n_group, self.topk_group)
+        topv, topi = jax.lax.top_k(eligible, self.top_k)
         mask = jnp.sum(jax.nn.one_hot(topi, self.num_experts, dtype=probs.dtype), axis=-2)
         gates = probs * mask
-        if not self.norm_topk_prob:
-            return gates, logits
-        denom = jnp.sum(gates, axis=-1, keepdims=True)
-        combine = gates / jnp.maximum(denom, 1e-9)
-        return combine, logits
+        if self.norm_topk_prob:
+            denom = jnp.sum(gates, axis=-1, keepdims=True)
+            gates = gates / jnp.maximum(denom, 1e-9)
+        if self.route_scale != 1.0:
+            gates = gates * self.route_scale
+        return gates, logits
+
+
+def group_limit(probs: jax.Array, n_group: int, topk_group: int) -> jax.Array:
+    """``(T, E)`` mask, one over the experts of each token's ``topk_group``
+    best groups (a group's score: its largest probability; a tie goes to the
+    lower group, as ``lax.top_k`` breaks it) and zero over the rest."""
+    T, E = probs.shape
+    best = jnp.max(probs.reshape(T, n_group, E // n_group), axis=-1)
+    _, groups = jax.lax.top_k(best, topk_group)
+    keep = jnp.sum(jax.nn.one_hot(groups, n_group, dtype=probs.dtype), axis=-2)
+    return jnp.repeat(keep, E // n_group, axis=-1)
 
 
 class RouterSinkhorn(nn.Module):

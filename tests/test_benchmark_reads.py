@@ -177,6 +177,9 @@ MOE_INSERT_STATS = ["moe_insert_experts_touched", "moe_insert_assignments",
 # `engine_stats` the same way; (calls + fetches) / inserts reads 2.0 where every
 # admission was one program and one fetch
 INSERT_HOST_OPS = ["insert_program_calls", "insert_host_fetches"]
+# every pick of the live rows' routers (PR 37): equal to `moe_assignments` where
+# every expert is held; more where the layer holds a share (tests/test_deepseek_v2.py)
+ROUTED_READS = ["moe_assignments_routed"]
 TRAIN_READS = ["jit_step_fn"]
 
 
@@ -192,6 +195,15 @@ def test_routing_counter_is_produced(run, moe_run, key):
     assert run.engine.stats[key] == 0
     if key == "moe_assignments":      # at least one live row chose top_k experts a layer step
         assert moe_run.engine.stats[key] >= moe_run.engine.stats["moe_layer_steps"] * 2
+
+
+@pytest.mark.parametrize("key", ROUTED_READS)
+def test_routed_assignment_counter_is_produced(run, moe_run, key):
+    """``moe.local_assignment_share`` divides by it: in ``engine.stats`` of
+    every engine, counted with the other three, and all of them local where
+    the layer holds every expert it routes over."""
+    assert key in dict(run.engine.stats.items()) and run.engine.stats[key] == 0
+    assert moe_run.engine.stats[key] == moe_run.engine.stats["moe_assignments"] > 0
 
 
 @pytest.mark.parametrize("key", MOE_INSERT_STATS)
@@ -242,7 +254,7 @@ def test_every_name_is_still_read():
              *sorted((BENCHMARK / "layer_metrics").glob("*.py")),
              BENCHMARK / "trace_parts.py", BENCHMARK / "trace_reduce.py"]
     text = "\n".join(f.read_text() for f in files)
-    names = [n for n, _ in SERVING_READS] + MOE_READS + TRAIN_READS
+    names = [n for n, _ in SERVING_READS] + MOE_READS + ROUTED_READS + TRAIN_READS
     assert len(set(names)) == len(names)
     missing = [n for n in names if n not in text]
     assert not missing, missing

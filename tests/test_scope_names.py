@@ -38,6 +38,17 @@ SCOPES = {
     "trainer/step.py": ["grad_accumulate", "grad_clip", "optimizer_update"],
     "parallel/loss.py": ["loss"],
 }
+# DeepSeek-V2's scopes (PR 37). ``scope_parts.json`` is the benchmark's and has
+# no rows for the new ones yet: ``kv_write``/``kv_gather``/``attend`` sort as
+# ever, the rest fall to their flax module's part or to ``named_other``.
+LATENT_SCOPES = {
+    "models/deepseek_v2.py DeepseekV2Attention": ["mla_q", "mla_kv_down", "kv_write",
+                                                  "kv_gather", "attend"],
+    "models/deepseek_v2.py decode only": ["mla_absorb"],
+    "models/deepseek_v2.py prompt only": ["mla_kv_up"],
+    "moe/routing.py RouterTopK": ["router_groups"],
+    "models/deepseek_v2.py DeepseekV2MoELayer": ["shared_expert"],
+}
 KERNELS = ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode", "fused_adamw",
            "grouped_matmul"]
 
@@ -119,6 +130,36 @@ def test_olmoe_names_qk_norm_router_and_experts(program):
         if m and "grouped_matmul" in m.group(1):
             assert trace_parts.part_of({"tf_op": m.group(1) + ":"}, TABLE) == "experts", line
     assert unnamed_share(parts) < 0.2, parts
+
+
+@pytest.mark.parametrize("program", ["fused_decode", "paged_insert"])
+def test_deepseek_v2_names_latent_attention_router_groups_and_shared_expert(program):
+    """The tiny DeepSeek-V2 decode block carries the absorbed form's scope and
+    not the up-projection's, the paged insert the other way round; both carry
+    the latent write and gather under the names the GQA path uses, the group
+    selection, the shared expert and the grouped matmul over the held experts."""
+    from neuronx_distributed_tpu.models.deepseek_v2 import DeepseekV2Config, DeepseekV2ForCausalLM
+
+    cfg = DeepseekV2Config(**dict(
+        TINY, num_layers=3, num_kv_heads=4, kv_lora_rank=16, q_lora_rank=24,
+        qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8, moe_intermediate_size=32,
+        router_experts=16, num_experts=4, n_group=4, topk_group=2, top_k=4,
+        use_flash_attention=False))
+    weights = meta.unbox(DeepseekV2ForCausalLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    lm = CausalLM(cfg, weights, DeepseekV2ForCausalLM, buckets=(128,), max_batch=2, page_size=16)
+    decode = program == "fused_decode"
+    compiled = (lm.compile_session_decode_fused(4) if decode
+                else lm._paged_insert_programs(2, 128))
+    components, parts = census(compiled)
+    want = {n for where, names in LATENT_SCOPES.items() for n in names
+            if "only" not in where} | {"grouped_matmul"}
+    want.add("mla_absorb" if decode else "mla_kv_up")
+    assert want <= components, sorted(want - components)
+    assert ("mla_kv_up" if decode else "mla_absorb") not in components
+    assert "dense_layers" in components and "layers" in components
+    for part in ("kv_write", "kv_gather", "attend", "router", "experts", "ffn", "norm"):
+        assert parts[part] > 0, part
 
 
 def test_dense_decode_has_no_qk_norm_scope(params):

@@ -37,6 +37,7 @@ from neuronx_distributed_tpu.inference.partition import (
     zeros_like_avals,
 )
 from neuronx_distributed_tpu.inference.sampling import Sampler, SlotSampler
+from neuronx_distributed_tpu.models.llama import KVWalk
 
 PyTree = Any
 
@@ -152,6 +153,22 @@ def _routing_sums(stats: PyTree, live: jax.Array) -> jax.Array:
         routed = jnp.concatenate([r.reshape(-1, r.shape[-1]) for r in by_name["routed"]])
         sums.append(jnp.sum(jnp.where(live.reshape(-1)[None, :], routed, 0)))
     return jnp.stack(sums).astype(jnp.int32)
+
+
+def _walk_sums(config, cache: PyTree, live: jax.Array) -> jax.Array:
+    """``(2,) int32`` of one decode step: the slots of the cache the step read
+    of every row (:class:`~neuronx_distributed_tpu.models.llama.KVWalk`, chunk
+    rounding included; all of ``max_seq_len`` under ``paged_attn_kernel``,
+    whose grid visits every page), and 1; both 0 where no row is live. From
+    the cache's own ``cache_index`` and the ``live`` the model is given, so it
+    is the bound the attention computed."""
+    idx = next(leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+               if jax.tree_util.keystr(path).endswith("['cache_index']"))[0]
+    if config.paged_attn_kernel:
+        tokens = jnp.int32(config.max_seq_len)
+    else:
+        tokens = KVWalk(config.max_seq_len, config.page_size, idx, live).tokens
+    return jnp.stack([tokens, 1]).astype(jnp.int32) * jnp.any(live)
 
 
 def infer_prompt_lengths(prompt_ids: np.ndarray, pad_token_id: int = 0) -> np.ndarray:
@@ -783,21 +800,29 @@ class CausalLM:
         Returns the compiled program ``(params, cache, tok (b,1), slot_keys
         (b,) keys, counts (b,), lengths (b,), active (b,), done (b,),
         eos_ids (b,), temperature (b,), greedy (b,)[, *gr]) -> (tokens
-        (steps, b), cache, next_tok, lengths, done[, dfa_state])``. The
+        (steps, b), cache, next_tok, lengths, done[, dfa_state], walked[,
+        routing])``. The
         trailing ``dfa_state`` rides out only for grammar-enabled lms: the
         async double-buffered loop feeds block t+1's grammar quad from
         block t's OUTPUT without a host fetch, so the final carried state
         must surface as a device value (the sync path ignores it). Cached
         per ``(steps, slot_sampler, pad)``.
 
+        Every model is told which rows are live at each step (``live``:
+        active and not done): they alone set how far the step reads the cache
+        (``models/llama.py::KVWalk``). After the row outputs (and the
+        ``dfa_state``) comes ``(2,) int32``: the slots of the cache the
+        block's steps read of every row, summed over the steps with a live
+        row, and the number of those steps (``_walk_sums``); over ``steps x
+        max_seq_len`` it is the share of the logical slab a step read.
+
         A model with experts (``self.moe_stats``) returns one more value,
         LAST: ``(3,) int32`` sums over the block's steps and the layers of
-        what the router chose for the rows that were live (active and not
-        done) at each step — expert slots touched (experts with a live row),
-        assignments (live rows x top-k x layers) and layer steps with a live
-        row. The same rows are what the model is told is real (``live``), so
-        ``touched / (layer_steps x experts)`` is the share of the expert
-        weights the block read. A dense model's program is unchanged.
+        what the router chose for the rows that were live at each step —
+        expert slots touched (experts with a live row), assignments (live
+        rows x top-k x layers) and layer steps with a live row. Dead rows
+        choose no expert, so ``touched / (layer_steps x experts)`` is the
+        share of the expert weights the block read.
         """
         if steps < 1:
             raise ValueError(f"steps must be >= 1, got {steps}")
@@ -820,17 +845,21 @@ class CausalLM:
             def body(carry, _):
                 if moe:
                     *carry, mstats = carry
+                *carry, walked = carry
                 if gr:
                     cache, tok, counts, lengths, done, gstate = carry
                 else:
                     cache, tok, counts, lengths, done = carry
                 with jax.named_scope("sampler"):
                     sub = jax.vmap(jax.random.fold_in)(slot_keys, counts)
-                live = active & ~done if moe else None
+                live = active & ~done
+                with jax.named_scope("bookkeeping"):
+                    walked = walked + _walk_sums(self.config, cache, live)
                 logits, mut = self.model.apply(
                     self._ad_vars(params, cache, ad), tok,
-                    # dead rows choose no expert (moe/layer.py)
-                    **({"live": live[:, None]} if moe else {}),
+                    # dead rows choose no expert (moe/layer.py) and do not
+                    # set how far the step reads the cache (KVWalk)
+                    live=live[:, None],
                     mutable=["cache", "moe_stats"] if moe else ["cache"]
                 )
                 with jax.named_scope("sampler"):
@@ -858,18 +887,18 @@ class CausalLM:
                     counts = counts + 1
                     lengths = lengths + 1
                     done = done | (active & (lengths + 1 >= max_len))
-                carry = ((mut["cache"], nxt[:, None], counts, lengths, done,
-                          gstate) if gr else
-                         (mut["cache"], nxt[:, None], counts, lengths, done))
-                return ((*carry, mstats) if moe else carry), out
+                return (mut["cache"], nxt[:, None], counts, lengths, done,
+                        *((gstate,) if gr else ()), walked,
+                        *((mstats,) if moe else ())), out
 
             init = ((cache, tok, counts, lengths, done, gstate0) if gr
                     else (cache, tok, counts, lengths, done))
+            init = (*init, jnp.zeros((2,), jnp.int32))
             if moe:
                 init = (*init, jnp.zeros((self.moe_sums,), jnp.int32))
             carry, toks = jax.lax.scan(body, init, None, length=steps)
             cache, tok, _counts, lengths, done = carry[:5]
-            last = self._replicate_out((carry[-1],)) if moe else ()
+            last = self._replicate_out(carry[-2:] if moe else carry[-1:])
             # row outputs pinned replicated: the async loop feeds block
             # t+1's inputs from these COMMITTED values (and edits them with
             # eager staged-override ops), so they must come back in exactly

@@ -355,6 +355,11 @@ _STAT_KEYS = (
     # one of each, so (calls + fetches) / inserts reads 2.0 where every
     # admission was a one-shot insert and more where one went by chunks
     "insert_program_calls", "insert_host_fetches",
+    # how far the fused decode blocks read the cache: slots read of every row
+    # (models/llama.py::KVWalk, chunk rounding included) summed over the
+    # steps that had a live row, and those steps; tokens / (steps x
+    # max_seq_len) is the share of the logical slab a step read
+    "kv_walk_tokens", "kv_walk_steps",
 )
 
 
@@ -773,6 +778,9 @@ class ServeEngine:
         # host mirrors the DFA walk from the fetched emissions (a pure
         # function of the emitted tokens — no extra host ops).
         self.grammar = bool(getattr(lm, "grammar", False))
+        # where a fused block's sums start among its outputs: after the five
+        # row outputs and the DFA state (compile_session_decode_fused)
+        self._walked_at = 6 if self.grammar else 5
         self._gidx = np.zeros((b,), np.int32)
         self._gstate = np.zeros((b,), np.int32)
         self._gbudget = np.zeros((b,), np.int32)
@@ -3625,6 +3633,17 @@ class ServeEngine:
                              block=self.blocks if block is None else block)
         return out
 
+    def _count_block_sums(self, sums, block: Optional[int] = None) -> None:
+        """What one fused block returned beside its rows
+        (``CausalLM.compile_session_decode_fused``): the cache slots its live
+        steps read of every row and the number of those steps, then a model
+        with experts' routing sums."""
+        walked, *routing = sums
+        self.stats["kv_walk_tokens"] += int(walked[0])
+        self.stats["kv_walk_steps"] += int(walked[1])
+        if routing:
+            self._count_routing(routing[0], block)
+
     def _count_routing(self, sums, block: Optional[int] = None) -> None:
         """One fused block's routing sums into ``stats`` and onto a counter
         track (the share of the expert slots the live rows touched)."""
@@ -3760,11 +3779,11 @@ class ServeEngine:
             self.session.lengths = self.session.lengths + self.block_steps
             self.stats["program_calls"] += 1
             self.stats["host_fetches"] += 1
-            if self.lm.moe_stats:      # the routing sums ride the same fetch
-                toks, sums = self._fetch((toks, outs[-1]))
-                self._count_routing(sums)
-                return toks
-            return self._fetch(toks)
+            # the block's sums ride the same fetch: how far its steps read
+            # the cache, then (a model with experts) what its router chose
+            toks, *sums = self._fetch((toks, *outs[self._walked_at:]))
+            self._count_block_sums(sums)
+            return toks
         out = np.zeros((self.block_steps, self.lm.max_batch), np.int64)
         done = self._done.copy()
         temp = jnp.asarray(self._temp)
@@ -4021,7 +4040,7 @@ class ServeEngine:
             self.session.cache = outs[1]
             rec = {"toks": outs[0], "nxt": outs[2], "done": outs[4],
                    "gstate": outs[5] if self.grammar else None,
-                   "moe": outs[-1] if self.lm.moe_stats else None,
+                   "sums": outs[self._walked_at:],
                    "rids": rids, "block": self.blocks}
         self._staged.clear()
         # the device increments lengths/counts unconditionally for every
@@ -4053,11 +4072,11 @@ class ServeEngine:
         its new occupant. The live done-latch gate discards a finished
         row's over-produced tokens, exactly like sync's mid-block
         post-EOS discard."""
-        if rec.get("moe") is not None:
-            toks, sums = self._fetch((rec["toks"], rec["moe"]),
-                                     block=rec["block"])
-            self._count_routing(sums, block=rec["block"])
-        else:
+        if "sums" in rec:
+            toks, *sums = self._fetch((rec["toks"], *rec["sums"]),
+                                      block=rec["block"])
+            self._count_block_sums(sums, block=rec["block"])
+        else:                       # sim mode: nothing ran on a device
             toks = self._fetch(rec["toks"], block=rec["block"])
         self.stats["host_fetches"] += 1
         now = time.perf_counter()

@@ -1,10 +1,11 @@
 """Paged decode-attention Pallas kernel (ISSUE 17 tentpole).
 
-The paged gather path (models/llama.py ``_decode_attention``) materializes
-a ``(b, max_seq_len)`` logical K/V slab from the page pool EVERY decode
-step — per-step HBM traffic and peak footprint both pay the slab price
-even though storage went paged in PR 3. This kernel is the fused
-replacement for the single-token decode step: FlashAttention-style
+The paged gather path (models/llama.py ``_decode_attention``) gathers K/V
+through the block table EVERY decode step: since PR 38 in chunks of whole
+pages up to the reach of the step's longest live row (``KVWalk``; before,
+all ``(b, max_seq_len)`` slots of the logical slab), dead rows inside that
+bound and the unmapped tail of every shorter row included. This kernel is
+the fused replacement for the single-token decode step: FlashAttention-style
 online-softmax tiling (kernels/flash_attn.py idiom) laid over
 PagedAttention's physical page layout, consuming the per-slot block
 tables DIRECTLY.
@@ -283,8 +284,9 @@ def paged_decode_attention(
 
 def reference_paged_attention(q, k_pages, v_pages, block_table, cache_len,
                               *, k_scale=None, v_scale=None, sm_scale=None):
-    """XLA gather oracle: materialize the logical view exactly the way
-    ``_decode_attention``'s gather branch does, then run the dense
+    """XLA gather oracle: materialize the whole ``(b, max_seq_len)`` logical
+    view the way ``_decode_attention``'s gather branch does for a prompt (a
+    one-token step reads a prefix of it, ``KVWalk``), then run the dense
     ``cached_attention`` math — the bit-exactness reference the kernel
     tests compare against (and the int8 dequant reference)."""
     from neuronx_distributed_tpu.models.llama import cached_attention

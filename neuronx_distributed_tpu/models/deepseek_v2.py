@@ -20,8 +20,9 @@ What is this file's and what is the stack's:
   the flash kernel, one row at a time (a row's expanded keys and values at
   4096 positions and 128 heads are 0.33 GB). A decode step attends in the
   ABSORBED form: ``q_nope`` is taken through ``W_uk`` into the latent space,
-  scores and values are einsums over the gathered latent slab, and ``W_uv``
-  brings the result back; keys and values of cached tokens are never
+  scores and values are einsums over the gathered latent pages (as far as
+  the step's longest live row reaches: ``models/llama.py::KVWalk``), and
+  ``W_uv`` brings the result back; keys and values of cached tokens are never
   expanded. The two are the same mathematics (``tests/test_deepseek_v2.py``
   holds them together).
 * **YaRN** rotary over the rope dims: ``models/llama.py::YarnScaling``.
@@ -53,6 +54,7 @@ from flax import linen as nn
 
 from neuronx_distributed_tpu.models.llama import (
     KVLayerView,
+    KVWalk,
     LlamaForCausalLM,
     LlamaMLP,
     YarnScaling,
@@ -160,7 +162,7 @@ class DeepseekV2Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, rope, chunk_ctx=None,
-                 kv: Optional[KVLayerView] = None) -> jax.Array:
+                 kv: Optional[KVLayerView] = None, live=None) -> jax.Array:
         cfg = self.config
         n, nope, rd, vd = (cfg.num_heads, cfg.qk_nope_head_dim,
                            cfg.qk_rope_head_dim, cfg.v_head_dim)
@@ -194,7 +196,7 @@ class DeepseekV2Attention(nn.Module):
             c_kv = norm("kv_a_norm")(down[..., :rank])                 # (b, s, rank)
             k_r = down[..., None, rank:]                               # (b, s, 1, rope)
         if cfg.decode:
-            o = self._cached(q, c_kv, k_r, w_uk, w_uv, kv, chunk_ctx)
+            o = self._cached(q, c_kv, k_r, w_uk, w_uv, kv, chunk_ctx, live)
         else:
             cos, sin = rope
             q = jnp.concatenate(
@@ -237,7 +239,7 @@ class DeepseekV2Attention(nn.Module):
                 use_flash=flash, block_q=blk_q, block_k=blk_k, q_positions=positions[None])
         return o[0].transpose(1, 0, 2)[..., :vd]
 
-    def _cached(self, q, c_kv, k_r, w_uk, w_uv, kv, chunk_ctx):
+    def _cached(self, q, c_kv, k_r, w_uk, w_uv, kv, chunk_ctx, live=None):
         """The serving path: the new tokens' ``[c_kv | k_rope]`` go into the
         latent leaf at their slots (through the block table where paged), and
         the queries attend over what the leaf then holds."""
@@ -250,7 +252,7 @@ class DeepseekV2Attention(nn.Module):
         if chunk_ctx is not None:
             raise ValueError("Medusa tree chunks are not supported with latent attention")
         b, s_new = q.shape[:2]
-        nope, rank, dim = cfg.qk_nope_head_dim, cfg.kv_lora_rank, cfg.latent_dim
+        nope, dim = cfg.qk_nope_head_dim, cfg.latent_dim
         ps, S = cfg.page_size, cfg.max_seq_len
         idx_var = self.variable("cache", "cache_index", lambda: jnp.zeros((b,), jnp.int32))
         idx = idx_var.value
@@ -277,13 +279,13 @@ class DeepseekV2Attention(nn.Module):
                 kv.put(LATENT_LEAF, pool.at[rows, slots].set(
                     latent.astype(pool.dtype), mode="drop"))
             idx_var.value = idx + s_new
-        with jax.named_scope("kv_gather"):
-            pool = kv.flat(LATENT_LEAF)
-            if ps:
-                slab = pool[table].reshape(b, S, dim)       # whole pages, by the table
-            else:
-                slab = jax.lax.dynamic_slice_in_dim(pool, kv.first_row(b), b).reshape(b, S, dim)
         if s_new > 1:
+            with jax.named_scope("kv_gather"):
+                pool = kv.flat(LATENT_LEAF)
+                if ps:
+                    slab = pool[table].reshape(b, S, dim)       # whole pages, by the table
+                else:
+                    slab = jax.lax.dynamic_slice_in_dim(pool, kv.first_row(b), b).reshape(b, S, dim)
             qx = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
             if b == 1:
                 return self._expanded(qx[0], slab[0], slots[0], w_uk, w_uv)[None]
@@ -296,13 +298,36 @@ class DeepseekV2Attention(nn.Module):
         with jax.named_scope("mla_absorb"):
             q_lat = jnp.einsum("bsnd,rnd->bsnr", q[..., :nope], w_uk, **_EXACT)
             q_all = jnp.concatenate([q_lat, q_rope.astype(jnp.float32)], axis=-1)
-        with jax.named_scope("attend"):
-            scores = jnp.einsum("bsnc,bjc->bnsj", q_all, slab, **_EXACT) * cfg.softmax_scale
-            visible = jnp.arange(S)[None, None, :] <= slots[:, :, None]    # (b, 1, S)
-            probs = jax.nn.softmax(jnp.where(visible[:, None], scores, -1e30), axis=-1)
-            o_lat = jnp.einsum("bnsj,bjc->bsnc", probs, slab, **_EXACT)[..., :rank]
+
+        # the step reads as far as its longest live row reaches
+        walk = KVWalk(S, ps, idx, None if live is None else live[:, 0])
+        o_lat = self._walk_attention(q_all, kv, walk, table if ps else None)
         with jax.named_scope("mla_absorb"):
             return jnp.einsum("bsnr,rnd->bsnd", o_lat, w_uv, **_EXACT).astype(q.dtype)
+
+    def _walk_attention(self, q_all, kv, walk: KVWalk, table):
+        """``q_all`` (b, 1, n, latent_dim) float32, one new token a row in the
+        latent space, over the prefix of the cache below ``walk``'s bound;
+        returns the weighted latents ``(b, 1, n, kv_lora_rank)``. By the
+        switch (``KVWalk.prefix``) whatever the table's length: the loop
+        would carry 128 heads x 576 float32 a row through every turn, and
+        read 30.7 ms a step on the v5e where this reads 7.5 and the whole
+        read 8.2 (PERF.md, PR 38)."""
+        cfg = self.config
+        b = q_all.shape[0]
+        pool, first = kv.flat(LATENT_LEAF), kv.first_row(b)
+
+        def attend(count):
+            with jax.named_scope("kv_gather"):
+                slab = walk.span(pool, table, first, 0, count).reshape(
+                    b, count * walk.chunk, cfg.latent_dim)
+            with jax.named_scope("attend"):
+                scores = jnp.einsum("bsnc,bjc->bnsj", q_all, slab, **_EXACT) * cfg.softmax_scale
+                probs = jax.nn.softmax(jnp.where(
+                    walk.visible(0, count)[:, None, None], scores, -1e30), axis=-1)
+                return jnp.einsum("bnsj,bjc->bsnc", probs, slab, **_EXACT)[..., :cfg.kv_lora_rank]
+
+        return attend(1) if walk.n_chunks == 1 else walk.prefix(attend)
 
 
 class DeepseekV2DenseLayer(nn.Module):
@@ -312,10 +337,11 @@ class DeepseekV2DenseLayer(nn.Module):
     config: DeepseekV2Config
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope, chunk_ctx=None, kv=None) -> jax.Array:
+    def __call__(self, x: jax.Array, rope, chunk_ctx=None, kv=None,
+                 live=None) -> jax.Array:
         cfg = self.config
         h = cfg.make_norm(name="input_norm")(x)
-        x = x + DeepseekV2Attention(cfg, name="attention")(h, rope, chunk_ctx, kv)
+        x = x + DeepseekV2Attention(cfg, name="attention")(h, rope, chunk_ctx, kv, live)
         h = cfg.make_norm(name="post_attn_norm")(x)
         return x + LlamaMLP(cfg, name="mlp")(h)
 
@@ -331,7 +357,7 @@ class DeepseekV2MoELayer(nn.Module):
                  stack=None) -> jax.Array:
         cfg = self.config
         h = cfg.make_norm(name="input_norm")(x)
-        x = x + DeepseekV2Attention(cfg, name="attention")(h, rope, kv=kv)
+        x = x + DeepseekV2Attention(cfg, name="attention")(h, rope, kv=kv, live=live)
         h = cfg.make_norm(name="post_attn_norm")(x)
         moe_out = MoE(
             num_experts=cfg.num_experts,

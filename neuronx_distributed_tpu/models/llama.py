@@ -20,6 +20,7 @@ RowParallel down MLP, sequence-parallel norms) re-designed for TPU:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional, Tuple, Union
 
@@ -144,8 +145,11 @@ class LlamaConfig:
     # resolve through per-slot block tables that RIDE THE CACHE COLLECTION,
     # so compiled programs keep their signatures (inference/paged_cache.py).
     # None = the contiguous max_batch x max_seq_len slab. page_size must
-    # divide max_seq_len so the gathered logical view keeps the slab's shape
-    # (that shape equality is what makes paged attention bit-identical).
+    # divide max_seq_len so that a row's table covers exactly the slab's
+    # slots: a prompt attends over the gathered (b, max_seq_len) logical view,
+    # a one-token step over whole pages of it up to its longest live row
+    # (KVWalk), and the slab is read the same two ways, which is what keeps
+    # paged attention bit-identical to it.
     page_size: Optional[int] = None
     page_pool_pages: Optional[int] = None
     # paged-pool storage dtype (paged mode only). None = ``dtype``;
@@ -158,10 +162,11 @@ class LlamaConfig:
     page_dtype: Optional[str] = None
     # fused paged decode attention (inference/paged_kernel.py): the
     # single-token decode step attends straight off the page pool through
-    # the block tables (block-sparse flash tiling) instead of gathering
-    # the (b, max_seq_len) logical slab in-scan. Prefill/chunk widths and
-    # Medusa tree steps keep the gather path — which also stays, at fp32
-    # pages, the bit-exactness reference oracle for this branch.
+    # the block tables (block-sparse flash tiling, every row to its own
+    # length) instead of gathering pages in-scan up to the longest live
+    # row's (KVWalk). Prefill/chunk widths and Medusa tree steps keep the
+    # gather path — which also stays, at fp32 pages, the bit-exactness
+    # reference oracle for this branch.
     paged_attn_kernel: bool = False
     # multi-LoRA serving pool (inference/adapters.py, S-LoRA/Punica): every
     # targeted projection gains per-slot low-rank stacks A (lora_slots,
@@ -467,16 +472,142 @@ class KVLayerView:
             name, value.reshape(self.leaves[name].shape))
 
 
+class KVWalk:
+    """How far a ONE-TOKEN decode step reads the cache, and in what pieces.
+
+    The cache of a row is ``max_seq_len`` slots, cut here into ``n_chunks``
+    chunks of ``chunk`` tokens: whole pages, an eighth of the table but not
+    under 128 tokens, and a divisor of the table so that every chunk is full.
+    A step reads the chunks below ``extent``, the reach of its longest LIVE
+    row (``cache_index + 1``: the row's new token sits at slot
+    ``cache_index``), and that bound is a value the program computes, not a
+    shape: one program serves every extent. ``live`` (b,) bool is what the
+    serving program knows (the fused session decode: active and not done). A
+    retired slot keeps a stale, still-growing ``cache_index`` over a table
+    that points at scratch, a done row keeps counting: neither sets the
+    bound. Without ``live`` every row counts, which is only ever too wide. A
+    row that is NOT live and longer than the bound attends over a prefix of
+    its keys; nobody reads what it computes, and it stays finite (``turns``
+    is at least one, so every row sees its slot 0).
+
+    ``span`` reads chunks ``start .. start + n`` of a leaf (``start`` may be
+    traced, ``n`` is static) through the block table, or out of the slab
+    where the cache is not paged; ``visible`` is the mask of the same
+    chunks' key positions. Two ways to use them inside one program:
+    ``fold`` (a loop with a traced trip count and a running softmax, the
+    arithmetic of ``paged_kernel.py::_decode_kernel``: no slab of keys or
+    values is ever held, but a turn has a fixed cost and the accumulator
+    rides every turn) and ``prefix`` (a ``lax.switch`` over the static
+    prefixes of the table: no carried state and the one-pass softmax, at the
+    price of a slab as long as the prefix). ``loops`` says which a GQA cache
+    should take, from what the v5e showed (PERF.md, PR 38)."""
+
+    # chunks of this many tokens or more (tables of 4 096 slots up) go by the
+    # loop: Mistral-7B widths, 8 rows, 3 live near 1 850 of 4 096: 12.51 ms a
+    # step by the loop, 12.91 by the switch, 15.68 whole; at 1 024 slots
+    # (chunks of 128) the switch reads 10.92 / 6.76 ms against the loop's
+    # 11.21 / 6.90 (Mistral / OLMoE; whole 11.38 / 7.69). The loop's fixed
+    # cost a turn is what a short table cannot pay; the slab is what a long
+    # one cannot
+    LOOP_FROM = 512
+
+    def __init__(self, max_seq_len: int, page_size: Optional[int],
+                 idx: jax.Array, live: Optional[jax.Array] = None):
+        page = page_size or 1               # the slab: "pages" of one token
+        table_pages = max_seq_len // page
+        pages = min(max(table_pages // 8, -(-128 // page)), table_pages)
+        while table_pages % pages:
+            pages += 1
+        self.pages = pages                  # pages a chunk
+        self.chunk = pages * page           # tokens a chunk
+        self.n_chunks = table_pages // pages
+        self.idx = idx                      # (b,) cache_index BEFORE this step's write
+        reach = idx + 1
+        if live is not None:
+            reach = jnp.where(live.reshape(idx.shape), reach, 0)
+        self.extent = jnp.clip(jnp.max(reach), 1, max_seq_len)
+        self.turns = (self.extent + self.chunk - 1) // self.chunk   # 1 .. n_chunks
+
+    @property
+    def loops(self) -> bool:
+        return self.chunk >= self.LOOP_FROM
+
+    @property
+    def tokens(self) -> jax.Array:
+        """Slots the step reads of every row, chunk rounding included."""
+        return self.turns * self.chunk
+
+    def span(self, flat: jax.Array, table: Optional[jax.Array], first_row,
+             start, n: int) -> jax.Array:
+        """``(b, n * pages, page, ...)``: chunks ``start .. start + n`` of
+        every row, out of ``flat`` (``KVLayerView.flat``). Paged: by ``table``
+        (b, table_pages), which already holds ids in the whole stack. Slab
+        (``table`` None): rows ``first_row .. + b`` of it."""
+        b = self.idx.shape[0]
+        if table is not None:
+            return flat[jax.lax.dynamic_slice_in_dim(
+                table, start * self.pages, n * self.pages, axis=1)]
+        rows = jax.lax.dynamic_slice_in_dim(flat, first_row, b)
+        return jax.lax.dynamic_slice_in_dim(
+            rows, start * self.chunk, n * self.chunk, axis=1)[:, :, None]
+
+    def visible(self, start, n: int) -> jax.Array:
+        """``(b, n * chunk)`` bool: key position ``j`` of those chunks is
+        visible to row ``i`` iff ``j <= idx[i]`` (``cached_attention``'s mask
+        for one new token)."""
+        kpos = start * self.chunk + jnp.arange(n * self.chunk, dtype=jnp.int32)
+        return kpos[None, :] <= self.idx[:, None]
+
+    def fold(self, heads: Tuple[int, ...], dim: int, chunk_fn) -> jax.Array:
+        """Softmax attention over the chunks below the bound, one chunk a
+        turn of a loop whose trip count is ``turns``: ``chunk_fn(c) ->
+        (scores (b, *heads, chunk) float32, weigh)`` with ``weigh(probs) ->
+        (b, *heads, dim) float32``. Running max, sum and weighted values in
+        float32; the sum is reassociated against a one-pass softmax and
+        nothing else changes. Returns ``(b, *heads, dim)`` float32."""
+        b = self.idx.shape[0]
+        lead = (b, *heads)
+
+        def turn(c, carry):
+            m, l, acc = carry
+            scores, weigh = chunk_fn(c)
+            with jax.named_scope("attend"):
+                mask = self.visible(c, 1).reshape(b, *(1,) * len(heads), self.chunk)
+                scores = jnp.where(mask, scores, -1e30)
+                m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+                p = jnp.exp(scores - m_new[..., None])
+                alpha = jnp.exp(m - m_new)
+                return (m_new, alpha * l + jnp.sum(p, axis=-1),
+                        alpha[..., None] * acc + weigh(p))
+
+        _, l, acc = jax.lax.fori_loop(
+            0, self.turns, turn,
+            (jnp.full(lead, -1e30, jnp.float32), jnp.zeros(lead, jnp.float32),
+             jnp.zeros((*lead, dim), jnp.float32)))
+        return acc / l[..., None]
+
+    def prefix(self, branch) -> jax.Array:
+        """``branch(n)`` for the static ``n`` that equals ``turns``: a
+        ``lax.switch`` over the prefixes of ``1 .. n_chunks`` chunks, each
+        branch whatever the caller does with ``span(.., 0, n)`` and
+        ``visible(0, n)``."""
+        return jax.lax.switch(
+            self.turns - 1,
+            [functools.partial(branch, n) for n in range(1, self.n_chunks + 1)])
+
+
 class LlamaAttention(nn.Module):
     config: LlamaConfig
 
     @nn.compact
     def __call__(self, x: jax.Array, rope, chunk_ctx=None,
-                 kv: Optional[KVLayerView] = None) -> jax.Array:
+                 kv: Optional[KVLayerView] = None, live=None) -> jax.Array:
         """``chunk_ctx`` (decode only): ``(chunk_mask (s,s) bool,
         chunk_positions (s,) int32)`` for Medusa tree steps — intra-chunk
         visibility by tree ancestry and RoPE positions by tree depth.
-        ``kv`` (decode only): this layer's view of the carried KV leaves."""
+        ``kv`` (decode only): this layer's view of the carried KV leaves.
+        ``live`` (b, s) bool, where a serving program gives it: the rows a
+        one-token step advances for someone (:class:`KVWalk`)."""
         cfg = self.config
         hd = cfg.head_dim_
         q, k, v = GQAQKVColumnParallelLinear(
@@ -517,7 +648,7 @@ class LlamaAttention(nn.Module):
             k = jnp.clip(k, -cfg.qkv_clip, cfg.qkv_clip)
             v = jnp.clip(v, -cfg.qkv_clip, cfg.qkv_clip)
         if cfg.decode:
-            return self._decode_attention(x, q, k, v, kv, chunk_ctx, aidx)
+            return self._decode_attention(x, q, k, v, kv, chunk_ctx, aidx, live)
         cos, sin = rope  # computed once in LlamaModel, broadcast through scan
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
@@ -546,6 +677,50 @@ class LlamaAttention(nn.Module):
             )
         o = o.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[1], -1)
         return self._o_proj(o, aidx)
+
+    def _walk_attention(self, q, kv, walk: KVWalk, table):
+        """One new token a row over the chunks of the cache below ``walk``'s
+        bound (``q`` (b, 1, n, hd) rotated, the cache already holds this
+        step's K/V). Grouped by KV head, K and V read in the cache's dtype,
+        int8 pages dequantised a chunk at a time with that chunk's scales;
+        scores, mask, softmax and accumulation float32, as
+        :func:`cached_attention`."""
+        cfg = self.config
+        b, _, n, hd = q.shape
+        n_kv = kv.leaves["cached_key"].shape[-2]
+        first = kv.first_row(b)
+        exact = dict(preferred_element_type=jnp.float32,
+                     precision=jax.lax.Precision.HIGHEST)
+
+        def read(name, start, count):
+            with jax.named_scope("kv_gather"):
+                pages = walk.span(kv.flat(name), table, first, start, count)
+                if cfg.page_dtype == "int8":
+                    scales = walk.span(kv.flat(name + "_scale"), table, first,
+                                       start, count)
+                    pages = (pages.astype(jnp.float32) * scales).astype(cfg.dtype)
+                return pages.reshape(b, count * walk.chunk, n_kv, hd)
+
+        if not walk.loops:
+            def branch(count):
+                k_all = read("cached_key", 0, count)
+                v_all = read("cached_value", 0, count)
+                with jax.named_scope("attend"):
+                    return cached_attention(q, k_all, v_all, walk.idx)
+
+            return walk.prefix(branch)
+
+        qg = q.reshape(b, n_kv, n // n_kv, hd)
+
+        def chunk(c):
+            k_c, v_c = read("cached_key", c, 1), read("cached_value", c, 1)
+            with jax.named_scope("attend"):
+                scores = jnp.einsum("bkgd,bjkd->bkgj", qg, k_c, **exact) * (1.0 / hd ** 0.5)
+            return scores, lambda p: jnp.einsum("bkgj,bjkd->bkgd", p, v_c, **exact)
+
+        with jax.named_scope("attend"):
+            o = walk.fold((n_kv, n // n_kv), hd, chunk)
+            return o.reshape(b, 1, n, hd).astype(q.dtype)
 
     def _qk_norm(self, name, y, heads, repeat):
         """RMSNorm in float32 over the flattened (heads, head_dim) axes of
@@ -578,12 +753,22 @@ class LlamaAttention(nn.Module):
                                      aidx).astype(y.dtype)
         return y
 
-    def _decode_attention(self, x, q, k, v, kv, chunk_ctx=None, aidx=None):
+    def _decode_attention(self, x, q, k, v, kv, chunk_ctx=None, aidx=None,
+                          live=None):
         """KV-cached path (flax ``cache`` collection; the reference keeps KV
         state in aliased runtime buffers, model_base.py KV management —
         donation of the cache collection is the TPU analogue). The K/V
         leaves themselves come through ``kv`` (:class:`KVLayerView`): the
-        stack of every layer's, carried by the layer loop."""
+        stack of every layer's, carried by the layer loop.
+
+        The new tokens' K/V are written first; what is then read depends on
+        the step. One new token a row and no tree (the decode step): chunks
+        of whole pages up to the reach of the longest LIVE row
+        (:class:`KVWalk`, ``_walk_attention``; ``live`` (b, s) is the serving
+        program's, None counts every row), or the paged kernel. A prompt, a
+        chunk, a speculative or tree step (``s_new > 1`` or ``chunk_ctx``),
+        and any table of a single chunk: all ``max_seq_len`` slots behind the
+        mask, as ever."""
         cfg = self.config
         b = x.shape[0]
         s_new = x.shape[1]
@@ -727,6 +912,7 @@ class LlamaAttention(nn.Module):
                     jax.lax.dynamic_slice_in_dim(kv.flat(name), first, b)
                     for name in ("cached_key", "cached_value"))
             ci.value = idx + s_new
+        one_token = s_new == 1 and chunk_mask is None
         if ps:
             from neuronx_distributed_tpu.inference.paged_kernel import (
                 paged_decode_attention,
@@ -735,7 +921,7 @@ class LlamaAttention(nn.Module):
             # single-token steps only (prefill/chunk widths amortize the
             # gather); whether THIS model's pages fit the kernel was
             # settled at construction (CausalLM, paged_kernel_refusal)
-            if cfg.paged_attn_kernel and chunk_mask is None and s_new == 1:
+            if cfg.paged_attn_kernel and one_token:
                 # fused paged decode (inference/paged_kernel.py): attend
                 # straight off the POST-write pool through the block
                 # table — no logical slab is ever materialized, which is
@@ -748,8 +934,18 @@ class LlamaAttention(nn.Module):
                         k_scale=kv.flat("cached_key_scale") if quantized else None,
                         v_scale=kv.flat("cached_value_scale") if quantized else None)
                 return self._o_proj(o.reshape(b, s_new, -1), aidx)
+        if one_token:
+            # the step reads as far as its longest live row reaches; a table
+            # of ONE chunk (max_seq_len of 128 or less) is the whole read below
+            walk = KVWalk(cfg.max_seq_len, ps, idx,
+                          None if live is None else live[:, 0])
+            if walk.n_chunks > 1:
+                o = self._walk_attention(q, kv, walk, table if ps else None)
+                return self._o_proj(o.reshape(b, s_new, -1), aidx)
+        if ps:
             # in-scan gather: the (b, max_seq_len) logical view the
-            # attention below consumes. Stale bytes in reused pages sit
+            # attention below consumes (prompts, chunks, tree steps; a
+            # one-token step left above). Stale bytes in reused pages sit
             # behind the position mask exactly like the slab's unwritten
             # zeros (masked scores are -1e30 -> exactly-zero probs), so
             # attention over the view is bit-identical to the contiguous
@@ -860,10 +1056,11 @@ class LlamaDecoderLayer(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope, chunk_ctx=None, kv=None) -> jax.Array:
+    def __call__(self, x: jax.Array, rope, chunk_ctx=None, kv=None,
+                 live=None) -> jax.Array:
         cfg = self.config
         h = cfg.make_norm(name="input_norm")(x)
-        x = x + LlamaAttention(cfg, name="attention")(h, rope, chunk_ctx, kv)
+        x = x + LlamaAttention(cfg, name="attention")(h, rope, chunk_ctx, kv, live)
         h = cfg.make_norm(name="post_attn_norm")(x)
         return x + LlamaMLP(cfg, name="mlp")(h)
 
@@ -888,9 +1085,11 @@ class _LayerStep(nn.Module):
     more is carried than the hidden states), else ``(layer index, KV
     leaves)``, which the block's attention updates through a
     :class:`KVLayerView`. ``live`` (b, s) bool, where a serving program gives
-    it, tells a block with experts which tokens are real (``moe/layer.py``),
-    and ``stack`` is what ``LlamaModel.layer_stack`` hands every layer whole;
-    only a block that asked for them takes them."""
+    it, says which tokens are real: a block's experts run those alone
+    (``moe/layer.py``) and a one-token step's attention reads the cache as
+    far as the live rows reach (:class:`KVWalk`); ``stack`` is what
+    ``LlamaModel.layer_stack`` hands every layer whole, to a block that asked
+    for it."""
 
     config: LlamaConfig
     layer_cls: Any = None  # default LlamaDecoderLayer (set below)
@@ -1008,8 +1207,12 @@ class LlamaModel(nn.Module):
             args = args[:-1]
         carry = (x, kv)
         if getattr(cfg, "first_k_dense", 0):
-            carry, _ = self.dense_layers(
-                carry, *((rope,) if chunk_ctx is None else (rope, chunk_ctx)))
+            # a one-token step's attention wants `live` (KVWalk); a prompt's
+            # does not, and a dense block has no other use for it
+            dense = (rope, chunk_ctx, live if input_ids.shape[1] == 1 else None)
+            while dense[-1] is None:
+                dense = dense[:-1]
+            carry, _ = self.dense_layers(carry, *dense)
         (x, kv), _ = self.layers(carry, *args)
         for name, pool in pools.items():
             pool.value = kv[1][name]
@@ -1072,8 +1275,8 @@ class LlamaForCausalLM(nn.Module):
         return x
 
     def __call__(self, input_ids: jax.Array, live=None) -> jax.Array:
-        """``live`` (b, s) bool: which tokens are real, for a model with
-        experts under a serving program (``_LayerStep``); else None."""
+        """``live`` (b, s) bool: which tokens are real, where a serving
+        program knows (``_LayerStep``); None counts every token."""
         return self._head(self._hidden(input_ids, live))
 
     def last_logits(self, input_ids: jax.Array, last: jax.Array,

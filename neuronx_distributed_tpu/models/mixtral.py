@@ -83,7 +83,7 @@ class MixtralDecoderLayer(nn.Module):
                  stack=None) -> jax.Array:
         cfg = self.config
         h = cfg.make_norm(name="input_norm")(x)
-        x = x + LlamaAttention(cfg, name="attention")(h, rope, kv=kv)
+        x = x + LlamaAttention(cfg, name="attention")(h, rope, kv=kv, live=live)
         h = cfg.make_norm(name="post_attn_norm")(x)
         moe_out = MoE(
             num_experts=cfg.num_experts,

@@ -167,6 +167,122 @@ def test_gather_decode_step_holds_no_widened_slab(chip):
     assert "bf16[1," + pool not in compiled.as_text()
 
 
+def _traced_bounds(text):
+    """How many ``while`` loops of a compiled module stop at a value the
+    program computed (their condition compares with no constant), and how
+    many ``conditional``s it holds."""
+    traced = 0
+    for cond in re.findall(r" while\(.*?condition=%([\w.]+)", text):
+        body = re.search(r"^%" + re.escape(cond) + r" \(.*?^}", text, re.M | re.S).group(0)
+        traced += "constant(" not in body
+    return traced, len(re.findall(r" conditional\(", text))
+
+
+def _leaf_copies(text, shape, dtype="bf16"):
+    """``copy`` instructions that produce a whole cache leaf INSIDE a loop
+    body or a branch: every computation but the entry one (where the
+    compiler may re-lay-out a donated argument once on the way in and once on
+    the way out, as it did before this walk existed: PERF.md section 7)."""
+    leaf = re.compile(r"= " + dtype + r"\[" + ",".join(map(str, shape)) + r"\]\S* copy\(")
+    found, entry = [], False
+    for line in text.splitlines():
+        if re.match(r"^(ENTRY )?%[\w.]+ \(", line):
+            entry = line.startswith("ENTRY")
+        elif not entry and leaf.search(line):
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("s_max", [4096, 1024], ids=["loop_4096", "switch_1024"])
+def test_gather_decode_step_reads_as_far_as_its_rows_reach(chip, s_max):
+    """The same step (GQA 32/8, head 128, bf16 pages of 16, batch 8, two
+    scanned layers), told which rows are live as the fused session decode
+    tells it: the cache is read in chunks up to a bound the program computes
+    (``models/llama.py::KVWalk``). At 4 096 slots by a loop: a ``while`` whose
+    condition holds no constant, and no value of the gathered slab's shape
+    ``(8, 4096, 8, 128)`` anywhere. At 1 024 slots by a switch: one
+    ``conditional``, whose widest branch is the old read. Either way no pool
+    leaf is copied into the loop or a branch (a pool-sized copy in the layer
+    body costs milliseconds a step: PR 29 met one under a kernel), and the
+    temporaries stay as small as the whole read's."""
+    b, n_kv, page, layers = 8, 8, 16, 2
+    cfg = dataclasses.replace(
+        LlamaConfig(vocab_size=256, hidden_size=32 * HEAD_DIM,
+                    intermediate_size=1024, num_heads=32, num_kv_heads=n_kv,
+                    num_layers=layers, max_seq_len=s_max, dtype=jnp.bfloat16,
+                    param_dtype=jnp.bfloat16),
+        decode=True, remat_policy=None, page_size=page,
+        page_pool_pages=b * s_max // page + b)
+    model = LlamaForCausalLM(cfg)
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        meta.unbox(jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((b, 1), jnp.int32)))))
+
+    def step(params, cache, ids, live):
+        return model.apply({"params": params, "cache": cache}, ids, live=live,
+                           mutable=["cache"])
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        variables["params"], variables["cache"],
+        jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=chip),
+        jax.ShapeDtypeStruct((b, 1), jnp.bool_, sharding=chip)).compile()
+    text = compiled.as_text()
+    loops, switches = _traced_bounds(text)
+    slab = f"[{b},{s_max},{n_kv},{HEAD_DIM}]"
+    if s_max == 4096:
+        assert loops == 1 and switches == 0
+        assert slab not in text
+    else:
+        assert loops == 0 and switches == 1
+        assert slab in text                       # the widest branch: the check can see it
+    assert not _leaf_copies(text, (layers, cfg.page_pool_pages, page, n_kv, HEAD_DIM))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * layers * cfg.page_pool_pages * page * n_kv * HEAD_DIM * 2
+    assert memory.temp_size_in_bytes < b * s_max * n_kv * HEAD_DIM * 2   # under one K slab
+
+
+def test_latent_decode_step_reads_as_far_as_its_rows_reach(chip):
+    """The benchmark's DeepSeek-V2 rehearsal configuration (bf16, pages of
+    16, batch 8, ``max_seq_len`` 4096) for the described v5e: the absorbed
+    decode reads the latent cache by a switch over its prefixes (no carried
+    state), in the dense layer's scan and in the expert layers' scan, with no
+    loop of a traced bound and no copy of the latent leaf."""
+    import json
+    from pathlib import Path
+
+    from benchmark import run as harness
+    from benchmark.drivers import serving
+
+    root = Path(harness.__file__).resolve().parents[1]
+    entry = next(c for c in json.loads((root / "BENCHMARK.json").read_text())["configs"]
+                 if c["name"] == "deepseek-v2")
+    b, s_max, page = 8, 4096, 16
+    preset = serving.model_config(harness.load_config(entry, rehearse=True), False,
+                                  max_seq_len=s_max, remat_policy=None)
+    cfg = dataclasses.replace(preset, decode=True, page_size=page,
+                              page_pool_pages=b * s_max // page + b,
+                              moe_mode="capacity_factor")
+    model = serving.load(harness.load_config(entry, rehearse=True)["builder"]["model"])(cfg)
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        meta.unbox(jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((b, 1), jnp.int32)))))
+
+    def step(params, cache, ids, live):
+        return model.apply({"params": params, "cache": cache}, ids, live=live,
+                           mutable=["cache"])
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        variables["params"], variables["cache"],
+        jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=chip),
+        jax.ShapeDtypeStruct((b, 1), jnp.bool_, sharding=chip)).compile()
+    text = compiled.as_text()
+    assert cfg.dtype == jnp.bfloat16 and cfg.first_k_dense == 1
+    assert _traced_bounds(text) == (0, 2)         # one switch a layer scan
+    assert not _leaf_copies(text, (cfg.num_layers, cfg.page_pool_pages, page, 1, cfg.latent_dim))
+
+
 def _moe_config(family, **kw):
     from neuronx_distributed_tpu.models.mixtral import MixtralForCausalLM, mixtral_8x7b
     from neuronx_distributed_tpu.models.olmoe import OlmoeForCausalLM, olmoe_1b_7b

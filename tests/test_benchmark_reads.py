@@ -180,6 +180,9 @@ INSERT_HOST_OPS = ["insert_program_calls", "insert_host_fetches"]
 # every pick of the live rows' routers (PR 37): equal to `moe_assignments` where
 # every expert is held; more where the layer holds a share (tests/test_deepseek_v2.py)
 ROUTED_READS = ["moe_assignments_routed"]
+# how far the fused decode blocks read the cache (PR 38): in `engine.stats`, so
+# in every record's `engine_stats`; no metric of BENCHMARK.json reads them yet
+WALK_STATS = ["kv_walk_tokens", "kv_walk_steps"]
 TRAIN_READS = ["jit_step_fn"]
 
 
@@ -232,6 +235,52 @@ def test_insert_host_op_counter_is_produced(run, moe_run, key):
     for r in (run, moe_run):
         assert r.engine.stats[key] == r.engine.stats["inserts"] > 0
         assert key in dict(r.engine.stats.items())
+
+
+@pytest.mark.parametrize("key", WALK_STATS)
+def test_walk_counter_is_produced(run, moe_run, key):
+    """Counted by every fused decode block, dense or with experts, over the
+    steps that had a live row. These tables are one chunk long (64 slots), so
+    every such step read all of them."""
+    for r in (run, moe_run):
+        stats = r.engine.stats
+        assert key in dict(stats.items())
+        assert 0 < stats["kv_walk_steps"] <= stats["decode_blocks"] * BLOCK_STEPS
+        assert stats["kv_walk_tokens"] == stats["kv_walk_steps"] * r.lm.config.max_seq_len
+
+
+@pytest.mark.parametrize("async_loop", [False, True], ids=["sync", "async"])
+def test_walk_counters_equal_a_python_model_of_the_same_lengths(async_loop):
+    """Three requests admitted together into a table of 512 slots (chunks of
+    128): the counters against a model of the lengths the host knows. A row
+    is live on the device through every step of a block it began unfinished
+    (its budget is the host's to latch, between blocks; the pipelined loop
+    predicts it at dispatch, so both loops count the same); a step reads whole
+    chunks up to its longest live row's reach, the token it writes included."""
+    cfg = LlamaConfig(**dict(TINY, max_seq_len=512))
+    params = meta.unbox(LlamaForCausalLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    lm = CausalLM(cfg, params, LlamaForCausalLM, buckets=(128,), max_batch=4, page_size=16)
+    engine = ServeEngine(lm, block_steps=BLOCK_STEPS, rng=jax.random.key(0),
+                         async_loop=async_loop)
+    rng = np.random.RandomState(2)
+    prompts, budgets = (100, 125, 30), (9, 14, 5)
+    for n, budget in zip(prompts, budgets):
+        engine.submit(rng.randint(1, 128, (n,)).astype(np.int32), max_new_tokens=budget,
+                      arrival_block=0)
+    while engine.step_block():
+        pass
+    assert [len(c.tokens) for c in sorted(engine.completed, key=lambda c: c.request_id)] \
+        == list(budgets)
+    tokens = steps = 0
+    for block in range(max(budgets)):
+        # a request holds 1 + block * K tokens when the block starts (the insert gave one)
+        live = [n for n, budget in zip(prompts, budgets) if 1 + block * BLOCK_STEPS < budget]
+        for step in range(BLOCK_STEPS if live else 0):
+            reach = max(live) + block * BLOCK_STEPS + step + 1
+            tokens += -(-reach // 128) * 128
+            steps += 1
+    assert (engine.stats["kv_walk_tokens"], engine.stats["kv_walk_steps"]) == (tokens, steps)
 
 
 def test_paged_insert_program_is_built_from_rows_and_bucket(run):

@@ -221,7 +221,7 @@ def test_fused_decode_counts_what_the_router_chose(params):
     lm = serving_lm(params)
     assert lm.moe_stats
     assert len(jax.tree.leaves(lm.compile_session_decode_fused(4).out_info)) == len(
-        jax.tree.leaves(lm._cache_avals())) + 5
+        jax.tree.leaves(lm._cache_avals())) + 6     # rows, how far it read, what it routed
     got = run_engine(lm)
     steps, rows = 8, 3
     assert got["moe_layer_steps"] == cfg.num_layers * steps
@@ -243,7 +243,7 @@ def test_a_dense_model_counts_nothing_and_returns_what_it_did():
     lm = CausalLM(cfg, weights, LlamaForCausalLM, buckets=(32,), max_batch=4, page_size=8)
     assert not lm.moe_stats
     assert len(jax.tree.leaves(lm.compile_session_decode_fused(4).out_info)) == len(
-        jax.tree.leaves(lm._cache_avals())) + 4
+        jax.tree.leaves(lm._cache_avals())) + 5     # rows, how far it read
     assert "moe_stats" not in lm.compile_session_decode_fused(4).as_text()
     assert run_engine(lm) == {"moe_experts_touched": 0, "moe_assignments": 0,
                               "moe_layer_steps": 0}

@@ -105,6 +105,32 @@ def test_fused_decode_names_its_regions(params, kernel):
     assert unnamed_share(parts) < 0.2, parts
 
 
+@pytest.mark.parametrize("seq,inside", [(4096, r"(while/body/.*){3}"), (256, r"branch_\d+_fun/")],
+                         ids=["loop", "switch"])
+def test_the_walk_keeps_kv_gather_and_attend_on_the_ops_that_do_the_work(params, seq, inside):
+    """The fused block reads the cache in chunks up to a bound it computes
+    (``KVWalk``): by a loop inside the layer scan inside the step scan at
+    4 096 slots, by a switch at 256. The gathers and the contractions sit in
+    the loop's body or the switch's branches, and are still ``kv_gather`` and
+    ``attend`` to ``trace_parts.py``; the bound's own arithmetic is the
+    attention's, and the counter of what was read is ``bookkeeping``."""
+    lm = CausalLM(LlamaConfig(**dict(TINY, max_seq_len=seq)), params, LlamaForCausalLM,
+                  buckets=(128,), max_batch=2, page_size=16)
+    text = lm.compile_session_decode_fused(4).as_text()
+    found = collections.Counter()
+    for line in text.splitlines():
+        m = OP_NAME.search(line)
+        if not m or PLUMBING.search(line):
+            continue
+        part = trace_parts.part_of({"tf_op": m.group(1) + ":"}, TABLE)
+        if part in ("kv_gather", "attend") and re.search(inside + r"(attend|kv_gather)/", m.group(1)):
+            found[part, re.search(r" (gather|dot|convolution|fusion|exponential)\(", line) is not None] += 1
+    assert found["kv_gather", True] > 0 and found["attend", True] > 0, found
+    components, parts = census(lm.compile_session_decode_fused(4))
+    assert {"kv_write", "kv_gather", "attend", "bookkeeping"} <= components
+    assert unnamed_share(parts) < 0.2, parts
+
+
 @pytest.mark.parametrize("program", ["fused_decode", "paged_insert"])
 def test_olmoe_names_qk_norm_router_and_experts(program):
     """The tiny OLMoE decode block and paged insert carry ``qk_norm`` (the one

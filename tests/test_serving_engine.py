@@ -240,7 +240,7 @@ def test_session_fused_overflow_guard_freezes_not_wraps(lm):
     lm.insert(session, [0, 1, 2], p)
     # slot 0 reports 2 tokens of room; slot 1 has plenty; slot 2 inactive
     lengths = np.asarray([max_len - 2, 8, 8], np.int32)
-    toks, cache, tok, out_len, done = fused(
+    toks, cache, tok, out_len, done, _walked = fused(
         lm.params, session.cache, jnp.zeros((3, 1), jnp.int32),
         jax.random.split(jax.random.key(0), 3), jnp.ones((3,), jnp.int32),
         jnp.asarray(lengths),

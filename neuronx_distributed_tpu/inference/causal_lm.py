@@ -1443,15 +1443,16 @@ class CausalLM:
         # adapter-specific, so reuse is scoped to (tokens, adapter)
         nss = list(ns) if ns is not None else [None] * rows
         plans = []
-        try:
-            for i in range(rows):
-                plans.append(pkv.plan(
-                    prompt_ids[i, : lengths[i]].tolist(), int(totals[i]),
-                    ns=nss[i]))
-        except Exception:
-            for p in plans:
-                pkv.rollback(p)
-            raise
+        with pkv.span("cache_plan", rows=rows):
+            try:
+                for i in range(rows):
+                    plans.append(pkv.plan(
+                        prompt_ids[i, : lengths[i]].tolist(), int(totals[i]),
+                        ns=nss[i]))
+            except Exception:
+                for p in plans:
+                    pkv.rollback(p)
+                raise
         starts = np.asarray([p.start for p in plans], np.int32)
         suffix = lengths - starts                      # >= 1 by plan()'s clamp
         bucket = self._bucket_for(int(suffix.max()))
@@ -1480,9 +1481,10 @@ class CausalLM:
                 pkv.rollback(p)
             raise
         session.insert_routing = sums[0] if sums else None
-        for i in range(rows):
-            pkv.commit(int(slot_ids[i]), plans[i],
-                       prompt_ids[i, : lengths[i]].tolist(), ns=nss[i])
+        with pkv.span("cache_commit", rows=rows):
+            for i in range(rows):
+                pkv.commit(int(slot_ids[i]), plans[i],
+                           prompt_ids[i, : lengths[i]].tolist(), ns=nss[i])
         session.lengths[slot_ids] = lengths
         session.active[slot_ids] = True
         return logits

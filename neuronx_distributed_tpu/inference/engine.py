@@ -580,8 +580,12 @@ class ServeEngine:
         # registry backs BOTH the Prometheus exposition and the legacy
         # ``stats`` dict view. Neither touches device programs: every event
         # derives from data the scheduler already holds between blocks.
+        # A tracer the engine builds itself also mirrors its spans into the
+        # device profiler's trace (``nxd:<name>`` on the host line, on the
+        # profiler's clock); one handed in says for itself what it mirrors.
         self.tracer = tracer if tracer is not None else Tracer(
-            enabled=bool(trace))
+            enabled=bool(trace),
+            annotate=jax.profiler.TraceAnnotation if trace else None)
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         # compile spans from lazily-compiled programs land on this tracer.
         # An ENABLED tracer always takes the lm; a disabled one only fills
@@ -749,6 +753,9 @@ class ServeEngine:
         # only reflects device effects through the PREVIOUS block
         self._observed_pin = 0
         self._entry_inflight = 0
+        # a traced round's stamp for its next phase to begin on: where its
+        # step_block span began, then where its block's fetch ended
+        self._tile_at: Optional[float] = None
         # paged mode (lm built with page_size): admission additionally
         # consults the prefix index + page allocator — a prefix hit prefills
         # only the suffix, pool pressure defers admission instead of OOMing
@@ -1648,10 +1655,14 @@ class ServeEngine:
             args={"queue_blocks": max(self.blocks - req.arrival_block, 0)})
 
     def _observe_first_token(self, req: Request, slot: int, now: float,
+                             claimed: Optional[float] = None,
                              **extra) -> None:
         """First-token observation shared by the admission paths (one-shot
         insert, chunked-prefill finish, fresh recovery replay): wall-TTFT
-        histogram + admit/first_token marks on the request lane."""
+        histogram + admit/first_token marks on the request lane. ``admit``
+        is stamped ``claimed`` where the caller took one when it claimed
+        the slot (a one-shot insert: the prefill lies between the two
+        marks), else ``now``."""
         sts = self._submit_ts.get(req.request_id)
         if sts is not None:
             self._m_ttft.observe((now - sts) * 1e3)
@@ -1659,7 +1670,8 @@ class ServeEngine:
             return
         rid = req.request_id
         self.tracer.instant(
-            "admit", ("req", rid), ts=now, block=self.blocks,
+            "admit", ("req", rid), ts=now if claimed is None else claimed,
+            block=self.blocks,
             args={"slot": int(slot),
                   **{k: v for k, v in extra.items() if v is not None}})
         self.tracer.instant("first_token", ("req", rid), ts=now,
@@ -1861,71 +1873,84 @@ class ServeEngine:
 
     def _insert_group(self, group: List[Request], slot_ids: List[int],
                       bucket: int) -> None:
+        # the slots are claimed HERE: the requests' ``queued`` spans end and
+        # their ``admit`` is stamped at this instant, before the prefill
+        claimed = time.perf_counter()
         rows = len(group)
-        ids = np.zeros((rows, bucket), np.int32)
-        lens = np.zeros((rows,), np.int32)
-        for i, r in enumerate(group):
-            ids[i, : r.prompt.size] = r.prompt
-            lens[i] = r.prompt.size
-        # paged mode reserves pages for the decode room only (budget + one
-        # block of post-budget overrun writes, which land in owned pages or
-        # scratch — never a neighbour); the contiguous path ignores the
-        # kwarg. A prefill worker reserves NOTHING beyond the prompt — its
-        # first-token sample writes no KV and the decode room is allocated
-        # by the adopting decode worker.
-        reserve = np.asarray(
-            [0 if self.role == "prefill"
-             else r.max_new_tokens + self._reserve_slack() for r in group],
-            np.int64)
-        aslots = (np.asarray([self._adapter_slot(r) for r in group], np.int32)
-                  if self.lora else None)
-        tier_before = self._tier_marker()
-        # ONE program call: the prompt's KV, the rows' request keys into
-        # slot_keys and their first tokens (token index 0 of each request's
-        # own key stream, fold_in(req_key, 0): the derivation the chunked
-        # path's final chunk and both decode modes use; constrained too, by
-        # each grammar's START state), all inside it. The inputs ride the
-        # call as host arrays, and nothing else runs on the device for this
-        # admission (host-only simulation: the stub's token function is the
-        # whole sampling path).
-        self._dispatch("insert", lambda: self.lm.insert(
-            self.session, np.asarray(slot_ids, np.int32), ids, lengths=lens,
-            pad_token_id=self.pad_token_id,
-            reserve_tokens=reserve if self.paged else None,
-            adapter_slots=aslots,
-            # adapter namespace for the radix walk — prefix KV reuse is
-            # scoped per adapter (cross-adapter reuse = wrong tokens)
-            ns=[r.adapter for r in group] if self.paged else None,
-            first=self._first_inputs(group)))
-        self._note_tier_restore(group, tier_before)
-        self.stats["inserts"] += 1
-        self.stats["inserted_requests"] += rows
-        self.stats["insert_program_calls"] += 1
-        # async pipeline: fetching the sampled first tokens here would block
-        # on the insert program, which chains AFTER the in-flight decode
-        # block (session.cache is its donated output future) — serializing
-        # the very overlap the loop exists for. Leave the program's output on
-        # device; _settle_firsts records the host values at the next harvest
-        # (the designated sync point). A prefill worker never defers: it has
-        # no decode pipeline and _handoff_group needs the token NOW.
-        defer = self.async_loop and self.role != "prefill"
-        first_dev = routing = None
-        if self._sim:
-            first = np.asarray(self.lm.sim_first_tokens(
-                [r.request_id for r in group], [0] * rows), np.int64)
-        else:
-            first_dev = self.session.first_tokens
-            # the insert's routing sums (a model with experts, paged) come
-            # to the host with its first tokens: here, or with the deferred
-            # record
-            routing = self.session.insert_routing
-            first = None if defer else self._fetch_first(first_dev, routing)
+        span_args = None
+        if self.tracer.enabled:
+            span_args = {
+                "rows": rows, "bucket": int(bucket),
+                # the rows this admission stalls while its program runs
+                "decoding": int((self._active & ~self._done).sum()),
+                "rids": [r.request_id for r in group]}
+        with self.tracer.span("admission", (self.lane, "phases"),
+                              block=self.blocks, args=span_args):
+            ids = np.zeros((rows, bucket), np.int32)
+            lens = np.zeros((rows,), np.int32)
+            for i, r in enumerate(group):
+                ids[i, : r.prompt.size] = r.prompt
+                lens[i] = r.prompt.size
+            # paged mode reserves pages for the decode room only (budget + one
+            # block of post-budget overrun writes, which land in owned pages or
+            # scratch — never a neighbour); the contiguous path ignores the
+            # kwarg. A prefill worker reserves NOTHING beyond the prompt — its
+            # first-token sample writes no KV and the decode room is allocated
+            # by the adopting decode worker.
+            reserve = np.asarray(
+                [0 if self.role == "prefill"
+                 else r.max_new_tokens + self._reserve_slack() for r in group],
+                np.int64)
+            aslots = (np.asarray([self._adapter_slot(r) for r in group], np.int32)
+                      if self.lora else None)
+            tier_before = self._tier_marker()
+            # ONE program call: the prompt's KV, the rows' request keys into
+            # slot_keys and their first tokens (token index 0 of each request's
+            # own key stream, fold_in(req_key, 0): the derivation the chunked
+            # path's final chunk and both decode modes use; constrained too, by
+            # each grammar's START state), all inside it. The inputs ride the
+            # call as host arrays, and nothing else runs on the device for this
+            # admission (host-only simulation: the stub's token function is the
+            # whole sampling path).
+            self._dispatch("insert", lambda: self.lm.insert(
+                self.session, np.asarray(slot_ids, np.int32), ids, lengths=lens,
+                pad_token_id=self.pad_token_id,
+                reserve_tokens=reserve if self.paged else None,
+                adapter_slots=aslots,
+                # adapter namespace for the radix walk — prefix KV reuse is
+                # scoped per adapter (cross-adapter reuse = wrong tokens)
+                ns=[r.adapter for r in group] if self.paged else None,
+                first=self._first_inputs(group)))
+            self._note_tier_restore(group, tier_before)
+            self.stats["inserts"] += 1
+            self.stats["inserted_requests"] += rows
+            self.stats["insert_program_calls"] += 1
+            # async pipeline: fetching the sampled first tokens here would block
+            # on the insert program, which chains AFTER the in-flight decode
+            # block (session.cache is its donated output future) — serializing
+            # the very overlap the loop exists for. Leave the program's output on
+            # device; _settle_firsts records the host values at the next harvest
+            # (the designated sync point). A prefill worker never defers: it has
+            # no decode pipeline and _handoff_group needs the token NOW.
+            defer = self.async_loop and self.role != "prefill"
+            first_dev = routing = None
+            if self._sim:
+                first = np.asarray(self.lm.sim_first_tokens(
+                    [r.request_id for r in group], [0] * rows), np.int64)
+            else:
+                first_dev = self.session.first_tokens
+                # the insert's routing sums (a model with experts, paged) come
+                # to the host with its first tokens: here, or with the deferred
+                # record
+                routing = self.session.insert_routing
+                first = None if defer else self._fetch_first(first_dev, routing)
         now = time.perf_counter()
         for i, (r, slot) in enumerate(zip(group, slot_ids)):
             r.start_block = self.blocks
             r.first_token_block = self.blocks
-            self._trace_queued(r, now)
-            self._observe_first_token(r, slot, now, bucket=bucket, rows=rows)
+            self._trace_queued(r, claimed)
+            self._observe_first_token(r, slot, now, claimed=claimed,
+                                      bucket=bucket, rows=rows)
             self.slots[slot] = r
             self._out[r.request_id] = []
             self._out_ts[r.request_id] = []
@@ -1972,6 +1997,7 @@ class ServeEngine:
         """Claim ``slot`` for a chunked admission: the slot leaves the free
         pool NOW (so decode membership is stable) but stays decode-inactive;
         prefill happens across rounds in :meth:`_advance_prefill`."""
+        claimed = time.perf_counter()
         chunk = None
         written = 0
         if self.paged:
@@ -1984,7 +2010,7 @@ class ServeEngine:
             written = chunk.start           # prefix hit: skip reused pages
             self._note_tier_restore([req], tier_before)
         req.start_block = self.blocks
-        self._trace_queued(req, time.perf_counter())
+        self._trace_queued(req, claimed)
         if self.tracer.enabled:
             self.tracer.instant(
                 "chunk_begin", ("req", req.request_id), block=self.blocks,
@@ -3628,12 +3654,14 @@ class ServeEngine:
             return get(arr)
         t0 = time.perf_counter()
         out = get(arr)
+        # where the sync loop's harvest phase begins
+        self._tile_at = time.perf_counter()
         self.tracer.complete("fetch", (self.lane, "dispatch"), t0,
-                             time.perf_counter(),
+                             self._tile_at,
                              block=self.blocks if block is None else block)
         return out
 
-    def _count_block_sums(self, sums, block: Optional[int] = None) -> None:
+    def _count_block_sums(self, sums) -> None:
         """What one fused block returned beside its rows
         (``CausalLM.compile_session_decode_fused``): the cache slots its live
         steps read of every row and the number of those steps, then a model
@@ -3642,21 +3670,15 @@ class ServeEngine:
         self.stats["kv_walk_tokens"] += int(walked[0])
         self.stats["kv_walk_steps"] += int(walked[1])
         if routing:
-            self._count_routing(routing[0], block)
+            self._count_routing(routing[0])
 
-    def _count_routing(self, sums, block: Optional[int] = None) -> None:
-        """One fused block's routing sums into ``stats`` and onto a counter
-        track (the share of the expert slots the live rows touched)."""
+    def _count_routing(self, sums) -> None:
+        """One fused block's routing sums into ``stats``."""
         touched, assigned, layer_steps, *routed = (int(x) for x in sums)
         self.stats["moe_experts_touched"] += touched
         self.stats["moe_assignments"] += assigned
         self.stats["moe_layer_steps"] += layer_steps
         self.stats["moe_assignments_routed"] += routed[0] if routed else assigned
-        if self.tracer.enabled and layer_steps:
-            self.tracer.counter(
-                "moe_experts_touched_share", (self.lane, "blocks"),
-                touched / (layer_steps * self.lm.config.num_experts),
-                block=self.blocks if block is None else block)
 
     def _count_insert_routing(self, sums) -> None:
         """One paged insert's routing sums into ``stats``."""
@@ -3680,29 +3702,55 @@ class ServeEngine:
         variant; same decisions, same streams — see _step_block_async)."""
         self._observed_pin = int(self.blocks)
         self._entry_inflight = len(self._inflight)
-        if self.async_loop:
-            return self._step_block_async()
-        return self._step_block_sync()
+        loop = (self._step_block_async if self.async_loop
+                else self._step_block_sync)
+        if not self.tracer.enabled:
+            return loop()
+        # the round as ONE span on the phases lane, numbered like every span
+        # inside it by the virtual block it started at
+        decoded = self.stats["decode_blocks"]
+        args: dict = {}
+        with self.tracer.span("step_block", (self.lane, "phases"),
+                              block=self.blocks, args=args) as rnd:
+            self._tile_at = rnd.start
+            worked = loop()
+            args["worked"] = bool(worked)
+            args["decoded"] = self.stats["decode_blocks"] > decoded
+        return worked
+
+    def _phase(self, name: str, block: int, start: Optional[float],
+               args: Optional[dict] = None):
+        """One phase of a round on the ``(lane, "phases")`` track. The sync
+        loop's ``admit``, ``observe``, ``launch``, the dispatch lane's
+        ``fetch`` and ``harvest`` follow one another and tile the round's
+        ``step_block`` span: each begins on the stamp (``start``) the tile
+        before it ended on, so what lies between two ``with`` blocks (a
+        check, a call, freed device buffers) is the later phase's."""
+        return self.tracer.span(name, (self.lane, "phases"), block=block,
+                                args=args, start=start)
 
     def _step_block_sync(self) -> bool:
         """The synchronous block loop — the exactness oracle the async
         pipeline is tested bit-identical against."""
-        self._emitted.clear()     # harvest reads last block's emissions
-        self.queue.advance(self.blocks)
-        self._sweep_idle_parks()  # idle streams spill to the durable tier
-        self._drain_replays()     # recovery work re-enters ahead of admits
-        self._admit()
-        self._retire_finished()   # a 1-token budget finishes at insert time
-        self._admit()             # ... freeing its slot for queued work now
-        self._expire_prefilling()  # deadline died mid-chunk: unwind, expire
-        self._advance_prefill()   # <= prefill_chunk_tokens of pending prefill
-        self._retire_finished()   # a 1-token budget may finish at chunk end
-        if self._injector is not None and self.paged:
-            victims = self._injector.pages_to_corrupt(
-                self.session.paged.live_pages())
-            if victims:
-                self._handle_corrupt_pages(victims)
-        self._observe_block()
+        rnd = self.blocks         # the round's number, on all of its spans
+        with self._phase("admit", rnd, self._tile_at) as tile:
+            self._emitted.clear()     # harvest reads last block's emissions
+            self.queue.advance(self.blocks)
+            self._sweep_idle_parks()  # idle streams spill to the durable tier
+            self._drain_replays()     # recovery re-enters ahead of admits
+            self._admit()
+            self._retire_finished()   # a 1-token budget finishes at insert
+            self._admit()             # ... freeing its slot for queued work
+            self._expire_prefilling()  # deadline died mid-chunk: unwind
+            self._advance_prefill()   # <= prefill_chunk_tokens of prefill
+            self._retire_finished()   # a 1-token budget may finish at chunk end
+            if self._injector is not None and self.paged:
+                victims = self._injector.pages_to_corrupt(
+                    self.session.paged.live_pages())
+                if victims:
+                    self._handle_corrupt_pages(victims)
+        with self._phase("observe", rnd, tile and tile.end) as tile:
+            self._observe_block()
         if not self._active.any():
             if (not self.queue and not self._prefilling
                     and not self._replay_q):
@@ -3713,77 +3761,98 @@ class ServeEngine:
             self.stats["blocks"] += 1
             return True
         t0 = time.perf_counter()
-        toks = self._advance_block()
+        toks, sums = self._advance_block(tile and tile.end)
         now = time.perf_counter()
-        if self.tracer.enabled:
-            self.tracer.complete(
-                "decode_block", (self.lane, "blocks"), t0, now,
-                block=self.blocks,
-                args={"active": int(self._active.sum()),
-                      "steps": self.block_steps, "fused": self.fused})
-        self.stats["blocks"] += 1
-        self.stats["decode_blocks"] += 1
-        # mirror the device latches from the one fetch (K, b)
-        for i in range(self.block_steps):
-            row = toks[i]
-            for slot, req in enumerate(self.slots):
-                if (req is not None and slot not in self._prefilling
-                        and not self._done[slot]):
-                    self._record(slot, int(row[slot]), now)
-                    # DFA-state mirror: the same transition the device took
-                    # on this emitted token (accept-terminal latches done +
-                    # finish_reason="grammar_accept", like EOS)
-                    self._advance_grammar(slot, int(row[slot]))
-            self._lengths += 1
-            self._gen_counts += 1
-        self._tok = toks[-1].astype(np.int32)
-        self.blocks += 1
-        self._expire_decoding()   # completion deadline passed: partial NOW
-        self._retire_finished()
+        with self._phase("harvest", rnd, self._tile_at):
+            if self.tracer.enabled:
+                self.tracer.complete(
+                    "decode_block", (self.lane, "blocks"), t0, now,
+                    block=self.blocks,
+                    args={"active": int(self._active.sum()),
+                          "steps": self.block_steps, "fused": self.fused})
+            if sums:
+                self._count_block_sums(sums)
+            self.stats["blocks"] += 1
+            self.stats["decode_blocks"] += 1
+            # mirror the device latches from the one fetch (K, b)
+            for i in range(self.block_steps):
+                row = toks[i]
+                for slot, req in enumerate(self.slots):
+                    if (req is not None and slot not in self._prefilling
+                            and not self._done[slot]):
+                        self._record(slot, int(row[slot]), now)
+                        # DFA-state mirror: the same transition the device
+                        # took on this emitted token (accept-terminal latches
+                        # done + finish_reason="grammar_accept", like EOS)
+                        self._advance_grammar(slot, int(row[slot]))
+                self._lengths += 1
+                self._gen_counts += 1
+            self._tok = toks[-1].astype(np.int32)
+            self.blocks += 1
+            self._expire_decoding()   # completion deadline passed: partial NOW
+            self._retire_finished()
         return True
 
-    def _advance_block(self) -> np.ndarray:
+    def _advance_block(self, after: Optional[float] = None
+                       ) -> Tuple[np.ndarray, list]:
         """Advance the pool ``block_steps`` tokens; returns the emitted
-        (K, max_batch) token matrix. Fused mode: ONE program call + ONE
-        fetch. Stepwise mode: the same schedule paid per token (K dispatches
-        + K fetches) — the measurement baseline and exactness oracle. Sim
-        mode (inference/simlm.py): the stub's deterministic token function,
-        pure numpy, accounted like one fused dispatch + fetch."""
+        (K, max_batch) token matrix and, from a fused block, the sums that
+        rode its fetch (``_count_block_sums``). Fused mode: ONE program call
+        + ONE fetch. Stepwise mode: the same schedule paid per token (K
+        dispatches + K fetches) — the measurement baseline and exactness
+        oracle. Sim mode (inference/simlm.py): the stub's deterministic
+        token function, pure numpy, accounted like one fused dispatch +
+        fetch. The ``launch`` phase begins on the stamp ``after`` (where
+        ``observe`` ended) and ends where the program call returned and the
+        block's fetch begins (stepwise: it holds all K of each)."""
+        launch = self._phase("launch", self.blocks, after,
+                             {"active": int(self._active.sum())}
+                             if self.tracer.enabled else None)
         if self._sim:
-            rids = [(-1 if r is None else r.request_id) for r in self.slots]
-            toks = self._dispatch("decode", lambda: self.lm.sim_decode_block(
-                self.block_steps, self._tok, self._active, self._done,
-                self._gen_counts, rids))
-            self.session.lengths = self.session.lengths + self.block_steps
-            self.stats["program_calls"] += 1
-            self.stats["host_fetches"] += 1
-            return self._fetch(toks)
+            with launch:
+                rids = [(-1 if r is None else r.request_id)
+                        for r in self.slots]
+                toks = self._dispatch(
+                    "decode", lambda: self.lm.sim_decode_block(
+                        self.block_steps, self._tok, self._active,
+                        self._done, self._gen_counts, rids))
+                self.session.lengths = self.session.lengths + self.block_steps
+                self.stats["program_calls"] += 1
+                self.stats["host_fetches"] += 1
+            return self._fetch(toks), []
         if self.fused:
-            fused = self.lm.compile_session_decode_fused(
-                self.block_steps, self.slot_sampler, self.pad_token_id)
-            args = (self.lm.params, self.session.cache,
-                    jnp.asarray(self._tok[:, None]), self._slot_keys,
-                    jnp.asarray(self._gen_counts),
-                    jnp.asarray(self._lengths), jnp.asarray(self._active),
-                    jnp.asarray(self._done), jnp.asarray(self._eos),
-                    jnp.asarray(self._temp), jnp.asarray(self._greedy),
-                    *self.lm._ad_args(self.session.adapters,
-                                      self._adapter_idx),
-                    *self.lm._gr_args(self.session.grammars, self._gidx,
-                                      self._gstate, self._gbudget))
-            # 5 outputs, or 6 with grammar (the trailing DFA state exists
-            # for the async pipeline; the sync loop ignores it)
-            outs = self._dispatch("decode", lambda: fused(*args))
-            toks, cache = outs[0], outs[1]
-            self.session.cache = cache
-            self.session.lengths = self.session.lengths + self.block_steps
-            self.stats["program_calls"] += 1
-            self.stats["host_fetches"] += 1
+            with launch:
+                fused = self.lm.compile_session_decode_fused(
+                    self.block_steps, self.slot_sampler, self.pad_token_id)
+                args = (self.lm.params, self.session.cache,
+                        jnp.asarray(self._tok[:, None]), self._slot_keys,
+                        jnp.asarray(self._gen_counts),
+                        jnp.asarray(self._lengths), jnp.asarray(self._active),
+                        jnp.asarray(self._done), jnp.asarray(self._eos),
+                        jnp.asarray(self._temp), jnp.asarray(self._greedy),
+                        *self.lm._ad_args(self.session.adapters,
+                                          self._adapter_idx),
+                        *self.lm._gr_args(self.session.grammars, self._gidx,
+                                          self._gstate, self._gbudget))
+                # 5 outputs, or 6 with grammar (the trailing DFA state exists
+                # for the async pipeline; the sync loop ignores it)
+                outs = self._dispatch("decode", lambda: fused(*args))
+                toks, cache = outs[0], outs[1]
+                self.session.cache = cache
+                self.session.lengths = self.session.lengths + self.block_steps
+                self.stats["program_calls"] += 1
+                self.stats["host_fetches"] += 1
             # the block's sums ride the same fetch: how far its steps read
             # the cache, then (a model with experts) what its router chose
             toks, *sums = self._fetch((toks, *outs[self._walked_at:]))
-            self._count_block_sums(sums)
-            return toks
+            return toks, sums
+        with launch:
+            toks = self._advance_stepwise()
+        self._tile_at = None        # harvest begins where launch ended
+        return toks, []
+
+    def _advance_stepwise(self) -> np.ndarray:
+        """``_advance_block`` paid per token: K dispatches, K fetches."""
         out = np.zeros((self.block_steps, self.lm.max_batch), np.int64)
         done = self._done.copy()
         temp = jnp.asarray(self._temp)
@@ -4075,7 +4144,7 @@ class ServeEngine:
         if "sums" in rec:
             toks, *sums = self._fetch((rec["toks"], *rec["sums"]),
                                       block=rec["block"])
-            self._count_block_sums(sums, block=rec["block"])
+            self._count_block_sums(sums)
         else:                       # sim mode: nothing ran on a device
             toks = self._fetch(rec["toks"], block=rec["block"])
         self.stats["host_fetches"] += 1

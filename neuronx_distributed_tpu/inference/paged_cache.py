@@ -62,6 +62,7 @@ size with ``kv_page_bytes_host()``.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import heapq
 import time
@@ -930,6 +931,17 @@ class PagedKVCache:
 
     def _block(self) -> Optional[int]:
         return None if self._block_fn is None else int(self._block_fn())
+
+    def span(self, name: str, **args):
+        """``with pkv.span("cache_plan", rows=8):`` — the body as one host
+        span on the ``("cache", "pool")`` lane of the attached tracer,
+        numbered by the scheduler's block (``CausalLM._insert_paged`` times
+        its ``cache_plan`` and ``cache_commit`` loops with it). Nothing
+        attached, or tracing off: a do-nothing context."""
+        if self._tracer is None:
+            return contextlib.nullcontext()
+        return self._tracer.span(name, ("cache", "pool"),
+                                 block=self._block(), args=args or None)
 
     def _note_prefix(self, shared: List[int]) -> None:
         if self._m_prefix is not None:

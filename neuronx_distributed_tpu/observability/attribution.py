@@ -25,7 +25,9 @@ non-overlapping phase segments:
   blocks until the retrying admission lands are the adapter-load price);
 * ``prefill``         — chunked prefill rounds (``chunk_begin`` to
   ``first_token``); one-shot inserts admit and sample the first token in
-  the same block, so their prefill phase is 0 blocks wide by construction;
+  the same block, so their prefill phase is 0 blocks wide by construction,
+  and its WALL time is the insert itself: ``admit`` is stamped where the
+  slot was claimed, ``first_token`` where the token reached the host;
 * ``decode``          — first token to the terminal event, minus any
   recovery interruption;
 * ``migration``       — prefill/decode disaggregation handoff: the span
@@ -186,7 +188,11 @@ def request_attribution(tracer, request_id: int) -> Optional[dict]:
         elif name == "tier_restore":
             annotations["tier_restored_pages"] += int(args.get("pages", 0))
         elif name == "admit":
+            # the slot is claimed: what follows until first_token is the
+            # request's own prefill (a one-shot insert's program and fetch:
+            # wall time only, the same block)
             close(blk, ts)
+            phase = "prefill"
         elif name == "place":
             # a replay placement is the failover path: leave the cursor
             # where the stream died so the replay_admit that follows can

@@ -22,9 +22,20 @@ Cost contract (the tentpole's hard constraint):
   off CANNOT change a compiled program — the signature-identity test in
   ``tests/test_observability.py`` pins this.
 
+One clock with a device trace: the stamps here are ``perf_counter()``, a
+profiler's are its own. ``Tracer(annotate=...)`` takes a callable, name ->
+context manager, and an enabled tracer enters ``annotate("nxd:" + name)``
+around the body of every :meth:`Tracer.span`, so the same span also lies on
+the profiler's host line, on the profiler's clock, beside the device's ops.
+The serving engine passes ``jax.profiler.TraceAnnotation`` where it builds
+its own tracer; this module never learns what the callable is. Spans written
+after the fact (:meth:`Tracer.complete`) are not mirrored.
+
 Lanes are ``(process, track)`` pairs: ``("req", <request_id>)`` gives every
-request its own Perfetto row; ``("engine", "dispatch"|"blocks"|"faults"|
-"snapshot"|"compile")``, ``("cache", "pool"|"tier")`` — the ``tier`` track
+request its own Perfetto row; ``("engine", "dispatch"|"blocks"|"phases"|
+"faults"|"snapshot"|"compile")`` — ``phases`` holds one ``step_block`` span
+a scheduling round and the round's ``admit``/``observe``/``launch``/
+``harvest`` inside it — ``("cache", "pool"|"tier")`` — the ``tier`` track
 carries the host-memory KV tier's ``tier:spill``/``tier:restore``/
 ``tier:corrupt`` instants plus the ``tier_pages`` counter — and
 ``("trainer", ...)`` carry the engine/cache/trainer timelines. The exporter
@@ -38,24 +49,40 @@ import contextlib
 import json
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 Lane = Tuple[str, Any]
+
+# what a disabled tracer's span() hands back: reusable, re-entrant, free
+_NO_SPAN = contextlib.nullcontext()
 
 # Chrome trace-event phases this tracer emits: X (complete span with dur),
 # i (instant), C (counter), M (metadata — exporter only)
 _PHASES = ("X", "i", "C")
 
 
+class SpanStamps:
+    """What ``with tracer.span(...) as s`` binds: the open span's wall
+    stamps (``end`` is None until the body has ended)."""
+
+    __slots__ = ("start", "end")
+
+    def __init__(self, start: float):
+        self.start = start
+        self.end: Optional[float] = None
+
+
 class Tracer:
     """Bounded structured event recorder. One per engine/trainer; share one
     across components to get a single merged timeline."""
 
-    def __init__(self, capacity: int = 65536, enabled: bool = True):
+    def __init__(self, capacity: int = 65536, enabled: bool = True,
+                 annotate: Optional[Callable[[str], Any]] = None):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.enabled = bool(enabled)
         self.capacity = int(capacity)
+        self.annotate = annotate
         self._buf: deque = deque(maxlen=capacity)
         self._recorded = 0
         self._t0 = time.perf_counter()
@@ -101,24 +128,41 @@ class Tracer:
                       "ts": self.now() if ts is None else ts,
                       "block": block, "args": {"value": value}})
 
-    @contextlib.contextmanager
     def span(self, name: str, lane: Lane, *, block: Optional[int] = None,
-             args: Optional[dict] = None):
+             args: Optional[dict] = None, start: Optional[float] = None):
         """``with tracer.span("decode", ("engine", "dispatch")):`` — times
         the body and records one X event (recorded even when the body
         raises, with ``error`` marked: a failed dispatch is exactly the
-        event a timeline reader is looking for)."""
+        event a timeline reader is looking for). ``args`` is read when the
+        body has ended, so the body may fill it in. With an ``annotate``
+        hook the body also runs inside ``annotate("nxd:" + name)``.
+
+        ``with ... as s`` gives the span's stamps, ``s.start`` and (once the
+        body has ended) ``s.end``; ``start=`` begins the span on a stamp
+        taken earlier — the ``end`` of the span before it — so that
+        consecutive spans tile with no gap for their own bookkeeping. A
+        disabled tracer hands back one shared do-nothing context, whose
+        ``as`` is None."""
         if not self.enabled:
-            yield None
-            return
-        t0 = self.now()
+            return _NO_SPAN
+        return self._span(name, lane, block, args, start)
+
+    @contextlib.contextmanager
+    def _span(self, name: str, lane: Lane, block: Optional[int],
+              args: Optional[dict], start: Optional[float]):
+        mirror = (_NO_SPAN if self.annotate is None
+                  else self.annotate("nxd:" + name))
+        stamps = SpanStamps(self.now() if start is None else start)
         try:
-            yield None
+            with mirror:
+                yield stamps
         except BaseException as e:
-            self.complete(name, lane, t0, self.now(), block=block,
-                          args={**(args or {}), "error": type(e).__name__})
+            args = {**(args or {}), "error": type(e).__name__}
             raise
-        self.complete(name, lane, t0, self.now(), block=block, args=args)
+        finally:
+            stamps.end = self.now()
+            self.complete(name, lane, stamps.start, stamps.end, block=block,
+                          args=args)
 
     # --- introspection ---------------------------------------------------
 

@@ -127,6 +127,41 @@ def test_base_lane_decomposition(lm):
         assert any("pool_wait" in w["phases_blocks"] for w in waited)
 
 
+def test_one_shot_inserts_wall_time_lands_in_prefill(lm):
+    """``admit`` is stamped where the slot was claimed and ``first_token``
+    where the token reached the host: the walker charges the insert between
+    them to ``prefill`` on the wall clock, nothing to it on the block clock
+    (both marks are the same block), and ``queued`` keeps only the wait."""
+    eng = ServeEngine(lm, block_steps=K, trace=True, rng=jax.random.key(7))
+    first = eng.submit(_prompts(1, s=8, seed=3)[0], 6)
+    eng.step_block()
+    second = eng.submit(_prompts(1, s=8, seed=4)[0], 6,
+                        arrival_block=eng.blocks)     # into the running batch
+    eng.run(max_blocks=50)
+    atts = _check_invariant(eng.tracer)
+    dispatch = [e for e in eng.tracer.events()
+                if e["lane"] == (eng.lane, "dispatch")]
+    inserts = [e for e in dispatch if e["name"] == "insert"]
+    fetches = [e for e in dispatch if e["name"] == "insert_fetch"]
+    assert len(inserts) == len(fetches) == 2
+    for rid, insert, fetch in zip((first, second), inserts, fetches):
+        a = atts[rid]
+        assert "prefill" not in a["phases_blocks"]       # block widths as before
+        assert a["phases_blocks"] == {"decode": a["e2e_blocks"]}
+        wall = a["phases_wall_ms"]
+        program_and_fetch = (insert["dur"] + fetch["dur"]) * 1e3
+        # the whole insert (its program call and its fetch) and little else
+        assert wall["prefill"] >= program_and_fetch - 1e-3
+        evs = {e["name"]: e for e in eng.tracer.by_request()[rid]}
+        between = (evs["first_token"]["ts"] - evs["admit"]["ts"]) * 1e3
+        assert wall["prefill"] == pytest.approx(between, abs=2e-3)
+        # queued: submit to the claim, which is before the insert began
+        queued = evs["queued"]
+        assert queued["ts"] + queued["dur"] <= insert["ts"]
+        assert wall.get("queued", 0.0) == pytest.approx(queued["dur"] * 1e3,
+                                                        abs=2e-3)
+
+
 def test_attribution_empty_without_tracing(lm):
     eng = ServeEngine(lm, block_steps=K)
     eng.submit(_prompts(1)[0], 4)

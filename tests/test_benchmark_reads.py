@@ -4,7 +4,8 @@
 ``benchmark/trace_parts.py`` and ``benchmark/trace_reduce.py`` read the
 program by name: ``engine.stats`` keys, the paged pool's counters,
 ``Completion`` fields, engine and ``CausalLM`` attributes, the names of the
-three jitted functions a device trace is reduced by. A rename fails
+three jitted functions a device trace is reduced by, the engine's own spans
+of a round and their args (``benchmark/phase_spans.py``). A rename fails
 silently there: ``_StatsView`` defaults a missing key to 0, a renamed
 jitted function zeroes ``decode.step_ms`` / ``prefill.ms_per_call`` only
 under trace on the chip, and the one CPU rehearsal of the serving driver
@@ -184,6 +185,19 @@ ROUTED_READS = ["moe_assignments_routed"]
 # in every record's `engine_stats`; no metric of BENCHMARK.json reads them yet
 WALK_STATS = ["kv_walk_tokens", "kv_walk_steps"]
 TRAIN_READS = ["jit_step_fn"]
+# the engine's own spans of a round (PR 39), as ``benchmark/phase_spans.py`` and
+# the seven readers over it spell them: (name, lane's track) of a complete span
+PHASE_SPANS = [('"step_block"', "phases"), ('"admit"', "phases"), ('"admission"', "phases"),
+               ('"observe"', "phases"), ('"launch"', "phases"), ('"harvest"', "phases"),
+               ('"cache_plan"', "pool"), ('"cache_commit"', "pool"), ('"queued"', None),
+               ('"fetch"', "dispatch"), ('"insert_fetch"', "dispatch"),
+               ('"decode_block"', "blocks")]
+# the args of those spans that a reader takes: (arg, the span that carries it)
+PHASE_ARGS_READ = [('"worked"', "step_block"), ('"decoding"', "admission")]
+# ... and the ones no reader takes yet: recorded for whoever looks at a trace
+PHASE_ARGS_UNREAD = [("decoded", "step_block"), ("rows", "admission"), ("bucket", "admission"),
+                     ("rids", "admission"), ("active", "launch"), ("rows", "cache_plan"),
+                     ("rows", "cache_commit")]
 
 
 @pytest.mark.parametrize("name,produced", SERVING_READS, ids=[n for n, _ in SERVING_READS])
@@ -283,6 +297,42 @@ def test_walk_counters_equal_a_python_model_of_the_same_lengths(async_loop):
     assert (engine.stats["kv_walk_tokens"], engine.stats["kv_walk_steps"]) == (tokens, steps)
 
 
+def _complete_spans(r, name):
+    return [e for e in r.engine.tracer.events(name.strip('"')) if e["ph"] == "X"]
+
+
+@pytest.mark.parametrize("name,track", PHASE_SPANS, ids=[n.strip('"') for n, _ in PHASE_SPANS])
+def test_phase_span_is_produced(run, name, track):
+    """On the track the helper looks on (a request's own lane for ``queued``),
+    with a start, a duration and the round's number."""
+    spans = _complete_spans(run, name)
+    assert spans and all(e["dur"] >= 0 and e["block"] is not None for e in spans)
+    if track is None:
+        assert {e["lane"] for e in spans} == {("req", rid) for rid in run.ids}
+    else:
+        assert {e["lane"][1] for e in spans} == {track}
+    if name == '"step_block"':
+        assert len(spans) == run.engine.stats["blocks"] + 1      # the last found nothing to do
+    if name == '"admission"':
+        assert len(spans) == run.engine.stats["inserts"]
+
+
+@pytest.mark.parametrize("arg,span", PHASE_ARGS_READ + PHASE_ARGS_UNREAD,
+                         ids=[f"{s}.{a}".replace('"', "") for a, s in PHASE_ARGS_READ + PHASE_ARGS_UNREAD])
+def test_phase_span_arg_is_produced(run, arg, span):
+    arg = arg.strip('"')
+    values = [e["args"][arg] for e in _complete_spans(run, span)]
+    assert values
+    if arg in ("worked", "decoded"):
+        assert {type(v) for v in values} == {bool} and any(values) and not all(values)
+    elif arg == "rids":
+        assert sorted(rid for v in values for rid in v) == sorted(run.ids)
+    elif arg == "decoding":       # two slots: the second pair was admitted beside nobody or one row
+        assert all(isinstance(v, int) and 0 <= v <= 2 for v in values) and values[0] == 0
+    else:
+        assert all(isinstance(v, int) and v > 0 for v in values)
+
+
 def test_paged_insert_program_is_built_from_rows_and_bucket(run):
     """``benchmark/aot_check.py`` builds a cell's insert programs with two
     arguments, and gets the engine's: the same object, keyed (rows, bucket)."""
@@ -301,9 +351,11 @@ def test_every_name_is_still_read():
     that stopped reading a name takes its case away with it."""
     files = [*sorted((BENCHMARK / "drivers").glob("*.py")),
              *sorted((BENCHMARK / "layer_metrics").glob("*.py")),
-             BENCHMARK / "trace_parts.py", BENCHMARK / "trace_reduce.py"]
+             BENCHMARK / "trace_parts.py", BENCHMARK / "trace_reduce.py",
+             BENCHMARK / "phase_spans.py"]
     text = "\n".join(f.read_text() for f in files)
-    names = [n for n, _ in SERVING_READS] + MOE_READS + ROUTED_READS + TRAIN_READS
+    names = ([n for n, _ in SERVING_READS] + MOE_READS + ROUTED_READS + TRAIN_READS
+             + [n for n, _ in PHASE_SPANS] + [n for n, _ in PHASE_ARGS_READ])
     assert len(set(names)) == len(names)
     missing = [n for n in names if n not in text]
     assert not missing, missing

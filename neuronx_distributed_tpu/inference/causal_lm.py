@@ -37,7 +37,7 @@ from neuronx_distributed_tpu.inference.partition import (
     zeros_like_avals,
 )
 from neuronx_distributed_tpu.inference.sampling import Sampler, SlotSampler
-from neuronx_distributed_tpu.models.llama import KVWalk
+from neuronx_distributed_tpu.models.llama import kv_walk
 
 PyTree = Any
 
@@ -156,19 +156,23 @@ def _routing_sums(stats: PyTree, live: jax.Array) -> jax.Array:
 
 
 def _walk_sums(config, cache: PyTree, live: jax.Array) -> jax.Array:
-    """``(2,) int32`` of one decode step: the slots of the cache the step read
-    of every row (:class:`~neuronx_distributed_tpu.models.llama.KVWalk`, chunk
-    rounding included; all of ``max_seq_len`` under ``paged_attn_kernel``,
-    whose grid visits every page), and 1; both 0 where no row is live. From
-    the cache's own ``cache_index`` and the ``live`` the model is given, so it
-    is the bound the attention computed."""
+    """``(3,) int32`` of one decode step: the slots of the cache the step read
+    of its longest row (:class:`~neuronx_distributed_tpu.models.llama.KVWalk`,
+    chunk rounding included; all of ``max_seq_len`` under
+    ``paged_attn_kernel``, whose grid visits every page), 1, and the slots it
+    read summed over its rows (the walk's rung of rows for every chunk read;
+    every row under the kernel); all 0 where no row is live. From the cache's
+    own ``cache_index`` and the ``live`` the model is given, so they are the
+    bounds the attention computed."""
     idx = next(leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
                if jax.tree_util.keystr(path).endswith("['cache_index']"))[0]
     if config.paged_attn_kernel:
         tokens = jnp.int32(config.max_seq_len)
+        row_slots = tokens * idx.shape[0]
     else:
-        tokens = KVWalk(config.max_seq_len, config.page_size, idx, live).tokens
-    return jnp.stack([tokens, 1]).astype(jnp.int32) * jnp.any(live)
+        walk = kv_walk(config, idx, live)
+        tokens, row_slots = walk.tokens, walk.row_slots
+    return jnp.stack([tokens, 1, row_slots]).astype(jnp.int32) * jnp.any(live)
 
 
 def infer_prompt_lengths(prompt_ids: np.ndarray, pad_token_id: int = 0) -> np.ndarray:
@@ -810,11 +814,14 @@ class CausalLM:
 
         Every model is told which rows are live at each step (``live``:
         active and not done): they alone set how far the step reads the cache
-        (``models/llama.py::KVWalk``). After the row outputs (and the
-        ``dfa_state``) comes ``(2,) int32``: the slots of the cache the
-        block's steps read of every row, summed over the steps with a live
-        row, and the number of those steps (``_walk_sums``); over ``steps x
-        max_seq_len`` it is the share of the logical slab a step read.
+        and of how many rows (``models/llama.py::KVWalk``). After the row
+        outputs (and the ``dfa_state``) comes ``(3,) int32``: the slots of the
+        cache the block's steps read of their longest row, summed over the
+        steps with a live row, the number of those steps, and the slots read
+        summed over the rows of each step's rung (``_walk_sums``). Over
+        ``steps x max_seq_len`` the first is the share of the logical slab a
+        step read; the third over the first x ``max_batch`` is the share of
+        that rectangle's rows.
 
         A model with experts (``self.moe_stats``) returns one more value,
         LAST: ``(3,) int32`` sums over the block's steps and the layers of
@@ -857,8 +864,8 @@ class CausalLM:
                     walked = walked + _walk_sums(self.config, cache, live)
                 logits, mut = self.model.apply(
                     self._ad_vars(params, cache, ad), tok,
-                    # dead rows choose no expert (moe/layer.py) and do not
-                    # set how far the step reads the cache (KVWalk)
+                    # dead rows choose no expert (moe/layer.py), do not set
+                    # how far the step reads the cache and are not read (KVWalk)
                     live=live[:, None],
                     mutable=["cache", "moe_stats"] if moe else ["cache"]
                 )
@@ -893,7 +900,7 @@ class CausalLM:
 
             init = ((cache, tok, counts, lengths, done, gstate0) if gr
                     else (cache, tok, counts, lengths, done))
-            init = (*init, jnp.zeros((2,), jnp.int32))
+            init = (*init, jnp.zeros((3,), jnp.int32))
             if moe:
                 init = (*init, jnp.zeros((self.moe_sums,), jnp.int32))
             carry, toks = jax.lax.scan(body, init, None, length=steps)

@@ -355,11 +355,13 @@ _STAT_KEYS = (
     # one of each, so (calls + fetches) / inserts reads 2.0 where every
     # admission was a one-shot insert and more where one went by chunks
     "insert_program_calls", "insert_host_fetches",
-    # how far the fused decode blocks read the cache: slots read of every row
-    # (models/llama.py::KVWalk, chunk rounding included) summed over the
-    # steps that had a live row, and those steps; tokens / (steps x
-    # max_seq_len) is the share of the logical slab a step read
-    "kv_walk_tokens", "kv_walk_steps",
+    # how far the fused decode blocks read the cache, and of how many rows:
+    # slots read of a row (models/llama.py::KVWalk, chunk rounding included)
+    # summed over the steps that had a live row, those steps, and the slots
+    # read summed over the rows of each step's rung; tokens / (steps x
+    # max_seq_len) is the share of the logical slab a step read, row_slots /
+    # (tokens x max_batch) the share of that rectangle's rows
+    "kv_walk_tokens", "kv_walk_steps", "kv_walk_row_slots",
 )
 
 
@@ -3664,11 +3666,13 @@ class ServeEngine:
     def _count_block_sums(self, sums) -> None:
         """What one fused block returned beside its rows
         (``CausalLM.compile_session_decode_fused``): the cache slots its live
-        steps read of every row and the number of those steps, then a model
-        with experts' routing sums."""
+        steps read of a row, the number of those steps and the slots read
+        over the rows of each step's rung, then a model with experts' routing
+        sums."""
         walked, *routing = sums
         self.stats["kv_walk_tokens"] += int(walked[0])
         self.stats["kv_walk_steps"] += int(walked[1])
+        self.stats["kv_walk_row_slots"] += int(walked[2])
         if routing:
             self._count_routing(routing[0])
 

@@ -3,8 +3,10 @@
 The paged gather path (models/llama.py ``_decode_attention``) gathers K/V
 through the block table EVERY decode step: since PR 38 in chunks of whole
 pages up to the reach of the step's longest live row (``KVWalk``; before,
-all ``(b, max_seq_len)`` slots of the logical slab), dead rows inside that
-bound and the unmapped tail of every shorter row included. This kernel is
+all ``(b, max_seq_len)`` slots of the logical slab), and since PR 40 of the
+smallest rung of rows (``KVWalk.ladder``) that holds the live ones; the rows that
+fill the rung up and the unmapped tail of every shorter row included. This
+kernel is
 the fused replacement for the single-token decode step: FlashAttention-style
 online-softmax tiling (kernels/flash_attn.py idiom) laid over
 PagedAttention's physical page layout, consuming the per-slot block
@@ -286,7 +288,7 @@ def reference_paged_attention(q, k_pages, v_pages, block_table, cache_len,
                               *, k_scale=None, v_scale=None, sm_scale=None):
     """XLA gather oracle: materialize the whole ``(b, max_seq_len)`` logical
     view the way ``_decode_attention``'s gather branch does for a prompt (a
-    one-token step reads a prefix of it, ``KVWalk``), then run the dense
+    one-token step reads a prefix of it, of its live rows: ``KVWalk``), then run the dense
     ``cached_attention`` math — the bit-exactness reference the kernel
     tests compare against (and the int8 dequant reference)."""
     from neuronx_distributed_tpu.models.llama import cached_attention

@@ -20,8 +20,8 @@ What is this file's and what is the stack's:
   the flash kernel, one row at a time (a row's expanded keys and values at
   4096 positions and 128 heads are 0.33 GB). A decode step attends in the
   ABSORBED form: ``q_nope`` is taken through ``W_uk`` into the latent space,
-  scores and values are einsums over the gathered latent pages (as far as
-  the step's longest live row reaches: ``models/llama.py::KVWalk``), and
+  scores and values are einsums over the gathered latent pages (of the live
+  rows, as far as the longest of them reaches: ``models/llama.py::KVWalk``), and
   ``W_uv`` brings the result back; keys and values of cached tokens are never
   expanded. The two are the same mathematics (``tests/test_deepseek_v2.py``
   holds them together).
@@ -59,6 +59,7 @@ from neuronx_distributed_tpu.models.llama import (
     LlamaMLP,
     YarnScaling,
     apply_rotary,
+    kv_walk,
     rotary_embedding,
 )
 from neuronx_distributed_tpu.models.mixtral import MixtralConfig, MixtralDecoderLayer
@@ -134,6 +135,15 @@ class DeepseekV2Config(MixtralConfig):
         if s is not None and s.mscale_all_dim:
             scale *= YarnScaling.get_mscale(s.factor, s.mscale_all_dim) ** 2
         return scale
+
+    @property
+    def kv_walk_loops(self) -> bool:
+        """A one-token step reads the latent cache by the switch whatever the
+        table's length (``models/llama.py::kv_walk``): the loop would carry
+        128 heads x 576 float32 a row through every turn, and read 30.7 ms a
+        step on the v5e where the switch reads 7.5 and the whole read 8.2
+        (PERF.md, PR 38)."""
+        return False
 
     def kv_leaf_shapes(self, batch: int) -> dict:
         """ONE leaf, no value leaf (``models/llama.py::kv_leaf_shapes``)."""
@@ -299,35 +309,30 @@ class DeepseekV2Attention(nn.Module):
             q_lat = jnp.einsum("bsnd,rnd->bsnr", q[..., :nope], w_uk, **_EXACT)
             q_all = jnp.concatenate([q_lat, q_rope.astype(jnp.float32)], axis=-1)
 
-        # the step reads as far as its longest live row reaches
-        walk = KVWalk(S, ps, idx, None if live is None else live[:, 0])
+        walk = kv_walk(cfg, idx, None if live is None else live[:, 0])
         o_lat = self._walk_attention(q_all, kv, walk, table if ps else None)
         with jax.named_scope("mla_absorb"):
             return jnp.einsum("bsnr,rnd->bsnd", o_lat, w_uv, **_EXACT).astype(q.dtype)
 
     def _walk_attention(self, q_all, kv, walk: KVWalk, table):
         """``q_all`` (b, 1, n, latent_dim) float32, one new token a row in the
-        latent space, over the prefix of the cache below ``walk``'s bound;
-        returns the weighted latents ``(b, 1, n, kv_lora_rank)``. By the
-        switch (``KVWalk.prefix``) whatever the table's length: the loop
-        would carry 128 heads x 576 float32 a row through every turn, and
-        read 30.7 ms a step on the v5e where this reads 7.5 and the whole
-        read 8.2 (PERF.md, PR 38)."""
+        latent space, over what ``walk`` reads of the latent cache (the
+        prefix below its bound, of the rows in its rung); returns the
+        weighted latents ``(b, 1, n, kv_lora_rank)``."""
         cfg = self.config
-        b = q_all.shape[0]
-        pool, first = kv.flat(LATENT_LEAF), kv.first_row(b)
+        pool = kv.flat(LATENT_LEAF)
 
-        def attend(count):
+        def attend(top, count):
             with jax.named_scope("kv_gather"):
-                slab = walk.span(pool, table, first, 0, count).reshape(
-                    b, count * walk.chunk, cfg.latent_dim)
+                slab = top.span(pool, 0, count).reshape(-1, count * walk.chunk, cfg.latent_dim)
             with jax.named_scope("attend"):
-                scores = jnp.einsum("bsnc,bjc->bnsj", q_all, slab, **_EXACT) * cfg.softmax_scale
+                scores = jnp.einsum("bsnc,bjc->bnsj", top.q, slab, **_EXACT) * cfg.softmax_scale
                 probs = jax.nn.softmax(jnp.where(
-                    walk.visible(0, count)[:, None, None], scores, -1e30), axis=-1)
+                    top.visible(0, count)[:, None, None], scores, -1e30), axis=-1)
                 return jnp.einsum("bnsj,bjc->bsnc", probs, slab, **_EXACT)[..., :cfg.kv_lora_rank]
 
-        return attend(1) if walk.n_chunks == 1 else walk.prefix(attend)
+        rows = walk.rows(q_all, table, kv.first_row(q_all.shape[0]))
+        return attend(rows, 1) if walk.n_chunks == 1 else walk.prefix(rows, attend)
 
 
 class DeepseekV2DenseLayer(nn.Module):

@@ -147,8 +147,8 @@ class LlamaConfig:
     # None = the contiguous max_batch x max_seq_len slab. page_size must
     # divide max_seq_len so that a row's table covers exactly the slab's
     # slots: a prompt attends over the gathered (b, max_seq_len) logical view,
-    # a one-token step over whole pages of it up to its longest live row
-    # (KVWalk), and the slab is read the same two ways, which is what keeps
+    # a one-token step over whole pages of its live rows up to the longest
+    # one's reach (KVWalk), and the slab is read the same two ways, which is what keeps
     # paged attention bit-identical to it.
     page_size: Optional[int] = None
     page_pool_pages: Optional[int] = None
@@ -163,8 +163,8 @@ class LlamaConfig:
     # fused paged decode attention (inference/paged_kernel.py): the
     # single-token decode step attends straight off the page pool through
     # the block tables (block-sparse flash tiling, every row to its own
-    # length) instead of gathering pages in-scan up to the longest live
-    # row's (KVWalk). Prefill/chunk widths and Medusa tree steps keep the
+    # length) instead of gathering the live rows' pages in-scan up to the
+    # longest one's (KVWalk). Prefill/chunk widths and Medusa tree steps keep the
     # gather path — which also stays, at fp32 pages, the bit-exactness
     # reference oracle for this branch.
     paged_attn_kernel: bool = False
@@ -473,34 +473,48 @@ class KVLayerView:
 
 
 class KVWalk:
-    """How far a ONE-TOKEN decode step reads the cache, and in what pieces.
+    """What a ONE-TOKEN decode step reads of the cache: how far, of how many
+    rows, and in what pieces.
 
     The cache of a row is ``max_seq_len`` slots, cut here into ``n_chunks``
-    chunks of ``chunk`` tokens: whole pages, an eighth of the table but not
-    under 128 tokens, and a divisor of the table so that every chunk is full.
-    A step reads the chunks below ``extent``, the reach of its longest LIVE
-    row (``cache_index + 1``: the row's new token sits at slot
-    ``cache_index``), and that bound is a value the program computes, not a
-    shape: one program serves every extent. ``live`` (b,) bool is what the
-    serving program knows (the fused session decode: active and not done). A
-    retired slot keeps a stale, still-growing ``cache_index`` over a table
-    that points at scratch, a done row keeps counting: neither sets the
-    bound. Without ``live`` every row counts, which is only ever too wide. A
-    row that is NOT live and longer than the bound attends over a prefix of
-    its keys; nobody reads what it computes, and it stays finite (``turns``
-    is at least one, so every row sees its slot 0).
+    chunks of ``chunk`` tokens (``cut``): whole pages, an eighth of the table
+    but not under 128 tokens, and a divisor of the table so that every chunk
+    is full. A row's ``reach`` is ``cache_index + 1`` (its new token sits at
+    slot ``cache_index``) if it is LIVE and 0 if not. ``live`` (b,) bool is
+    what the serving program knows (the fused session decode: active and not
+    done). A retired slot keeps a stale, still-growing ``cache_index`` over a
+    table that points at scratch, a done row keeps counting: neither is read.
+    Both bounds are values the program computes, not shapes, so one program
+    serves every state of the batch:
 
-    ``span`` reads chunks ``start .. start + n`` of a leaf (``start`` may be
-    traced, ``n`` is static) through the block table, or out of the slab
-    where the cache is not paged; ``visible`` is the mask of the same
-    chunks' key positions. Two ways to use them inside one program:
-    ``fold`` (a loop with a traced trip count and a running softmax, the
-    arithmetic of ``paged_kernel.py::_decode_kernel``: no slab of keys or
-    values is ever held, but a turn has a fixed cost and the accumulator
-    rides every turn) and ``prefix`` (a ``lax.switch`` over the static
-    prefixes of the table: no carried state and the one-pass softmax, at the
-    price of a slab as long as the prefix). ``loops`` says which a GQA cache
-    should take, from what the v5e showed (PERF.md, PR 38)."""
+    * how FAR: the chunks below ``extent``, the longest reach (``turns`` of
+      them, at least one);
+    * how MANY ROWS: the smallest of ``rungs`` (``ladder``) that holds the
+      ``live_rows``. Below the top rung the rows are taken longest reach
+      first (``sorted_rows``: the live rows come first), ``WalkRows.top(r)``
+      picks the first ``r`` and ``WalkRows.back`` puts what was computed for
+      them where the step expects it, zeros for every other row (nobody
+      reads what a row that is not live computes). The top rung is the batch
+      as it stands: nothing sorted, picked or put back.
+
+    Without ``live`` (``lm.step``, ``generate``, the speculative drafts)
+    every row counts: one rung, as far as the longest row. So does a table of
+    one chunk.
+
+    Two forms inside one program: ``fold`` (a loop with a traced trip count
+    and a running softmax, the arithmetic of
+    ``paged_kernel.py::_decode_kernel``: no slab of keys or values is ever
+    held, but a turn has a fixed cost and the accumulator rides every turn)
+    and ``prefix`` (a ``lax.switch`` over the static prefixes of the table:
+    no carried state and the one-pass softmax, at the price of a slab as long
+    as the prefix). ``loops`` says which this walk takes: by the length of a
+    chunk, from what the v5e showed (PERF.md, PR 38), unless the caller's
+    cache cannot take the loop (``kv_walk``). The form sets the ladder too: a
+    rung costs the program one loop, or an attention body for EVERY prefix.
+    Either way the rung is chosen once a step: a rung chosen chunk by chunk
+    inside the loop (each row read as far as ITS reach) pays a switch a turn,
+    0.2-0.35 ms a step at Mistral-7B's 16 layers, more than it saves with
+    three rows live or fewer (PERF.md, PR 40)."""
 
     # chunks of this many tokens or more (tables of 4 096 slots up) go by the
     # loop: Mistral-7B widths, 8 rows, 3 live near 1 850 of 4 096: 12.51 ms a
@@ -511,89 +525,202 @@ class KVWalk:
     # one cannot
     LOOP_FROM = 512
 
-    def __init__(self, max_seq_len: int, page_size: Optional[int],
-                 idx: jax.Array, live: Optional[jax.Array] = None):
+    @staticmethod
+    def cut(max_seq_len: int, page_size: Optional[int]) -> Tuple[int, int, int]:
+        """``(pages a chunk, tokens a chunk, chunks)`` of a table."""
         page = page_size or 1               # the slab: "pages" of one token
         table_pages = max_seq_len // page
         pages = min(max(table_pages // 8, -(-128 // page)), table_pages)
         while table_pages % pages:
             pages += 1
-        self.pages = pages                  # pages a chunk
-        self.chunk = pages * page           # tokens a chunk
-        self.n_chunks = table_pages // pages
-        self.idx = idx                      # (b,) cache_index BEFORE this step's write
-        reach = idx + 1
-        if live is not None:
-            reach = jnp.where(live.reshape(idx.shape), reach, 0)
-        self.extent = jnp.clip(jnp.max(reach), 1, max_seq_len)
-        self.turns = (self.extent + self.chunk - 1) // self.chunk   # 1 .. n_chunks
+        return pages, pages * page, table_pages // pages
 
-    @property
-    def loops(self) -> bool:
-        return self.chunk >= self.LOOP_FROM
+    def ladder(self, b: int) -> Tuple[int, ...]:
+        """The row counts a step of ``b`` rows may read. The loop form holds
+        one loop a rung: 1, 2, 4, .. ``b``. The switch form holds an attention
+        body for every (prefix, rung), and each costs set-up its trace and
+        its compile: ONE rung below the top, a quarter of the batch. Where
+        its chunks are long (a cache that cannot loop over a table of 4 096
+        slots: the latent cache) no rung but the batch: a second set of
+        bodies in DeepSeek-V2's two layer scans read 0.4-0.8 % off a token
+        of ``deepseek-v2.longctx``, and cost 1.3-1.5 s of set-up with every
+        compile cached (13 s cold) and 0.06 ms of every step with three
+        rows live or more (PERF.md, PR 40)."""
+        if self.loops:
+            return (*(1 << i for i in range((b - 1).bit_length())), b)
+        if self.chunk >= self.LOOP_FROM:
+            return (b,)
+        return tuple(sorted({max(b // 4, 1), b}))
+
+    def __init__(self, max_seq_len: int, page_size: Optional[int], idx: jax.Array,
+                 live: Optional[jax.Array] = None, loops: Optional[bool] = None):
+        self.pages, self.chunk, self.n_chunks = self.cut(max_seq_len, page_size)
+        self.loops = self.chunk >= self.LOOP_FROM if loops is None else loops
+        self.idx = idx                      # (b,) cache_index BEFORE this step's write
+        b = idx.shape[0]
+        self.reach = jnp.minimum(idx + 1, max_seq_len)
+        self.rungs = (b,)
+        if live is not None:
+            self.reach = jnp.where(live.reshape(idx.shape), self.reach, 0)
+            if self.n_chunks > 1:
+                self.rungs = self.ladder(b)
+        self.extent = jnp.maximum(jnp.max(self.reach), 1)
+        self.turns = (self.extent + self.chunk - 1) // self.chunk   # 1 .. n_chunks
+        self.live_rows = jnp.sum(self.reach > 0)
 
     @property
     def tokens(self) -> jax.Array:
-        """Slots the step reads of every row, chunk rounding included."""
+        """Slots the step reads of a row, chunk rounding included."""
         return self.turns * self.chunk
 
-    def span(self, flat: jax.Array, table: Optional[jax.Array], first_row,
-             start, n: int) -> jax.Array:
-        """``(b, n * pages, page, ...)``: chunks ``start .. start + n`` of
-        every row, out of ``flat`` (``KVLayerView.flat``). Paged: by ``table``
-        (b, table_pages), which already holds ids in the whole stack. Slab
-        (``table`` None): rows ``first_row .. + b`` of it."""
-        b = self.idx.shape[0]
-        if table is not None:
-            return flat[jax.lax.dynamic_slice_in_dim(
-                table, start * self.pages, n * self.pages, axis=1)]
-        rows = jax.lax.dynamic_slice_in_dim(flat, first_row, b)
-        return jax.lax.dynamic_slice_in_dim(
-            rows, start * self.chunk, n * self.chunk, axis=1)[:, :, None]
+    def rung(self, rows) -> jax.Array:
+        """Index of the smallest rung that holds ``rows`` rows."""
+        return jnp.sum(rows > jnp.asarray(self.rungs[:-1], jnp.int32))
 
-    def visible(self, start, n: int) -> jax.Array:
-        """``(b, n * chunk)`` bool: key position ``j`` of those chunks is
-        visible to row ``i`` iff ``j <= idx[i]`` (``cached_attention``'s mask
-        for one new token)."""
-        kpos = start * self.chunk + jnp.arange(n * self.chunk, dtype=jnp.int32)
-        return kpos[None, :] <= self.idx[:, None]
+    @property
+    def row_slots(self) -> jax.Array:
+        """Slots the step reads, summed over the rows of its rung."""
+        return jnp.asarray(self.rungs, jnp.int32)[self.rung(self.live_rows)] * self.tokens
 
-    def fold(self, heads: Tuple[int, ...], dim: int, chunk_fn) -> jax.Array:
+    def sorted_rows(self) -> Tuple[jax.Array, jax.Array]:
+        """``(order, place)``: the rows longest reach first, ties by row
+        number, the rows without a reach last. ``order[k]`` is who stands at
+        k and ``place[i]`` where row i stands. A stable sort of b numbers
+        written as comparisons (no ``sort`` op in the layer body)."""
+        row = jnp.arange(self.idx.shape[0])
+        ahead = (self.reach[None] > self.reach[:, None]) | (
+            (self.reach[None] == self.reach[:, None]) & (row[None] < row[:, None]))
+        place = jnp.sum(ahead, axis=1)
+        return jnp.sum((place[None] == row[:, None]) * row[None], axis=1), place
+
+    def rows(self, q: jax.Array, table: Optional[jax.Array], first_row) -> "WalkRows":
+        """The step's rows as the batch holds them: ``q`` (b, ...) the
+        queries, ``table`` (b, table_pages) ids in the whole stack, or None
+        for the slab, whose rows ``first_row .. + b`` these are."""
+        return WalkRows(self, q, self.idx, table, None if table is not None else first_row)
+
+    def fold(self, rows: "WalkRows", heads: Tuple[int, ...], dim: int, chunk_fn) -> jax.Array:
         """Softmax attention over the chunks below the bound, one chunk a
-        turn of a loop whose trip count is ``turns``: ``chunk_fn(c) ->
-        (scores (b, *heads, chunk) float32, weigh)`` with ``weigh(probs) ->
-        (b, *heads, dim) float32``. Running max, sum and weighted values in
+        turn of a loop whose trip count is ``turns``, over the rows of the
+        rung (a ``lax.switch`` over the rungs, a loop in each):
+        ``chunk_fn(top, c) -> (scores (r, *heads, chunk) float32,
+        weigh)`` with ``weigh(probs) -> (r, *heads, dim) float32`` for the
+        ``r`` rows of ``top``. Running max, sum and weighted values in
         float32; the sum is reassociated against a one-pass softmax and
         nothing else changes. Returns ``(b, *heads, dim)`` float32."""
-        b = self.idx.shape[0]
-        lead = (b, *heads)
 
-        def turn(c, carry):
-            m, l, acc = carry
-            scores, weigh = chunk_fn(c)
-            with jax.named_scope("attend"):
-                mask = self.visible(c, 1).reshape(b, *(1,) * len(heads), self.chunk)
-                scores = jnp.where(mask, scores, -1e30)
-                m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
-                p = jnp.exp(scores - m_new[..., None])
-                alpha = jnp.exp(m - m_new)
-                return (m_new, alpha * l + jnp.sum(p, axis=-1),
-                        alpha[..., None] * acc + weigh(p))
+        def loop(top):
+            lead = (top.idx.shape[0], *heads)
 
-        _, l, acc = jax.lax.fori_loop(
-            0, self.turns, turn,
-            (jnp.full(lead, -1e30, jnp.float32), jnp.zeros(lead, jnp.float32),
-             jnp.zeros((*lead, dim), jnp.float32)))
-        return acc / l[..., None]
+            def turn(c, carry):
+                m, l, acc = carry
+                scores, weigh = chunk_fn(top, c)
+                with jax.named_scope("attend"):
+                    mask = top.visible(c, 1).reshape(lead[0], *(1,) * len(heads), self.chunk)
+                    scores = jnp.where(mask, scores, -1e30)
+                    m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+                    p = jnp.exp(scores - m_new[..., None])
+                    alpha = jnp.exp(m - m_new)
+                    return (m_new, alpha * l + jnp.sum(p, axis=-1),
+                            alpha[..., None] * acc + weigh(p))
 
-    def prefix(self, branch) -> jax.Array:
-        """``branch(n)`` for the static ``n`` that equals ``turns``: a
-        ``lax.switch`` over the prefixes of ``1 .. n_chunks`` chunks, each
-        branch whatever the caller does with ``span(.., 0, n)`` and
-        ``visible(0, n)``."""
+            _, l, acc = jax.lax.fori_loop(
+                0, self.turns, turn,
+                (jnp.full(lead, -1e30, jnp.float32), jnp.zeros(lead, jnp.float32),
+                 jnp.zeros((*lead, dim), jnp.float32)))
+            return acc / l[..., None]
+
+        return jax.lax.switch(self.rung(self.live_rows),
+                              [functools.partial(rows.attend, r, loop) for r in self.rungs])
+
+    def prefix(self, rows: "WalkRows", branch) -> jax.Array:
+        """``branch(top, n)`` -> ``(r, ...)`` for the static ``n`` that
+        equals ``turns`` and the ``r`` rows of the rung: ONE ``lax.switch``
+        over the (prefix, rung) pairs, each whatever the caller does with
+        ``top.span(.., 0, n)`` and ``top.visible(0, n)``, its sort and picks
+        inside it. Returns ``(b, ...)``. (A switch over the rungs around one
+        over the prefixes made the compiler re-lay-out the whole latent pool
+        in every branch, 227 MB a layer-step at ``deepseek-v2.longctx``'s
+        sizes: PERF.md, PR 40.)"""
         return jax.lax.switch(
-            self.turns - 1,
-            [functools.partial(branch, n) for n in range(1, self.n_chunks + 1)])
+            (self.turns - 1) * len(self.rungs) + self.rung(self.live_rows),
+            [functools.partial(rows.attend, r, lambda top, n=n: branch(top, n))
+             for n in range(1, self.n_chunks + 1) for r in self.rungs])
+
+
+def kv_walk(cfg: LlamaConfig, idx: jax.Array, live: Optional[jax.Array] = None) -> KVWalk:
+    """The walk of ``cfg``'s cache for a step whose rows hold ``idx`` tokens.
+    A configuration whose cache cannot take the loop says so itself
+    (``cfg.kv_walk_loops``; ``models/deepseek_v2.py``: the latent cache, by
+    the switch whatever the table's length), as it says what its leaves are
+    (:func:`kv_leaf_shapes`); whoever counts what a step read asks here too
+    (``inference/causal_lm.py::_walk_sums``)."""
+    return KVWalk(cfg.max_seq_len, cfg.page_size, idx, live,
+                  getattr(cfg, "kv_walk_loops", None))
+
+
+class WalkRows:
+    """Rows of a one-token step as :class:`KVWalk` reads them (``KVWalk.rows``:
+    all ``b`` as the batch holds them; ``top(r)``: the ``r`` of longest
+    reach, and ``place``, where each row of the batch stands among the
+    sorted): their queries ``q``, their ``idx`` (``cache_index`` before the
+    write) and where their cache lies: ``table`` (r, table_pages), or for the
+    slab ``slab``, the first row's id where the rows stand as the slab holds
+    them and the ids (r,) of picked rows."""
+
+    def __init__(self, walk: KVWalk, q, idx, table, slab, place=None):
+        self.walk, self.q, self.idx, self.table, self.slab = walk, q, idx, table, slab
+        self.place = place
+
+    def top(self, r: int) -> "WalkRows":
+        if r == self.idx.shape[0]:
+            return self
+        order, place = self.walk.sorted_rows()
+        pick = order[:r]
+        table, slab = ((self.table[pick], None) if self.slab is None
+                       else (None, self.slab + pick))
+        return WalkRows(self.walk, self.q[pick], self.idx[pick], table, slab, place)
+
+    def attend(self, r: int, fn) -> jax.Array:
+        """``fn(top) -> (r, ...)`` for the ``r`` rows of longest reach, as
+        the ``(b, ...)`` the step expects. The sort and the picks happen
+        HERE, inside whatever branch calls it: the top rung pays for none."""
+        top = self.top(r)
+        return top.back(fn(top))
+
+    def back(self, got: jax.Array) -> jax.Array:
+        """``got`` (r, ...), computed for these rows, as the ``(b, ...)`` the
+        step expects: each row's at its own place, zeros for the rows
+        outside the rung."""
+        if self.place is None:
+            return got
+        rest = self.place.shape[0] - got.shape[0]
+        return jnp.pad(got, [(0, rest)] + [(0, 0)] * (got.ndim - 1))[self.place]
+
+    def span(self, flat: jax.Array, start, n: int) -> jax.Array:
+        """``(r, n * pages, page, ...)``: chunks ``start .. start + n``
+        (``start`` may be traced, ``n`` is static) of these rows, out of
+        ``flat`` (``KVLayerView.flat``), through the table or out of the slab."""
+        w = self.walk
+        if self.table is not None:
+            return flat[jax.lax.dynamic_slice_in_dim(
+                self.table, start * w.pages, n * w.pages, axis=1)]
+        if jnp.ndim(self.slab) == 0:
+            rows = jax.lax.dynamic_slice_in_dim(flat, self.slab, self.idx.shape[0])
+            return jax.lax.dynamic_slice_in_dim(
+                rows, start * w.chunk, n * w.chunk, axis=1)[:, :, None]
+        rest = flat.shape[2:]
+        return jax.vmap(lambda row: jax.lax.dynamic_slice(
+            flat, (row, start * w.chunk, *(0,) * len(rest)), (1, n * w.chunk, *rest))[0]
+        )(self.slab)[:, :, None]
+
+    def visible(self, start, n: int) -> jax.Array:
+        """``(r, n * chunk)`` bool: key position ``j`` of those chunks is
+        visible to row ``i`` iff ``j <= idx[i]`` (``cached_attention``'s mask
+        for one new token)."""
+        w = self.walk
+        kpos = start * w.chunk + jnp.arange(n * w.chunk, dtype=jnp.int32)
+        return kpos[None, :] <= self.idx[:, None]
 
 
 class LlamaAttention(nn.Module):
@@ -679,47 +806,46 @@ class LlamaAttention(nn.Module):
         return self._o_proj(o, aidx)
 
     def _walk_attention(self, q, kv, walk: KVWalk, table):
-        """One new token a row over the chunks of the cache below ``walk``'s
-        bound (``q`` (b, 1, n, hd) rotated, the cache already holds this
-        step's K/V). Grouped by KV head, K and V read in the cache's dtype,
+        """One new token a row over what ``walk`` reads of the cache: the
+        chunks below its bound, of the rows in its rung (``q`` (b, 1, n, hd)
+        rotated, the cache already holds this step's K/V; ``table`` None for
+        the slab). Grouped by KV head, K and V read in the cache's dtype,
         int8 pages dequantised a chunk at a time with that chunk's scales;
         scores, mask, softmax and accumulation float32, as
         :func:`cached_attention`."""
         cfg = self.config
         b, _, n, hd = q.shape
         n_kv = kv.leaves["cached_key"].shape[-2]
-        first = kv.first_row(b)
+        rows = walk.rows(q, table, kv.first_row(b))
         exact = dict(preferred_element_type=jnp.float32,
                      precision=jax.lax.Precision.HIGHEST)
 
-        def read(name, start, count):
+        def read(top, name, start, count):
             with jax.named_scope("kv_gather"):
-                pages = walk.span(kv.flat(name), table, first, start, count)
+                pages = top.span(kv.flat(name), start, count)
                 if cfg.page_dtype == "int8":
-                    scales = walk.span(kv.flat(name + "_scale"), table, first,
-                                       start, count)
+                    scales = top.span(kv.flat(name + "_scale"), start, count)
                     pages = (pages.astype(jnp.float32) * scales).astype(cfg.dtype)
-                return pages.reshape(b, count * walk.chunk, n_kv, hd)
+                return pages.reshape(-1, count * walk.chunk, n_kv, hd)
 
         if not walk.loops:
-            def branch(count):
-                k_all = read("cached_key", 0, count)
-                v_all = read("cached_value", 0, count)
+            def branch(top, count):
+                k_all = read(top, "cached_key", 0, count)
+                v_all = read(top, "cached_value", 0, count)
                 with jax.named_scope("attend"):
-                    return cached_attention(q, k_all, v_all, walk.idx)
+                    return cached_attention(top.q, k_all, v_all, top.idx)
 
-            return walk.prefix(branch)
+            return walk.prefix(rows, branch)
 
-        qg = q.reshape(b, n_kv, n // n_kv, hd)
-
-        def chunk(c):
-            k_c, v_c = read("cached_key", c, 1), read("cached_value", c, 1)
+        def chunk(top, c):
+            k_c, v_c = read(top, "cached_key", c, 1), read(top, "cached_value", c, 1)
             with jax.named_scope("attend"):
+                qg = top.q.reshape(-1, n_kv, n // n_kv, hd)
                 scores = jnp.einsum("bkgd,bjkd->bkgj", qg, k_c, **exact) * (1.0 / hd ** 0.5)
             return scores, lambda p: jnp.einsum("bkgj,bjkd->bkgd", p, v_c, **exact)
 
         with jax.named_scope("attend"):
-            o = walk.fold((n_kv, n // n_kv), hd, chunk)
+            o = walk.fold(rows, (n_kv, n // n_kv), hd, chunk)
             return o.reshape(b, 1, n, hd).astype(q.dtype)
 
     def _qk_norm(self, name, y, heads, repeat):
@@ -763,9 +889,11 @@ class LlamaAttention(nn.Module):
 
         The new tokens' K/V are written first; what is then read depends on
         the step. One new token a row and no tree (the decode step): chunks
-        of whole pages up to the reach of the longest LIVE row
-        (:class:`KVWalk`, ``_walk_attention``; ``live`` (b, s) is the serving
-        program's, None counts every row), or the paged kernel. A prompt, a
+        of whole pages up to the reach of the longest LIVE row, of the rung
+        of rows that holds the live ones (:class:`KVWalk`,
+        ``_walk_attention``; ``live`` (b, s) is the serving program's, None
+        counts every row; a row that is not live gets zeros where its rung
+        leaves it out), or the paged kernel. A prompt, a
         chunk, a speculative or tree step (``s_new > 1`` or ``chunk_ctx``),
         and any table of a single chunk: all ``max_seq_len`` slots behind the
         mask, as ever."""
@@ -935,10 +1063,9 @@ class LlamaAttention(nn.Module):
                         v_scale=kv.flat("cached_value_scale") if quantized else None)
                 return self._o_proj(o.reshape(b, s_new, -1), aidx)
         if one_token:
-            # the step reads as far as its longest live row reaches; a table
+            # the step reads its live rows as far as the longest reaches; a table
             # of ONE chunk (max_seq_len of 128 or less) is the whole read below
-            walk = KVWalk(cfg.max_seq_len, ps, idx,
-                          None if live is None else live[:, 0])
+            walk = kv_walk(cfg, idx, None if live is None else live[:, 0])
             if walk.n_chunks > 1:
                 o = self._walk_attention(q, kv, walk, table if ps else None)
                 return self._o_proj(o.reshape(b, s_new, -1), aidx)
@@ -1086,8 +1213,8 @@ class _LayerStep(nn.Module):
     leaves)``, which the block's attention updates through a
     :class:`KVLayerView`. ``live`` (b, s) bool, where a serving program gives
     it, says which tokens are real: a block's experts run those alone
-    (``moe/layer.py``) and a one-token step's attention reads the cache as
-    far as the live rows reach (:class:`KVWalk`); ``stack`` is what
+    (``moe/layer.py``) and a one-token step's attention reads the cache of
+    the live rows, as far as they reach (:class:`KVWalk`); ``stack`` is what
     ``LlamaModel.layer_stack`` hands every layer whole, to a block that asked
     for it."""
 

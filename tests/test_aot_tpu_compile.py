@@ -182,14 +182,33 @@ def _leaf_copies(text, shape, dtype="bf16"):
     """``copy`` instructions that produce a whole cache leaf INSIDE a loop
     body or a branch: every computation but the entry one (where the
     compiler may re-lay-out a donated argument once on the way in and once on
-    the way out, as it did before this walk existed: PERF.md section 7)."""
-    leaf = re.compile(r"= " + dtype + r"\[" + ",".join(map(str, shape)) + r"\]\S* copy\(")
+    the way out, as it did before this walk existed: PERF.md section 7). A
+    leaf in any shape: the walk reads it flat, layers x pages as one axis
+    (one switch inside another made every branch copy the latent pool so:
+    PERF.md, PR 40)."""
+    layers, pages, *rest = shape
+    leaf = [[d for d in dims if d != 1] for dims in (shape, (layers * pages, *rest))]
     found, entry = [], False
     for line in text.splitlines():
         if re.match(r"^(ENTRY )?%[\w.]+ \(", line):
             entry = line.startswith("ENTRY")
-        elif not entry and leaf.search(line):
+            continue
+        copied = re.search(r"= " + dtype + r"\[([0-9,]+)\]\S* copy\(", line)
+        if not entry and copied and [d for d in map(int, copied.group(1).split(",")) if d != 1] in leaf:
             found.append(line.strip()[:160])
+    return found
+
+
+def _page_gathers(text, page_shape, dtype="bf16"):
+    """``{(rows, pages): count}`` of the ``gather`` instructions of a compiled
+    module that bring whole pages ``page_shape`` out of a pool: what each
+    branch of the walk reads (a rung of ONE row has no row axis left)."""
+    tail = ",".join(map(str, page_shape))
+    found = {}
+    for lead in re.findall(r"= " + dtype + r"\[([0-9,]*)" + tail + r"\]\S* gather\(", text):
+        dims = [int(d) for d in lead.split(",") if d]
+        key = (1, dims[0]) if len(dims) == 1 else tuple(dims)
+        found[key] = found.get(key, 0) + 1
     return found
 
 
@@ -197,14 +216,18 @@ def _leaf_copies(text, shape, dtype="bf16"):
 def test_gather_decode_step_reads_as_far_as_its_rows_reach(chip, s_max):
     """The same step (GQA 32/8, head 128, bf16 pages of 16, batch 8, two
     scanned layers), told which rows are live as the fused session decode
-    tells it: the cache is read in chunks up to a bound the program computes
-    (``models/llama.py::KVWalk``). At 4 096 slots by a loop: a ``while`` whose
+    tells it: the cache is read in chunks up to a bound the program computes,
+    of the rung of rows (1, 2, 4, 8 by the loop; 2, 8 by the switch) that
+    holds the live ones (``models/llama.py::KVWalk``). At 4 096 slots by a
+    loop: one ``conditional`` over the rungs, in each a ``while`` whose
     condition holds no constant, and no value of the gathered slab's shape
-    ``(8, 4096, 8, 128)`` anywhere. At 1 024 slots by a switch: one
-    ``conditional``, whose widest branch is the old read. Either way no pool
-    leaf is copied into the loop or a branch (a pool-sized copy in the layer
-    body costs milliseconds a step: PR 29 met one under a kernel), and the
-    temporaries stay as small as the whole read's."""
+    ``(8, 4096, 8, 128)`` anywhere. At 1 024 slots by a switch over (prefix,
+    rung): one ``conditional``, whose widest branch is the old read. Either way a
+    branch's gathers bring ITS rung's rows and no more (K and V: two of each
+    shape, and of all 8 rows only in the top rung), no pool leaf is copied
+    into the loop or a branch (a pool-sized copy in the layer body costs
+    milliseconds a step: PR 29 met one under a kernel), and the temporaries
+    stay as small as the whole read's."""
     b, n_kv, page, layers = 8, 8, 16, 2
     cfg = dataclasses.replace(
         LlamaConfig(vocab_size=256, hidden_size=32 * HEAD_DIM,
@@ -230,12 +253,17 @@ def test_gather_decode_step_reads_as_far_as_its_rows_reach(chip, s_max):
     text = compiled.as_text()
     loops, switches = _traced_bounds(text)
     slab = f"[{b},{s_max},{n_kv},{HEAD_DIM}]"
+    chunk_pages = s_max // 8 // page
     if s_max == 4096:
-        assert loops == 1 and switches == 0
+        reads = [(r, 1) for r in (1, 2, 4, 8)]    # a loop a rung; a turn reads one chunk
+        assert loops == len(reads) and switches == 1
         assert slab not in text
     else:
+        reads = [(r, n) for r in (2, 8) for n in range(1, 9)]    # one rung below the top
         assert loops == 0 and switches == 1
         assert slab in text                       # the widest branch: the check can see it
+    assert _page_gathers(text, (page, n_kv, HEAD_DIM)) == {
+        (r, n * chunk_pages): 2 for r, n in reads}
     assert not _leaf_copies(text, (layers, cfg.page_pool_pages, page, n_kv, HEAD_DIM))
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 2 * layers * cfg.page_pool_pages * page * n_kv * HEAD_DIM * 2
@@ -246,8 +274,10 @@ def test_latent_decode_step_reads_as_far_as_its_rows_reach(chip):
     """The benchmark's DeepSeek-V2 rehearsal configuration (bf16, pages of
     16, batch 8, ``max_seq_len`` 4096) for the described v5e: the absorbed
     decode reads the latent cache by a switch over its prefixes (no carried
-    state), in the dense layer's scan and in the expert layers' scan, with no
-    loop of a traced bound and no copy of the latent leaf."""
+    state; chunks of 512 tokens, so no rung of rows but the batch: the
+    parent's bodies and no more), in the dense layer's scan and in the expert
+    layers' scan, with no loop of a traced bound, no copy of the latent leaf,
+    and in every branch a gather of its own rectangle of pages."""
     import json
     from pathlib import Path
 
@@ -280,6 +310,8 @@ def test_latent_decode_step_reads_as_far_as_its_rows_reach(chip):
     text = compiled.as_text()
     assert cfg.dtype == jnp.bfloat16 and cfg.first_k_dense == 1
     assert _traced_bounds(text) == (0, 2)         # one switch a layer scan
+    assert _page_gathers(text, (page, cfg.latent_dim)) == {
+        (8, n * s_max // 8 // page): 2 for n in range(1, 9)}     # two scans; no rung but the batch
     assert not _leaf_copies(text, (cfg.num_layers, cfg.page_pool_pages, page, 1, cfg.latent_dim))
 
 

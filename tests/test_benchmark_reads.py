@@ -183,7 +183,8 @@ INSERT_HOST_OPS = ["insert_program_calls", "insert_host_fetches"]
 ROUTED_READS = ["moe_assignments_routed"]
 # how far the fused decode blocks read the cache (PR 38): in `engine.stats`, so
 # in every record's `engine_stats`; no metric of BENCHMARK.json reads them yet
-WALK_STATS = ["kv_walk_tokens", "kv_walk_steps"]
+# ... and over how many rows (PR 40): the rung of rows that holds the live ones
+WALK_STATS = ["kv_walk_tokens", "kv_walk_steps", "kv_walk_row_slots"]
 TRAIN_READS = ["jit_step_fn"]
 # the engine's own spans of a round (PR 39), as ``benchmark/phase_spans.py`` and
 # the seven readers over it spell them: (name, lane's track) of a complete span
@@ -261,6 +262,8 @@ def test_walk_counter_is_produced(run, moe_run, key):
         assert key in dict(stats.items())
         assert 0 < stats["kv_walk_steps"] <= stats["decode_blocks"] * BLOCK_STEPS
         assert stats["kv_walk_tokens"] == stats["kv_walk_steps"] * r.lm.config.max_seq_len
+        # ... of every row: a table of one chunk has one rung
+        assert stats["kv_walk_row_slots"] == stats["kv_walk_tokens"] * r.lm.max_batch
 
 
 @pytest.mark.parametrize("async_loop", [False, True], ids=["sync", "async"])
@@ -286,7 +289,7 @@ def test_walk_counters_equal_a_python_model_of_the_same_lengths(async_loop):
         pass
     assert [len(c.tokens) for c in sorted(engine.completed, key=lambda c: c.request_id)] \
         == list(budgets)
-    tokens = steps = 0
+    tokens = steps = row_slots = 0
     for block in range(max(budgets)):
         # a request holds 1 + block * K tokens when the block starts (the insert gave one)
         live = [n for n, budget in zip(prompts, budgets) if 1 + block * BLOCK_STEPS < budget]
@@ -294,7 +297,33 @@ def test_walk_counters_equal_a_python_model_of_the_same_lengths(async_loop):
             reach = max(live) + block * BLOCK_STEPS + step + 1
             tokens += -(-reach // 128) * 128
             steps += 1
+            # of the rows of the smallest rung (1 or 4 of 4 slots: chunks of 128 tokens
+            # go by the switch, one rung below the top) that holds the live ones
+            row_slots += next(r for r in (1, 4) if r >= len(live)) * -(-reach // 128) * 128
     assert (engine.stats["kv_walk_tokens"], engine.stats["kv_walk_steps"]) == (tokens, steps)
+    assert engine.stats["kv_walk_row_slots"] == row_slots
+    assert tokens < row_slots < tokens * lm.max_batch      # three live at first, one at last
+
+
+@pytest.mark.parametrize("async_loop", [False, True], ids=["sync", "async"])
+def test_walk_row_slots_are_the_whole_rectangle_when_every_row_is_live(async_loop):
+    """Two slots, two requests of one budget admitted together: every step
+    with a live row has both live, the top rung, the parent's read."""
+    cfg = LlamaConfig(**dict(TINY, max_seq_len=512))
+    params = meta.unbox(LlamaForCausalLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    lm = CausalLM(cfg, params, LlamaForCausalLM, buckets=(128,), max_batch=2, page_size=16)
+    engine = ServeEngine(lm, block_steps=BLOCK_STEPS, rng=jax.random.key(0),
+                         async_loop=async_loop)
+    rng = np.random.RandomState(3)
+    for n in (120, 40):
+        engine.submit(rng.randint(1, 128, (n,)).astype(np.int32), max_new_tokens=13,
+                      arrival_block=0)
+    while engine.step_block():
+        pass
+    stats = engine.stats
+    assert stats["kv_walk_steps"] > 0
+    assert stats["kv_walk_row_slots"] == stats["kv_walk_tokens"] * lm.max_batch
 
 
 def _complete_spans(r, name):

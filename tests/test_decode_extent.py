@@ -1,5 +1,5 @@
 """A one-token decode step reads the cache as far as its longest LIVE row
-reaches (ISSUE 38, ``models/llama.py::KVWalk``).
+reaches (ISSUE 38) and of its live rows only (ISSUE 40): ``models/llama.py::KVWalk``.
 
 The oracle is kept HERE: gather all of ``max_seq_len`` through the block table
 and attend over it behind the mask (``cached_attention``; for the latent
@@ -17,6 +17,14 @@ The one bug the change can have is a bound too SHORT for a live row, and the
 control plants it: the same comparison with the bound one chunk short must
 fail wherever a step reads more than one chunk (at ``chunk - 1`` one chunk is
 all the right bound reads, and the least any step reads).
+
+The ROW bound (the second half of the file) has two more: a rung of rows one
+row short of the live ones, and rows handed back in the walk's order instead
+of the step's. Both are planted too. Beside the oracle stands the parent's
+read ("every_row": every row of the batch inside the bound, no order, one
+rung), and what a live row computes and writes must EQUAL it, bit for bit:
+the rows of a batch do not meet in the attention, and a chunk a row is not
+read at held no visible key for it.
 """
 
 import contextlib
@@ -123,14 +131,28 @@ def whole_latent(self, q_all, kv, walk, table):
 @contextlib.contextmanager
 def reads(how):
     """Programs built inside read the cache ``how``: "bounded" (the program's
-    own), "whole" (the oracle) or "short" (the planted bug: one chunk less)."""
+    own), "whole" (the oracle), "short" (the planted bug: one chunk less),
+    "every_row" (the parent's read: no row bound), "rung_short" (planted: the
+    rung chosen for one row fewer) or "sorted" (planted: rows put back by the
+    order, the sort once more, and not by their places)."""
     with pytest.MonkeyPatch.context() as patch:
+        init = KVWalk.__init__
+        if how == "every_row":
+            def every_row(self, *args, **kwargs):
+                init(self, *args, **kwargs)
+                self.rungs = (self.idx.shape[0],)
+
+            patch.setattr(KVWalk, "__init__", every_row)
+        elif how == "rung_short":
+            rung = KVWalk.rung
+            patch.setattr(KVWalk, "rung", lambda self, rows: rung(self, rows - 1))
+        elif how == "sorted":
+            sorted_rows = KVWalk.sorted_rows
+            patch.setattr(KVWalk, "sorted_rows", lambda self: (sorted_rows(self)[0],) * 2)
         if how == "whole":
             patch.setattr(LlamaAttention, "_walk_attention", whole_gqa)
             patch.setattr(DeepseekV2Attention, "_walk_attention", whole_latent)
         elif how == "short":
-            init = KVWalk.__init__
-
             def short(self, *args, **kwargs):
                 init(self, *args, **kwargs)
                 self.turns = self.turns - 1
@@ -144,20 +166,20 @@ def reads(how):
 _BUILT = {}
 
 
-def build(case, how, fused=False):
-    """``(lm, decode or fused program)`` of ``case`` reading the cache ``how``,
-    built once a module; the world (mesh) is the case's, made anew for the
-    test (``conftest.py`` takes it down after each)."""
+def build(case, how, fused=False, rows=B):
+    """``(lm, decode or fused program)`` of ``case`` with ``rows`` slots reading
+    the cache ``how``, built once a module; the world (mesh) is the case's,
+    made anew for the test (``conftest.py`` takes it down after each)."""
     kind, over, lm_kw, tp, seq = CASES[case]
     psm.destroy_model_parallel()
     psm.initialize_model_parallel(tensor_model_parallel_size=tp,
                                   devices=jax.devices()[:tp])
-    if (case, how, fused) not in _BUILT:
-        _BUILT[case, how, fused] = _build(case, how, fused)
-    return _BUILT[case, how, fused]
+    if (case, how, fused, rows) not in _BUILT:
+        _BUILT[case, how, fused, rows] = _build(case, how, fused, rows)
+    return _BUILT[case, how, fused, rows]
 
 
-def _build(case, how, fused):
+def _build(case, how, fused, rows):
     kind, over, lm_kw, tp, seq = CASES[case]
     if kind == "latent":
         cfg, cls = DeepseekV2Config(**{**LATENT, **over, "max_seq_len": seq}), DeepseekV2ForCausalLM
@@ -166,9 +188,9 @@ def _build(case, how, fused):
     nxd = neuronx_distributed_config(tensor_parallel_size=tp)
     params = initialize_parallel_model(nxd, lambda: cls(cfg), jnp.zeros((1, 8), jnp.int32)).params
     page = lm_kw.get("page_size", PAGE)
-    pages = dict(page_size=page, page_pool_pages=B * seq // page + 1) if page else {}
+    pages = dict(page_size=page, page_pool_pages=rows * seq // page + 1) if page else {}
     kw = {k: v for k, v in lm_kw.items() if k != "page_size"}
-    lm = CausalLM(cfg, params, cls, buckets=(16,), max_batch=B, **pages, **kw)
+    lm = CausalLM(cfg, params, cls, buckets=(16,), max_batch=rows, **pages, **kw)
     with reads(how):
         program = (lm.compile_session_decode_fused(K, SlotSampler(), 0) if fused
                    else lm.compile()._decode)
@@ -182,7 +204,8 @@ def state(lm, lengths, mapped=None, seed=0):
     rng = np.random.RandomState(seed)
     cfg = lm.config
     session = lm.start_session()
-    mapped = np.ones((B,), bool) if mapped is None else np.asarray(mapped)
+    rows = lm.max_batch
+    mapped = np.ones((rows,), bool) if mapped is None else np.asarray(mapped)
 
     def fill(path, leaf):
         name = jax.tree_util.keystr(path)
@@ -199,7 +222,7 @@ def state(lm, lengths, mapped=None, seed=0):
     cache = jax.tree_util.tree_map_with_path(fill, session.cache)
     if lm.paged:
         per_row = cfg.max_seq_len // cfg.page_size
-        tables = 1 + np.arange(B)[:, None] * per_row + np.arange(per_row)[None, :]
+        tables = 1 + np.arange(rows)[:, None] * per_row + np.arange(per_row)[None, :]
         cache = causal_lm._set_block_tables(cache, np.where(mapped[:, None], tables, 0))
     return causal_lm._set_cache_index(cache, jnp.asarray(lengths, jnp.int32))
 
@@ -231,11 +254,12 @@ def close(got, want, dtype):
 
 def run_block(lm, fused, lengths, active, done, mapped=None):
     """One fused block of ``K`` greedy steps from the hand-made state."""
-    outs = fused(lm.params, state(lm, lengths, mapped), jnp.ones((B, 1), jnp.int32),
-                 jax.random.split(jax.random.key(1), B), jnp.zeros((B,), jnp.int32),
+    rows = lm.max_batch
+    outs = fused(lm.params, state(lm, lengths, mapped), jnp.ones((rows, 1), jnp.int32),
+                 jax.random.split(jax.random.key(1), rows), jnp.zeros((rows,), jnp.int32),
                  jnp.asarray(lengths, jnp.int32), jnp.asarray(active), jnp.asarray(done),
-                 jnp.full((B,), -1, jnp.int32), jnp.ones((B,), jnp.float32),
-                 jnp.ones((B,), bool))
+                 jnp.full((rows,), -1, jnp.int32), jnp.ones((rows,), jnp.float32),
+                 jnp.ones((rows,), bool))
     pools = {jax.tree_util.keystr(p): np.asarray(leaf, np.float32) for p, leaf in
              jax.tree_util.tree_flatten_with_path(outs[1])[0]
              if "cached_" in jax.tree_util.keystr(p)}
@@ -247,6 +271,49 @@ def walked(lm, reaches):
     reaches ``r`` slots reads whole chunks up to it."""
     chunk = chunk_of(lm)
     return sum(-(-min(r, lm.config.max_seq_len) // chunk) * chunk for r in reaches)
+
+
+def ladder(rows, loops, chunk=128):
+    """The rungs of ``rows`` slots: the powers of two below them where the
+    step reads by the loop; by the switch one rung of a quarter of them, and
+    none where its chunks are long; then the rows themselves."""
+    if loops:
+        below = [r for r in (1, 2, 4, 8, 16, 32) if r < rows]
+    else:
+        below = [max(rows // 4, 1)] if chunk < 512 else []
+    return [r for r in below if r < rows] + [rows]
+
+
+def loops(lm):
+    """By the loop: a GQA cache whose chunks hold 512 tokens or more."""
+    return chunk_of(lm) >= 512 and not isinstance(lm.config, DeepseekV2Config)
+
+
+def live_steps(lm, lengths, active, done):
+    """The block's bookkeeping in Python: the reaches of the rows live at each
+    of its ``K`` steps (a row is done once its next token would not fit)."""
+    lengths, done, seq = np.array(lengths), np.array(done), lm.config.max_seq_len
+    steps = []
+    for _ in range(K):
+        steps.append((lengths[np.asarray(active) & ~done] + 1).tolist())
+        lengths = lengths + 1
+        done = done | (np.asarray(active) & (lengths + 1 >= seq))
+    return steps
+
+
+def sums(lm, steps):
+    """Python model of the three sums a block returns (``_walk_sums``) from
+    the live rows' reaches step by step: slots read of the longest row, steps
+    with a live row, slots read over the rows of the rung that holds them."""
+    chunk, seq = chunk_of(lm), lm.config.max_seq_len
+    rungs = ladder(lm.max_batch, loops(lm), chunk)
+    tokens = count = row_slots = 0
+    for reaches in (r for r in steps if r):
+        reaches = [min(r, seq) for r in reaches]
+        turns = -(-max(reaches) // chunk)
+        tokens, count = tokens + turns * chunk, count + 1
+        row_slots += next(r for r in rungs if r >= len(reaches)) * turns * chunk
+    return [tokens, count, row_slots]
 
 
 # ----------------------------------------------------------------- tests
@@ -317,7 +384,8 @@ def test_only_live_rows_set_the_bound(case, beside):
             else:
                 assert close(mine, theirs, lm.config.dtype), name
     longest = (seq - 60 if beside == "every_row" else 200)
-    assert got[2].tolist() == [walked(lm, [longest + 1 + i for i in range(K)]), K]
+    assert got[2].tolist() == sums(lm, live_steps(lm, lengths, active, done))
+    assert got[2][:2].tolist() == [walked(lm, [longest + 1 + i for i in range(K)]), K]
     assert want[2].tolist() == got[2].tolist()      # the counter is the bound's, not the read's
 
 
@@ -331,18 +399,240 @@ def test_a_row_crosses_a_chunk_edge_inside_a_block(case):
     lm, whole = build(case, "whole", fused=True)
     want = run_block(lm, whole, lengths, active, done)
     assert (got[0][:, :2] == want[0][:, :2]).all()
-    for name, pool in got[1].items():
-        assert close(pool, want[1][name], lm.config.dtype), name
+    for row in (0, 1):      # what the two live rows wrote (a row that is not live computes zeros)
+        mine, theirs = pages_of(lm, got[1], row), pages_of(lm, want[1], row)
+        for name in mine:
+            assert close(mine[name], theirs[name], lm.config.dtype), name
     # reaches chunk - 1, chunk (one chunk each), chunk + 1, chunk + 2 (two)
-    assert got[2].tolist() == [6 * chunk, K] == [walked(lm, [chunk - 1 + i for i in range(K)]), K]
+    assert got[2][:2].tolist() == [6 * chunk, K] == [walked(lm, [chunk - 1 + i for i in range(K)]), K]
+    # two live rows of four over the same chunks: the loop's rung of 2, the switch's top rung
+    assert got[2].tolist() == sums(lm, live_steps(lm, lengths, active, done))
+    assert got[2][2] == (2 if loops(lm) else B) * got[2][0]
 
 
 def test_no_live_row_reads_one_chunk_counts_nothing_and_stays_finite():
     lm, bounded = build("gqa-f32-loop", "bounded", fused=True)
     lengths = np.asarray([900, 700, 5, 9], np.int32)
-    toks, pools, sums = run_block(lm, bounded, lengths, np.zeros((B,), bool), np.zeros((B,), bool))
-    assert sums.tolist() == [0, 0] and (toks == 0).all()
+    toks, pools, read = run_block(lm, bounded, lengths, np.zeros((B,), bool), np.zeros((B,), bool))
+    assert read.tolist() == [0, 0, 0] and (toks == 0).all()
     assert all(np.isfinite(p).all() for p in pools.values())
+
+
+# ------------------------------------------------- the row bound (ISSUE 40)
+
+ROWS = 8
+# who is live, by name: the first n of this order for "live_n"
+SPREAD = [6, 3, 0, 7, 1, 4, 2, 5]
+PATTERNS = ["interleaved", "live_1", "live_2", "live_3", "live_4", "live_5", "live_8",
+            "equal_reach", "done_longer", "retired_scratch", "edge_and_finish"]
+# every form, head layout, page dtype and world once; the whole matrix runs two patterns
+ROW_CASES = ["gqa-f32-loop", "gqa-f32-switch", "mha_qknorm-f32-switch", "mqa-int8-switch",
+             "gqa-slab-loop", "gqa-f32-loop-tp2", "latent-f32"]
+
+
+def pattern(lm, name):
+    """``(lengths, active, done, mapped)`` of eight rows whose lengths lie in
+    four chunks of the table, none in row order."""
+    c, seq = chunk_of(lm), lm.config.max_seq_len
+    lengths = np.asarray([c + 40, 3 * c + 17, 70, 2 * c + 5, 3 * c + 90, 9, c - 1, 2 * c + c // 2],
+                         np.int32)
+    active, done, mapped = np.zeros((ROWS,), bool), np.zeros((ROWS,), bool), np.ones((ROWS,), bool)
+    if name == "interleaved":
+        active[[1, 4]] = True
+    elif name.startswith("live_"):
+        active[SPREAD[:int(name[5:])]] = True
+    elif name == "equal_reach":           # rows 3 and 7 reach as far; 0 is shorter
+        lengths[7] = lengths[3]
+        active[[0, 3, 7]] = True
+    elif name == "done_longer":           # the longest row is done: nobody reads it
+        active[[0, 3, 4]] = True
+        done[4] = True
+    elif name == "retired_scratch":       # a stale long index over a table of scratch
+        lengths[1], mapped[1] = seq - 60, False
+        active[[2, 6, 7]] = True
+    elif name == "edge_and_finish":       # row 0 crosses a chunk edge, row 1 fills its table
+        lengths[0], lengths[1] = c - 2, seq - 3
+        active[[0, 1, 5]] = True
+    return lengths, active, done, mapped
+
+
+def through(lm, lengths, active, done):
+    """Rows live at every step of the block."""
+    return np.asarray(active) & ~np.asarray(done) & (np.asarray(lengths) + K + 1 < lm.config.max_seq_len)
+
+
+def pages_of(lm, pools, row):
+    """What ``row`` holds of every paged pool leaf (scales apart)."""
+    per_row = lm.config.max_seq_len // PAGE
+    first = 1 + row * per_row
+    return {name: pool[:, first:first + per_row] for name, pool in pools.items()
+            if not name.endswith("_scale']")}
+
+
+def compare_rows(case, name, against):
+    """One block of ``name`` by the program's own read and by ``against``:
+    the streams of the rows live at the start, the pages of the rows live
+    throughout, and the program's sums against the Python model."""
+    lm, bounded = build(case, "bounded", fused=True, rows=ROWS)
+    spec = pattern(lm, name)
+    got = run_block(lm, bounded, *spec)
+    lm, other = build(case, against, fused=True, rows=ROWS)
+    want = run_block(lm, other, *spec)
+    lengths, active, done, _ = spec
+    live = active & ~done
+    assert np.isfinite(got[0]).all() and all(np.isfinite(p).all() for p in got[1].values())
+    assert (got[0][:, live] == want[0][:, live]).all()
+    for row in np.nonzero(through(lm, lengths, active, done))[0] if lm.paged else []:
+        mine, theirs = pages_of(lm, got[1], row), pages_of(lm, want[1], row)
+        for leaf in mine:
+            if against == "every_row":
+                assert np.array_equal(mine[leaf], theirs[leaf]), (leaf, row)
+            elif lm.config.page_dtype == "int8":
+                assert np.abs(mine[leaf] - theirs[leaf]).max() <= 1, (leaf, row)
+            else:
+                assert close(mine[leaf], theirs[leaf], lm.config.dtype), (leaf, row)
+    assert got[2].tolist() == sums(lm, live_steps(lm, lengths, active, done))
+    return lm, got, want
+
+
+@pytest.mark.parametrize("name", ["interleaved", "live_5"])
+@pytest.mark.parametrize("case", list(CASES))
+def test_live_rows_among_dead_ones_give_the_whole_reads_block(case, name):
+    """Two live rows between six that are not, and five of eight (the top
+    rung): every form, head layout, page dtype and world against the oracle."""
+    compare_rows(case, name, "whole")
+
+
+@pytest.mark.parametrize("name", PATTERNS)
+@pytest.mark.parametrize("case", ROW_CASES)
+def test_a_live_row_computes_what_the_parents_read_gave_it(case, name):
+    """Against the parent's read (every row inside the bound, in place): each
+    rung's edge (1, 2, 3, 4, 5, 8 live), two rows of one reach, a done row
+    longer than every live one, a retired slot over scratch, a row crossing a
+    chunk edge beside one that finishes inside the block. Bit for bit."""
+    lm, got, want = compare_rows(case, name, "every_row")
+    if name == "live_8":       # the top rung reads what the parent read
+        assert got[2][2] == got[2][0] * ROWS
+    if not lm.paged:           # the slab has no pages to tell rows by: the whole leaves
+        rows = through(lm, *pattern(lm, name)[:3])
+        for leaf, pool in got[1].items():
+            layers = pool.shape[0] // ROWS
+            for row in np.nonzero(rows)[0]:
+                assert np.array_equal(pool[row::ROWS][:layers], want[1][leaf][row::ROWS][:layers]), leaf
+
+
+@pytest.mark.parametrize("name", ["interleaved", "live_5", "edge_and_finish"])
+@pytest.mark.parametrize("case", ["gqa-f32-loop", "gqa-f32-switch", "latent-f32"])
+def test_the_oracle_agrees_with_the_parents_read(case, name):
+    """The two references against each other, so that neither test above
+    passes by sharing a fault with its reference."""
+    lm, every_row = build(case, "every_row", fused=True, rows=ROWS)
+    spec = pattern(lm, name)
+    got = run_block(lm, every_row, *spec)
+    lm, whole = build(case, "whole", fused=True, rows=ROWS)
+    want = run_block(lm, whole, *spec)
+    live = spec[1] & ~spec[2]
+    assert (got[0][:, live] == want[0][:, live]).all()
+    for row in np.nonzero(through(lm, *spec[:3]))[0]:
+        mine, theirs = pages_of(lm, got[1], row), pages_of(lm, want[1], row)
+        assert all(close(mine[leaf], theirs[leaf], lm.config.dtype) for leaf in mine)
+
+
+@pytest.mark.parametrize("planted,name", [
+    ("rung_short", "live_3"), ("rung_short", "live_5"), ("rung_short", "equal_reach"),
+    ("rung_short", "retired_scratch"), ("sorted", "interleaved"), ("sorted", "live_3"),
+    ("sorted", "equal_reach")])
+@pytest.mark.parametrize("case", ["gqa-f32-loop", "gqa-f32-switch", "latent-f32"])
+def test_a_rung_one_row_short_and_an_unsort_that_sorts_are_caught(case, planted, name):
+    """The controls: a ladder that holds one row fewer than are live leaves a
+    live row unread just past a rung's edge (three live: the rung of 2; five:
+    the loop's rung of 4, while the switch has no rung between 2 and 8 to
+    take by mistake, and must come out right); rows handed back in the
+    walk's order give a live row another row's attention (two live: the
+    switch's rung too)."""
+    lm, wrong = build(case, planted, fused=True, rows=ROWS)
+    spec = pattern(lm, name)
+    got = run_block(lm, wrong, *spec)
+    lm, whole = build(case, "whole", fused=True, rows=ROWS)
+    want = run_block(lm, whole, *spec)
+    rows = np.nonzero(through(lm, *spec[:3]))[0]
+    same = [close(mine, theirs, lm.config.dtype)
+            for row in rows
+            for mine, theirs in zip(pages_of(lm, got[1], row).values(),
+                                    pages_of(lm, want[1], row).values())]
+    live = int((spec[1] & ~spec[2]).sum())
+    rungs = ladder(ROWS, loops(lm), chunk_of(lm))
+    took = {"rung_short": next(r for r in rungs if r >= live - 1)}.get(planted, 0)
+    harmless = took >= live or (planted == "sorted" and rungs[-2] < live)
+    assert all(same) == harmless
+
+
+@pytest.mark.parametrize("case", ["gqa-f32-loop", "gqa-bf16-switch", "mqa-int8-switch",
+                                  "gqa-slab-loop", "latent-f32", "gqa-f32-loop-tp2"])
+def test_no_row_live_of_eight_reads_one_row_once_and_counts_nothing(case):
+    lm, bounded = build(case, "bounded", fused=True, rows=ROWS)
+    lengths = pattern(lm, "interleaved")[0]
+    toks, pools, read = run_block(lm, bounded, lengths, np.zeros((ROWS,), bool),
+                                  np.zeros((ROWS,), bool))
+    assert read.tolist() == [0, 0, 0] and (toks == 0).all()
+    assert all(np.isfinite(p).all() for p in pools.values())
+
+
+@pytest.mark.parametrize("rows,want,thin", [
+    (1, (1,), (1,)), (2, (1, 2), (1, 2)), (3, (1, 2, 3), (1, 3)), (4, (1, 2, 4), (1, 4)),
+    (6, (1, 2, 4, 6), (1, 6)), (8, (1, 2, 4, 8), (2, 8)), (16, (1, 2, 4, 8, 16), (4, 16))])
+def test_the_ladder_follows_the_form(rows, want, thin):
+    """The loop (chunks of 512 tokens or more, unless the caller says its
+    cache cannot loop) holds a loop a rung: the powers of two up to the
+    batch. The switch holds a body a (prefix, rung): a quarter of the batch,
+    and the batch; over chunks as long as the loop's, the batch alone."""
+    idx, live = jnp.zeros((rows,), jnp.int32), jnp.ones((rows,), bool)
+    walk = KVWalk(4096, PAGE, idx, live)
+    assert walk.loops and walk.rungs == want == tuple(ladder(rows, True))
+    short = KVWalk(512, PAGE, idx, live)
+    assert not short.loops and short.rungs == thin == tuple(ladder(rows, False))
+    latent = KVWalk(4096, PAGE, idx, live, loops=False)
+    assert not latent.loops and latent.rungs == (rows,) == tuple(ladder(rows, False, 512))
+    assert KVWalk(512, PAGE, idx, live, loops=False).rungs == thin
+    for walk in (walk, short, latent):
+        for need in range(rows + 1):
+            assert walk.rungs[int(walk.rung(need))] == next(r for r in walk.rungs if r >= need)
+    # without ``live``, and over a table of one chunk: one rung
+    assert KVWalk(512, PAGE, idx).rungs == KVWalk(4096, PAGE, idx).rungs == (rows,)
+    assert KVWalk(128, PAGE, idx, live).rungs == (rows,)
+    # the configuration's word is the walk's: the program's and the counter's
+    for seq, rungs in ((4096, (rows,)), (512, thin)):
+        cfg = DeepseekV2Config(**{**LATENT, "max_seq_len": seq, "page_size": PAGE})
+        assert not llama.kv_walk(cfg, idx, live).loops
+        assert llama.kv_walk(cfg, idx, live).rungs == rungs
+    assert llama.kv_walk(LlamaConfig(**{**TINY, "max_seq_len": 4096, "page_size": PAGE}),
+                         idx, live).rungs == want
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_the_order_is_a_stable_sort_by_reach_longest_first(seed):
+    rng = np.random.RandomState(seed)
+    idx = rng.choice([3, 130, 130, 700, 2000, 4090, 5000], ROWS)     # ties, and one past the end
+    live = rng.rand(ROWS) < 0.6
+    walk = KVWalk(4096, PAGE, jnp.asarray(idx, jnp.int32), jnp.asarray(live))
+    reach = np.where(live, np.minimum(idx + 1, 4096), 0)
+    order = np.argsort(-reach, kind="stable")
+    assert np.asarray(walk.sorted_rows()[0]).tolist() == order.tolist()
+    assert np.asarray(walk.sorted_rows()[1]).tolist() == np.argsort(order).tolist()
+    x = jnp.arange(ROWS * 3).reshape(ROWS, 3)
+    rows = walk.rows(x, jnp.zeros((ROWS, 4096 // PAGE), jnp.int32), 0)
+    for r in walk.rungs[:-1]:           # what top(r) picks, back() returns to its rows
+        top = rows.top(r)
+        assert np.asarray(top.q).tolist() == np.asarray(x)[order[:r]].tolist()
+        assert np.asarray(top.idx).tolist() == idx[order[:r]].tolist()
+        put = np.asarray(top.back(top.q))
+        assert np.array_equal(put[order[:r]], np.asarray(x)[order[:r]])
+        assert (put[order[r:]] == 0).all()
+    assert rows.top(ROWS) is rows and rows.back(x) is x
+    assert int(walk.live_rows) == live.sum()
+    assert (reach[order[:live.sum()]] > 0).all()         # the live rows are a prefix of the order
+    assert int(walk.row_slots) == ladder(ROWS, True)[int(walk.rung(live.sum()))] * int(walk.tokens)
+    assert int(walk.row_slots) <= ROWS * int(walk.tokens)
 
 
 @pytest.mark.parametrize("seq,page,want", [
@@ -351,19 +641,26 @@ def test_no_live_row_reads_one_chunk_counts_nothing_and_stays_finite():
     (1536, 16, (192, 8)), (32768, 16, (4096, 8))])
 def test_the_chunk_rule(seq, page, want):
     """An eighth of the table, not under 128 tokens, whole pages, a divisor."""
+    pages, chunk, n_chunks = KVWalk.cut(seq, page)
+    assert (chunk, n_chunks) == want and pages * (page or 1) == chunk
+    assert chunk * n_chunks == seq and chunk % (page or 1) == 0
     walk = KVWalk(seq, page, jnp.zeros((2,), jnp.int32))
-    assert (walk.chunk, walk.n_chunks) == want
-    assert walk.chunk * walk.n_chunks == seq and walk.chunk % (page or 1) == 0
-    assert walk.loops == (walk.chunk >= 512)
+    assert (walk.pages, walk.chunk, walk.n_chunks) == (pages, chunk, n_chunks)
+    assert walk.loops == (chunk >= 512)
+    assert not KVWalk(seq, page, jnp.zeros((2,), jnp.int32), loops=False).loops
 
 
 def test_a_model_called_without_live_counts_every_row():
     """``live=None`` (plain ``generate``, the stand-alone step): too wide at
-    worst. The stand-alone step of a state whose longest row is retired reads
-    as far as that row."""
+    worst, and every row of the batch. The stand-alone step of a state whose
+    longest row is retired reads as far as that row."""
     idx = jnp.asarray([10, 3000, 7])
-    assert int(KVWalk(4096, 16, idx).turns) == 6
-    assert int(KVWalk(4096, 16, idx, jnp.asarray([True, False, True])).turns) == 1
+    every = KVWalk(4096, 16, idx)
+    assert int(every.turns) == 6 and every.rungs == (3,)
+    assert int(every.row_slots) == 3 * int(every.tokens) == 3 * 3072
+    some = KVWalk(4096, 16, idx, jnp.asarray([True, False, True]))
+    assert int(some.turns) == 1 and some.rungs == (1, 2, 3)
+    assert int(some.live_rows) == 2 and int(some.row_slots) == 2 * 512
     assert int(KVWalk(4096, 16, idx, jnp.asarray([[True], [True], [False]])).tokens) == 3072
     assert int(KVWalk(4096, 16, jnp.asarray([5000, 1])).turns) == 8       # a stale index past the end
 
@@ -390,11 +687,17 @@ def test_the_fused_block_and_generate_agree_where_every_row_counts():
     # 15 decode steps from 120 tokens: reaches 121 .. 135, the edge at 128
     assert engine.stats["kv_walk_steps"] == 16
     assert engine.stats["kv_walk_tokens"] == walked(paged, [121 + i for i in range(16)])
+    # one live row of two: the rung of 1 (a quarter of two rows is one), so a
+    # row's slots and no more
+    assert engine.stats["kv_walk_row_slots"] == engine.stats["kv_walk_tokens"]
 
 
 def test_dataclass_configs_gain_no_field():
     """No new option: the walk is derived from ``max_seq_len`` and
-    ``page_size`` alone."""
-    names = {f.name for f in dataclasses.fields(LlamaConfig)}
-    assert not {n for n in names if "walk" in n or "chunk_tokens" in n or "extent" in n}
+    ``page_size`` alone (and what a configuration's cache IS:
+    ``DeepseekV2Config.kv_walk_loops`` is a property, not a field)."""
+    for config in (LlamaConfig, DeepseekV2Config):
+        names = {f.name for f in dataclasses.fields(config)}
+        assert not {n for n in names if "walk" in n or "chunk_tokens" in n or "extent" in n
+                    or "rung" in n or "ladder" in n}
     assert not hasattr(llama, "_WALK_FORM")

@@ -222,6 +222,8 @@ def test_fused_decode_counts_what_the_router_chose(params):
     assert lm.moe_stats
     assert len(jax.tree.leaves(lm.compile_session_decode_fused(4).out_info)) == len(
         jax.tree.leaves(lm._cache_avals())) + 6     # rows, how far it read, what it routed
+    # ... how far, in how many steps, of how many rows; then the three routing sums
+    assert [leaf.shape for leaf in lm.compile_session_decode_fused(4).out_info[-2:]] == [(3,), (3,)]
     got = run_engine(lm)
     steps, rows = 8, 3
     assert got["moe_layer_steps"] == cfg.num_layers * steps
@@ -244,6 +246,7 @@ def test_a_dense_model_counts_nothing_and_returns_what_it_did():
     assert not lm.moe_stats
     assert len(jax.tree.leaves(lm.compile_session_decode_fused(4).out_info)) == len(
         jax.tree.leaves(lm._cache_avals())) + 5     # rows, how far it read
+    assert lm.compile_session_decode_fused(4).out_info[-1].shape == (3,)   # ... and of how many rows
     assert "moe_stats" not in lm.compile_session_decode_fused(4).as_text()
     assert run_engine(lm) == {"moe_experts_touched": 0, "moe_assignments": 0,
                               "moe_layer_steps": 0}

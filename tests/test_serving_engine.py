@@ -240,7 +240,7 @@ def test_session_fused_overflow_guard_freezes_not_wraps(lm):
     lm.insert(session, [0, 1, 2], p)
     # slot 0 reports 2 tokens of room; slot 1 has plenty; slot 2 inactive
     lengths = np.asarray([max_len - 2, 8, 8], np.int32)
-    toks, cache, tok, out_len, done, _walked = fused(
+    toks, cache, tok, out_len, done, walked = fused(
         lm.params, session.cache, jnp.zeros((3, 1), jnp.int32),
         jax.random.split(jax.random.key(0), 3), jnp.ones((3,), jnp.int32),
         jnp.asarray(lengths),
@@ -248,6 +248,9 @@ def test_session_fused_overflow_guard_freezes_not_wraps(lm):
         jnp.full((3,), -1, jnp.int32), jnp.zeros((3,), np.float32),
         jnp.ones((3,), bool))
     toks, done = np.asarray(toks), np.asarray(done)
+    # how far the block read, in how many steps, over how many rows: one chunk (64
+    # slots) of every row at each of the K steps, two of the three rows being live
+    assert np.asarray(walked).tolist() == [K * max_len, K, K * max_len * 3]
     assert done[0] and not done[1]
     assert (toks[1:, 0] == 0).all(), "frozen slot must emit pad"
     assert (toks[:, 1] != 0).all(), "healthy slot keeps emitting"

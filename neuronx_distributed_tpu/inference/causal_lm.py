@@ -38,6 +38,7 @@ from neuronx_distributed_tpu.inference.partition import (
 )
 from neuronx_distributed_tpu.inference.sampling import Sampler, SlotSampler
 from neuronx_distributed_tpu.models.llama import kv_walk
+from neuronx_distributed_tpu.moe.expert_mlps import grouped_rows_multiplied
 
 PyTree = Any
 
@@ -130,27 +131,34 @@ def _scatter_cache_rows(old: PyTree, fresh: PyTree, slots: jax.Array,
     return jax.tree_util.tree_map_with_path(upd, old, fresh)
 
 
-def _routing_sums(stats: PyTree, live: jax.Array) -> jax.Array:
-    """``(3,) int32`` of one model call: expert slots touched by a real token
-    (summed over layers), assignments of real tokens, layers run with a real
-    token in them — from the ``(layers, tokens, experts)`` choice masks ``MoE``
-    sows and ``live``, which tokens are real (a decode step's live rows, an
-    insert's prompt positions), in any shape of ``tokens`` elements. The
-    experts are those HELD and the layers those with experts. Where the
-    layer holds a share of a wider router's experts (``moe/layer.py``) a
-    fourth sum follows: every pick of the real tokens, absent experts'
-    included (``(layers, tokens)`` counts sown as ``routed``)."""
+def _chosen(stats: PyTree, live: jax.Array) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """The ``(layers, tokens, experts held)`` choice masks ``MoE`` sows, those
+    of the tokens ``live`` calls real (any shape of ``tokens`` elements), and
+    where the layer holds a share of a wider router's experts
+    (``moe/layer.py``) the ``(layers, tokens)`` counts of ALL a token's picks
+    (sown as ``routed``; None elsewhere). The layers are those with experts."""
     by_name: Dict[str, list] = {"chosen": [], "routed": []}
     for path, leaf in jax.tree_util.tree_flatten_with_path(stats)[0]:
         name = next(k for k in by_name if f"['{k}']" in jax.tree_util.keystr(path))
         by_name[name].append(leaf)
     chosen = jnp.concatenate([c.reshape(-1, *c.shape[-2:])
                               for c in by_name["chosen"]])
-    chosen = chosen & live.reshape(-1)[None, :, None]
+    routed = (jnp.concatenate([r.reshape(-1, r.shape[-1]) for r in by_name["routed"]])
+              if by_name["routed"] else None)
+    return chosen & live.reshape(-1)[None, :, None], routed
+
+
+def _routing_sums(chosen: jax.Array, routed: Optional[jax.Array],
+                  live: jax.Array) -> jax.Array:
+    """``(3,) int32`` of one model call, from :func:`_chosen`'s masks: expert
+    slots touched by a real token (summed over layers), assignments of real
+    tokens, layers run with a real token in them. The experts are those HELD
+    and the layers those with experts. Where the layer holds a share of a
+    wider router's experts a fourth sum follows: every pick of the real
+    tokens, absent experts' included."""
     sums = [jnp.sum(jnp.any(chosen, axis=1)), jnp.sum(chosen),
             chosen.shape[0] * jnp.any(live)]
-    if by_name["routed"]:
-        routed = jnp.concatenate([r.reshape(-1, r.shape[-1]) for r in by_name["routed"]])
+    if routed is not None:
         sums.append(jnp.sum(jnp.where(live.reshape(-1)[None, :], routed, 0)))
     return jnp.stack(sums).astype(jnp.int32)
 
@@ -879,7 +887,8 @@ class CausalLM:
                 with jax.named_scope("bookkeeping"):
                     done_before = done
                     if moe:
-                        mstats = mstats + _routing_sums(mut["moe_stats"], live)
+                        mstats = mstats + _routing_sums(
+                            *_chosen(mut["moe_stats"], live), live)
                     out = jnp.where(done | ~active, jnp.int32(pad_token_id),
                                     nxt)
                     done = done | (active & (eos_ids >= 0) & (nxt == eos_ids))
@@ -1207,10 +1216,13 @@ class CausalLM:
 
         A model with experts (``self.moe_stats``) is told which tokens are
         real (each row's ``new_len - starts`` suffix; the bucket's padding
-        chooses no expert) and returns one more value, LAST: ``(4,) int32``,
-        the three sums of the fused session decode taken over the real tokens
-        (expert slots touched, assignments, layers run) and the grouped rows
-        the experts ran, real or not (layers x rows x bucket x top_k)."""
+        chooses no expert) and returns one more value, LAST: ``(5,) int32``
+        (six where a share of the experts is held), the sums of the fused
+        session decode taken over the real tokens (expert slots touched,
+        assignments, layers run), the grouped rows the experts were handed,
+        real or not (layers x rows x bucket x top_k), and the rows the grouped
+        kernel's dots ran over (``moe/expert_mlps.py::grouped_rows_multiplied``:
+        the sub-tiles each layer's groups touch)."""
         key = self._insert_key(rows, bucket, sampler)
         if key in self._paged_insert:
             return self._paged_insert[key]
@@ -1245,13 +1257,15 @@ class CausalLM:
             sums = ()
             if moe:
                 with jax.named_scope("bookkeeping"):
-                    expert_layers = self.config.num_layers - getattr(
-                        self.config, "first_k_dense", 0)
-                    grouped_rows = (expert_layers * rows * bucket
-                                    * min(self.config.top_k, self.config.num_experts))
+                    top_k = min(self.config.top_k, self.config.num_experts)
+                    chosen, routed = _chosen(mut["moe_stats"], live)
+                    sizes = jnp.sum(chosen, axis=1, dtype=jnp.int32)  # (layers, E)
+                    grouped_rows = sizes.shape[0] * rows * bucket * top_k
                     sums = self._replicate_out((jnp.concatenate([
-                        _routing_sums(mut["moe_stats"], live),
-                        jnp.full((1,), grouped_rows, jnp.int32)]),))
+                        _routing_sums(chosen, routed, live),
+                        jnp.full((1,), grouped_rows, jnp.int32),
+                        grouped_rows_multiplied(sizes, rows * bucket, top_k
+                                                ).reshape(1)]),))
 
             def back(path, old, new):
                 p = jax.tree_util.keystr(path)

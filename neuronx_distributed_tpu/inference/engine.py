@@ -344,11 +344,14 @@ _STAT_KEYS = (
     "moe_assignments_routed",
     # the same three over the real tokens of the paged inserts (bucket
     # padding chooses nothing; CausalLM._paged_insert_programs), and the
-    # grouped rows the inserts' experts ran, real or not: assignments / rows
-    # is the share that was real work, touched / layer_calls the experts an
-    # insert's layer read
+    # grouped rows the inserts' experts were handed, real or not, and the rows
+    # the grouped kernel's dots ran over (the sub-tiles its groups touched:
+    # kernels/grouped_matmul.py::rows_multiplied): assignments / rows is the
+    # share of the sorted list that was real work, assignments /
+    # rows_multiplied the share of the MXU's, touched / layer_calls the
+    # experts an insert's layer read
     "moe_insert_experts_touched", "moe_insert_assignments",
-    "moe_insert_layer_calls", "moe_insert_rows",
+    "moe_insert_layer_calls", "moe_insert_rows", "moe_insert_rows_multiplied",
     # the insert's twins of program_calls / host_fetches: compiled-program
     # calls that admitted requests (an insert; each chunk extend of a chunked
     # or replayed admission) and fetches of their first tokens. An insert is
@@ -3686,11 +3689,13 @@ class ServeEngine:
 
     def _count_insert_routing(self, sums) -> None:
         """One paged insert's routing sums into ``stats``."""
-        *routing, rows = sums        # (3 or 4 routing sums, grouped rows)
+        # (3 or 4 routing sums, grouped rows, rows the kernel multiplied)
+        *routing, rows, multiplied = sums
         for name, x in zip(("experts_touched", "assignments", "layer_calls"),
                            routing):
             self.stats["moe_insert_" + name] += int(x)
         self.stats["moe_insert_rows"] += int(rows)
+        self.stats["moe_insert_rows_multiplied"] += int(multiplied)
 
     def step_block(self) -> bool:
         """One scheduling round: drain recovery replays, admit (expire/shed

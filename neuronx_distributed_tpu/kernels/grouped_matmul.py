@@ -22,10 +22,15 @@ Rows that belong to no group are never stored: they come back as whatever the
 buffer held (NaN under the interpreter), so the caller selects them away with
 ``where`` and never multiplies them by a zero weight.
 
-Tile sizes come from the shapes (:func:`row_tile`, :func:`_tiles`): few rows
-(decode) are one m tile, so each touched group's weights stream through once
-in blocks of megabytes; many rows (prefill) take 256-row tiles. Off the TPU
-the same body runs under the Pallas interpreter (``kernels/mode.py``).
+Tile sizes come from the shapes (:func:`row_tile`, :func:`sub_tile`,
+:func:`_tiles`): few rows (decode) are one m tile, so each touched group's
+weights stream through once in blocks of megabytes. Many rows (prefill) take
+tiles of 512 or 1 024 rows, so that a group's weights stream once per 512 rows
+of it or more, and a visit runs its dots over the 64-row sub-tiles that hold a
+row of its group, a loop whose bounds are the prefetched offsets: the MXU's
+work follows the groups' rows to within a sub-tile, whatever the tile
+(:func:`rows_multiplied` counts it). Off the TPU the same body runs under the
+Pallas interpreter (``kernels/mode.py``).
 """
 
 from __future__ import annotations
@@ -52,18 +57,82 @@ class GroupVisits(NamedTuple):
     count: jax.Array     # () visits that exist (the rest is padding)
 
 
-def row_tile(m: int) -> tuple[int, int]:
-    """``(tm, padded m)`` for ``m`` rows. Up to 256 rows are one tile (a
-    multiple of 16, bf16's sublane tile). More take 256-row tiles, or 128
-    where 256 does not divide ``m`` rounded up to 128: a group that ends
-    inside a tile costs a whole tile's work again, so the tile stays well
-    under a group's rows (measured on the v5e at Mixtral's 8 x 512 insert,
-    ms a layer: 128 -> 20.5, 256 -> 16.7, 512 -> 19.0; OLMoE's: 3.3, 3.4, 4.1)."""
+_SUB = 64      # a multiple of 16; row_tile's docstring has the others' readings
+
+
+def row_tile(m: int, E: int) -> tuple[int, int]:
+    """``(tm, padded m)`` for ``m`` rows in ``E`` groups. Up to 256 rows are
+    one tile (a multiple of 16, bf16's sublane tile): every decode step. More
+    take 512-row tiles, 1 024 where the groups' mean rows ``m // E`` reach
+    that, or one tile where ``m`` is under it: at 256 rows a visit does 256
+    FLOP a weight byte, on the v5e's ridge (240), and re-streams its group's
+    weights for every 256 rows of it. A larger tile streams them less and
+    every visit streams a whole tile of ``lhs``, which the many small groups
+    of a short tile pay for (DeepSeek-V2's 20 groups of ~57 rows: 1.52 ms at
+    512, 1.78 at 1 024, 2.00 at 2 048).
+
+    One layer's three matmuls on the v5e, ms (gate + up + act, down; bf16,
+    three quarters of the tokens real; my chip runs, PR 43, ``tm`` 256 whole
+    = PR 29's kernel -> this one):
+
+    ======================  =======  =====  ================  ================
+    insert (rows x bucket)  ``m``    tm     256 whole         tm, 64-row subs
+    ======================  =======  =====  ================  ================
+    Mixtral 8 x 512         8 192    1 024  10.99 + 5.50      8.62 + 4.56
+    Mixtral 4 x 512         4 096    512    6.85 + 3.47       5.01 + 2.85
+    Mixtral 2 x 512         2 048    512    4.69 + 2.37       3.37 + 1.89
+    Mixtral 1 x 512         1 024    512    3.60 + 1.84       2.94 + 1.66
+    Mixtral 2 x 128         512      512    3.24 + 1.66       2.61 + 1.48
+    Mixtral 1 x 128         256      256    2.83 + 1.44       the same kernel
+    OLMoE 8 x 512           32 768   512    1.94 + 1.11       1.66 + 0.98
+    OLMoE 2 x 512           8 192    512    1.08 + 0.63       0.85 + 0.49
+    OLMoE 1 x 512           4 096    512    0.93 + 0.53       0.80 + 0.45
+    OLMoE 1 x 128           1 024    512    0.84 + 0.47       0.79 + 0.43
+    DeepSeek-V2 1 x 2048    12 288   512    1.21 + 0.63       0.99 + 0.53
+    decode, 8 rows          16-64    = m    1.92, 0.459,      1.92, 0.456,
+    (Mixtral, OLMoE, DSV2)                  0.393             0.391
+    ======================  =======  =====  ================  ================
+
+    At Mixtral's 8 x 512 (6 144 real rows of 8 192, 8 groups) the parent's 30
+    visits multiplied 7 808 rows; 12-13 visits now multiply 6 560 (93.7 % real)
+    in 13.2 ms where the rows' FLOPs need 11.0. Whole 256-row sub-tiles read
+    15.6 ms there, 128-row 14.3, 32-row 22.1 (with the parent's blocks; 64-row
+    14.3); 512-row tiles 13.8 and 2 048-row tiles do not fit VMEM."""
     if m <= 256:
         tm = -(-m // 16) * 16
         return tm, tm
-    mp = -(-m // 128) * 128
-    return (256 if mp % 256 == 0 else 128), mp
+    tm = min(1024 if m // E >= 1024 else 512, -(-m // _SUB) * _SUB)
+    return tm, -(-m // tm) * tm
+
+
+def sub_tile(tm: int) -> int:
+    """Rows of a sub-tile of a ``tm``-row tile: a visit multiplies the
+    sub-tiles that hold a row of its group. One tile of rows is one."""
+    return tm if tm <= 256 else _SUB
+
+
+def tiles_touched(sizes: jax.Array, ends: jax.Array, tile: int
+                  ) -> tuple[jax.Array, jax.Array]:
+    """``(first, count)``: the ``tile``-row tile each group's first row is in
+    and how many it has a row in, none for a group without rows. ``ends`` is
+    the running sum of ``sizes`` along the groups. The visits (``tile`` = tm)
+    and the rows multiplied (``tile`` = sub) are both this."""
+    first = lax.div(lax.sub(ends, sizes), np.int32(tile))          # rows are >= 0
+    count = lax.select(lax.gt(sizes, np.int32(0)),
+                       lax.sub(lax.div(lax.add(ends, np.int32(tile - 1)),
+                                       np.int32(tile)), first),
+                       lax.full_like(sizes, 0))
+    return first, count
+
+
+def rows_multiplied(group_sizes: jax.Array, tm: int) -> jax.Array:
+    """Rows the kernel's dots run over for ``group_sizes (..., E)`` at tile
+    ``tm``, summed over everything: a sub-tile once for every group with a
+    row in it."""
+    sub = sub_tile(tm)
+    sizes = lax.convert_element_type(group_sizes, jnp.int32)
+    ends = lax.cumsum(sizes, axis=sizes.ndim - 1)
+    return lax.mul(jnp.sum(tiles_touched(sizes, ends, sub)[1]), np.int32(sub))
 
 
 def group_visits(group_sizes: jax.Array, m: int, tm: int) -> GroupVisits:
@@ -77,14 +146,8 @@ def group_visits(group_sizes: jax.Array, m: int, tm: int) -> GroupVisits:
     V = m // tm + E - 1
     sizes = lax.convert_element_type(group_sizes, jnp.int32)
     ends = lax.cumsum(sizes, axis=0)
-    starts = lax.sub(ends, sizes)
-    first_tile = lax.div(starts, np.int32(tm))                     # rows are >= 0
-    # tiles a group touches: from the tile of its first row to that of its
-    # last; none when it has no row
-    n_tiles = lax.select(lax.gt(sizes, np.int32(0)),
-                         lax.sub(lax.div(lax.add(ends, np.int32(tm - 1)), np.int32(tm)),
-                                 first_tile),
-                         lax.full_like(sizes, 0))
+    # tiles a group touches: from the tile of its first row to that of its last
+    first_tile, n_tiles = tiles_touched(sizes, ends, tm)
     after = lax.cumsum(n_tiles, axis=0)       # visits up to and with a group
     count = lax.index_in_dim(after, E - 1, 0, keepdims=False)
     visit = np.arange(V, dtype=np.int32)
@@ -106,57 +169,93 @@ def group_visits(group_sizes: jax.Array, m: int, tm: int) -> GroupVisits:
     return GroupVisits(offsets, group, tile, count)
 
 
-def _tiles(k: int, n: int, block_bytes: int, itemsize: int) -> tuple[int, int]:
-    """``(tk, tn)`` of a weight block: ``tn`` the largest multiple of 128 that
-    divides ``n`` up to 2048, ``tk`` the largest that divides ``k`` and keeps
-    the block within ``block_bytes`` (a dimension no multiple of 128 divides
-    is taken whole). Blocks of megabytes keep the stream of a touched
-    expert's weights near the memory's rate; a wide ``tn`` re-reads ``lhs``
-    less."""
+def _tiles(k: int, n: int, stacks: int, itemsize: int, tm: int, sub: int
+           ) -> tuple[int, int]:
+    """``(tk, tn)`` of the weight blocks, 8 MiB of them a step between the
+    ``stacks``; a dimension no multiple of 128 divides is taken whole.
+
+    One tile of rows (decode): ``tn`` the largest multiple of 128 that divides
+    ``n`` up to 2048, ``tk`` the largest that divides ``k`` and fits. Blocks
+    of megabytes keep the stream of a touched expert's weights near the
+    memory's rate; a wide ``tn`` re-reads ``lhs`` less.
+
+    Sub-tiled rows (prefill) are bound by the MXU, and a sub-tile's dot does
+    best over the whole ``k``: Mixtral's gate + up at 8 x 512 read 9.50 ms in
+    blocks of (1024, 2048), 10.09 in (2048, 1024), 8.55 in (4096, 512), 9.03
+    in (4096, 1024), 9.59 in (4096, 256) (my chip runs, PR 43). Every visit
+    streams a whole tile of ``lhs`` once per n tile, so ``tk = k`` where that
+    is no more than the weights it streams (``tm <= tn x stacks``), and else
+    the whole ``n`` and ``lhs`` once a visit: Mixtral's down (``k`` = 14336)
+    read 4.84 ms in (2048, 2048), 4.55 in (1024, 4096), 6.4 in (14336, 256).
+    Widths whose sums of a whole-``n`` tile would not fit VMEM beside the
+    blocks (no cell has them) take the one-tile rule."""
     def largest(dim: int, limit: int) -> int:
         fits = [t for t in range(128, dim + 1, 128) if dim % t == 0 and t <= limit]
         return fits[-1] if fits else dim
 
+    block = (8 << 20) // stacks // itemsize
+    if sub < tm:
+        tn = largest(n, block // k)
+        if tn * k <= block and tm <= tn * stacks:
+            return k, tn
+        if stacks * tm * n * 4 <= 32 << 20:      # the float32 sums of a tile
+            return largest(k, max(128, block // n)), n
     tn = largest(n, 2048)
-    return largest(k, max(128, block_bytes // (tn * itemsize))), tn
+    return largest(k, max(128, block // tn)), tn
 
 
 def _kernel(offsets_ref, group_ref, tile_ref, layer_ref, lhs_ref, *refs,
-            tm, tiles_k, finish):
+            tm, sub, tiles_k, finish):
     *rhs_refs, out_ref = refs[: len(refs) // 2 + 1]
     acc_refs = refs[len(refs) // 2 + 1:]
     visit = pl.program_id(1)
     ki = pl.program_id(2)
+    g = group_ref[visit]
+    # the group's rows, counted from the tile's first
+    first = offsets_ref[g] - tile_ref[visit] * tm
+    last = offsets_ref[g + 1] - tile_ref[visit] * tm
 
-    @pl.when(ki == 0)
-    def _init():
-        for acc_ref in acc_refs:
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+    def work(at, n):
+        """One k step of the tile's rows ``[at, at + n)``: a dot per stack
+        and, at the last, the group's rows of them out."""
+        rows = pl.ds(at, n)
 
-    # operands stay in their storage dtype (bf16 on the MXU), fp32 accumulate
-    lhs = lhs_ref[...]
-    for rhs_ref, acc_ref in zip(rhs_refs, acc_refs):
-        acc_ref[...] += jnp.dot(lhs, rhs_ref[...],
-                                preferred_element_type=jnp.float32)
+        @pl.when(ki == 0)
+        def _init():
+            for acc_ref in acc_refs:
+                acc_ref[rows, :] = jnp.zeros((n, acc_ref.shape[1]), jnp.float32)
 
-    @pl.when(ki == tiles_k - 1)
-    def _store():
-        g = group_ref[visit]
-        rows = tile_ref[visit] * tm + jax.lax.broadcasted_iota(
-            jnp.int32, out_ref.shape, 0)
-        mine = (rows >= offsets_ref[g]) & (rows < offsets_ref[g + 1])
-        # another group's rows of this tile (stored by its own visit) stay
-        out_ref[...] = jnp.where(mine, finish(*(acc[...] for acc in acc_refs)),
-                                 out_ref[...].astype(jnp.float32)
-                                 ).astype(out_ref.dtype)
+        # operands stay in their storage dtype (bf16 on the MXU), fp32 accumulate
+        lhs = lhs_ref[rows, :]
+        for rhs_ref, acc_ref in zip(rhs_refs, acc_refs):
+            acc_ref[rows, :] += jnp.dot(lhs, rhs_ref[...],
+                                        preferred_element_type=jnp.float32)
+
+        @pl.when(ki == tiles_k - 1)
+        def _store():
+            row = at + jax.lax.broadcasted_iota(
+                jnp.int32, (n, out_ref.shape[1]), 0)
+            mine = (row >= first) & (row < last)
+            # another group's rows of this tile (stored by its own visit) stay
+            out_ref[rows, :] = jnp.where(
+                mine, finish(*(acc[rows, :] for acc in acc_refs)),
+                out_ref[rows, :].astype(jnp.float32)).astype(out_ref.dtype)
+
+    if sub == tm:                # one sub-tile: the whole tile, no branch
+        work(0, tm)
+    else:                        # the sub-tiles that hold a row of the group
+        lax.fori_loop(jnp.maximum(first, 0) // sub,
+                      (jnp.minimum(last, tm) + (sub - 1)) // sub,
+                      lambda s, _: work(pl.multiple_of(s * sub, sub), sub), None)
 
 
 @functools.lru_cache(maxsize=None)
-def _kernel_for(tm: int, tiles_k: int, finish):
+def _kernel_for(tm: int, sub: int, tiles_k: int, finish):
     """One function object per kernel variant: ``pallas_call`` keeps the
     traced body by the function's identity, so the programs of a serving
     cell (a dozen and more, one per insert shape) trace it once."""
-    return functools.partial(_kernel, tm=tm, tiles_k=tiles_k, finish=finish)
+    return functools.partial(_kernel, tm=tm, sub=sub, tiles_k=tiles_k,
+                             finish=finish)
 
 
 def _as_is(acc):
@@ -184,7 +283,8 @@ def grouped_matmul(lhs: jax.Array, rhs, layer: jax.Array, visits: GroupVisits,
                          f"rhs {[r.shape for r in rhs]}, tm {tm}")
     out_dtype = out_dtype or lhs.dtype
     itemsize = jnp.dtype(rhs[0].dtype).itemsize
-    tk, tn = _tiles(k, n, (8 << 20) // len(rhs), itemsize)
+    sub = sub_tile(tm)
+    tk, tn = _tiles(k, n, len(rhs), itemsize, tm, sub)
     tiles_k = k // tk
     blocks = (2 * (tm * tk * jnp.dtype(lhs.dtype).itemsize
                    + len(rhs) * tk * tn * itemsize
@@ -194,7 +294,7 @@ def grouped_matmul(lhs: jax.Array, rhs, layer: jax.Array, visits: GroupVisits,
                            lambda ni, v, ki, offs, grp, tile, lyr:
                            (lyr[0], grp[v], ki, ni))
     return pl.pallas_call(
-        _kernel_for(tm, tiles_k, finish),
+        _kernel_for(tm, sub, tiles_k, finish),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,

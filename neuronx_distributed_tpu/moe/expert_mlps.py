@@ -45,6 +45,7 @@ from neuronx_distributed_tpu.kernels.grouped_matmul import (
     group_visits,
     grouped_matmul,
     row_tile,
+    rows_multiplied,
 )
 from neuronx_distributed_tpu.kernels import mode as kernel_mode
 from neuronx_distributed_tpu.parallel.layers import default_kernel_init
@@ -117,6 +118,16 @@ def token_class(tokens: int) -> int:
     return max(8, 1 << (tokens - 1).bit_length())
 
 
+def grouped_rows_multiplied(group_sizes: jax.Array, tokens: int, top_k: int
+                            ) -> jax.Array:
+    """Rows the grouped kernel's dots run over in calls of ``tokens`` tokens
+    whose group sizes are ``group_sizes (calls, E)``, as
+    :func:`_grouped_experts` tiles them: a count for the serving counters, by
+    the kernel's own arithmetic (``kernels/grouped_matmul.py::rows_multiplied``)."""
+    tm, _ = row_tile(token_class(tokens) * top_k, group_sizes.shape[-1])
+    return rows_multiplied(group_sizes, tm)
+
+
 @functools.partial(jax.jit, static_argnames=("top_k", "glu", "dtype", "interpret",
                                               "share"))
 def _grouped_experts(x, combine, live, layer, gate, up, down, *, top_k, glu,
@@ -129,7 +140,7 @@ def _grouped_experts(x, combine, live, layer, gate, up, down, *, top_k, glu,
     programs (one per insert shape) hold seven token classes between them."""
     T, H = x.shape
     weight, order, place, group_sizes = sort_by_expert(combine, top_k, live, share)
-    tm, rows = row_tile(T * top_k)
+    tm, rows = row_tile(T * top_k, combine.shape[1])
     visits = group_visits(group_sizes, rows, tm)
     xs = x.astype(dtype).at[jax.lax.div(order, np.int32(top_k))].get(  # (M, H)
         mode="promise_in_bounds")

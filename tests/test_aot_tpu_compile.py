@@ -398,7 +398,7 @@ def test_mixtral_insert_holds_no_logits_but_the_last_positions(chip):
     ``(8, 512, 32000)``: not an output (0.24 GiB in bf16 before PR 32; what
     the engine read of it was 8 rows), not a temporary; (b) what it returns
     beside the donated cache and keys is the ``(8, 32000)`` logits, eight
-    tokens and four sums; (c) the kernels are still in it."""
+    tokens and five sums; (c) the kernels are still in it."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     from neuronx_distributed_tpu.parallel import mesh

@@ -172,8 +172,10 @@ SERVING_READS = [
 MOE_READS = ["moe_experts_touched", "moe_assignments", "moe_layer_steps"]
 # the paged insert's side of the same counter (PR 29): in `engine.stats`, so
 # in every record's `engine_stats`; no metric of BENCHMARK.json reads them yet
+# ... and the rows the grouped kernel's dots ran over (PR 43)
 MOE_INSERT_STATS = ["moe_insert_experts_touched", "moe_insert_assignments",
-                    "moe_insert_layer_calls", "moe_insert_rows"]
+                    "moe_insert_layer_calls", "moe_insert_rows",
+                    "moe_insert_rows_multiplied"]
 # the insert's twins of program_calls / host_fetches (PR 32): in every record's
 # `engine_stats` the same way; (calls + fetches) / inserts reads 2.0 where every
 # admission was one program and one fetch
@@ -236,10 +238,18 @@ def test_insert_routing_counter_is_produced(run, moe_run, key):
             "moe_insert_rows": 2 * stats["inserted_requests"] * 16 * 2}
     if key in want:
         assert stats[key] == want[key]
-    else:       # experts with a real token, a layer call: some, and no more than held
+    elif key == "moe_insert_experts_touched":
+        # experts with a real token, a layer call: some, and no more than held
         assert 0 < stats[key] <= stats["moe_insert_layer_calls"] * 8
     # the share of the grouped rows that was real work can be read
     assert 0 < stats["moe_insert_assignments"] / stats["moe_insert_rows"] < 1
+    # ... and the share of the rows the kernel multiplied: summed over the
+    # inserts' layer calls, no fewer than the real rows and no more than a
+    # whole tile a visit (these inserts are one tile of rows x 16 x top-2 rows,
+    # one sub-tile, a visit a touched expert: what the parent's kernel ran)
+    tile = stats["moe_insert_rows"] // stats["moe_insert_layer_calls"]
+    assert (stats["moe_insert_assignments"] <= stats["moe_insert_rows_multiplied"]
+            == stats["moe_insert_experts_touched"] * tile)
 
 
 @pytest.mark.parametrize("key", INSERT_HOST_OPS)

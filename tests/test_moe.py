@@ -268,13 +268,18 @@ def test_grouped_equals_all_experts(E, k, glu, norm, shape, masked):
 
 
 @pytest.mark.parametrize("masked", [False, True], ids=["all_real", "masked"])
-@pytest.mark.parametrize("E,k,T", [(8, 2, 8), (64, 8, 8), (4, 1, 1024), (8, 2, 1000)])
+@pytest.mark.parametrize("E,k,T", [(8, 2, 8), (64, 8, 8), (4, 1, 1024), (8, 2, 1000),
+                                   (8, 2, 4096), (64, 8, 512), (4, 1, 512)])
 def test_group_sizes_sum_to_the_live_assignments(E, k, T, masked):
     """The sort that feeds the kernel: group sizes count the real tokens'
     choices and nothing else, the order lists each expert's rows together,
-    and the visits cover every row of a group exactly once."""
-    from neuronx_distributed_tpu.kernels.grouped_matmul import group_visits, row_tile
-    from neuronx_distributed_tpu.moe.expert_mlps import sort_by_expert
+    and the visits' sub-tiles (the 512- and 1 024-row tiles of many rows are
+    cut into them; one tile is one) multiply and store every row of a group
+    exactly once."""
+    from neuronx_distributed_tpu.kernels.grouped_matmul import (
+        group_visits, row_tile, rows_multiplied, sub_tile)
+    from neuronx_distributed_tpu.moe.expert_mlps import (
+        grouped_rows_multiplied, sort_by_expert, token_class)
 
     rs = np.random.RandomState(T + E)
     chosen = np.stack([rs.choice(E, k, replace=False) for _ in range(T)])
@@ -291,16 +296,29 @@ def test_group_sizes_sum_to_the_live_assignments(E, k, T, masked):
     for e in range(E):                       # group e's rows chose expert e
         toks = order[ends[e] - sizes[e]: ends[e]] // k
         assert live[toks].all() and (combine[toks, e] > 0).all()
-    tm, rows = row_tile(T * k)
+    tm, rows = row_tile(token_class(T) * k, E)       # as ``_grouped_experts`` tiles it
+    sub = sub_tile(tm)
     visits = jax.tree.map(np.asarray, group_visits(jnp.asarray(sizes), rows, tm))
-    covered = np.zeros(rows, int)
+    covered, multiplied = np.zeros(rows, int), 0
     for v in range(int(visits.count)):
         g, t = visits.group[v], visits.tile[v]
         lo, hi = max(visits.offsets[g], t * tm), min(visits.offsets[g + 1], (t + 1) * tm)
         assert hi > lo                       # no visit without a row
-        covered[lo:hi] += 1
+        # the sub-tiles of the tile the kernel's loop runs for this visit
+        # (``_kernel``'s bounds), and the rows of them it stores
+        first, last = visits.offsets[g] - t * tm, visits.offsets[g + 1] - t * tm
+        for s in range(max(first, 0) // sub, (min(last, tm) + sub - 1) // sub):
+            at = t * tm + s * sub
+            multiplied += sub
+            covered[max(at, lo): min(at + sub, hi)] += 1
     assert (covered[: sizes.sum()] == 1).all() and (covered[sizes.sum():] == 0).all()
     assert (np.diff(visits.tile[: int(visits.count)]) >= 0).all()  # revisits are consecutive
+    # ``moe_insert_rows_multiplied`` is this count: a sub-tile once for every
+    # group with a row in it
+    touched = sum((e - 1) // sub - (e - n) // sub + 1 for e, n in zip(ends, sizes) if n)
+    assert int(rows_multiplied(jnp.asarray(sizes), tm)) == multiplied == touched * sub
+    assert int(grouped_rows_multiplied(jnp.asarray(sizes)[None], T, k)) == multiplied
+    assert sizes.sum() <= multiplied <= int(visits.count) * tm
 
 
 def test_ep_sharded_checkpoint_roundtrip(tmp_path):

@@ -34,7 +34,7 @@ import numpy as np
 from neuronx_distributed_tpu.inference.paged_cache import PagedKVCache
 from neuronx_distributed_tpu.inference.partition import (
     leaf_partition_spec, repl_args, repl_avals, shard_avals, shard_out,
-    zeros_like_avals,
+    tp_degree, zeros_like_avals,
 )
 from neuronx_distributed_tpu.inference.sampling import Sampler, SlotSampler
 from neuronx_distributed_tpu.models.llama import kv_walk
@@ -129,6 +129,15 @@ def _scatter_cache_rows(old: PyTree, fresh: PyTree, slots: jax.Array,
         return o
 
     return jax.tree_util.tree_map_with_path(upd, old, fresh)
+
+
+def _state_rows(leaf: jax.Array, slots: jax.Array, starts: jax.Array) -> jax.Array:
+    """Rows ``slots`` of a per-slot state leaf ``(layers, max_batch, ...)`` as
+    an insert's model call takes them: what the slot holds where the row
+    continues (``starts > 0``, a chunked extend), zeros where a request begins
+    (whatever the slot's last tenant left behind)."""
+    keep = (starts > 0).reshape(1, -1, *(1,) * (leaf.ndim - 2))
+    return jnp.where(keep, leaf[:, slots], 0)
 
 
 def _chosen(stats: PyTree, live: jax.Array) -> Tuple[jax.Array, Optional[jax.Array]]:
@@ -249,6 +258,9 @@ class DecodeSession:
     # a model with experts: the last paged insert's routing sums, still on
     # the device (CausalLM._paged_insert_programs); None for a dense model
     insert_routing: Optional[jax.Array] = None
+    # a model with per-slot state: the last paged insert's (real tokens,
+    # positions) of its recurrence's scan, still on the device
+    insert_scanned: Optional[jax.Array] = None
     # (max_batch,) typed keys, one request key a slot: an insert program
     # writes its rows' entries (donated in, like the cache), the fused
     # session decode samples row j's token t under fold_in(slot_keys[j], t)
@@ -295,6 +307,28 @@ class CausalLM:
         # admission then defers under pool pressure instead of OOMing.
         self.paged = bool(page_size)
         self.prefix_cache = bool(prefix_cache)
+        # cache leaves that hold ONE ROW A SLOT beside the pages (a recurrent
+        # layer's state; ``models/granite_hybrid.py``). The insert programs
+        # gather and scatter those rows at ``slots``; nothing else moves
+        # them, so whatever reuses or moves a slot's cache BY PAGES is
+        # refused here (and in ``ServeEngine``) instead of being wrong
+        self.slot_rows = tuple(getattr(config, "slot_row_leaves", ()))
+        self._slot_row_ends = tuple(f"['{name}']" for name in self.slot_rows)
+        if self.slot_rows:
+            refused = {
+                "the contiguous slab (pass page_size: generate() and the slab "
+                "insert scan a prompt's padding)": not page_size,
+                "prefix_cache=True (a page-sharing prefix hit restores no state; "
+                "pass prefix_cache=False)": bool(page_size) and prefix_cache,
+                "lora_rank (the state's projections carry no adapter)": bool(lora_rank),
+                f"tensor parallelism (tp = {tp_degree()}: the state leaves have no "
+                "serving spec across 'tp')": tp_degree() > 1,
+            }
+            for what, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"{type(config).__name__} keeps per-slot state "
+                        f"{self.slot_rows} beside its pages and does not serve with {what}")
         if self.paged:
             if self.config.max_seq_len % page_size:
                 raise ValueError(
@@ -417,6 +451,9 @@ class CausalLM:
         # routed experts (``_routing_sums``)
         routed = getattr(self.config, "router_experts", None)
         self.moe_sums = 4 if routed and routed != self.config.num_experts else 3
+        # a model that asks which tokens are real: an insert names each row's
+        # suffix (the bucket's padding chooses no expert, advances no state)
+        self.wants_live = self.moe_stats or bool(self.slot_rows)
 
     # --- compilation (reference ModelBuilder.trace over CTX/TKG) ---------
 
@@ -631,8 +668,11 @@ class CausalLM:
             return logits, self._shard_out(mut["cache"])
 
         def decode_fn(params, cache, ids, *ad):
+            # a model with per-slot state is told its live rows first
+            # (``_live_args``): a row that is not live keeps its state
+            live, ad = (ad[:1], ad[1:]) if self.slot_rows else ((), ad)
             logits, mut = self.model.apply(self._ad_vars(params, cache, ad),
-                                           ids, mutable=["cache"])
+                                           ids, *live, mutable=["cache"])
             return logits, self._shard_out(mut["cache"])
 
         ad0 = self._ad_lower(self.max_batch)
@@ -654,8 +694,17 @@ class CausalLM:
         self._decode = self._time_compile(
             "decode",
             lambda: jax.jit(decode_fn, donate_argnums=(1,))
-            .lower(self.params, cache0, tok, *ad0).compile())
+            .lower(self.params, cache0, tok,
+                   *self._live_args(np.ones((self.max_batch,), bool)), *ad0).compile())
         return self
+
+    def _live_args(self, live) -> tuple:
+        """What the one-step decode program takes after its tokens: for a
+        model with per-slot state the ``(max_batch, 1)`` bool of the rows the
+        step advances, for every other model nothing."""
+        if not self.slot_rows:
+            return ()
+        return repl_args(jnp.asarray(np.asarray(live, bool).reshape(-1, 1)))
 
     def compile_decode_fused(self, steps: int, sampler: Optional[Sampler] = None,
                              eos_token_id: Optional[int] = None,
@@ -949,12 +998,21 @@ class CausalLM:
                 return b
         raise ValueError(f"prompt length {s} exceeds largest bucket {self.buckets[-1]}")
 
+    def _slot_row_leaves(self, tree: PyTree):
+        """``(path, leaf)`` of the per-slot state leaves of a cache tree."""
+        ends = self._slot_row_ends
+        return [(p, leaf) for p, leaf in
+                ((jax.tree_util.keystr(path), leaf) for path, leaf in
+                 jax.tree_util.tree_flatten_with_path(tree)[0])
+                if ends and p.endswith(ends)]
+
     def kv_cache_bytes(self) -> dict:
         """KV-cache footprint of this serving config. ``kv_bytes`` is what
         a session allocates PER CHIP — the HBM-sizing number: under a TP
         mesh the KV pools shard their head axis, so each shard holds
         ``1/tp`` of every sharded leaf (replicated off-mesh / at tp=1 /
-        non-divisible heads: per-chip == global). ``kv_bytes_global`` is
+        non-divisible heads: per-chip == global; a model's per-slot state is
+        not in it but under ``state_bytes``). ``kv_bytes_global`` is
         the full logical footprint (the host-width number: handoff
         payloads and host-tier pages gather to full width);
         ``kv_slab_bytes`` is the per-chip slab-equivalent for the same
@@ -996,8 +1054,14 @@ class CausalLM:
                     self.max_batch * self.config.max_seq_len) // tokens
             else:
                 slab += nbytes // shard_div
-        return {"kv_bytes": actual, "kv_bytes_global": actual_global,
-                "kv_slab_bytes": slab}
+        out = {"kv_bytes": actual, "kv_bytes_global": actual_global,
+               "kv_slab_bytes": slab}
+        if self.slot_rows:
+            # counted apart from the pages: it follows max_batch, not tokens
+            out["state_bytes"] = sum(
+                int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+                for _, leaf in self._slot_row_leaves(self._cache_avals()))
+        return out
 
     def kv_page_bytes(self) -> int:
         """Bytes ONE physical KV page occupies across every layer ON ONE
@@ -1222,13 +1286,21 @@ class CausalLM:
         assignments, layers run), the grouped rows the experts were handed,
         real or not (layers x rows x bucket x top_k), and the rows the grouped
         kernel's dots ran over (``moe/expert_mlps.py::grouped_rows_multiplied``:
-        the sub-tiles each layer's groups touch)."""
+        the sub-tiles each layer's groups touch).
+
+        A model with per-slot state (``self.slot_rows``) is told the same
+        (``self.wants_live``), runs over its rows' state gathered at ``slots``
+        (zeros where ``starts`` is 0) and has it scattered back there, and
+        returns after the above ``(2,) int32``: the real tokens its recurrence
+        scanned and the positions it ran over (rows x the bucket, rounded up
+        to its chunk)."""
         key = self._insert_key(rows, bucket, sampler)
         if key in self._paged_insert:
             return self._paged_insert[key]
         sampler = sampler or SlotSampler()
         ppseq = self.config.max_seq_len // self.config.page_size
         moe = self.moe_stats
+        state_leaves = self._slot_row_ends
 
         def insert_fn(params, cache, slot_keys, first, ids, tables, slots,
                       starts, new_len, *ad):
@@ -1240,17 +1312,23 @@ class CausalLM:
                 if p.endswith("['block_table']"):
                     return jnp.broadcast_to(
                         tables[None], (leaf.shape[0], rows, ppseq))
+                if state_leaves and p.endswith(state_leaves):
+                    # the rows' state: a fresh request starts from zero
+                    # whatever the slot's last tenant left, a chunked
+                    # extend (starts > 0) continues
+                    with jax.named_scope("state_rows"):
+                        return _state_rows(leaf, slots, starts)
                 return leaf  # the pool itself is batch-independent
 
             with jax.named_scope("cache_rows"):
                 row_cache = jax.tree_util.tree_map_with_path(as_rows, cache)
             # a row's own suffix is real, the bucket's padding is not
             live = (jnp.arange(bucket)[None, :] < (new_len - starts)[:, None]
-                    if moe else None)
+                    if self.wants_live else None)
             logits, mut = self.model.apply(
                 self._ad_vars(params, row_cache, ad), ids,
                 jnp.maximum(new_len - starts - 1, 0),
-                **({"live": live} if moe else {}), method="last_logits",
+                **({"live": live} if self.wants_live else {}), method="last_logits",
                 mutable=["cache", "moe_stats"] if moe else ["cache"])
             tokens, slot_keys = self._first_token(
                 logits, slot_keys, slots, first, sampler)
@@ -1266,6 +1344,12 @@ class CausalLM:
                         jnp.full((1,), grouped_rows, jnp.int32),
                         grouped_rows_multiplied(sizes, rows * bucket, top_k
                                                 ).reshape(1)]),))
+            if state_leaves:
+                with jax.named_scope("bookkeeping"):
+                    sums = (*sums, *self._replicate_out((jnp.stack([
+                        jnp.sum(new_len - starts),
+                        rows * self.config.scan_positions(bucket)
+                    ]).astype(jnp.int32),)))
 
             def back(path, old, new):
                 p = jax.tree_util.keystr(path)
@@ -1286,6 +1370,9 @@ class CausalLM:
                         out = jax.lax.dynamic_update_slice_in_dim(
                             out, v, slots[i], axis=1)
                     return out
+                if state_leaves and p.endswith(state_leaves):
+                    with jax.named_scope("state_rows"):
+                        return old.at[:, slots].set(new)
                 return new  # mutated pool leaves
 
             with jax.named_scope("table_write"):
@@ -1430,7 +1517,7 @@ class CausalLM:
              *sums) = prog(
                 self.params, session.cache, session.slot_keys, first, ids,
                 np.asarray(tables, np.int32), slot_ids, starts, new_len, *ad)
-            session.insert_routing = sums[0] if sums else None
+            self._keep_insert_sums(session, sums)
             session.lengths[slot_ids] = new_len
             return logits
         prog = self._chunk_extend_programs(rows, bucket)
@@ -1439,6 +1526,14 @@ class CausalLM:
         session.lengths[slot_ids] = new_len
         last = jnp.asarray(np.maximum(lengths - 1, 0))
         return logits[jnp.arange(rows), last]
+
+    def _keep_insert_sums(self, session: "DecodeSession", sums) -> None:
+        """What a paged insert returned after its four outputs, left on the
+        device for the scheduler's one fetch: the routing sums of a model
+        with experts, then the scan sums of a model with per-slot state."""
+        sums = list(sums)
+        session.insert_routing = sums.pop(0) if self.moe_stats else None
+        session.insert_scanned = sums.pop(0) if self.slot_rows else None
 
     def _insert_paged(self, session: "DecodeSession", slot_ids: np.ndarray,
                       prompt_ids: np.ndarray, lengths: np.ndarray,
@@ -1501,7 +1596,7 @@ class CausalLM:
             for p in plans:
                 pkv.rollback(p)
             raise
-        session.insert_routing = sums[0] if sums else None
+        self._keep_insert_sums(session, sums)
         with pkv.span("cache_commit", rows=rows):
             for i in range(rows):
                 pkv.commit(int(slot_ids[i]), plans[i],
@@ -1589,6 +1684,7 @@ class CausalLM:
         logits, cache = self._decode(
             self.params, session.cache,
             jnp.asarray(tokens, jnp.int32).reshape(-1, 1),
+            *self._live_args(session.active),
             *self._ad_args(session.adapters,
                            adapter_slots if adapter_slots is not None
                            else np.zeros((self.max_batch,), np.int32))

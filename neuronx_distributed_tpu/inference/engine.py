@@ -365,6 +365,11 @@ _STAT_KEYS = (
     # max_seq_len) is the share of the logical slab a step read, row_slots /
     # (tokens x max_batch) the share of that rectangle's rows
     "kv_walk_tokens", "kv_walk_steps", "kv_walk_row_slots",
+    # a model with per-slot state beside its pages (lm.slot_rows;
+    # models/granite_hybrid.py): the real tokens its recurrence scanned in
+    # the paged inserts and the positions it ran over (rows x the bucket
+    # rounded up to the scan's chunk)
+    "ssm_scan_tokens", "ssm_scan_positions",
 )
 
 
@@ -531,6 +536,20 @@ class ServeEngine:
                 raise ValueError(
                     "conversation parking requires a paged CausalLM "
                     "(KV pages are the park unit)")
+        # a model with per-slot state beside its pages (lm.slot_rows): what
+        # moves a slot's cache BY PAGES would leave its state behind
+        if getattr(lm, "slot_rows", ()):
+            refused = {
+                "role='prefill' / 'decode' (the handoff moves pages)": role != "both",
+                "host_tier_pages (the tier spills pages)": bool(host_tier_pages),
+                "conversation parking (a park exports pages)": bool(
+                    park_idle_blocks or park_dir is not None or park_store is not None),
+            }
+            for what, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"this model keeps per-slot state {lm.slot_rows} beside its "
+                        f"pages and is not served with {what}")
         self.lm = lm
         self.block_steps = int(block_steps)
         self.fused = bool(fused)
@@ -1111,16 +1130,20 @@ class ServeEngine:
             allowed=self._grammar_allowed_rows(group, [0] * n, [0] * n))
 
     def _fetch_first(self, first_dev, routing) -> np.ndarray:
-        """An insert's ONE fetch: its sampled first tokens and, from a model
-        with experts, its routing sums (counted here), together."""
+        """An insert's ONE fetch: its sampled first tokens and ``routing``,
+        the pair ``(routing sums of a model with experts, scan sums of a model
+        with per-slot state)`` (counted here; either None), together."""
         t0 = time.perf_counter()
-        first, sums = jax.device_get((first_dev, routing))
+        first, (sums, scanned) = jax.device_get((first_dev, routing or (None, None)))
         if self.tracer.enabled:
             self.tracer.complete("insert_fetch", (self.lane, "dispatch"), t0,
                                  time.perf_counter(), block=self.blocks)
         self.stats["insert_host_fetches"] += 1
         if sums is not None:
             self._count_insert_routing(sums)
+        if scanned is not None:
+            self.stats["ssm_scan_tokens"] += int(scanned[0])
+            self.stats["ssm_scan_positions"] += int(scanned[1])
         return first
 
     def _free_slots(self) -> List[int]:
@@ -1947,7 +1970,8 @@ class ServeEngine:
                 # the insert's routing sums (a model with experts, paged) come
                 # to the host with its first tokens: here, or with the deferred
                 # record
-                routing = self.session.insert_routing
+                routing = (self.session.insert_routing,
+                           self.session.insert_scanned)
                 first = None if defer else self._fetch_first(first_dev, routing)
         now = time.perf_counter()
         for i, (r, slot) in enumerate(zip(group, slot_ids)):
@@ -2490,6 +2514,10 @@ class ServeEngine:
         the full detect/invalidate/replay recovery."""
         if not self.paged:
             raise ValueError("page corruption applies to paged engines only")
+        if getattr(self.lm, "slot_rows", ()):
+            raise ValueError(
+                "page corruption recovery replays pages; this model keeps "
+                f"per-slot state {self.lm.slot_rows} beside them")
         self._handle_corrupt_pages([int(p) for p in pages])
         self.stats.setdefault("injected_corruptions", 0)
         self.stats["injected_corruptions"] += len(pages)
@@ -3894,6 +3922,7 @@ class ServeEngine:
                 "decode", lambda t=tok: self.lm._decode(
                     self.lm.params, self.session.cache,
                     jnp.asarray(t[:, None], jnp.int32),
+                    *self.lm._live_args(self._active & ~done),
                     *self.lm._ad_args(self.session.adapters,
                                       self._adapter_idx)))
             self.session.cache = cache

@@ -197,6 +197,10 @@ def medusa_generate(
     params (``MedusaLlamaForCausalLM`` tree)."""
     if prompt_ids.shape[0] != 1:
         raise ValueError("medusa_generate handles batch size 1")
+    if getattr(config, "slot_row_leaves", ()):
+        raise ValueError(
+            "a tree step has no order for a recurrence to follow: a model with "
+            f"per-slot state {config.slot_row_leaves} takes no Medusa heads")
     buffers = generate_medusa_buffers(medusa_choices)
     if buffers["depth"] > num_medusa_heads:
         raise ValueError(

@@ -33,6 +33,9 @@ The sharding story, per collection:
   ``tp`` so the budget-aware mask is computed pre-gather per shard,
   aligned with the vocab-sharded lm_head logits
   (``ColumnParallelLinear(gather_output=False)``).
+* **Per-slot state** (a configuration's ``slot_row_leaves``, one row a slot
+  of a recurrent layer): no rule here, so replicated; ``CausalLM`` refuses
+  such a model at ``tp > 1`` rather than guess a spec.
 * **Control leaves** (``block_table``/``cache_index``/``adapter_idx``/
   scales/``terminal``/budgets): tiny, host-written between blocks —
   replicated.

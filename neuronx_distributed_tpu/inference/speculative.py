@@ -53,6 +53,16 @@ from neuronx_distributed_tpu.inference.causal_lm import (
 )
 
 
+def _refuse_slot_rows(*lms: CausalLM) -> None:
+    """A rejected draft rewinds ``cache_index`` and the rejected K/V sit
+    behind the mask; a recurrent state has no such rewind."""
+    for lm in lms:
+        if getattr(lm, "slot_rows", ()):
+            raise ValueError(
+                f"speculative decoding rewinds the cache by position; a model with "
+                f"per-slot state {lm.slot_rows} cannot take back a rejected draft")
+
+
 def _propose(draft: CausalLM, num_draft: int, greedy: bool, temperature: float,
              params, cache, last_tok, rng):
     """γ-token draft proposal scan. ONE function traced by BOTH the host-loop
@@ -276,6 +286,7 @@ def speculative_decode_fused(
     ``result.stats`` reports ``fused_block_calls`` (compiled-program
     invocations), acceptance counters on the same surface as the host loop,
     and per-block wall percentiles."""
+    _refuse_slot_rows(target, draft)
     if prompt_ids.shape[0] != 1:
         raise ValueError("speculative_decode_fused handles batch size 1")
     if rounds_per_block < 1:
@@ -411,6 +422,7 @@ def speculative_generate(
     once, at the acceptance read) — leave it off outside benchmarking.
     Acceptance counts and per-round times ride on the existing sync and are
     always reported in ``result.stats``."""
+    _refuse_slot_rows(target, draft)
     if prompt_ids.shape[0] != 1:
         raise ValueError("speculative_generate handles batch size 1")
     if target._decode is None:

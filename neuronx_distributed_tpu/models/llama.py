@@ -135,6 +135,12 @@ class LlamaConfig:
     # projection over ALL heads together, before the split into heads, the
     # clip and the rotary. Off: no parameter and no op.
     qk_norm: bool = False
+    # scale of the attention scores before the softmax (Granite's
+    # ``attention_multiplier``); None: 1 / sqrt(head_dim)
+    attention_multiplier: Optional[float] = None
+    # False: q and k are not rotated (a model without positions, HF
+    # ``position_embedding_type: "nope"``)
+    use_rope: bool = True
     decode: bool = False  # KV-cache inference mode (cache collection)
     # CE loss sequence-chunking (long-seq memory lever): the head matmul +
     # CE run per chunk of this many tokens when seq exceeds it (None = 4096)
@@ -412,6 +418,12 @@ def kv_leaf_shapes(cfg: LlamaConfig, batch: int) -> dict:
     own = getattr(cfg, "kv_leaf_shapes", None)
     if own is not None:
         return own(batch)
+    return kv_page_leaf_shapes(cfg, batch)
+
+
+def kv_page_leaf_shapes(cfg: LlamaConfig, batch: int) -> dict:
+    """:func:`kv_leaf_shapes` of a configuration that declares none of its
+    own: K and V heads, paged or slab."""
     n_kv = cfg.num_kv_heads * cfg.kv_size_multiplier
     hd = cfg.head_dim_
     if not cfg.page_size:
@@ -776,12 +788,16 @@ class LlamaAttention(nn.Module):
             v = jnp.clip(v, -cfg.qkv_clip, cfg.qkv_clip)
         if cfg.decode:
             return self._decode_attention(x, q, k, v, kv, chunk_ctx, aidx, live)
-        cos, sin = rope  # computed once in LlamaModel, broadcast through scan
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
+        if cfg.use_rope:
+            cos, sin = rope  # computed once in LlamaModel, broadcast through scan
+            q = apply_rotary(q, cos, sin)
+            k = apply_rotary(k, cos, sin)
         s = x.shape[1]
         if cfg.context_parallel:
             from neuronx_distributed_tpu.ops.ring_attention import ring_attention
+
+            if cfg.attention_multiplier is not None:
+                raise ValueError("ring attention scales by 1 / sqrt(head_dim) only")
 
             o = ring_attention(
                 q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
@@ -798,6 +814,7 @@ class LlamaAttention(nn.Module):
                 q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                 v.transpose(0, 2, 1, 3),
                 causal=True,
+                sm_scale=cfg.attention_multiplier,
                 use_flash=cfg.use_flash_attention and flash_supported(s, s, blk_q, blk_k),
                 block_q=blk_q,
                 block_k=blk_k,
@@ -833,7 +850,8 @@ class LlamaAttention(nn.Module):
                 k_all = read(top, "cached_key", 0, count)
                 v_all = read(top, "cached_value", 0, count)
                 with jax.named_scope("attend"):
-                    return cached_attention(top.q, k_all, v_all, top.idx)
+                    return cached_attention(top.q, k_all, v_all, top.idx,
+                                            sm_scale=cfg.attention_multiplier)
 
             return walk.prefix(rows, branch)
 
@@ -841,7 +859,8 @@ class LlamaAttention(nn.Module):
             k_c, v_c = read(top, "cached_key", c, 1), read(top, "cached_value", c, 1)
             with jax.named_scope("attend"):
                 qg = top.q.reshape(-1, n_kv, n // n_kv, hd)
-                scores = jnp.einsum("bkgd,bjkd->bkgj", qg, k_c, **exact) * (1.0 / hd ** 0.5)
+                scores = jnp.einsum("bkgd,bjkd->bkgj", qg, k_c, **exact) * (
+                    cfg.attention_multiplier or 1.0 / hd ** 0.5)
             return scores, lambda p: jnp.einsum("bkgj,bjkd->bkgd", p, v_c, **exact)
 
         with jax.named_scope("attend"):
@@ -954,10 +973,11 @@ class LlamaAttention(nn.Module):
         else:
             positions = idx[:, None] + chunk_positions[None, :].astype(jnp.int32)
         rows = jnp.arange(b)[:, None]
-        cos, sin = rotary_embedding(positions, hd, cfg.rope_theta, dtype=q.dtype,
-                                    scaling=cfg.rope_scaling)
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
+        if cfg.use_rope:
+            cos, sin = rotary_embedding(positions, hd, cfg.rope_theta, dtype=q.dtype,
+                                        scaling=cfg.rope_scaling)
+            q = apply_rotary(q, cos, sin)
+            k = apply_rotary(k, cos, sin)
         with jax.named_scope("kv_write"):
             if ps:
                 # write through the block table: logical slot -> physical page.
@@ -1058,7 +1078,7 @@ class LlamaAttention(nn.Module):
                 with jax.named_scope("attend"):
                     o = paged_decode_attention(
                         q, kv.flat("cached_key"), kv.flat("cached_value"),
-                        table, idx,
+                        table, idx, sm_scale=cfg.attention_multiplier,
                         k_scale=kv.flat("cached_key_scale") if quantized else None,
                         v_scale=kv.flat("cached_value_scale") if quantized else None)
                 return self._o_proj(o.reshape(b, s_new, -1), aidx)
@@ -1103,7 +1123,8 @@ class LlamaAttention(nn.Module):
                 cm = jnp.broadcast_to(chunk_mask.astype(bool)[None], (b, s_new, s_new))
                 tree = jnp.take_along_axis(cm, rel_c.astype(jnp.int32), axis=2)
                 mask = prefix | (in_chunk & tree)
-                o = cached_attention(q, k_all, v_all, idx, mask=mask)
+                o = cached_attention(q, k_all, v_all, idx, mask=mask,
+                                     sm_scale=cfg.attention_multiplier)
             o = o.reshape(b, s_new, -1)
             return self._o_proj(o, aidx)
         # prefill/chunk attention: the Pallas kernel with per-slot position
@@ -1128,6 +1149,7 @@ class LlamaAttention(nn.Module):
                     k_all.transpose(0, 2, 1, 3),
                     v_all.transpose(0, 2, 1, 3),
                     causal=False,
+                    sm_scale=cfg.attention_multiplier,
                     use_flash=True,
                     block_q=blk_q,
                     block_k=cfg_blk_k,
@@ -1136,7 +1158,8 @@ class LlamaAttention(nn.Module):
                 )
                 o = o.transpose(0, 2, 1, 3)
             else:
-                o = cached_attention(q, k_all, v_all, idx)
+                o = cached_attention(q, k_all, v_all, idx,
+                                     sm_scale=cfg.attention_multiplier)
         o = o.reshape(b, s_new, -1)
         return self._o_proj(o, aidx)
 

@@ -28,6 +28,10 @@ from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
 from neuronx_distributed_tpu.inference.engine import Rejected
+from neuronx_distributed_tpu.models.granite_hybrid import (
+    GraniteHybridConfig,
+    GraniteHybridForCausalLM,
+)
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.models.olmoe import OlmoeConfig, OlmoeForCausalLM
 from neuronx_distributed_tpu.trainer import (
@@ -45,14 +49,15 @@ TINY = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2, 
 BUDGET, BLOCK_STEPS = 6, 4
 
 
-def _serve(cfg, model_cls, prompts):
+def _serve(cfg, model_cls, prompts, prefix_cache=True):
     """The serving driver's calls, at toy size: ``CausalLM`` paged with the
-    prefix cache on, ``compile()``, a traced fused engine, ``submit`` /
+    prefix cache on (off for a model with per-slot state, as its configuration
+    file says), ``compile()``, a traced fused engine, ``submit`` /
     ``step_block`` until everything drained."""
     params = meta.unbox(model_cls(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
     lm = CausalLM(cfg, params, model_cls, buckets=(16, 32), max_batch=2, page_size=4,
-                  prefix_cache=True)
+                  prefix_cache=prefix_cache)
     lm.compile()
     engine = ServeEngine(lm, block_steps=BLOCK_STEPS, rng=jax.random.key(0), trace=True)
     ids = [engine.submit(p, max_new_tokens=BUDGET, arrival_block=engine.blocks)
@@ -89,6 +94,24 @@ def moe_run():
     rng = np.random.RandomState(1)
     return _serve(cfg, OlmoeForCausalLM,
                   [rng.randint(1, 128, (n,)).astype(np.int32) for n in (6, 9)])
+
+
+@pytest.fixture(scope="module")
+def hybrid_run():
+    """One period of Granite-4.0-H's stack, tiny: prompts of 6 and 9 tokens in
+    buckets of 16, a scan chunk of 8."""
+    cfg = GraniteHybridConfig(**dict(
+        TINY, num_layers=10, layer_types=["mamba"] * 5 + ["attention"] + ["mamba"] * 4,
+        head_dim=8, mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8, mamba_chunk_size=8,
+        attention_multiplier=0.125, param_dtype=jnp.float32))
+    rng = np.random.RandomState(2)
+    got = _serve(cfg, GraniteHybridForCausalLM,
+                 [rng.randint(1, 128, (n,)).astype(np.int32) for n in (6, 9)],
+                 prefix_cache=False)
+    got.fused = got.lm.compile_session_decode_fused(
+        got.engine.block_steps, got.engine.slot_sampler, got.engine.pad_token_id)
+    got.insert = got.lm._paged_insert_programs(1, 16)
+    return got
 
 
 @pytest.fixture(scope="module")
@@ -187,6 +210,15 @@ ROUTED_READS = ["moe_assignments_routed"]
 # in every record's `engine_stats`; no metric of BENCHMARK.json reads them yet
 # ... and over how many rows (PR 40): the rung of rows that holds the live ones
 WALK_STATS = ["kv_walk_tokens", "kv_walk_steps", "kv_walk_row_slots"]
+# a model with per-slot state beside its pages (PR 44): what its inserts'
+# chunked scan ran over (``ssm.scan_real_token_share`` divides them) ...
+SSM_READS = ["ssm_scan_tokens", "ssm_scan_positions"]
+# ... and the names its mixer's regions carry in a device trace (the flax
+# module ``mamba``, ``jax.named_scope``s inside it, ``state_rows`` around the
+# insert's gather and scatter of slot rows); ``scope_parts.json`` is the
+# benchmark's and has no rows for them yet. True: in the fused decode's step
+SSM_SCOPES = {"mamba": None, "ssm_in_proj": None, "ssm_conv": None, "ssm_gate_norm": None,
+              "ssm_out_proj": None, "ssm_scan": False, "state_rows": False, "ssm_step": True}
 TRAIN_READS = ["jit_step_fn"]
 # the engine's own spans of a round (PR 39), as ``benchmark/phase_spans.py`` and
 # the seven readers over it spell them: (name, lane's track) of a complete span
@@ -250,6 +282,32 @@ def test_insert_routing_counter_is_produced(run, moe_run, key):
     tile = stats["moe_insert_rows"] // stats["moe_insert_layer_calls"]
     assert (stats["moe_insert_assignments"] <= stats["moe_insert_rows_multiplied"]
             == stats["moe_insert_experts_touched"] * tile)
+
+
+@pytest.mark.parametrize("key", SSM_READS)
+def test_state_counter_is_produced(run, moe_run, hybrid_run, key):
+    """Counted by the programs of a model with per-slot state, and only
+    there: two inserts of one row (6 and 9 real tokens of 16 positions, two
+    chunks of 8)."""
+    stats = hybrid_run.engine.stats
+    for other in (run, moe_run):
+        assert key in dict(other.engine.stats.items()) and other.engine.stats[key] == 0
+    want = {"ssm_scan_tokens": 6 + 9, "ssm_scan_positions": 2 * 16}
+    assert stats[key] == want[key] > 0
+
+
+@pytest.mark.parametrize("name", sorted(SSM_SCOPES))
+def test_state_scope_is_in_the_compiled_programs(hybrid_run, name):
+    """In the op names of the insert (the chunked scan, the slot rows' gather
+    and scatter) or of the fused decode (the recurrence's one step), or both."""
+    found = {fused: re.search(rf'op_name="[^"]*\b{name}\b', program.as_text()) is not None
+             for fused, program in ((True, hybrid_run.fused), (False, hybrid_run.insert))}
+    where = SSM_SCOPES[name]
+    assert found[True if where is None else where]
+    if where is not None:
+        assert not found[not where]
+    else:
+        assert found[False]
 
 
 @pytest.mark.parametrize("key", INSERT_HOST_OPS)
@@ -393,7 +451,7 @@ def test_every_name_is_still_read():
              BENCHMARK / "trace_parts.py", BENCHMARK / "trace_reduce.py",
              BENCHMARK / "phase_spans.py"]
     text = "\n".join(f.read_text() for f in files)
-    names = ([n for n, _ in SERVING_READS] + MOE_READS + ROUTED_READS + TRAIN_READS
+    names = ([n for n, _ in SERVING_READS] + MOE_READS + ROUTED_READS + SSM_READS + TRAIN_READS
              + [n for n, _ in PHASE_SPANS] + [n for n, _ in PHASE_ARGS_READ])
     assert len(set(names)) == len(names)
     missing = [n for n in names if n not in text]

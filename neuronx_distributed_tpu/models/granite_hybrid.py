@@ -40,6 +40,13 @@ What is new to the serving path, and where it lives:
   IS the state after the row's last real token, and the convolution's tail
   kept is the last ``d_conv - 1`` REAL inputs. In a one-token step a row that
   is not live keeps its state.
+* **The step's recurrence is a kernel.** One token a row on a state goes
+  through ``kernels/ssm_step.py``: the layer's rows of the stacked
+  ``ssm_state`` leaf are read, stepped, reduced to ``y`` and written where
+  they were, once. In XLA the same expressions were a reduction and an
+  in-place update that each read the rows (PERF.md, PR 45). A prompt and a
+  forward pass that keeps nothing, whatever its length, take the chunked
+  form (``ssd_chunked``), which is plain XLA.
 
 Serving only, one chip: no remat, no sequence or context parallelism, and
 the state leaves have no spec for ``tp > 1`` (``inference/partition.py``
@@ -56,6 +63,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from neuronx_distributed_tpu.kernels.ssm_step import ssm_step
 from neuronx_distributed_tpu.models.llama import (
     KVLayerView,
     LlamaAttention,
@@ -280,7 +288,8 @@ class Mamba2Mixer(nn.Module):
     (b, s) bool: the real positions of a prompt, the live rows of a step;
     None counts everything. A prompt (``s > 1``) continues from the rows'
     state as it is given (an insert hands a fresh request zeros) by the
-    chunked form; one token a row is the recurrence once.
+    chunked form; one token a row is the recurrence once, on a state by
+    ``kernels/ssm_step.py`` over the stacked leaf in place.
 
     Two departures from the published layout of the weights, for a converter
     to apply. The convolution's kernel is stored ``(d_conv, channels)``, the
@@ -324,12 +333,10 @@ class Mamba2Mixer(nn.Module):
 
         if state is None:
             tail = jnp.zeros((b, k - 1, conv_dim), cfg.dtype)
-            S = jnp.zeros((b, h, p, n), f32)
         else:
             first = state.first_row(b)
             tail = jax.lax.dynamic_slice_in_dim(state.flat(CONV_LEAF), first, b)
-            S = jax.lax.dynamic_slice_in_dim(state.flat(STATE_LEAF), first, b)
-        S_in, tail_in = S, tail
+        tail_in = tail
 
         with jax.named_scope("ssm_conv"):
             window = jnp.concatenate([tail.astype(cfg.dtype), xbc], axis=1)   # (b, k-1+s, c)
@@ -349,25 +356,33 @@ class Mamba2Mixer(nn.Module):
         if live is not None:
             dt = jnp.where(live[..., None], dt, 0.0)
 
-        if s == 1:
+        if s == 1 and state is not None:
+            # the kernel steps this layer's rows of the whole leaf in place: no
+            # slice of them before it and no update after it, each a copy
             with jax.named_scope("ssm_step"):
-                dt1, x1 = dt[:, 0], x[:, 0].astype(f32)                      # (b, h), (b, h, p)
-                S = (jnp.exp(dt1 * A)[..., None, None] * S.astype(f32)
-                     + (dt1[..., None] * x1)[..., None] * B[:, 0].astype(f32)[:, None, None, :])
-                y = jnp.einsum("bhpn,bn->bhp", S, C[:, 0].astype(f32))[:, None]
-        else:
+                dt1 = dt[:, 0]
+                flat, y = ssm_step(state.flat(STATE_LEAF), first, jnp.exp(dt1 * A),
+                                   dt1[..., None] * x[:, 0].astype(f32), B[:, 0], C[:, 0],
+                                   None if live is None else live[:, 0])
+            state.put(STATE_LEAF, flat)
+            y = y[:, None]
+        else:       # a prompt, or a pass that keeps nothing: the chunked form
+            S = (jnp.zeros((b, h, p, n), f32) if state is None else
+                 jax.lax.dynamic_slice_in_dim(state.flat(STATE_LEAF), first, b))
             with jax.named_scope("ssm_scan"):
                 y, S = ssd_chunked(x, dt, dt * A, B, C, S, cfg.mamba_chunk_size)
+            if state is not None:
+                flat = state.flat(STATE_LEAF)
+                state.put(STATE_LEAF, jax.lax.dynamic_update_slice_in_dim(
+                    flat, S.astype(flat.dtype), first, axis=0))
         y = y + D[:, None] * x.astype(f32)
 
         if state is not None:
-            if live is not None and s == 1:     # a row that is not live keeps its state
-                S = jnp.where(live[:, :, None, None], S, S_in.astype(f32))
+            if live is not None and s == 1:     # a row that is not live keeps its tail
                 tail = jnp.where(live[:, :, None], tail, tail_in)
-            for name, new in ((STATE_LEAF, S), (CONV_LEAF, tail)):
-                flat = state.flat(name)
-                state.put(name, jax.lax.dynamic_update_slice_in_dim(
-                    flat, new.astype(flat.dtype), first, axis=0))
+            flat = state.flat(CONV_LEAF)
+            state.put(CONV_LEAF, jax.lax.dynamic_update_slice_in_dim(
+                flat, tail.astype(flat.dtype), first, axis=0))
 
         with jax.named_scope("ssm_gate_norm"):
             y = y.reshape(b, s, d_inner) * nn.silu(z.astype(f32))

@@ -39,6 +39,7 @@ from neuronx_distributed_tpu.kernels.flash_attn import (
     default_attention_blocks,
     flash_attention,
 )
+from neuronx_distributed_tpu.kernels.ssm_step import ssm_step
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.optimizer.fused_kernel import fused_adamw_leaf
 
@@ -441,6 +442,38 @@ def test_fused_adamw_leaf(chip):
     f32 = jnp.float32
     assert _kernel_calls(fn, aval(jnp.bfloat16), aval(f32), aval(f32),
                          aval(f32), aval(f32, (1, 4))) == 1
+
+
+def test_ssm_step_steps_the_leaf_in_place(chip):
+    """granite-4.0-h-micro's state at 16 slots (36 layers x 16 rows of
+    (64, 64, 128) float32, 1.2 GB), the kernel called layer after layer from a
+    loop whose carry is the donated leaf, as the period scan and the fused
+    block's step loop hold it: the alias reaches the argument, so the program
+    holds no second leaf, no copy of it and no update of a layer's rows."""
+    layers, b, h, p, n = 36, 16, 64, 64, 128
+    f32 = jnp.float32
+
+    def aval(*shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def fn(state, decay, dtx, B, C, live):
+        def layer(at, carry):
+            state, ys = carry
+            state, y = ssm_step(state, at * b, decay, dtx, B, C, live)
+            return state, ys + y
+        return jax.lax.fori_loop(0, layers, layer, (state, jnp.zeros((b, h, p), f32)))
+
+    compiled = jax.jit(fn, donate_argnums=0).lower(
+        aval(layers * b, h, p, n), aval(b, h), aval(b, h, p), aval(b, n), aval(b, n),
+        aval(b, dtype=jnp.bool_)).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    leaf = layers * b * h * p * n * 4
+    assert text.count("tpu_custom_call") == 1
+    assert memory.alias_size_in_bytes >= leaf and memory.temp_size_in_bytes < leaf // 100
+    rows = re.escape(f"f32[{b},{h},{p},{n}]")
+    whole = re.escape(f"f32[{layers * b},{h},{p},{n}]")
+    assert not re.search(rf"= (?:{rows}|{whole})\S* (?:copy|dynamic-update-slice|dynamic-slice)\(",
+                         text)
 
 
 def test_paged_kernel_refused_at_construction_not_mid_serve():

@@ -27,12 +27,15 @@ from jax.sharding import PartitionSpec
 from benchmark.reference import granite_hybrid as reference
 from neuronx_distributed_tpu.inference import CausalLM, ServeEngine, causal_lm
 from neuronx_distributed_tpu.inference.partition import leaf_partition_spec
+from neuronx_distributed_tpu.models import granite_hybrid
 from neuronx_distributed_tpu.models.granite_hybrid import (
     GraniteHybridConfig,
     GraniteHybridForCausalLM,
+    Mamba2Mixer,
     granite_4_0_h_micro,
     ssd_chunked,
 )
+from neuronx_distributed_tpu.models.llama import KVLayerView
 from neuronx_distributed_tpu.parallel import mesh
 
 TOL = 1e-4
@@ -159,6 +162,60 @@ def test_the_chunked_scan_equals_the_recurrence(s):
         ys.append(np.einsum("bhpn,bn->bhp", S, C[:, t]))
     np.testing.assert_allclose(np.asarray(y), np.stack(ys, 1), rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(S_out), S, rtol=2e-5, atol=2e-5)
+
+
+def test_only_a_one_token_step_with_a_state_runs_the_kernel(monkeypatch):
+    """The mixer alone, so that no ``nn.jit`` trace kept from another test
+    hides a call: one token a row on a layer's rows of the stacked leaves goes
+    through ``kernels/ssm_step.py`` once, gives what the chunked form gives
+    without a state that starts from the same rows, and leaves the other
+    layers' rows alone; a prompt on the same leaves and any forward pass that
+    keeps nothing do not call it."""
+    world()
+    cfg = GraniteHybridConfig(**TINY)
+    calls = []
+
+    def counted(*args, **kw):
+        calls.append(args[0].shape)
+        return ssm_step(*args, **kw)
+
+    ssm_step = granite_hybrid.ssm_step
+    monkeypatch.setattr(granite_hybrid, "ssm_step", counted)
+    mixer = Mamba2Mixer(cfg)
+    b, layers, layer = 3, 4, 2
+    rng = np.random.RandomState(5)
+    u = jnp.asarray(rng.randn(b, 6, cfg.hidden_size), jnp.float32)
+    params = meta.unbox(mixer.init(jax.random.key(2), u))
+    assert not calls                                        # init: a prompt, no state
+
+    def leaves():
+        return {name: jnp.asarray(rng.randn(layers, *shape), dtype)
+                for name, (shape, dtype) in cfg.kv_leaf_shapes(b).items() if name in cfg.slot_row_leaves}
+
+    def apply(u, state=None, live=None):
+        view = None if state is None else KVLayerView(jnp.int32(layer), state)
+        out = mixer.apply(params, u, view, live)
+        return out, None if view is None else view.leaves
+
+    before = leaves()
+    live = jnp.asarray([[True], [False], [True]])
+    out, after = apply(u[:, :1], before, live)
+    assert calls == [(layers * b, cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state)]
+    for name in before:
+        others = np.arange(layers) != layer
+        np.testing.assert_array_equal(np.asarray(after[name])[others], np.asarray(before[name])[others])
+        np.testing.assert_array_equal(np.asarray(after[name])[layer, 1], np.asarray(before[name])[layer, 1])
+        assert not np.array_equal(np.asarray(after[name])[layer, 0], np.asarray(before[name])[layer, 0])
+    del calls[:]
+    apply(u, leaves(), jnp.ones((b, 6), bool))              # a prompt on a state: the chunked form
+    apply(u)                                                # a forward pass, nothing kept
+    apply(u[:, :1])                                         # one token, nothing kept
+    assert not calls
+    # one token from zeros, with the state and without: the same output
+    zeros = jax.tree.map(jnp.zeros_like, before)
+    np.testing.assert_allclose(np.asarray(apply(u[:, :1], zeros)[0]), np.asarray(apply(u[:, :1])[0]),
+                               rtol=1e-5, atol=1e-6)
+    assert len(calls) == 1
 
 
 WRONG_FORWARD = {

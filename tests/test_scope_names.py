@@ -50,7 +50,15 @@ LATENT_SCOPES = {
     "models/deepseek_v2.py DeepseekV2MoELayer": ["shared_expert"],
 }
 KERNELS = ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode", "fused_adamw",
-           "grouped_matmul"]
+           "grouped_matmul", "ssm_step"]
+# Granite-4.0-H's scopes (PRs 44, 45), all inside the flax module ``mamba``.
+# ``scope_parts.json`` has no rows for them either (PERF.md section 7).
+MAMBA_SCOPES = {
+    "models/granite_hybrid.py Mamba2Mixer": ["ssm_in_proj", "ssm_conv", "ssm_gate_norm",
+                                             "ssm_out_proj"],
+    "fused_decode": ["ssm_step"],                   # one token a row on a state
+    "paged_insert": ["ssm_scan", "state_rows"],     # a prompt; causal_lm.py's rows of the slots
+}
 
 TINY = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2, num_heads=4,
             num_kv_heads=2, kv_size_multiplier=1, max_seq_len=256, dtype=jnp.float32,
@@ -186,6 +194,39 @@ def test_deepseek_v2_names_latent_attention_router_groups_and_shared_expert(prog
     assert "dense_layers" in components and "layers" in components
     for part in ("kv_write", "kv_gather", "attend", "router", "experts", "ffn", "norm"):
         assert parts[part] > 0, part
+
+
+@pytest.mark.parametrize("program", ["fused_decode", "paged_insert"])
+def test_granite_names_the_mixers_stages_and_the_step_kernel(program):
+    """The tiny Granite-4.0-H decode block carries the one-token recurrence
+    under ``mamba/ssm_step``, where ``kernels/ssm_step.py`` runs (its ops carry
+    the kernel's name too, interpreted here, one custom call on the chip),
+    and no chunked scan; the paged insert the other way round, with the slot
+    rows' gather and scatter under ``state_rows``."""
+    from neuronx_distributed_tpu.models.granite_hybrid import (
+        GraniteHybridConfig,
+        GraniteHybridForCausalLM,
+    )
+
+    cfg = GraniteHybridConfig(**dict(
+        TINY, num_layers=4, layer_types=["mamba", "attention"] * 2, mamba_n_heads=8,
+        mamba_d_head=8, mamba_d_state=16, mamba_chunk_size=8, use_flash_attention=False))
+    weights = meta.unbox(GraniteHybridForCausalLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    lm = CausalLM(cfg, weights, GraniteHybridForCausalLM, buckets=(128,), max_batch=2,
+                  page_size=16, prefix_cache=False)
+    decode = program == "fused_decode"
+    compiled = (lm.compile_session_decode_fused(4) if decode
+                else lm._paged_insert_programs(2, 128))
+    components, _ = census(compiled)
+    other = "paged_insert" if decode else "fused_decode"
+    want = ({"mamba", "kv_write", "attend"} | set(MAMBA_SCOPES[program])
+            | set(MAMBA_SCOPES["models/granite_hybrid.py Mamba2Mixer"]))
+    assert want <= components, sorted(want - components)
+    assert not set(MAMBA_SCOPES[other]) & components
+    under = [m.group(1) for m in map(OP_NAME.search, compiled.as_text().splitlines())
+             if m and "ssm_step" in m.group(1)]
+    assert all("/mamba/ssm_step/" in name for name in under), under[:3]
 
 
 def test_dense_decode_has_no_qk_norm_scope(params):

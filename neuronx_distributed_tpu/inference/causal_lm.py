@@ -625,14 +625,14 @@ class CausalLM:
     def _gr_args(self, pool, gidx, gstate, gbudget) -> tuple:
         """Trailing call args: the pool's live tables (identity when no
         pool rides along) + per-row grammar slot / DFA state / budget — ()
-        when grammars are off."""
+        when grammars are off. Host rows ride the call as host arrays; a
+        device value (the async loop chains the DFA state) passes through."""
         if not self.grammar:
             return ()
         tree = pool.tree if pool is not None else self._identity_grammars()
-        return (tree,
-                jnp.asarray(np.asarray(gidx, np.int32)),
-                jnp.asarray(np.asarray(gstate, np.int32)),
-                jnp.asarray(np.asarray(gbudget, np.int32)))
+        return (tree, *(v if isinstance(v, jax.Array)
+                        else np.asarray(v, np.int32)
+                        for v in (gidx, gstate, gbudget)))
 
     @staticmethod
     def grammar_allowed(tree, gidx, gstate, gbudget, counts):
@@ -805,6 +805,35 @@ class CausalLM:
         self._cache_avals_cache = shard_avals(avals)
         return self._cache_avals_cache
 
+    # the rows of the fused session decode's ``rows`` argument, in order
+    BLOCK_ROWS = ("counts", "lengths", "active", "eos_ids", "temperature",
+                  "greedy")
+
+    @staticmethod
+    def block_rows(counts, lengths, active, eos_ids, temperature, greedy,
+                   out: Optional[np.ndarray] = None) -> np.ndarray:
+        """The six row arrays of a fused block that only the host writes, as
+        the ONE ``(6, rows)`` int32 host matrix the program takes
+        (``BLOCK_ROWS`` is the order): ``temperature``'s float32 bits, the
+        bools as 0/1. NumPy only, no device op. Filled into ``out`` where
+        given (the sync loop's kept buffer), else into a new matrix (a copy
+        of the mirrors, which the async loop needs)."""
+        if out is None:
+            out = np.empty((len(CausalLM.BLOCK_ROWS), len(counts)), np.int32)
+        bits = np.asarray(temperature, np.float32).view(np.int32)
+        for i, row in enumerate((counts, lengths, active, eos_ids, bits,
+                                 greedy)):
+            out[i] = row
+        return out
+
+    @staticmethod
+    def unpack_block_rows(rows: jax.Array) -> tuple:
+        """:meth:`block_rows` undone inside a program: every row in the
+        dtype and with the bits it had on the host."""
+        counts, lengths, active, eos_ids, bits, greedy = rows
+        return (counts, lengths, active != 0, eos_ids,
+                jax.lax.bitcast_convert_type(bits, jnp.float32), greedy != 0)
+
     def compile_session_decode_fused(self, steps: int,
                                      slot_sampler: Optional[SlotSampler] = None,
                                      pad_token_id: int = 0):
@@ -859,10 +888,14 @@ class CausalLM:
         host ops, zero recompiles when the grammar mix changes.
 
         Returns the compiled program ``(params, cache, tok (b,1), slot_keys
-        (b,) keys, counts (b,), lengths (b,), active (b,), done (b,),
-        eos_ids (b,), temperature (b,), greedy (b,)[, *gr]) -> (tokens
+        (b,) keys, done (b,), rows (6, b) int32[, *ad][, *gr]) -> (tokens
         (steps, b), cache, next_tok, lengths, done[, dfa_state], walked[,
-        routing])``. The
+        routing])``. ``rows`` is :meth:`block_rows` of the six row arrays
+        that only the host writes (``counts``, ``lengths``, ``active``,
+        ``eos_ids``, ``temperature``, ``greedy``): one host matrix, so one
+        transfer inside the call; ``tok`` and ``done`` stay arguments of
+        their own because the async loop chains them from the block before
+        without a fetch. The
         trailing ``dfa_state`` rides out only for grammar-enabled lms: the
         async double-buffered loop feeds block t+1's grammar quad from
         block t's OUTPUT without a host fetch, so the final carried state
@@ -898,8 +931,9 @@ class CausalLM:
         n_ad = 2 if self.lora else 0
         moe = self.moe_stats
 
-        def fused_fn(params, cache, tok, slot_keys, counts, lengths, active,
-                     done, eos_ids, temperature, greedy, *tail):
+        def fused_fn(params, cache, tok, slot_keys, done, rows, *tail):
+            (counts, lengths, active, eos_ids, temperature,
+             greedy) = self.unpack_block_rows(rows)
             ad = tail[:n_ad]
             gr = tail[n_ad:]
             if gr:
@@ -982,12 +1016,8 @@ class CausalLM:
             .lower(self.params, self._cache_avals(),
                    *repl_args(jnp.zeros((b, 1), jnp.int32),
                               jax.random.split(jax.random.key(0), b),
-                              jnp.zeros((b,), jnp.int32),
-                              jnp.zeros((b,), jnp.int32),
-                              jnp.zeros((b,), bool), jnp.zeros((b,), bool),
-                              jnp.full((b,), -1, jnp.int32),
-                              jnp.ones((b,), jnp.float32),
-                              jnp.ones((b,), bool)),
+                              jnp.zeros((b,), bool),
+                              jnp.zeros((len(self.BLOCK_ROWS), b), jnp.int32)),
                    *self._ad_lower(b), *self._gr_lower(b))
             .compile())
         return self._session_fused[key]

@@ -317,7 +317,10 @@ def _page_payload(data: Dict[str, np.ndarray], path: str):
 
 _STAT_KEYS = (
     "blocks", "decode_blocks", "inserts", "inserted_requests",
-    "program_calls", "host_fetches", "deferred_admissions",
+    "program_calls", "host_fetches",
+    # host arrays that went up WITH the fused blocks' calls (the rows'
+    # mirrors: _advance_block), summed over the blocks
+    "block_uploads", "deferred_admissions",
     "chunk_program_calls", "prefill_chunk_tokens_done", "prefill_aborts",
     "cancelled", "rejected", "shed_evictions", "expired",
     "dispatch_retries", "corrupt_page_replays", "restored_requests",
@@ -752,6 +755,10 @@ class ServeEngine:
         # under fold_in(slot_keys[j], counts[j]); the request keys are the
         # session's, see _slot_keys)
         self._gen_counts = np.zeros((b,), np.int32)
+        # where a sync launch packs the rows only the host writes
+        self._block_rows = CausalLM.block_rows(
+            self._gen_counts, self._lengths, self._active, self._eos,
+            self._temp, self._greedy)
         # async pipeline state (async_loop=True): at most ONE in-flight
         # dispatched-but-unfetched block record rides _inflight between
         # iterations (deque so a flush drains in dispatch order); _staged
@@ -3842,9 +3849,9 @@ class ServeEngine:
         fetch. The ``launch`` phase begins on the stamp ``after`` (where
         ``observe`` ended) and ends where the program call returned and the
         block's fetch begins (stepwise: it holds all K of each)."""
-        launch = self._phase("launch", self.blocks, after,
-                             {"active": int(self._active.sum())}
-                             if self.tracer.enabled else None)
+        said = ({"active": int(self._active.sum()), "host_args": 0}
+                if self.tracer.enabled else None)
+        launch = self._phase("launch", self.blocks, after, said)
         if self._sim:
             with launch:
                 rids = [(-1 if r is None else r.request_id)
@@ -3861,16 +3868,27 @@ class ServeEngine:
             with launch:
                 fused = self.lm.compile_session_decode_fused(
                     self.block_steps, self.slot_sampler, self.pad_token_id)
+                # what the block needs from the host goes up IN the call,
+                # as host arrays, with no eager upload before it: the tokens
+                # and latches as the mirrors they are (no copy), the six rows
+                # only the host writes as one matrix in a buffer kept for it.
+                # Safe in the sync loop and only there: the block is fetched
+                # before harvest writes a mirror or the next launch refills
+                # the buffer, so a backend that aliases a host buffer (jax's
+                # CPU client does: _dispatch_block_async) still reads what
+                # the scheduler decided
+                rows = self.lm.block_rows(
+                    self._gen_counts, self._lengths, self._active, self._eos,
+                    self._temp, self._greedy, out=self._block_rows)
                 args = (self.lm.params, self.session.cache,
-                        jnp.asarray(self._tok[:, None]), self._slot_keys,
-                        jnp.asarray(self._gen_counts),
-                        jnp.asarray(self._lengths), jnp.asarray(self._active),
-                        jnp.asarray(self._done), jnp.asarray(self._eos),
-                        jnp.asarray(self._temp), jnp.asarray(self._greedy),
+                        self._tok[:, None], self._slot_keys, self._done, rows,
                         *self.lm._ad_args(self.session.adapters,
                                           self._adapter_idx),
                         *self.lm._gr_args(self.session.grammars, self._gidx,
                                           self._gstate, self._gbudget))
+                uploads = self._count_uploads(args)
+                if said is not None:
+                    said["host_args"] = uploads
                 # 5 outputs, or 6 with grammar (the trailing DFA state exists
                 # for the async pipeline; the sync loop ignores it)
                 outs = self._dispatch("decode", lambda: fused(*args))
@@ -3887,6 +3905,13 @@ class ServeEngine:
             toks = self._advance_stepwise()
         self._tile_at = None        # harvest begins where launch ended
         return toks, []
+
+    def _count_uploads(self, args) -> int:
+        """The host arrays among a fused block's arguments (they go up with
+        the call), counted into ``stats["block_uploads"]``."""
+        uploads = sum(isinstance(a, np.ndarray) for a in args)
+        self.stats["block_uploads"] += uploads
+        return uploads
 
     def _advance_stepwise(self) -> np.ndarray:
         """``_advance_block`` paid per token: K dispatches, K fetches."""
@@ -4129,13 +4154,13 @@ class ServeEngine:
                 done_in = done_in.at[slot].set(d_v)
                 if gstate_in is not None:
                     gstate_in = gstate_in.at[slot].set(g_v)
+            # block_rows without ``out``: a new matrix, the copy of these
+            # six mirrors
             args = (self.lm.params, self.session.cache, tok_in,
-                    self._slot_keys, jnp.asarray(self._gen_counts.copy()),
-                    jnp.asarray(self._lengths.copy()),
-                    jnp.asarray(self._active.copy()),
-                    done_in, jnp.asarray(self._eos.copy()),
-                    jnp.asarray(self._temp.copy()),
-                    jnp.asarray(self._greedy.copy()),
+                    self._slot_keys, done_in,
+                    self.lm.block_rows(self._gen_counts, self._lengths,
+                                       self._active, self._eos, self._temp,
+                                       self._greedy),
                     *self.lm._ad_args(self.session.adapters,
                                       self._adapter_idx.copy()),
                     *self.lm._gr_args(self.session.grammars,
@@ -4143,6 +4168,7 @@ class ServeEngine:
                                       gstate_in if gstate_in is not None
                                       else self._gstate.copy(),
                                       self._gbudget.copy()))
+            self._count_uploads(args)
             outs = self._dispatch("decode", lambda: fused(*args))
             self.session.cache = outs[1]
             rec = {"toks": outs[0], "nxt": outs[2], "done": outs[4],

@@ -29,7 +29,7 @@ from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
-from neuronx_distributed_tpu.inference import CausalLM
+from neuronx_distributed_tpu.inference import CausalLM, SlotSampler
 from neuronx_distributed_tpu.inference.paged_kernel import (
     paged_decode_attention,
     paged_kernel_refusal,
@@ -427,6 +427,84 @@ def test_mixtral_insert_holds_no_logits_but_the_last_positions(chip):
           f"the cache, temporaries {memory.temp_size_in_bytes / 2 ** 20:.0f} MiB")
     assert returned < 2 * rows * cfg.vocab_size * 2
     assert text.count("tpu_custom_call") >= 3    # flash forward, two grouped matmuls
+
+
+def _described_lm(chip, family, monkeypatch):
+    """``CausalLM`` of one of this file's configurations on the described
+    chip, shapes for parameters: two layers, bf16, pages of 16, batch 8."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from neuronx_distributed_tpu.inference import causal_lm
+    from neuronx_distributed_tpu.parallel import mesh
+
+    mesh.initialize_model_parallel(tensor_model_parallel_size=1,
+                                   devices=list(chip.device_set))
+    repl = NamedSharding(mesh.get_mesh(), PartitionSpec())
+    # the fused decode commits its example rows to the mesh with device_put,
+    # which a described device cannot take: hand it shapes
+    # (benchmark/aot_check.py does the same)
+    monkeypatch.setattr(causal_lm, "repl_args", lambda *xs: tuple(
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=repl) for x in xs))
+    if family == "llama":         # the long-context cell's widths: the walk is a loop
+        cfg, cls = LlamaConfig(
+            vocab_size=256, hidden_size=32 * HEAD_DIM, intermediate_size=1024,
+            num_heads=32, num_kv_heads=8, num_layers=2, max_seq_len=4096,
+            dtype=jnp.bfloat16, param_dtype=jnp.bfloat16), LlamaForCausalLM
+    elif family == "deepseek":    # the benchmark's rehearsal configuration
+        import json
+        from pathlib import Path
+
+        from benchmark import run as harness
+        from benchmark.drivers import serving
+
+        root = Path(harness.__file__).resolve().parents[1]
+        entry = next(c for c in json.loads((root / "BENCHMARK.json").read_text())["configs"]
+                     if c["name"] == "deepseek-v2")
+        loaded = harness.load_config(entry, rehearse=True)
+        cfg = serving.model_config(loaded, False, max_seq_len=4096, remat_policy=None)
+        cls = serving.load(loaded["builder"]["model"])
+    else:
+        cfg, cls = _moe_config(family, num_layers=2, max_seq_len=4096,
+                               dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
+        meta.unbox(jax.eval_shape(lambda: cls(cfg).init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))))["params"])
+    return CausalLM(cfg, params, cls, buckets=(128,), max_batch=8, page_size=16)
+
+
+@pytest.mark.parametrize("family", ["llama", "olmoe", "mixtral", "deepseek"])
+def test_fused_session_decode_takes_its_rows_as_one_matrix_and_donates_the_cache(
+        chip, family, monkeypatch):
+    """The fused session decode as ``CausalLM`` builds it, for the described
+    v5e: the six rows only the host writes arrive as ONE ``s32[6,8]``
+    parameter beside ``tok`` and ``done`` (ISSUE 47), and the new argument
+    did not disturb the donation of the cache, argument 1: the aliased bytes
+    cover every page leaf, the temporaries stay under one of them (where a
+    leaf is larger than the step's activations), and no computation but the
+    entry copies a leaf."""
+    lm = _described_lm(chip, family, monkeypatch)
+    compiled = lm.compile_session_decode_fused(8, SlotSampler(), 0)
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_fused_fn")
+    entry = re.search(r"^ENTRY .*?^}", text, re.M | re.S).group(0)
+    small = re.findall(r"= (\w+\[[0-9,]*\])\S* parameter\(", entry)
+    assert small.count("s32[6,8]") == 1                  # rows
+    assert small.count("s32[8,1]") == 1 and small.count("pred[8]") == 1   # tok, done
+    assert "f32[8]" not in small                         # no temperature of its own
+    leaves = [leaf for path, leaf in
+              jax.tree_util.tree_flatten_with_path(lm._cache_avals())[0]
+              if "cached_" in jax.tree_util.keystr(path)]
+    assert leaves
+    sizes = [math.prod(leaf.shape) * leaf.dtype.itemsize for leaf in leaves]
+    memory = compiled.memory_analysis()
+    print(f"{family} fused block: temporaries {memory.temp_size_in_bytes / 2 ** 20:.0f} MiB, "
+          f"a page leaf {min(sizes) / 2 ** 20:.0f} MiB")
+    assert memory.alias_size_in_bytes >= sum(sizes)
+    if family != "deepseek":      # the rehearsal's latent leaf is 8 MiB: under its activations
+        assert memory.temp_size_in_bytes < min(sizes)
+    for leaf in leaves:
+        assert not _leaf_copies(text, leaf.shape)
 
 
 def test_fused_adamw_leaf(chip):

@@ -256,10 +256,9 @@ def run_block(lm, fused, lengths, active, done, mapped=None):
     """One fused block of ``K`` greedy steps from the hand-made state."""
     rows = lm.max_batch
     outs = fused(lm.params, state(lm, lengths, mapped), jnp.ones((rows, 1), jnp.int32),
-                 jax.random.split(jax.random.key(1), rows), jnp.zeros((rows,), jnp.int32),
-                 jnp.asarray(lengths, jnp.int32), jnp.asarray(active), jnp.asarray(done),
-                 jnp.full((rows,), -1, jnp.int32), jnp.ones((rows,), jnp.float32),
-                 jnp.ones((rows,), bool))
+                 jax.random.split(jax.random.key(1), rows), jnp.asarray(done),
+                 lm.block_rows(np.zeros((rows,), np.int32), lengths, active,
+                               np.full((rows,), -1), np.ones((rows,)), np.ones((rows,), bool)))
     pools = {jax.tree_util.keystr(p): np.asarray(leaf, np.float32) for p, leaf in
              jax.tree_util.tree_flatten_with_path(outs[1])[0]
              if "cached_" in jax.tree_util.keystr(p)}

@@ -31,6 +31,7 @@ TINY = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2, 
             use_flash_attention=False, remat_policy=None)
 K = 4
 TILES = ("admit", "observe", "launch", "fetch", "harvest")
+HOST_ARGS = 3       # host arrays a fused block's call carries: tok, done, rows
 
 
 class Recorder:
@@ -59,12 +60,14 @@ def lm():
                     prefix_cache=True).compile()
 
 
-def _drive(lm, **engine_kw):
+def _drive(lm, prepare=None, **engine_kw):
     """Two requests at once, a third into the running batch (two rows
     decoding), a fourth that waits a round for a slot (one row left decoding):
     inserts with nobody to stall and with rows to stall, and a request that
-    queues."""
+    queues. ``prepare(engine)`` runs before the first round."""
     engine = ServeEngine(lm, block_steps=K, rng=jax.random.key(0), **engine_kw)
+    if prepare is not None:
+        prepare(engine)
     rng = np.random.RandomState(0)
     prompts = [rng.randint(1, 128, (n,)).astype(np.int32) for n in (9, 12, 20, 7)]
     ids = [engine.submit(p, max_new_tokens=budget, arrival_block=0)
@@ -142,6 +145,100 @@ def test_launch_contains_the_decode_dispatch_and_says_how_many_rows(traced):
     assert len(launches) == len(decodes) == engine.stats["decode_blocks"]
     for launch, decode in zip(launches, decodes):
         assert _inside(decode, launch) and 1 <= launch["args"]["active"] <= 3
+
+
+class NoEagerUpload:
+    """Stands in for ``jnp`` / ``jax`` in a module's namespace: the module as
+    it is, but the eager uploads raise while the guard is armed."""
+
+    def __init__(self, module, names, guard):
+        self._module, self._names, self._guard = module, names, guard
+
+    def __getattr__(self, name):
+        if name in self._names and self._guard["armed"]:
+            def refused(*a, **kw):
+                raise AssertionError(f"eager {name} between observe and the decode dispatch")
+            return refused
+        return getattr(self._module, name)
+
+
+@contextlib.contextmanager
+def no_eager_uploads():
+    """``jnp.asarray``, ``jnp.array`` and ``jax.device_put`` raise inside
+    ``inference/engine.py`` and ``inference/causal_lm.py`` from the end of an
+    engine's ``observe`` (with rows to decode) to its ``decode`` dispatch.
+    Yields ``watch(engine)``, which returns the list of the blocks whose launch
+    ran under the guard."""
+    from neuronx_distributed_tpu.inference import causal_lm as lm_mod, engine as engine_mod
+
+    guard = {"armed": False}
+
+    def watch(engine):
+        guarded = []
+        observe, dispatch = engine._observe_block, engine._dispatch
+
+        def observed():
+            observe()
+            guard["armed"] = bool(engine._active.any())
+
+        def dispatched(kind, fn):
+            if kind == "decode" and guard["armed"]:
+                guarded.append(engine.blocks)
+                guard["armed"] = False
+            return dispatch(kind, fn)
+
+        engine._observe_block, engine._dispatch = observed, dispatched
+        return guarded
+
+    with pytest.MonkeyPatch.context() as patch:
+        for mod in (engine_mod, lm_mod):
+            patch.setattr(mod, "jnp", NoEagerUpload(jnp, ("asarray", "array"), guard))
+            patch.setattr(mod, "jax", NoEagerUpload(jax, ("device_put",), guard))
+        try:
+            yield watch
+        finally:
+            guard["armed"] = False
+
+
+@pytest.fixture(scope="module")
+def guarded(lm):
+    with no_eager_uploads() as watch:
+        blocks = []
+        engine, _ = _drive(lm, prepare=lambda e: blocks.append(watch(e)), trace=True)
+    return engine, blocks[0]
+
+
+@pytest.mark.parametrize("insert_before", [True, False], ids=["after_an_insert", "no_insert"])
+def test_a_fused_block_launches_with_no_eager_upload(guarded, traced, insert_before):
+    """The rows' mirrors ride the program's own call (ISSUE 47): nothing is
+    uploaded by hand between ``observe`` and the ``decode`` dispatch, in a
+    round that inserted first and in one that did not."""
+    engine, under_guard = guarded
+    launches = _spans(engine, "phases", "launch")
+    assert under_guard == [span["block"] for span in launches]       # every block, guarded
+    inserted = {a["block"] for a in _spans(engine, "phases", "admission")}
+    mine = [span for span in launches if (span["block"] in inserted) == insert_before]
+    assert mine and all(span["args"]["host_args"] == HOST_ARGS for span in mine)
+    plain = traced[0]
+    assert ({c.request_id: c.tokens.tolist() for c in engine.completed}
+            == {c.request_id: c.tokens.tolist() for c in plain.completed})
+
+
+def test_block_uploads_adds_up_the_launches_host_args(traced):
+    engine, _, _ = traced
+    launches = _spans(engine, "phases", "launch")
+    assert engine.stats["block_uploads"] == sum(s["args"]["host_args"] for s in launches)
+    assert engine.stats["block_uploads"] == HOST_ARGS * engine.stats["decode_blocks"] > 0
+
+
+@pytest.mark.parametrize("engine_kw", [dict(fused=False), dict(async_loop=True)],
+                         ids=["stepwise", "async_loop"])
+def test_the_guard_bites_the_loops_that_still_upload_by_hand(lm, engine_kw):
+    """The stepwise oracle and the async loop are exempt from the rule, so
+    under the guard they are what a launch that uploads looks like."""
+    with no_eager_uploads() as watch:
+        with pytest.raises(AssertionError, match="eager (asarray|array|device_put) between"):
+            _drive(lm, prepare=watch, **engine_kw)
 
 
 @pytest.mark.parametrize("arg", ["rows", "bucket", "decoding", "rids"])

@@ -91,13 +91,13 @@ def _drive(lm, prompts, lengths, blocks=2):
     out = {"insert": np.asarray(lm.insert(session, slots, prompts, lengths=lengths, **kw))}
     fused = lm.compile_session_decode_fused(K, SlotSampler(), 0)
     tok = jnp.asarray(out["insert"].argmax(-1)[:, None], jnp.int32)
-    lens, counts = jnp.asarray(lengths, jnp.int32), jnp.zeros((B,), jnp.int32)
+    lens, counts = np.asarray(lengths, np.int32), np.zeros((B,), np.int32)
     done, tokens = jnp.zeros((B,), bool), []
     for _ in range(blocks):
         toks, session.cache, tok, lens, done = fused(
-            lm.params, session.cache, tok, jax.random.split(jax.random.key(1), B),
-            counts, lens, jnp.ones((B,), bool), done, jnp.full((B,), -1, jnp.int32),
-            jnp.ones((B,), jnp.float32), jnp.ones((B,), bool))[:5]
+            lm.params, session.cache, tok, jax.random.split(jax.random.key(1), B), done,
+            lm.block_rows(counts, lens, np.ones((B,), bool), np.full((B,), -1),
+                          np.ones((B,)), np.ones((B,), bool)))[:5]
         tokens.append(np.asarray(toks))
         counts = counts + K
     out["tokens"] = np.concatenate(tokens)

@@ -229,6 +229,37 @@ def test_bucketed_admission_batches_one_insert(lm):
     assert eng.stats["inserts"] == 1 and eng.stats["inserted_requests"] == 3
 
 
+# what a scheduler may hold in the six rows a fused block takes as ONE host
+# matrix: a per-request EOS beside none (-1), temperatures that are zero, no
+# small integer and tiny, counters at both ends of int32
+BLOCK_ROWS = dict(
+    counts=np.asarray([0, 3, 1000, 2 ** 31 - 1], np.int32),
+    lengths=np.asarray([1, 63, 4096, 0], np.int32),
+    active=np.asarray([True, False, True, True]),
+    eos_ids=np.asarray([-1, 2, 31999, -1], np.int32),
+    temperature=np.asarray([0.0, 0.7, 1e-3, 2.5], np.float32),
+    greedy=np.asarray([True, False, False, True]))
+
+
+@pytest.mark.parametrize("row", CausalLM.BLOCK_ROWS)
+def test_block_rows_reach_the_program_in_their_dtype_bit_for_bit(row):
+    """``CausalLM.block_rows`` on the host, then the program's own unpacking:
+    every row comes out in the dtype and with the bits it went up with before
+    it shared a matrix (ISSUE 47). Into a kept buffer or a new one alike."""
+    assert tuple(BLOCK_ROWS) == CausalLM.BLOCK_ROWS
+    packed = CausalLM.block_rows(**BLOCK_ROWS)
+    assert packed.dtype == np.int32 and packed.shape == (6, 4)
+    kept = np.full((6, 4), 77, np.int32)
+    assert CausalLM.block_rows(**BLOCK_ROWS, out=kept) is kept
+    assert kept.tobytes() == packed.tobytes()
+    for value in BLOCK_ROWS.values():
+        assert not np.shares_memory(packed, value)       # a copy: the async loop counts on it
+    got = dict(zip(CausalLM.BLOCK_ROWS, jax.jit(CausalLM.unpack_block_rows)(packed)))[row]
+    want = BLOCK_ROWS[row]
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.asarray(got).tobytes() == want.tobytes()
+
+
 def test_session_fused_overflow_guard_freezes_not_wraps(lm):
     """Device-side overflow guard: a slot driven to the cache edge inside a
     block freezes (done latch + pad emissions) instead of wrapping writes —
@@ -242,11 +273,9 @@ def test_session_fused_overflow_guard_freezes_not_wraps(lm):
     lengths = np.asarray([max_len - 2, 8, 8], np.int32)
     toks, cache, tok, out_len, done, walked = fused(
         lm.params, session.cache, jnp.zeros((3, 1), jnp.int32),
-        jax.random.split(jax.random.key(0), 3), jnp.ones((3,), jnp.int32),
-        jnp.asarray(lengths),
-        jnp.asarray([True, True, False]), jnp.zeros((3,), bool),
-        jnp.full((3,), -1, jnp.int32), jnp.zeros((3,), np.float32),
-        jnp.ones((3,), bool))
+        jax.random.split(jax.random.key(0), 3), jnp.zeros((3,), bool),
+        lm.block_rows(np.ones((3,), np.int32), lengths, [True, True, False],
+                      np.full((3,), -1), np.zeros((3,)), np.ones((3,), bool)))
     toks, done = np.asarray(toks), np.asarray(done)
     # how far the block read, in how many steps, over how many rows: one chunk (64
     # slots) of every row at each of the K steps, two of the three rows being live

@@ -11,8 +11,7 @@ holds, on seeded random weights:
   requests from ``synthetic_trace``/``run_trace``; the fused engine's
   streams against the stepwise oracle's, prefill logits against the plain
   float32 forward, the Pallas kernels looked up in the compiled programs,
-  no compile after warm-up; then the paged decode kernel (bf16 and int8
-  pages) against the gather path;
+  no compile after warm-up;
 * train: the ``llama2_tp_zero1`` example's model/optimizer/step
   construction, three steps at batch 8 x 2048 on one repeated batch.
 
@@ -61,15 +60,6 @@ PROBE_ROWS, PROBE_LEN, PROBE_STEPS = 4, 100, 16
 # <= 2^-9 relative and the residual stream is rounded a handful of times in
 # each of 16 layers. Measured 0.005.
 TOL_PREFILL_VS_F32 = 0.02
-# paged kernel on bf16 pages vs the gather path: the same pages, but the
-# kernel keeps scores and probabilities in float32 where the gather path's
-# dense attention rounds them to bf16, and the logits themselves are bf16
-# (one quantum at this scale is 0.006 of it). Measured 0.009.
-TOL_KERNEL_BF16 = 0.03
-# int8 pages add absmax quantisation of K and V (<= 1/254 of a page's
-# largest value per element): bounded divergence by design (verify skill,
-# "int8 KV pages"), compared at the decision level. Measured 0.009.
-TOL_KERNEL_INT8 = 0.04
 # TP=4 vs TP=1: the same math with bf16 partial sums reduced across four
 # chips in another order. Measured 0.009 on the logits. The loss is a mean
 # over 16k tokens, so the roundings average out: measured 1e-5 relative,
@@ -202,29 +192,17 @@ def serving_stack(sizes: Sizes, tp: int):
         max_seq_len=sizes.max_seq_len, max_batch=MAX_BATCH,
         tensor_parallel_size=tp, quantize=False, paged=True,
         page_size=PAGE_SIZE, page_pool_pages=sizes.page_pool_pages,
-        no_prefix_cache=False, kv_dtype=None, paged_kernel=False)
+        no_prefix_cache=False, kv_dtype=None)
     # flash attention is asked for by name, never derived from the backend
     cfg = dataclasses.replace(runner.build_config(args),
                               use_flash_attention=True, **sizes.widths)
     return runner.build_model(args, cfg)
 
 
-def kernel_variant(lm, cfg, sizes: Sizes, page_dtype):
-    """The same weights behind the paged decode kernel (library API: the
-    runner's builder would initialise a second copy of the weights)."""
-    from neuronx_distributed_tpu.inference import CausalLM
-    from neuronx_distributed_tpu.models.llama import LlamaForCausalLM
-
-    return CausalLM(cfg, lm.params, LlamaForCausalLM, buckets=lm.buckets,
-                    max_batch=MAX_BATCH, page_size=PAGE_SIZE,
-                    page_pool_pages=sizes.page_pool_pages,
-                    page_dtype=page_dtype, paged_attn_kernel=True)
-
-
-def make_trace(cfg, seed: int, n: int = NUM_REQUESTS):
+def make_trace(cfg, seed: int):
     from neuronx_distributed_tpu.inference.replay import synthetic_trace
 
-    return synthetic_trace(n, cfg.vocab_size, prompt_lens=PROMPT_LENS,
+    return synthetic_trace(NUM_REQUESTS, cfg.vocab_size, prompt_lens=PROMPT_LENS,
                            max_new_tokens=NEW_TOKENS, seed=seed)
 
 
@@ -369,12 +347,10 @@ def serve_phase(sizes: Sizes, seed: int, rehearse: bool, watch, devices) -> dict
                       for k, p in prefill.items())
 
     prompts = probe_prompts(cfg, seed)
-    ref_logits, ref_toks = probe(lm, prompts)
+    ref_logits, _ = probe(lm, prompts)
     vs_f32 = compare_logits(
         plain_forward_logits(lm, cfg, prompts[0]), ref_logits[0, 0],
         TOL_PREFILL_VS_F32, "prefill logits vs the plain float32 forward")
-    few = make_trace(cfg, seed, n=PROBE_ROWS)
-    few_ref, _ = serve(lm, few, seed)
     emit("serve", layers=cfg.num_layers, hidden=cfg.hidden_size,
          ffn=cfg.intermediate_size, heads=cfg.num_heads, vocab=cfg.vocab_size,
          requests=len(fused), new_tokens=NEW_TOKENS,
@@ -385,27 +361,6 @@ def serve_phase(sizes: Sizes, seed: int, rehearse: bool, watch, devices) -> dict
          wall_s=rep["wall_s"], host_ops_per_block=rep["host_ops_per_block"],
          flash_custom_calls_in_prefill=flash_calls,
          prefill_vs_float32_forward=vs_f32, memory=memory(devices))
-
-    for page_dtype, tol in ((None, TOL_KERNEL_BF16), ("int8", TOL_KERNEL_INT8)):
-        release()
-        klm = kernel_variant(lm, cfg, sizes, page_dtype).compile()
-        name = f"paged kernel, {page_dtype or 'bf16'} pages"
-        calls = require_mosaic(klm._decode.as_text(), 1, name + ": decode",
-                               rehearse)
-        got, rep = serve(klm, few, seed)
-        agreement = decision_agreement(few_ref, got)
-        check(agreement >= MIN_DECISION_AGREEMENT,
-              f"{name}: decision agreement with the gather path {agreement:.3f}")
-        logits, _ = probe(klm, prompts, forced=ref_toks)
-        emit("serve_paged_kernel", pages=page_dtype or "bf16",
-             page_size=PAGE_SIZE, custom_calls_in_decode=calls,
-             stream_decision_agreement=round(agreement, 4),
-             vs_gather=compare_logits(ref_logits, logits, tol,
-                                      name + " vs the gather path"),
-             tokens_per_sec=rep["tokens_per_sec"],
-             block_ms_p50=rep["itl_p50_ms"], compile_ms=klm.compile_ms,
-             memory=memory(devices))
-        del klm
 
 
 # ---------------------------------------------------------------- training

@@ -11,9 +11,6 @@ Subcommands:
 * ``benchmark`` — p50/p90/p95/p99 TTFT + per-token decode latency +
   end-to-end throughput per submodel (context-encoding vs token-gen — the
   reference reports the same split per model wrapper);
-* ``speculate`` — draft-assisted decoding (reference
-  ``run_llama_speculative.py``): pass --draft_layers to build a shallower
-  draft from the same config, or rely on the tiny self-draft demo;
 * ``check-accuracy`` — greedy-token match + logit divergence report vs an
   fp32 cache-free golden (or the fp32 ``transformers`` model with
   --hf_checkpoint) — reference ``check_accuracy``:290 /
@@ -185,12 +182,11 @@ def build_model(args, cfg=None):
 
         params = quantize_params(params)
     paged_kw = {}
-    # storage/kernel knobs imply paged mode — `serve --paged-kernel` or
-    # `serve --kv_dtype int8` alone gets the page pool they require
-    if getattr(args, "paged_kernel", False) or getattr(args, "kv_dtype", None):
+    # the storage knob implies paged mode: `serve --kv_dtype int8` alone
+    # gets the page pool it requires
+    if getattr(args, "kv_dtype", None):
         if args.cmd != "serve":
-            raise SystemExit("--paged-kernel/--kv_dtype apply to the serve "
-                             "subcommand only")
+            raise SystemExit("--kv_dtype applies to the serve subcommand only")
         args.paged = True
     if getattr(args, "paged", False):
         if args.cmd != "serve":
@@ -199,9 +195,7 @@ def build_model(args, cfg=None):
         paged_kw = dict(page_size=args.page_size,
                         page_pool_pages=args.page_pool_pages or None,
                         prefix_cache=not args.no_prefix_cache,
-                        page_dtype=getattr(args, "kv_dtype", None),
-                        paged_attn_kernel=getattr(args, "paged_kernel",
-                                                  False))
+                        page_dtype=getattr(args, "kv_dtype", None))
     if getattr(args, "adapters", 0) > 0:
         # multi-LoRA serving pool: N demo adapters share this one base
         # model via per-slot batched low-rank corrections (S-LoRA); the
@@ -334,141 +328,6 @@ def cmd_benchmark(args) -> None:
         report["decode_tokens_per_sec_fused"] = round(
             lm.max_batch / float(np.median(fused_ts)), 1)
     print(json.dumps(report))
-
-
-def cmd_speculate(args) -> None:
-    """Assisted decoding with a shallower draft model (same family/config,
-    fewer layers — the reference's speculative runner pairs a small draft
-    checkpoint with the target the same way). ``--fused_rounds R`` switches
-    to the single-program path (``speculative_decode_fused``): R complete
-    rounds per device dispatch, two host ops per block, token-identical to
-    the host loop."""
-    import dataclasses
-
-    from neuronx_distributed_tpu.inference.speculative import (
-        speculative_decode_fused,
-        speculative_generate,
-    )
-
-    if args.top_k or args.top_p < 1.0:
-        raise SystemExit("speculate supports --sample with --temperature only "
-                         "(top_k/top_p acceptance is not implemented)")
-    lm, cfg = build_model(args)
-    draft_layers = (args.draft_layers if args.draft_layers is not None
-                    else max(1, cfg.num_layers // 4))
-    if not 1 <= draft_layers < cfg.num_layers:
-        raise SystemExit(
-            f"--draft_layers must be in [1, {cfg.num_layers - 1}] "
-            f"(target has {cfg.num_layers} layers), got {draft_layers}"
-        )
-    draft_cfg = dataclasses.replace(cfg, num_layers=draft_layers)
-    # tiny demo: the draft reuses the target's params truncated to its depth
-    draft_params = jax.tree.map(
-        lambda p: p[: draft_cfg.num_layers] if (
-            hasattr(p, "shape") and p.ndim > 0 and p.shape[0] == cfg.num_layers
-        ) else p,
-        lm.params,
-    )
-    draft = CausalLM(draft_cfg, draft_params, _model_cls(args),
-                     buckets=lm.buckets, max_batch=lm.max_batch,
-                     param_transform=lm.param_transform)
-    rs = np.random.RandomState(args.seed)
-    prompt_len = 16 if args.tiny else 128
-    prompt = rs.randint(1, cfg.vocab_size, (1, prompt_len)).astype(np.int32)
-    # warmup compiles every program (target/draft prefill+decode, proposer,
-    # chunk verify / the fused R-round block) OUTSIDE the timed window —
-    # cmd_generate's discipline
-    if args.fused_rounds > 0:
-        run = lambda n, rng, stats=False: speculative_decode_fused(  # noqa: E731
-            lm, draft, prompt, max_new_tokens=n,
-            num_draft=args.num_draft, rounds_per_block=args.fused_rounds,
-            greedy=not args.sample, temperature=args.temperature, rng=rng,
-        )
-    else:
-        run = lambda n, rng, stats=False: speculative_generate(  # noqa: E731
-            lm, draft, prompt, max_new_tokens=n,
-            num_draft=args.num_draft, greedy=not args.sample,
-            temperature=args.temperature, rng=rng, collect_stats=stats,
-        )
-    run(2, jax.random.key(args.seed + 1))
-    # timed pass WITHOUT the per-submodel syncs (they add 2 host round-trips
-    # per round and would bias tokens_per_sec down); a second short
-    # instrumented pass supplies the draft/verify percentiles
-    t0 = time.perf_counter()
-    result = run(args.max_new_tokens, jax.random.key(args.seed))
-    dt = time.perf_counter() - t0
-    instr = run(min(args.max_new_tokens, 16), jax.random.key(args.seed),
-                stats=True)
-    sub = {k: v for k, v in (instr.stats or {}).items()
-           if k.startswith(("draft_ms", "verify_ms"))}
-    print(json.dumps({
-        "generated": result.tokens[0][: int(result.lengths[0])].tolist(),
-        "tokens_per_sec": round(int(result.lengths[0]) / dt, 1),
-        "draft_layers": draft_cfg.num_layers,
-        "num_draft": args.num_draft,
-        # acceptance + per-submodel p50/p90 (reference benchmark.py:55-71
-        # percentile report applied to the speculation submodels)
-        **(result.stats or {}),
-        **sub,
-    }))
-
-
-def cmd_medusa(args) -> None:
-    """Medusa tree decoding (reference speculative runner's medusa mode,
-    utils/speculative_decoding.py:189). Heads are RANDOMLY initialized (no
-    head-checkpoint loading is wired), so acceptance is near zero — but
-    Medusa's greedy-posterior invariant guarantees the OUTPUT equals the
-    base model's greedy continuation regardless; the per-round p50s (which
-    exclude the first round's compile) show the machinery's cost. No
-    end-to-end tok/s is reported: medusa_generate builds its programs per
-    call, so a wall-clock over the call would mostly measure compilation."""
-    import dataclasses
-
-    from flax.core import meta
-
-    from neuronx_distributed_tpu.inference.medusa import (
-        DEFAULT_CHOICES,
-        MedusaLlamaForCausalLM,
-        medusa_generate,
-    )
-    from neuronx_distributed_tpu.parallel import mesh as ps
-
-    if args.model != "llama":
-        raise SystemExit("medusa supports --model llama")
-    if args.hf_checkpoint or getattr(args, "quantize", False) or args.sample:
-        raise SystemExit(
-            "medusa supports none of --hf_checkpoint/--quantize/--sample "
-            "(random heads, greedy posterior)")
-    cfg = build_config(args)
-    tp = _tp(args)
-    if not ps.model_parallel_is_initialized():
-        ps.initialize_model_parallel(tensor_model_parallel_size=tp)
-    mm = MedusaLlamaForCausalLM(
-        dataclasses.replace(cfg, decode=True), num_medusa_heads=2)
-    ids0 = jnp.zeros((1, 8), jnp.int32)
-    mparams = meta.unbox(jax.jit(
-        lambda: mm.init(jax.random.key(args.seed), ids0))())["params"]
-    rs = np.random.RandomState(args.seed)
-    prompt_len = 16 if args.tiny else 128
-    prompt = rs.randint(1, cfg.vocab_size, (1, prompt_len)).astype(np.int32)
-    result = medusa_generate(
-        cfg, mparams, prompt, max_new_tokens=args.max_new_tokens,
-        num_medusa_heads=2, medusa_choices=DEFAULT_CHOICES)
-
-    # invariant check: output == the base model's greedy continuation
-    base_params = {k: v for k, v in mparams.items() if not k.startswith("medusa")}
-    lm = CausalLM(cfg, base_params, _model_cls(args),
-                  buckets=(prompt_len,), max_batch=1)
-    golden = lm.generate(prompt, max_new_tokens=args.max_new_tokens)
-    n = int(result.lengths[0])
-    exact = bool(np.array_equal(result.tokens[0][:n], golden.tokens[0][:n]))
-    print(json.dumps({
-        "generated": result.tokens[0][:n].tolist(),
-        "matches_base_greedy": exact,
-        **(result.stats or {}),
-    }))
-    if not exact:
-        raise SystemExit(1)
 
 
 def cmd_serve(args) -> None:
@@ -876,8 +735,7 @@ def cmd_check_accuracy(args) -> None:
 def main(argv=None) -> None:
     parser = argparse.ArgumentParser(description=__doc__)
     sub = parser.add_subparsers(dest="cmd", required=True)
-    for name in ("generate", "benchmark", "speculate", "medusa",
-                 "check-accuracy", "serve"):
+    for name in ("generate", "benchmark", "check-accuracy", "serve"):
         p = sub.add_parser(name)
         p.add_argument("--tensor_parallel_size", "--tp", type=int, default=None,
                        help="default: every device this process sees")
@@ -900,16 +758,10 @@ def main(argv=None) -> None:
         p.add_argument("--top_k", type=int, default=0)
         p.add_argument("--top_p", type=float, default=1.0)
         p.add_argument("--seed", type=int, default=0)
-        p.add_argument("--num_draft", type=int, default=4)
         p.add_argument("--fused_chunk", type=int, default=0,
                        help="K>1: decode in K-step fused device programs "
                             "(one dispatch per K tokens; any sampler, "
                             "per-token EOS)")
-        p.add_argument("--fused_rounds", type=int, default=0,
-                       help="speculate: R>0 runs R complete speculative "
-                            "rounds per device dispatch "
-                            "(speculative_decode_fused)")
-        p.add_argument("--draft_layers", type=int, default=None)
         p.add_argument("--fused_steps", type=int, default=8,
                        help="serve: K decode steps per device dispatch for "
                             "the whole slot pool (the fused-K knob)")
@@ -950,13 +802,6 @@ def main(argv=None) -> None:
                        help="serve --paged: per-layer pool size in pages "
                             "(0 = slab parity; smaller = the HBM win, "
                             "admission defers under pool pressure)")
-        p.add_argument("--paged-kernel", dest="paged_kernel",
-                       action="store_true",
-                       help="serve: fused paged decode-attention kernel "
-                            "(Pallas; interpret mode off-TPU) — decode "
-                            "steps attend straight off the page pool "
-                            "through the block tables, no logical-slab "
-                            "gather. Implies --paged.")
         p.add_argument("--kv_dtype", choices=["float32", "int8"],
                        default=None,
                        help="serve: KV page storage dtype. int8 stores "
@@ -1192,7 +1037,6 @@ def main(argv=None) -> None:
 
         place_compile_cache()
     {"generate": cmd_generate, "benchmark": cmd_benchmark,
-     "speculate": cmd_speculate, "medusa": cmd_medusa,
      "check-accuracy": cmd_check_accuracy, "serve": cmd_serve}[args.cmd](args)
 
 

@@ -85,7 +85,7 @@ def _names_from_tree(tree: ast.AST) -> Tuple[Set[str], Set[str], List[str]]:
     """(stats keys, event names, event f-string prefixes) produced by one
     file. Producers of a stats key: a ``stats`` subscript (``self.stats``
     or a bare ``stats`` dict), a dict literal assigned/returned as
-    ``stats`` (the speculative/medusa result-stats idiom), or the
+    ``stats`` (``self.stats = {...}``), or the
     ``_STAT_KEYS`` registry literal."""
     stats: Set[str] = set()
     events: Set[str] = set()

@@ -1,7 +1,6 @@
 """Inference stack (reference ``trace/`` + ``examples/inference/modules``;
 SURVEY §3.5): AOT builder with shape router, KV-cached CausalLM serving,
-samplers, the continuous-batching engine (``engine.py``). Speculative
-decoding in ``speculative.py``. The replay driver (``replay.py``: load
+samplers, the continuous-batching engine (``engine.py``). The replay driver (``replay.py``: load
 generator and serving reports) sits above the engine, the router and the
 disaggregated fleet and is loaded only when one of its names is asked for."""
 

@@ -50,9 +50,7 @@ def replicate_out(tree: PyTree) -> PyTree:
     must route it through this constraint — the AOT session programs are
     lowered on replicated cache avals, and an unconstrained output lets
     GSPMD hand back a sharded layout the next call rejects (the PR 3
-    class; statically enforced by nxdcheck's cache-replication rule).
-    Module-level so standalone program builders (``inference/medusa.py``)
-    share the exact constraint ``CausalLM`` uses."""
+    class; statically enforced by nxdcheck's cache-replication rule)."""
     from neuronx_distributed_tpu.parallel import mesh as ps
 
     if not ps.model_parallel_is_initialized():
@@ -175,21 +173,14 @@ def _routing_sums(chosen: jax.Array, routed: Optional[jax.Array],
 def _walk_sums(config, cache: PyTree, live: jax.Array) -> jax.Array:
     """``(3,) int32`` of one decode step: the slots of the cache the step read
     of its longest row (:class:`~neuronx_distributed_tpu.models.llama.KVWalk`,
-    chunk rounding included; all of ``max_seq_len`` under
-    ``paged_attn_kernel``, whose grid visits every page), 1, and the slots it
-    read summed over its rows (the walk's rung of rows for every chunk read;
-    every row under the kernel); all 0 where no row is live. From the cache's
-    own ``cache_index`` and the ``live`` the model is given, so they are the
-    bounds the attention computed."""
+    chunk rounding included), 1, and the slots it read summed over its rows
+    (the walk's rung of rows for every chunk read); all 0 where no row is
+    live. From the cache's own ``cache_index`` and the ``live`` the model is
+    given, so they are the bounds the attention computed."""
     idx = next(leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
                if jax.tree_util.keystr(path).endswith("['cache_index']"))[0]
-    if config.paged_attn_kernel:
-        tokens = jnp.int32(config.max_seq_len)
-        row_slots = tokens * idx.shape[0]
-    else:
-        walk = kv_walk(config, idx, live)
-        tokens, row_slots = walk.tokens, walk.row_slots
-    return jnp.stack([tokens, 1, row_slots]).astype(jnp.int32) * jnp.any(live)
+    walk = kv_walk(config, idx, live)
+    return jnp.stack([walk.tokens, 1, walk.row_slots]).astype(jnp.int32) * jnp.any(live)
 
 
 def infer_prompt_lengths(prompt_ids: np.ndarray, pad_token_id: int = 0) -> np.ndarray:
@@ -206,16 +197,6 @@ def infer_prompt_lengths(prompt_ids: np.ndarray, pad_token_id: int = 0) -> np.nd
 class GenerationResult:
     tokens: np.ndarray          # (b, max_new_tokens), eos-padded
     lengths: np.ndarray         # (b,) generated lengths incl. eos
-    # speculation paths attach per-run metrics (rounds, proposed/accepted
-    # counts, per-round wall times) — the reference benchmark's
-    # per-submodel report surface (examples/inference/runner.py:454-530)
-    stats: Optional[dict] = None
-
-
-def percentile_ms(ts, q) -> Optional[float]:
-    """q-th percentile of a list of second-timings, in ms (None when empty) —
-    the speculation paths' shared stats helper."""
-    return round(float(np.percentile(np.asarray(ts) * 1e3, q)), 2) if ts else None
 
 
 @dataclasses.dataclass
@@ -285,7 +266,6 @@ class CausalLM:
         page_size: Optional[int] = None,
         page_pool_pages: Optional[int] = None,
         page_dtype: Optional[str] = None,
-        paged_attn_kernel: bool = False,
         prefix_cache: bool = True,
         lora_rank: Optional[int] = None,
         lora_slots: int = 0,
@@ -337,34 +317,17 @@ class CausalLM:
             pool = page_pool_pages or (
                 max_batch * (self.config.max_seq_len // page_size) + max_batch)
             over = dict(page_size=int(page_size), page_pool_pages=int(pool))
-            # int8 page storage + the fused decode kernel are paged-mode
-            # knobs; replace() only when set so non-Llama configs without
-            # the fields keep working un-paged.
+            # int8 page storage is a paged-mode knob; replace() only when
+            # set so non-Llama configs without the field keep working.
             if page_dtype is not None:
                 if page_dtype not in ("int8", "float32"):
                     raise ValueError(
                         f"page_dtype must be 'int8' or 'float32', "
                         f"got {page_dtype!r}")
                 over["page_dtype"] = page_dtype
-            if paged_attn_kernel:
-                from neuronx_distributed_tpu.inference.paged_kernel import (
-                    paged_kernel_refusal,
-                )
-
-                c = self.config
-                why = paged_kernel_refusal(
-                    int(page_size), c.num_heads,
-                    c.num_kv_heads * c.kv_size_multiplier, c.head_dim_,
-                    jnp.int8 if page_dtype == "int8"
-                    else (page_dtype or c.dtype))
-                if why:
-                    raise ValueError(f"paged_attn_kernel refused: {why}")
-                over["paged_attn_kernel"] = True
             self.config = dataclasses.replace(self.config, **over)
-        elif page_dtype or paged_attn_kernel:
-            raise ValueError(
-                "page_dtype / paged_attn_kernel require paged mode "
-                "(pass page_size)")
+        elif page_dtype:
+            raise ValueError("page_dtype requires paged mode (pass page_size)")
         # multi-LoRA serving (inference/adapters.py): the config grows the
         # pool dims so every targeted projection declares its per-slot A/B
         # stacks; each session then owns an AdapterPool whose tree rides

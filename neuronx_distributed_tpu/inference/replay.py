@@ -574,11 +574,8 @@ def run_trace(engine: ServeEngine, trace: List[dict],
             "paged": True,
             "page_size": pkv.page_size,
             "page_pool_pages": pkv.num_pages,
-            # storage + kernel knobs (ISSUE 17): what the pool bytes
-            # below were measured under
+            # what the pool bytes below were measured under
             "page_dtype": engine._page_dtype(),
-            "paged_attn_kernel": bool(
-                getattr(engine.lm.config, "paged_attn_kernel", False)),
             "prefix_queries": pkv.stats["prefix_queries"],
             "prefix_hits": pkv.stats["prefix_hits"],
             "prefix_hit_tokens": pkv.stats["prefix_hit_tokens"],

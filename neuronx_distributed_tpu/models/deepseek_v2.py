@@ -39,7 +39,7 @@ What is this file's and what is the stack's:
 
 Not here: ``tp > 1`` serving (the latent leaf has one head and is replicated,
 ``inference/partition.py``; the projections are not partitioned for it), int8
-latent pages (refused), Medusa tree chunks, LoRA on the latent projections.
+latent pages (refused), LoRA on the latent projections.
 """
 
 from __future__ import annotations
@@ -171,7 +171,7 @@ class DeepseekV2Attention(nn.Module):
     config: DeepseekV2Config
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope, chunk_ctx=None,
+    def __call__(self, x: jax.Array, rope,
                  kv: Optional[KVLayerView] = None, live=None) -> jax.Array:
         cfg = self.config
         n, nope, rd, vd = (cfg.num_heads, cfg.qk_nope_head_dim,
@@ -206,7 +206,7 @@ class DeepseekV2Attention(nn.Module):
             c_kv = norm("kv_a_norm")(down[..., :rank])                 # (b, s, rank)
             k_r = down[..., None, rank:]                               # (b, s, 1, rope)
         if cfg.decode:
-            o = self._cached(q, c_kv, k_r, w_uk, w_uv, kv, chunk_ctx, live)
+            o = self._cached(q, c_kv, k_r, w_uk, w_uv, kv, live)
         else:
             cos, sin = rope
             q = jnp.concatenate(
@@ -249,7 +249,7 @@ class DeepseekV2Attention(nn.Module):
                 use_flash=flash, block_q=blk_q, block_k=blk_k, q_positions=positions[None])
         return o[0].transpose(1, 0, 2)[..., :vd]
 
-    def _cached(self, q, c_kv, k_r, w_uk, w_uv, kv, chunk_ctx, live=None):
+    def _cached(self, q, c_kv, k_r, w_uk, w_uv, kv, live=None):
         """The serving path: the new tokens' ``[c_kv | k_rope]`` go into the
         latent leaf at their slots (through the block table where paged), and
         the queries attend over what the leaf then holds."""
@@ -259,8 +259,6 @@ class DeepseekV2Attention(nn.Module):
                 "decode-mode attention reads and writes the latent leaf that "
                 "LlamaModel declares and its layer scan carries; apply it "
                 "through LlamaModel (or pass a KVLayerView)")
-        if chunk_ctx is not None:
-            raise ValueError("Medusa tree chunks are not supported with latent attention")
         b, s_new = q.shape[:2]
         nope, dim = cfg.qk_nope_head_dim, cfg.latent_dim
         ps, S = cfg.page_size, cfg.max_seq_len
@@ -342,11 +340,10 @@ class DeepseekV2DenseLayer(nn.Module):
     config: DeepseekV2Config
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope, chunk_ctx=None, kv=None,
-                 live=None) -> jax.Array:
+    def __call__(self, x: jax.Array, rope, kv=None, live=None) -> jax.Array:
         cfg = self.config
         h = cfg.make_norm(name="input_norm")(x)
-        x = x + DeepseekV2Attention(cfg, name="attention")(h, rope, chunk_ctx, kv, live)
+        x = x + DeepseekV2Attention(cfg, name="attention")(h, rope, kv, live)
         h = cfg.make_norm(name="post_attn_norm")(x)
         return x + LlamaMLP(cfg, name="mlp")(h)
 

@@ -75,7 +75,7 @@ class GPTNeoXAttention(nn.Module):
     config: GPTNeoXConfig
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope, chunk_ctx=None) -> jax.Array:
+    def __call__(self, x: jax.Array, rope) -> jax.Array:
         cfg = self.config
         if cfg.decode:
             raise NotImplementedError(
@@ -151,13 +151,13 @@ class GPTNeoXDecoderLayer(nn.Module):
     config: GPTNeoXConfig
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope, chunk_ctx=None) -> jax.Array:
+    def __call__(self, x: jax.Array, rope) -> jax.Array:
         cfg = self.config
         h_attn = SPLayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                              param_dtype=cfg.param_dtype,
                              sequence_parallel=cfg.sequence_parallel,
                              name="input_norm")(x)
-        attn_out = GPTNeoXAttention(cfg, name="attention")(h_attn, rope, chunk_ctx)
+        attn_out = GPTNeoXAttention(cfg, name="attention")(h_attn, rope)
         if cfg.use_parallel_residual:
             h_mlp = SPLayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                                 param_dtype=cfg.param_dtype,

@@ -408,12 +408,12 @@ class GraniteLayer(nn.Module):
     kind: str
 
     @nn.compact
-    def __call__(self, x, cache=None, live=None, rope=None, chunk_ctx=None):
+    def __call__(self, x, cache=None, live=None, rope=None):
         cfg = self.config
         view = None if cache is None else KVLayerView(*cache)
         h = cfg.make_norm(name="input_norm")(x)
         mixed = (Mamba2Mixer(cfg, name="mamba")(h, view, live) if self.kind == MAMBA
-                 else LlamaAttention(cfg, name="attention")(h, rope, chunk_ctx, view, live))
+                 else LlamaAttention(cfg, name="attention")(h, rope, view, live))
         x = x + cfg.residual_multiplier * mixed
         h = cfg.make_norm(name="post_mixer_norm")(x)
         x = x + cfg.residual_multiplier * LlamaMLP(cfg, name="mlp")(h)
@@ -440,7 +440,7 @@ class _Period(nn.Module):
     config: GraniteHybridConfig
 
     @nn.compact
-    def __call__(self, carry, rope=None, chunk_ctx=None, live=None):
+    def __call__(self, carry, rope=None, live=None):
         cfg = self.config
         x, caches = carry
         caches = dict(caches or {})
@@ -450,7 +450,7 @@ class _Period(nn.Module):
                 x, leaves = _JitLayer(cfg, kind, name=f"{kind}_{at}")(x, cache, live)
             else:
                 x, leaves = GraniteLayer(cfg, kind, name=f"{kind}_{at}")(
-                    x, cache, live, rope, chunk_ctx)
+                    x, cache, live, rope)
             if cache is not None:
                 caches[kind] = (cache[0] + 1, leaves)
         return (x, caches or None), None
@@ -477,14 +477,11 @@ class GraniteHybridModel(nn.Module):
         self.final_norm = cfg.make_norm()
 
     @nn.compact
-    def __call__(self, input_ids: jax.Array, chunk_ctx=None, live=None) -> jax.Array:
+    def __call__(self, input_ids: jax.Array, live=None) -> jax.Array:
         cfg = self.config
         b, s = input_ids.shape
         if s > cfg.max_seq_len:
             raise ValueError(f"sequence length {s} exceeds max_seq_len {cfg.max_seq_len}")
-        if chunk_ctx is not None and cfg.slot_row_leaves:
-            raise ValueError("a tree step has no order for a recurrence to follow: "
-                             "per-slot state takes no chunk_ctx")
         x = self.embed(input_ids)
         x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
         rope = None
@@ -503,7 +500,7 @@ class GraniteHybridModel(nn.Module):
                 kind: (jnp.int32(0), {n: v.value for n, v in pools.items()
                                       if (n in rows) == (kind == MAMBA)})
                 for kind in (ATTENTION, MAMBA) if cfg.layers_of(kind)}
-        args = (rope, chunk_ctx, live)
+        args = (rope, live)
         while args and args[-1] is None:
             args = args[:-1]
         (x, caches), _ = self.periods((x, caches), *args)

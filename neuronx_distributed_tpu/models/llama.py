@@ -164,16 +164,8 @@ class LlamaConfig:
     # per-(page, head) fp32 scales as sibling cache leaves
     # (``cached_key_scale``/``cached_value_scale``) — ~4x fewer pool
     # bytes than fp32 pages at the same page count, dequantized at the
-    # attention read (inside the kernel tile on the kernel path).
+    # attention read.
     page_dtype: Optional[str] = None
-    # fused paged decode attention (inference/paged_kernel.py): the
-    # single-token decode step attends straight off the page pool through
-    # the block tables (block-sparse flash tiling, every row to its own
-    # length) instead of gathering the live rows' pages in-scan up to the
-    # longest one's (KVWalk). Prefill/chunk widths and Medusa tree steps keep the
-    # gather path — which also stays, at fp32 pages, the bit-exactness
-    # reference oracle for this branch.
-    paged_attn_kernel: bool = False
     # multi-LoRA serving pool (inference/adapters.py, S-LoRA/Punica): every
     # targeted projection gains per-slot low-rank stacks A (lora_slots,
     # fan_in, lora_rank) / B (lora_slots, lora_rank, fan_out) + scale on a
@@ -329,7 +321,7 @@ def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
-def cached_attention(q, k_cache, v_cache, cache_len, sm_scale=None, mask=None):
+def cached_attention(q, k_cache, v_cache, cache_len, sm_scale=None):
     """Decode/prefill attention against a fixed-size KV cache.
 
     ``q``: (b, s_new, n, d) — queries at absolute positions
@@ -342,11 +334,7 @@ def cached_attention(q, k_cache, v_cache, cache_len, sm_scale=None, mask=None):
     Grouped by KV head: query head ``h`` reads KV head ``h // group`` through
     a (b, s_new, n_kv, group, d) view of ``q``, so K and V are read once, in
     the dtype the cache holds (widened inside the matmul, never in memory);
-    accumulation, scores, mask and softmax are float32.
-
-    An explicit ``mask`` (b, s_new, S_max) overrides the positional default —
-    Medusa tree steps attend by tree ancestry, not linear position
-    (reference ``medusa_attn_mask``, utils/medusa_utils.py:59-73)."""
+    accumulation, scores, mask and softmax are float32."""
     b, s_new, n, d = q.shape
     s_max, n_kv = k_cache.shape[1:3]
     if sm_scale is None:
@@ -359,10 +347,9 @@ def cached_attention(q, k_cache, v_cache, cache_len, sm_scale=None, mask=None):
     exact = dict(preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST)
     scores = jnp.einsum("bikgd,bjkd->bkgij", q.reshape(b, s_new, n_kv, n // n_kv, d),
                         k_cache, **exact) * sm_scale
-    if mask is None:
-        qpos = cache_len[:, None] + jnp.arange(s_new)[None, :]  # (b, s_new)
-        kpos = jnp.arange(s_max)
-        mask = kpos[None, None, :] <= qpos[..., None]           # (b, s_new, s_max)
+    qpos = cache_len[:, None] + jnp.arange(s_new)[None, :]      # (b, s_new)
+    kpos = jnp.arange(s_max)
+    mask = kpos[None, None, :] <= qpos[..., None]               # (b, s_new, s_max)
     scores = jnp.where(mask[:, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgij,bjkd->bikgd", probs, v_cache, **exact)
@@ -509,14 +496,12 @@ class KVWalk:
       reads what a row that is not live computes). The top rung is the batch
       as it stands: nothing sorted, picked or put back.
 
-    Without ``live`` (``lm.step``, ``generate``, the speculative drafts)
-    every row counts: one rung, as far as the longest row. So does a table of
-    one chunk.
+    Without ``live`` (``lm.step``, ``generate``) every row counts: one rung,
+    as far as the longest row. So does a table of one chunk.
 
     Two forms inside one program: ``fold`` (a loop with a traced trip count
-    and a running softmax, the arithmetic of
-    ``paged_kernel.py::_decode_kernel``: no slab of keys or values is ever
-    held, but a turn has a fixed cost and the accumulator rides every turn)
+    and a running softmax: no slab of keys or values is ever held, but a
+    turn has a fixed cost and the accumulator rides every turn)
     and ``prefix`` (a ``lax.switch`` over the static prefixes of the table:
     no carried state and the one-pass softmax, at the price of a slab as long
     as the prefix). ``loops`` says which this walk takes: by the length of a
@@ -739,12 +724,9 @@ class LlamaAttention(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope, chunk_ctx=None,
+    def __call__(self, x: jax.Array, rope,
                  kv: Optional[KVLayerView] = None, live=None) -> jax.Array:
-        """``chunk_ctx`` (decode only): ``(chunk_mask (s,s) bool,
-        chunk_positions (s,) int32)`` for Medusa tree steps — intra-chunk
-        visibility by tree ancestry and RoPE positions by tree depth.
-        ``kv`` (decode only): this layer's view of the carried KV leaves.
+        """``kv`` (decode only): this layer's view of the carried KV leaves.
         ``live`` (b, s) bool, where a serving program gives it: the rows a
         one-token step advances for someone (:class:`KVWalk`)."""
         cfg = self.config
@@ -787,7 +769,7 @@ class LlamaAttention(nn.Module):
             k = jnp.clip(k, -cfg.qkv_clip, cfg.qkv_clip)
             v = jnp.clip(v, -cfg.qkv_clip, cfg.qkv_clip)
         if cfg.decode:
-            return self._decode_attention(x, q, k, v, kv, chunk_ctx, aidx, live)
+            return self._decode_attention(x, q, k, v, kv, aidx, live)
         if cfg.use_rope:
             cos, sin = rope  # computed once in LlamaModel, broadcast through scan
             q = apply_rotary(q, cos, sin)
@@ -898,8 +880,7 @@ class LlamaAttention(nn.Module):
                                      aidx).astype(y.dtype)
         return y
 
-    def _decode_attention(self, x, q, k, v, kv, chunk_ctx=None, aidx=None,
-                          live=None):
+    def _decode_attention(self, x, q, k, v, kv, aidx=None, live=None):
         """KV-cached path (flax ``cache`` collection; the reference keeps KV
         state in aliased runtime buffers, model_base.py KV management —
         donation of the cache collection is the TPU analogue). The K/V
@@ -907,15 +888,13 @@ class LlamaAttention(nn.Module):
         stack of every layer's, carried by the layer loop.
 
         The new tokens' K/V are written first; what is then read depends on
-        the step. One new token a row and no tree (the decode step): chunks
-        of whole pages up to the reach of the longest LIVE row, of the rung
-        of rows that holds the live ones (:class:`KVWalk`,
-        ``_walk_attention``; ``live`` (b, s) is the serving program's, None
-        counts every row; a row that is not live gets zeros where its rung
-        leaves it out), or the paged kernel. A prompt, a
-        chunk, a speculative or tree step (``s_new > 1`` or ``chunk_ctx``),
-        and any table of a single chunk: all ``max_seq_len`` slots behind the
-        mask, as ever."""
+        the step. One new token a row (the decode step): chunks of whole
+        pages up to the reach of the longest LIVE row, of the rung of rows
+        that holds the live ones (:class:`KVWalk`, ``_walk_attention``;
+        ``live`` (b, s) is the serving program's, None counts every row; a
+        row that is not live gets zeros where its rung leaves it out). A
+        prompt or a chunk (``s_new > 1``) and any table of a single chunk:
+        all ``max_seq_len`` slots behind the mask, as ever."""
         cfg = self.config
         b = x.shape[0]
         s_new = x.shape[1]
@@ -948,13 +927,11 @@ class LlamaAttention(nn.Module):
                            lambda: jnp.zeros((b,), jnp.int32))
         idx = ci.value                                            # (b,)
         # unified write: s_new tokens land at SLOTS idx..idx+s_new per slot —
-        # covers prefill (idx=0), single-token decode, multi-token
-        # speculative verification chunks, Medusa tree chunks (reference
-        # CTX/TKG/speculation submodels + scatter_index, model_wrapper.py),
-        # AND chunked-prefill extends (idx = tokens already written: a
-        # partial-length continuation whose queries attend both the
-        # already-written prefix and, causally, each other). Tree steps
-        # decouple the RoPE POSITION (tree depth) from the slot.
+        # covers prefill (idx=0), single-token decode (reference CTX/TKG
+        # submodels + scatter_index, model_wrapper.py) AND chunked-prefill
+        # extends (idx = tokens already written: a partial-length
+        # continuation whose queries attend both the already-written prefix
+        # and, causally, each other).
         #
         # Partial-length masking contract (what makes chunked prefill exact):
         # only positions < the row's TRUE length are ever visible — query i
@@ -964,17 +941,10 @@ class LlamaAttention(nn.Module):
         # K/V only at slots STRICTLY ABOVE every real query position, where
         # it sits behind the mask exactly like the slab's unwritten zeros
         # until a later chunk / decode step overwrites it.
-        chunk_mask = chunk_positions = None
-        if chunk_ctx is not None:
-            chunk_mask, chunk_positions = chunk_ctx
         slots = idx[:, None] + jnp.arange(s_new, dtype=jnp.int32)[None, :]
-        if chunk_positions is None:
-            positions = slots
-        else:
-            positions = idx[:, None] + chunk_positions[None, :].astype(jnp.int32)
         rows = jnp.arange(b)[:, None]
         if cfg.use_rope:
-            cos, sin = rotary_embedding(positions, hd, cfg.rope_theta, dtype=q.dtype,
+            cos, sin = rotary_embedding(slots, hd, cfg.rope_theta, dtype=q.dtype,
                                         scaling=cfg.rope_scaling)
             q = apply_rotary(q, cos, sin)
             k = apply_rotary(k, cos, sin)
@@ -994,7 +964,7 @@ class LlamaAttention(nn.Module):
                     first = idx // ps                                  # (b,)
                     lpage = (first[:, None]
                              + jnp.arange(W, dtype=jnp.int32)[None, :])  # (b, W)
-                    from neuronx_distributed_tpu.inference.paged_kernel import (
+                    from neuronx_distributed_tpu.inference.kv_quant import (
                         dequantize_kv_pages,
                         quantize_kv_pages,
                     )
@@ -1044,7 +1014,6 @@ class LlamaAttention(nn.Module):
                         by_slot = kv.flat(name).reshape(npages * ps, n_kv, hd)
                         kv.put(name, by_slot.at[flat].set(
                             upd.astype(by_slot.dtype), mode="drop"))
-                k_all = v_all = None  # gather deferred: the kernel may skip it
             else:
                 # mode="drop" pins the out-of-bounds semantics the overflow
                 # latch and late chunked-prefill extends rely on (a chunk whose
@@ -1060,29 +1029,7 @@ class LlamaAttention(nn.Module):
                     jax.lax.dynamic_slice_in_dim(kv.flat(name), first, b)
                     for name in ("cached_key", "cached_value"))
             ci.value = idx + s_new
-        one_token = s_new == 1 and chunk_mask is None
-        if ps:
-            from neuronx_distributed_tpu.inference.paged_kernel import (
-                paged_decode_attention,
-            )
-
-            # single-token steps only (prefill/chunk widths amortize the
-            # gather); whether THIS model's pages fit the kernel was
-            # settled at construction (CausalLM, paged_kernel_refusal)
-            if cfg.paged_attn_kernel and one_token:
-                # fused paged decode (inference/paged_kernel.py): attend
-                # straight off the POST-write pool through the block
-                # table — no logical slab is ever materialized, which is
-                # the whole perf point of this branch. The gather below
-                # stays as the bit-exactness reference oracle.
-                with jax.named_scope("attend"):
-                    o = paged_decode_attention(
-                        q, kv.flat("cached_key"), kv.flat("cached_value"),
-                        table, idx, sm_scale=cfg.attention_multiplier,
-                        k_scale=kv.flat("cached_key_scale") if quantized else None,
-                        v_scale=kv.flat("cached_value_scale") if quantized else None)
-                return self._o_proj(o.reshape(b, s_new, -1), aidx)
-        if one_token:
+        if s_new == 1:
             # the step reads its live rows as far as the longest reaches; a table
             # of ONE chunk (max_seq_len of 128 or less) is the whole read below
             walk = kv_walk(cfg, idx, None if live is None else live[:, 0])
@@ -1091,12 +1038,11 @@ class LlamaAttention(nn.Module):
                 return self._o_proj(o.reshape(b, s_new, -1), aidx)
         if ps:
             # in-scan gather: the (b, max_seq_len) logical view the
-            # attention below consumes (prompts, chunks, tree steps; a
-            # one-token step left above). Stale bytes in reused pages sit
-            # behind the position mask exactly like the slab's unwritten
-            # zeros (masked scores are -1e30 -> exactly-zero probs), so
-            # attention over the view is bit-identical to the contiguous
-            # path.
+            # attention below consumes (prompts, chunks; a one-token step
+            # left above). Stale bytes in reused pages sit behind the
+            # position mask exactly like the slab's unwritten zeros (masked
+            # scores are -1e30 -> exactly-zero probs), so attention over the
+            # view is bit-identical to the contiguous path.
             with jax.named_scope("kv_gather"):
                 # by whole pages, one (page, n_kv, hd) run of the buffer per
                 # table entry: the same rows a gather by slot would bring
@@ -1111,22 +1057,6 @@ class LlamaAttention(nn.Module):
                                             (v_all, "cached_value_scale")))
                 k_all, v_all = (pages.reshape(b, cfg.max_seq_len, n_kv, hd)
                                 for pages in (k_all, v_all))
-        if chunk_mask is not None:
-            # prefix slots (< idx) fully visible; chunk slots by tree mask
-            with jax.named_scope("attend"):
-                s_max = cfg.max_seq_len
-                kslot = jnp.arange(s_max)[None, None, :]              # (1,1,S)
-                prefix = kslot < idx[:, None, None]                   # (b,1,S)
-                rel = kslot - idx[:, None, None]                      # (b,1,S)
-                in_chunk = (rel >= 0) & (rel < s_new)
-                rel_c = jnp.broadcast_to(jnp.clip(rel, 0, s_new - 1), (b, s_new, s_max))
-                cm = jnp.broadcast_to(chunk_mask.astype(bool)[None], (b, s_new, s_new))
-                tree = jnp.take_along_axis(cm, rel_c.astype(jnp.int32), axis=2)
-                mask = prefix | (in_chunk & tree)
-                o = cached_attention(q, k_all, v_all, idx, mask=mask,
-                                     sm_scale=cfg.attention_multiplier)
-            o = o.reshape(b, s_new, -1)
-            return self._o_proj(o, aidx)
         # prefill/chunk attention: the Pallas kernel with per-slot position
         # masks (q at idx..idx+s_new; key j visible iff j <= q position, which
         # also excludes unwritten cache slots). The reference likewise uses
@@ -1153,7 +1083,7 @@ class LlamaAttention(nn.Module):
                     use_flash=True,
                     block_q=blk_q,
                     block_k=cfg_blk_k,
-                    q_positions=positions,
+                    q_positions=slots,
                     kv_positions=None,  # default iota: j <= q position
                 )
                 o = o.transpose(0, 2, 1, 3)
@@ -1206,11 +1136,10 @@ class LlamaDecoderLayer(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope, chunk_ctx=None, kv=None,
-                 live=None) -> jax.Array:
+    def __call__(self, x: jax.Array, rope, kv=None, live=None) -> jax.Array:
         cfg = self.config
         h = cfg.make_norm(name="input_norm")(x)
-        x = x + LlamaAttention(cfg, name="attention")(h, rope, chunk_ctx, kv, live)
+        x = x + LlamaAttention(cfg, name="attention")(h, rope, kv, live)
         h = cfg.make_norm(name="post_attn_norm")(x)
         return x + LlamaMLP(cfg, name="mlp")(h)
 
@@ -1245,7 +1174,7 @@ class _LayerStep(nn.Module):
     layer_cls: Any = None  # default LlamaDecoderLayer (set below)
 
     @nn.compact
-    def __call__(self, carry, rope, chunk_ctx=None, live=None, stack=None):
+    def __call__(self, carry, rope, live=None, stack=None):
         cfg = self.config
         x, kv = carry
         cls = self.layer_cls or LlamaDecoderLayer
@@ -1253,15 +1182,13 @@ class _LayerStep(nn.Module):
         if policy is not None and kv is None:  # nothing differentiates a decode
             cls = nn.remat(cls, policy=policy, prevent_cse=False)
         block = cls(cfg, name="block")
-        # 2-arg layer variants (Mixtral) stay compatible
-        args = (x, rope) if chunk_ctx is None else (x, rope, chunk_ctx)
         kwargs = {k: v for k, v in (("live", live), ("stack", stack))
                   if v is not None}
         if kv is None:
-            return (block(*args, **kwargs), None), None
+            return (block(x, rope, **kwargs), None), None
         layer, leaves = kv
         view = KVLayerView(layer, leaves)
-        return (block(*args, kv=view, **kwargs),
+        return (block(x, rope, kv=view, **kwargs),
                 (layer + 1, view.leaves)), None
 
 
@@ -1311,8 +1238,7 @@ class LlamaModel(nn.Module):
         self.final_norm = cfg.make_norm()
 
     @nn.compact
-    def __call__(self, input_ids: jax.Array, chunk_ctx=None,
-                 live=None) -> jax.Array:
+    def __call__(self, input_ids: jax.Array, live=None) -> jax.Array:
         cfg = self.config
         if input_ids.shape[1] > cfg.max_seq_len:
             raise ValueError(
@@ -1352,14 +1278,14 @@ class LlamaModel(nn.Module):
                     cfg, input_ids.shape[0]).items()}
             kv = (jnp.int32(0), {n: p.value for n, p in pools.items()})
         stack = self.layer_stack() if cfg.decode else None
-        args = (rope, chunk_ctx, live, stack)
+        args = (rope, live, stack)
         while args[-1] is None:     # dense models, training: (rope,) as ever
             args = args[:-1]
         carry = (x, kv)
         if getattr(cfg, "first_k_dense", 0):
             # a one-token step's attention wants `live` (KVWalk); a prompt's
             # does not, and a dense block has no other use for it
-            dense = (rope, chunk_ctx, live if input_ids.shape[1] == 1 else None)
+            dense = (rope, live if input_ids.shape[1] == 1 else None)
             while dense[-1] is None:
                 dense = dense[:-1]
             carry, _ = self.dense_layers(carry, *dense)

@@ -30,10 +30,6 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from neuronx_distributed_tpu.inference import CausalLM, SlotSampler
-from neuronx_distributed_tpu.inference.paged_kernel import (
-    paged_decode_attention,
-    paged_kernel_refusal,
-)
 from neuronx_distributed_tpu.kernels import mode
 from neuronx_distributed_tpu.kernels.flash_attn import (
     default_attention_blocks,
@@ -91,30 +87,6 @@ def test_flash_attention_fwd_bwd(chip, n_kv):
 
     # forward, dK/dV and dQ kernels
     assert _kernel_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) >= 3
-
-
-@pytest.mark.parametrize("pages", ["bf16", "int8"])
-@pytest.mark.parametrize("n_kv", [32, 8], ids=["mha32", "gqa32_8"])
-def test_paged_decode_attention(chip, n_kv, pages):
-    b, h, page, num_pages, pages_per_seq = 8, 32, 16, 640, 64
-
-    def aval(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
-    pool = aval((num_pages, page, n_kv, HEAD_DIM),
-                jnp.int8 if pages == "int8" else jnp.bfloat16)
-    args = [aval((b, 1, h, HEAD_DIM), jnp.bfloat16), pool, pool,
-            aval((b, pages_per_seq), jnp.int32), aval((b,), jnp.int32)]
-    if pages == "int8":
-        scale = aval((num_pages, 1, n_kv, 1), jnp.float32)
-
-        def fn(q, k, v, bt, cl, ks, vs):
-            return paged_decode_attention(q, k, v, bt, cl, k_scale=ks,
-                                          v_scale=vs)
-
-        assert _kernel_calls(fn, *args, scale, scale) == 1
-    else:
-        assert _kernel_calls(paged_decode_attention, *args) == 1
 
 
 def test_gather_decode_step_holds_no_widened_slab(chip):
@@ -552,19 +524,3 @@ def test_ssm_step_steps_the_leaf_in_place(chip):
     whole = re.escape(f"f32[{layers * b},{h},{p},{n}]")
     assert not re.search(rf"= (?:{rows}|{whole})\S* (?:copy|dynamic-update-slice|dynamic-slice)\(",
                          text)
-
-
-def test_paged_kernel_refused_at_construction_not_mid_serve():
-    """A page too large for the kernel's VMEM budget is refused when the
-    model is built, with the reason — not by a decode step quietly taking
-    the gather path. (The bound's two sides were found by compiling:
-    ``paged_kernel._PAGE_TILE_VMEM_BYTES``.)"""
-    assert paged_kernel_refusal(256, 32, 32, HEAD_DIM, jnp.bfloat16) is None
-    assert paged_kernel_refusal(512, 32, 32, HEAD_DIM, jnp.int8) is None
-    assert "VMEM" in paged_kernel_refusal(512, 32, 32, HEAD_DIM, jnp.bfloat16)
-    assert "multiple" in paged_kernel_refusal(16, 6, 4, HEAD_DIM, jnp.bfloat16)
-    cfg = LlamaConfig(vocab_size=128, hidden_size=4096, num_heads=32,
-                      num_kv_heads=32, num_layers=1, max_seq_len=1024)
-    with pytest.raises(ValueError, match="paged_attn_kernel refused"):
-        CausalLM(cfg, None, LlamaForCausalLM, buckets=(128,), max_batch=1,
-                 page_size=512, paged_attn_kernel=True)

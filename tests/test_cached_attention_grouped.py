@@ -20,40 +20,25 @@ B, S_MAX, N, D = 4, 32, 8, 16
 # per-row lengths: an empty row, a full one (the last slot is the query's
 # own for s_new 1), and two in between
 CACHE_LEN = np.array([0, S_MAX - 1, 7, 19], np.int32)
-# a Medusa-style tree over 5 chunk tokens: row i sees its ancestors and itself
-TREE = np.array([[1, 0, 0, 0, 0],
-                 [1, 1, 0, 0, 0],
-                 [1, 0, 1, 0, 0],
-                 [1, 1, 0, 1, 0],
-                 [1, 0, 1, 0, 1]], bool)
+# a model that scales its scores by a multiplier of its own, not 1 / sqrt(d)
+# (``LlamaConfig.attention_multiplier``: Granite's 0.015625 at head size 64)
+MULTIPLIER = 0.11
 
 
-def repeat_and_widen_attention(q, k_cache, v_cache, cache_len, mask=None):
+def repeat_and_widen_attention(q, k_cache, v_cache, cache_len, sm_scale=None):
     """The formulation before the grouped one, verbatim in what it computes."""
     b, s_new, n, d = q.shape
     n_kv = k_cache.shape[2]
     k_cache = jnp.repeat(k_cache, n // n_kv, axis=2)
     v_cache = jnp.repeat(v_cache, n // n_kv, axis=2)
     scores = jnp.einsum("bind,bjnd->bnij", q.astype(jnp.float32),
-                        k_cache.astype(jnp.float32)) / (d ** 0.5)
-    if mask is None:
-        qpos = cache_len[:, None] + jnp.arange(s_new)[None, :]
-        mask = jnp.arange(k_cache.shape[1])[None, None, :] <= qpos[..., None]
+                        k_cache.astype(jnp.float32)) * (sm_scale or 1.0 / d ** 0.5)
+    qpos = cache_len[:, None] + jnp.arange(s_new)[None, :]
+    mask = jnp.arange(k_cache.shape[1])[None, None, :] <= qpos[..., None]
     scores = jnp.where(mask[:, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bnij,bjnd->bind", probs, v_cache.astype(jnp.float32))
     return out.astype(q.dtype)
-
-
-def _tree_mask(cache_len, s_new):
-    """What ``_decode_attention`` builds from a chunk mask: the prefix below
-    ``cache_len`` fully visible, the chunk's own slots by tree ancestry."""
-    kslot = np.arange(S_MAX)[None, None, :]
-    rel = kslot - cache_len[:, None, None]
-    in_chunk = (rel >= 0) & (rel < s_new)
-    tree = TREE[:s_new, :s_new]
-    by_tree = tree[np.arange(s_new)[None, :, None], np.clip(rel, 0, s_new - 1)]
-    return jnp.asarray((kslot < cache_len[:, None, None]) | (in_chunk & by_tree))
 
 
 @pytest.mark.parametrize(
@@ -63,10 +48,10 @@ def _tree_mask(cache_len, s_new):
      # the contraction and q keeps its mantissa
      (jnp.float32, jnp.bfloat16)],
     ids=["f32", "bf16", "f32_over_bf16"])
-@pytest.mark.parametrize("masked", ["positional", "tree"])
+@pytest.mark.parametrize("sm_scale", [None, MULTIPLIER], ids=["rsqrt_d", "multiplier"])
 @pytest.mark.parametrize("s_new", [1, 5])
 @pytest.mark.parametrize("group", [1, 4, 8], ids=["mha", "gqa4", "mqa"])
-def test_grouped_matches_repeat_and_widen(group, s_new, masked, q_dtype, kv_dtype):
+def test_grouped_matches_repeat_and_widen(group, s_new, sm_scale, q_dtype, kv_dtype):
     n_kv = N // group
     kq, kk, kv = jax.random.split(jax.random.key(group * 16 + s_new), 3)
     q = jax.random.normal(kq, (B, s_new, N, D), jnp.float32).astype(q_dtype)
@@ -74,9 +59,8 @@ def test_grouped_matches_repeat_and_widen(group, s_new, masked, q_dtype, kv_dtyp
     v = jax.random.normal(kv, (B, S_MAX, n_kv, D), jnp.float32).astype(kv_dtype)
     # a chunk at the very end would run past the slab: keep every query's slot
     cache_len = np.minimum(CACHE_LEN, S_MAX - s_new)
-    mask = _tree_mask(cache_len, s_new) if masked == "tree" else None
-    got = cached_attention(q, k, v, jnp.asarray(cache_len), mask=mask)
-    want = repeat_and_widen_attention(q, k, v, jnp.asarray(cache_len), mask=mask)
+    got = cached_attention(q, k, v, jnp.asarray(cache_len), sm_scale=sm_scale)
+    want = repeat_and_widen_attention(q, k, v, jnp.asarray(cache_len), sm_scale=sm_scale)
     assert got.shape == want.shape == (B, s_new, N, D)
     assert got.dtype == want.dtype == q_dtype
     got, want = (np.asarray(x, np.float32) for x in (got, want))

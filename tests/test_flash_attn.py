@@ -168,7 +168,7 @@ def test_padded_prompt_mask():
 
 
 def test_decode_chunk_against_cache():
-    """sq < sk with per-slot cache offsets (chunked prefill / speculation):
+    """sq < sk with per-slot cache offsets (chunked prefill):
     query i of slot b sits at cache_len[b] + i and sees keys j <= that."""
     b, h, d = 2, 2, 32
     s_new, s_max = 64, 256

@@ -412,20 +412,6 @@ REFUSED = {
     "host_tier": lambda p: ServeEngine(serving_lm(p), host_tier_pages=4),
     "parking": lambda p: ServeEngine(serving_lm(p), park_idle_blocks=2, park_dir="/nonexistent"),
     "page_corruption": lambda p: ServeEngine(serving_lm(p)).inject_page_corruption([1]),
-    "speculative": lambda p: __import__(
-        "neuronx_distributed_tpu.inference.speculative", fromlist=["x"]).speculative_generate(
-            serving_lm(p), serving_lm(p), IDS[:1, :8], 4),
-    "speculative_fused": lambda p: __import__(
-        "neuronx_distributed_tpu.inference.speculative", fromlist=["x"]).speculative_decode_fused(
-            serving_lm(p), serving_lm(p), IDS[:1, :8], 4),
-    "medusa": lambda p: __import__(
-        "neuronx_distributed_tpu.inference.medusa", fromlist=["x"]).medusa_generate(
-            GraniteHybridConfig(**TINY), p, IDS[:1, :8], 4),
-    "tree_step": lambda p: GraniteHybridForCausalLM(
-        dataclasses.replace(GraniteHybridConfig(**TINY), decode=True)).apply(
-            {"params": p}, jnp.asarray(IDS[:1, :4]),
-            (jnp.ones((4, 4), bool), jnp.arange(4)), mutable=["cache"],
-            method=lambda m, ids, ctx: m.model(ids, chunk_ctx=ctx)),
     "generate": lambda p: serving_lm(p).generate(IDS[:1, :8], 4),
 }
 

@@ -126,47 +126,6 @@ def test_sampler_modes():
     assert int(s(logits, jax.random.key(1))[0]) == 1  # top-p 0.5 keeps only argmax here
 
 
-def test_speculative_self_draft_matches_greedy():
-    """Draft == target: every proposal is accepted and the output must equal
-    plain greedy generation (the canonical spec-decoding sanity check)."""
-    from neuronx_distributed_tpu.inference.speculative import speculative_generate
-
-    cfg = LlamaConfig(**TINY)
-    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(5), (1, 8), 1, 127))
-    params = _params(cfg, jnp.asarray(ids))
-    lm = CausalLM(cfg, params, LlamaForCausalLM, buckets=(16,), max_batch=1).compile()
-    golden = lm.generate(ids, max_new_tokens=6)
-    spec = speculative_generate(lm, lm, ids, max_new_tokens=6, num_draft=3,
-                                collect_stats=True)
-    np.testing.assert_array_equal(spec.tokens, golden.tokens)
-    # stats surface (reference benchmark report role): self-draft greedy
-    # acceptance is exactly 1.0, and the per-submodel percentiles exist
-    assert spec.stats["acceptance_rate"] == 1.0, spec.stats
-    assert spec.stats["accepted"] == spec.stats["proposed"] > 0
-    for k in ("round_ms_p50", "draft_ms_p50", "verify_ms_p50",
-              "round_ms_p90", "draft_ms_p90", "verify_ms_p90"):
-        assert spec.stats[k] is not None and spec.stats[k] >= 0
-
-
-def test_speculative_different_draft_still_exact():
-    """With ANY draft (here: a differently-initialized model), greedy
-    acceptance guarantees the output equals the target's own greedy output —
-    the core spec-decoding invariant. Exercises both the rejection path and
-    the full-acceptance draft-cache refill."""
-    from neuronx_distributed_tpu.inference.speculative import speculative_generate
-
-    cfg = LlamaConfig(**TINY)
-    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(6), (1, 8), 1, 127))
-    params_t = _params(cfg, jnp.asarray(ids))
-    model = LlamaForCausalLM(cfg)
-    params_d = meta.unbox(model.init(jax.random.PRNGKey(99), jnp.asarray(ids)))["params"]
-    t_lm = CausalLM(cfg, params_t, LlamaForCausalLM, buckets=(16,), max_batch=1).compile()
-    d_lm = CausalLM(cfg, params_d, LlamaForCausalLM, buckets=(16,), max_batch=1).compile()
-    golden = t_lm.generate(ids, max_new_tokens=6)
-    spec = speculative_generate(t_lm, d_lm, ids, max_new_tokens=6, num_draft=2)
-    np.testing.assert_array_equal(spec.tokens, golden.tokens)
-
-
 def test_generate_overflow_guard():
     cfg = LlamaConfig(**TINY)
     ids = np.asarray(jax.random.randint(jax.random.PRNGKey(7), (1, 8), 1, 127))
@@ -214,131 +173,6 @@ def test_generate_flash_prefill_end_to_end():
         lm = CausalLM(c, params, LlamaForCausalLM, buckets=(192,), max_batch=2)
         out[name] = lm.generate(prompts, max_new_tokens=4).tokens
     np.testing.assert_array_equal(out["dense"], out["flash"])
-
-
-# --- Medusa tree decoding + speculative v2 ---------------------------------
-
-def _medusa_setup():
-    from flax.core import meta
-
-    from neuronx_distributed_tpu.inference.medusa import MedusaLlamaForCausalLM
-    from neuronx_distributed_tpu.models.llama import LlamaConfig
-
-    cfg = LlamaConfig(vocab_size=128, hidden_size=32, intermediate_size=64,
-                      num_layers=2, num_heads=4, num_kv_heads=4, max_seq_len=128,
-                      dtype=jnp.float32, use_flash_attention=False,
-                      remat_policy=None)
-    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(0), (1, 8), 1, 127),
-                     np.int32)
-    import dataclasses
-
-    mm = MedusaLlamaForCausalLM(dataclasses.replace(cfg, decode=True),
-                                num_medusa_heads=2)
-    mparams = meta.unbox(mm.init(jax.random.PRNGKey(0), jnp.asarray(ids)))["params"]
-    return cfg, ids, mparams
-
-
-def test_medusa_buffers_structure():
-    from neuronx_distributed_tpu.inference.medusa import generate_medusa_buffers
-
-    b = generate_medusa_buffers([(0,), (1,), (0, 0), (0, 1), (1, 0)])
-    assert b["num_nodes"] == 6 and b["depth"] == 2
-    # every node attends root and itself; (0,0) attends (0,) but not (1,)
-    assert b["attn_mask"][:, 0].all()
-    assert b["attn_mask"][3, 1] and not b["attn_mask"][3, 2]
-    # depth-2 nodes index into head-1's pool (offset 1 + TOPK)
-    assert b["tree_indices"][3] == 11
-    assert list(b["position_ids"]) == [0, 1, 1, 2, 2, 2]
-    assert b["retrieve_indices"].shape == (3, 3)  # three maximal paths
-
-
-def test_medusa_matches_greedy_exactly():
-    """The Medusa invariant: tree decoding with ANY head quality (here
-    random heads) emits exactly the base model's greedy continuation —
-    acceptance verifies every token against the verifier's argmax."""
-    from neuronx_distributed_tpu.inference.medusa import medusa_generate
-    from neuronx_distributed_tpu.models.llama import LlamaForCausalLM
-
-    cfg, ids, mparams = _medusa_setup()
-    base_params = {k: v for k, v in mparams.items() if not k.startswith("medusa")}
-    lm = CausalLM(cfg, base_params, LlamaForCausalLM, buckets=(8,), max_batch=1)
-    golden = lm.generate(ids, max_new_tokens=12)
-    res = medusa_generate(cfg, mparams, ids, max_new_tokens=12,
-                          num_medusa_heads=2,
-                          medusa_choices=[(0,), (1,), (0, 0), (0, 1), (1, 0)])
-    assert golden.tokens[0].tolist() == res.tokens[0].tolist()
-
-
-def test_medusa_eos_stops():
-    from neuronx_distributed_tpu.inference.medusa import medusa_generate
-    from neuronx_distributed_tpu.models.llama import LlamaForCausalLM
-
-    cfg, ids, mparams = _medusa_setup()
-    base_params = {k: v for k, v in mparams.items() if not k.startswith("medusa")}
-    lm = CausalLM(cfg, base_params, LlamaForCausalLM, buckets=(8,), max_batch=1)
-    golden = lm.generate(ids, max_new_tokens=12)
-    eos = int(golden.tokens[0, 4])  # force a stop mid-stream
-    res = medusa_generate(cfg, mparams, ids, max_new_tokens=12,
-                          num_medusa_heads=2, eos_token_id=eos)
-    n = int(res.lengths[0])
-    assert res.tokens[0, n - 1] == eos
-    assert (res.tokens[0, n:] == 0).all()
-
-
-def test_speculative_sampling_acceptance_identical_models():
-    """draft == target -> acceptance prob min(1, p/p) = 1: every proposal
-    accepted, output length always fills, tokens valid. (The distributional
-    guarantee of speculative sampling degenerates to 'sample from target'.)"""
-    from neuronx_distributed_tpu.inference.speculative import speculative_generate
-    from flax.core import meta
-
-    from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-
-    cfg = LlamaConfig(vocab_size=128, hidden_size=32, intermediate_size=64,
-                      num_layers=2, num_heads=4, num_kv_heads=4, max_seq_len=128,
-                      dtype=jnp.float32, use_flash_attention=False,
-                      remat_policy=None)
-    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(0), (1, 8), 1, 127),
-                     np.int32)
-    model = LlamaForCausalLM(cfg)
-    params = meta.unbox(model.init(jax.random.PRNGKey(0), jnp.asarray(ids)))["params"]
-    target = CausalLM(cfg, params, LlamaForCausalLM, buckets=(8,), max_batch=1)
-    draft = CausalLM(cfg, params, LlamaForCausalLM, buckets=(8,), max_batch=1)
-    res = speculative_generate(target, draft, ids, 10, num_draft=3,
-                               greedy=False, temperature=0.8,
-                               rng=jax.random.key(3))
-    assert int(res.lengths[0]) == 10
-    assert (res.tokens[0] >= 0).all() and (res.tokens[0] < 128).all()
-
-
-def test_medusa_tied_embeddings():
-    """Tied configs must route the base logits through the embedding table
-    exactly like LlamaForCausalLM (r2 review)."""
-    import dataclasses
-
-    from flax.core import meta
-
-    from neuronx_distributed_tpu.inference.medusa import (
-        MedusaLlamaForCausalLM,
-        medusa_generate,
-    )
-    from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-
-    cfg = LlamaConfig(vocab_size=128, hidden_size=32, intermediate_size=64,
-                      num_layers=2, num_heads=4, num_kv_heads=4, max_seq_len=128,
-                      dtype=jnp.float32, use_flash_attention=False,
-                      remat_policy=None, tie_word_embeddings=True)
-    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(0), (1, 8), 1, 127),
-                     np.int32)
-    mm = MedusaLlamaForCausalLM(dataclasses.replace(cfg, decode=True),
-                                num_medusa_heads=2)
-    mparams = meta.unbox(mm.init(jax.random.PRNGKey(0), jnp.asarray(ids)))["params"]
-    assert "lm_head" not in mparams
-    base_params = {k: v for k, v in mparams.items() if not k.startswith("medusa")}
-    lm = CausalLM(cfg, base_params, LlamaForCausalLM, buckets=(8,), max_batch=1)
-    golden = lm.generate(ids, max_new_tokens=8)
-    res = medusa_generate(cfg, mparams, ids, max_new_tokens=8, num_medusa_heads=2)
-    assert golden.tokens[0].tolist() == res.tokens[0].tolist()
 
 
 # --- AOT artifact save/load + weight sharding ------------------------------
@@ -605,113 +439,3 @@ def test_fused_decode_post_eos_frozen_to_pad():
         if n < 12:
             assert r_fused.tokens[row, n - 1] == eos
             assert (r_fused.tokens[row, n:] == 0).all()  # pad_token_id=0
-
-
-# --- single-program fused speculation (tentpole) ----------------------------
-
-def _spec_pair(seed_t=0, seed_d=99):
-    cfg = LlamaConfig(**TINY)
-    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(6), (1, 8), 1, 127))
-    model = LlamaForCausalLM(cfg)
-    params_t = meta.unbox(model.init(jax.random.PRNGKey(seed_t), jnp.asarray(ids)))["params"]
-    params_d = meta.unbox(model.init(jax.random.PRNGKey(seed_d), jnp.asarray(ids)))["params"]
-    t_lm = CausalLM(cfg, params_t, LlamaForCausalLM, buckets=(16,), max_batch=1).compile()
-    d_lm = CausalLM(cfg, params_d, LlamaForCausalLM, buckets=(16,), max_batch=1).compile()
-    return t_lm, d_lm, ids
-
-
-def test_speculative_fused_matches_host_loop_greedy():
-    """The fused R-round program must emit BIT-IDENTICAL tokens to the host
-    loop (greedy), including the rejection path of a divergent draft, across
-    block sizes that divide / don't divide / exceed the round count."""
-    from neuronx_distributed_tpu.inference.speculative import (
-        speculative_decode_fused,
-        speculative_generate,
-    )
-
-    t_lm, d_lm, ids = _spec_pair()
-    host = speculative_generate(t_lm, d_lm, ids, max_new_tokens=12,
-                                num_draft=3, rng=jax.random.key(7))
-    for rpb in (1, 3, 16):
-        fused = speculative_decode_fused(
-            t_lm, d_lm, ids, max_new_tokens=12, num_draft=3,
-            rounds_per_block=rpb, rng=jax.random.key(7))
-        np.testing.assert_array_equal(fused.tokens, host.tokens,
-                                      err_msg=f"rounds_per_block={rpb}")
-        assert fused.stats["rounds"] == host.stats["rounds"]
-        assert fused.stats["accepted"] == host.stats["accepted"]
-        assert fused.stats["acceptance_rate"] == host.stats["acceptance_rate"]
-
-
-@pytest.mark.slow  # compiles two full fused-round programs; tier-1 keeps the
-# greedy + eos/dispatch-count exactness gates, this rides the slow lane
-def test_speculative_fused_matches_host_loop_sampled():
-    """Sampled acceptance (speculative sampling): identical rng fold-in
-    discipline -> identical accept/reject draws and residual resamples ->
-    token-identical output."""
-    from neuronx_distributed_tpu.inference.speculative import (
-        speculative_decode_fused,
-        speculative_generate,
-    )
-
-    t_lm, d_lm, ids = _spec_pair()
-    host = speculative_generate(t_lm, d_lm, ids, max_new_tokens=12,
-                                num_draft=3, greedy=False, temperature=0.8,
-                                rng=jax.random.key(3))
-    fused = speculative_decode_fused(
-        t_lm, d_lm, ids, max_new_tokens=12, num_draft=3, greedy=False,
-        temperature=0.8, rounds_per_block=4, rng=jax.random.key(3))
-    np.testing.assert_array_equal(fused.tokens, host.tokens)
-    # self-draft sampled: acceptance prob min(1, p/p) = 1 -> full length
-    t2 = _spec_pair()[0]
-    from neuronx_distributed_tpu.inference.speculative import (
-        speculative_decode_fused as sdf,
-    )
-    res = sdf(t2, t2, ids, max_new_tokens=10, num_draft=3, greedy=False,
-              temperature=0.8, rounds_per_block=3, rng=jax.random.key(5))
-    assert int(res.lengths[0]) == 10
-    assert res.stats["acceptance_rate"] == 1.0
-
-
-def test_speculative_fused_eos_and_dispatch_count():
-    """EOS stops mid-block (later rounds frozen by the length mask, post-EOS
-    slots pad) AND the dispatch contract holds: counting invocations of the
-    compiled block program shows ONE program call per R-round block — with
-    the single result fetch, <= 2 host dispatches per block."""
-    from neuronx_distributed_tpu.inference import speculative as spec
-
-    t_lm, d_lm, ids = _spec_pair()
-    host = spec.speculative_generate(t_lm, d_lm, ids, max_new_tokens=12,
-                                     num_draft=3, rng=jax.random.key(7))
-    eos = int(host.tokens[0, 5])
-
-    calls = {"n": 0}
-    orig = spec._compile_block
-
-    def counting_compile(*a, **kw):
-        compiled = orig(*a, **kw)
-
-        def wrapped(*ca, **ckw):
-            calls["n"] += 1
-            return compiled(*ca, **ckw)
-
-        return wrapped
-
-    spec._compile_block = counting_compile
-    try:
-        he = spec.speculative_generate(t_lm, d_lm, ids, max_new_tokens=12,
-                                       num_draft=3, eos_token_id=eos,
-                                       rng=jax.random.key(7))
-        fe = spec.speculative_decode_fused(
-            t_lm, d_lm, ids, max_new_tokens=12, num_draft=3, eos_token_id=eos,
-            rounds_per_block=4, rng=jax.random.key(7))
-    finally:
-        spec._compile_block = orig
-    np.testing.assert_array_equal(fe.tokens, he.tokens)
-    np.testing.assert_array_equal(fe.lengths, he.lengths)
-    n = int(fe.lengths[0])
-    assert fe.tokens[0, n - 1] == eos and (fe.tokens[0, n:] == 0).all()
-    # independently-counted program invocations == self-reported block calls,
-    # and each block performed exactly one program call
-    assert calls["n"] == fe.stats["fused_block_calls"] >= 1
-    assert fe.stats["host_dispatches_per_block"] == 2

@@ -122,7 +122,7 @@ class _ModelCarryingX(llama.LlamaModel):
         )(cfg, self.layer_cls)
         self.final_norm = cfg.make_norm()
 
-    def __call__(self, input_ids, chunk_ctx=None):
+    def __call__(self, input_ids):
         cfg = self.config
         x = self.embed(input_ids)
         rope = llama.rotary_embedding(

@@ -7,9 +7,10 @@ pool of its OWN per layer (a one-layer ``KVLayerView``), two ways: as a scan
 whose xs and ys are the per-layer pools (the form the carry replaced) and as
 a Python loop over layers with no scan. Insert logits, the tokens of two fused
 blocks and every byte of every layer's pool after them must be the same, bit
-for bit, for GQA / MHA with QK-norm / MQA, float32 / bfloat16 / int8 pages,
-the gather and the paged kernel, on one device and under 2- and 4-device TP
-meshes. The logits of one more step come from the stand-alone one-token
+for bit, for GQA / MHA with QK-norm / MQA (and MQA with its one KV head repeated
+under TP), float32 / bfloat16 / int8 pages, a table of one chunk (the whole
+read) and of eight (``KVWalk``: by the loop at 4 096 slots, by the switch at
+512), on one device and under 2- and 4-device TP meshes. The logits of one more step come from the stand-alone one-token
 program, whose head the CPU compiler rounds 1-2 ulp away from the oracle's on
 a mesh without TP (the K/V it writes agree to the bit): held to 2e-6. With
 full-precision pages the contiguous slab (which takes the same route, written
@@ -52,21 +53,21 @@ GQA, MQA = {}, dict(num_kv_heads=1)
 MHA_QK = dict(num_kv_heads=4, qk_norm=True)
 BF16 = dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
 
-# (attention, model dtype, lm keywords, TP degree)
+# (attention, model dtype, lm keywords, TP degree[, max_seq_len])
 CASES = {
     "gqa-f32-gather-tp1": (GQA, {}, {}, 1),
     "mha_qknorm-f32-gather-tp1": (MHA_QK, {}, {}, 1),
     "mqa-f32-gather-tp1": (MQA, {}, {}, 1),
     "gqa-bf16-gather-tp1": (GQA, BF16, {}, 1),
     "mqa-int8-gather-tp1": (MQA, {}, dict(page_dtype="int8"), 1),
-    "gqa-f32-kernel-tp1": (GQA, {}, dict(paged_attn_kernel=True), 1),
-    "mha_qknorm-int8-kernel-tp1": (MHA_QK, {}, dict(page_dtype="int8", paged_attn_kernel=True), 1),
+    "gqa-bf16-loop-tp1": (GQA, BF16, {}, 1, 4096),
+    "mha_qknorm-int8-gather-tp1": (MHA_QK, {}, dict(page_dtype="int8"), 1),
     "gqa-f32-gather-tp2": (GQA, {}, {}, 2),
     "gqa-int8-gather-tp2": (GQA, {}, dict(page_dtype="int8"), 2),
-    "mqa_x2-f32-kernel-tp2": (dict(num_kv_heads=1, kv_size_multiplier=2), {},
-                              dict(paged_attn_kernel=True), 2),
+    "gqa-int8-switch-tp2": (GQA, {}, dict(page_dtype="int8"), 2, 512),
+    "mqa_x2-f32-gather-tp2": (dict(num_kv_heads=1, kv_size_multiplier=2), {}, {}, 2),
     "mha_qknorm-f32-gather-tp4": (MHA_QK, {}, {}, 4),
-    "mha_qknorm-bf16-kernel-tp4": (MHA_QK, BF16, dict(paged_attn_kernel=True), 4),
+    "mha_qknorm-bf16-gather-tp4": (MHA_QK, BF16, {}, 4),
 }
 
 
@@ -116,7 +117,7 @@ def _own_pool_layer(cfg, x, layer_params, small, pools):
     view = KVLayerView(jnp.int32(0), jax.tree.map(lambda p: p[None], pools))
     x, mut = LlamaDecoderLayer(cfg).apply(
         {"params": layer_params, "cache": {"attention": small}},
-        x, None, None, view, mutable=["cache"])
+        x, None, view, mutable=["cache"])
     return x, mut["cache"]["attention"], jax.tree.map(lambda p: p[0], view.leaves)
 
 
@@ -207,15 +208,15 @@ def _assert_same(got, want, what, logits_atol=0.0):
             np.testing.assert_array_equal(a, b, err_msg=f"{what}: {name}")
 
 
-LOOPED = ["gqa-f32-gather-tp1", "mha_qknorm-int8-kernel-tp1", "mqa-f32-gather-tp1",
+LOOPED = ["gqa-f32-gather-tp1", "mha_qknorm-int8-gather-tp1", "mqa-f32-gather-tp1",
           "gqa-f32-gather-tp2"]
 
 
 @pytest.mark.parametrize("how,case", [("xs_ys", c) for c in sorted(CASES)]
                          + [("python_loop", c) for c in LOOPED])
 def test_carried_pools_match_layers_that_own_theirs(how, case):
-    attention, dtype, lm_kw, tp = CASES[case]
-    cfg = LlamaConfig(**{**TINY, **attention, **dtype})
+    attention, dtype, lm_kw, tp, *seq = CASES[case]
+    cfg = LlamaConfig(**{**TINY, **attention, **dtype, **dict(zip(("max_seq_len",), seq))})
     params = _params(cfg, tp)
     prompts, lengths = _prompts(B), np.array([8, 5])
     lm = CausalLM(cfg, params, LlamaForCausalLM, buckets=(8,), max_batch=B,
@@ -228,12 +229,7 @@ def test_carried_pools_match_layers_that_own_theirs(how, case):
         # pages in the model's own precision hold what the slab holds
         slab = CausalLM(cfg, params, LlamaForCausalLM, buckets=(8,), max_batch=B).compile()
         want, _ = _drive(slab, prompts, lengths)
-        if lm_kw.get("paged_attn_kernel"):
-            # the kernel's online softmax is not the slab's arithmetic
-            np.testing.assert_array_equal(got["insert"], want["insert"])
-            np.testing.assert_array_equal(got["tokens"], want["tokens"])
-        else:
-            _assert_same(got, want, "against the contiguous slab")
+        _assert_same(got, want, "against the contiguous slab")
 
 
 @pytest.mark.parametrize("pages", ["float32", "int8"])

@@ -49,7 +49,7 @@ LATENT_SCOPES = {
     "moe/routing.py RouterTopK": ["router_groups"],
     "models/deepseek_v2.py DeepseekV2MoELayer": ["shared_expert"],
 }
-KERNELS = ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode", "fused_adamw",
+KERNELS = ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "fused_adamw",
            "grouped_matmul", "ssm_step"]
 # Granite-4.0-H's scopes (PRs 44, 45), all inside the flax module ``mamba``.
 # ``scope_parts.json`` has no rows for them either (PERF.md section 7).
@@ -93,17 +93,15 @@ def params():
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
 
 
-def serving_lm(params, **kw):
+def serving_lm(params):
     return CausalLM(LlamaConfig(**TINY), params, LlamaForCausalLM, buckets=(128,), max_batch=2,
-                    page_size=16, **kw)
+                    page_size=16)
 
 
-@pytest.mark.parametrize("kernel", [False, True], ids=["gather", "paged_kernel"])
-def test_fused_decode_names_its_regions(params, kernel):
-    lm = serving_lm(params, paged_attn_kernel=kernel)
+def test_fused_decode_names_its_regions(params):
+    lm = serving_lm(params)
     components, parts = census(lm.compile_session_decode_fused(4))
-    want = SCOPES["inference/causal_lm.py fused_fn"] + ["kv_write", "attend"]
-    want += ["paged_decode"] if kernel else ["kv_gather"]
+    want = SCOPES["inference/causal_lm.py fused_fn"] + ["kv_write", "attend", "kv_gather"]
     assert set(want) <= components
     for part in ("sampler", "bookkeeping", "kv_write", "attend", "attn_proj", "ffn", "norm",
                  "embed_head"):

@@ -228,24 +228,6 @@ def test_analysis_package_imports_without_jax():
 # adapter-namespace precedent: the fix carries its own pin)
 # --------------------------------------------------------------------------
 
-def test_medusa_programs_pin_replicated():
-    """medusa_generate predated the PR 3 boundary fix: its three jitted
-    programs returned the cache unconstrained, so under a device mesh
-    GSPMD could hand back a drifted-layout cache the next call rejects.
-    Pin the fix at the AST level (the runtime mesh repro needs a
-    multi-device TPU; the static shape is exactly what regressed). The
-    pin accepts either boundary form — PR 16 moved medusa to the
-    TP-sharded ``shard_out``."""
-    ctx = RepoCtx(REPO)
-    medusa = ctx.maybe_file("neuronx_distributed_tpu/inference/medusa.py")
-    assert medusa is not None
-    from neuronx_distributed_tpu.analysis import replication
-    findings = list(replication._check_file(medusa))
-    assert findings == [], [f.message for f in findings]
-    assert ("replicate_out" in medusa.source
-            or "shard_out" in medusa.source)
-
-
 def test_handoff_seam_carries_adapter_absence_witness():
     """The disagg handoff seam releases the grammar pin but not the
     adapter pin — legal ONLY because disagg submit rejects adapters. The

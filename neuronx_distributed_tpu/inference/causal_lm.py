@@ -176,11 +176,16 @@ def _walk_sums(config, cache: PyTree, live: jax.Array) -> jax.Array:
     chunk rounding included), 1, and the slots it read summed over its rows
     (the walk's rung of rows for every chunk read); all 0 where no row is
     live. From the cache's own ``cache_index`` and the ``live`` the model is
-    given, so they are the bounds the attention computed."""
+    given, so they are the bounds the attention computed. They count the
+    layers that page: a model whose window layers keep a ring a slot
+    (``config.window_walk_sums``; ``models/laguna.py``) adds two, the ring
+    slots those layers read and the tokens they needed."""
     idx = next(leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
                if jax.tree_util.keystr(path).endswith("['cache_index']"))[0]
     walk = kv_walk(config, idx, live)
-    return jnp.stack([walk.tokens, 1, walk.row_slots]).astype(jnp.int32) * jnp.any(live)
+    window = getattr(config, "window_walk_sums", None)
+    sums = [walk.tokens, 1, walk.row_slots, *(window(walk) if window else ())]
+    return jnp.stack(sums).astype(jnp.int32) * jnp.any(live)
 
 
 def infer_prompt_lengths(prompt_ids: np.ndarray, pad_token_id: int = 0) -> np.ndarray:
@@ -294,6 +299,10 @@ class CausalLM:
         # refused here (and in ``ServeEngine``) instead of being wrong
         self.slot_rows = tuple(getattr(config, "slot_row_leaves", ()))
         self._slot_row_ends = tuple(f"['{name}']" for name in self.slot_rows)
+        # False: the rows are written by a prompt from position 0 and by
+        # one-token steps only (a window layer's ring), so a chunk that
+        # CONTINUES a row is refused (``extend``)
+        self.slot_rows_continue = bool(getattr(config, "slot_rows_continue", True))
         if self.slot_rows:
             refused = {
                 "the contiguous slab (pass page_size: generate() and the slab "
@@ -417,6 +426,11 @@ class CausalLM:
         # a model that asks which tokens are real: an insert names each row's
         # suffix (the bucket's padding chooses no expert, advances no state)
         self.wants_live = self.moe_stats or bool(self.slot_rows)
+        # sums a fused block's steps add up of what they read of the cache
+        # (``_walk_sums``): three, and two more of a model with rings
+        self.walk_sums = 5 if hasattr(self.config, "window_walk_sums") else 3
+        # per-slot state that a prompt's recurrence scans (the insert's scan sums)
+        self.scans = bool(self.slot_rows) and hasattr(self.config, "scan_positions")
 
     # --- compilation (reference ModelBuilder.trace over CTX/TKG) ---------
 
@@ -955,7 +969,7 @@ class CausalLM:
 
             init = ((cache, tok, counts, lengths, done, gstate0) if gr
                     else (cache, tok, counts, lengths, done))
-            init = (*init, jnp.zeros((3,), jnp.int32))
+            init = (*init, jnp.zeros((self.walk_sums,), jnp.int32))
             if moe:
                 init = (*init, jnp.zeros((self.moe_sums,), jnp.int32))
             carry, toks = jax.lax.scan(body, init, None, length=steps)
@@ -1051,7 +1065,8 @@ class CausalLM:
                "kv_slab_bytes": slab}
         if self.slot_rows:
             # counted apart from the pages: it follows max_batch, not tokens
-            out["state_bytes"] = sum(
+            # (a window layer's rings under their own name)
+            out["window_bytes" if self.walk_sums > 3 else "state_bytes"] = sum(
                 int(np.prod(leaf.shape)) * leaf.dtype.itemsize
                 for _, leaf in self._slot_row_leaves(self._cache_avals()))
         return out
@@ -1337,7 +1352,7 @@ class CausalLM:
                         jnp.full((1,), grouped_rows, jnp.int32),
                         grouped_rows_multiplied(sizes, rows * bucket, top_k
                                                 ).reshape(1)]),))
-            if state_leaves:
+            if self.scans:
                 with jax.named_scope("bookkeeping"):
                     sums = (*sums, *self._replicate_out((jnp.stack([
                         jnp.sum(new_len - starts),
@@ -1488,6 +1503,11 @@ class CausalLM:
         if (lengths < 1).any():
             raise ValueError(f"empty chunk in {lengths.tolist()}")
         new_len = starts + lengths
+        if not self.slot_rows_continue and (starts > 0).any():
+            raise ValueError(
+                f"{type(self.config).__name__} keeps {self.slot_rows} a slot, written "
+                "by a whole prompt from position 0: a chunk that continues a row "
+                f"(starts {starts.tolist()}) is not served")
         if int(new_len.max()) >= self.config.max_seq_len:
             raise ValueError(
                 f"chunk end {int(new_len.max())} leaves no decode room in "
@@ -1526,7 +1546,7 @@ class CausalLM:
         with experts, then the scan sums of a model with per-slot state."""
         sums = list(sums)
         session.insert_routing = sums.pop(0) if self.moe_stats else None
-        session.insert_scanned = sums.pop(0) if self.slot_rows else None
+        session.insert_scanned = sums.pop(0) if self.scans else None
 
     def _insert_paged(self, session: "DecodeSession", slot_ids: np.ndarray,
                       prompt_ids: np.ndarray, lengths: np.ndarray,

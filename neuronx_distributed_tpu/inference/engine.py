@@ -373,6 +373,12 @@ _STAT_KEYS = (
     # the paged inserts and the positions it ran over (rows x the bucket
     # rounded up to the scan's chunk)
     "ssm_scan_tokens", "ssm_scan_positions",
+    # a model whose window layers keep a ring a slot (models/laguna.py): the
+    # ring slots those layers' decode steps read of the rows of their rung
+    # (rung rounding included) and the tokens inside the window they needed
+    # (min(reach, window) a live row a window layer-step). kv_walk_* above
+    # count the layers that page, the full layers, alone
+    "kv_window_slots_read", "kv_window_slots_needed",
 )
 
 
@@ -553,6 +559,10 @@ class ServeEngine:
                     raise ValueError(
                         f"this model keeps per-slot state {lm.slot_rows} beside its "
                         f"pages and is not served with {what}")
+            if prefill_chunk_tokens and not getattr(lm, "slot_rows_continue", True):
+                raise ValueError(
+                    f"this model's {lm.slot_rows} are written by a whole prompt; "
+                    "prefill_chunk_tokens would continue a row by chunks")
         self.lm = lm
         self.block_steps = int(block_steps)
         self.fused = bool(fused)
@@ -3711,6 +3721,9 @@ class ServeEngine:
         self.stats["kv_walk_tokens"] += int(walked[0])
         self.stats["kv_walk_steps"] += int(walked[1])
         self.stats["kv_walk_row_slots"] += int(walked[2])
+        if len(walked) > 3:
+            self.stats["kv_window_slots_read"] += int(walked[3])
+            self.stats["kv_window_slots_needed"] += int(walked[4])
         if routing:
             self._count_routing(routing[0])
 

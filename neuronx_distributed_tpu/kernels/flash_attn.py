@@ -31,6 +31,11 @@ Design (flash-attention-2 tiling written for the MXU/VMEM model):
   skipping exactly — the program_id comparison was already a traced scalar).
 * fully-masked query rows produce output 0 and LSE == NEG_INF (the ``l == 0``
   guard), so pad rows never NaN.
+* a WINDOW (``flash_attention(window=w)``, forward only) adds a lower bound:
+  key ``j`` also needs ``kv_pos[j] > q_pos[i] - w``, the query's own position
+  and the ``w - 1`` before it. The block-skip predicate sees it too, so a
+  long prompt through a window layer multiplies the band's blocks only.
+  ``window=None`` is the program it always was.
 
 Unlike the reference's kernel (seq must be a multiple of 2048,
 flash_attn.py:177-179) block sizes adapt down to the sequence length, so any
@@ -63,7 +68,7 @@ INVALID_POS = 2**30  # kv sentinel: never <= any real query position
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, sm_scale, kv_blocks):
+                m_scr, l_scr, acc_scr, *, sm_scale, kv_blocks, window=None):
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -76,6 +81,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref, lse_ref,
     kp = kp_ref[0, :]                               # (block_k,)
     # skip blocks with no valid (query, key) pair
     run = jnp.min(kp) <= jnp.max(qp)
+    if window is not None:      # ... and blocks wholly below the window
+        run = run & (jnp.max(kp) > jnp.min(qp) - window)
 
     @pl.when(run)
     def _compute():
@@ -88,6 +95,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref, lse_ref,
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * sm_scale                               # (block_q, block_k) fp32
         valid = kp[None, :] <= qp[:, None]
+        if window is not None:
+            valid = valid & (kp[None, :] > qp[:, None] - window)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_scr[:]                          # (block_q, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -224,14 +233,16 @@ def _flash_attention_bh(q, k, v, qpos, kpos, sm_scale, block_q, block_k,
     return out
 
 
-def _fwd(q, k, v, qpos, kpos, sm_scale, block_q, block_k, group, num_q_heads):
+def _fwd(q, k, v, qpos, kpos, sm_scale, block_q, block_k, group, num_q_heads,
+         window=None):
     bh, sq, d = q.shape
     sk = k.shape[1]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     q_blocks = pl.cdiv(sq, block_q)
     kv_blocks = pl.cdiv(sk, block_k)
-    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, kv_blocks=kv_blocks)
+    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, kv_blocks=kv_blocks,
+                               **({} if window is None else {"window": int(window)}))
     from jax.experimental.pallas import tpu as pltpu
 
     h = num_q_heads
@@ -259,7 +270,7 @@ def _fwd(q, k, v, qpos, kpos, sm_scale, block_q, block_k, group, num_q_heads):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=mode.interpret_kernels(),
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "flash_fwd_window",
     )(q, k, v, qpos, kpos)
     return out, lse
 
@@ -364,6 +375,24 @@ def flash_block_grads(q, k, v, do, lse, delta, qpos, kpos, sm_scale,
 _flash_attention_bh.defvjp(_flash_fwd_vjp, _flash_bwd_vjp)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash_window_bh(q, k, v, qpos, kpos, sm_scale, block_q, block_k, group,
+                     num_q_heads, window):
+    """:func:`_flash_attention_bh` under a window. Forward only: serving
+    prompts are its one caller, and the backward kernels know no lower bound."""
+    return _fwd(q, k, v, qpos, kpos, sm_scale, block_q, block_k, group,
+                num_q_heads, window)[0]
+
+
+def _window_fwd_vjp(*args):
+    raise NotImplementedError(
+        "flash_attention(window=...) is forward only: the backward kernels "
+        "mask kv_pos <= q_pos alone")
+
+
+_flash_window_bh.defvjp(_window_fwd_vjp, lambda *a: None)
+
+
 def flash_block_forward(q, k, v, qpos, kpos, sm_scale, block_q, block_k,
                         group, num_q_heads):
     """Forward kernel WITH its softmax statistics: returns ``(out, lse)``
@@ -441,6 +470,7 @@ def flash_attention(
     block_k: int = 128,
     q_positions: Optional[jax.Array] = None,
     kv_positions: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention over ``(batch, num_heads, seq, head_dim)`` tensors
     (reference ``nki_flash_attn_func``, kernels/flash_attn.py:151 — same
@@ -456,7 +486,9 @@ def flash_attention(
     position arrays ((b, sq) and (b, sk)) for padded prompts (pad keys →
     ``INVALID_POS``, pad query rows → ``-1``) or KV-cache decode
     (``q_positions = cache_len + iota``, unwritten cache slots →
-    ``INVALID_POS``). Gradients flow through q/k/v only.
+    ``INVALID_POS``). Gradients flow through q/k/v only. ``window``: key
+    ``j`` must also lie above ``q_positions[b, i] - window`` (forward only;
+    differentiating a windowed call raises).
     """
     b, h, sq, d = q.shape
     hk = k.shape[1]
@@ -479,6 +511,12 @@ def flash_attention(
     vf = v.reshape(b * hk, sk, d)
     qp = q_positions.astype(jnp.int32).reshape(b, 1, sq)
     kp = kv_positions.astype(jnp.int32).reshape(b, 1, sk)
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"window {window}: a query sees itself at least")
+        out = _flash_window_bh(qf, kf, vf, qp, kp, float(sm_scale), block_q, block_k,
+                               h // hk, h, int(window))
+        return out.reshape(b, h, sq, d)
     out = _flash_attention_bh(
         qf, kf, vf, qp, kp, float(sm_scale), block_q, block_k, h // hk, h
     )
@@ -486,7 +524,7 @@ def flash_attention(
 
 
 def reference_attention(q, k, v, causal=True, sm_scale=None,
-                        q_positions=None, kv_positions=None):
+                        q_positions=None, kv_positions=None, window=None):
     """Plain-XLA attention, used as the numerical golden in tests (the role
     of the reference's CPU-control modules, SURVEY §4.2). Supports the same
     position-based masking as :func:`flash_attention`."""
@@ -503,6 +541,9 @@ def reference_attention(q, k, v, causal=True, sm_scale=None,
     )
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)) * sm_scale
     mask = kv_positions[:, None, None, :] <= q_positions[:, None, :, None]
+    if window is not None:
+        mask = mask & (kv_positions[:, None, None, :]
+                       > q_positions[:, None, :, None] - window)
     s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     # fully-masked rows: softmax over all NEG_INF is uniform garbage — zero it

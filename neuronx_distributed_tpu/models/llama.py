@@ -67,7 +67,9 @@ class YarnScaling:
     blends its own frequency with the same over ``factor``, by a linear ramp
     between the dims whose wavelength makes ``beta_fast`` and ``beta_slow``
     turns in ``original_max_position_embeddings`` positions; cos and sin are
-    scaled by ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``."""
+    scaled by ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``, or
+    by ``attention_factor`` where the config gives that number itself (HF
+    ``rope_parameters``: Laguna's full-attention layers)."""
 
     factor: float = 40.0
     original_max_position_embeddings: int = 4096
@@ -75,6 +77,7 @@ class YarnScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+    attention_factor: Optional[float] = None
 
     @staticmethod
     def get_mscale(factor: float, mscale: float) -> float:
@@ -94,6 +97,8 @@ class YarnScaling:
         inv_freq = own / self.factor * ramp + own * (1.0 - ramp)
         amplitude = (self.get_mscale(self.factor, self.mscale)
                      / self.get_mscale(self.factor, self.mscale_all_dim))
+        if self.attention_factor is not None:
+            amplitude = float(self.attention_factor)
         return inv_freq.astype(np.float32), amplitude
 
 
@@ -138,6 +143,16 @@ class LlamaConfig:
     # scale of the attention scores before the softmax (Granite's
     # ``attention_multiplier``); None: 1 / sqrt(head_dim)
     attention_multiplier: Optional[float] = None
+    # "per-head": each head's attention output is multiplied by
+    # ``sigmoid(x W_g)[head]`` before ``o_proj`` (``x`` the layer's normed
+    # input, ``W_g`` (hidden, heads): Laguna's ``gating``). None: no
+    # parameter and no op.
+    attention_gate: Optional[str] = None
+    # a query sees its own position and the ``sliding_window - 1`` before it
+    # (None: every earlier one). A forward pass masks by it
+    # (``flash_attention(window=)``); what a window layer CACHES is a ring a
+    # slot, which ``models/laguna.py`` owns: the decode path here refuses.
+    sliding_window: Optional[int] = None
     # False: q and k are not rotated (a model without positions, HF
     # ``position_embedding_type: "nope"``)
     use_rope: bool = True
@@ -310,7 +325,13 @@ def rotary_embedding(positions: jax.Array, head_dim: int, theta: float,
 
 
 def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Rotate pairs (x1, x2) — x is (b, s, n, d); cos/sin (s, d/2) or (b, s, d/2)."""
+    """Rotate pairs (x1, x2) — x is (b, s, n, d); cos/sin (s, d/2) or (b, s, d/2).
+    Tables narrower than the head (``cfg.rope_dims < head_dim``) rotate the
+    head's first ``2 * cos.shape[-1]`` dims and pass the rest."""
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rotary(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
     x1, x2 = jnp.split(x, 2, axis=-1)
     if cos.ndim == 2:
         cos = cos[None, :, None, :]
@@ -768,8 +789,9 @@ class LlamaAttention(nn.Module):
             q = jnp.clip(q, -cfg.qkv_clip, cfg.qkv_clip)
             k = jnp.clip(k, -cfg.qkv_clip, cfg.qkv_clip)
             v = jnp.clip(v, -cfg.qkv_clip, cfg.qkv_clip)
+        gate = self._head_gate(x) if cfg.attention_gate else None
         if cfg.decode:
-            return self._decode_attention(x, q, k, v, kv, aidx, live)
+            return self._decode_attention(x, q, k, v, kv, aidx, live, gate)
         if cfg.use_rope:
             cos, sin = rope  # computed once in LlamaModel, broadcast through scan
             q = apply_rotary(q, cos, sin)
@@ -800,9 +822,10 @@ class LlamaAttention(nn.Module):
                 use_flash=cfg.use_flash_attention and flash_supported(s, s, blk_q, blk_k),
                 block_q=blk_q,
                 block_k=blk_k,
+                **({"window": cfg.sliding_window} if cfg.sliding_window else {}),
             )
         o = o.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[1], -1)
-        return self._o_proj(o, aidx)
+        return self._o_proj(o, aidx, gate)
 
     def _walk_attention(self, q, kv, walk: KVWalk, table):
         """One new token a row over what ``walk`` reads of the cache: the
@@ -868,8 +891,35 @@ class LlamaAttention(nn.Module):
         y = (yf * jax.lax.rsqrt(var + cfg.rms_norm_eps)).astype(y.dtype)
         return y * scale.astype(y.dtype)
 
-    def _o_proj(self, o, aidx=None):
+    def _head_gate(self, x):
+        """``sigmoid(x W_g)`` (b, s, heads) float32, one scalar a head a
+        token (``cfg.attention_gate``: the headwise form of gated attention)."""
         cfg = self.config
+        if cfg.attention_gate != "per-head":
+            raise ValueError(f"attention_gate {cfg.attention_gate!r}: 'per-head' or None")
+        with jax.named_scope("attn_gate"):
+            w = self.param("gate_kernel", nn.with_partitioning(
+                nn.initializers.variance_scaling(1.0, "fan_in", "normal"), (None, TP_AXIS)),
+                (x.shape[-1], cfg.num_heads), cfg.param_dtype)
+            return jax.nn.sigmoid((x.astype(cfg.dtype) @ w.astype(cfg.dtype)
+                                   ).astype(jnp.float32))
+
+    def _rotate_at(self, q, k, slots):
+        """``q`` and ``k`` (b, s, n, hd) rotated to the positions ``slots``
+        (b, s) of the cache they are written at."""
+        cfg = self.config
+        cos, sin = rotary_embedding(slots, cfg.rope_dims, cfg.rope_theta, dtype=q.dtype,
+                                    scaling=cfg.rope_scaling)
+        return apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+
+    def _o_proj(self, o, aidx=None, gate=None):
+        """``o`` (b, s, heads * hd) through the output projection, each head
+        first multiplied by its ``gate`` (b, s, heads) where there is one."""
+        cfg = self.config
+        if gate is not None:
+            with jax.named_scope("attn_gate"):
+                o = (o.reshape(*gate.shape, -1) * gate[..., None].astype(o.dtype)
+                     ).reshape(o.shape)
         y = RowParallelLinear(
             cfg.hidden_size, use_bias=False,
             sequence_parallel=cfg.sequence_parallel,
@@ -880,7 +930,7 @@ class LlamaAttention(nn.Module):
                                      aidx).astype(y.dtype)
         return y
 
-    def _decode_attention(self, x, q, k, v, kv, aidx=None, live=None):
+    def _decode_attention(self, x, q, k, v, kv, aidx=None, live=None, gate=None):
         """KV-cached path (flax ``cache`` collection; the reference keeps KV
         state in aliased runtime buffers, model_base.py KV management —
         donation of the cache collection is the TPU analogue). The K/V
@@ -906,6 +956,10 @@ class LlamaAttention(nn.Module):
                 "decode-mode attention reads and writes the KV leaves that "
                 "LlamaModel declares and its layer scan carries; apply it "
                 "through LlamaModel (or pass a KVLayerView)")
+        if cfg.sliding_window:
+            raise ValueError(
+                "a window layer's cache is a ring a slot (models/laguna.py); "
+                "this attention would cache and read every position")
         if ps:
             # paged KV (PagedAttention layout, TPU-shaped): a page POOL
             # instead of a per-slot slab; per-slot block tables are a
@@ -944,10 +998,7 @@ class LlamaAttention(nn.Module):
         slots = idx[:, None] + jnp.arange(s_new, dtype=jnp.int32)[None, :]
         rows = jnp.arange(b)[:, None]
         if cfg.use_rope:
-            cos, sin = rotary_embedding(slots, hd, cfg.rope_theta, dtype=q.dtype,
-                                        scaling=cfg.rope_scaling)
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
+            q, k = self._rotate_at(q, k, slots)
         with jax.named_scope("kv_write"):
             if ps:
                 # write through the block table: logical slot -> physical page.
@@ -1035,7 +1086,7 @@ class LlamaAttention(nn.Module):
             walk = kv_walk(cfg, idx, None if live is None else live[:, 0])
             if walk.n_chunks > 1:
                 o = self._walk_attention(q, kv, walk, table if ps else None)
-                return self._o_proj(o.reshape(b, s_new, -1), aidx)
+                return self._o_proj(o.reshape(b, s_new, -1), aidx, gate)
         if ps:
             # in-scan gather: the (b, max_seq_len) logical view the
             # attention below consumes (prompts, chunks; a one-token step
@@ -1091,7 +1142,7 @@ class LlamaAttention(nn.Module):
                 o = cached_attention(q, k_all, v_all, idx,
                                      sm_scale=cfg.attention_multiplier)
         o = o.reshape(b, s_new, -1)
-        return self._o_proj(o, aidx)
+        return self._o_proj(o, aidx, gate)
 
 
 class LlamaMLP(nn.Module):

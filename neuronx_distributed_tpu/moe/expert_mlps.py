@@ -112,6 +112,16 @@ def sort_by_expert(combine: jax.Array, top_k: int,
     return weight, order, place, lax.slice_in_dim(sizes, 0, E)
 
 
+# tokens one grouped call takes. The call sorts ``tokens x top_k`` rows and
+# gathers, multiplies and scatters all of them, real or not: at top-10 of a
+# held share (``models/laguna.py``: an eighth of the rows real) a 5 x 4096
+# insert's class of 32 768 tokens held 5.5 GiB of temporaries beside 7.8 GiB
+# of weights and cache, and on the v5e that program never returned (PERF.md,
+# PR 49). Longer calls go by slices of this many tokens, each its own class;
+# no cell before PR 49 hands over more (DeepSeek-V2's 8 x 2048 is 16 384).
+GROUPED_TOKENS = 16384
+
+
 def token_class(tokens: int) -> int:
     """The token count a grouped call is padded to: the next power of two, 8
     at least. What is traced for one class serves every program of it."""
@@ -123,8 +133,11 @@ def grouped_rows_multiplied(group_sizes: jax.Array, tokens: int, top_k: int
     """Rows the grouped kernel's dots run over in calls of ``tokens`` tokens
     whose group sizes are ``group_sizes (calls, E)``, as
     :func:`_grouped_experts` tiles them: a count for the serving counters, by
-    the kernel's own arithmetic (``kernels/grouped_matmul.py::rows_multiplied``)."""
-    tm, _ = row_tile(token_class(tokens) * top_k, group_sizes.shape[-1])
+    the kernel's own arithmetic (``kernels/grouped_matmul.py::rows_multiplied``).
+    A call of more than ``GROUPED_TOKENS`` goes by slices and is counted here
+    as ONE sort at a slice's tile: each slice's group edges add at most a
+    sub-tile a group, which this leaves out."""
+    tm, _ = row_tile(token_class(min(tokens, GROUPED_TOKENS)) * top_k, group_sizes.shape[-1])
     return rows_multiplied(group_sizes, tm)
 
 
@@ -265,6 +278,12 @@ class ExpertMLPs(nn.Module):
         router's choices; a row has at most ``top_k`` nonzeros and those that
         fell elsewhere are nobody's here (:func:`sort_by_expert`)."""
         T, H = x.shape
+        if T > GROUPED_TOKENS:
+            return jnp.concatenate([
+                self.forward_grouped(x[at: at + GROUPED_TOKENS], combine[at: at + GROUPED_TOKENS],
+                                     top_k, None if live is None else live[at: at + GROUPED_TOKENS],
+                                     stack, share)
+                for at in range(0, T, GROUPED_TOKENS)])
         if stack is None or stack[1]["gate"].dtype != self.dtype:
             # (a stack kept in another dtype would be cast whole, every layer)
             stack = (0, {"gate": self.w_gate.astype(self.dtype)[None],

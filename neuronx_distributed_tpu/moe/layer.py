@@ -64,6 +64,8 @@ class MoE(nn.Module):
     n_group: int = 1
     topk_group: int = 1
     route_scale: float = 1.0
+    # how the router scores an expert: "softmax" | "sigmoid" (moe/routing.py)
+    scoring_func: str = "softmax"
 
     @nn.compact
     def __call__(self, x: jax.Array, live: Optional[jax.Array] = None,
@@ -87,7 +89,10 @@ class MoE(nn.Module):
             router = RouterTopK(routed, top_k=self.top_k,
                                 norm_topk_prob=self.norm_topk_prob,
                                 n_group=self.n_group, topk_group=self.topk_group,
-                                route_scale=self.route_scale, name="router")
+                                route_scale=self.route_scale,
+                                **({} if self.scoring_func == "softmax"
+                                   else {"scoring_func": self.scoring_func}),
+                                name="router")
         elif self.router == "sinkhorn":
             router = RouterSinkhorn(routed, name="router")
         else:

@@ -28,7 +28,13 @@ class RouterTopK(nn.Module):
     probability among its own, the ``topk_group`` best groups stay and the
     top-k is taken inside them. ``route_scale`` multiplies the weights
     (``routed_scaling_factor``). The scores are the softmax's, in float32,
-    whatever the selection."""
+    whatever the selection.
+
+    ``scoring_func="sigmoid"`` scores each expert by ``sigmoid(logit)`` on its
+    own (DeepSeek-V3's router, without its selection bias): the top-k by
+    score, their scores renormalised (``norm_topk_prob``) and scaled. The
+    selection, the groups and the weights read ``scores`` wherever the
+    softmax's probabilities stood."""
 
     num_experts: int
     top_k: int = 2
@@ -36,17 +42,21 @@ class RouterTopK(nn.Module):
     n_group: int = 1
     topk_group: int = 1
     route_scale: float = 1.0
+    scoring_func: str = "softmax"      # | "sigmoid"
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring_func {self.scoring_func!r}: 'softmax' or 'sigmoid'")
         # router weight is replicated (the reference's LinearRouter with
         # weight-grad all-reduce, moe_parallel_layers.py:348)
         w = self.param("kernel", default_kernel_init, (x.shape[-1], self.num_experts),
                        self.param_dtype)
         logits = (x.astype(jnp.float32) @ w.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)
+        probs = (jax.nn.sigmoid(logits) if self.scoring_func == "sigmoid"
+                 else jax.nn.softmax(logits, axis=-1))
         eligible = probs
         if self.n_group > 1:
             with jax.named_scope("router_groups"):

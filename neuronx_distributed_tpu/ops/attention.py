@@ -38,20 +38,26 @@ def attention(
     block_k: int = 128,
     q_positions: Optional[jax.Array] = None,
     kv_positions: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Multi-head attention over BHSD tensors; K/V may carry fewer (GQA)
     heads. Heads must be TP-sharded (the GQA QKV layer's output layout).
 
     ``q_positions``/``kv_positions`` ((b, sq)/(b, sk) int32) select the
     position-based mask (padded prompts, KV-cache decode — see
-    kernels/flash_attn.py); defaults are (bottom-aligned) causal."""
+    kernels/flash_attn.py); defaults are (bottom-aligned) causal. ``window``
+    (forward only): a key must also lie within ``window`` positions of its
+    query; None passes nothing on and is the call it always was."""
+    windowed = {} if window is None else {"window": window}
     if not use_flash:
         return reference_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                                   q_positions=q_positions, kv_positions=kv_positions)
+                                   q_positions=q_positions, kv_positions=kv_positions,
+                                   **windowed)
     if not ps.model_parallel_is_initialized():
         return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                                block_q=block_q, block_k=block_k,
-                               q_positions=q_positions, kv_positions=kv_positions)
+                               q_positions=q_positions, kv_positions=kv_positions,
+                               **windowed)
     mesh = ps.get_mesh()
     spec = P(DP_AXES, TP_AXIS, None, None)
     pos_spec = P(DP_AXES, None)  # positions are per-batch, replicated over TP
@@ -63,7 +69,8 @@ def attention(
 
     def call(q, k, v, qp, kp):
         return flash_attention(q, k, v, sm_scale=sm_scale, block_q=block_q,
-                               block_k=block_k, q_positions=qp, kv_positions=kp)
+                               block_k=block_k, q_positions=qp, kv_positions=kp,
+                               **windowed)
 
     # check_vma=False: pallas_call out_shapes don't carry vma annotations
     return shard_map(

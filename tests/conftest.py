@@ -53,6 +53,29 @@ collect_ignore = ["fixtures"]
 # day a ``benchmark`` PR moves the snapshot, this fails the run and has to go.
 _PINS_THE_LAST_SEVEN = "test_the_seven_are_listed_where_the_issue_says_and_nothing_else_moved"
 
+# PR 49 (``model_config``) adds the sixth open-loop cell, ``laguna-s-2.1.longctx``,
+# a seventh configuration and three metrics, all at the END of their lists, and
+# may edit no file under ``tests/benchmark/`` that was there. Three snapshots
+# cannot hold beside it, each by ONE assertion of a place, a count or a key:
+# ``test_bm_latent.py``'s list of the FIVE open-loop cells with DeepSeek-V2's
+# last in ``tpot_ms_p50``; ``test_bm_hybrid.py``'s Granite as the LAST workload
+# and configuration; and ``test_bm_latent.py``'s case that
+# ``moe.local_assignment_share`` finds nothing to read in a record of any OTHER
+# configuration, which goes by the key ``router_experts`` and Laguna holds a
+# share too (the metric lists DeepSeek-V2's cell alone all the same).
+# ``tests/benchmark/test_bm_window.py`` asserts what each guarded, by name, for
+# the cells that exist. ``strict``, as above: the ``benchmark`` PR that moves
+# the snapshots (ROADMAP S13q) takes these out.
+_PR_49_MOVED = {
+    "test_bm_latent.py::test_the_median_time_per_token_is_judged_in_the_open_loop_cells_and_no_other":
+        "lists five open-loop cells, DeepSeek-V2's last; PR 49 appends the sixth",
+    "test_bm_hybrid.py::test_the_cell_is_judged_on_tokens_per_second_and_setup":
+        "wants Granite's the last workload and configuration; PR 49 appends Laguna's",
+    "test_bm_latent.py::test_new_reader_is_silent_on_another_configurations_record"
+    "[laguna-s-2.1-moe.local_assignment_share]":
+        "Laguna holds a share of its routed experts too: the reader has something to read",
+}
+
 
 def pytest_collection_modifyitems(items):
     for item in items:
@@ -60,6 +83,10 @@ def pytest_collection_modifyitems(items):
             item.add_marker(pytest.mark.xfail(
                 strict=True, reason="pins the last seven names of per_layer; PR 44's four are "
                                     "appended after them (tests/conftest.py says why)"))
+        for tail, why in _PR_49_MOVED.items():
+            if item.nodeid.endswith(tail):
+                item.add_marker(pytest.mark.xfail(
+                    strict=True, reason=f"{why} (tests/conftest.py says why)"))
 
 
 @pytest.fixture(autouse=True)

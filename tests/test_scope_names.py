@@ -49,7 +49,7 @@ LATENT_SCOPES = {
     "moe/routing.py RouterTopK": ["router_groups"],
     "models/deepseek_v2.py DeepseekV2MoELayer": ["shared_expert"],
 }
-KERNELS = ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "fused_adamw",
+KERNELS = ["flash_fwd", "flash_fwd_window", "flash_bwd_dkv", "flash_bwd_dq", "fused_adamw",
            "grouped_matmul", "ssm_step"]
 # Granite-4.0-H's scopes (PRs 44, 45), all inside the flax module ``mamba``.
 # ``scope_parts.json`` has no rows for them either (PERF.md section 7).
@@ -58,6 +58,17 @@ MAMBA_SCOPES = {
                                              "ssm_out_proj"],
     "fused_decode": ["ssm_step"],                   # one token a row on a state
     "paged_insert": ["ssm_scan", "state_rows"],     # a prompt; causal_lm.py's rows of the slots
+}
+
+# Laguna's scopes (PR 49). ``scope_parts.json`` has no rows for the new ones
+# (PERF.md section 7): under the flax module ``attention`` they sort as
+# attention, the full layers' ``kv_write`` / ``kv_gather`` / ``attend`` as ever.
+WINDOW_SCOPES = {
+    "models/laguna.py LagunaAttention": ["attend_window", "ring_write", "rope_full",
+                                         "rope_window"],
+    "models/llama.py LlamaAttention (attention_gate)": ["attn_gate"],
+    "models/laguna.py LagunaLayer": ["shared_expert"],
+    "paged_insert": ["state_rows", "flash_fwd_window"],     # causal_lm.py's rows of the slots
 }
 
 TINY = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2, num_heads=4,
@@ -225,6 +236,50 @@ def test_granite_names_the_mixers_stages_and_the_step_kernel(program):
     under = [m.group(1) for m in map(OP_NAME.search, compiled.as_text().splitlines())
              if m and "ssm_step" in m.group(1)]
     assert all("/mamba/ssm_step/" in name for name in under), under[:3]
+
+
+@pytest.mark.parametrize("program", ["fused_decode", "paged_insert"])
+def test_laguna_names_the_ring_the_window_read_the_gate_and_both_ropes(program):
+    """The tiny Laguna decode block and paged insert carry a window layer's
+    ring write and its read (one attend over the ring in a step, the windowed
+    flash call over the prompt itself in an insert), the gate, a rope a kind,
+    the shared expert, and for the full layers the page write and the read
+    under the names every GQA model uses; the insert moves the slots' rings
+    under ``state_rows`` and gathers no ring."""
+    from neuronx_distributed_tpu.models.laguna import (
+        FULL,
+        SLIDING,
+        LagunaConfig,
+        LagunaForCausalLM,
+    )
+
+    cfg = LagunaConfig(**dict(
+        TINY, num_layers=5, head_dim=8, layer_types=[FULL, SLIDING, SLIDING, SLIDING, FULL],
+        num_heads_per_layer=[4, 6, 6, 6, 4], sliding_window=8, moe_intermediate_size=16,
+        shared_expert_intermediate_size=16, num_experts=8, router_experts=16, top_k=3,
+        rope_parameters={FULL: dict(rope_type="yarn", rope_theta=5e5, factor=8.0,
+                                    original_max_position_embeddings=64, attention_factor=1.2,
+                                    partial_rotary_factor=0.5),
+                         SLIDING: dict(rope_type="default", rope_theta=1e4)}))
+    weights = meta.unbox(LagunaForCausalLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    lm = CausalLM(cfg, weights, LagunaForCausalLM, buckets=(128,), max_batch=2, page_size=16,
+                  prefix_cache=False)
+    decode = program == "fused_decode"
+    compiled = (lm.compile_session_decode_fused(4) if decode
+                else lm._paged_insert_programs(2, 128))
+    components, parts = census(compiled)
+    want = {n for where, names in WINDOW_SCOPES.items() for n in names if where != "paged_insert"}
+    want |= {"kv_write", "kv_gather", "attend", "grouped_matmul", "first", "periods"}
+    want |= set(WINDOW_SCOPES["paged_insert"]) | {"flash_fwd"} if not decode else set()
+    assert want <= components, sorted(want - components)
+    if decode:
+        assert not set(WINDOW_SCOPES["paged_insert"]) & components
+    for part in ("kv_write", "kv_gather", "attend", "router", "experts", "ffn", "norm"):
+        assert parts[part] > 0, part
+    under = [m.group(1) for m in map(OP_NAME.search, compiled.as_text().splitlines())
+             if m and "attend_window" in m.group(1)]
+    assert under and all("/attention/" in name.split("attend_window")[0] for name in under), under[:3]
 
 
 def test_dense_decode_has_no_qk_norm_scope(params):

@@ -32,7 +32,13 @@ What is this file's and what is the stack's:
   layer needs its last ``sliding_window`` tokens: cache leaves ``window_key``
   / ``window_value``, ONE ROW A SLOT of ``ring`` tokens
   (``LagunaConfig.slot_row_leaves``), stacked over the window layers only,
-  beside K/V pages stacked over the full layers only. The token at position
+  beside K/V pages stacked over the full layers only. A row is HEAD-MAJOR,
+  ``(n_kv, ring, hd)``: the one-token read contracts it with the KV head as
+  a batch dimension, and a leaf that kept the head inside the ring slot had
+  the WHOLE stacked leaf re-laid-out ahead of the slice that takes a layer's
+  rows, in every branch of the rungs' switch (PERF.md, PR 50). For the same
+  reason a step takes its rows out of the stack by slices, never by an array
+  index. The token at position
   ``p`` lies at ``p % ring``; which position a ring slot holds follows from
   the row's length, and the mask goes by that position, so a slot's last
   tenant is never seen. ``CausalLM`` moves these rows where it moves block
@@ -222,7 +228,7 @@ class LagunaConfig(MixtralConfig):
         a full layer's K/V pages or slab, a window layer's ring rows."""
         leaves = kv_page_leaf_shapes(self, batch)
         if self.slot_row_leaves:
-            row = ((batch, self.ring, self.num_kv_heads, self.head_dim_),
+            row = ((batch, self.num_kv_heads, self.ring, self.head_dim_),
                    jnp.dtype(self.dtype))
             leaves[WINDOW_KEY] = leaves[WINDOW_VALUE] = row
         return leaves
@@ -287,21 +293,29 @@ class LagunaAttention(LlamaAttention):
             # ``ring`` REAL tokens go to the ring, the bucket's padding does not
             real = (jnp.full((b,), s_new, jnp.int32) if live is None
                     else jnp.sum(live, axis=1, dtype=jnp.int32))
+            qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))   # head-major, as the ring
             with jax.named_scope("ring_write"):
-                take = jnp.clip(held(real - 1), 0, s_new - 1)[:, :, None, None]
-                for name, new in ((WINDOW_KEY, k), (WINDOW_VALUE, v)):
-                    flat = kv.flat(name)                          # (L_w * b, ring, n_kv, hd)
+                # ring slot j takes the token at ``take[j]``, picked by a
+                # product with a 0/1 matrix (exact: one 1 a row), not by a
+                # gather. A gather's output has its index outermost: along
+                # the tokens it drags the leaf it is written to into
+                # token-major for the whole program, and the two forms over
+                # head-major K/V that do not (a (row, head) at a time, or
+                # along axis 2) crashed and hung the v5e (PERF.md, PR 50)
+                take = jnp.clip(held(real - 1), 0, s_new - 1)
+                pick = (take[:, :, None] == jnp.arange(s_new, dtype=jnp.int32)).astype(k.dtype)
+                for name, new in ((WINDOW_KEY, kt), (WINDOW_VALUE, vt)):
+                    flat = kv.flat(name)                          # (L_w * b, n_kv, ring, hd)
+                    tokens = jnp.einsum("bjs,bksd->bkjd", pick, new, **_EXACT)
                     kv.put(name, jax.lax.dynamic_update_slice_in_dim(
-                        flat, jnp.take_along_axis(new, take, axis=1).astype(flat.dtype),
-                        first, axis=0))
+                        flat, tokens.astype(flat.dtype), first, axis=0))
             ci.value = idx + s_new
             from neuronx_distributed_tpu.kernels.flash_attn import flash_supported
 
             blk = min(cfg.attention_block_q or 512, s_new)
             with jax.named_scope("attend_window"):
                 o = attention(
-                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-                    causal=True, sm_scale=cfg.attention_multiplier,
+                    qt, kt, vt, causal=True, sm_scale=cfg.attention_multiplier,
                     use_flash=(cfg.use_flash_attention and s_new >= 128
                                and flash_supported(s_new, s_new, blk, blk)),
                     block_q=blk, block_k=blk, window=window).transpose(0, 2, 1, 3)
@@ -311,30 +325,39 @@ class LagunaAttention(LlamaAttention):
         # live writes nothing: its ring is its next tenant's or nobody's),
         # then ONE read of the rings of the rung of rows that holds the live ones
         row_live = None if live is None else live[:, 0]
+        n_kv = k.shape[2]
         with jax.named_scope("ring_write"):
             rows = first + jnp.arange(b)
             if row_live is not None:
                 rows = jnp.where(row_live, rows, kv.leaves[WINDOW_KEY].shape[0] * b)
+            # a (row, head) at a time over the leaf as (rows x n_kv, ring, hd),
+            # a free reshape: a scatter wants its index outermost, and one
+            # over (row, ring slot) with the head between them re-laid-out the
+            # whole leaf token-major for the block and back in every branch
+            at = (rows[:, None] * n_kv + jnp.arange(n_kv)).reshape(-1)
+            slot_at = jnp.repeat(idx % ring, n_kv)
             for name, new in ((WINDOW_KEY, k), (WINDOW_VALUE, v)):
                 flat = kv.flat(name)
-                kv.put(name, flat.at[rows, idx % ring].set(
-                    new[:, 0].astype(flat.dtype), mode="drop"))
+                kv.put(name, flat.reshape(-1, ring, hd).at[at, slot_at].set(
+                    new[:, 0].reshape(-1, hd).astype(flat.dtype), mode="drop"))
         ci.value = idx + 1
-        n_kv = k.shape[2]
         scale = cfg.attention_multiplier or 1.0 / hd ** 0.5
 
         def attend(top):
             def rings(name):
+                # by slices: an array index over the stacked rows would slice
+                # ALL of them into fast memory first (a "mini gather")
                 flat = kv.flat(name)
                 if jnp.ndim(top.slab) == 0:     # the batch as it stands
                     return jax.lax.dynamic_slice_in_dim(flat, top.slab, top.idx.shape[0])
-                return flat[top.slab]           # the picked rows
+                return jnp.concatenate([jax.lax.dynamic_slice_in_dim(flat, row, 1)
+                                        for row in top.slab])    # the picked rows
             at = held(top.idx)
             seen = (at >= 0) & (at > top.idx[:, None] - window)
             qg = top.q.reshape(-1, n_kv, n // n_kv, hd)
-            scores = jnp.einsum("bkgd,bjkd->bkgj", qg, rings(WINDOW_KEY), **_EXACT) * scale
+            scores = jnp.einsum("bkgd,bkjd->bkgj", qg, rings(WINDOW_KEY), **_EXACT) * scale
             probs = jax.nn.softmax(jnp.where(seen[:, None, None], scores, -1e30), axis=-1)
-            return jnp.einsum("bkgj,bjkd->bkgd", probs, rings(WINDOW_VALUE), **_EXACT)
+            return jnp.einsum("bkgj,bkjd->bkgd", probs, rings(WINDOW_VALUE), **_EXACT)
 
         walk = kv_walk(cfg, idx, row_live)
         rows = walk.rows(q, None, first)

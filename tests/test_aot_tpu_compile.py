@@ -18,6 +18,8 @@ import dataclasses
 import math
 import os
 import re
+import sys
+from pathlib import Path
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 
@@ -38,6 +40,9 @@ from neuronx_distributed_tpu.kernels.flash_attn import (
 from neuronx_distributed_tpu.kernels.ssm_step import ssm_step
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.optimizer.fused_kernel import fused_adamw_leaf
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "scripts"))
+from big_ops import big_ops  # noqa: E402 — the listing tool's reader of a compiled text
 
 HEAD_DIM = 128
 
@@ -422,18 +427,21 @@ def _described_lm(chip, family, monkeypatch):
             vocab_size=256, hidden_size=32 * HEAD_DIM, intermediate_size=1024,
             num_heads=32, num_kv_heads=8, num_layers=2, max_seq_len=4096,
             dtype=jnp.bfloat16, param_dtype=jnp.bfloat16), LlamaForCausalLM
-    elif family == "deepseek":    # the benchmark's rehearsal configuration
+    elif family in ("deepseek", "laguna"):
+        # the benchmark's configuration: DeepSeek-V2's rehearsal one, Laguna's
+        # as ``laguna-s-2.1.longctx`` runs it (9 layers, 32 experts held, 8192 slots)
         import json
-        from pathlib import Path
 
         from benchmark import run as harness
         from benchmark.drivers import serving
 
         root = Path(harness.__file__).resolve().parents[1]
+        name = {"deepseek": "deepseek-v2", "laguna": "laguna-s-2.1"}[family]
         entry = next(c for c in json.loads((root / "BENCHMARK.json").read_text())["configs"]
-                     if c["name"] == "deepseek-v2")
-        loaded = harness.load_config(entry, rehearse=True)
-        cfg = serving.model_config(loaded, False, max_seq_len=4096, remat_policy=None)
+                     if c["name"] == name)
+        loaded = harness.load_config(entry, rehearse=family == "deepseek")
+        cfg = serving.model_config(loaded, False, remat_policy=None,
+                                   max_seq_len=4096 if family == "deepseek" else 8192)
         cls = serving.load(loaded["builder"]["model"])
     else:
         cfg, cls = _moe_config(family, num_layers=2, max_seq_len=4096,
@@ -442,7 +450,9 @@ def _described_lm(chip, family, monkeypatch):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
         meta.unbox(jax.eval_shape(lambda: cls(cfg).init(
             jax.random.key(0), jnp.zeros((1, 8), jnp.int32))))["params"])
-    return CausalLM(cfg, params, cls, buckets=(128,), max_batch=8, page_size=16)
+    # a ring a slot is not served beside the prefix cache (a hit would continue a row)
+    return CausalLM(cfg, params, cls, buckets=(128,), max_batch=8, page_size=16,
+                    prefix_cache=family != "laguna")
 
 
 @pytest.mark.parametrize("family", ["llama", "olmoe", "mixtral", "deepseek"])
@@ -477,6 +487,55 @@ def test_fused_session_decode_takes_its_rows_as_one_matrix_and_donates_the_cache
         assert memory.temp_size_in_bytes < min(sizes)
     for leaf in leaves:
         assert not _leaf_copies(text, leaf.shape)
+
+
+_LAGUNA_BLOCK = {}     # the compiled block, once for the four cases below
+
+
+@pytest.mark.parametrize("rung", [1, 2, 4, 8])
+def test_laguna_window_step_moves_its_rows_of_one_ring(chip, monkeypatch, rung):
+    """``laguna-s-2.1.longctx``'s fused decode block as ``CausalLM`` builds it
+    (published widths, 9 layers: 6 window layers' rings of 528 tokens stacked
+    ``(6, 8, 8, 528, 128)``, 72 query heads over 8 KV heads of 128), for the
+    described v5e. A window layer's one-token read contracts its ring with the
+    KV head as a batch dimension: with the head INSIDE the ring slot the
+    compiler re-laid-out the whole stacked leaf (49.5 MiB, K and V) ahead of
+    the slice in the branches of rungs 1 and 8, and lowered the array index
+    of rungs 2 and 4 as a mini gather that slices all 48 stacked rows
+    (``bf16[48,256,8,128]``, four a branch): 96-99 MiB moved a window
+    layer-step where a row needs 2.16 MB (PERF.md, PR 50). Head-major, and the
+    rows taken by slices: (a) nothing but the write in place has the stacked
+    leaf's element count, nothing the mini gather's; (b) under the branch of
+    ``rung`` rows nothing is larger than one layer's eight rows, and nothing
+    of the ring's dtype larger than the rung's rows."""
+    if not _LAGUNA_BLOCK:
+        lm = _described_lm(chip, "laguna", monkeypatch)
+        compiled = lm.compile_session_decode_fused(8, SlotSampler(), 0)
+        _LAGUNA_BLOCK.update(
+            text=compiled.as_text(), temp=compiled.memory_analysis().temp_size_in_bytes,
+            leaf=next(leaf.shape for path, leaf in
+                      jax.tree_util.tree_flatten_with_path(lm._cache_avals())[0]
+                      if jax.tree_util.keystr(path).endswith("['window_key']")))
+    text, leaf = _LAGUNA_BLOCK["text"], _LAGUNA_BLOCK["leaf"]
+    layers, b = leaf[:2]
+    assert (layers, b, math.prod(leaf[2:])) == (6, 8, 8 * 528 * HEAD_DIM)
+    row = math.prod(leaf[2:]) * 2                 # one slot's ring in one layer, bf16
+    ops = big_ops(text, 2 ** 14)
+    whole = [op for op in ops                     # the stacked leaf, in any shape
+             if op["bytes"] == layers * b * row and op["shape"].startswith("bf16")]
+    moved = [op for op in whole if not op["in_place"]]
+    assert not moved, [(op["kind"], op["op"], op["shape"], op["computation"]) for op in moved]
+    assert len(whole) == 2 * 3 and all("ring_write" in op["op_name"] for op in whole)
+    gathered = [op for op in ops if op["shape"].startswith(f"bf16[{layers * b},256,")]
+    assert not gathered, [(op["op"], op["shape"], op["computation"]) for op in gathered]
+    branch = f"attend_window/cond/branch_{(1, 2, 4, 8).index(rung)}_fun"
+    under = [op for op in ops if branch in op["op_name"]]
+    assert under                                  # the check can see the branch
+    assert max(op["bytes"] for op in under) <= b * row
+    assert max((op["bytes"] for op in under if op["shape"].startswith("bf16")),
+               default=0) <= rung * row
+    print(f"laguna fused block: temporaries {_LAGUNA_BLOCK['temp'] / 2 ** 20:.1f} MiB")
+    assert _LAGUNA_BLOCK["temp"] < 571 * 2 ** 20  # the parent's 571.6 MiB
 
 
 def test_fused_adamw_leaf(chip):

@@ -91,10 +91,11 @@ def want(params):
     return np.asarray(reference.forward(params, jnp.asarray(IDS[:2]), SIZES))
 
 
-def padded(rows, lens):
+def padded(rows, lens, ids=None):
+    ids = IDS if ids is None else ids
     prompts = np.zeros((len(rows), int(max(lens))), np.int32)
     for i, (r, n) in enumerate(zip(rows, lens)):
-        prompts[i, :n] = IDS[r, :n]
+        prompts[i, :n] = ids[r, :n]
     return prompts
 
 
@@ -180,6 +181,70 @@ def test_a_dead_rows_ring_is_left_alone(lm, params):
     before = ring(1), ring(0)
     lm.step(session, np.asarray([5, 6, 0, 0], np.int32))
     assert (ring(1) == before[0]).all() and not (ring(0) == before[1]).all()
+
+
+# ----------------------------------------------------------- the rungs' read
+
+# eight slots at a table of 4 096 (chunks of 512: the walk's ladder is 1, 2, 4,
+# 8 rows): prompts past a wrap of the ring of 24 (30, 47, 60), one a step short
+# of it (23: it wraps under the steps), rows shorter than the window of 8 (5,
+# 3), one exactly as long, one between
+RUNG_LENS = np.asarray([30, 5, 47, 11, 3, 23, 8, 60])
+RUNG_STEPS = 3
+RUNG_IDS = np.random.RandomState(6).randint(1, 128, (8, 64)).astype(np.int32)
+LIVE_ROWS = {
+    "1_row": [5], "2_rows": [1, 4], "3_rows_of_rung_4": [0, 5, 7], "4_rows": [0, 2, 4, 6],
+    "7_rows_of_rung_8": [0, 1, 2, 4, 5, 6, 7], "8_rows": list(range(8)),
+}
+
+
+@pytest.fixture(scope="module")
+def rung_lm(params):
+    world()
+    return serving_lm(params, config=dict(max_seq_len=4096), buckets=(64,), max_batch=8).compile()
+
+
+@pytest.fixture(scope="module")
+def rung_want(params):
+    return np.asarray(reference.forward(params, jnp.asarray(RUNG_IDS), SIZES))
+
+
+@pytest.mark.parametrize("case", sorted(LIVE_ROWS))
+def test_every_rung_reads_its_rows_windows_and_writes_no_dead_rows_ring(
+        rung_lm, rung_want, case):
+    """One-token steps with 1, 2, 3, 4, 7 and 8 of 8 rows live, dead rows
+    between the live ones: each live row's logits are the full-attention
+    reference's, masked to the window (the rows of a rung are sorted by reach,
+    taken out of the stacked head-major leaf by slices and put back), and a
+    dead row's rings keep every byte in every window layer."""
+    world()
+    live = LIVE_ROWS[case]
+    dead = sorted(set(range(8)) - set(live))
+    session = rung_lm.start_session()
+    rung_lm.insert(session, np.arange(8), padded(range(8), RUNG_LENS, RUNG_IDS),
+                   lengths=RUNG_LENS, reserve_tokens=RUNG_STEPS + 2)
+    if dead:
+        rung_lm.retire(session, dead)
+
+    def rings():
+        return [np.asarray(leaf) for path, leaf in
+                jax.tree_util.tree_flatten_with_path(session.cache)[0]
+                if "window_" in jax.tree_util.keystr(path)]
+
+    before = rings()
+    assert len(before) == 2 and before[0].shape == (3, 8, 2, RING, 8)
+    scale = np.abs(rung_want).max()
+    for t in range(RUNG_STEPS):
+        tok = np.zeros((8,), np.int32)
+        tok[live] = [RUNG_IDS[r, RUNG_LENS[r] + t] for r in live]
+        got = np.asarray(rung_lm.step(session, tok))
+        for r in live:
+            assert distance(got[r], rung_want[r, RUNG_LENS[r] + t], scale) <= TOL, (r, t)
+    for was, now in zip(before, rings()):
+        assert (now[:, dead] == was[:, dead]).all()
+        for r in live:      # RUNG_STEPS new tokens a layer, each at its position % RING
+            changed = np.nonzero((now[:, r] != was[:, r]).any(axis=(0, 1, 3)))[0]
+            assert sorted(changed) == sorted((RUNG_LENS[r] + t) % RING for t in range(RUNG_STEPS))
 
 
 # ----------------------------------------------------------- planted faults
@@ -311,7 +376,7 @@ def test_the_leaves_are_stacked_by_kind_and_a_ring_does_not_grow_with_the_table(
               for p, leaf in jax.tree_util.tree_flatten_with_path(lm._cache_avals())[0]}
     pages = lm.config.page_pool_pages
     assert shapes["cached_key"] == shapes["cached_value"] == (2, pages, 16, 2, 8)
-    assert shapes["window_key"] == shapes["window_value"] == (3, 4, RING, 2, 8)
+    assert shapes["window_key"] == shapes["window_value"] == (3, 4, 2, RING, 8)
     sizes = lm.kv_cache_bytes()
     assert sizes["kv_bytes"] == 2 * 2 * pages * 16 * 2 * 8 * 4
     assert sizes["window_bytes"] == 2 * 3 * 4 * RING * 2 * 8 * 4 and "state_bytes" not in sizes
@@ -327,7 +392,7 @@ def test_the_published_sizes_hold_a_ring_of_528_and_the_reckoned_parameters():
     assert (cfg.period, cfg.ring, cfg.layers_of(FULL), cfg.layers_of(SLIDING)) == (4, 528, 3, 6)
     assert cfg.layer_types == (FULL, SLIDING, SLIDING, SLIDING) * 2 + (FULL,)
     leaves = cfg.kv_leaf_shapes(8)
-    assert leaves["window_key"][0] == (8, 528, 8, 128)
+    assert leaves["window_key"][0] == (8, 8, 528, 128)      # head-major: (slots, n_kv, ring, hd)
     assert leaves["cached_key"][0] == (8 * 513, 16, 8, 128)
     full, window = cfg.of_kind(FULL), cfg.of_kind(SLIDING)
     assert (full.num_heads, full.rope_dims, full.sliding_window) == (48, 64, None)
@@ -408,7 +473,7 @@ def test_an_extend_that_continues_a_row_is_refused(lm, served):
 
 
 def test_the_ring_leaves_are_not_served_across_tp(params, monkeypatch):
-    assert leaf_partition_spec("['model']['window_key']", (6, 8, 528, 8, 128), 4) == PartitionSpec()
+    assert leaf_partition_spec("['model']['window_key']", (6, 8, 8, 528, 128), 4) == PartitionSpec()
     world()
     monkeypatch.setattr(causal_lm, "tp_degree", lambda: 4)
     with pytest.raises(ValueError, match="tensor parallelism"):

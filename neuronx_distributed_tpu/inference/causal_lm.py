@@ -38,7 +38,7 @@ from neuronx_distributed_tpu.inference.partition import (
 )
 from neuronx_distributed_tpu.inference.sampling import Sampler, SlotSampler
 from neuronx_distributed_tpu.models.llama import kv_walk
-from neuronx_distributed_tpu.moe.expert_mlps import grouped_rows_multiplied
+from neuronx_distributed_tpu.moe.expert_mlps import grouped_rows_multiplied, share_call_sums
 
 PyTree = Any
 
@@ -1288,13 +1288,16 @@ class CausalLM:
 
         A model with experts (``self.moe_stats``) is told which tokens are
         real (each row's ``new_len - starts`` suffix; the bucket's padding
-        chooses no expert) and returns one more value, LAST: ``(5,) int32``
-        (six where a share of the experts is held), the sums of the fused
-        session decode taken over the real tokens (expert slots touched,
-        assignments, layers run), the grouped rows the experts were handed,
-        real or not (layers x rows x bucket x top_k), and the rows the grouped
-        kernel's dots ran over (``moe/expert_mlps.py::grouped_rows_multiplied``:
-        the sub-tiles each layer's groups touch).
+        chooses no expert) and returns one more value, LAST: ``(5,) int32``,
+        the sums of the fused session decode taken over the real tokens
+        (expert slots touched, assignments, layers run), the grouped rows the
+        experts were handed, real or not (layers x rows x bucket x top_k), and
+        the rows the grouped kernel's dots ran over
+        (``moe/expert_mlps.py::grouped_rows_multiplied``: the sub-tiles each
+        layer's groups touch). Where a share of the experts is held it is
+        ``(7,)``: the fourth routing sum, then what the layers' compact passes
+        really handled (``moe/expert_mlps.py::share_call_sums``: passes x the
+        row bound, the rows multiplied at the passes' tile, the passes).
 
         A model with per-slot state (``self.slot_rows``) is told the same
         (``self.wants_live``), runs over its rows' state gathered at ``slots``
@@ -1308,6 +1311,7 @@ class CausalLM:
         sampler = sampler or SlotSampler()
         ppseq = self.config.max_seq_len // self.config.page_size
         moe = self.moe_stats
+        share = self.moe_sums == 4      # the expert layers hold a share of the routed
         state_leaves = self._slot_row_ends
 
         def insert_fn(params, cache, slot_keys, first, ids, tables, slots,
@@ -1345,13 +1349,17 @@ class CausalLM:
                 with jax.named_scope("bookkeeping"):
                     top_k = min(self.config.top_k, self.config.num_experts)
                     chosen, routed = _chosen(mut["moe_stats"], live)
-                    sizes = jnp.sum(chosen, axis=1, dtype=jnp.int32)  # (layers, E)
-                    grouped_rows = sizes.shape[0] * rows * bucket * top_k
-                    sums = self._replicate_out((jnp.concatenate([
-                        _routing_sums(chosen, routed, live),
-                        jnp.full((1,), grouped_rows, jnp.int32),
-                        grouped_rows_multiplied(sizes, rows * bucket, top_k
-                                                ).reshape(1)]),))
+                    if not share:          # every expert held: every pick is a row
+                        sizes = jnp.sum(chosen, axis=1, dtype=jnp.int32)  # (layers, E)
+                        grouped_rows = sizes.shape[0] * rows * bucket * top_k
+                    parts = [_routing_sums(chosen, routed, live)]
+                    if share:              # the passes' rows, and the passes
+                        parts.append(share_call_sums(chosen, top_k, self.config.router_experts))
+                    else:
+                        parts += [jnp.full((1,), grouped_rows, jnp.int32),
+                                  grouped_rows_multiplied(sizes, rows * bucket, top_k
+                                                          ).reshape(1)]
+                    sums = self._replicate_out((jnp.concatenate(parts),))
             if self.scans:
                 with jax.named_scope("bookkeeping"):
                     sums = (*sums, *self._replicate_out((jnp.stack([

@@ -347,14 +347,20 @@ _STAT_KEYS = (
     "moe_assignments_routed",
     # the same three over the real tokens of the paged inserts (bucket
     # padding chooses nothing; CausalLM._paged_insert_programs), and the
-    # grouped rows the inserts' experts were handed, real or not, and the rows
+    # grouped rows the inserts' experts gathered, multiplied and combined,
+    # real or not (every pick where every expert is held; passes x the row
+    # bound where a share is, moe/expert_mlps.py::row_bound), and the rows
     # the grouped kernel's dots ran over (the sub-tiles its groups touched:
     # kernels/grouped_matmul.py::rows_multiplied): assignments / rows is the
-    # share of the sorted list that was real work, assignments /
+    # share of the rows handled that was real work, assignments /
     # rows_multiplied the share of the MXU's, touched / layer_calls the
-    # experts an insert's layer read
+    # experts an insert's layer read. moe_insert_passes: the passes the
+    # layer calls made over their sorted lists (one a call where every expert
+    # is held; ceil(real picks / bound) a slice where a share is), so
+    # passes / layer_calls reads 1.0 where the bound always held
     "moe_insert_experts_touched", "moe_insert_assignments",
     "moe_insert_layer_calls", "moe_insert_rows", "moe_insert_rows_multiplied",
+    "moe_insert_passes",
     # the insert's twins of program_calls / host_fetches: compiled-program
     # calls that admitted requests (an insert; each chunk extend of a chunked
     # or replayed admission) and fetches of their first tokens. An insert is
@@ -3737,13 +3743,16 @@ class ServeEngine:
 
     def _count_insert_routing(self, sums) -> None:
         """One paged insert's routing sums into ``stats``."""
-        # (3 or 4 routing sums, grouped rows, rows the kernel multiplied)
-        *routing, rows, multiplied = sums
-        for name, x in zip(("experts_touched", "assignments", "layer_calls"),
-                           routing):
-            self.stats["moe_insert_" + name] += int(x)
-        self.stats["moe_insert_rows"] += int(rows)
-        self.stats["moe_insert_rows_multiplied"] += int(multiplied)
+        # (3 routing sums, grouped rows, rows the kernel multiplied; where a
+        # share is held 4 routing sums and, last, the passes)
+        touched, assigned, layer_calls, *rest = (int(x) for x in sums)
+        rows, multiplied, passes = rest[1:] if len(rest) == 4 else (*rest, layer_calls)
+        self.stats["moe_insert_experts_touched"] += touched
+        self.stats["moe_insert_assignments"] += assigned
+        self.stats["moe_insert_layer_calls"] += layer_calls
+        self.stats["moe_insert_rows"] += rows
+        self.stats["moe_insert_rows_multiplied"] += multiplied
+        self.stats["moe_insert_passes"] += passes
 
     def step_block(self) -> bool:
         """One scheduling round: drain recovery replays, admit (expire/shed

@@ -74,7 +74,10 @@ def sort_by_expert(combine: jax.Array, top_k: int,
     ``share``: ``combine`` is the held share of a wider router's choices
     (``moe/layer.py``), so a token has up to ``top_k`` nonzero weights here
     and often none. A choice of weight zero fell on an absent expert: it
-    joins no group, exactly as a dead token's."""
+    joins no group, exactly as a dead token's. The rows of the groups come
+    FIRST in the list (``sum(group_sizes)`` of them), so the picks that
+    fell here are a prefix of ``order``, and a caller that hands on only
+    those takes slices of it (:func:`_grouped_share`)."""
     T, E = combine.shape
     k, C = top_k, E + 1                # column E: no expert, a dead token's
     weight, expert = lax.top_k(combine, k)                         # (T, k)
@@ -112,13 +115,15 @@ def sort_by_expert(combine: jax.Array, top_k: int,
     return weight, order, place, lax.slice_in_dim(sizes, 0, E)
 
 
-# tokens one grouped call takes. The call sorts ``tokens x top_k`` rows and
-# gathers, multiplies and scatters all of them, real or not: at top-10 of a
-# held share (``models/laguna.py``: an eighth of the rows real) a 5 x 4096
-# insert's class of 32 768 tokens held 5.5 GiB of temporaries beside 7.8 GiB
-# of weights and cache, and on the v5e that program never returned (PERF.md,
-# PR 49). Longer calls go by slices of this many tokens, each its own class;
-# no cell before PR 49 hands over more (DeepSeek-V2's 8 x 2048 is 16 384).
+# tokens one grouped call takes. The call sorts ``tokens x top_k`` picks, and
+# where every expert is held it gathers, multiplies and scatters all of them:
+# at top-10 a 5 x 4096 insert's class of 32 768 tokens held 5.5 GiB of
+# temporaries beside 7.8 GiB of weights and cache, and on the v5e that program
+# never returned (PERF.md, PR 49). Longer calls go by slices of this many
+# tokens, each its own class; no cell before PR 49 hands over more
+# (DeepSeek-V2's 8 x 2048 is 16 384). A layer that holds a share now hands on
+# ``row_bound`` rows a pass, a quarter of that at an eighth held (PR 51); the
+# slices stay as they are until a chip run of their own says otherwise.
 GROUPED_TOKENS = 16384
 
 
@@ -128,12 +133,39 @@ def token_class(tokens: int) -> int:
     return max(8, 1 << (tokens - 1).bit_length())
 
 
+def row_bound(tokens: int, top_k: int, held: int, routed: Optional[int]) -> int:
+    """Rows of the sorted list ONE pass of a grouped call of ``tokens`` (a
+    token class) gathers, multiplies and combines. Where every expert is held
+    (``routed`` None or ``held``) every live pick is a row: all
+    ``tokens x top_k``. A layer that holds ``held`` of ``routed`` experts
+    expects ``held / routed`` of the picks to fall on its own: the bound is
+    the largest power-of-two part of the list that holds twice that (a
+    quarter at an eighth held) and no less than one 256-row tile, so every
+    decode step and every short call is one pass over the whole list, the
+    program it always was. What falls beyond the bound goes by further passes
+    (:func:`_grouped_share`): the bound sets a pass's cost, never the
+    result."""
+    m = tokens * top_k
+    parts = 1
+    while (routed and 4 * parts * held <= routed and m % (2 * parts) == 0
+           and m // (2 * parts) >= 256):
+        parts *= 2
+    return m // parts
+
+
+def _slices(tokens: int):
+    """``(first token, tokens)`` of the slices a grouped call of ``tokens``
+    goes by (:data:`GROUPED_TOKENS`)."""
+    return [(at, min(GROUPED_TOKENS, tokens - at)) for at in range(0, tokens, GROUPED_TOKENS)]
+
+
 def grouped_rows_multiplied(group_sizes: jax.Array, tokens: int, top_k: int
                             ) -> jax.Array:
     """Rows the grouped kernel's dots run over in calls of ``tokens`` tokens
     whose group sizes are ``group_sizes (calls, E)``, as
-    :func:`_grouped_experts` tiles them: a count for the serving counters, by
-    the kernel's own arithmetic (``kernels/grouped_matmul.py::rows_multiplied``).
+    :func:`_grouped_experts` tiles them where every expert is held: a count
+    for the serving counters, by the kernel's own arithmetic
+    (``kernels/grouped_matmul.py::rows_multiplied``).
     A call of more than ``GROUPED_TOKENS`` goes by slices and is counted here
     as ONE sort at a slice's tile: each slice's group edges add at most a
     sub-tile a group, which this leaves out."""
@@ -141,19 +173,73 @@ def grouped_rows_multiplied(group_sizes: jax.Array, tokens: int, top_k: int
     return rows_multiplied(group_sizes, tm)
 
 
+def _pass_sizes(starts: jax.Array, ends: jax.Array, base, bound: int) -> jax.Array:
+    """The groups' sizes within rows ``[base, base + bound)`` of the sorted
+    list, from their first rows and ends ``(..., E)``."""
+    lo, hi = np.int32(0), np.int32(bound)
+    return lax.sub(lax.clamp(lo, lax.sub(ends, base), hi),
+                   lax.clamp(lo, lax.sub(starts, base), hi))
+
+
+def share_call_sums(chosen: jax.Array, top_k: int, routed: int) -> jax.Array:
+    """``(3,) int32`` of grouped calls that hold a share, from the real
+    tokens' choices ``chosen (calls, tokens, E)``: the rows the passes handled
+    (passes x ``row_bound``), the rows the kernel's dots ran over (the
+    sub-tiles each pass's groups touch at the pass's tile) and the passes,
+    summed over the calls and over the slices a long call goes by: the
+    arithmetic of :func:`_grouped_experts` and of
+    ``ExpertMLPs.forward_grouped``, for the serving counters."""
+    calls, tokens, E = chosen.shape
+    handled = multiplied = passes = jnp.int32(0)
+    for at, n in _slices(tokens):
+        picks = token_class(n) * top_k
+        bound = row_bound(token_class(n), top_k, E, routed)
+        tm, _ = row_tile(bound, E)
+        sizes = jnp.sum(chosen[:, at: at + n], axis=1, dtype=jnp.int32)     # (calls, E)
+        ends = lax.cumsum(sizes, axis=1)
+        # (a list of one bound is taken whole, without the loop: one pass whatever fell here)
+        ran = (jnp.int32(calls) if bound == picks else
+               jnp.sum(lax.div(lax.add(ends[:, E - 1], np.int32(bound - 1)), np.int32(bound))))
+        passes = passes + ran
+        handled = handled + ran * np.int32(bound)
+        # a pass that did not run has no rows within it: nothing to mask
+        for p in range(picks // bound):
+            multiplied = multiplied + rows_multiplied(
+                _pass_sizes(lax.sub(ends, sizes), ends, np.int32(p * bound), bound), tm)
+    return jnp.stack([handled, multiplied, passes])
+
+
 @functools.partial(jax.jit, static_argnames=("top_k", "glu", "dtype", "interpret",
-                                              "share"))
+                                              "routed"))
 def _grouped_experts(x, combine, live, layer, gate, up, down, *, top_k, glu,
-                     dtype, interpret, share=False):
+                     dtype, interpret, routed=None):
     """``ExpertMLPs.forward_grouped`` on a whole token class: ``x (T, H)``,
     ``combine (T, E)``, ``live (T,)``, the weight stacks ``(L, E, ...)`` and
     this layer's index. A function of the module, jitted, so that its trace
     (some two hundred equations with the kernels' bodies, a second of Python
     on a serving host) is kept by shape and dtype: a serving cell's 18
-    programs (one per insert shape) hold seven token classes between them."""
-    T, H = x.shape
-    weight, order, place, group_sizes = sort_by_expert(combine, top_k, live, share)
-    tm, rows = row_tile(T * top_k, combine.shape[1])
+    programs (one per insert shape) hold seven token classes between them.
+
+    ``routed``: the width of the router whose choices ``combine`` holds the
+    held experts' columns of (None or E: this layer's own). Where only a share
+    is held and the list is longer than a tile, :func:`_grouped_share` hands
+    on the picks that fell here and no others; else every pick is a row
+    (:func:`_grouped_whole`). (The choice is an expression, not an ``if``:
+    ``analysis/host_sync.py`` takes keyword-only parameters for traced.)"""
+    T, E = combine.shape
+    share = routed is not None and routed != E
+    bound = row_bound(T, top_k, E, routed)
+    return (_grouped_share if bound < T * top_k else _grouped_whole)(
+        x, *sort_by_expert(combine, top_k, live, share), live, layer, gate, up, down,
+        bound, glu, dtype, interpret, share)
+
+
+def _grouped_whole(x, weight, order, place, group_sizes, live, layer, gate, up, down,
+                   bound, glu, dtype, interpret, share):
+    """Every pick of the sorted list a row (``bound`` is all ``T x top_k``):
+    a layer that holds every expert, and any list of one tile."""
+    (T, top_k), H, E = weight.shape, x.shape[1], group_sizes.shape[0]
+    tm, rows = row_tile(bound, E)
     visits = group_visits(group_sizes, rows, tm)
     xs = x.astype(dtype).at[jax.lax.div(order, np.int32(top_k))].get(  # (M, H)
         mode="promise_in_bounds")
@@ -172,6 +258,58 @@ def _grouped_experts(x, combine, live, layer, gate, up, down, *, top_k, glu,
     out = jnp.where(real, out, 0)
     return jnp.einsum("tkh,tk->th", out.astype(jnp.float32),
                       weight.astype(jnp.float32))
+
+
+def _grouped_share(x, weight, order, place, group_sizes, live, layer, gate, up, down,
+                   bound, glu, dtype, interpret, share=True):
+    """The held share of a long sorted list, ``bound`` rows a pass. The sort
+    put the picks that fell on held experts FIRST (``n`` of them, a number the
+    device alone knows), so a pass's rows are a slice of ``order``: it gathers
+    those rows of ``x``, clips the groups to the slice, runs the two kernels
+    on ``bound`` rows at ``row_tile(bound, E)`` (the tile sees the rows that
+    are there) and adds each token's picks of the slice to the float32 sum.
+    ``ceil(n / bound)`` passes: one as a rule, ``M / bound`` where every pick
+    fell here (what a layer that holds all pays), none where none did. No
+    array of ``T x top_k`` rows of ``H`` is written: on the v5e a Laguna layer
+    of a 1 x 4096 insert (40 960 picks, 5 120 real) took 5.33 ms that way and
+    takes 2.30 (my chip runs, PR 51; PERF.md section 6 has the forms tried).
+
+    The weighted sum takes a token's picks slot by slot: ``top_k`` gathers of
+    ``T`` rows each from the pass's output, a pick that is not in the pass
+    selected away (never scaled: its index points at some row of the buffer),
+    all added in ONE fusion. XLA fuses no gather into a reduction, so one
+    gather of ``(top_k, T, H)`` is written out whole; the slots' read the same
+    bytes in pieces and cost the same (2.32 against 2.30 ms)."""
+    T, k = weight.shape
+    E = group_sizes.shape[0]
+    x = x.astype(dtype)
+    tm, rows = row_tile(bound, E)
+    ends = lax.cumsum(group_sizes, axis=0)
+    starts = lax.sub(ends, group_sizes)
+    n = lax.index_in_dim(ends, E - 1, 0, keepdims=False)
+    real = live[:, None] & (weight > 0)
+    weight = weight.astype(jnp.float32)
+    place = place.reshape(T, k)
+
+    def one_pass(p, acc):
+        base = lax.mul(p, np.int32(bound))
+        visits = group_visits(_pass_sizes(starts, ends, base, bound), rows, tm)
+        token = lax.div(lax.dynamic_slice(order, (base,), (bound,)), np.int32(k))
+        xs = x.at[token].get(mode="promise_in_bounds")                 # (bound, H)
+        xs = jnp.pad(xs, ((0, rows - bound), (0, 0)))
+        a = grouped_matmul(xs, (gate, up) if glu else (gate,), layer, visits, tm,
+                           _silu_gated if glu else nn.gelu, interpret=interpret)
+        out = grouped_matmul(a, (down,), layer, visits, tm, interpret=interpret)
+        at = lax.sub(place, base)
+        mine = real & (at >= 0) & (at < bound)
+        at = lax.select(mine, at, lax.full_like(at, 0))
+        for j in range(k):
+            pick = out.at[at[:, j]].get(mode="promise_in_bounds").astype(jnp.float32)
+            acc = acc + jnp.where(mine[:, j, None], pick * weight[:, j, None], 0)
+        return acc
+
+    return lax.fori_loop(np.int32(0), lax.div(lax.add(n, np.int32(bound - 1)), np.int32(bound)),
+                         one_pass, jnp.zeros((T, x.shape[1]), jnp.float32))
 
 
 class ExpertMLPs(nn.Module):
@@ -261,7 +399,7 @@ class ExpertMLPs(nn.Module):
 
     def forward_grouped(self, x: jax.Array, combine: jax.Array, top_k: int,
                         live: Optional[jax.Array] = None,
-                        stack=None, share: bool = False) -> jax.Array:
+                        stack=None, routed: Optional[int] = None) -> jax.Array:
         """x: (T, H); combine: (T, E) with ``top_k`` nonzeros a row; ``live``
         (T,) bool says which tokens are real (None: all). Every real
         (token, expert) assignment is computed, none dropped, so the result
@@ -274,16 +412,17 @@ class ExpertMLPs(nn.Module):
         which a kernel could only be handed as a copy of all ``E`` experts
         (``models/mixtral.py::MixtralDecoderLayer.layer_stack``).
 
-        ``share``: ``combine`` holds the held experts' columns of a wider
-        router's choices; a row has at most ``top_k`` nonzeros and those that
-        fell elsewhere are nobody's here (:func:`sort_by_expert`)."""
+        ``routed``: the width of the router whose choices ``combine`` holds
+        the held experts' columns of (None: this layer's own); a row then has
+        at most ``top_k`` nonzeros, those that fell elsewhere are nobody's
+        here (:func:`sort_by_expert`) and a long call hands on only the picks
+        that fell here (:func:`row_bound`)."""
         T, H = x.shape
         if T > GROUPED_TOKENS:
             return jnp.concatenate([
-                self.forward_grouped(x[at: at + GROUPED_TOKENS], combine[at: at + GROUPED_TOKENS],
-                                     top_k, None if live is None else live[at: at + GROUPED_TOKENS],
-                                     stack, share)
-                for at in range(0, T, GROUPED_TOKENS)])
+                self.forward_grouped(x[at: at + n], combine[at: at + n], top_k,
+                                     None if live is None else live[at: at + n], stack, routed)
+                for at, n in _slices(T)])
         if stack is None or stack[1]["gate"].dtype != self.dtype:
             # (a stack kept in another dtype would be cast whole, every layer)
             stack = (0, {"gate": self.w_gate.astype(self.dtype)[None],
@@ -297,19 +436,19 @@ class ExpertMLPs(nn.Module):
             jnp.pad(live, (0, pad)), jnp.asarray(layer, jnp.int32),
             w["gate"], w["up"], w["down"], top_k=min(top_k, self.num_experts),
             glu=self.glu, dtype=jnp.dtype(self.dtype),
-            interpret=kernel_mode.interpret_kernels(), share=share)
+            interpret=kernel_mode.interpret_kernels(), routed=routed)
         return out[:T].astype(x.dtype)
 
     def __call__(self, x: jax.Array, combine: jax.Array,
                  top_k: Optional[int] = None,
                  live: Optional[jax.Array] = None, stack=None,
-                 share: bool = False) -> jax.Array:
+                 routed: Optional[int] = None) -> jax.Array:
         # int8 leaves ({"qweight", "scale"}) keep all_experts, whose einsums
         # fuse the dequantisation: no cell serves them
         if self.mode == "grouped" and not isinstance(self.w_gate, Mapping):
             if top_k is None:
                 raise ValueError("grouped mode needs the router's top_k")
-            return self.forward_grouped(x, combine, top_k, live, stack, share)
+            return self.forward_grouped(x, combine, top_k, live, stack, routed)
         if self.mode == "capacity_factor":
             return self.forward_capacity_factor(x, combine)
         if self.mode in ("all_experts", "grouped"):

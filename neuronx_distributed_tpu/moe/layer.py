@@ -130,7 +130,7 @@ class MoE(nn.Module):
         )
         out = experts(flat, combine.astype(flat.dtype), top_k=self.top_k,
                       live=None if live is None else live.reshape(b * s),
-                      stack=stack, share=share).reshape(b, s, h)
+                      stack=stack, routed=routed).reshape(b, s, h)
 
         aux = self.aux_loss_coef * load_balancing_loss(logits, picks, routed)
         if self.z_loss_coef:

@@ -406,9 +406,10 @@ def test_mixtral_insert_holds_no_logits_but_the_last_positions(chip):
     assert text.count("tpu_custom_call") >= 3    # flash forward, two grouped matmuls
 
 
-def _described_lm(chip, family, monkeypatch):
+def _described_lm(chip, family, monkeypatch, buckets=(128,), rehearse=None):
     """``CausalLM`` of one of this file's configurations on the described
-    chip, shapes for parameters: two layers, bf16, pages of 16, batch 8."""
+    chip, shapes for parameters: two layers, bf16, pages of 16, batch 8.
+    ``rehearse``: DeepSeek-V2's rehearsal sizes (the default) or the cell's."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     from neuronx_distributed_tpu.inference import causal_lm
@@ -439,7 +440,8 @@ def _described_lm(chip, family, monkeypatch):
         name = {"deepseek": "deepseek-v2", "laguna": "laguna-s-2.1"}[family]
         entry = next(c for c in json.loads((root / "BENCHMARK.json").read_text())["configs"]
                      if c["name"] == name)
-        loaded = harness.load_config(entry, rehearse=family == "deepseek")
+        loaded = harness.load_config(
+            entry, rehearse=family == "deepseek" if rehearse is None else rehearse)
         cfg = serving.model_config(loaded, False, remat_policy=None,
                                    max_seq_len=4096 if family == "deepseek" else 8192)
         cls = serving.load(loaded["builder"]["model"])
@@ -451,7 +453,7 @@ def _described_lm(chip, family, monkeypatch):
         meta.unbox(jax.eval_shape(lambda: cls(cfg).init(
             jax.random.key(0), jnp.zeros((1, 8), jnp.int32))))["params"])
     # a ring a slot is not served beside the prefix cache (a hit would continue a row)
-    return CausalLM(cfg, params, cls, buckets=(128,), max_batch=8, page_size=16,
+    return CausalLM(cfg, params, cls, buckets=buckets, max_batch=8, page_size=16,
                     prefix_cache=family != "laguna")
 
 
@@ -489,7 +491,19 @@ def test_fused_session_decode_takes_its_rows_as_one_matrix_and_donates_the_cache
         assert not _leaf_copies(text, leaf.shape)
 
 
-_LAGUNA_BLOCK = {}     # the compiled block, once for the four cases below
+_LAGUNA_BLOCK = {}     # the compiled block, once for the cases below
+
+
+def _laguna_block(chip, monkeypatch):
+    if not _LAGUNA_BLOCK:
+        lm = _described_lm(chip, "laguna", monkeypatch)
+        compiled = lm.compile_session_decode_fused(8, SlotSampler(), 0)
+        _LAGUNA_BLOCK.update(
+            text=compiled.as_text(), temp=compiled.memory_analysis().temp_size_in_bytes,
+            leaf=next(leaf.shape for path, leaf in
+                      jax.tree_util.tree_flatten_with_path(lm._cache_avals())[0]
+                      if jax.tree_util.keystr(path).endswith("['window_key']")))
+    return _LAGUNA_BLOCK
 
 
 @pytest.mark.parametrize("rung", [1, 2, 4, 8])
@@ -508,15 +522,8 @@ def test_laguna_window_step_moves_its_rows_of_one_ring(chip, monkeypatch, rung):
     leaf's element count, nothing the mini gather's; (b) under the branch of
     ``rung`` rows nothing is larger than one layer's eight rows, and nothing
     of the ring's dtype larger than the rung's rows."""
-    if not _LAGUNA_BLOCK:
-        lm = _described_lm(chip, "laguna", monkeypatch)
-        compiled = lm.compile_session_decode_fused(8, SlotSampler(), 0)
-        _LAGUNA_BLOCK.update(
-            text=compiled.as_text(), temp=compiled.memory_analysis().temp_size_in_bytes,
-            leaf=next(leaf.shape for path, leaf in
-                      jax.tree_util.tree_flatten_with_path(lm._cache_avals())[0]
-                      if jax.tree_util.keystr(path).endswith("['window_key']")))
-    text, leaf = _LAGUNA_BLOCK["text"], _LAGUNA_BLOCK["leaf"]
+    block = _laguna_block(chip, monkeypatch)
+    text, leaf = block["text"], block["leaf"]
     layers, b = leaf[:2]
     assert (layers, b, math.prod(leaf[2:])) == (6, 8, 8 * 528 * HEAD_DIM)
     row = math.prod(leaf[2:]) * 2                 # one slot's ring in one layer, bf16
@@ -536,6 +543,50 @@ def test_laguna_window_step_moves_its_rows_of_one_ring(chip, monkeypatch, rung):
                default=0) <= rung * row
     print(f"laguna fused block: temporaries {_LAGUNA_BLOCK['temp'] / 2 ** 20:.1f} MiB")
     assert _LAGUNA_BLOCK["temp"] < 571 * 2 ** 20  # the parent's 571.6 MiB
+
+
+@pytest.mark.parametrize("family,bucket,sizes", [
+    ("laguna", 4096, (10, 32, 256, 3072, 1024)), ("deepseek", 2048, (6, 20, 160, 5120, 1536))],
+    ids=["laguna_1x4096", "deepseek_v2_1x2048"])
+def test_a_share_holding_insert_writes_no_array_of_every_pick(chip, monkeypatch, family, bucket,
+                                                              sizes):
+    """The 1 x 4096 insert of ``laguna-s-2.1.longctx`` and the 1 x 2048 of
+    ``deepseek-v2.longctx`` at the cells' sizes, for the described v5e. An
+    expert layer holds an eighth of the routed experts: the parent's program
+    gathered, allocated and combined every pick (under ``forward_grouped`` four
+    arrays of ``bucket x top_k`` rows of ``H`` a layer, 240 MiB each in Laguna,
+    and the kernel's gate/up buffer beside them); now a pass holds
+    ``row_bound`` rows, a quarter, and nothing under ``forward_grouped`` is as
+    large as every pick's row: the gather of ``x``, the kernels' buffers and
+    the slots of the weighted sum are the bound's or the tokens'."""
+    from neuronx_distributed_tpu.moe.expert_mlps import row_bound
+
+    top_k, held, routed, hidden, inter = sizes
+    lm = _described_lm(chip, family, monkeypatch, buckets=(bucket,), rehearse=False)
+    cfg = lm.config
+    assert (cfg.top_k, cfg.num_experts, cfg.router_experts, cfg.hidden_size,
+            cfg.moe_intermediate_size) == sizes
+    text = lm._paged_insert_programs(1, bucket).as_text()
+    ops = [op for op in big_ops(text, 2 ** 20) if "forward_grouped" in op["op_name"]]
+    bound = row_bound(bucket, top_k, held, routed)
+    assert bound == bucket * top_k // 4
+    every_pick = bucket * top_k * hidden * 2          # bf16
+    assert ops and max(op["bytes"] for op in ops) < every_pick // 2, \
+        [(op["op"], op["shape"]) for op in ops if op["bytes"] >= every_pick // 2]
+    kernels = {op["shape"] for op in ops if op["kind"] == "custom-call"}
+    assert kernels == {f"bf16[{bound},{inter}]", f"bf16[{bound},{hidden}]"}
+    assert any("/while/body/" in op["op_name"] for op in ops)       # the passes' loop
+
+
+def test_lagunas_decode_block_sorts_one_tile_and_loops_over_no_pass(chip, monkeypatch):
+    """A decode step's list (8 rows x top-10 = 80 picks) is one tile: the
+    whole list a call, no loop of passes, the kernels' buffers 80 rows."""
+    text = _laguna_block(chip, monkeypatch)["text"]
+    ops = [op for op in big_ops(text, 2 ** 10) if "forward_grouped" in op["op_name"]]
+    assert ops
+    kernels = {op["shape"] for op in ops if op["kind"] == "custom-call"}
+    assert kernels == {"bf16[80,1024]", "bf16[80,3072]"}
+    assert not re.search(r"forward_grouped/[^\"]*while", text)
 
 
 def test_fused_adamw_leaf(chip):

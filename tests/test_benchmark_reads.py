@@ -195,10 +195,12 @@ SERVING_READS = [
 MOE_READS = ["moe_experts_touched", "moe_assignments", "moe_layer_steps"]
 # the paged insert's side of the same counter (PR 29): in `engine.stats`, so
 # in every record's `engine_stats`; no metric of BENCHMARK.json reads them yet
-# ... and the rows the grouped kernel's dots ran over (PR 43)
+# ... and the rows the grouped kernel's dots ran over (PR 43), and the passes
+# the layer calls made over their sorted lists (PR 51: one a call where every
+# expert is held; ``moe.insert_real_row_share`` reads assignments / rows)
 MOE_INSERT_STATS = ["moe_insert_experts_touched", "moe_insert_assignments",
                     "moe_insert_layer_calls", "moe_insert_rows",
-                    "moe_insert_rows_multiplied"]
+                    "moe_insert_rows_multiplied", "moe_insert_passes"]
 # the insert's twins of program_calls / host_fetches (PR 32): in every record's
 # `engine_stats` the same way; (calls + fetches) / inserts reads 2.0 where every
 # admission was one program and one fetch
@@ -267,6 +269,7 @@ def test_insert_routing_counter_is_produced(run, moe_run, key):
     assert run.engine.stats[key] == 0
     want = {"moe_insert_assignments": (6 + 9) * 2 * 2,
             "moe_insert_layer_calls": 2 * stats["inserts"],
+            "moe_insert_passes": 2 * stats["inserts"],     # every expert held: one a call
             "moe_insert_rows": 2 * stats["inserted_requests"] * 16 * 2}
     if key in want:
         assert stats[key] == want[key]

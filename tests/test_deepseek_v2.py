@@ -285,6 +285,10 @@ def test_serve_engine_gives_solo_generates_tokens_and_hits_a_latent_prefix(param
     assert stats["moe_experts_touched"] <= 4 * stats["moe_layer_steps"]
     assert 0 < stats["moe_assignments"] < stats["moe_assignments_routed"]
     assert stats["moe_assignments_routed"] % 4 == 0        # top-4 a live row and layer
+    # the inserts' expert layers: lists of a tile, so one pass over the whole
+    # list a call (``moe/expert_mlps.py::row_bound``)
+    assert stats["moe_insert_passes"] == stats["moe_insert_layer_calls"] > 0
+    assert 0 < stats["moe_insert_assignments"] < stats["moe_insert_rows"]
 
 
 # ------------------------------------------------------------------ the router

@@ -652,26 +652,62 @@ def test_a_config_without_a_window_lowers_its_insert_to_the_parents_text():
     assert hashlib.sha1(texts[0].encode()).hexdigest() == PARENTS_INSERT
 
 
-def test_a_long_grouped_call_goes_by_slices_and_adds_up(monkeypatch):
+def test_a_long_insert_hands_on_the_held_picks_alone():
+    """Four of sixteen experts held, top-3, a prompt of 200 tokens in a bucket
+    of 256: the expert layers' lists are 768 picks long and a pass takes 384
+    of them (``row_bound``). The last position's logits are the reference's,
+    and the insert's sums say what the passes handled: one a layer call here,
+    so ``moe_insert_rows`` is layer calls x 384 and not x 768."""
+    from neuronx_distributed_tpu.moe.expert_mlps import row_bound
+
+    world()
+    cfg = dict(TINY, num_experts=4, max_seq_len=512)
+    ids = np.random.RandomState(3).randint(1, 128, (1, 200)).astype(np.int32)
+    tree = meta.unbox(LagunaForCausalLM(LagunaConfig(**cfg)).init(
+        jax.random.PRNGKey(2), jnp.asarray(ids[:, :8])))["params"]
+    lm = CausalLM(LagunaConfig(**cfg), tree, LagunaForCausalLM, buckets=(256,), max_batch=2,
+                  page_size=16, prefix_cache=False)
+    session = lm.start_session()
+    got = np.asarray(lm.insert(session, np.arange(1), ids, lengths=np.asarray([200]),
+                               reserve_tokens=8))
+    ref = np.asarray(reference.forward(tree, jnp.asarray(ids), SIZES))[0, -1]
+    assert distance(got[0], ref) <= TOL
+    touched, assigned, calls, routed, rows, multiplied, passes = (
+        int(v) for v in np.asarray(session.insert_routing))
+    bound = row_bound(256, 3, 4, 16)
+    assert bound == 384 and calls == 4                 # the four layers with experts
+    assert routed == 4 * 200 * 3 and 0 < assigned < routed
+    assert passes == calls and rows == passes * bound
+    assert assigned <= multiplied <= rows
+
+
+@pytest.mark.parametrize("tokens,slice_tokens,calls", [
+    (40, 16, [16, 16, 8]), (1300, 512, [512, 512, 512])], ids=["one_tile", "compact_passes"])
+def test_a_long_grouped_call_goes_by_slices_and_adds_up(monkeypatch, tokens, slice_tokens, calls):
     """More tokens than ``GROUPED_TOKENS`` (an 8 x 4096 insert is 32 768) run
     the sort, the gather and the kernels a slice at a time: the same rows
-    through the same experts."""
+    through the same experts. Slices of a tile's picks are the whole list
+    each; longer ones (512 tokens: 1 536 picks, 4 of 16 experts held) hand on
+    half their list a pass (``row_bound``), and so does the call taken whole
+    (a class of 2 048 tokens)."""
     from neuronx_distributed_tpu.moe import expert_mlps
 
     world()
     rng = np.random.RandomState(5)
-    x = jnp.asarray(rng.normal(size=(1, 40, 32)).astype(np.float32))
+    x = jnp.asarray(rng.normal(size=(1, tokens, 32)).astype(np.float32))
     moe = MoE(num_experts=4, hidden_size=32, intermediate_size=16, top_k=3, dtype=jnp.float32,
               inference=True, router_experts=16, experts_held_first=4, route_scale=2.5,
               scoring_func="sigmoid")
     tree = moe.init(jax.random.PRNGKey(1), x)["params"]
-    live = jnp.asarray(rng.rand(1, 40) < 0.8)
+    live = jnp.asarray(rng.rand(1, tokens) < 0.8)
     whole = np.asarray(moe.apply({"params": tree}, x, live))
-    monkeypatch.setattr(expert_mlps, "GROUPED_TOKENS", 16)
-    calls = []
+    monkeypatch.setattr(expert_mlps, "GROUPED_TOKENS", slice_tokens)
+    seen = []
     real = expert_mlps._grouped_experts
     monkeypatch.setattr(expert_mlps, "_grouped_experts",
-                        lambda x, *a, **k: calls.append(x.shape[0]) or real(x, *a, **k))
+                        lambda x, *a, **k: seen.append(x.shape[0]) or real(x, *a, **k))
     sliced = np.asarray(moe.apply({"params": tree}, x, live))
-    assert calls == [16, 16, 8] and np.abs(whole).max() > 0
+    assert seen == calls and np.abs(whole).max() > 0
     assert distance(sliced, whole) <= 1e-6
+    compact = [expert_mlps.row_bound(n, 3, 4, 16) < n * 3 for n in calls]
+    assert compact == [tokens > 40] * 3

@@ -321,6 +321,133 @@ def test_group_sizes_sum_to_the_live_assignments(E, k, T, masked):
     assert sizes.sum() <= multiplied <= int(visits.count) * tm
 
 
+# --- a held share of a wider router's picks: the compact passes -------------
+
+def _share_case(case):
+    """``(T, k, held, routed, combine (T, held), live (T,) or None)`` of a
+    grouped call that holds ``held`` of ``routed`` experts: each token picks
+    ``k`` of the routed at random and the held are the first."""
+    T, k, held, routed = 512, 4, 4, 32
+    rs = np.random.RandomState(11)
+    live = None
+    if case == "all_here":                  # every pick on a held expert
+        picks = np.stack([rs.permutation(held)[:k] for _ in range(T)])
+    elif case == "none_here":               # every pick elsewhere
+        picks = np.stack([held + rs.permutation(routed - held)[:k] for _ in range(T)])
+    else:
+        if case == "masked_and_padded":     # 300 tokens in a class of 512
+            T, live = 300, rs.uniform(size=300) < 0.7
+        elif case == "sliced":              # three slices of GROUPED_TOKENS (patched to 512)
+            T = 1300
+        picks = np.stack([rs.permutation(routed)[:k] for _ in range(T)])
+    combine = np.zeros((T, routed), np.float32)
+    np.put_along_axis(combine, picks, rs.uniform(0.1, 1.0, (T, k)).astype(np.float32), axis=1)
+    return T, k, held, routed, combine[:, :held], live
+
+
+@pytest.mark.parametrize("case", ["an_eighth_here", "all_here", "none_here",
+                                  "masked_and_padded", "sliced"])
+def test_a_held_share_goes_by_compact_passes(case, monkeypatch):
+    """A layer that holds a share hands on ``row_bound`` rows of the sorted
+    list a pass (here 512 of a class's 2 048 picks): against all_experts on
+    the same weights at the grouped tests' tolerance, whatever the routing.
+    The usual eighth is one pass; every pick on a held expert is ``M / R``
+    passes and nothing is dropped; no pick here is no pass and zeros; tokens
+    that are not live and a class's padding choose nothing; a call longer
+    than ``GROUPED_TOKENS`` goes by slices, each with its own passes. The
+    passes the serving counter reckons (``share_call_sums``) are
+    ``ceil(n / R)`` a slice and the rows handled ``passes x R``."""
+    from neuronx_distributed_tpu.kernels.grouped_matmul import row_tile
+    from neuronx_distributed_tpu.moe import expert_mlps
+    from neuronx_distributed_tpu.moe.expert_mlps import ExpertMLPs
+
+    monkeypatch.setattr(expert_mlps, "GROUPED_TOKENS", 512)
+    T, k, held, routed, combine, live = _share_case(case)
+    x = jax.random.normal(jax.random.PRNGKey(3), (T, 32), jnp.float32).astype(jnp.bfloat16)
+    make = lambda mode: ExpertMLPs(num_experts=held, hidden_size=32, intermediate_size=64,  # noqa: E731
+                                   mode=mode, dtype=jnp.bfloat16, param_dtype=jnp.float32)
+    combine = jnp.asarray(combine, jnp.bfloat16)
+    params = make("grouped").init(jax.random.PRNGKey(1), x, combine, top_k=k)
+    calls = []
+    real_call = expert_mlps._grouped_experts
+    monkeypatch.setattr(expert_mlps, "_grouped_experts",
+                        lambda x, *a, **kw: calls.append(x.shape[0]) or real_call(x, *a, **kw))
+    got = make("grouped").apply(params, x, combine, top_k=k, routed=routed,
+                                live=None if live is None else jnp.asarray(live))
+    want = make("all_experts").apply(params, x, combine)
+    assert got.dtype == want.dtype and np.isfinite(np.asarray(got, np.float32)).all()
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    alive = np.ones(T, bool) if live is None else live
+    assert np.abs(got - want)[alive].max() <= 2 ** -6 * max(np.abs(want).max(), 1e-6)
+    assert (got[~alive] == 0).all()
+    if case == "none_here":
+        assert (got == 0).all()
+    else:
+        assert np.abs(got).max() > 0
+
+    assert calls == ([512, 512, 512] if case == "sliced" else [512])
+    bound = expert_mlps.row_bound(512, k, held, routed)
+    assert bound == 512 and row_tile(bound, held)[0] == 512     # of 2 048, 512-row tiles
+    chosen = (np.asarray(combine, np.float32) > 0) & alive[:, None]
+    passes = [-(-int(chosen[at: at + 512].sum()) // bound) for at in range(0, T, 512)]
+    assert passes == {"an_eighth_here": [1], "all_here": [4], "none_here": [0],
+                      "masked_and_padded": [1], "sliced": [1, 1, 1]}[case]
+    handled, multiplied, counted = np.asarray(
+        expert_mlps.share_call_sums(jnp.asarray(chosen)[None], k, routed))
+    assert counted == sum(passes) and handled == sum(passes) * bound
+    assert chosen.sum() <= multiplied <= handled + 64 * held * sum(passes)
+
+
+# sha1 of ``_grouped_experts``' lowered text at the parent of PR 51 (commit
+# b0ce4da, computed there by the same lines): a layer that holds every expert,
+# and a share's call of one tile, are the programs they were.
+PARENTS_GROUPED = {
+    (512, 2, 8, None): "609ef73d254e78bd455b4e0f2a24d6827c5df565",
+    (1024, 8, 64, None): "85bd3caa1c611009efd702a673e99b0c19925352",
+    (8, 4, 4, 32): "f8e1034140f8ab20782c5a8a46f82daf51e56768",
+    (16, 4, 4, 32): "8474b466f3f11a3690e13801398b1ec8bb85961a",
+}
+
+
+@pytest.mark.parametrize("case", list(PARENTS_GROUPED),
+                         ids=["all_held_T512", "all_held_T1024", "share_T8", "share_T16"])
+def test_all_held_and_one_tile_calls_lower_to_the_parents_text(case):
+    import hashlib
+
+    from neuronx_distributed_tpu.moe.expert_mlps import _grouped_experts, row_bound
+
+    T, k, E, routed = case
+    assert row_bound(T, k, E, routed) == T * k
+    sds = jax.ShapeDtypeStruct
+    bf16 = jnp.bfloat16
+    text = _grouped_experts.lower(
+        sds((T, 32), bf16), sds((T, E), bf16), sds((T,), bool), sds((), jnp.int32),
+        sds((2, E, 32, 64), bf16), sds((2, E, 32, 64), bf16), sds((2, E, 64, 32), bf16),
+        top_k=k, glu=True, dtype=jnp.dtype(bf16), interpret=True, routed=routed).as_text()
+    assert hashlib.sha1(text.encode()).hexdigest() == PARENTS_GROUPED[case]
+
+
+@pytest.mark.parametrize("tokens,top_k,held,routed,bound", [
+    (4096, 10, 32, 256, 10240),      # Laguna's 1 x 4096 insert: a quarter
+    (2048, 6, 20, 160, 3072),        # DeepSeek-V2's 1 x 2048
+    (16384, 10, 32, 256, 40960),     # a slice of GROUPED_TOKENS
+    (8, 10, 32, 256, 80),            # a decode step: one tile, the whole list
+    (64, 10, 32, 256, 320),          # 640 picks: a quarter would be under a tile, a half holds
+    (128, 10, 32, 256, 320),
+    (4096, 2, 8, None, 8192),        # every expert held
+    (4096, 2, 8, 8, 8192),
+    (4096, 4, 8, 16, 16384),         # half held: twice the expectation is the list
+    (4096, 4, 8, 32, 8192),          # a quarter held: half the list
+])
+def test_row_bound_is_twice_the_share_and_a_tile_at_least(tokens, top_k, held, routed, bound):
+    from neuronx_distributed_tpu.moe.expert_mlps import row_bound
+
+    got = row_bound(tokens, top_k, held, routed)
+    assert got == bound and (tokens * top_k) % got == 0
+    if routed and got < tokens * top_k:
+        assert got >= 256 and got * routed >= 2 * tokens * top_k * held
+
+
 def test_ep_sharded_checkpoint_roundtrip(tmp_path):
     """EP2xTP2-sharded Mixtral state saves and restores into the same
     shardings (reshard-on-load covers EP axes like any other; VERDICT r1

@@ -138,35 +138,41 @@ def _state_rows(leaf: jax.Array, slots: jax.Array, starts: jax.Array) -> jax.Arr
     return jnp.where(keep, leaf[:, slots], 0)
 
 
-def _chosen(stats: PyTree, live: jax.Array) -> Tuple[jax.Array, Optional[jax.Array]]:
+def _chosen(stats: PyTree, live: jax.Array
+            ) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array]]:
     """The ``(layers, tokens, experts held)`` choice masks ``MoE`` sows, those
     of the tokens ``live`` calls real (any shape of ``tokens`` elements), and
     where the layer holds a share of a wider router's experts
     (``moe/layer.py``) the ``(layers, tokens)`` counts of ALL a token's picks
-    (sown as ``routed``; None elsewhere). The layers are those with experts."""
-    by_name: Dict[str, list] = {"chosen": [], "routed": []}
+    (sown as ``routed``; None elsewhere), and where some of the router's
+    experts cost nothing those of its picks that fell on them (``zero``; None
+    elsewhere). The layers are those with experts."""
+    by_name: Dict[str, list] = {"chosen": [], "routed": [], "zero": []}
     for path, leaf in jax.tree_util.tree_flatten_with_path(stats)[0]:
         name = next(k for k in by_name if f"['{k}']" in jax.tree_util.keystr(path))
         by_name[name].append(leaf)
     chosen = jnp.concatenate([c.reshape(-1, *c.shape[-2:])
                               for c in by_name["chosen"]])
-    routed = (jnp.concatenate([r.reshape(-1, r.shape[-1]) for r in by_name["routed"]])
-              if by_name["routed"] else None)
-    return chosen & live.reshape(-1)[None, :, None], routed
+    routed, zero = (jnp.concatenate([r.reshape(-1, r.shape[-1]) for r in by_name[name]])
+                    if by_name[name] else None for name in ("routed", "zero"))
+    return chosen & live.reshape(-1)[None, :, None], routed, zero
 
 
 def _routing_sums(chosen: jax.Array, routed: Optional[jax.Array],
-                  live: jax.Array) -> jax.Array:
+                  zero: Optional[jax.Array], live: jax.Array) -> jax.Array:
     """``(3,) int32`` of one model call, from :func:`_chosen`'s masks: expert
     slots touched by a real token (summed over layers), assignments of real
     tokens, layers run with a real token in them. The experts are those HELD
-    and the layers those with experts. Where the layer holds a share of a
-    wider router's experts a fourth sum follows: every pick of the real
-    tokens, absent experts' included."""
+    and the layers those with experts, so an assignment is a pick that costs
+    a product here. Where the layer holds a share of a wider router's experts
+    a fourth sum follows: every pick of the real tokens, absent experts'
+    included; where some of the router's experts cost nothing a fifth: the
+    real tokens' picks that fell on those."""
     sums = [jnp.sum(jnp.any(chosen, axis=1)), jnp.sum(chosen),
             chosen.shape[0] * jnp.any(live)]
-    if routed is not None:
-        sums.append(jnp.sum(jnp.where(live.reshape(-1)[None, :], routed, 0)))
+    for picks in (routed, zero):
+        if picks is not None:
+            sums.append(jnp.sum(jnp.where(live.reshape(-1)[None, :], picks, 0)))
     return jnp.stack(sums).astype(jnp.int32)
 
 
@@ -419,10 +425,15 @@ class CausalLM:
         # a config with experts: the fused session decode counts what its
         # router chose (three sums, one more output; see its docstring)
         self.moe_stats = getattr(self.config, "num_experts", 0) > 1
-        # three routing sums, or four where the layer holds a share of the
-        # routed experts (``_routing_sums``)
-        routed = getattr(self.config, "router_experts", None)
-        self.moe_sums = 4 if routed and routed != self.config.num_experts else 3
+        # three routing sums; four where the layer holds a share of what its
+        # router chooses among (``moe/layer.py``: ``moe_width`` wide, experts
+        # held elsewhere and experts that cost nothing included); five where
+        # some cost nothing (``_routing_sums``)
+        held = getattr(self.config, "num_experts", 0)
+        zero = getattr(self.config, "zero_experts", 0)
+        self.moe_width = (getattr(self.config, "router_experts", None) or held) + zero
+        self.moe_share = self.moe_stats and self.moe_width != held
+        self.moe_sums = 3 + self.moe_share + bool(zero)
         # a model that asks which tokens are real: an insert names each row's
         # suffix (the bucket's padding chooses no expert, advances no state)
         self.wants_live = self.moe_stats or bool(self.slot_rows)
@@ -1311,7 +1322,7 @@ class CausalLM:
         sampler = sampler or SlotSampler()
         ppseq = self.config.max_seq_len // self.config.page_size
         moe = self.moe_stats
-        share = self.moe_sums == 4      # the expert layers hold a share of the routed
+        share = self.moe_share          # the expert layers hold a share of the routed
         state_leaves = self._slot_row_ends
 
         def insert_fn(params, cache, slot_keys, first, ids, tables, slots,
@@ -1348,13 +1359,13 @@ class CausalLM:
             if moe:
                 with jax.named_scope("bookkeeping"):
                     top_k = min(self.config.top_k, self.config.num_experts)
-                    chosen, routed = _chosen(mut["moe_stats"], live)
+                    chosen, routed, zero = _chosen(mut["moe_stats"], live)
                     if not share:          # every expert held: every pick is a row
                         sizes = jnp.sum(chosen, axis=1, dtype=jnp.int32)  # (layers, E)
                         grouped_rows = sizes.shape[0] * rows * bucket * top_k
-                    parts = [_routing_sums(chosen, routed, live)]
+                    parts = [_routing_sums(chosen, routed, zero, live)]
                     if share:              # the passes' rows, and the passes
-                        parts.append(share_call_sums(chosen, top_k, self.config.router_experts))
+                        parts.append(share_call_sums(chosen, top_k, self.moe_width))
                     else:
                         parts += [jnp.full((1,), grouped_rows, jnp.int32),
                                   grouped_rows_multiplied(sizes, rows * bucket, top_k

@@ -345,6 +345,11 @@ _STAT_KEYS = (
     # included (moe/layer.py's share of a wider expert layer); equal to
     # moe_assignments where every expert is held
     "moe_assignments_routed",
+    # those of them that fell on experts that cost nothing (moe/layer.py's
+    # ``zero_experts``: no weights, no product; moe_assignments never counts
+    # one), over the decode blocks and over the paged inserts, and beside the
+    # second every pick of the inserts' real tokens
+    "moe_zero_picks", "moe_insert_zero_picks", "moe_insert_assignments_routed",
     # the same three over the real tokens of the paged inserts (bucket
     # padding chooses nothing; CausalLM._paged_insert_programs), and the
     # grouped rows the inserts' experts gathered, multiplied and combined,
@@ -3735,18 +3740,23 @@ class ServeEngine:
 
     def _count_routing(self, sums) -> None:
         """One fused block's routing sums into ``stats``."""
-        touched, assigned, layer_steps, *routed = (int(x) for x in sums)
+        touched, assigned, layer_steps, *more = (int(x) for x in sums)
         self.stats["moe_experts_touched"] += touched
         self.stats["moe_assignments"] += assigned
         self.stats["moe_layer_steps"] += layer_steps
-        self.stats["moe_assignments_routed"] += routed[0] if routed else assigned
+        self.stats["moe_assignments_routed"] += more[0] if more else assigned
+        self.stats["moe_zero_picks"] += more[1] if len(more) > 1 else 0
 
     def _count_insert_routing(self, sums) -> None:
         """One paged insert's routing sums into ``stats``."""
         # (3 routing sums, grouped rows, rows the kernel multiplied; where a
-        # share is held 4 routing sums and, last, the passes)
+        # share is held 4 routing sums, a fifth where some experts cost
+        # nothing, and, last, the passes)
         touched, assigned, layer_calls, *rest = (int(x) for x in sums)
-        rows, multiplied, passes = rest[1:] if len(rest) == 4 else (*rest, layer_calls)
+        *picks, rows, multiplied, passes = (
+            rest if len(rest) > 2 else (assigned, *rest, layer_calls))
+        self.stats["moe_insert_assignments_routed"] += picks[0]
+        self.stats["moe_insert_zero_picks"] += sum(picks[1:])
         self.stats["moe_insert_experts_touched"] += touched
         self.stats["moe_insert_assignments"] += assigned
         self.stats["moe_insert_layer_calls"] += layer_calls

@@ -95,6 +95,11 @@ class DeepseekV2Config(MixtralConfig):
     rms_norm_eps: float = 1e-6
     # the published ``rope_scaling`` dict, or a YarnScaling
     rope_scaling: Any = None
+    # factors on the two low-rank paths after their RMSNorms (LongCat-Flash's
+    # ``mla_scale_q_lora`` / ``mla_scale_kv_lora``; models/longcat_flash.py);
+    # 1.0: none, and nothing is traced for it
+    q_lora_scale: float = 1.0
+    kv_lora_scale: float = 1.0
 
     def __post_init__(self):
         scaling = self.rope_scaling
@@ -178,10 +183,18 @@ class DeepseekV2Attention(nn.Module):
                            cfg.qk_rope_head_dim, cfg.v_head_dim)
         rank = cfg.kv_lora_rank
 
-        def kernel(name, shape, axes):
-            """A projection ``(fan_in, *out)``, normal with variance 1 / fan_in."""
+        def kernel(name, shape, axes, scale=1.0):
+            """A projection ``(fan_in, *out)``, normal with variance 1 / fan_in;
+            one that follows a low-rank path scaled by ``scale`` takes
+            ``1 / (scale^2 fan_in)``: the scales stand for a fan-in of the
+            hidden size, and a seeded model's queries, keys and values keep
+            the variance they have without them (at ``1 / fan_in`` the scores'
+            spread grows by both scales' product, the softmax picks one key,
+            and bf16 rounding grows with every layer: 0.42 of the largest
+            logit at LongCat-Flash's widths on the v5e, PERF.md, PR 52)."""
             init = nn.initializers.variance_scaling(
-                1.0, "fan_in", "normal", in_axis=0, out_axis=tuple(range(1, len(shape))))
+                1.0 / scale ** 2, "fan_in", "normal", in_axis=0,
+                out_axis=tuple(range(1, len(shape))))
             return self.param(name, nn.with_partitioning(init, axes), shape,
                               cfg.param_dtype).astype(cfg.dtype)
 
@@ -191,19 +204,28 @@ class DeepseekV2Attention(nn.Module):
 
         h = cfg.hidden_size
         w_dq = kernel("q_a_proj", (h, cfg.q_lora_rank), (None, None))
-        w_uq = kernel("q_b_proj", (cfg.q_lora_rank, n, nope + rd), (None, TP_AXIS, None))
+        w_uq = kernel("q_b_proj", (cfg.q_lora_rank, n, nope + rd), (None, TP_AXIS, None),
+                      cfg.q_lora_scale)
         w_dkv = kernel("kv_a_proj", (h, rank + rd), (None, None))
         # the published ``kv_b_proj`` (rank -> heads x [k_nope | v]) is kept as
         # its two halves: the absorbed decode multiplies by each alone, and a
         # slice of one stacked matrix would be copied out every layer-step
-        w_uk = kernel("k_b_proj", (rank, n, nope), (None, TP_AXIS, None))
-        w_uv = kernel("v_b_proj", (rank, n, vd), (None, TP_AXIS, None))
+        w_uk = kernel("k_b_proj", (rank, n, nope), (None, TP_AXIS, None), cfg.kv_lora_scale)
+        w_uv = kernel("v_b_proj", (rank, n, vd), (None, TP_AXIS, None), cfg.kv_lora_scale)
         x = x.astype(cfg.dtype)
+
+        def scaled(c, factor):
+            if factor == 1.0:
+                return c
+            with jax.named_scope("mla_lora_scale"):
+                return c * jnp.asarray(factor, c.dtype)
+
         with jax.named_scope("mla_q"):
-            q = jnp.einsum("bsr,rnd->bsnd", norm("q_a_norm")(x @ w_dq), w_uq)
+            q = jnp.einsum("bsr,rnd->bsnd",
+                           scaled(norm("q_a_norm")(x @ w_dq), cfg.q_lora_scale), w_uq)
         with jax.named_scope("mla_kv_down"):
             down = x @ w_dkv
-            c_kv = norm("kv_a_norm")(down[..., :rank])                 # (b, s, rank)
+            c_kv = scaled(norm("kv_a_norm")(down[..., :rank]), cfg.kv_lora_scale)  # (b, s, rank)
             k_r = down[..., None, rank:]                               # (b, s, 1, rope)
         if cfg.decode:
             o = self._cached(q, c_kv, k_r, w_uk, w_uv, kv, live)

@@ -1322,9 +1322,11 @@ class LlamaModel(nn.Module):
         if cfg.decode:
             # one buffer per K/V leaf for the whole stack, in and out of the
             # layer loop as its carry: written in place at [layer, row]
+            # (``cfg.kv_layers``: a layer that holds more than one attention
+            # says how many cache layers the stack has; models/longcat_flash.py)
             pools = {
                 name: self.variable("cache", name, jnp.zeros,
-                                    (cfg.num_layers, *shape), dtype)
+                                    (getattr(cfg, "kv_layers", cfg.num_layers), *shape), dtype)
                 for name, (shape, dtype) in kv_leaf_shapes(
                     cfg, input_ids.shape[0]).items()}
             kv = (jnp.int32(0), {n: p.value for n, p in pools.items()})

@@ -66,6 +66,17 @@ class MoE(nn.Module):
     route_scale: float = 1.0
     # how the router scores an expert: "softmax" | "sigmoid" (moe/routing.py)
     scoring_func: str = "softmax"
+    # experts that cost nothing (LongCat-Flash's identity experts): the router
+    # is ``router_experts + zero_experts`` wide and a token's ``top_k`` fall on
+    # real and identity experts alike. An identity expert returns its input,
+    # so the chosen weights of the last ``zero_experts`` columns are summed
+    # into ONE scalar a token that multiplies the layer's input, in float32,
+    # beside the routed result: no weights, no row of the grouped matmul (the
+    # picks are dropped before the sort as an absent expert's are) and, under
+    # expert parallelism, no exchange: the chip that owns the token adds it.
+    zero_experts: int = 0
+    # a bias on the scores for the CHOICE only (moe/routing.py)
+    selection_bias: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array, live: Optional[jax.Array] = None,
@@ -84,14 +95,19 @@ class MoE(nn.Module):
         flat = x.reshape(b * s, h)
 
         routed = self.router_experts or self.num_experts
-        share = routed != self.num_experts     # only some of the routed are held
+        if (self.zero_experts or self.selection_bias) and self.router != "top_k":
+            raise ValueError(f"zero_experts and selection_bias are the top_k router's, "
+                             f"not {self.router!r}'s")
+        width = routed + self.zero_experts     # what the router chooses among
+        share = width != self.num_experts      # only some of those are held (and cost)
         if self.router == "top_k":
-            router = RouterTopK(routed, top_k=self.top_k,
+            router = RouterTopK(width, top_k=self.top_k,
                                 norm_topk_prob=self.norm_topk_prob,
                                 n_group=self.n_group, topk_group=self.topk_group,
                                 route_scale=self.route_scale,
                                 **({} if self.scoring_func == "softmax"
                                    else {"scoring_func": self.scoring_func}),
+                                **({"selection_bias": True} if self.selection_bias else {}),
                                 name="router")
         elif self.router == "sinkhorn":
             router = RouterSinkhorn(routed, name="router")
@@ -113,6 +129,9 @@ class MoE(nn.Module):
             if share:
                 self.sow("moe_stats", "routed",
                          jnp.sum(picks > 0, axis=-1, dtype=jnp.int32))
+            if self.zero_experts:       # the picks that cost nothing
+                self.sow("moe_stats", "zero",
+                         jnp.sum(picks[:, routed:] > 0, axis=-1, dtype=jnp.int32))
 
         mode = self.mode
         if self.inference and mode == "capacity_factor":
@@ -130,9 +149,17 @@ class MoE(nn.Module):
         )
         out = experts(flat, combine.astype(flat.dtype), top_k=self.top_k,
                       live=None if live is None else live.reshape(b * s),
-                      stack=stack, routed=routed).reshape(b, s, h)
+                      stack=stack, routed=width).reshape(b, s, h)
+        if self.zero_experts:
+            with jax.named_scope("zero_experts"):
+                kept = jnp.sum(picks[:, routed:].astype(jnp.float32), axis=-1)
+                if live is not None:        # a token that is not real comes out zero
+                    kept = jnp.where(live.reshape(b * s), kept, 0.0)
+                out = (out.astype(jnp.float32)
+                       + (kept[:, None] * flat.astype(jnp.float32)).reshape(b, s, h)
+                       ).astype(out.dtype)
 
-        aux = self.aux_loss_coef * load_balancing_loss(logits, picks, routed)
+        aux = self.aux_loss_coef * load_balancing_loss(logits, picks, width)
         if self.z_loss_coef:
             aux = aux + self.z_loss_coef * router_z_loss(logits)
         self.sow("losses", "moe_aux_loss", aux)

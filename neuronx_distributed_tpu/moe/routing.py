@@ -34,7 +34,13 @@ class RouterTopK(nn.Module):
     own (DeepSeek-V3's router, without its selection bias): the top-k by
     score, their scores renormalised (``norm_topk_prob``) and scaled. The
     selection, the groups and the weights read ``scores`` wherever the
-    softmax's probabilities stood."""
+    softmax's probabilities stood.
+
+    ``selection_bias`` adds a parameter ``e_score_correction_bias``, one
+    float32 an expert, to the scores for the CHOICE only (LongCat-Flash's and
+    DeepSeek-V3's load-balancing bias): the top-k is taken of ``scores +
+    bias``, the weights stay the scores themselves. Not with groups (a
+    group's score would have to say which of the two it reads)."""
 
     num_experts: int
     top_k: int = 2
@@ -43,6 +49,7 @@ class RouterTopK(nn.Module):
     topk_group: int = 1
     route_scale: float = 1.0
     scoring_func: str = "softmax"      # | "sigmoid"
+    selection_bias: bool = False
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -50,6 +57,9 @@ class RouterTopK(nn.Module):
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         if self.scoring_func not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring_func {self.scoring_func!r}: 'softmax' or 'sigmoid'")
+        if self.selection_bias and self.n_group > 1:
+            raise ValueError("selection_bias is not supported with n_group > 1: a group's "
+                             "score would have to choose between the score and score + bias")
         # router weight is replicated (the reference's LinearRouter with
         # weight-grad all-reduce, moe_parallel_layers.py:348)
         w = self.param("kernel", default_kernel_init, (x.shape[-1], self.num_experts),
@@ -61,6 +71,10 @@ class RouterTopK(nn.Module):
         if self.n_group > 1:
             with jax.named_scope("router_groups"):
                 eligible = probs * group_limit(probs, self.n_group, self.topk_group)
+        if self.selection_bias:
+            with jax.named_scope("router_bias"):
+                eligible = probs + self.param("e_score_correction_bias", nn.initializers.zeros,
+                                              (self.num_experts,), jnp.float32)
         topv, topi = jax.lax.top_k(eligible, self.top_k)
         mask = jnp.sum(jax.nn.one_hot(topi, self.num_experts, dtype=probs.dtype), axis=-2)
         gates = probs * mask

@@ -71,6 +71,17 @@ WINDOW_SCOPES = {
     "paged_insert": ["state_rows", "flash_fwd_window"],     # causal_lm.py's rows of the slots
 }
 
+# LongCat-Flash's scopes (PR 52). ``scope_parts.json`` has no rows for them
+# (PERF.md section 7); its sub-layers keep the module names the table knows
+# (``sub_<i>/attention``, ``sub_<i>/mlp``, ``..._norm``), so attention, ffn and
+# norm sort as ever, and the identity sum falls to ``named_other`` under ``moe``.
+SCMOE_SCOPES = {
+    "models/deepseek_v2.py DeepseekV2Attention (a scale other than 1)": ["mla_lora_scale"],
+    "models/longcat_flash.py LongcatFlashLayer": ["scmoe_branch"],
+    "moe/layer.py MoE (zero_experts)": ["zero_experts"],
+    "moe/routing.py RouterTopK (selection_bias)": ["router_bias"],
+}
+
 TINY = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2, num_heads=4,
             num_kv_heads=2, kv_size_multiplier=1, max_seq_len=256, dtype=jnp.float32,
             use_flash_attention=True, remat_policy=None)
@@ -280,6 +291,46 @@ def test_laguna_names_the_ring_the_window_read_the_gate_and_both_ropes(program):
     under = [m.group(1) for m in map(OP_NAME.search, compiled.as_text().splitlines())
              if m and "attend_window" in m.group(1)]
     assert under and all("/attention/" in name.split("attend_window")[0] for name in under), under[:3]
+
+
+@pytest.mark.parametrize("program", ["fused_decode", "paged_insert"])
+def test_longcat_flash_names_the_branch_the_identity_sum_the_bias_and_the_scales(program):
+    """The tiny LongCat-Flash decode block and paged insert carry the expert
+    branch, the identity experts' sum, the selection bias and the low-rank
+    scales, both sub-layers under the module names the parts table sorts
+    attention, ffn and norm by, and the latent scopes DeepSeek-V2's carry."""
+    from neuronx_distributed_tpu.models.longcat_flash import (
+        LongcatFlashConfig,
+        LongcatFlashForCausalLM,
+    )
+
+    cfg = LongcatFlashConfig(**dict(
+        TINY, num_kv_heads=4, kv_lora_rank=16, q_lora_rank=24, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, moe_intermediate_size=32, router_experts=16,
+        num_experts=4, zero_experts=8, top_k=6, use_flash_attention=False))
+    weights = meta.unbox(LongcatFlashForCausalLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    lm = CausalLM(cfg, weights, LongcatFlashForCausalLM, buckets=(128,), max_batch=2, page_size=16)
+    decode = program == "fused_decode"
+    compiled = (lm.compile_session_decode_fused(4) if decode
+                else lm._paged_insert_programs(2, 128))
+    components, parts = census(compiled)
+    want = {n for names in SCMOE_SCOPES.values() for n in names}
+    want |= {"sub_0.attend", "sub_1.attend", "sub_0.feed", "sub_1.feed", "mla_q", "mla_kv_down",
+             "kv_write", "kv_gather", "attend", "grouped_matmul",
+             "mla_absorb" if decode else "mla_kv_up"}
+    assert want <= components, sorted(want - components)
+    assert "router_groups" not in components and "shared_expert" not in components
+    for part in ("kv_write", "kv_gather", "attend", "attention", "router", "experts", "ffn", "norm"):
+        assert parts[part] > 0, part
+    names = [m.group(1) for m in map(OP_NAME.search, compiled.as_text().splitlines()) if m]
+    for scope, inside in (("zero_experts", r"/scmoe_branch/.*/moe/$"),
+                          ("router_bias", r"/scmoe_branch/.*/moe/router/$"),
+                          ("mla_lora_scale", r"/sub_[01]\.attend/attention/mla_(q|kv_down)/$")):
+        under = [n for n in names if f"/{scope}/" in n]
+        assert under and all(re.search(inside, n.split(f"{scope}/")[0]) for n in under), \
+            (scope, under[:3])
+    assert unnamed_share(parts) < 0.2, parts
 
 
 def test_dense_decode_has_no_qk_norm_scope(params):

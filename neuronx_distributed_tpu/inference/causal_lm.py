@@ -23,6 +23,7 @@ reference becomes plain slot indexing.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Dict, Optional, Sequence, Tuple
@@ -30,6 +31,8 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.compilation_cache import compilation_cache
+from jax.experimental.layout import Format, Layout
 
 from neuronx_distributed_tpu.inference.paged_cache import PagedKVCache
 from neuronx_distributed_tpu.inference.partition import (
@@ -192,6 +195,54 @@ def _walk_sums(config, cache: PyTree, live: jax.Array) -> jax.Array:
     window = getattr(config, "window_walk_sums", None)
     sums = [walk.tokens, 1, walk.row_slots, *(window(walk) if window else ())]
     return jnp.stack(sums).astype(jnp.int32) * jnp.any(live)
+
+
+def _layout_of(leaf) -> Optional[Layout]:
+    """The device layout a weight leaf HAS: an array's own; for a shape on a
+    described device (which holds no array) that device's default, or the
+    layout the shape was given; None where the leaf says nothing (a host
+    array, a shape without a sharding)."""
+    fmt = getattr(leaf, "format", None)
+    if fmt is None or fmt.layout is not None or fmt.sharding is None:
+        return None if fmt is None else fmt.layout
+    device = min(fmt.sharding.device_set, key=lambda d: d.id)
+    return Layout.from_pjrt_layout(device.client.get_default_layout(
+        jnp.dtype(leaf.dtype), fmt.sharding.shard_shape(leaf.shape), device))
+
+
+@contextlib.contextmanager
+def _compiled_afresh():
+    """Programs compiled inside are not read from JAX's persistent
+    compilation cache (nor written to it). For the re-lay alone: the program
+    behind ``jax.device_put(leaf, format)`` is an identity whose RESULT has
+    the layout, and on the v5e (jax 0.9.0, libtpu 0.0.34) that program read
+    back from the cache hands back the layout it was given: compiled it
+    re-lays, loaded it does not (PERF.md section 6, PR 53). The flag is the
+    process's; it is put back as it was."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _relaid(leaf, fmt: Format):
+    """``leaf`` in the format ``fmt``: a copy on the device (a shape, which
+    holds nothing, just says so), or an error: a weight that is not held the
+    way the programs were told is refused by every one of them."""
+    if isinstance(leaf, jax.ShapeDtypeStruct):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=fmt)
+    with _compiled_afresh():
+        out = jax.device_put(leaf, fmt)
+    got, want = out.format.layout, fmt.layout
+    if got.major_to_minor != want.major_to_minor or want.tiling not in (None, got.tiling):
+        raise RuntimeError(
+            f"a weight {leaf.dtype}{list(leaf.shape)} asked for in {fmt.layout} came "
+            f"back in {out.format.layout}")
+    return out
 
 
 def infer_prompt_lengths(prompt_ids: np.ndarray, pad_token_id: int = 0) -> np.ndarray:
@@ -393,7 +444,13 @@ class CausalLM:
         self._cache_avals_cache: Optional[PyTree] = None
         self._identity_adapters_cache: Optional[PyTree] = None
         self._identity_grammars_cache: Optional[PyTree] = None
-        self.params = params
+        # the weights, HELD IN THE LAYOUT THE ONE-TOKEN STEP READS THEM IN
+        # (``_compile``): as loaded until the first program is lowered
+        self._params = params
+        self._param_formats: Optional[PyTree] = None
+        self._formats_settled = False
+        self.param_relaid_leaves = 0
+        self.param_relaid_bytes = 0
         self.max_batch = max_batch
         # applied INSIDE every compiled program (e.g. int8 dequantization —
         # the quantized weights are what lives in HBM and XLA fuses the
@@ -458,6 +515,109 @@ class CausalLM:
         if tr is not None and tr.enabled:
             tr.complete("compile:" + signature, ("engine", "compile"), t0, t1)
         return prog
+
+    # --- the layout the weights are held in ------------------------------
+    # A kernel stored ``(hidden, heads, head_dim)`` lies, by default, tiled
+    # over ``(heads, head_dim)``; the one-token step's dot wants ``hidden`` in
+    # the tile, so a program that assumes the default copies the whole
+    # stacked leaf at its entry, every call (768 MiB a block at Mistral-7B's
+    # widths), and an insert copies a layer's slice in its layer scan. So the
+    # FIRST program lowered settles the formats. One that runs the one-token
+    # step (``decode``, which ``compile()`` lowers before anything else; a
+    # fused block where a caller builds that first) is compiled once with
+    # ``Layout.AUTO`` on its ``params`` argument, to ASK: what the compiler
+    # reports is the format tree, the few leaves that lie otherwise are
+    # re-laid once, and every program is lowered with those formats fixed on
+    # its ``params`` argument. Nothing names a leaf. On a backend whose
+    # compiler keeps every default (the CPU) the asking compile IS the
+    # program, nothing moves and everything is lowered as it always was.
+
+    @property
+    def params(self) -> PyTree:
+        return self._params
+
+    @params.setter
+    def params(self, tree: PyTree) -> None:
+        """New weights take the formats the compiled programs were given
+        (those programs refuse a committed leaf in any other)."""
+        self._params = tree
+        if self._param_formats is not None:
+            self._hold(self._param_formats)
+
+    def _hold(self, formats: PyTree) -> None:
+        """Settle the weights' formats as ``formats`` (a ``Format`` a leaf,
+        or None: as the leaf is) and re-lay the leaves that are held in
+        another layout, one at a time: this tree lets go of a leaf before the
+        next is copied, so the most held twice is one leaf (a caller that
+        keeps the tree it handed in keeps those leaves too)."""
+        flat, tree = jax.tree_util.tree_flatten(self._params)
+        want = tree.flatten_up_to(formats)
+        self._params = None
+        for i, fmt in enumerate(want):
+            have = _layout_of(flat[i])
+            if fmt is None or have is None or have == fmt.layout:
+                want[i] = None
+                continue
+            flat[i] = _relaid(flat[i], fmt)
+            self.param_relaid_leaves += 1
+            self.param_relaid_bytes += (
+                int(np.prod(flat[i].shape)) * jnp.dtype(flat[i].dtype).itemsize)
+        self._params = tree.unflatten(flat)
+        self._param_formats = (tree.unflatten(want)
+                               if any(f is not None for f in want) else None)
+        self._formats_settled = True
+
+    def _ask_formats(self, fn, rest, jit_kw):
+        """The format pass: ``fn`` compiled with its ``params`` argument as
+        shapes under ``Layout.AUTO`` (each leaf's own sharding kept), the
+        formats that compile reports held (``_hold``). Returns the compiled
+        program where it is the one a plain lowering gives: no leaf moved,
+        and every other argument and every result in the default, row-major
+        order. The TPU compiler, once ANY argument is its to lay out, lays
+        out the others too (a latent pool, a ``(rows, 1)`` token column, the
+        logits), and the next program would refuse those: there the pass is
+        kept for its answer alone and the caller compiles again, formats
+        fixed. Returns None then."""
+        leaves, tree = jax.tree_util.tree_flatten(self._params)
+        shapes = [jax.ShapeDtypeStruct(leaf.shape, leaf.dtype) for leaf in leaves]
+        auto = [Format(Layout.AUTO, getattr(leaf, "sharding", None)
+                       if getattr(leaf, "committed", True) else None)
+                for leaf in leaves]
+        asked = jax.jit(
+            fn, in_shardings=(tree.unflatten(auto), *(None,) * len(rest)),
+            **jit_kw).lower(tree.unflatten(shapes), *rest).compile()
+        formats, *others = asked.input_formats[0]
+        self._hold(formats)
+        plain = self._param_formats is None and all(
+            f.layout is None
+            or f.layout.major_to_minor == tuple(range(len(f.layout.major_to_minor)))
+            for f in jax.tree_util.tree_leaves((others, asked.output_formats)))
+        return asked if plain else None
+
+    def _compile(self, fn, *rest, chooses: bool = False, **jit_kw):
+        """``jax.jit(fn, **jit_kw).lower(self.params, *rest).compile()``, the
+        one place a program meets the weights. The first call settles their
+        formats: where the program ``chooses`` (it runs the one-token step)
+        by asking (``_ask_formats``), otherwise as the weights are. Every
+        program takes them fixed."""
+        if not self._formats_settled and chooses:
+            asked = self._ask_formats(fn, rest, jit_kw)
+            if asked is not None:
+                return asked
+        self._formats_settled = True
+        if self._param_formats is not None:
+            jit_kw["in_shardings"] = (self._param_formats, *(None,) * len(rest))
+        return jax.jit(fn, **jit_kw).lower(self._params, *rest).compile()
+
+    def relaid_leaves(self) -> list:
+        """``[(path, leaf)]`` of the weight leaves held off the layout they
+        were loaded in (``scripts/big_ops.py --formats`` and the tests list
+        them)."""
+        if self._param_formats is None:
+            return []
+        formats = jax.tree_util.tree_leaves(self._param_formats, is_leaf=lambda f: f is None)
+        return [(jax.tree_util.keystr(path), leaf) for (path, leaf), fmt in zip(
+            jax.tree_util.tree_flatten_with_path(self._params)[0], formats) if fmt is not None]
 
     def _resolve(self, params):
         """The single place the serving param transform applies (e.g. int8
@@ -664,6 +824,20 @@ class CausalLM:
             return logits, self._shard_out(mut["cache"])
 
         ad0 = self._ad_lower(self.max_batch)
+        # decode FIRST: it runs the one-token step, so it is the program that
+        # says in which layout the weights are held (``_compile``), and
+        # everything lowered after it takes them so. Donate the cache
+        # (argnum 1). Abstract cache avals suffice for lowering — no need to
+        # execute a real prefill at startup (_cache_avals also pins them
+        # replicated under a mesh).
+        cache0 = self._cache_avals()
+        tok = jnp.zeros((self.max_batch, 1), jnp.int32)
+        self._decode = self._time_compile(
+            "decode",
+            lambda: self._compile(
+                decode_fn, cache0, tok,
+                *self._live_args(np.ones((self.max_batch,), bool)), *ad0,
+                chooses=True, donate_argnums=(1,)))
         if not self.paged:
             # paged mode never runs the stand-alone prefill (its cache init
             # would alias every slot onto page 0): all prefill goes through
@@ -672,18 +846,7 @@ class CausalLM:
                 ids = jnp.zeros((self.max_batch, bucket), jnp.int32)
                 self._prefill[bucket] = self._time_compile(
                     f"prefill_b{bucket}",
-                    lambda ids=ids: jax.jit(prefill_fn)
-                    .lower(self.params, ids, *ad0).compile())
-        # decode: donate the cache (argnum 1). Abstract cache avals suffice
-        # for lowering — no need to execute a real prefill at startup
-        # (_cache_avals also pins them replicated under a mesh).
-        cache0 = self._cache_avals()
-        tok = jnp.zeros((self.max_batch, 1), jnp.int32)
-        self._decode = self._time_compile(
-            "decode",
-            lambda: jax.jit(decode_fn, donate_argnums=(1,))
-            .lower(self.params, cache0, tok,
-                   *self._live_args(np.ones((self.max_batch,), bool)), *ad0).compile())
+                    lambda ids=ids: self._compile(prefill_fn, ids, *ad0))
         return self
 
     def _live_args(self, live) -> tuple:
@@ -761,10 +924,10 @@ class CausalLM:
         done0 = jnp.zeros((self.max_batch,), bool)
         self._decode_fused[key] = self._time_compile(
             f"decode_fused_k{steps}",
-            lambda: jax.jit(fused_fn, donate_argnums=(1,))
-            .lower(self.params, cache0, tok0, jax.random.key(0), done0,
-                   *self._ad_lower(self.max_batch))
-            .compile())
+            lambda: self._compile(
+                fused_fn, cache0, tok0, jax.random.key(0), done0,
+                *self._ad_lower(self.max_batch),
+                chooses=True, donate_argnums=(1,)))
         return self._decode_fused[key]
 
     def _cache_avals(self) -> PyTree:
@@ -1000,14 +1163,14 @@ class CausalLM:
         b = self.max_batch
         self._session_fused[key] = self._time_compile(
             f"session_fused_k{steps}",
-            lambda: jax.jit(fused_fn, donate_argnums=(1,))
-            .lower(self.params, self._cache_avals(),
-                   *repl_args(jnp.zeros((b, 1), jnp.int32),
-                              jax.random.split(jax.random.key(0), b),
-                              jnp.zeros((b,), bool),
-                              jnp.zeros((len(self.BLOCK_ROWS), b), jnp.int32)),
-                   *self._ad_lower(b), *self._gr_lower(b))
-            .compile())
+            lambda: self._compile(
+                fused_fn, self._cache_avals(),
+                *repl_args(jnp.zeros((b, 1), jnp.int32),
+                           jax.random.split(jax.random.key(0), b),
+                           jnp.zeros((b,), bool),
+                           jnp.zeros((len(self.BLOCK_ROWS), b), jnp.int32)),
+                *self._ad_lower(b), *self._gr_lower(b),
+                chooses=True, donate_argnums=(1,)))
         return self._session_fused[key]
 
     def _bucket_for(self, s: int) -> int:
@@ -1256,13 +1419,12 @@ class CausalLM:
 
         self._slab_insert[key] = self._time_compile(
             f"insert_r{rows}_b{bucket}",
-            lambda: jax.jit(insert_fn, donate_argnums=(1, 2))
-            .lower(self.params, self._cache_avals(), *self._first_lower(rows),
-                   jnp.zeros((rows, bucket), jnp.int32),
-                   jnp.zeros((rows,), jnp.int32),
-                   jnp.zeros((rows,), jnp.int32),
-                   *self._ad_lower(rows))
-            .compile())
+            lambda: self._compile(
+                insert_fn, self._cache_avals(), *self._first_lower(rows),
+                jnp.zeros((rows, bucket), jnp.int32),
+                jnp.zeros((rows,), jnp.int32),
+                jnp.zeros((rows,), jnp.int32),
+                *self._ad_lower(rows), donate_argnums=(1, 2)))
         return self._slab_insert[key]
 
     def _replicate_out(self, tree: PyTree) -> PyTree:
@@ -1410,15 +1572,14 @@ class CausalLM:
 
         self._paged_insert[key] = self._time_compile(
             f"paged_insert_r{rows}_b{bucket}",
-            lambda: jax.jit(insert_fn, donate_argnums=(1, 2))
-            .lower(self.params, self._cache_avals(), *self._first_lower(rows),
-                   jnp.zeros((rows, bucket), jnp.int32),
-                   jnp.zeros((rows, ppseq), jnp.int32),
-                   jnp.zeros((rows,), jnp.int32),
-                   jnp.zeros((rows,), jnp.int32),
-                   jnp.zeros((rows,), jnp.int32),
-                   *self._ad_lower(rows))
-            .compile())
+            lambda: self._compile(
+                insert_fn, self._cache_avals(), *self._first_lower(rows),
+                jnp.zeros((rows, bucket), jnp.int32),
+                jnp.zeros((rows, ppseq), jnp.int32),
+                jnp.zeros((rows,), jnp.int32),
+                jnp.zeros((rows,), jnp.int32),
+                jnp.zeros((rows,), jnp.int32),
+                *self._ad_lower(rows), donate_argnums=(1, 2)))
         return self._paged_insert[key]
 
     def _chunk_extend_programs(self, rows: int, bucket: int):
@@ -1478,14 +1639,13 @@ class CausalLM:
 
         self._chunk_extend[key] = self._time_compile(
             f"chunk_extend_r{rows}_b{bucket}",
-            lambda: jax.jit(extend_fn, donate_argnums=(1,))
-            .lower(self.params, self._cache_avals(),
-                   jnp.zeros((rows, bucket), jnp.int32),
-                   jnp.zeros((rows,), jnp.int32),
-                   jnp.zeros((rows,), jnp.int32),
-                   jnp.zeros((rows,), jnp.int32),
-                   *self._ad_lower(rows))
-            .compile())
+            lambda: self._compile(
+                extend_fn, self._cache_avals(),
+                jnp.zeros((rows, bucket), jnp.int32),
+                jnp.zeros((rows,), jnp.int32),
+                jnp.zeros((rows,), jnp.int32),
+                jnp.zeros((rows,), jnp.int32),
+                *self._ad_lower(rows), donate_argnums=(1,)))
         return self._chunk_extend[key]
 
     def extend(self, session: "DecodeSession", slot_ids: np.ndarray,

@@ -390,6 +390,11 @@ _STAT_KEYS = (
     # (min(reach, window) a live row a window layer-step). kv_walk_* above
     # count the layers that page, the full layers, alone
     "kv_window_slots_read", "kv_window_slots_needed",
+    # weight leaves the lm re-laid ONCE into the layout its one-token step
+    # reads them in, and their bytes (CausalLM._hold; 0 where the backend's
+    # compiler keeps the default, as the CPU's does): set when the engine is
+    # built, by which time ``lm.compile()`` has settled them
+    "param_relaid_leaves", "param_relaid_bytes",
 )
 
 
@@ -855,6 +860,8 @@ class ServeEngine:
                     self._injector.on_grammar_acquire
         # legacy counter surface, now a registry-backed view (see _StatsView)
         self.stats = _StatsView(self.metrics, _STAT_KEYS)
+        for key in ("param_relaid_leaves", "param_relaid_bytes"):
+            self.stats[key] = getattr(lm, key, 0)
 
     # --- submission ------------------------------------------------------
 

@@ -3,6 +3,7 @@
 
     JAX_PLATFORMS=cpu python3 scripts/big_ops.py <cell> [--min-mib 6]
                                                  [--insert rows,bucket]
+                                                 [--formats]
 
 Compiles the cell's fused decode block (or, with ``--insert``, one paged
 insert) at its real sizes for a DESCRIBED v5e, as ``benchmark/aot_check.py``
@@ -11,9 +12,18 @@ prints each op whose output is ``--min-mib`` or more, with the computation it
 stands in, its shape and layout, MiB and ``op_name``, and the program's
 ``temp_size_in_bytes``. The compiled text's op names are the device trace's
 (``fusion.1500``, ``copy.1096``), so a row of ``benchmark/trace_parts.py``
-can be looked up here.
+can be looked up here. The program is built the way serving builds it:
+``lm.compile()`` first, whose ``decode`` settles the layout the weights are
+held in (``CausalLM._ask_formats``); ``--formats`` prints the leaves that
+left the default layout for it, with their ``major_to_minor`` and MiB.
 
-What to look for (ROADMAP S10, found by PR 50): a ``copy`` of a whole stacked
+What to look for (ROADMAP S10): a ``copy`` in the ENTRY computation whose
+shape is a parameter's (a weight leaf held in another layout than the program
+reads it in, copied whole at every call: 768 MiB a block at Mistral-7B's
+widths until PR 53 held the weights the way the one-token step reads them;
+what is left there is a cache leaf's, the latent pool's); in an insert, a
+``dynamic-slice`` fusion and a ``copy`` of ONE layer's slice of such a leaf
+inside the layer scan. Found by PR 50: a ``copy`` of a whole stacked
 cache leaf inside a loop body or a ``conditional``'s branch (layout assignment
 re-lays-out the operand of a ``dot_general`` whose batch dimension is not
 outermost in the leaf, AHEAD of the slice that takes a layer's rows), and
@@ -145,9 +155,20 @@ def main(argv=None) -> int:
     parser.add_argument("--insert", metavar="ROWS,BUCKET",
                         help="one paged insert in place of the fused decode block")
     parser.add_argument("--text", metavar="FILE", help="also write the compiled text there")
+    parser.add_argument("--formats", action="store_true",
+                        help="print the weight leaves held off the default layout")
     args = parser.parse_args(argv)
 
+    mib = 2 ** 20
     lm = described_lm(args.workload)
+    lm.compile()                    # decode first: the weights' formats are settled
+    if args.formats:
+        for path, leaf in lm.relaid_leaves():
+            size = math.prod(leaf.shape) * leaf.dtype.itemsize
+            print(f"{size / mib:8.2f} MiB  held {leaf.format.layout.major_to_minor}  "
+                  f"{leaf.dtype.name}{list(leaf.shape)}  {path}")
+        print(json.dumps({"workload": args.workload, "param_relaid_leaves": lm.param_relaid_leaves,
+                          "param_relaid_mib": round(lm.param_relaid_bytes / mib, 1)}))
     if args.insert:
         rows, bucket = map(int, args.insert.split(","))
         compiled = lm._paged_insert_programs(rows, bucket)
@@ -158,7 +179,6 @@ def main(argv=None) -> int:
     text = compiled.as_text()
     if args.text:
         Path(args.text).write_text(text)
-    mib = 2 ** 20
     for op in big_ops(text, int(args.min_mib * mib)):
         print(f"{op['bytes'] / mib:8.2f} MiB  {op['kind'] + (' (in place)' if op['in_place'] else ''):<22} "
               f"{op['op']:<24} {op['shape']}{op['layout']}  in {op['computation']}  {op['op_name']}")

@@ -406,10 +406,11 @@ def test_mixtral_insert_holds_no_logits_but_the_last_positions(chip):
     assert text.count("tpu_custom_call") >= 3    # flash forward, two grouped matmuls
 
 
-def _described_lm(chip, family, monkeypatch, buckets=(128,), rehearse=None):
+def _described_lm(chip, family, monkeypatch, buckets=(128,), rehearse=None, layers=None):
     """``CausalLM`` of one of this file's configurations on the described
     chip, shapes for parameters: two layers, bf16, pages of 16, batch 8.
-    ``rehearse``: DeepSeek-V2's rehearsal sizes (the default) or the cell's."""
+    ``rehearse``: DeepSeek-V2's rehearsal sizes (the default) or the cell's,
+    and those at ``layers`` layers where given."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     from neuronx_distributed_tpu.inference import causal_lm
@@ -428,22 +429,26 @@ def _described_lm(chip, family, monkeypatch, buckets=(128,), rehearse=None):
             vocab_size=256, hidden_size=32 * HEAD_DIM, intermediate_size=1024,
             num_heads=32, num_kv_heads=8, num_layers=2, max_seq_len=4096,
             dtype=jnp.bfloat16, param_dtype=jnp.bfloat16), LlamaForCausalLM
-    elif family in ("deepseek", "laguna"):
-        # the benchmark's configuration: DeepSeek-V2's rehearsal one, Laguna's
-        # as ``laguna-s-2.1.longctx`` runs it (9 layers, 32 experts held, 8192 slots)
+    elif family in ("deepseek", "laguna", "longcat"):
+        # the benchmark's configuration: DeepSeek-V2's and LongCat-Flash's
+        # rehearsal ones, Laguna's as ``laguna-s-2.1.longctx`` runs it
+        # (9 layers, 32 experts held, 8192 slots)
         import json
 
         from benchmark import run as harness
         from benchmark.drivers import serving
 
         root = Path(harness.__file__).resolve().parents[1]
-        name = {"deepseek": "deepseek-v2", "laguna": "laguna-s-2.1"}[family]
+        name = {"deepseek": "deepseek-v2", "laguna": "laguna-s-2.1",
+                "longcat": "longcat-flash-chat"}[family]
         entry = next(c for c in json.loads((root / "BENCHMARK.json").read_text())["configs"]
                      if c["name"] == name)
         loaded = harness.load_config(
-            entry, rehearse=family == "deepseek" if rehearse is None else rehearse)
+            entry, rehearse=family != "laguna" if rehearse is None else rehearse)
         cfg = serving.model_config(loaded, False, remat_policy=None,
-                                   max_seq_len=4096 if family == "deepseek" else 8192)
+                                   max_seq_len=8192 if family == "laguna" else 4096)
+        if layers:
+            cfg = dataclasses.replace(cfg, num_layers=layers)
         cls = serving.load(loaded["builder"]["model"])
     else:
         cfg, cls = _moe_config(family, num_layers=2, max_seq_len=4096,
@@ -467,11 +472,11 @@ def test_fused_session_decode_takes_its_rows_as_one_matrix_and_donates_the_cache
     cover every page leaf, the temporaries stay under one of them (where a
     leaf is larger than the step's activations), and no computation but the
     entry copies a leaf."""
-    lm = _described_lm(chip, family, monkeypatch)
+    lm = _described_lm(chip, family, monkeypatch).compile()      # decode first, as serving does
     compiled = lm.compile_session_decode_fused(8, SlotSampler(), 0)
     text = compiled.as_text()
     assert text.startswith("HloModule jit_fused_fn")
-    entry = re.search(r"^ENTRY .*?^}", text, re.M | re.S).group(0)
+    entry = _entry(text)
     small = re.findall(r"= (\w+\[[0-9,]*\])\S* parameter\(", entry)
     assert small.count("s32[6,8]") == 1                  # rows
     assert small.count("s32[8,1]") == 1 and small.count("pred[8]") == 1   # tok, done
@@ -491,12 +496,95 @@ def test_fused_session_decode_takes_its_rows_as_one_matrix_and_donates_the_cache
         assert not _leaf_copies(text, leaf.shape)
 
 
+def _entry(text):
+    return re.search(r"^ENTRY .*?^}", text, re.M | re.S).group(0)
+
+
+def _copied(text, dims, dtype):
+    """``copy`` instructions of a compiled text (or one computation of it)
+    whose result has ``dims``, ones aside, in ``dtype``."""
+    want = [d for d in dims if d != 1]
+    hlo = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    return [line.strip()[:140] for line in text.splitlines()
+            if (m := re.search(r"= " + hlo + r"\[([0-9,]+)\]\S* copy\(", line))
+            and [d for d in map(int, m.group(1).split(",")) if d != 1] == want]
+
+
+@pytest.mark.parametrize("family", ["llama", "olmoe", "mixtral", "deepseek", "laguna", "longcat"])
+def test_the_weights_are_held_the_way_the_decode_block_reads_them(chip, family, monkeypatch):
+    """``CausalLM``'s real path for the described v5e: ``compile()`` lowers
+    ``decode`` first, which asks the compiler how the one-token step reads
+    each weight leaf (``Layout.AUTO``: ``CausalLM._ask_formats``), the leaves
+    that lie otherwise are re-laid (here shapes, which only say so), and the
+    fused block and the inserts are lowered with those formats fixed. The
+    attention kernels are stored ``(layers, hidden, heads, head_dim)``, tiled
+    over ``(heads, head_dim)`` by default, and the one-token dot wants
+    ``hidden`` in the tile: the parent's block copied each such leaf whole at
+    its entry, every call (llama at Mistral's head counts: 3 copies, the
+    parent's count; 768 MiB a block at 16 layers), and its inserts sliced and
+    copied a layer's share inside the layer scan. Now (a) some leaf was
+    re-laid; (b) the block's entry computation copies NO re-laid leaf, and no
+    other weight leaf of a MiB or more but those the compiler's own answer
+    left where they were (DeepSeek-V2's ``kv_a_proj``, 576 columns: the
+    default of such a shape is not row-major, and the pinned block re-lays
+    28 MiB of it a call where the asking compile did not; PERF.md section 7);
+    (c) the 1 x 128 and 8 x 128 inserts copy no layer's slice of a re-laid
+    leaf anywhere, or, where a prompt's path wants a third order (a latent
+    model's expanded form reads ``k_b_proj`` / ``v_b_proj`` its own way), no
+    more of them than the same insert lowered on the weights as loaded: an
+    insert does not pay for the step's choice; (d) the cache's donation is as
+    it was: the aliased bytes cover every page leaf."""
+    # the latent models at their cells' widths (two layers: one dense and one
+    # expert layer of DeepSeek-V2): the rehearsal's are too narrow to tile
+    from neuronx_distributed_tpu.parallel import mesh
+
+    sizes = dict(rehearse=False, layers=2) if family in ("deepseek", "longcat") else {}
+    lm = _described_lm(chip, family, monkeypatch, **sizes)
+    lm.compile()
+    assert lm.param_relaid_leaves > 0 and lm.param_relaid_bytes > 2 ** 20
+    moved = lm.relaid_leaves()
+    assert len(moved) == lm.param_relaid_leaves
+    kept = [(jax.tree_util.keystr(path), leaf) for path, leaf in
+            jax.tree_util.tree_flatten_with_path(lm.params)[0]
+            if jax.tree_util.keystr(path) not in dict(moved)]
+    print(f"{family}: {len(moved)} leaves held off the default, "
+          f"{lm.param_relaid_bytes / 2 ** 20:.1f} MiB: "
+          + ", ".join(f"{path.split('[')[-1]} {leaf.format.layout.major_to_minor}"
+                      for path, leaf in moved))
+    block = lm.compile_session_decode_fused(8, SlotSampler(), 0)
+    entry = _entry(block.as_text())
+    for path, leaf in moved:
+        assert not _copied(entry, leaf.shape, leaf.dtype), path
+    others = {path: _copied(entry, leaf.shape, leaf.dtype) for path, leaf in kept
+              if math.prod(leaf.shape) * leaf.dtype.itemsize >= 2 ** 20}
+    assert {path.split("'")[-2] for path, copies in others.items() if copies} <= {"kv_a_proj"}
+    pages = [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(lm._cache_avals())[0]
+             if "cached_" in jax.tree_util.keystr(path)]
+    assert block.memory_analysis().alias_size_in_bytes >= sum(
+        math.prod(leaf.shape) * leaf.dtype.itemsize for leaf in pages)
+    def slices_copied(text):
+        return sum(len(_copied(text, shape, leaf.dtype))
+                   for _, leaf in moved for shape in (leaf.shape, leaf.shape[1:]))
+
+    for rows in (1, 8):
+        copied = slices_copied(lm._paged_insert_programs(rows, 128).as_text())
+        if copied:
+            assert family in ("deepseek", "longcat")
+            # an insert lowered first settles the formats as the weights are
+            mesh.destroy_model_parallel()        # the same devices again: the same mesh
+            loaded = _described_lm(chip, family, monkeypatch, **sizes)
+            was = slices_copied(loaded._paged_insert_programs(rows, 128).as_text())
+            assert loaded._formats_settled and loaded.param_relaid_leaves == 0
+            print(f"{family} {rows} x 128 insert: {copied} slices copied, {was} as loaded")
+            assert copied <= was
+
+
 _LAGUNA_BLOCK = {}     # the compiled block, once for the cases below
 
 
 def _laguna_block(chip, monkeypatch):
     if not _LAGUNA_BLOCK:
-        lm = _described_lm(chip, "laguna", monkeypatch)
+        lm = _described_lm(chip, "laguna", monkeypatch).compile()
         compiled = lm.compile_session_decode_fused(8, SlotSampler(), 0)
         _LAGUNA_BLOCK.update(
             text=compiled.as_text(), temp=compiled.memory_analysis().temp_size_in_bytes,

@@ -394,6 +394,13 @@ _STAT_KEYS = (
     # one of each, so (calls + fetches) / inserts reads 2.0 where every
     # admission was a one-shot insert and more where one went by chunks
     "insert_program_calls", "insert_host_fetches",
+    # inserts whose first tokens were left on the device at dispatch because
+    # nothing on it waited for them (_insert_group), inserts dispatched while
+    # an earlier one was still unfetched (the host planned and dispatched
+    # them while the device ran that one: overlapped / inserts is the share),
+    # and one-token rows that gave their slot and pages back at dispatch
+    "insert_fetches_deferred", "inserts_overlapped",
+    "slots_released_at_dispatch",
     # how far the fused decode blocks read the cache, and of how many rows:
     # slots read of a row (models/llama.py::KVWalk, chunk rounding included)
     # summed over the steps that had a live row, those steps, and the slots
@@ -1148,6 +1155,12 @@ class ServeEngine:
                                         block=self.blocks,
                                         args={"state": "prefill"})
                 return True
+        if any(p["rid"] == request_id for p in self._first_pending):
+            # its first token is still on the device: settle (what was
+            # dispatched for the client is recorded). A one-token request
+            # that released its slot at dispatch completes here, as it would
+            # have inside its round, and the cancel finds nothing to cut
+            self._flush()
         for slot, req in enumerate(self.slots):
             if req is not None and req.request_id == request_id:
                 # async: the in-flight block still includes this row; drain
@@ -1439,13 +1452,13 @@ class ServeEngine:
                 or (r.deadline_block is not None
                     and self.blocks > r.deadline_block))
 
-    def _missed(self, req: Request) -> bool:
+    def _missed(self, req: Request, at: Optional[int] = None) -> bool:
         if req.ttft_deadline_block is not None and (
                 req.first_token_block is None
                 or req.first_token_block > req.ttft_deadline_block):
             return True
         return (req.deadline_block is not None
-                and self.blocks > req.deadline_block)
+                and (self.blocks if at is None else at) > req.deadline_block)
 
     def _retry_after(self) -> int:
         """Backlog-drain estimate in blocks: total undelivered token budget
@@ -1667,22 +1680,27 @@ class ServeEngine:
                     time.sleep(delay)
 
     def _completion_of(self, req: Request, cancelled: bool = False,
-                       expired: bool = False) -> Completion:
+                       expired: bool = False,
+                       block: Optional[int] = None) -> Completion:
+        """``req``'s completion as of virtual block ``block`` (now, unless a
+        reply fetched late names the block its stream ended on)."""
+        at = self.blocks if block is None else block
         ts = self._out_ts.pop(req.request_id, [])
         self._submit_ts.pop(req.request_id, None)
         self._last_tok_ts.pop(req.request_id, None)
         self._decode_since.pop(req.request_id, None)
         self._release_adapter(req)   # retire unpins (adapter stays resident)
         self._release_grammar(req)   # ... and the grammar pin likewise
-        if self.incident is not None and (expired or self._missed(req)):
-            self._miss_blocks.append(self.blocks)
+        missed = expired or self._missed(req, at)
+        if self.incident is not None and missed:
+            self._miss_blocks.append(at)
         if self.tracer.enabled:
             kind = ("cancel" if cancelled else
                     "expire" if expired else "retire")
             self.tracer.instant(
-                kind, ("req", req.request_id), block=self.blocks,
+                kind, ("req", req.request_id), block=at,
                 args={"generated": len(self._out.get(req.request_id, [])),
-                      "deadline_missed": bool(expired or self._missed(req))})
+                      "deadline_missed": bool(missed)})
         reason = self._finish_reason.pop(req.request_id, "budget")
         if cancelled:
             reason = "cancelled"
@@ -1693,15 +1711,15 @@ class ServeEngine:
             tokens=np.asarray(self._out.pop(req.request_id, []), np.int64),
             prompt_len=req.prompt.size,
             queue_blocks=max((req.start_block
-                              if req.start_block is not None else self.blocks)
+                              if req.start_block is not None else at)
                              - req.arrival_block, 0),
-            decode_blocks=self.blocks - (req.start_block or 0),
+            decode_blocks=at - (req.start_block or 0),
             ttft_blocks=max((req.first_token_block
                              if req.first_token_block is not None
-                             else self.blocks) - req.arrival_block, 0),
+                             else at) - req.arrival_block, 0),
             token_ts=np.asarray(ts, np.float64),
             cancelled=cancelled, expired=expired,
-            deadline_missed=expired or self._missed(req),
+            deadline_missed=missed,
             tenant=req.tenant,
             adapter=req.adapter,
             grammar=req.grammar,
@@ -1752,28 +1770,32 @@ class ServeEngine:
 
     def _observe_first_token(self, req: Request, slot: int, now: float,
                              claimed: Optional[float] = None,
+                             block: Optional[int] = None,
                              **extra) -> None:
         """First-token observation shared by the admission paths (one-shot
         insert, chunked-prefill finish, fresh recovery replay): wall-TTFT
         histogram + admit/first_token marks on the request lane. ``admit``
         is stamped ``claimed`` where the caller took one when it claimed
         the slot (a one-shot insert: the prefill lies between the two
-        marks), else ``now``."""
+        marks), else ``now``. ``now`` is when the token reached the host: a
+        deferred first token is observed where it is settled, under the
+        ``block`` that admitted it."""
         sts = self._submit_ts.get(req.request_id)
         if sts is not None:
             self._m_ttft.observe((now - sts) * 1e3)
         if not self.tracer.enabled:
             return
         rid = req.request_id
+        at = self.blocks if block is None else block
         self.tracer.instant(
             "admit", ("req", rid), ts=now if claimed is None else claimed,
-            block=self.blocks,
+            block=at,
             args={"slot": int(slot),
                   **{k: v for k, v in extra.items() if v is not None}})
         self.tracer.instant("first_token", ("req", rid), ts=now,
-                            block=self.blocks,
+                            block=at,
                             args={"ttft_blocks": max(
-                                self.blocks - req.arrival_block, 0)})
+                                at - req.arrival_block, 0)})
 
     def _expire_request(self, req: Request) -> None:
         """Deadline passed before (or while) prefill: deliver an empty
@@ -1841,8 +1863,7 @@ class ServeEngine:
             and self.blocks > req.deadline_block]
         if not victims:
             return
-        if self.async_loop:
-            self._flush()
+        self._flush()
         for slot in victims:
             req = self.slots[slot]
             if req is None or self._done[slot]:
@@ -1920,7 +1941,8 @@ class ServeEngine:
             if not group:
                 continue
             try:
-                self._insert_group(group, free[: len(group)], bucket)
+                released = self._insert_group(group, free[: len(group)],
+                                              bucket)
             except PagePoolExhausted:
                 # pool pressure (paged mode): the group insert is atomic and
                 # no device work ran (allocation precedes the program).
@@ -1934,11 +1956,16 @@ class ServeEngine:
                 self.queue.extendleft(reversed(group[1:]))
                 self._note_pool_pressure(group[1:])
                 try:
-                    self._insert_group(group[:1], free[:1], bucket)
+                    released = self._insert_group(group[:1], free[:1], bucket)
                 except PagePoolExhausted:
                     self.queue.appendleft(group[0])
                     self._note_pool_pressure(group[:1])
                     return
+            if released:
+                # one-token rows of an insert nobody waits for: slot, pages
+                # and table row go back now, for the next group of this loop
+                self.lm.retire(self.session, np.asarray(released, np.int32))
+                self.stats["slots_released_at_dispatch"] += len(released)
 
     def _tier_marker(self) -> Optional[int]:
         """Cumulative tier-restore count before an admission (None without
@@ -1968,17 +1995,45 @@ class ServeEngine:
                 args={"pages": int(delta), "group_rows": len(group)})
 
     def _insert_group(self, group: List[Request], slot_ids: List[int],
-                      bucket: int) -> None:
+                      bucket: int) -> List[int]:
+        """Admit ``group`` into ``slot_ids`` by ONE insert program call, and
+        decide from what the engine sees when its first tokens are fetched
+        and when a slot is given back.
+
+        Rows are decoding at the dispatch (``decoding`` below, what the
+        ``admission`` span says): the tokens are fetched at once, inside the
+        span; a decode block follows in this round and needs them. Nothing
+        decodes (or the loop is the async one, whose blocks read the tokens
+        on the device): the first tokens and the routing sums stay on the
+        device, the admission is queued on ``_first_pending`` and
+        :meth:`_settle_firsts` fetches it later, so the host plans and
+        dispatches the next insert while this one runs. A prefill worker
+        never defers: its handoff needs the token now.
+
+        A row of a deferred insert whose budget is one token is finished
+        whatever that token is: its slot is never taken, the pending entry
+        carries the REQUEST, its completion is built when the token arrives,
+        as of this block, and the slots of such rows are RETURNED for the
+        caller (``_admit_loop``) to give back pages and table rows right
+        after the dispatch (the device runs programs in order, so the next
+        insert may take them). Every other row keeps its slot until it
+        retires."""
         # the slots are claimed HERE: the requests' ``queued`` spans end and
         # their ``admit`` is stamped at this instant, before the prefill
         claimed = time.perf_counter()
         rows = len(group)
+        # the rows this admission stalls while its program runs
+        decoding = int((self._active & ~self._done).sum())
+        defer = self.role != "prefill" and (self.async_loop or not decoding)
+        if not self.async_loop:
+            # the sync round leaves at most ONE insert unfetched behind the
+            # one it dispatches (two in the device's queue), and none behind
+            # an insert whose tokens it fetches itself
+            self._settle_firsts(keep_newest=defer)
         span_args = None
         if self.tracer.enabled:
             span_args = {
-                "rows": rows, "bucket": int(bucket),
-                # the rows this admission stalls while its program runs
-                "decoding": int((self._active & ~self._done).sum()),
+                "rows": rows, "bucket": int(bucket), "decoding": decoding,
                 "rids": [r.request_id for r in group]}
         with self.tracer.span("admission", (self.lane, "phases"),
                               block=self.blocks, args=span_args):
@@ -2021,14 +2076,13 @@ class ServeEngine:
             self.stats["inserts"] += 1
             self.stats["inserted_requests"] += rows
             self.stats["insert_program_calls"] += 1
-            # async pipeline: fetching the sampled first tokens here would block
-            # on the insert program, which chains AFTER the in-flight decode
-            # block (session.cache is its donated output future) — serializing
-            # the very overlap the loop exists for. Leave the program's output on
-            # device; _settle_firsts records the host values at the next harvest
-            # (the designated sync point). A prefill worker never defers: it has
-            # no decode pipeline and _handoff_group needs the token NOW.
-            defer = self.async_loop and self.role != "prefill"
+            self.stats["insert_fetches_deferred"] += int(defer)
+            self.stats["inserts_overlapped"] += int(bool(self._first_pending))
+            # a deferred insert's outputs stay on the device: fetching them
+            # here would block the host on the program (and, async, on the
+            # in-flight decode block it chains after: session.cache is that
+            # block's donated output future) through work that needs none
+            # of them
             first_dev = routing = None
             if self._sim:
                 first = np.asarray(self.lm.sim_first_tokens(
@@ -2042,12 +2096,30 @@ class ServeEngine:
                            self.session.insert_scanned)
                 first = None if defer else self._fetch_first(first_dev, routing)
         now = time.perf_counter()
+        seen = {"claimed": claimed, "bucket": bucket, "rows": rows}
+        released = []
         for i, (r, slot) in enumerate(zip(group, slot_ids)):
             r.start_block = self.blocks
             r.first_token_block = self.blocks
             self._trace_queued(r, claimed)
-            self._observe_first_token(r, slot, now, claimed=claimed,
-                                      bucket=bucket, rows=rows)
+            if defer:
+                # whatever its one token is, the row is finished: no slot
+                leaves = r.max_new_tokens == 1
+                # the RECORD (and in sim, only the record — the value is
+                # host-known) waits for the settle, so a 1-token budget
+                # retires on the same virtual block in sim and real mode
+                self._first_pending.append({
+                    "slot": slot, "rid": r.request_id, "idx": i,
+                    "fut": first_dev, "block": self.blocks,
+                    "insert": self.stats["inserts"], "seen": seen,
+                    "routing": routing,
+                    "val": None if first is None else int(first[i]),
+                    "req": r if leaves else None})
+                if leaves:
+                    released.append(slot)
+                    continue
+            else:
+                self._observe_first_token(r, slot, now, **seen)
             self.slots[slot] = r
             self._out[r.request_id] = []
             self._out_ts[r.request_id] = []
@@ -2062,31 +2134,24 @@ class ServeEngine:
             self._gidx[slot] = self._grammar_slot(r)
             self._gstate[slot] = 0
             self._gbudget[slot] = r.max_new_tokens
-            if defer:
-                # the RECORD (and in sim, only the record — the value is
-                # host-known) waits for the harvest so a 1-token budget
-                # retires on the same virtual block in sim and real mode
-                self._first_pending.append({
-                    "slot": slot, "rid": r.request_id, "idx": i,
-                    "fut": first_dev, "block": self.blocks,
-                    "routing": routing,
-                    "val": None if first is None else int(first[i])})
-                if self._sim:
-                    self._tok[slot] = int(first[i])
-                    self._staged[slot] = None
-                else:
-                    self._staged[slot] = {"fut": first_dev, "idx": i}
-            else:
+            if not defer:
                 self._tok[slot] = int(first[i])
                 self._record(slot, int(first[i]), now)
                 self._advance_grammar(slot, int(first[i]))
-                if self.async_loop:
-                    self._staged[slot] = None
+            elif self._sim:
+                self._tok[slot] = int(first[i])
+            if self.async_loop:
+                # the row's override at the next pipelined dispatch: the
+                # host mirrors, or the token still on the device
+                self._staged[slot] = (
+                    {"fut": first_dev, "idx": i}
+                    if defer and not self._sim else None)
         if self.role == "prefill":
             # disaggregation: the prompt's KV is done and its first token
             # sampled — hand the pages to the decode pool and free the slot
             # (streams finished AT the first token retire locally instead)
             self._handoff_group(list(slot_ids))
+        return released
 
     # --- chunked prefill (the stall-free admission path) ------------------
 
@@ -2209,8 +2274,9 @@ class ServeEngine:
                 first = int(np.asarray(first_dev)[0])
                 self.stats["insert_host_fetches"] += 1
         req.first_token_block = self.blocks
-        self._observe_first_token(req, slot, time.perf_counter(),
-                                  chunked=True)
+        if not defer:
+            self._observe_first_token(req, slot, time.perf_counter(),
+                                      chunked=True)
         self._out[req.request_id] = []
         self._out_ts[req.request_id] = []
         self._lengths[slot] = req.prompt.size
@@ -2228,7 +2294,8 @@ class ServeEngine:
             self._first_pending.append({
                 "slot": slot, "rid": req.request_id, "idx": 0,
                 "fut": first_dev, "block": self.blocks,
-                "val": first if self._sim else None})
+                "insert": self.stats["inserts"], "seen": {"chunked": True},
+                "val": first if self._sim else None, "req": None})
             if self._sim:
                 self._tok[slot] = first
                 self._staged[slot] = None
@@ -2330,12 +2397,12 @@ class ServeEngine:
         largest-bucket ``extend`` chunks (prefix-cache hits skip shared
         pages where they survive), then sample token ``g`` under
         ``fold_in(req_key, g)`` — bit-identical to the uninterrupted run."""
-        # async: a replay is recovery work, not the steady-state path — it
-        # samples its resumed token synchronously, so drain the pipeline
-        # first (designated sync point; the next dispatch restarts cold from
-        # the host mirrors, which this admission is about to set)
-        if self.async_loop:
-            self._flush()
+        # a replay is recovery work, not the steady-state path — it samples
+        # its resumed token synchronously, so drain what is in flight first:
+        # the async pipeline (designated sync point; the next dispatch
+        # restarts cold from the host mirrors, which this admission is about
+        # to set) and, in either loop, the inserts whose replies are pending
+        self._flush()
         aslot = 0
         if self.lora and req.adapter is not None:
             # re-pin the stream's adapter BEFORE any page work (it may have
@@ -2601,14 +2668,14 @@ class ServeEngine:
         through one — their streams resume bit-identical (per-request
         rng)."""
         pkv = self.session.paged
-        # async: recovery reads _out (delivered-so-far) to rebuild replay
-        # records — drain the pipeline first so those records are whole,
-        # then retire streams the drain completed: a finished stream's KV
-        # needs no repair, and replaying it would sample one token past
-        # its budget (_replay_admission resumes at len(pregen))
-        if self.async_loop:
-            self._flush()
-            self._retire_finished()
+        # recovery reads _out (delivered-so-far) to rebuild replay
+        # records — drain the async pipeline and the pending replies first
+        # so those records are whole, then retire streams the drain
+        # completed: a finished stream's KV needs no repair, and replaying
+        # it would sample one token past its budget (_replay_admission
+        # resumes at len(pregen))
+        self._flush()
+        self._retire_finished()
         bad = {int(p) for p in pages}
         all_bad = sorted(bad)
         replays_before = self.stats["corrupt_page_replays"]
@@ -2969,14 +3036,14 @@ class ServeEngine:
                      if r is not None and r.request_id == rid), None)
         if slot is None or slot in self._prefilling:
             raise ValueError(f"request {rid} is not a decoding stream")
-        if self.async_loop:
-            # designated sync point: the in-flight block may still emit for
-            # (or finish) this slot — drain before freezing its state
-            self._flush()
-            self._retire_finished()
-            cur = self.slots[slot]
-            if cur is None or cur.request_id != rid:
-                return "retired"
+        # designated sync point: the in-flight block may still emit for (or
+        # finish) this slot, and a pending reply is delivered before a
+        # stream is frozen — drain both
+        self._flush()
+        self._retire_finished()
+        cur = self.slots[slot]
+        if cur is None or cur.request_id != rid:
+            return "retired"
         if self._done[slot]:
             # finished while we looked: nothing to park, the next
             # scheduling pass retires it with a normal completion
@@ -3173,11 +3240,10 @@ class ServeEngine:
                 "a prefill worker cannot resume decode streams — route "
                 "resumes to a decode-capable worker")
         rid = int(request_id)
-        if self.async_loop:
-            # designated sync point: page adoption + mirror install must
-            # land on a true block boundary
-            self._flush()
-            self._retire_finished()
+        # designated sync point: page adoption + mirror install must land
+        # on a true block boundary, nothing in flight and no reply pending
+        self._flush()
+        self._retire_finished()
         t0 = time.perf_counter()
         try:
             parked = self.park_store.load(rid)
@@ -3389,16 +3455,17 @@ class ServeEngine:
         if self._sim:
             raise ValueError(
                 "sim engines have no rng/device state to snapshot")
-        # async: the snapshot serializes _out (delivered-so-far) per stream;
-        # drain the pipeline so the capture is a true block boundary — the
-        # restored engine replays prompt+generated and resumes bit-identical.
+        # the snapshot serializes _out (delivered-so-far) per stream; drain
+        # the async pipeline and the pending replies so the capture is a
+        # true block boundary — the restored engine replays prompt+generated
+        # and resumes bit-identical, and a one-token request whose reply was
+        # on the device is completed, not lost.
         # The drain may latch done for streams that finished in flight:
         # retire them NOW (exactly what the next scheduling pass would do)
         # or the snapshot would encode an already-complete stream as
         # "decoding" and the restore would decode past its budget
-        if self.async_loop:
-            self._flush()
-            self._retire_finished()
+        self._flush()
+        self._retire_finished()
 
         def enc(r: Request, state: str, generated: List[int]) -> dict:
             # constrained streams carry (grammar name, DFA state): the
@@ -3637,6 +3704,18 @@ class ServeEngine:
         req = self.slots[slot]
         if req is None or self._done[slot]:
             return
+        delivered = self._deliver(req, token, ts, block)
+        if req.eos_token_id is not None and token == req.eos_token_id:
+            self._done[slot] = True
+            self._finish_reason.setdefault(req.request_id, "eos")
+        if delivered >= req.max_new_tokens:
+            self._done[slot] = True
+            self._finish_reason.setdefault(req.request_id, "budget")
+
+    def _deliver(self, req: Request, token: int, ts: float,
+                 block: Optional[int] = None) -> int:
+        """One token onto ``req``'s stream, stamped ``ts``: what of
+        :meth:`_record` needs no slot. Returns the stream's length."""
         out = self._out[req.request_id]
         out.append(token)
         self._out_ts[req.request_id].append(ts)
@@ -3653,12 +3732,26 @@ class ServeEngine:
                 "tok", ("req", req.request_id),
                 block=self.blocks if block is None else block, ts=ts,
                 args={"t": int(token), "i": len(out) - 1})
+        return len(out)
+
+    def _complete_released(self, req: Request, token: int, ts: float,
+                           block: int) -> None:
+        """The reply of a request that gave its slot back when its insert
+        was dispatched (:meth:`_insert_group`: a budget of one token): the
+        token is its whole stream, and its completion is the one the round
+        that inserted it (``block``) would have built."""
+        rid = req.request_id
+        self._out[rid], self._out_ts[rid] = [], []
+        self._deliver(req, token, ts, block)
         if req.eos_token_id is not None and token == req.eos_token_id:
-            self._done[slot] = True
-            self._finish_reason.setdefault(req.request_id, "eos")
-        if len(out) >= req.max_new_tokens:
-            self._done[slot] = True
-            self._finish_reason.setdefault(req.request_id, "budget")
+            self._finish_reason[rid] = "eos"
+        elif self.grammar and req.grammar is not None:
+            # the mirror of _advance_grammar, from the START state
+            dfa = self.session.grammars.grammar(req.grammar)
+            nxt = dfa.walk(0, token)
+            if nxt >= 0 and dfa.terminal[nxt]:
+                self._finish_reason[rid] = "grammar_accept"
+        self._emit_completion(self._completion_of(req, block=block))
 
     def _retire_finished(self) -> None:
         finished = [i for i, r in enumerate(self.slots)
@@ -3809,7 +3902,17 @@ class ServeEngine:
         first), spend the prefill-chunk budget, advance every active slot
         ``block_steps`` tokens, record emissions, expire past-deadline
         streams, retire finished slots. Returns False when there is nothing
-        left to do at the current virtual time.
+        left to do at the current virtual time, and never while a reply is
+        still on the device: an insert dispatched with nothing decoding
+        leaves its first tokens there (:meth:`_insert_group`), the round
+        fetches every such insert but the newest it dispatched itself
+        (:meth:`_step_block_sync`), and a round that ends with one
+        unfetched, or that began with one and fetched it, answers True: so
+        ``run()`` and a caller's drain call again, the next round (which
+        fetches whatever it did not dispatch) brings the reply, and a
+        caller that reads completions only after a True sees it. A
+        one-token request's slot is free from its insert's dispatch; every
+        other slot from the round its stream ends in.
 
         With ``async_loop=True`` the same round runs double-buffered: the
         scheduling pass commits on state as of block t-2's harvest, block t
@@ -3847,11 +3950,28 @@ class ServeEngine:
 
     def _step_block_sync(self) -> bool:
         """The synchronous block loop — the exactness oracle the async
-        pipeline is tested bit-identical against."""
+        pipeline is tested bit-identical against.
+
+        When the first tokens come to the host: an insert dispatched with
+        rows decoding is fetched inside its ``admission`` span; one
+        dispatched with nothing decoding is left on ``_first_pending``, and
+        the admit phase ends by settling every pending insert except the
+        newest, and the newest too unless this round dispatched it and no
+        row will decode in this round (a launch reads the tokens). So a
+        round that dispatched nothing fetches all, a round that dispatched
+        two fetches the first, and a closed loop of one-token requests keeps
+        two inserts in the device's queue: insert n+1 is planned and
+        dispatched while insert n runs, then n is fetched. When a slot is
+        free: a one-token row's at its insert's dispatch, any other row's
+        when ``_retire_finished`` sees its stream ended. ``cancel``,
+        ``park``, ``snapshot``, replay, corruption recovery and deadline
+        expiry settle first (``_flush``), like the async loop's."""
         rnd = self.blocks         # the round's number, on all of its spans
         with self._phase("admit", rnd, self._tile_at) as tile:
             self._emitted.clear()     # harvest reads last block's emissions
             self.queue.advance(self.blocks)
+            owed = bool(self._first_pending)    # replies this round brings
+            deferred = self.stats["insert_fetches_deferred"]
             self._sweep_idle_parks()  # idle streams spill to the durable tier
             self._drain_replays()     # recovery re-enters ahead of admits
             self._admit()
@@ -3859,6 +3979,11 @@ class ServeEngine:
             self._admit()             # ... freeing its slot for queued work
             self._expire_prefilling()  # deadline died mid-chunk: unwind
             self._advance_prefill()   # <= prefill_chunk_tokens of prefill
+            # the replies nothing waited for, all but the one insert the
+            # device may still be running behind this round's host work
+            self._settle_firsts(keep_newest=(
+                self.stats["insert_fetches_deferred"] > deferred
+                and not self._active.any()))
             self._retire_finished()   # a 1-token budget may finish at chunk end
             if self._injector is not None and self.paged:
                 victims = self._injector.pages_to_corrupt(
@@ -3870,7 +3995,10 @@ class ServeEngine:
         if not self._active.any():
             if (not self.queue and not self._prefilling
                     and not self._replay_q):
-                return False
+                # virtual time stands still; a reply this round brought, or
+                # one still on the device for the next round to fetch, is
+                # work all the same
+                return owed or bool(self._first_pending)
             # nothing decoding, but arrivals, chunked prefill, or deferred
             # recovery replays pending: advance virtual time
             self.blocks += 1
@@ -4298,22 +4426,31 @@ class ServeEngine:
             if req is not None and rids[slot] == req.request_id:
                 self._tok[slot] = int(toks[-1, slot])
 
-    def _settle_firsts(self, before_block: Optional[int] = None) -> None:
-        """Record deferred first tokens (sim: host-known values whose
-        RECORD waited for schedule parity; real: device futures from the
-        admission-time sampler, fetched here — after the previous block's
-        harvest, while the current block still runs). ``before_block``
-        limits the pass to admissions at or before that block — a multi-
-        block drain must interleave first-token records with the blocks
-        that follow them, or a stream's token 0 would land after its
-        token 1."""
+    def _settle_firsts(self, before_block: Optional[int] = None,
+                       keep_newest: bool = False) -> None:
+        """Fetch and record deferred first tokens, ONE fetch an insert (sim:
+        host-known values whose RECORD waited for schedule parity; real:
+        what the insert program left on the device). Both loops settle
+        here. The async loop after the previous block's harvest, while the
+        current block runs; ``before_block`` limits the pass to admissions
+        at or before that block — a multi-block drain must interleave
+        first-token records with the blocks that follow them, or a stream's
+        token 0 would land after its token 1. The sync round
+        (:meth:`_step_block_sync`) once its admit phase has dispatched what
+        it could, with ``keep_newest`` leaving the newest insert on the
+        device for the next round to fetch. A row that kept its slot is
+        recorded into it; a request that released its slot at dispatch is
+        completed (:meth:`_complete_released`). The stamp of a token is
+        taken after its fetch returned."""
         if not self._first_pending:
             return
+        kept = self._first_pending[-1]["insert"] if keep_newest else None
         keep: List[dict] = []
         fetched: Dict[int, np.ndarray] = {}     # one fetch an admission
         now = time.perf_counter()
         for p in self._first_pending:
-            if before_block is not None and p["block"] > before_block:
+            if p["insert"] == kept or (
+                    before_block is not None and p["block"] > before_block):
                 keep.append(p)
                 continue
             if p["fut"] is None:
@@ -4322,11 +4459,17 @@ class ServeEngine:
                 if id(p["fut"]) not in fetched:
                     fetched[id(p["fut"])] = self._fetch_first(
                         p["fut"], p.get("routing"))
+                    now = time.perf_counter()
                 tok = int(fetched[id(p["fut"])][p["idx"]])
             slot = p["slot"]
-            req = self.slots[slot]
+            req = p["req"] or self.slots[slot]
             if req is None or req.request_id != p["rid"]:
                 continue        # cancelled/expired before delivery
+            self._observe_first_token(req, slot, now, block=p["block"],
+                                      **p["seen"])
+            if p["req"] is not None:
+                self._complete_released(req, tok, now, p["block"])
+                continue
             self._tok[slot] = tok
             self._record(slot, tok, now, block=p["block"])
             self._advance_grammar(slot, tok)
@@ -4334,11 +4477,13 @@ class ServeEngine:
 
     def _flush(self) -> None:
         """Drain the pipeline completely: fetch+harvest every in-flight
-        block and settle every deferred first token. After a flush the next
+        block and settle every deferred first token (the sync loop has no
+        block in flight: its pending replies alone). After a flush the next
         dispatch restarts cold from the host mirrors — bit-identical state
-        to a sync engine at the same block boundary (which is why snapshot,
-        cancel, replay, corruption recovery and deadline expiry may run
-        their sync-era logic unchanged after calling this)."""
+        to a sync engine at the same block boundary with nothing pending
+        (which is why snapshot, cancel, park, replay, corruption recovery
+        and deadline expiry run their logic unchanged after calling this,
+        in either loop)."""
         self._harvest_inflight(drain=True)
 
     # --- observability surface -------------------------------------------

@@ -255,12 +255,20 @@ def test_admission_lies_inside_admit_and_says_whom_it_stalled(traced, arg):
 
 
 def test_admission_holds_its_insert_and_the_fetch_of_its_first_tokens(traced):
+    """With rows decoding the fetch lies inside the span (they stand still for
+    it); with none it follows the span, still inside the round's ``admit``
+    (ISSUE 55: nobody waited, so the host did not either)."""
     engine, _, _ = traced
+    admits = _spans(engine, "phases", "admit")
+    stalled = []
     for admission, insert, fetch in zip(_spans(engine, "phases", "admission"),
                                         _spans(engine, "dispatch", "insert"),
                                         _spans(engine, "dispatch", "insert_fetch")):
-        assert _inside(insert, admission) and _inside(fetch, admission)
-        assert insert["ts"] + insert["dur"] <= fetch["ts"]
+        assert _inside(insert, admission) and insert["ts"] + insert["dur"] <= fetch["ts"]
+        stalled.append(admission["args"]["decoding"] > 0)
+        assert _inside(fetch, admission) == stalled[-1]
+        assert sum(_inside(fetch, outer) for outer in admits) == 1
+    assert stalled == [False, True, True]
 
 
 @pytest.mark.parametrize("name", ["cache_plan", "cache_commit"])

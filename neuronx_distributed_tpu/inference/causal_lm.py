@@ -40,7 +40,12 @@ from neuronx_distributed_tpu.inference.partition import (
     tp_degree, zeros_like_avals,
 )
 from neuronx_distributed_tpu.inference.sampling import Sampler, SlotSampler
-from neuronx_distributed_tpu.models.llama import kv_walk
+from neuronx_distributed_tpu.models.llama import (
+    KV_PAGE_LEAVES,
+    KV_SCALE_LEAVES,
+    kv_walk,
+    leaf_paths,
+)
 from neuronx_distributed_tpu.moe.expert_mlps import grouped_rows_multiplied, share_call_sums
 from neuronx_distributed_tpu.utils.compile_cache import compile_log
 
@@ -180,6 +185,15 @@ def _routing_sums(chosen: jax.Array, routed: Optional[jax.Array],
     return jnp.stack(sums).astype(jnp.int32)
 
 
+# what a configuration may add to a step's three sums, and the ``engine.stats``
+# counters each of its values lands in (in this order, after the three)
+WALK_SUMS = {
+    "window_walk_sums": ("kv_window_slots_read", "kv_window_slots_needed"),
+    "sparse_walk_sums": ("dsa_tokens_visible", "dsa_tokens_selected",
+                         "dsa_latent_slots_read"),
+}
+
+
 def _walk_sums(config, cache: PyTree, live: jax.Array) -> jax.Array:
     """``(3,) int32`` of one decode step: the slots of the cache the step read
     of its longest row (:class:`~neuronx_distributed_tpu.models.llama.KVWalk`,
@@ -189,12 +203,17 @@ def _walk_sums(config, cache: PyTree, live: jax.Array) -> jax.Array:
     given, so they are the bounds the attention computed. They count the
     layers that page: a model whose window layers keep a ring a slot
     (``config.window_walk_sums``; ``models/laguna.py``) adds two, the ring
-    slots those layers read and the tokens they needed."""
+    slots those layers read and the tokens they needed; one that reads a
+    chosen set of its tokens (``config.sparse_walk_sums``;
+    ``models/deepseek_v32.py``) three, the tokens visible, chosen and read
+    (``WALK_SUMS``)."""
     idx = next(leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
                if jax.tree_util.keystr(path).endswith("['cache_index']"))[0]
     walk = kv_walk(config, idx, live)
-    window = getattr(config, "window_walk_sums", None)
-    sums = [walk.tokens, 1, walk.row_slots, *(window(walk) if window else ())]
+    sums = [walk.tokens, 1, walk.row_slots]
+    for more in WALK_SUMS:
+        if hasattr(config, more):
+            sums.extend(getattr(config, more)(walk))
     return jnp.stack(sums).astype(jnp.int32) * jnp.any(live)
 
 
@@ -498,7 +517,9 @@ class CausalLM:
         self.wants_live = self.moe_stats or bool(self.slot_rows)
         # sums a fused block's steps add up of what they read of the cache
         # (``_walk_sums``): three, and two more of a model with rings
-        self.walk_sums = 5 if hasattr(self.config, "window_walk_sums") else 3
+        self.walk_sum_names = tuple(name for more, names in WALK_SUMS.items()
+                                    if hasattr(self.config, more) for name in names)
+        self.walk_sums = 3 + len(self.walk_sum_names)
         # per-slot state that a prompt's recurrence scans (the insert's scan sums)
         self.scans = bool(self.slot_rows) and hasattr(self.config, "scan_positions")
 
@@ -1230,8 +1251,8 @@ class CausalLM:
 
         tp = (ps.get_tensor_model_parallel_size()
               if ps.model_parallel_is_initialized() else 1)
-        pool_leaves = ("['cached_key']", "['cached_value']")
-        scale_leaves = ("['cached_key_scale']", "['cached_value_scale']")
+        pool_leaves = leaf_paths(KV_PAGE_LEAVES)
+        scale_leaves = leaf_paths(KV_SCALE_LEAVES)
         slab_itemsize = jnp.dtype(self.config.dtype).itemsize
         actual = actual_global = slab = 0
         for path, leaf in jax.tree_util.tree_flatten_with_path(
@@ -1260,7 +1281,8 @@ class CausalLM:
         if self.slot_rows:
             # counted apart from the pages: it follows max_batch, not tokens
             # (a window layer's rings under their own name)
-            out["window_bytes" if self.walk_sums > 3 else "state_bytes"] = sum(
+            out["window_bytes" if hasattr(self.config, "window_walk_sums")
+                else "state_bytes"] = sum(
                 int(np.prod(leaf.shape)) * leaf.dtype.itemsize
                 for _, leaf in self._slot_row_leaves(self._cache_avals()))
         return out

@@ -138,6 +138,7 @@ from neuronx_distributed_tpu.inference.paged_cache import (
     PagePoolExhausted,
 )
 from neuronx_distributed_tpu.inference.sampling import Sampler, SlotSampler
+from neuronx_distributed_tpu.models.llama import KV_PAGE_LEAVES, KV_SCALE_LEAVES, leaf_paths
 from neuronx_distributed_tpu.utils.compile_cache import PARTS as _COMPILE_PARTS, compile_log
 from neuronx_distributed_tpu.inference.schedq import (
     AdmissionQueue,
@@ -297,10 +298,7 @@ class _PrefillInFlight:
 # seam) move per page. int8 pools add the per-(page, head) fp32 scale
 # leaves; a page's bytes and its scales always travel (and garble, and
 # CRC) together.
-_KV_PAGE_LEAVES = (
-    "['cached_key']", "['cached_value']",
-    "['cached_key_scale']", "['cached_value_scale']",
-)
+_KV_PAGE_LEAVES = leaf_paths(KV_PAGE_LEAVES + KV_SCALE_LEAVES)
 
 
 def _page_payload(data: Dict[str, np.ndarray], path: str):
@@ -419,6 +417,13 @@ _STAT_KEYS = (
     # (min(reach, window) a live row a window layer-step). kv_walk_* above
     # count the layers that page, the full layers, alone
     "kv_window_slots_read", "kv_window_slots_needed",
+    # a model that scores its cached tokens and reads a chosen set
+    # (models/deepseek_v32.py), a live row a sparse layer-step each: the
+    # tokens visible to it, the tokens chosen for it (min(reach, index_topk))
+    # and the latent slots the step read for it and the other rows of its
+    # rung; selected / visible is the share the choice kept, read / selected
+    # 1.0 where a step reads the chosen alone
+    "dsa_tokens_visible", "dsa_tokens_selected", "dsa_latent_slots_read",
     # weight leaves the lm re-laid ONCE into the layout its one-token step
     # reads them in, and their bytes (CausalLM._hold; 0 where the backend's
     # compiler keeps the default, as the CPU's does): set when the engine is
@@ -3865,9 +3870,8 @@ class ServeEngine:
         self.stats["kv_walk_tokens"] += int(walked[0])
         self.stats["kv_walk_steps"] += int(walked[1])
         self.stats["kv_walk_row_slots"] += int(walked[2])
-        if len(walked) > 3:
-            self.stats["kv_window_slots_read"] += int(walked[3])
-            self.stats["kv_window_slots_needed"] += int(walked[4])
+        for name, value in zip(getattr(self.lm, "walk_sum_names", ()), walked[3:]):
+            self.stats[name] += int(value)
         if routing:
             self._count_routing(routing[0])
 

@@ -88,9 +88,14 @@ def leaf_partition_spec(path: str, shape, tp: int) -> PartitionSpec:
     """The serving spec for ONE leaf, keyed by its tree-path name (a
     ``jax.tree_util.keystr`` suffix or a bare ``['name']``). Replicated
     whenever the would-be sharded dim does not divide ``tp``."""
+    from neuronx_distributed_tpu.models.llama import (
+        KV_PAGE_LEAVES,
+        KV_SCALE_LEAVES,
+        leaf_paths,
+    )
+
     nd = len(shape)
-    if path.endswith(("['cached_key']", "['cached_value']",
-                      "['cached_key_scale']", "['cached_value_scale']")):
+    if path.endswith(leaf_paths(KV_PAGE_LEAVES + KV_SCALE_LEAVES)):
         # int8 pools carry per-(page, kv-head) fp32 scale leaves shaped
         # (.., npages, 1, n_kv, 1): the n_kv axis sits at -2 exactly like
         # the pools, so one rule shards pool and scales congruently — a

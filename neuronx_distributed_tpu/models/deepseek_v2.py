@@ -100,6 +100,12 @@ class DeepseekV2Config(MixtralConfig):
     # 1.0: none, and nothing is traced for it
     q_lora_scale: float = 1.0
     kv_lora_scale: float = 1.0
+    # the router beyond V2's (moe/routing.py): how an expert is scored, a bias
+    # on the scores for the choice, and what a group is scored by (V3's
+    # ``noaux_tc``: "sigmoid", True, "top2_sum"; models/deepseek_v32.py)
+    scoring_func: str = "softmax"
+    router_selection_bias: bool = False
+    group_score: str = "max"
 
     def __post_init__(self):
         scaling = self.rope_scaling
@@ -221,14 +227,15 @@ class DeepseekV2Attention(nn.Module):
                 return c * jnp.asarray(factor, c.dtype)
 
         with jax.named_scope("mla_q"):
-            q = jnp.einsum("bsr,rnd->bsnd",
-                           scaled(norm("q_a_norm")(x @ w_dq), cfg.q_lora_scale), w_uq)
+            c_q = scaled(norm("q_a_norm")(x @ w_dq), cfg.q_lora_scale)
+            q = jnp.einsum("bsr,rnd->bsnd", c_q, w_uq)
         with jax.named_scope("mla_kv_down"):
             down = x @ w_dkv
             c_kv = scaled(norm("kv_a_norm")(down[..., :rank]), cfg.kv_lora_scale)  # (b, s, rank)
             k_r = down[..., None, rank:]                               # (b, s, 1, rope)
+        index = self._index(x, c_q, w_uq, kernel)
         if cfg.decode:
-            o = self._cached(q, c_kv, k_r, w_uk, w_uv, kv, live)
+            o = self._cached(q, c_kv, k_r, w_uk, w_uv, kv, live, index)
         else:
             cos, sin = rope
             q = jnp.concatenate(
@@ -236,42 +243,79 @@ class DeepseekV2Attention(nn.Module):
             latent = jnp.concatenate([c_kv[:, :, None], apply_rotary(k_r, cos, sin)], axis=-1)
             s = x.shape[1]
             pos = jnp.arange(s, dtype=jnp.int32)
-            o = jnp.stack([self._expanded(q[i], latent[i, :, 0], pos, w_uk, w_uv)
-                           for i in range(x.shape[0])])
+            if index is None:
+                o = jnp.stack([self._expanded(q[i], latent[i, :, 0], pos, w_uk, w_uv)
+                               for i in range(x.shape[0])])
+            else:
+                o = self._chosen_prompt(
+                    q, latent[:, :, 0], jnp.broadcast_to(pos, x.shape[:2]),
+                    self._index_rotated(index, cos, sin), None, None, w_uk, w_uv)
         return RowParallelLinear(
             cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="o_proj",
         )(o.reshape(*x.shape[:2], n * vd))
+
+    # --- the seam of a model that scores the cached tokens and reads a chosen
+    # set of them (models/deepseek_v32.py fills it). Here nothing is scored:
+    # ``_index`` says None, every visible token is read and none of the
+    # ``index is not None`` branches below is traced.
+    def _index(self, x, c_q, w_uq, kernel):
+        """What this layer's indexer makes of the tokens ``x`` and their
+        normed query latents ``c_q`` (``kernel`` draws its projections), or
+        None without one."""
+        return None
 
     def _expanded(self, q, latent, positions, w_uk, w_uv):
         """ONE row in the expanded form: ``q`` (s, n, nope + rope) at
         ``positions`` (s,), ``latent`` (S, rank + rope) of cache slots 0..S-1;
         slot j is visible to a query at position p iff j <= p. Keys and values
         come up through ``w_uk`` and ``w_uv``; returns (s, n, v)."""
-        cfg = self.config
-        n, nope, vd = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
-        rank, rd = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-        from neuronx_distributed_tpu.kernels.flash_attn import flash_supported
+        return self._causal(q, *self._kv_up(latent, w_uk, w_uv), positions)
 
-        s, S = q.shape[0], latent.shape[0]
+    def _kv_up(self, latent, w_uk, w_uv, heads_first=False):
+        """Keys (S, n, nope + rope) and values (S, n, v) of ONE row's latents,
+        for the ``n`` heads ``w_uk`` and ``w_uv`` hold; ``heads_first``: (n, S,
+        ..), the way the kernel reads them, where the caller slices them by
+        slots besides (models/deepseek_v32.py: a transposed copy of both is
+        0.77 GB at 8192 slots and 128 heads)."""
+        cfg = self.config
+        n, rank, rd = w_uk.shape[1], cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        S = latent.shape[0]
         with jax.named_scope("mla_kv_up"):
+            if heads_first:
+                k = jnp.concatenate(
+                    [jnp.einsum("jr,rnd->njd", latent[:, :rank], w_uk),
+                     jnp.broadcast_to(latent[None, :, rank:], (n, S, rd))], axis=-1)
+                return k, jnp.einsum("jr,rnd->njd", latent[:, :rank], w_uv)
             k = jnp.concatenate(
                 [jnp.einsum("jr,rnd->jnd", latent[:, :rank], w_uk),
                  jnp.broadcast_to(latent[:, None, rank:], (S, n, rd))], axis=-1)
             v = jnp.einsum("jr,rnd->jnd", latent[:, :rank], w_uv)
+        return k, v
+
+    def _causal(self, q, k, v, positions, heads_first=False):
+        """Queries ``q`` (s, n, d) at ``positions`` over every key at or
+        before them: the flash kernel where it takes the shapes. ``k`` and
+        ``v`` as ``_kv_up`` gives them."""
+        cfg = self.config
+        nope, vd, rd = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.qk_rope_head_dim
+        from neuronx_distributed_tpu.kernels.flash_attn import flash_supported
+
+        s, S = q.shape[0], k.shape[1 if heads_first else 0]
         blk_q, blk_k = cfg.blocks_for(s, S)
         blk_q = min(blk_q, s)
         flash = cfg.use_flash_attention and s >= 128 and flash_supported(s, S, blk_q, blk_k)
         if flash:   # the kernel takes ONE head size: v is padded to q's and k's
             v = jnp.pad(v, ((0, 0), (0, 0), (0, nope + rd - vd)))
+        heads = (lambda a: a) if heads_first else (lambda a: a.transpose(1, 0, 2))
         with jax.named_scope("attend"):
             o = attention(
-                q.transpose(1, 0, 2)[None], k.transpose(1, 0, 2)[None],
-                v.transpose(1, 0, 2)[None], causal=False, sm_scale=cfg.softmax_scale,
+                q.transpose(1, 0, 2)[None], heads(k)[None], heads(v)[None], causal=False,
+                sm_scale=cfg.softmax_scale,
                 use_flash=flash, block_q=blk_q, block_k=blk_k, q_positions=positions[None])
         return o[0].transpose(1, 0, 2)[..., :vd]
 
-    def _cached(self, q, c_kv, k_r, w_uk, w_uv, kv, live=None):
+    def _cached(self, q, c_kv, k_r, w_uk, w_uv, kv, live=None, index=None):
         """The serving path: the new tokens' ``[c_kv | k_rope]`` go into the
         latent leaf at their slots (through the block table where paged), and
         the queries attend over what the leaf then holds."""
@@ -291,6 +335,8 @@ class DeepseekV2Attention(nn.Module):
                                     dtype=q.dtype, scaling=cfg.rope_scaling)
         q_rope = apply_rotary(q[..., nope:], cos, sin)
         latent = jnp.concatenate([c_kv[:, :, None], apply_rotary(k_r, cos, sin)], axis=-1)
+        if index is not None:
+            index = self._index_rotated(index, cos, sin)
         if ps:
             bt = self.variable("cache", "block_table",
                                lambda: jnp.zeros((b, S // ps), jnp.int32))
@@ -304,10 +350,14 @@ class DeepseekV2Attention(nn.Module):
                 flat = jnp.where(slots < S, phys * ps + slots % ps, npages * ps)
                 kv.put(LATENT_LEAF, pool.reshape(npages * ps, 1, dim).at[flat].set(
                     latent.astype(pool.dtype), mode="drop"))
+                at = lambda leaf: leaf.reshape(npages * ps, 1, leaf.shape[-1]).at[flat]  # noqa: E731
             else:
                 rows = kv.first_row(b) + jnp.arange(b)[:, None]
                 kv.put(LATENT_LEAF, pool.at[rows, slots].set(
                     latent.astype(pool.dtype), mode="drop"))
+                at = lambda leaf: leaf.at[rows, slots]  # noqa: E731
+            if index is not None:       # the second leaf, at the same slots
+                self._index_write(kv, index, at)
             idx_var.value = idx + s_new
         if s_new > 1:
             with jax.named_scope("kv_gather"):
@@ -317,6 +367,9 @@ class DeepseekV2Attention(nn.Module):
                 else:
                     slab = jax.lax.dynamic_slice_in_dim(pool, kv.first_row(b), b).reshape(b, S, dim)
             qx = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+            if index is not None:
+                return self._chosen_prompt(qx, slab, slots, index, live,
+                                           (kv, table if ps else None), w_uk, w_uv)
             if b == 1:
                 return self._expanded(qx[0], slab[0], slots[0], w_uk, w_uv)[None]
             return jax.lax.map(
@@ -330,28 +383,37 @@ class DeepseekV2Attention(nn.Module):
             q_all = jnp.concatenate([q_lat, q_rope.astype(jnp.float32)], axis=-1)
 
         walk = kv_walk(cfg, idx, None if live is None else live[:, 0])
-        o_lat = self._walk_attention(q_all, kv, walk, table if ps else None)
+        # without an indexer the call is what it always was (whoever stands in
+        # for ``_walk_attention`` takes the four arguments it always took)
+        asks = {} if index is None else {"index": index}
+        o_lat = self._walk_attention(q_all, kv, walk, table if ps else None, **asks)
         with jax.named_scope("mla_absorb"):
             return jnp.einsum("bsnr,rnd->bsnd", o_lat, w_uv, **_EXACT).astype(q.dtype)
 
-    def _walk_attention(self, q_all, kv, walk: KVWalk, table):
+    def _walk_attention(self, q_all, kv, walk: KVWalk, table, index=None):
         """``q_all`` (b, 1, n, latent_dim) float32, one new token a row in the
         latent space, over what ``walk`` reads of the latent cache (the
         prefix below its bound, of the rows in its rung); returns the
-        weighted latents ``(b, 1, n, kv_lora_rank)``."""
+        weighted latents ``(b, 1, n, kv_lora_rank)``. With an ``index`` (what
+        the rows' indexer asks, riding with the queries through the rung's
+        picks) a row reads, of what is visible, the tokens ``_chosen`` says."""
         cfg = self.config
         pool = kv.flat(LATENT_LEAF)
 
         def attend(top, count):
+            q, asks = (top.q, None) if index is None else top.q
             with jax.named_scope("kv_gather"):
                 slab = top.span(pool, 0, count).reshape(-1, count * walk.chunk, cfg.latent_dim)
+            chosen = None if asks is None else self._chosen(asks, kv, top, count)
             with jax.named_scope("attend"):
-                scores = jnp.einsum("bsnc,bjc->bnsj", top.q, slab, **_EXACT) * cfg.softmax_scale
+                scores = jnp.einsum("bsnc,bjc->bnsj", q, slab, **_EXACT) * cfg.softmax_scale
                 probs = jax.nn.softmax(jnp.where(
-                    top.visible(0, count)[:, None, None], scores, -1e30), axis=-1)
+                    (top.visible(0, count) if chosen is None else chosen)[:, None, None],
+                    scores, -1e30), axis=-1)
                 return jnp.einsum("bnsj,bjc->bsnc", probs, slab, **_EXACT)[..., :cfg.kv_lora_rank]
 
-        rows = walk.rows(q_all, table, kv.first_row(q_all.shape[0]))
+        rows = walk.rows(q_all if index is None else (q_all, index), table,
+                         kv.first_row(q_all.shape[0]))
         return attend(rows, 1) if walk.n_chunks == 1 else walk.prefix(rows, attend)
 
 
@@ -360,12 +422,13 @@ class DeepseekV2DenseLayer(nn.Module):
     ``intermediate_size``."""
 
     config: DeepseekV2Config
+    attention_cls = DeepseekV2Attention
 
     @nn.compact
     def __call__(self, x: jax.Array, rope, kv=None, live=None) -> jax.Array:
         cfg = self.config
         h = cfg.make_norm(name="input_norm")(x)
-        x = x + DeepseekV2Attention(cfg, name="attention")(h, rope, kv, live)
+        x = x + self.attention_cls(cfg, name="attention")(h, rope, kv, live)
         h = cfg.make_norm(name="post_attn_norm")(x)
         return x + LlamaMLP(cfg, name="mlp")(h)
 
@@ -375,13 +438,14 @@ class DeepseekV2MoELayer(nn.Module):
     plus the shared experts (one SwiGLU MLP, added once)."""
 
     config: DeepseekV2Config
+    attention_cls = DeepseekV2Attention
 
     @nn.compact
     def __call__(self, x: jax.Array, rope, kv=None, live=None,
                  stack=None) -> jax.Array:
         cfg = self.config
         h = cfg.make_norm(name="input_norm")(x)
-        x = x + DeepseekV2Attention(cfg, name="attention")(h, rope, kv=kv, live=live)
+        x = x + self.attention_cls(cfg, name="attention")(h, rope, kv=kv, live=live)
         h = cfg.make_norm(name="post_attn_norm")(x)
         moe_out = MoE(
             num_experts=cfg.num_experts,
@@ -403,6 +467,9 @@ class DeepseekV2MoELayer(nn.Module):
             n_group=cfg.n_group,
             topk_group=cfg.topk_group,
             route_scale=cfg.routed_scaling_factor,
+            scoring_func=cfg.scoring_func,
+            selection_bias=cfg.router_selection_bias,
+            group_score=cfg.group_score,
             name="moe",
         )(h, live, None if stack is None else (kv.layer - cfg.first_k_dense, stack))
         x = x + moe_out

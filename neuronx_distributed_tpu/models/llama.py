@@ -408,6 +408,25 @@ def _lora_pool_delta(mdl: nn.Module, cfg: LlamaConfig, name: str,
     return d * s[idx][:, None, None]
 
 
+# The names a cache leaf that holds TOKENS may take: axis 1 (under the stacked
+# layers) is the physical page where paged, the batch row in the slab. Whatever
+# moves, shards or counts a cache by pages finds its leaves by these (page IO
+# in inference/engine.py, inference/partition.py's specs,
+# ``CausalLM.kv_cache_bytes``), so a leaf a configuration declares under one of
+# them is carried by all of it: K and V heads, a latent (MLA) leaf under
+# ``cached_key``, and the index keys a learned-sparse attention scores beside
+# its latents (models/deepseek_v32.py). int8 pools bring per-(page, head)
+# scales as sibling leaves.
+INDEX_LEAF = "cached_index_key"
+KV_PAGE_LEAVES = ("cached_key", "cached_value", INDEX_LEAF)
+KV_SCALE_LEAVES = ("cached_key_scale", "cached_value_scale")
+
+
+def leaf_paths(names) -> Tuple[str, ...]:
+    """The names as ``jax.tree_util.keystr`` suffixes."""
+    return tuple(f"['{name}']" for name in names)
+
+
 def kv_leaf_shapes(cfg: LlamaConfig, batch: int) -> dict:
     """``{leaf name: (shape, dtype)}`` of ONE layer's KV storage in the
     ``cache`` collection: the paged pool ``(page_pool_pages, page_size, n_kv,
@@ -681,7 +700,9 @@ class WalkRows:
     """Rows of a one-token step as :class:`KVWalk` reads them (``KVWalk.rows``:
     all ``b`` as the batch holds them; ``top(r)``: the ``r`` of longest
     reach, and ``place``, where each row of the batch stands among the
-    sorted): their queries ``q``, their ``idx`` (``cache_index`` before the
+    sorted): their queries ``q`` (an array, or a tree of arrays with the rows
+    leading: what a step asks of a second leaf rides with them), their ``idx``
+    (``cache_index`` before the
     write) and where their cache lies: ``table`` (r, table_pages), or for the
     slab ``slab``, the first row's id where the rows stand as the slab holds
     them and the ids (r,) of picked rows."""
@@ -697,7 +718,8 @@ class WalkRows:
         pick = order[:r]
         table, slab = ((self.table[pick], None) if self.slab is None
                        else (None, self.slab + pick))
-        return WalkRows(self.walk, self.q[pick], self.idx[pick], table, slab, place)
+        return WalkRows(self.walk, jax.tree.map(lambda a: a[pick], self.q), self.idx[pick],
+                        table, slab, place)
 
     def attend(self, r: int, fn) -> jax.Array:
         """``fn(top) -> (r, ...)`` for the ``r`` rows of longest reach, as
@@ -1338,7 +1360,10 @@ class LlamaModel(nn.Module):
         if getattr(cfg, "first_k_dense", 0):
             # a one-token step's attention wants `live` (KVWalk); a prompt's
             # does not, and a dense block has no other use for it
-            dense = (rope, live if input_ids.shape[1] == 1 else None)
+            # (one that scores the cached tokens asks which of a prompt's
+            # queries are real: ``cfg.prompt_live``, models/deepseek_v32.py)
+            dense = (rope, live if input_ids.shape[1] == 1
+                     or getattr(cfg, "prompt_live", False) else None)
             while dense[-1] is None:
                 dense = dense[:-1]
             carry, _ = self.dense_layers(carry, *dense)

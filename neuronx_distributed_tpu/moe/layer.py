@@ -77,6 +77,8 @@ class MoE(nn.Module):
     zero_experts: int = 0
     # a bias on the scores for the CHOICE only (moe/routing.py)
     selection_bias: bool = False
+    # what a router group is scored by: "max" | "top2_sum" (moe/routing.py)
+    group_score: str = "max"
 
     @nn.compact
     def __call__(self, x: jax.Array, live: Optional[jax.Array] = None,
@@ -108,6 +110,8 @@ class MoE(nn.Module):
                                 **({} if self.scoring_func == "softmax"
                                    else {"scoring_func": self.scoring_func}),
                                 **({"selection_bias": True} if self.selection_bias else {}),
+                                **({} if self.group_score == "max"
+                                   else {"group_score": self.group_score}),
                                 name="router")
         elif self.router == "sinkhorn":
             router = RouterSinkhorn(routed, name="router")

@@ -50,6 +50,7 @@ class RouterTopK(nn.Module):
     route_scale: float = 1.0
     scoring_func: str = "softmax"      # | "sigmoid"
     selection_bias: bool = False
+    group_score: str = "max"           # | "top2_sum"
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -57,9 +58,13 @@ class RouterTopK(nn.Module):
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         if self.scoring_func not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring_func {self.scoring_func!r}: 'softmax' or 'sigmoid'")
-        if self.selection_bias and self.n_group > 1:
-            raise ValueError("selection_bias is not supported with n_group > 1: a group's "
-                             "score would have to choose between the score and score + bias")
+        if self.group_score not in ("max", "top2_sum"):
+            raise ValueError(f"group_score {self.group_score!r}: 'max' or 'top2_sum'")
+        biased_groups = self.selection_bias and self.n_group > 1
+        if biased_groups and self.group_score != "top2_sum":
+            raise ValueError("selection_bias with n_group > 1 needs group_score='top2_sum': "
+                             "a group scored by its max would have to choose between the "
+                             "score and score + bias")
         # router weight is replicated (the reference's LinearRouter with
         # weight-grad all-reduce, moe_parallel_layers.py:348)
         w = self.param("kernel", default_kernel_init, (x.shape[-1], self.num_experts),
@@ -68,10 +73,18 @@ class RouterTopK(nn.Module):
         probs = (jax.nn.sigmoid(logits) if self.scoring_func == "sigmoid"
                  else jax.nn.softmax(logits, axis=-1))
         eligible = probs
-        if self.n_group > 1:
+        if biased_groups:
+            with jax.named_scope("router_bias"):
+                choice = probs + self.param("e_score_correction_bias", nn.initializers.zeros,
+                                            (self.num_experts,), jnp.float32)
             with jax.named_scope("router_groups"):
-                eligible = probs * group_limit(probs, self.n_group, self.topk_group)
-        if self.selection_bias:
+                keep = group_limit(choice, self.n_group, self.topk_group, self.group_score)
+                eligible = jnp.where(keep > 0, choice, -jnp.inf)
+        elif self.n_group > 1:
+            with jax.named_scope("router_groups"):
+                eligible = probs * group_limit(probs, self.n_group, self.topk_group,
+                                               self.group_score)
+        elif self.selection_bias:
             with jax.named_scope("router_bias"):
                 eligible = probs + self.param("e_score_correction_bias", nn.initializers.zeros,
                                               (self.num_experts,), jnp.float32)
@@ -86,12 +99,18 @@ class RouterTopK(nn.Module):
         return gates, logits
 
 
-def group_limit(probs: jax.Array, n_group: int, topk_group: int) -> jax.Array:
+def group_limit(probs: jax.Array, n_group: int, topk_group: int,
+                score: str = "max") -> jax.Array:
     """``(T, E)`` mask, one over the experts of each token's ``topk_group``
-    best groups (a group's score: its largest probability; a tie goes to the
+    best groups (a group's score: its largest probability, or with
+    ``score="top2_sum"`` the sum of its two largest; a tie goes to the
     lower group, as ``lax.top_k`` breaks it) and zero over the rest."""
     T, E = probs.shape
-    best = jnp.max(probs.reshape(T, n_group, E // n_group), axis=-1)
+    grouped = probs.reshape(T, n_group, E // n_group)
+    if score == "top2_sum":
+        best = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    else:
+        best = jnp.max(grouped, axis=-1)
     _, groups = jax.lax.top_k(best, topk_group)
     keep = jnp.sum(jax.nn.one_hot(groups, n_group, dtype=probs.dtype), axis=-2)
     return jnp.repeat(keep, E // n_group, axis=-1)

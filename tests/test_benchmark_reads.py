@@ -293,6 +293,52 @@ def test_insert_routing_counter_is_produced(run, moe_run, key):
             == stats["moe_insert_experts_touched"] * tile)
 
 
+SPARSE_READS = ["dsa_tokens_visible", "dsa_tokens_selected", "dsa_latent_slots_read"]
+
+
+@pytest.fixture(scope="module")
+def sparse_run():
+    """A tiny DeepSeek-V3.2: prompts of 9 and 12 tokens under ``index_topk`` 8,
+    so every decode step of both rows chooses."""
+    from neuronx_distributed_tpu.models.deepseek_v32 import (
+        DeepseekV32Config,
+        DeepseekV32ForCausalLM,
+    )
+
+    cfg = DeepseekV32Config(**dict(
+        TINY, num_layers=3, num_kv_heads=4, kv_lora_rank=16, q_lora_rank=24,
+        qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8, first_k_dense=1,
+        moe_intermediate_size=32, router_experts=16, num_experts=4, n_group=4, topk_group=2,
+        top_k=4, index_topk=8, index_n_heads=2, index_head_dim=16))
+    rng = np.random.RandomState(3)
+    return _serve(cfg, DeepseekV32ForCausalLM,
+                  [rng.randint(1, 128, (n,)).astype(np.int32) for n in (9, 12)])
+
+
+@pytest.mark.parametrize("key", SPARSE_READS)
+def test_sparse_counter_is_produced(run, moe_run, sparse_run, key):
+    """Counted by the fused blocks of a model whose attention chooses what it
+    reads, under the names ``benchmark/layer_metrics/dsa.*.py`` read, and only
+    there: a live row a layer-step sees its tokens, is chosen ``index_topk`` of
+    them, and the step reads the rung's rows as far as the walk goes."""
+    stats = sparse_run.engine.stats
+    for other in (run, moe_run):
+        assert key in dict(other.engine.stats.items()) and other.engine.stats[key] == 0
+    layers, topk = 3, 8
+    # a row's BUDGET - 1 tokens after its first take two blocks, and the device
+    # keeps a row live to the end of its last block (the host drops the rest)
+    steps = -(-(BUDGET - 1) // BLOCK_STEPS) * BLOCK_STEPS
+    assert stats["dsa_tokens_selected"] == layers * topk * 2 * steps
+    # the row of prompt n sees n + 1, n + 2, .. tokens at its steps
+    assert stats["dsa_tokens_visible"] == layers * sum(
+        n + t for n in (9, 12) for t in range(1, steps + 1))
+    assert stats["dsa_latent_slots_read"] == layers * stats["kv_walk_row_slots"] > 0
+    readers = {name: (BENCHMARK / "layer_metrics" / f"dsa.{name}.py").read_text()
+               for name in ("selected_share", "latent_read_over_selected", "decode_step_mfu_share",
+                            "insert_mfu_share")}
+    assert any(f'"{key}"' in text for text in readers.values()), key
+
+
 @pytest.mark.parametrize("key", SSM_READS)
 def test_state_counter_is_produced(run, moe_run, hybrid_run, key):
     """Counted by the programs of a model with per-slot state, and only

@@ -82,6 +82,16 @@ SCMOE_SCOPES = {
     "moe/routing.py RouterTopK (selection_bias)": ["router_bias"],
 }
 
+# DeepSeek-V3.2's scopes (PR 56). ``scope_parts.json`` has no rows for them
+# (PERF.md section 7): inside the flax module ``attention`` they sort as
+# attention; the index key's write sits under ``kv_write`` and its read under
+# ``kv_gather``, so both sort with the latent's, and the read of the chosen is
+# the latent ``attend`` under its mask.
+SPARSE_SCOPES = {
+    "models/deepseek_v32.py DeepseekV32Attention": ["dsa_index", "dsa_scores", "dsa_select"],
+    "moe/routing.py RouterTopK (selection_bias, in groups)": ["router_bias", "router_groups"],
+}
+
 TINY = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2, num_heads=4,
             num_kv_heads=2, kv_size_multiplier=1, max_seq_len=256, dtype=jnp.float32,
             use_flash_attention=True, remat_policy=None)
@@ -330,6 +340,52 @@ def test_longcat_flash_names_the_branch_the_identity_sum_the_bias_and_the_scales
         under = [n for n in names if f"/{scope}/" in n]
         assert under and all(re.search(inside, n.split(f"{scope}/")[0]) for n in under), \
             (scope, under[:3])
+    assert unnamed_share(parts) < 0.2, parts
+
+
+@pytest.mark.parametrize("program", ["fused_decode", "paged_insert"])
+def test_deepseek_v32_names_the_indexer_the_scores_and_the_choice(program):
+    """The tiny DeepSeek-V3.2 decode block (a table of 512 slots: its longer
+    prefixes hold a choice) and paged insert carry the indexer's projections,
+    the index scores and the choice inside the attention module, the index
+    key's write under ``kv_write`` and its read under ``kv_gather``, the bias
+    and the groups of V3's route, and the latent scopes DeepSeek-V2's carry."""
+    from neuronx_distributed_tpu.models.deepseek_v32 import (
+        DeepseekV32Config,
+        DeepseekV32ForCausalLM,
+    )
+
+    cfg = DeepseekV32Config(**dict(
+        TINY, num_layers=3, num_kv_heads=4, kv_lora_rank=16, q_lora_rank=24,
+        qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8, first_k_dense=1,
+        moe_intermediate_size=32, router_experts=16, num_experts=4, n_group=4, topk_group=2,
+        top_k=4, index_topk=32, index_n_heads=2, index_head_dim=16, index_block_q=64,
+        max_seq_len=512, use_flash_attention=False))
+    weights = meta.unbox(DeepseekV32ForCausalLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    lm = CausalLM(cfg, weights, DeepseekV32ForCausalLM, buckets=(128,), max_batch=2, page_size=16)
+    decode = program == "fused_decode"
+    compiled = (lm.compile_session_decode_fused(4) if decode
+                else lm._paged_insert_programs(2, 128))
+    components, parts = census(compiled)
+    want = {n for names in SPARSE_SCOPES.values() for n in names}
+    want |= {"mla_q", "mla_kv_down", "kv_write", "kv_gather", "attend", "shared_expert",
+             "grouped_matmul", "mla_absorb" if decode else "mla_kv_up"}
+    assert want <= components, sorted(want - components)
+    assert "dense_layers" in components and "layers" in components
+    for part in ("kv_write", "kv_gather", "attend", "attention", "router", "experts", "ffn", "norm"):
+        assert parts[part] > 0, part
+    names = [m.group(1) for m in map(OP_NAME.search, compiled.as_text().splitlines()) if m]
+    for scope in SPARSE_SCOPES["models/deepseek_v32.py DeepseekV32Attention"]:
+        under = [n for n in names if f"/{scope}/" in n]
+        # inside the attention module; a few ops of a ``switch`` branch carry a
+        # stack that starts over at the branch (``cond/branch_1_fun/dsa_select/..``)
+        assert under and all("/attention/" in n.split(f"{scope}/")[0]
+                             or n.startswith("cond/branch_") for n in under), scope
+        assert any("/attention/" in n.split(f"{scope}/")[0] for n in under), scope
+    # both leaves are written under kv_write and read under kv_gather
+    assert any("/kv_write/attention._index_write/" in n for n in names)
+    assert any("/kv_gather/" in n and "attention._chosen" in n for n in names)
     assert unnamed_share(parts) < 0.2, parts
 
 

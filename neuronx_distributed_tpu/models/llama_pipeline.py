@@ -301,9 +301,10 @@ class PipelinedLlama:
         mesh = ps.get_mesh()
         specs = self.param_specs(sample_ids)
         shardings = specs_to_shardings(specs, mesh)
-        params = jax.jit(
-            lambda: self.init(jax.random.key(seed), sample_ids), out_shardings=shardings
-        )()
+        # the key and the ids are ARGUMENTS of the weights' program, as in
+        # ``initialize_parallel_model``: one program for every seed
+        params = jax.jit(self.init, out_shardings=shardings)(
+            jax.random.key(seed), sample_ids)
 
         outer = self
 

@@ -14,6 +14,7 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 from flax.core import meta
 
@@ -137,6 +138,18 @@ def initialize_parallel_model(
     mixed-precision / activation-checkpoint config overrides to the model
     config and injects LoRA adapters when ``lora_config`` is set (reference
     phases 4+6).
+
+    What varies from run to run reaches the weights' program as ARGUMENTS:
+    the keys of ``rngs`` and every array leaf (``jax.Array``,
+    ``numpy.ndarray``) of the example arguments. A key closed over is a
+    literal of the lowered program, so every seed was a program the
+    persistent compile cache had never seen; as an argument, one cache entry
+    serves every seed (the key's kind is part of its type: two kinds are two
+    programs; an example the initialisers only take shapes from is pruned by
+    ``jit``, so its shape is in the program only where the weights' shapes
+    follow it). Anything else among the example arguments (a flag, ``None``,
+    a Python number) decides the trace and stays in the closure. The same
+    seed draws the same weights, bit for bit, as the closed-over form did.
     """
     if not ps.model_parallel_is_initialized():
         ps.initialize_model_parallel(
@@ -152,20 +165,30 @@ def initialize_parallel_model(
 
     from neuronx_distributed_tpu.parallel.partitioning import specs_to_shardings
 
-    def init_fn():
-        variables = module.init(rngs, *example_args, **example_kwargs)
-        return meta.unbox(variables)["params"]
+    leaves, treedef = jax.tree_util.tree_flatten((example_args, example_kwargs))
+    places = [i for i, leaf in enumerate(leaves) if isinstance(leaf, (jax.Array, np.ndarray))]
+    arrays = [leaves[i] for i in places]
+
+    def boxed_init(rngs, arrays):
+        given = list(leaves)
+        for i, array in zip(places, arrays):
+            given[i] = array
+        args, kwargs = treedef.unflatten(given)
+        return module.init(rngs, *args, **kwargs)
+
+    def init_fn(rngs, arrays):
+        return meta.unbox(boxed_init(rngs, arrays))["params"]
 
     # the compile log's row of the weights' program: the abstract pass, the
     # program's stages, and (the rest of its wall) the call's dispatch
     with compile_log.program("init_params", group="weights"):
         # Abstract-eval once to learn shapes + partition metadata without FLOPs.
         with compile_log.stretch("abstract_ms"):
-            abstract = jax.eval_shape(
-                lambda: module.init(rngs, *example_args, **example_kwargs))
+            abstract = jax.eval_shape(boxed_init, rngs, arrays)
         specs = nn.get_partition_spec(abstract)["params"]
         shardings = specs_to_shardings(specs, mesh)
-        params = compile_log.staged(jax.jit(init_fn, out_shardings=shardings))()
+        params = compile_log.staged(
+            jax.jit(init_fn, out_shardings=shardings), rngs, arrays)(rngs, arrays)
 
     lora_cfg = nxd_config.get("lora_config")
     lora_params = lora_specs = None

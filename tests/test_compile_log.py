@@ -174,8 +174,9 @@ def test_without_a_cache_neither_event_fires_and_the_stretch_is_the_backends():
 
 def test_init_params_row_says_what_the_processs_events_say(cache_dir):
     """The weights' program: its abstract pass, its three stages, and a hit or
-    a miss as JAX's own events count it (what a NEW seed gives on the chip is
-    PERF.md's to say; the same seed again is a hit)."""
+    a miss as JAX's own events count it. The key is an ARGUMENT of the program
+    (ISSUE 57), so a NEW seed asks the cache for the program the first seed
+    wrote and is a hit, as the same seed again is."""
     nxd = neuronx_distributed_config(tensor_parallel_size=1)
     ids = jnp.ones((1, 8), jnp.int32)
 
@@ -204,7 +205,10 @@ def test_init_params_row_says_what_the_processs_events_say(cache_dir):
 
     cold, params = build(0)
     assert cold["misses"] == 1
-    build(1)                                  # hit or miss: held to the events inside ``build``
+    other, drawn = build(1)                   # a new seed loads the first one's program
+    assert (other["hits"], other["misses"], other["xla_compile_ms"]) == (1, 0, 0.0)
+    assert not all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(
+        jax.tree_util.tree_leaves(params), jax.tree_util.tree_leaves(drawn)))
     jax.clear_caches()
     again, same = build(0)
     assert again["hits"] == 1

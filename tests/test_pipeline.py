@@ -647,3 +647,39 @@ def test_interleaved_1f1b_train_step_pp4_tp2():
     l0 = float(metrics["loss"])
     state, metrics = step(state, {"ids": ids, "labels": labels}, jax.random.key(1))
     assert np.isfinite(l0) and float(metrics["loss"]) < l0
+
+
+def test_the_pipelined_weights_program_is_one_text_for_every_seed(monkeypatch):
+    """``as_parallel_model`` hands the key and the ids to its weights' program
+    as arguments (ISSUE 57, as ``initialize_parallel_model`` does): two seeds
+    lower to one text, and a seed draws what the closed-over form drew."""
+    from neuronx_distributed_tpu.models import llama_pipeline
+    from neuronx_distributed_tpu.models.llama_pipeline import PipelinedLlama
+
+    texts, jit = [], jax.jit
+
+    def recording(fn, **kw):
+        jitted = jit(fn, **kw)
+
+        def call(*args):
+            texts.append(jitted.lower(*args).as_text())
+            return jitted(*args)
+        return call
+
+    ps.initialize_model_parallel(tensor_model_parallel_size=2, pipeline_model_parallel_size=2)
+    pm = PipelinedLlama(_tiny_cfg(), num_stages=2, num_microbatches=2)
+    ids = np.random.RandomState(0).randint(0, 127, (4, 16)).astype(np.int32)
+    monkeypatch.setattr(llama_pipeline.jax, "jit", recording)
+    first = pm.as_parallel_model(ids, seed=1)
+    second = pm.as_parallel_model(ids + 1, seed=2)
+    monkeypatch.undo()
+    assert len(texts) == 2 and texts[0] == texts[1]
+    oracle = jax.jit(lambda: pm.init(jax.random.key(1), ids),
+                     out_shardings=first.param_shardings())()
+    got, want = jax.tree.leaves(first.params), jax.tree.leaves(oracle)
+    assert len(got) == len(want) > 4
+    for a, b in zip(got, want):
+        assert a.sharding == b.sharding
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert not all(np.array_equal(np.asarray(a), np.asarray(b))
+                   for a, b in zip(got, jax.tree.leaves(second.params)))

@@ -281,3 +281,113 @@ def test_grad_accum_matches_full_batch():
     worst = max(jax.tree.leaves(jax.tree.map(
         lambda a, b_: float(jnp.max(jnp.abs(a - b_))), s_acc.params, s_full.params)))
     assert worst < 1e-5, f"params diverged after one update: {worst}"
+
+
+# ---------------------------------------------- the weights' program (ISSUE 57)
+# What varies from run to run reaches the weights' program as ARGUMENTS: the
+# keys and the array leaves of the example arguments. One lowered text for
+# every seed is one entry of the persistent compile cache for every seed.
+
+_TINY_LLAMA = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2,
+                   num_heads=4, num_kv_heads=2, kv_size_multiplier=1, max_seq_len=64,
+                   dtype=jnp.float32, use_flash_attention=False, remat_policy=None)
+
+
+def _tiny_llama():
+    from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+    return LlamaForCausalLM(LlamaConfig(**_TINY_LLAMA))
+
+
+class FollowsItsExample(nn.Module):
+    """Parameter shapes that follow the example's width and two example
+    keywords that are no arrays: ``wide`` and ``times`` decide the trace
+    (a tracer in their place raises), ``bias`` is ``None`` or an array."""
+
+    @nn.compact
+    def __call__(self, x, *, wide=False, times=1, bias=None):
+        h = ColumnParallelLinear(features=(32 if wide else 8) * times, name="up")(x)
+        h = nn.gelu(h if bias is None else h + bias)
+        return RowParallelLinear(features=x.shape[-1], name="down")(h)
+
+
+@pytest.fixture
+def weights_texts(monkeypatch):
+    """The lowered text of every weights' program built in the test."""
+    from neuronx_distributed_tpu.utils.compile_cache import compile_log
+
+    texts, staged = [], compile_log.staged
+
+    def recording(jitted, *args):
+        texts.append(jitted.lower(*args).as_text())
+        return staged(jitted, *args)
+
+    monkeypatch.setattr(compile_log, "staged", recording)
+    return texts
+
+
+def _ids(fill, shape=(1, 8)):
+    return np.full(shape, fill, np.int32)
+
+
+@pytest.mark.parametrize("what, one, other, same", [
+    ("two seeds", dict(seed=1), dict(seed=2), True),
+    ("two seeds of the chip's generator", dict(seed=1, impl="rbg"),
+     dict(seed=2147489203, impl="rbg"), True),
+    ("two contents of the example ids", dict(ids=_ids(0)), dict(ids=_ids(7)), True),
+    ("a numpy and a jax example", dict(ids=_ids(3)), dict(ids=jnp.asarray(_ids(5))), True),
+    ("two kinds of key", dict(impl="threefry2x32"), dict(impl="rbg"), False),
+    ("two example widths that the weights follow",
+     dict(module=FollowsItsExample, ids=np.zeros((2, 4, 8), np.float32)),
+     dict(module=FollowsItsExample, ids=np.zeros((2, 4, 16), np.float32)), False),
+], ids=lambda v: v.replace(" ", "_") if isinstance(v, str) else "")
+def test_the_weights_program_is_one_text_for_what_varies_from_run_to_run(
+        weights_texts, what, one, other, same):
+    def build(seed=0, impl="threefry2x32", ids=_ids(0), module=_tiny_llama):
+        nxd = neuronx_distributed_config(tensor_parallel_size=1)
+        initialize_parallel_model(nxd, module, ids,
+                                  rngs={"params": jax.random.key(seed, impl=impl)})
+        ps.destroy_model_parallel()
+
+    build(**one)
+    build(**other)
+    first, second = weights_texts
+    assert (first == second) == same, what
+    # the key is the program's one argument: an initialiser reads shapes, never
+    # values, so jit prunes the example (and the forward is dead code)
+    for text in weights_texts:
+        taken = text[text.index("@main("):].split(") ->")[0]
+        assert "%arg0" in taken and "%arg1" not in taken
+
+
+@pytest.mark.parametrize("impl", ["threefry2x32", "rbg"])
+@pytest.mark.parametrize("tp, case", [(1, "llama"), (2, "llama"), (1, "keywords"), (4, "keywords")])
+def test_the_weights_drawn_are_the_closed_over_forms_bit_for_bit(impl, tp, case):
+    """The oracle is the form the parent compiled: keys and example arguments
+    closed over, ``jax.jit(lambda: module.init(rngs, ids))``, a program a seed."""
+    from flax.core import meta
+
+    rngs = {"params": jax.random.key(2147489203, impl=impl)}
+    if case == "llama":
+        module, args, kwargs = _tiny_llama(), (_ids(5, (2, 8)),), {}
+    else:
+        module, args = FollowsItsExample(), (np.ones((2, 4, 8), np.float32),)
+        kwargs = dict(wide=True, times=2, bias=jnp.full((64,), 0.5))
+    nxd = neuronx_distributed_config(tensor_parallel_size=tp)
+    model = initialize_parallel_model(nxd, lambda: module, *args, rngs=rngs, **kwargs)
+    oracle = jax.jit(lambda: meta.unbox(module.init(rngs, *args, **kwargs))["params"],
+                     out_shardings=model.param_shardings())()
+    got, want = jax.tree.leaves(model.params), jax.tree.leaves(oracle)
+    assert len(got) == len(want) > 2
+    for a, b in zip(got, want):
+        assert a.sharding == b.sharding and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if tp > 1:                                # born on its shard, as before
+        assert any("tp" in str(leaf.sharding.spec) for leaf in got)
+    if case == "keywords":
+        assert model.params["up"]["kernel"].shape == (8, 64)
+    # another seed draws other weights through the same form
+    again = initialize_parallel_model(
+        nxd, lambda: module, *args, rngs={"params": jax.random.key(1, impl=impl)}, **kwargs)
+    assert not all(np.array_equal(np.asarray(a), np.asarray(b))
+                   for a, b in zip(got, jax.tree.leaves(again.params)))

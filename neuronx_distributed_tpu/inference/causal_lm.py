@@ -191,6 +191,7 @@ WALK_SUMS = {
     "window_walk_sums": ("kv_window_slots_read", "kv_window_slots_needed"),
     "sparse_walk_sums": ("dsa_tokens_visible", "dsa_tokens_selected",
                          "dsa_latent_slots_read"),
+    "stream_walk_sums": ("mhc_mix_steps",),
 }
 
 
@@ -205,7 +206,9 @@ def _walk_sums(config, cache: PyTree, live: jax.Array) -> jax.Array:
     (``config.window_walk_sums``; ``models/laguna.py``) adds two, the ring
     slots those layers read and the tokens they needed; one that reads a
     chosen set of its tokens (``config.sparse_walk_sums``;
-    ``models/deepseek_v32.py``) three, the tokens visible, chosen and read
+    ``models/deepseek_v32.py``) three, the tokens visible, chosen and read;
+    one that carries several residual streams (``config.stream_walk_sums``;
+    ``models/xing4.py``) one, its live rows times its stream mixes
     (``WALK_SUMS``)."""
     idx = next(leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
                if jax.tree_util.keystr(path).endswith("['cache_index']"))[0]
@@ -330,6 +333,9 @@ class DecodeSession:
     slot_keys: Optional[jax.Array] = None
     # the last insert's sampled first tokens, (rows,) int32, on the device
     first_tokens: Optional[jax.Array] = None
+    # what the last insert program ran over, host numbers: the rows' real
+    # tokens (a prefix hit's suffix only) and its token slots, rows x bucket
+    insert_ran: Tuple[int, int] = (0, 0)
 
 
 class CausalLM:
@@ -1752,6 +1758,7 @@ class CausalLM:
                 self.params, session.cache, session.slot_keys, first, ids,
                 np.asarray(tables, np.int32), slot_ids, starts, new_len, *ad)
             self._keep_insert_sums(session, sums)
+            session.insert_ran = (int(lengths.sum()), rows * bucket)
             session.lengths[slot_ids] = new_len
             return logits
         prog = self._chunk_extend_programs(rows, bucket)
@@ -1831,6 +1838,7 @@ class CausalLM:
                 pkv.rollback(p)
             raise
         self._keep_insert_sums(session, sums)
+        session.insert_ran = (int(suffix.sum()), rows * bucket)
         with pkv.span("cache_commit", rows=rows):
             for i in range(rows):
                 pkv.commit(int(slot_ids[i]), plans[i],
@@ -1898,6 +1906,7 @@ class CausalLM:
             *self._ad_args(session.adapters,
                            adapter_slots if adapter_slots is not None
                            else np.zeros((rows,), np.int32)))
+        session.insert_ran = (int(lengths.sum()), rows * bucket)
         session.lengths[slot_ids] = lengths
         session.active[slot_ids] = True
         return logits

@@ -424,6 +424,13 @@ _STAT_KEYS = (
     # rung; selected / visible is the share the choice kept, read / selected
     # 1.0 where a step reads the chosen alone
     "dsa_tokens_visible", "dsa_tokens_selected", "dsa_latent_slots_read",
+    # a model that carries several residual streams (models/xing4.py), times
+    # the stream mixes a token passes (config.stream_mixes): the real tokens
+    # its insert programs mixed, the token slots those programs ran over
+    # (rows x bucket, padding included; both host numbers, no fetch), and the
+    # live rows of its decode steps (with the block's walk sums); tokens /
+    # slots is the share of the n-wide stream traffic spent on real tokens
+    "mhc_mix_tokens", "mhc_mix_slots", "mhc_mix_steps",
     # weight leaves the lm re-laid ONCE into the layout its one-token step
     # reads them in, and their bytes (CausalLM._hold; 0 where the backend's
     # compiler keeps the default, as the CPU's does): set when the engine is
@@ -903,6 +910,7 @@ class ServeEngine:
                     self._injector.on_grammar_acquire
         # legacy counter surface, now a registry-backed view (see _StatsView)
         self.stats = _StatsView(self.metrics, _STAT_KEYS)
+        self._stream_mixes = int(getattr(lm.config, "stream_mixes", 0))
         for key in ("param_relaid_leaves", "param_relaid_bytes"):
             self.stats[key] = getattr(lm, key, 0)
         for key, value in _startup_stats().items():
@@ -1231,6 +1239,16 @@ class ServeEngine:
             self.stats["ssm_scan_tokens"] += int(scanned[0])
             self.stats["ssm_scan_positions"] += int(scanned[1])
         return first
+
+    def _count_insert_program(self) -> None:
+        """One compiled call that admitted requests (an insert, a chunk's
+        extend) was dispatched; for a model with several residual streams,
+        what it ran over (``session.insert_ran``: host numbers)."""
+        self.stats["insert_program_calls"] += 1
+        if self._stream_mixes and not self._sim:
+            tokens, slots = self.session.insert_ran
+            self.stats["mhc_mix_tokens"] += self._stream_mixes * tokens
+            self.stats["mhc_mix_slots"] += self._stream_mixes * slots
 
     def _free_slots(self) -> List[int]:
         return [i for i, r in enumerate(self.slots) if r is None]
@@ -2080,7 +2098,7 @@ class ServeEngine:
             self._note_tier_restore(group, tier_before)
             self.stats["inserts"] += 1
             self.stats["inserted_requests"] += rows
-            self.stats["insert_program_calls"] += 1
+            self._count_insert_program()
             self.stats["insert_fetches_deferred"] += int(defer)
             self.stats["inserts_overlapped"] += int(bool(self._first_pending))
             # a deferred insert's outputs stay on the device: fetching them
@@ -2230,7 +2248,7 @@ class ServeEngine:
                 tables=tables, adapter_slots=aslots,
                 first=self._first_inputs([req])))
             self.stats["chunk_program_calls"] += 1
-            self.stats["insert_program_calls"] += 1
+            self._count_insert_program()
             self.stats["prefill_chunk_tokens_done"] += n
             st.written += n
             budget -= n
@@ -2461,7 +2479,7 @@ class ServeEngine:
                     adapter_slots=(np.asarray([aslot], np.int32)
                                    if self.lora else None),
                     first=self._first_inputs([req])))
-                self.stats["insert_program_calls"] += 1
+                self._count_insert_program()
                 written += n
         except BaseException:
             # atomic unwind: every page hold released, device table reset —

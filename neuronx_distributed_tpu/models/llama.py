@@ -30,6 +30,7 @@ import numpy as np
 from flax import linen as nn
 
 from neuronx_distributed_tpu.ops.attention import attention
+from neuronx_distributed_tpu.ops.stream_mix import mhc_expand, mhc_reduce
 from neuronx_distributed_tpu.parallel.layers import (
     ColumnParallelLinear,
     GQAQKVColumnParallelLinear,
@@ -1356,6 +1357,12 @@ class LlamaModel(nn.Module):
         args = (rope, live, stack)
         while args[-1] is None:     # dense models, training: (rope,) as ever
             args = args[:-1]
+        # a config with ``hc_mult`` carries that many residual streams, each (b,
+        # s, hidden), from here to the final norm (models/xing4.py); the scans
+        # and ``_LayerStep`` carry whatever they are given
+        streams = getattr(cfg, "hc_mult", None)
+        if streams:
+            x = mhc_expand(x, streams)
         carry = (x, kv)
         if getattr(cfg, "first_k_dense", 0):
             # a one-token step's attention wants `live` (KVWalk); a prompt's
@@ -1370,6 +1377,8 @@ class LlamaModel(nn.Module):
         (x, kv), _ = self.layers(carry, *args)
         for name, pool in pools.items():
             pool.value = kv[1][name]
+        if streams:
+            x = mhc_reduce(x)
         return self.final_norm(x)
 
     def layer_stack(self):

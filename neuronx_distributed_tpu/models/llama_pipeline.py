@@ -89,6 +89,10 @@ class PipelinedLlama:
             )
         if cfg.tie_word_embeddings:
             raise NotImplementedError("tied embeddings with PP: use the non-PP model")
+        if getattr(cfg, "hc_mult", None):
+            raise ValueError(
+                f"hc_mult = {cfg.hc_mult} residual streams are not carried through a pipeline: "
+                "a stage hands on ONE (batch, seq, hidden) state and runs LlamaDecoderLayer")
         self._layer = LlamaDecoderLayer(cfg)
         # gradient="matmul": the embedding backward runs INSIDE the pipeline's
         # partial-manual shard_map (1F1B stage 0), where XLA's partitioner

@@ -336,6 +336,10 @@ class DecodeSession:
     # what the last insert program ran over, host numbers: the rows' real
     # tokens (a prefix hit's suffix only) and its token slots, rows x bucket
     insert_ran: Tuple[int, int] = (0, 0)
+    # whether every row of that program started at position 0 (no prefix hit,
+    # no earlier chunk): what latent attention's prompt form asks inside the
+    # program (models/deepseek_v2.py), said here from the host's own starts
+    insert_fresh: bool = True
 
 
 class CausalLM:
@@ -1745,6 +1749,7 @@ class CausalLM:
         ad = self._ad_args(session.adapters,
                            adapter_slots if adapter_slots is not None
                            else np.zeros((rows,), np.int32))
+        session.insert_fresh = not starts.any()
         if self.paged:
             if session.paged is None:
                 raise ValueError("paged CausalLM needs a session from "
@@ -1839,6 +1844,7 @@ class CausalLM:
             raise
         self._keep_insert_sums(session, sums)
         session.insert_ran = (int(suffix.sum()), rows * bucket)
+        session.insert_fresh = not starts.any()
         with pkv.span("cache_commit", rows=rows):
             for i in range(rows):
                 pkv.commit(int(slot_ids[i]), plans[i],
@@ -1907,6 +1913,7 @@ class CausalLM:
                            adapter_slots if adapter_slots is not None
                            else np.zeros((rows,), np.int32)))
         session.insert_ran = (int(lengths.sum()), rows * bucket)
+        session.insert_fresh = True
         session.lengths[slot_ids] = lengths
         session.active[slot_ids] = True
         return logits

@@ -392,6 +392,11 @@ _STAT_KEYS = (
     # one of each, so (calls + fetches) / inserts reads 2.0 where every
     # admission was a one-shot insert and more where one went by chunks
     "insert_program_calls", "insert_host_fetches",
+    # of insert_program_calls, those whose rows all started at position 0 (no
+    # prefix hit, no earlier chunk): fresh / calls is how often latent
+    # attention's prompt form reads the call's own tokens and no slab
+    # (models/deepseek_v2.py); 1.0 on unshared one-shot prompts
+    "insert_programs_fresh",
     # inserts whose first tokens were left on the device at dispatch because
     # nothing on it waited for them (_insert_group), inserts dispatched while
     # an earlier one was still unfetched (the host planned and dispatched
@@ -1245,7 +1250,10 @@ class ServeEngine:
         extend) was dispatched; for a model with several residual streams,
         what it ran over (``session.insert_ran``: host numbers)."""
         self.stats["insert_program_calls"] += 1
-        if self._stream_mixes and not self._sim:
+        if self._sim:
+            return
+        self.stats["insert_programs_fresh"] += int(self.session.insert_fresh)
+        if self._stream_mixes:
             tokens, slots = self.session.insert_ran
             self.stats["mhc_mix_tokens"] += self._stream_mixes * tokens
             self.stats["mhc_mix_slots"] += self._stream_mixes * slots

@@ -15,11 +15,17 @@ What is this file's and what is the stack's:
   name ``cached_key`` so that page IO, partition specs and byte counts find it
   as they find every model's pages (``models/llama.py::kv_leaf_shapes``).
   A prompt (more than one new token) attends in the expanded form: keys and
-  values of every cached position come up through ``k_b_proj`` and
-  ``v_b_proj`` (the published ``kv_b_proj``, kept as its two halves) and through
-  the flash kernel, one row at a time (a row's expanded keys and values at
-  4096 positions and 128 heads are 0.33 GB). A decode step attends in the
-  ABSORBED form: ``q_nope`` is taken through ``W_uk`` into the latent space,
+  values come up through ``k_b_proj`` and ``v_b_proj`` (the published
+  ``kv_b_proj``, kept as its two halves) and through the flash kernel. How
+  far a row reads is decided inside the program by the call's
+  ``cache_index``: where every row starts at 0 (an insert without a prefix
+  hit) a query can see the call's own tokens alone, so slots ``0..s_new-1``
+  are expanded and swept, the rows as the kernel's batch in one call where its
+  arrays stay under ``PROMPT_CALL_BYTES`` (``_one_call``) and a row a call
+  where not; where any row continues (a prefix hit, a chunk) all
+  ``max_seq_len`` slots are, one row at a time (a row's expanded keys and
+  values at 4096 positions and 128 heads are 0.33 GB). A decode step attends
+  in the ABSORBED form: ``q_nope`` is taken through ``W_uk`` into the latent space,
   scores and values are einsums over the gathered latent pages (of the live
   rows, as far as the longest of them reaches: ``models/llama.py::KVWalk``), and
   ``W_uv`` brings the result back; keys and values of cached tokens are never
@@ -45,6 +51,7 @@ latent pages (refused), LoRA on the latent projections.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections.abc import Mapping
 from typing import Any, Optional
 
@@ -69,6 +76,10 @@ from neuronx_distributed_tpu.parallel.layers import RMSNorm, RowParallelLinear
 from neuronx_distributed_tpu.parallel.mesh import TP_AXIS
 
 LATENT_LEAF = "cached_key"      # the one cache leaf: [c_kv | k_rope] a token
+# what ONE call of a fresh prompt's attention may hold (``_one_call``): what
+# one row of 2048 tokens at 64 heads does, so that prompts that long go a row
+# a call as they always did, and 8 x 512 tokens at 32 heads go whole
+PROMPT_CALL_BYTES = 256 * 2 ** 20
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,6 +189,29 @@ def deepseek_v2(**over) -> DeepseekV2Config:
 _EXACT = dict(preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _prompt_rows(cls, cfg, continues, w_uk, w_uv, q, slab, slots):
+    """Some rows of a prompt over their slabs: ``q`` (rows, s_new, n, nope +
+    rope) at ``slots`` (rows, s_new), ``slab`` (rows, S, rank + rope); returns
+    (rows, s_new, n, v). How far a row reads is the CALL's (``continues``, a
+    traced bool): where every row of the call starts at 0 (an insert without a
+    prefix hit) a query can see the call's own tokens alone, slots
+    0..s_new-1, and the rows are the kernel's batch; where any continues (a
+    prefix hit, a chunk) each reads its S slots, one row a call. A function of
+    the attention's class (``cls``: its ``_kv_up`` and ``_causal`` are what
+    runs), the config and its arguments, jitted: the layer scans of a program,
+    and the programs of a bucket, trace and lower one body for a shape."""
+    expanded = functools.partial(cls(cfg)._expanded, w_uk=w_uk, w_uv=w_uv)
+    s_new = q.shape[1]
+
+    def continued():
+        if q.shape[0] == 1:
+            return expanded(q[0], slab[0], slots[0])[None]
+        return jax.lax.map(lambda row: expanded(*row), (q, slab, slots))
+
+    return jax.lax.cond(continues, continued, lambda: expanded(q, slab[:, :s_new], slots))
+
+
 class DeepseekV2Attention(nn.Module):
     config: DeepseekV2Config
 
@@ -269,8 +303,18 @@ class DeepseekV2Attention(nn.Module):
         """ONE row in the expanded form: ``q`` (s, n, nope + rope) at
         ``positions`` (s,), ``latent`` (S, rank + rope) of cache slots 0..S-1;
         slot j is visible to a query at position p iff j <= p. Keys and values
-        come up through ``w_uk`` and ``w_uv``; returns (s, n, v)."""
+        come up through ``w_uk`` and ``w_uv``; returns (s, n, v). With a
+        leading axis of rows on all three, the rows go through ONE call."""
         return self._causal(q, *self._kv_up(latent, w_uk, w_uv), positions)
+
+    def _one_call(self, b: int, s: int) -> bool:
+        """Whether a fresh prompt of ``b`` rows x ``s`` tokens attends in ONE
+        call: its queries, keys, values and outputs as the kernel takes them
+        (every head of ``head_dim_``) stay under ``PROMPT_CALL_BYTES``. Else a
+        row a call, as a prompt always went."""
+        cfg = self.config
+        a_row = 4 * s * cfg.num_heads * cfg.head_dim_ * jnp.dtype(cfg.dtype).itemsize
+        return b == 1 or b * a_row <= PROMPT_CALL_BYTES
 
     def _kv_up(self, latent, w_uk, w_uv, heads_first=False):
         """Keys (S, n, nope + rope) and values (S, n, v) of ONE row's latents,
@@ -280,7 +324,7 @@ class DeepseekV2Attention(nn.Module):
         0.77 GB at 8192 slots and 128 heads)."""
         cfg = self.config
         n, rank, rd = w_uk.shape[1], cfg.kv_lora_rank, cfg.qk_rope_head_dim
-        S = latent.shape[0]
+        rows, S = latent.shape[:-2], latent.shape[-2]    # rows: none, or a fresh prompt's
         with jax.named_scope("mla_kv_up"):
             if heads_first:
                 k = jnp.concatenate(
@@ -288,32 +332,34 @@ class DeepseekV2Attention(nn.Module):
                      jnp.broadcast_to(latent[None, :, rank:], (n, S, rd))], axis=-1)
                 return k, jnp.einsum("jr,rnd->njd", latent[:, :rank], w_uv)
             k = jnp.concatenate(
-                [jnp.einsum("jr,rnd->jnd", latent[:, :rank], w_uk),
-                 jnp.broadcast_to(latent[:, None, rank:], (S, n, rd))], axis=-1)
-            v = jnp.einsum("jr,rnd->jnd", latent[:, :rank], w_uv)
+                [jnp.einsum("...jr,rnd->...jnd", latent[..., :rank], w_uk),
+                 jnp.broadcast_to(latent[..., None, rank:], (*rows, S, n, rd))], axis=-1)
+            v = jnp.einsum("...jr,rnd->...jnd", latent[..., :rank], w_uv)
         return k, v
 
     def _causal(self, q, k, v, positions, heads_first=False):
         """Queries ``q`` (s, n, d) at ``positions`` over every key at or
         before them: the flash kernel where it takes the shapes. ``k`` and
-        ``v`` as ``_kv_up`` gives them."""
+        ``v`` as ``_kv_up`` gives them; with a leading axis of rows on all
+        four, the rows are the kernel's batch."""
         cfg = self.config
         nope, vd, rd = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.qk_rope_head_dim
         from neuronx_distributed_tpu.kernels.flash_attn import flash_supported
 
-        s, S = q.shape[0], k.shape[1 if heads_first else 0]
+        s, S = q.shape[-3], k.shape[-2 if heads_first else -3]
         blk_q, blk_k = cfg.blocks_for(s, S)
         blk_q = min(blk_q, s)
         flash = cfg.use_flash_attention and s >= 128 and flash_supported(s, S, blk_q, blk_k)
         if flash:   # the kernel takes ONE head size: v is padded to q's and k's
-            v = jnp.pad(v, ((0, 0), (0, 0), (0, nope + rd - vd)))
-        heads = (lambda a: a) if heads_first else (lambda a: a.transpose(1, 0, 2))
+            v = jnp.pad(v, ((0, 0),) * (v.ndim - 1) + ((0, nope + rd - vd),))
+        heads = (lambda a: a) if heads_first else (lambda a: a.swapaxes(-3, -2))
+        batch = (lambda a: a) if q.ndim == 4 else (lambda a: a[None])
         with jax.named_scope("attend"):
             o = attention(
-                q.transpose(1, 0, 2)[None], heads(k)[None], heads(v)[None], causal=False,
+                batch(q.swapaxes(-3, -2)), batch(heads(k)), batch(heads(v)), causal=False,
                 sm_scale=cfg.softmax_scale,
-                use_flash=flash, block_q=blk_q, block_k=blk_k, q_positions=positions[None])
-        return o[0].transpose(1, 0, 2)[..., :vd]
+                use_flash=flash, block_q=blk_q, block_k=blk_k, q_positions=batch(positions))
+        return (o if q.ndim == 4 else o[0]).swapaxes(-3, -2)[..., :vd]
 
     def _cached(self, q, c_kv, k_r, w_uk, w_uv, kv, live=None, index=None):
         """The serving path: the new tokens' ``[c_kv | k_rope]`` go into the
@@ -370,11 +416,14 @@ class DeepseekV2Attention(nn.Module):
             if index is not None:
                 return self._chosen_prompt(qx, slab, slots, index, live,
                                            (kv, table if ps else None), w_uk, w_uv)
-            if b == 1:
-                return self._expanded(qx[0], slab[0], slots[0], w_uk, w_uv)[None]
-            return jax.lax.map(
-                lambda row: self._expanded(*row, w_uk, w_uv),
-                (qx, slab, slots))
+            attend = functools.partial(
+                _prompt_rows, type(self), cfg, jnp.any(idx > 0), w_uk, w_uv)
+            if self._one_call(b, s_new):
+                return attend(qx, slab, slots)
+            # a row a call, as a prompt always went; the call's choice is each row's
+            out = jax.lax.map(lambda row: attend(*row), tuple(
+                a.reshape(b, 1, *a.shape[1:]) for a in (qx, slab, slots)))
+            return out.reshape(b, *out.shape[2:])
         # one new token a row: absorbed. q_nope goes INTO the latent space
         # (W_uk), the scores and the values are taken over the latent slab,
         # and W_uv brings the result out: no cached key or value is expanded.

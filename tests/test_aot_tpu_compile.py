@@ -429,7 +429,7 @@ def _described_lm(chip, family, monkeypatch, buckets=(128,), rehearse=None, laye
             vocab_size=256, hidden_size=32 * HEAD_DIM, intermediate_size=1024,
             num_heads=32, num_kv_heads=8, num_layers=2, max_seq_len=4096,
             dtype=jnp.bfloat16, param_dtype=jnp.bfloat16), LlamaForCausalLM
-    elif family in ("deepseek", "laguna", "longcat"):
+    elif family in ("deepseek", "laguna", "longcat", "xing"):
         # the benchmark's configuration: DeepSeek-V2's and LongCat-Flash's
         # rehearsal ones, Laguna's as ``laguna-s-2.1.longctx`` runs it
         # (9 layers, 32 experts held, 8192 slots)
@@ -440,13 +440,14 @@ def _described_lm(chip, family, monkeypatch, buckets=(128,), rehearse=None, laye
 
         root = Path(harness.__file__).resolve().parents[1]
         name = {"deepseek": "deepseek-v2", "laguna": "laguna-s-2.1",
-                "longcat": "longcat-flash-chat"}[family]
+                "longcat": "longcat-flash-chat", "xing": "xing4.0-29b-a4b"}[family]
         entry = next(c for c in json.loads((root / "BENCHMARK.json").read_text())["configs"]
                      if c["name"] == name)
         loaded = harness.load_config(
             entry, rehearse=family != "laguna" if rehearse is None else rehearse)
-        cfg = serving.model_config(loaded, False, remat_policy=None,
-                                   max_seq_len=8192 if family == "laguna" else 4096)
+        cfg = serving.model_config(
+            loaded, False, remat_policy=None,
+            max_seq_len={"laguna": 8192, "xing": 1024}.get(family, 4096))
         if layers:
             cfg = dataclasses.replace(cfg, num_layers=layers)
         cls = serving.load(loaded["builder"]["model"])
@@ -664,6 +665,66 @@ def test_a_share_holding_insert_writes_no_array_of_every_pick(chip, monkeypatch,
     kernels = {op["shape"] for op in ops if op["kind"] == "custom-call"}
     assert kernels == {f"bf16[{bound},{inter}]", f"bf16[{bound},{hidden}]"}
     assert any("/while/body/" in op["op_name"] for op in ops)       # the passes' loop
+
+
+@pytest.mark.parametrize("family,rows,bucket,slots,sizes,parents", [
+    ("xing", 8, 512, 1024, (32, 192), 372_436_992),
+    ("longcat", 8, 2048, 4096, (64, 192), 3_390_457_344)],
+    ids=["xing_8x512", "longcat_8x2048"])
+def test_a_fresh_latent_insert_sweeps_its_own_tokens(chip, monkeypatch, family, rows, bucket,
+                                                     slots, sizes, parents):
+    """The 8 x 512 insert of ``xing4.0-29b-a4b.score`` and the 8 x 2048 of
+    ``longcat-flash-chat.longctx`` at the cells' sizes, for the described v5e.
+    Latent attention's prompt form is a ``conditional``: where every row of the
+    call starts at 0, the keys and values of the rows' OWN ``bucket`` slots go
+    through the flash kernel (all 8 of Xing's rows in one call, LongCat's a
+    row a call: ``_one_call``), and nothing that branch expands reaches a
+    row's keys at ``max_seq_len``; where a row continues, the other branch, one
+    row a call over every slot as before. The pool stays out of the rows' loop
+    and of both branches (inside, the compiler copied the stacked leaf whole,
+    289 MiB a layer in LongCat's listing). The program's temporaries stay
+    within 128 MiB of ``parents``, what the listing of the tree before the
+    branch read (commit 0107ef1: Xing's rows in one call hold 120 MiB more,
+    LongCat's 0.4), and the whole program within 14.5 GiB: the largest insert
+    of any latent cell (LongCat's r7, 13.9) and the 0.6 GiB the chip has to
+    spare beside it (ROADMAP S18)."""
+    from neuronx_distributed_tpu.models.deepseek_v2 import DeepseekV2Attention
+
+    lm = _described_lm(chip, family, monkeypatch, buckets=(bucket,), rehearse=False)
+    cfg = lm.config
+    assert (cfg.max_seq_len, cfg.num_heads, cfg.head_dim_) == (slots, *sizes)
+    group = rows if DeepseekV2Attention(cfg)._one_call(rows, bucket) else 1
+    assert group == (rows if family == "xing" else 1)
+    compiled = lm._paged_insert_programs(rows, bucket)
+    text = compiled.as_text()
+    calls = {"branch_0_fun": set(), "branch_1_fun": set()}      # fresh (false), continued
+    for line in text.splitlines():
+        if "flash_fwd/pallas_call" in line and "custom_call_target" in line:
+            branch = re.findall(r"jit\(_prompt_rows\)/cond/(branch_\d_fun)/", line)[-1]
+            calls[branch].add(
+                re.search(r"operand_layout_constraints=\{(.*?)\}, front", line).group(1))
+    n, d = sizes
+
+    def operands(g, keys):
+        q, k = f"bf16[{g * n},{bucket},{d}]{{2,1,0}}", f"bf16[{g * n},{keys},{d}]{{2,1,0}}"
+        return ", ".join([q, k, k, f"s32[{g},1,{bucket}]{{2,1,0}}", f"s32[{g},1,{keys}]{{2,1,0}}"])
+
+    assert calls["branch_0_fun"] == {operands(group, bucket)}
+    assert calls["branch_1_fun"] == {operands(1, slots)}
+    up = [op for op in big_ops(text, 2 ** 20)
+          if "cond/branch_0_fun/" in op["op_name"] and "mla_kv_up" in op["op_name"]]
+    assert up and max(op["bytes"] for op in up) <= group * n * bucket * d * 2, \
+        [(op["op"], op["shape"]) for op in up]
+    leaf = next(leaf for path, leaf in jax.tree_util.tree_flatten_with_path(lm._cache_avals())[0]
+                if "cached_key" in jax.tree_util.keystr(path))
+    assert not _leaf_copies(text, leaf.shape)
+    memory = compiled.memory_analysis()
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    print(f"{family} {rows} x {bucket} insert: temporaries {memory.temp_size_in_bytes} bytes, "
+          f"{total} in all")
+    assert memory.temp_size_in_bytes <= parents + 128 * 2 ** 20
+    assert total <= 14.5 * 2 ** 30
 
 
 def test_lagunas_decode_block_sorts_one_tile_and_loops_over_no_pass(chip, monkeypatch):

@@ -16,6 +16,7 @@ bf16 is over 500 times past the tolerance (``test_a_lower_precision_fails``).
 """
 
 import dataclasses
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -289,6 +290,143 @@ def test_serve_engine_gives_solo_generates_tokens_and_hits_a_latent_prefix(param
     # list a call (``moe/expert_mlps.py::row_bound``)
     assert stats["moe_insert_passes"] == stats["moe_insert_layer_calls"] > 0
     assert 0 < stats["moe_insert_assignments"] < stats["moe_insert_rows"]
+
+
+# --------------------------------------- a fresh insert reads its own tokens
+
+def a_row_a_call(monkeypatch):
+    """No two rows of a fresh insert fit one call: they go a row a call."""
+    monkeypatch.setattr(sys.modules[DeepseekV2Attention.__module__], "PROMPT_CALL_BYTES", 0)
+
+
+def prompt_block(cache, rows):
+    """One attention block in decode mode over a latent leaf it owns (256 slots
+    a row, paged 16 a page or slab-backed), 128 new tokens a row through the
+    interpreted flash kernel: ``run(x, starts) -> (out, leaf)``."""
+    paged = cache == "paged"
+    cfg = dataclasses.replace(
+        DeepseekV2Config(**TINY), decode=True, num_layers=1, max_seq_len=256,
+        use_flash_attention=True,
+        **(dict(page_size=16, page_pool_pages=16 * rows + 1) if paged else {}))
+    attn = DeepseekV2Attention(cfg)
+    shape = (1, 16 * rows + 1, 16, 1, cfg.latent_dim) if paged else (1, rows, 256, 1, cfg.latent_dim)
+    leaves = {"cached_key": jnp.zeros(shape, jnp.float32)}
+    x = jax.random.normal(jax.random.key(3), (rows, 128, cfg.hidden_size), jnp.float32)
+    init = attn.init(jax.random.key(0), x, None, kv=KVLayerView(jnp.int32(0), leaves))
+    weights = meta.unbox(init["params"])
+
+    @jax.jit
+    def run(x, starts):
+        b = x.shape[0]
+        cache = {"cache_index": starts}
+        if paged:       # row i holds pages 1 + 16 i .. 16 + 16 i; page 0 is nobody's
+            cache["block_table"] = 1 + jnp.arange(16 * b, dtype=jnp.int32).reshape(b, 16)
+        view = KVLayerView(jnp.int32(0), leaves)
+        with jax.default_matmul_precision("highest"):
+            out, _ = attn.apply({"params": weights, "cache": cache}, x, None, kv=view,
+                                mutable=["cache"])
+        return out, view.leaves["cached_key"]
+
+    return x, run
+
+
+@pytest.mark.parametrize("cache", ["paged", "slab"])
+@pytest.mark.parametrize("rows,call", [(1, "one"), (3, "one"), (4, "one"),
+                                       (3, "a_row"), (4, "a_row")])
+def test_a_fresh_insert_equals_the_slab_form_on_the_same_cache(cache, rows, call, monkeypatch):
+    """``rows`` prompts that start at 0 attend over their own 128 tokens, all
+    through one flash call or a row a call; the same rows beside ONE row that
+    continues at slot 7 all read their 256-slot slabs back. Same outputs, same
+    leaf."""
+    if call == "a_row":
+        a_row_a_call(monkeypatch)
+    x, run = prompt_block(cache, rows + 1)
+    fresh, left_fresh = run(x[:rows], jnp.zeros((rows,), jnp.int32))
+    slab, left_slab = run(x, jnp.zeros((rows + 1,), jnp.int32).at[rows].set(7))
+    np.testing.assert_allclose(fresh, slab[:rows], rtol=0, atol=2e-6)
+    written = (slice(None), slice(1, 1 + 16 * rows)) if cache == "paged" else (
+        slice(None), slice(0, rows))
+    np.testing.assert_allclose(left_fresh[written], left_slab[written], rtol=0, atol=0)
+    assert float(jnp.abs(left_fresh[written]).max()) > 0
+
+
+@pytest.mark.parametrize("cache", ["paged", "slab"])
+def test_a_fresh_insert_a_row_a_call_equals_the_reference(params, want, cache, monkeypatch):
+    """Three prompts of 18, 12 and 15 tokens in a bucket of 32 (the padding
+    written and attended like tokens, its outputs never read), a row a call
+    (the rule itself takes all three in one at these widths:
+    ``test_insert_and_decode_through_the_latent_cache_equal_the_reference``):
+    first-token logits inside the tolerance."""
+    world()
+    a_row_a_call(monkeypatch)
+    with jax.default_matmul_precision("highest"):
+        lm = serving_lm(params, page_size=8 if cache == "paged" else None).compile()
+        session = lm.start_session()
+        got = np.asarray(lm.insert(session, np.arange(3), IDS, lengths=LENS,
+                                   **(dict(reserve_tokens=1) if lm.paged else {})))
+    assert session.insert_fresh
+    assert distance(got, want[np.arange(3), LENS - 1]) <= TOL
+
+
+@pytest.mark.parametrize("heads,rows,tokens,one", [
+    (32, 8, 512, True), (32, 1, 128, True),          # xing4.0-29b-a4b: .score, .chat
+    (64, 8, 2048, False), (64, 5, 2048, False),      # longcat-flash-chat.longctx
+    (128, 8, 2048, False), (128, 1, 2048, True)],    # deepseek-v2.longctx
+    ids=["xing_8x512", "xing_1x128", "longcat_8x2048", "longcat_5x2048",
+         "deepseek_8x2048", "deepseek_1x2048"])
+def test_the_rows_of_a_fresh_insert_go_whole_where_the_calls_arrays_fit(heads, rows, tokens, one):
+    """The rule at the latent cells' shapes (bf16, heads of 192): Xing's
+    inserts are one flash call, the long-context cells' 2048-token rows a row
+    a call as they always went, and one row is one call whatever its size."""
+    cfg = dataclasses.replace(DeepseekV2Config(**TINY), num_heads=heads, num_kv_heads=heads,
+                              qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                              dtype=jnp.bfloat16)
+    assert cfg.head_dim_ == 192
+    assert DeepseekV2Attention(cfg)._one_call(rows, tokens) is one
+
+
+def test_an_insert_with_one_row_past_zero_reads_the_slab_and_equals_the_reference(params, want):
+    """Two prompts in ONE insert, the first continuing a 16-token prefix that an
+    earlier request left in the pool: the call is not fresh, every row reads its
+    slab, and both rows' logits are the reference's."""
+    world()
+    with jax.default_matmul_precision("highest"):
+        lm = serving_lm(params).compile()
+        session = lm.start_session()
+        lm.insert(session, np.asarray([0]), IDS[:1, :18], lengths=np.asarray([18]),
+                  reserve_tokens=1)
+        assert session.insert_fresh
+        lens = np.asarray([22, 15])
+        got = np.asarray(lm.insert(session, np.asarray([1, 2]), IDS[:2, :22], lengths=lens,
+                                   reserve_tokens=1))
+    assert session.paged.stats["prefix_hits"] == 1 and not session.insert_fresh
+    assert session.insert_ran == (22 - 16 + 15, 2 * 32)
+    assert distance(got, want[np.arange(2), lens - 1]) <= TOL
+
+
+@pytest.mark.parametrize("cache", ["paged", "slab"])
+def test_a_chunked_extend_after_a_fresh_insert_equals_the_one_shot_prefill(params, want, cache):
+    """Ten tokens of two prompts inserted (fresh), the rest extended as one
+    chunk that attends over what the insert left: logits at each row's last
+    token are the whole prompt's."""
+    world()
+    lens, first = np.asarray([22, 17]), 10
+    with jax.default_matmul_precision("highest"):
+        lm = serving_lm(params, page_size=8 if cache == "paged" else None).compile()
+        session = lm.start_session()
+        slots = np.arange(2)
+        lm.insert(session, slots, IDS[:2, :first], lengths=np.full((2,), first),
+                  **(dict(reserve_tokens=16) if lm.paged else {}))
+        assert session.insert_fresh
+        chunk = np.zeros((2, 12), np.int32)
+        for i, n in enumerate(lens):
+            chunk[i, : n - first] = IDS[i, first:n]
+        tables = ({"tables": np.stack([session.paged.tables[i] for i in slots])}
+                  if lm.paged else {})
+        got = np.asarray(lm.extend(session, slots, chunk, lens - first,
+                                   np.full((2,), first), **tables))
+    assert not session.insert_fresh
+    assert distance(got, want[np.arange(2), lens - 1]) <= TOL
 
 
 # ------------------------------------------------------------------ the router

@@ -29,7 +29,7 @@ from flax.core import meta
 from benchmark.reference import deepseek_v32 as reference
 from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
 from neuronx_distributed_tpu.inference.partition import leaf_partition_spec
-from neuronx_distributed_tpu.models import deepseek_v32
+from neuronx_distributed_tpu.models import deepseek_v2, deepseek_v32
 from neuronx_distributed_tpu.models.deepseek_v2 import DeepseekV2Attention, DeepseekV2Config
 from neuronx_distributed_tpu.models.deepseek_v32 import (
     DeepseekV32Attention,
@@ -332,28 +332,44 @@ def test_the_prompts_form_equals_the_absorbed_form():
     assert float(jnp.abs(left_whole[INDEX_LEAF][0, :, :16]).min()) > 0      # written, every slot
 
 
-def test_a_choice_of_everything_is_deepseek_v2_bit_for_bit():
+def test_a_choice_of_everything_is_deepseek_v2_bit_for_bit(monkeypatch):
     """``index_topk`` at or over the context: the module gives, on the same
     five MLA matrices, what ``DeepseekV2Attention`` gives, bit for bit, in the
-    prompt's form and in the one-token step's; and it still writes its keys."""
+    prompt's form over the slab (the branch a continuing row takes, held to
+    here on a fresh prompt) and in the one-token step's; a fresh prompt's own
+    branch adds up its 12 slots where this module adds the slab's 64, all but
+    12 of them masked, and agrees to the order of a sum; and the module still
+    writes its keys."""
     cfg = DeepseekV32Config(**dict(TINY, index_topk=64))
     x = jax.random.normal(jax.random.key(3), (2, 16, cfg.hidden_size), jnp.float32)
     got, leaves, weights = _one_layer(DeepseekV32Attention, cfg, x, steps=4)
     v2 = DeepseekV2Config(**{k: v for k, v in TINY.items() if not k.startswith("index_")})
     attn = DeepseekV2Attention(dataclasses.replace(v2, decode=True, num_layers=1))
     mla = {k: v for k, v in weights["params"].items() if not k.startswith("index_")}
-    empty = {"cached_key": jnp.zeros((1, 2, 64, 1, v2.latent_dim), jnp.float32)}
-    view = KVLayerView(jnp.int32(0), empty)
-    with jax.default_matmul_precision("highest"):
-        want, mut = attn.apply({"params": mla}, x[:, :12], None, kv=view, mutable=["cache"])
-        outs = [want]
-        for t in range(12, 16):
-            out, mut = attn.apply({"params": mla, "cache": mut["cache"]}, x[:, t: t + 1], None,
-                                  kv=view, mutable=["cache"])
-            outs.append(out)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(jnp.concatenate(outs, axis=1)))
-    np.testing.assert_array_equal(np.asarray(leaves["cached_key"]),
-                                  np.asarray(view.leaves["cached_key"]))
+
+    def v2s():
+        empty = {"cached_key": jnp.zeros((1, 2, 64, 1, v2.latent_dim), jnp.float32)}
+        view = KVLayerView(jnp.int32(0), empty)
+        with jax.default_matmul_precision("highest"):
+            out, mut = attn.apply({"params": mla}, x[:, :12], None, kv=view, mutable=["cache"])
+            outs = [out]
+            for t in range(12, 16):
+                out, mut = attn.apply({"params": mla, "cache": mut["cache"]}, x[:, t: t + 1],
+                                      None, kv=view, mutable=["cache"])
+                outs.append(out)
+        return jnp.concatenate(outs, axis=1), view.leaves["cached_key"]
+
+    fresh, left = v2s()
+    np.testing.assert_allclose(np.asarray(got[:, :12]), np.asarray(fresh[:, :12]),
+                               rtol=0, atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(got[:, 12:]), np.asarray(fresh[:, 12:]))
+    np.testing.assert_array_equal(np.asarray(leaves["cached_key"]), np.asarray(left))
+    prompt_rows = deepseek_v2._prompt_rows
+    monkeypatch.setattr(deepseek_v2, "_prompt_rows", lambda cls, cfg, continues, *rest: prompt_rows(
+        cls, cfg, jnp.bool_(True), *rest))
+    over_the_slab, left = v2s()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(over_the_slab))
+    np.testing.assert_array_equal(np.asarray(leaves["cached_key"]), np.asarray(left))
     assert float(jnp.abs(leaves[INDEX_LEAF][0, :, :16]).min()) > 0
 
 

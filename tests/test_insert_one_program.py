@@ -193,6 +193,36 @@ def test_an_insert_is_one_program_call_and_one_fetch(cache, rows, model, grammar
         assert stats["moe_insert_layer_calls"] == 2 * stats["inserts"]
 
 
+def test_insert_programs_fresh_counts_the_calls_whose_rows_all_start_at_zero():
+    """Beside ``insert_program_calls``: equal to it on prompts that share
+    nothing, smaller by the inserts that a prefix hit started past 0 and by
+    the later chunks of a chunked prompt; in ``stats`` from construction."""
+    lm = _lm("dense", paged=True)
+    engine = ServeEngine(lm, block_steps=2, rng=jax.random.key(3))
+    assert engine.stats["insert_programs_fresh"] == 0
+
+    def admit(prompts):
+        for p in prompts:
+            engine.submit(p, max_new_tokens=1)
+            while engine.step_block():
+                pass
+        return engine.stats["insert_program_calls"], engine.stats["insert_programs_fresh"]
+
+    alone = _prompts(3, seed=41, lo=9)
+    assert admit(alone) == (3, 3)
+    hits = engine.session.paged.stats["prefix_hits"]
+    tail = np.arange(1, 6, dtype=np.int32)
+    sharers = [np.concatenate([p[:2 * PAGE], tail]) for p in alone[:2]]
+    assert admit(sharers + _prompts(1, seed=43)) == (6, 4)
+    assert engine.session.paged.stats["prefix_hits"] - hits == 2
+    chunked = ServeEngine(lm, block_steps=2, rng=jax.random.key(3), prefill_chunk_tokens=5)
+    chunked.submit(_prompts(1, seed=47, lo=12, hi=12)[0], max_new_tokens=1)
+    while chunked.step_block():
+        pass
+    stats = chunked.stats
+    assert (stats["insert_program_calls"], stats["insert_programs_fresh"]) == (3, 1)
+
+
 # ------------------------------------------------------------------ the values
 
 def _eager_first_tokens(lm, logits, rng, rids, temps, greedy, allowed=None):

@@ -8,8 +8,15 @@ sharding, and pipeline test below runs the real code path with real
 (simulated) devices and no mocks.
 
 This file must set the env vars before jax is imported anywhere.
+
+A new architecture's test file copies no scaffold: ``tests/tiny.py`` holds the
+world, the seeded weights (``make_params``, one compiled program), the full
+forward, the serving ``CausalLM``, teacher-forced logits through the cache and
+the one process-wide memo (``built``). ``pytest_xdist_make_scheduler`` below
+keeps a file's cases on one worker, so what a file builds it builds once.
 """
 
+import collections
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -204,6 +211,29 @@ _PR_58_MOVED = {
 }
 
 
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    """A file's cases run on ONE worker, in file order, whatever ``--dist``
+    says (the driver's command says ``load``; xdist's own answer is ``trylast``).
+    Every memo and every module-scoped fixture in ``tests/`` is per process
+    (``tests/tiny.py::built``, 71 fixtures in 34 files), so under ``load`` each
+    of the six workers a file's cases were dealt to built the file's weights
+    and programs again: the same 279 cases of six files summed 1,372 s dealt by
+    case and 632 s dealt by file, and the whole run compiled 20,566 programs
+    dealt by case and 14,766 dealt by file (PR 60). ``optionalhook``:
+    ``-p no:xdist`` still collects."""
+    from xdist.scheduler import LoadFileScheduling
+
+    config.option.loadscopereorder = False      # the order is ``pytest_collection_modifyitems``'s
+    return LoadFileScheduling(config, log)
+
+
+# These hold ONE worker for minutes (PR 60's table: 536, 288 and 279 s of a run of
+# 890) and have few cases, so xdist's own order of files, most cases first, starts
+# them late and the run ends with one worker alone: they start first.
+_START_FIRST = ("test_aot_tpu_compile.py", "test_examples.py", "test_kv_carry_values.py")
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         if item.name == _PINS_THE_LAST_SEVEN:
@@ -215,6 +245,8 @@ def pytest_collection_modifyitems(items):
             if item.nodeid.endswith(tail):
                 item.add_marker(pytest.mark.xfail(
                     strict=True, reason=f"{why} (tests/conftest.py says why)"))
+    cases = collections.Counter(item.path for item in items)
+    items.sort(key=lambda item: (item.path.name not in _START_FIRST, -cases[item.path]))
 
 
 @pytest.fixture(autouse=True)

@@ -40,6 +40,7 @@ from neuronx_distributed_tpu.kernels.flash_attn import (
 from neuronx_distributed_tpu.kernels.ssm_step import ssm_step
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.optimizer.fused_kernel import fused_adamw_leaf
+from tests import tiny
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "scripts"))
 from big_ops import big_ops  # noqa: E402 — the listing tool's reader of a compiled text
@@ -580,19 +581,18 @@ def test_the_weights_are_held_the_way_the_decode_block_reads_them(chip, family, 
             assert copied <= was
 
 
-_LAGUNA_BLOCK = {}     # the compiled block, once for the cases below
-
-
 def _laguna_block(chip, monkeypatch):
-    if not _LAGUNA_BLOCK:
+    """What the cases below read of the compiled block, once a process."""
+    def make():
         lm = _described_lm(chip, "laguna", monkeypatch).compile()
         compiled = lm.compile_session_decode_fused(8, SlotSampler(), 0)
-        _LAGUNA_BLOCK.update(
+        return dict(
             text=compiled.as_text(), temp=compiled.memory_analysis().temp_size_in_bytes,
             leaf=next(leaf.shape for path, leaf in
                       jax.tree_util.tree_flatten_with_path(lm._cache_avals())[0]
                       if jax.tree_util.keystr(path).endswith("['window_key']")))
-    return _LAGUNA_BLOCK
+
+    return tiny.built("aot_laguna_block", make)
 
 
 @pytest.mark.parametrize("rung", [1, 2, 4, 8])
@@ -630,8 +630,8 @@ def test_laguna_window_step_moves_its_rows_of_one_ring(chip, monkeypatch, rung):
     assert max(op["bytes"] for op in under) <= b * row
     assert max((op["bytes"] for op in under if op["shape"].startswith("bf16")),
                default=0) <= rung * row
-    print(f"laguna fused block: temporaries {_LAGUNA_BLOCK['temp'] / 2 ** 20:.1f} MiB")
-    assert _LAGUNA_BLOCK["temp"] < 571 * 2 ** 20  # the parent's 571.6 MiB
+    print(f"laguna fused block: temporaries {block['temp'] / 2 ** 20:.1f} MiB")
+    assert block["temp"] < 571 * 2 ** 20  # the parent's 571.6 MiB
 
 
 @pytest.mark.parametrize("family,bucket,sizes", [

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import (
     CausalLM,
@@ -41,6 +40,7 @@ from neuronx_distributed_tpu.observability.attribution import (
     known_request_ids,
     request_attribution,
 )
+from tests import tiny
 
 TINY = dict(
     vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2,
@@ -55,9 +55,7 @@ SMALL_POOL = 13
 @pytest.fixture(scope="module")
 def lm():
     cfg = LlamaConfig(**TINY)
-    ids = jnp.zeros((1, 8), jnp.int32)
-    params = meta.unbox(
-        LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     return CausalLM(cfg, params, LlamaForCausalLM, buckets=(8, 16),
                     max_batch=3, page_size=PAGE,
                     page_pool_pages=SMALL_POOL).compile()

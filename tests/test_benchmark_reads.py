@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
 from neuronx_distributed_tpu.inference.engine import Rejected
@@ -41,6 +40,7 @@ from neuronx_distributed_tpu.trainer import (
     make_train_step,
     neuronx_distributed_config,
 )
+from tests import tiny
 
 BENCHMARK = Path(__file__).resolve().parent.parent / "benchmark"
 TINY = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2, num_heads=4,
@@ -54,8 +54,7 @@ def _serve(cfg, model_cls, prompts, prefix_cache=True):
     prefix cache on (off for a model with per-slot state, as its configuration
     file says), ``compile()``, a traced fused engine, ``submit`` /
     ``step_block`` until everything drained."""
-    params = meta.unbox(model_cls(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    params = tiny.make_params(model_cls, cfg, seed=0)
     lm = CausalLM(cfg, params, model_cls, buckets=(16, 32), max_batch=2, page_size=4,
                   prefix_cache=prefix_cache)
     lm.compile()
@@ -410,8 +409,7 @@ def test_walk_counters_equal_a_python_model_of_the_same_lengths(async_loop):
     predicts it at dispatch, so both loops count the same); a step reads whole
     chunks up to its longest live row's reach, the token it writes included."""
     cfg = LlamaConfig(**dict(TINY, max_seq_len=512))
-    params = meta.unbox(LlamaForCausalLM(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     lm = CausalLM(cfg, params, LlamaForCausalLM, buckets=(128,), max_batch=4, page_size=16)
     engine = ServeEngine(lm, block_steps=BLOCK_STEPS, rng=jax.random.key(0),
                          async_loop=async_loop)
@@ -445,8 +443,7 @@ def test_walk_row_slots_are_the_whole_rectangle_when_every_row_is_live(async_loo
     """Two slots, two requests of one budget admitted together: every step
     with a live row has both live, the top rung, the parent's read."""
     cfg = LlamaConfig(**dict(TINY, max_seq_len=512))
-    params = meta.unbox(LlamaForCausalLM(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     lm = CausalLM(cfg, params, LlamaForCausalLM, buckets=(128,), max_batch=2, page_size=16)
     engine = ServeEngine(lm, block_steps=BLOCK_STEPS, rng=jax.random.key(0),
                          async_loop=async_loop)

@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import (
     CausalLM,
@@ -55,6 +54,7 @@ from neuronx_distributed_tpu.inference.faults import FaultInjector, FaultPlan
 from neuronx_distributed_tpu.inference.router import Router
 from neuronx_distributed_tpu.lora import LoraConfig, init_lora
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from tests import tiny
 
 TINY = dict(
     vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2,
@@ -71,9 +71,7 @@ ACFG = LoraConfig(r=RANK, lora_alpha=8.0)
 @pytest.fixture(scope="module")
 def base():
     cfg = LlamaConfig(**TINY)
-    ids = jnp.zeros((1, 8), jnp.int32)
-    params = meta.unbox(
-        LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     return cfg, params
 
 

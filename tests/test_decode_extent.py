@@ -62,11 +62,11 @@ from neuronx_distributed_tpu.models.llama import (
     LlamaForCausalLM,
     cached_attention,
 )
-from neuronx_distributed_tpu.parallel import mesh as psm
 from neuronx_distributed_tpu.trainer import (
     initialize_parallel_model,
     neuronx_distributed_config,
 )
+from tests import tiny
 
 B, K, PAGE = 4, 4, 16
 TINY = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2, num_heads=4,
@@ -173,38 +173,31 @@ def reads(how):
 
 # ----------------------------------------------------- models and states
 
-_BUILT = {}
-
-
 def build(case, how, fused=False, rows=B):
     """``(lm, decode or fused program)`` of ``case`` with ``rows`` slots reading
-    the cache ``how``, built once a module; the world (mesh) is the case's,
-    made anew for the test (``conftest.py`` takes it down after each)."""
+    the cache ``how``, built once a process and the case's weights once for
+    all of them; the world (mesh) is the case's, made anew for the test
+    (``conftest.py`` takes it down after each)."""
     kind, over, lm_kw, tp, seq = CASES[case]
-    psm.destroy_model_parallel()
-    psm.initialize_model_parallel(tensor_model_parallel_size=tp,
-                                  devices=jax.devices()[:tp])
-    if (case, how, fused, rows) not in _BUILT:
-        _BUILT[case, how, fused, rows] = _build(case, how, fused, rows)
-    return _BUILT[case, how, fused, rows]
-
-
-def _build(case, how, fused, rows):
-    kind, over, lm_kw, tp, seq = CASES[case]
+    tiny.world(tp)
     if kind == "latent":
         cfg, cls = DeepseekV2Config(**{**LATENT, **over, "max_seq_len": seq}), DeepseekV2ForCausalLM
     else:
         cfg, cls = LlamaConfig(**{**TINY, **over, "max_seq_len": seq}), LlamaForCausalLM
-    nxd = neuronx_distributed_config(tensor_parallel_size=tp)
-    params = initialize_parallel_model(nxd, lambda: cls(cfg), jnp.zeros((1, 8), jnp.int32)).params
-    page = lm_kw.get("page_size", PAGE)
-    pages = dict(page_size=page, page_pool_pages=rows * seq // page + 1) if page else {}
-    kw = {k: v for k, v in lm_kw.items() if k != "page_size"}
-    lm = CausalLM(cfg, params, cls, buckets=(16,), max_batch=rows, **pages, **kw)
-    with reads(how):
-        program = (lm.compile_session_decode_fused(K, SlotSampler(), 0) if fused
-                   else lm.compile()._decode)
-    return lm, program
+    params = tiny.built(("decode_extent", case), lambda: initialize_parallel_model(
+        neuronx_distributed_config(tensor_parallel_size=tp), lambda: cls(cfg),
+        jnp.zeros((1, 8), jnp.int32)).params)
+
+    def make():
+        page = lm_kw.get("page_size", PAGE)
+        pages = dict(page_size=page, page_pool_pages=rows * seq // page + 1) if page else {}
+        kw = {k: v for k, v in lm_kw.items() if k != "page_size"}
+        lm = CausalLM(cfg, params, cls, buckets=(16,), max_batch=rows, **pages, **kw)
+        with reads(how):
+            return lm, (lm.compile_session_decode_fused(K, SlotSampler(), 0) if fused
+                        else lm.compile()._decode)
+
+    return tiny.built(("decode_extent", case, how, fused, rows), make)
 
 
 def state(lm, lengths, mapped=None, seed=0):

@@ -12,17 +12,16 @@ computes and writes must EQUAL, bit for bit.
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM
 from neuronx_distributed_tpu.models import llama
 from neuronx_distributed_tpu.models.deepseek_v2 import DeepseekV2Config
 from neuronx_distributed_tpu.models.llama import KVWalk, LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.parallel import mesh as psm
+from tests import tiny
 from tests.test_decode_extent import (
     CASES,
     K,
@@ -286,8 +285,7 @@ def test_the_fused_block_and_generate_agree_where_every_row_counts():
 
     psm.destroy_model_parallel()
     cfg = LlamaConfig(**{**TINY, "max_seq_len": 512})
-    params = meta.unbox(LlamaForCausalLM(cfg).init(
-        jax.random.key(2), jnp.zeros((1, 8), jnp.int32)))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=2)
     prompt = np.random.RandomState(3).randint(1, 127, (1, 120)).astype(np.int32)
     slab = CausalLM(cfg, params, LlamaForCausalLM, buckets=(128,), max_batch=1)
     want = slab.generate(prompt, 16).tokens[0]

@@ -16,6 +16,7 @@ bf16 is over 500 times past the tolerance (``test_a_lower_precision_fails``).
 """
 
 import dataclasses
+import functools
 import sys
 
 import jax
@@ -36,7 +37,8 @@ from neuronx_distributed_tpu.models.deepseek_v2 import (
 from neuronx_distributed_tpu.models.llama import KVLayerView, YarnScaling
 from neuronx_distributed_tpu.moe.layer import MoE
 from neuronx_distributed_tpu.moe.routing import RouterTopK
-from neuronx_distributed_tpu.parallel import mesh
+from tests import tiny
+from tests.tiny import IDS, LENS, STEPS, at_cached, cached_logits, distance, world
 
 TOL = 1e-4
 YARN = {"type": "yarn", "factor": 40, "original_max_position_embeddings": 16, "beta_fast": 32,
@@ -52,47 +54,19 @@ SIZES = {"rms_norm_eps": 1e-6, "rope_theta": 10000.0, "rope_scaling": YARN,
          "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
          "num_experts_per_tok": 4, "n_group": 4, "topk_group": 2, "routed_scaling_factor": 16.0,
          "norm_topk_prob": False, "experts_held_first": 4, "router_experts": 16}
-IDS = np.random.RandomState(0).randint(1, 512, (3, 24)).astype(np.int32)
-LENS = np.asarray([18, 12, 15])
-STEPS = 6
-
-
-def world():
-    mesh.destroy_model_parallel()
-    mesh.initialize_model_parallel(tensor_model_parallel_size=1, devices=jax.devices()[:1])
-
-
-def make_params(cfg):
-    params = meta.unbox(DeepseekV2ForCausalLM(cfg).init(jax.random.key(1), jnp.asarray(IDS)))[
-        "params"]
-
-    def shake(path, a):
-        name = jax.tree_util.keystr(path)
-        if "norm" in name:
-            return a * (1.0 + 0.3 * jax.random.normal(jax.random.key(len(name)), a.shape))
-        return a
-
-    return jax.tree.map(np.asarray, jax.tree_util.tree_map_with_path(shake, params))
-
-
-def distance(got, want):
-    return float(np.abs(np.asarray(got) - np.asarray(want)).max() / np.abs(want).max())
+full_forward = functools.partial(tiny.full_forward, DeepseekV2ForCausalLM)
+serving_lm = functools.partial(tiny.serving_lm, DeepseekV2ForCausalLM, cfg=DeepseekV2Config(**TINY))
 
 
 @pytest.fixture(scope="module")
 def params():
     world()
-    return make_params(DeepseekV2Config(**TINY))
+    return tiny.make_params(DeepseekV2ForCausalLM, DeepseekV2Config(**TINY), IDS, tiny.shake_norms)
 
 
 @pytest.fixture(scope="module")
 def want(params):
     return np.asarray(reference.forward(params, jnp.asarray(IDS), SIZES))
-
-
-def full_forward(cfg, params):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(DeepseekV2ForCausalLM(cfg).apply({"params": params}, jnp.asarray(IDS)))
 
 
 def test_preset_is_the_published_configuration():
@@ -112,7 +86,7 @@ def test_full_forward_equals_the_reference(params, want, held):
         assert distance(full_forward(DeepseekV2Config(**TINY), params), want) <= TOL
         return
     cfg = DeepseekV2Config(**dict(TINY, num_experts=16, experts_held_first=0))
-    uncut = make_params(cfg)
+    uncut = tiny.make_params(DeepseekV2ForCausalLM, cfg, IDS, tiny.shake_norms)
     sizes = dict(SIZES, experts_held_first=0)
     assert distance(full_forward(cfg, uncut),
                     reference.forward(uncut, jnp.asarray(IDS), sizes)) <= TOL
@@ -160,28 +134,6 @@ def test_yarn_frequencies_equal_the_references():
 
 # ------------------------------------------------------------- the serving path
 
-def serving_lm(params, cfg=None, page_size=8, **kw):
-    cfg = dataclasses.replace(cfg or DeepseekV2Config(**TINY), moe_mode="capacity_factor")
-    return CausalLM(cfg, params, DeepseekV2ForCausalLM, buckets=(32,), max_batch=4,
-                    page_size=page_size, prefix_cache=True, **kw)
-
-
-def cached_logits(lm):
-    rows = len(LENS)
-    prompts = np.zeros((rows, int(LENS.max())), np.int32)
-    for i, n in enumerate(LENS):
-        prompts[i, :n] = IDS[i, :n]
-    session = lm.start_session()
-    kw = dict(reserve_tokens=STEPS + 1) if lm.paged else {}
-    with jax.default_matmul_precision("highest"):
-        got = [np.asarray(lm.insert(session, np.arange(rows), prompts, lengths=LENS, **kw))]
-        for t in range(STEPS):
-            tok = np.zeros((lm.max_batch,), np.int32)
-            tok[:rows] = IDS[np.arange(rows), LENS + t]
-            got.append(np.asarray(lm.step(session, tok))[:rows])
-    return np.stack(got)
-
-
 @pytest.mark.parametrize("cache", ["paged", "slab"])
 def test_insert_and_decode_through_the_latent_cache_equal_the_reference(params, want, cache):
     """Prefill in the expanded form, then every decoded position in the
@@ -189,10 +141,8 @@ def test_insert_and_decode_through_the_latent_cache_equal_the_reference(params, 
     (which never takes the absorbed form)."""
     world()
     with jax.default_matmul_precision("highest"):
-        lm = serving_lm(params, page_size=8 if cache == "paged" else None).compile()
-    pick = LENS[:, None] - 1 + np.arange(STEPS + 1)[None, :]
-    at_cached = want[np.arange(len(LENS))[:, None], pick].transpose(1, 0, 2)
-    assert distance(cached_logits(lm), at_cached) <= TOL
+        lm = tiny.compiled_lm(serving_lm, params, cache)
+    assert distance(cached_logits(lm), at_cached(want)) <= TOL
 
 
 def test_absorbed_decode_equals_expanded_attention_on_the_same_latent():
@@ -224,7 +174,7 @@ def test_absorbed_decode_equals_expanded_attention_on_the_same_latent():
 def test_the_latent_leaf_is_one_leaf_and_counts_its_own_bytes(params):
     world()
     cfg = DeepseekV2Config(**dict(TINY, dtype=jnp.bfloat16))
-    lm = serving_lm(params, cfg)
+    lm = serving_lm(params, cfg=cfg)
     leaves = {jax.tree_util.keystr(p): a for p, a in
               jax.tree_util.tree_flatten_with_path(lm._cache_avals())[0]}
     pools = {p: a for p, a in leaves.items() if "cached" in p}
@@ -265,9 +215,9 @@ def test_serve_engine_gives_solo_generates_tokens_and_hits_a_latent_prefix(param
     prompts += [np.concatenate([shared, rng.randint(1, 512, (n,)).astype(np.int32)])
                 for n in (5, 9)]
     with jax.default_matmul_precision("highest"):
-        alone = serving_lm(params, page_size=None)       # generate() is the slab path's
+        alone = tiny.compiled_lm(serving_lm, params, "slab")   # generate() is the slab path's
         solo = [alone.generate(p[None], STEPS + 1).tokens[0] for p in prompts]
-        lm = serving_lm(params).compile()
+        lm = tiny.compiled_lm(serving_lm, params)
         engine = ServeEngine(lm, block_steps=4, rng=jax.random.key(0))
         ids = [engine.submit(p, max_new_tokens=STEPS + 1, arrival_block=0) for p in prompts[:4]]
         while engine.step_block():
@@ -391,7 +341,7 @@ def test_an_insert_with_one_row_past_zero_reads_the_slab_and_equals_the_referenc
     slab, and both rows' logits are the reference's."""
     world()
     with jax.default_matmul_precision("highest"):
-        lm = serving_lm(params).compile()
+        lm = tiny.compiled_lm(serving_lm, params)
         session = lm.start_session()
         lm.insert(session, np.asarray([0]), IDS[:1, :18], lengths=np.asarray([18]),
                   reserve_tokens=1)
@@ -412,7 +362,7 @@ def test_a_chunked_extend_after_a_fresh_insert_equals_the_one_shot_prefill(param
     world()
     lens, first = np.asarray([22, 17]), 10
     with jax.default_matmul_precision("highest"):
-        lm = serving_lm(params, page_size=8 if cache == "paged" else None).compile()
+        lm = tiny.compiled_lm(serving_lm, params, cache)
         session = lm.start_session()
         slots = np.arange(2)
         lm.insert(session, slots, IDS[:2, :first], lengths=np.full((2,), first),

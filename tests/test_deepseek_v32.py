@@ -19,6 +19,7 @@ choice alone to it, ties included.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,7 @@ import pytest
 from flax.core import meta
 
 from benchmark.reference import deepseek_v32 as reference
-from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
+from neuronx_distributed_tpu.inference import ServeEngine
 from neuronx_distributed_tpu.inference.partition import leaf_partition_spec
 from neuronx_distributed_tpu.models import deepseek_v2, deepseek_v32
 from neuronx_distributed_tpu.models.deepseek_v2 import DeepseekV2Attention, DeepseekV2Config
@@ -41,7 +42,8 @@ from neuronx_distributed_tpu.models.deepseek_v32 import (
 from neuronx_distributed_tpu.models.llama import INDEX_LEAF, KVLayerView
 from neuronx_distributed_tpu.moe.layer import MoE
 from neuronx_distributed_tpu.moe.routing import RouterTopK
-from neuronx_distributed_tpu.parallel import mesh
+from tests import tiny
+from tests.tiny import IDS, STEPS, at_cached, cached_logits, distance, world
 
 TOL = 2e-5
 YARN = {"type": "yarn", "factor": 40, "original_max_position_embeddings": 16, "beta_fast": 32,
@@ -58,9 +60,6 @@ SIZES = {"rms_norm_eps": 1e-6, "rope_theta": 10000.0, "rope_scaling": YARN,
          "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16, "index_topk": 6,
          "num_experts_per_tok": 4, "n_group": 4, "topk_group": 2, "routed_scaling_factor": 2.5,
          "norm_topk_prob": True, "experts_held_first": 4, "router_experts": 16}
-IDS = np.random.RandomState(0).randint(1, 512, (3, 24)).astype(np.int32)
-LENS = np.asarray([18, 12, 15])
-STEPS = 6
 # a table of 512 slots is four chunks of 128: the one-token step reads by
 # prefixes and a prompt's blocks reach different ones; top-100 of up to 300
 LONG = dict(max_seq_len=512, index_topk=100, index_block_q=64)
@@ -68,45 +67,26 @@ LONG_IDS = np.random.RandomState(3).randint(1, 512, (3, 300)).astype(np.int32)
 LONG_LENS = np.asarray([290, 140, 205])
 
 
-def world():
-    mesh.destroy_model_parallel()
-    mesh.initialize_model_parallel(tensor_model_parallel_size=1, devices=jax.devices()[:1])
+full_forward = functools.partial(tiny.full_forward, DeepseekV32ForCausalLM)
+serving_lm = functools.partial(tiny.serving_lm, DeepseekV32ForCausalLM,
+                               cfg=DeepseekV32Config(**TINY))
 
 
-def make_params(cfg):
-    params = meta.unbox(DeepseekV32ForCausalLM(cfg).init(jax.random.key(1), jnp.asarray(IDS)))[
-        "params"]
-
-    def shake(path, a):
-        name = jax.tree_util.keystr(path)
-        noise = jax.random.normal(jax.random.key(len(name)), a.shape)
-        if "e_score_correction_bias" in name or name.endswith("['index_k_norm']['bias']"):
-            return a + 0.1 * noise
-        if "norm" in name:
-            return a * (1.0 + 0.3 * noise)
-        return a
-
-    return jax.tree.map(np.asarray, jax.tree_util.tree_map_with_path(shake, params))
-
-
-def distance(got, want):
-    return float(np.abs(np.asarray(got) - np.asarray(want)).max() / np.abs(want).max())
+def shake(name, a):
+    if "e_score_correction_bias" in name or name.endswith("['index_k_norm']['bias']"):
+        return a + 0.1 * tiny.noise(name, a)
+    return tiny.shake_norms(name, a)
 
 
 @pytest.fixture(scope="module")
 def params():
     world()
-    return make_params(DeepseekV32Config(**TINY))
+    return tiny.make_params(DeepseekV32ForCausalLM, DeepseekV32Config(**TINY), IDS, shake)
 
 
 @pytest.fixture(scope="module")
 def want(params):
     return np.asarray(reference.forward(params, jnp.asarray(IDS), SIZES))
-
-
-def full_forward(cfg, params, ids=IDS):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(DeepseekV32ForCausalLM(cfg).apply({"params": params}, jnp.asarray(ids)))
 
 
 def test_preset_is_the_published_configuration():
@@ -132,7 +112,7 @@ def test_full_forward_equals_the_reference(params, want, held):
         assert distance(full_forward(DeepseekV32Config(**TINY), params), want) <= TOL
         return
     cfg = DeepseekV32Config(**dict(TINY, num_experts=16, experts_held_first=0))
-    uncut = make_params(cfg)
+    uncut = tiny.make_params(DeepseekV32ForCausalLM, cfg, IDS, shake)
     assert distance(full_forward(cfg, uncut), reference.forward(
         uncut, jnp.asarray(IDS), dict(SIZES, experts_held_first=0))) <= TOL
 
@@ -238,28 +218,6 @@ def test_the_index_scores_are_the_references():
 
 # ------------------------------------------------------------- the serving path
 
-def serving_lm(params, cfg=None, page_size=8, buckets=(32,), **kw):
-    cfg = dataclasses.replace(cfg or DeepseekV32Config(**TINY), moe_mode="capacity_factor")
-    return CausalLM(cfg, params, DeepseekV32ForCausalLM, buckets=buckets, max_batch=4,
-                    page_size=page_size, prefix_cache=True, **kw)
-
-
-def cached_logits(lm, ids=IDS, lens=LENS, steps=STEPS):
-    rows = len(lens)
-    prompts = np.zeros((rows, int(lens.max())), np.int32)
-    for i, n in enumerate(lens):
-        prompts[i, :n] = ids[i, :n]
-    session = lm.start_session()
-    kw = dict(reserve_tokens=steps + 1) if lm.paged else {}
-    with jax.default_matmul_precision("highest"):
-        got = [np.asarray(lm.insert(session, np.arange(rows), prompts, lengths=lens, **kw))]
-        for t in range(steps):
-            tok = np.zeros((lm.max_batch,), np.int32)
-            tok[:rows] = ids[np.arange(rows), lens + t]
-            got.append(np.asarray(lm.step(session, tok))[:rows])
-    return np.stack(got)
-
-
 @pytest.mark.parametrize("cache", ["paged", "slab"])
 def test_insert_and_decode_through_both_leaves_equal_the_reference(params, want, cache):
     """Prefill (the blocked masked form past ``index_topk``), then every decoded
@@ -267,10 +225,8 @@ def test_insert_and_decode_through_both_leaves_equal_the_reference(params, want,
     reference's full forward."""
     world()
     with jax.default_matmul_precision("highest"):
-        lm = serving_lm(params, page_size=8 if cache == "paged" else None).compile()
-    pick = LENS[:, None] - 1 + np.arange(STEPS + 1)[None, :]
-    at_cached = want[np.arange(len(LENS))[:, None], pick].transpose(1, 0, 2)
-    assert distance(cached_logits(lm), at_cached) <= TOL
+        lm = tiny.compiled_lm(serving_lm, params, cache)
+    assert distance(cached_logits(lm), at_cached(want)) <= TOL
 
 
 @pytest.mark.parametrize("heads", ["all_at_once", "in_groups_of_two"])
@@ -287,10 +243,9 @@ def test_a_long_table_is_read_by_prefixes_and_chosen_from(params, heads, monkeyp
     steps = 4
     want = np.asarray(reference.forward(params, jnp.asarray(LONG_IDS), sizes))
     with jax.default_matmul_precision("highest"):
-        lm = serving_lm(params, cfg, buckets=(512,)).compile()
-    pick = LONG_LENS[:, None] - 1 + np.arange(steps + 1)[None, :]
-    at_cached = want[np.arange(3)[:, None], pick].transpose(1, 0, 2)
-    assert distance(cached_logits(lm, LONG_IDS, LONG_LENS, steps), at_cached) <= TOL
+        lm = serving_lm(params, cfg=cfg, buckets=(512,)).compile()
+    assert distance(cached_logits(lm, LONG_IDS, LONG_LENS, steps),
+                    at_cached(want, LONG_LENS, steps)) <= TOL
 
 
 def _one_layer(cls, cfg, x, steps=1):
@@ -400,7 +355,7 @@ def test_both_leaves_are_declared_counted_and_replicated(params):
     world()
     from jax.sharding import PartitionSpec
 
-    lm = serving_lm(params, DeepseekV32Config(**dict(TINY, dtype=jnp.bfloat16)))
+    lm = serving_lm(params, cfg=DeepseekV32Config(**dict(TINY, dtype=jnp.bfloat16)))
     leaves = {jax.tree_util.keystr(p): a for p, a in
               jax.tree_util.tree_flatten_with_path(lm._cache_avals())[0]}
     pools = {p: a.shape for p, a in leaves.items() if "cached" in p}
@@ -449,9 +404,9 @@ def test_serve_engine_shares_both_leaves_of_a_prefix_and_counts_the_choice(param
     prompts += [np.concatenate([shared, rng.randint(1, 512, (n,)).astype(np.int32)])
                 for n in (5, 9)]
     with jax.default_matmul_precision("highest"):
-        alone = serving_lm(params, page_size=None)       # generate() is the slab path's
+        alone = tiny.compiled_lm(serving_lm, params, "slab")   # generate() is the slab path's
         solo = [alone.generate(p[None], STEPS + 1).tokens[0] for p in prompts]
-        lm = serving_lm(params).compile()
+        lm = tiny.compiled_lm(serving_lm, params)
         engine = ServeEngine(lm, block_steps=4, rng=jax.random.key(0))
         ids = [engine.submit(p, max_new_tokens=STEPS + 1, arrival_block=0) for p in prompts[:4]]
         while engine.step_block():

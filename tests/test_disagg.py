@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import (
     CausalLM,
@@ -32,6 +31,7 @@ from neuronx_distributed_tpu.inference import (
 from neuronx_distributed_tpu.inference.replay import synthetic_trace
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.observability import validate_chrome_trace
+from tests import tiny
 
 TINY = dict(
     vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2,
@@ -45,9 +45,7 @@ PAGE = 4
 @pytest.fixture(scope="module")
 def lm_p():
     cfg = LlamaConfig(**TINY)
-    ids = jnp.zeros((1, 8), jnp.int32)
-    params = meta.unbox(
-        LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     return CausalLM(cfg, params, LlamaForCausalLM, buckets=(8, 16),
                     max_batch=3, page_size=PAGE).compile()
 
@@ -313,9 +311,7 @@ def test_run_disagg_trace_report_and_lanes(lm_p, tmp_path):
 
 def test_disagg_validation_and_role_guards(lm_p):
     cfg = LlamaConfig(**TINY)
-    ids = jnp.zeros((1, 8), jnp.int32)
-    params = meta.unbox(
-        LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     lm_c = CausalLM(cfg, params, LlamaForCausalLM, buckets=(8,), max_batch=2)
     with pytest.raises(ValueError, match="paged"):
         DisaggRouter(lm_c, 2)

@@ -20,11 +20,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.observability import Tracer
+from tests import tiny
 
 TINY = dict(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2, num_heads=4,
             num_kv_heads=2, kv_size_multiplier=1, max_seq_len=64, dtype=jnp.float32,
@@ -54,8 +54,7 @@ class Recorder:
 @pytest.fixture(scope="module")
 def lm():
     cfg = LlamaConfig(**TINY)
-    params = meta.unbox(LlamaForCausalLM(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     return CausalLM(cfg, params, LlamaForCausalLM, buckets=(16, 32), max_batch=3, page_size=4,
                     prefix_cache=True).compile()
 

@@ -15,7 +15,7 @@ logits within 1e-4 of the reference's largest. The right mathematics sits near
 the tolerance.
 """
 
-import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,7 @@ from flax.core import meta
 from jax.sharding import PartitionSpec
 
 from benchmark.reference import granite_hybrid as reference
-from neuronx_distributed_tpu.inference import CausalLM, ServeEngine, causal_lm
+from neuronx_distributed_tpu.inference import ServeEngine, causal_lm
 from neuronx_distributed_tpu.inference.partition import leaf_partition_spec
 from neuronx_distributed_tpu.models import granite_hybrid
 from neuronx_distributed_tpu.models.granite_hybrid import (
@@ -36,7 +36,8 @@ from neuronx_distributed_tpu.models.granite_hybrid import (
     ssd_chunked,
 )
 from neuronx_distributed_tpu.models.llama import KVLayerView
-from neuronx_distributed_tpu.parallel import mesh
+from tests import tiny
+from tests.tiny import distance, world
 
 TOL = 1e-4
 PERIOD = ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
@@ -53,24 +54,21 @@ IDS = np.random.RandomState(0).randint(1, 256, (4, 40)).astype(np.int32)
 STEPS = 5
 
 
-def world():
-    mesh.destroy_model_parallel()
-    mesh.initialize_model_parallel(tensor_model_parallel_size=1, devices=jax.devices()[:1])
+full_forward = functools.partial(tiny.full_forward, GraniteHybridForCausalLM, ids=IDS)
+serving_lm = functools.partial(tiny.serving_lm, GraniteHybridForCausalLM,
+                               cfg=GraniteHybridConfig(**TINY), buckets=(16, 32), prefix_cache=False)
+
+
+def shake(name, a):
+    if name.endswith("['D']"):
+        return a * (1.0 + 0.3 * tiny.noise(name, a))
+    return tiny.shake_norms(name, a)
 
 
 @pytest.fixture(scope="module")
 def params():
     world()
-    tree = meta.unbox(GraniteHybridForCausalLM(GraniteHybridConfig(**TINY)).init(
-        jax.random.key(1), jnp.asarray(IDS)))["params"]
-
-    def shake(path, a):
-        name = jax.tree_util.keystr(path)
-        if "norm" in name or name.endswith("['D']"):
-            return a * (1.0 + 0.3 * jax.random.normal(jax.random.key(len(name)), a.shape))
-        return a
-
-    return jax.tree.map(np.asarray, jax.tree_util.tree_map_with_path(shake, tree))
+    return tiny.make_params(GraniteHybridForCausalLM, GraniteHybridConfig(**TINY), IDS, shake)
 
 
 def ref_logits(params, ids, sizes=SIZES):
@@ -82,25 +80,12 @@ def want(params):
     return ref_logits(params, IDS)
 
 
-def distance(got, want):
-    return float(np.abs(np.asarray(got) - np.asarray(want)).max() / np.abs(want).max())
-
-
-def full_forward(cfg, params, ids=IDS):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(GraniteHybridForCausalLM(cfg).apply({"params": params}, jnp.asarray(ids)))
-
-
-def serving_lm(params, **kw):
-    kw = {**dict(buckets=(16, 32), max_batch=4, page_size=8, prefix_cache=False), **kw}
-    return CausalLM(GraniteHybridConfig(**TINY), params, GraniteHybridForCausalLM, **kw)
-
-
-def padded(rows, lens):
-    prompts = np.zeros((len(rows), int(max(lens))), np.int32)
-    for i, (r, n) in enumerate(zip(rows, lens)):
-        prompts[i, :n] = IDS[r, :n]
-    return prompts
+@pytest.fixture(scope="module")
+def lm(params):
+    """The serving lm of every test that plants nothing in it: its programs
+    (an insert a bucket, the step, the fused block) compile once a process."""
+    world()
+    return serving_lm(params)
 
 
 def insert_then_step(lm, lens, steps=STEPS, slots=None):
@@ -111,18 +96,13 @@ def insert_then_step(lm, lens, steps=STEPS, slots=None):
     lens = np.asarray(lens)
     session = lm.start_session()
     with jax.default_matmul_precision("highest"):
-        got = [np.asarray(lm.insert(session, slots, padded(range(rows), lens), lengths=lens,
+        got = [np.asarray(lm.insert(session, slots, tiny.padded(IDS, range(rows), lens), lengths=lens,
                                     reserve_tokens=steps + 1))]
         for t in range(steps):
             tok = np.zeros((lm.max_batch,), np.int32)
             tok[slots] = IDS[np.arange(rows), lens + t]
             got.append(np.asarray(lm.step(session, tok))[slots])
     return np.stack(got)
-
-
-def at_cached(want, lens, steps=STEPS):
-    pick = np.asarray(lens)[:, None] - 1 + np.arange(steps + 1)[None, :]
-    return want[np.arange(len(lens))[:, None], pick].transpose(1, 0, 2)
 
 
 # --------------------------------------------------------------- the forward
@@ -252,14 +232,13 @@ LENGTHS = {"short_beside_full": [19, 32, 7], "fills_the_small_bucket": [16, 16],
 
 
 @pytest.mark.parametrize("case", sorted(LENGTHS))
-def test_insert_and_decode_equal_the_reference(params, want, case):
+def test_insert_and_decode_equal_the_reference(lm, want, case):
     """(b) prefill then decode through ``lm.insert`` / ``lm.step``: the state
     after a padded bucket is the state after the row's last real token, so
     every decoded position equals the reference's full forward."""
     world()
     lens = LENGTHS[case]
-    lm = serving_lm(params)
-    assert distance(insert_then_step(lm, lens), at_cached(want, lens)) <= TOL
+    assert distance(insert_then_step(lm, lens), tiny.at_cached(want, lens, STEPS)) <= TOL
 
 
 def run_engine(lm, prompts, budget, **kw):
@@ -282,7 +261,7 @@ def greedy_by_the_reference(params, prompt, tokens):
 
 
 @pytest.mark.parametrize("loop", ["fused", "stepwise", "async"])
-def test_the_engines_block_decodes_what_the_reference_does(params, loop):
+def test_the_engines_block_decodes_what_the_reference_does(params, lm, loop):
     """(b) through ``ServeEngine``: six requests over four slots, so slots
     are reused and inserts land in a running batch; every token is the
     reference's argmax over the whole sequence so far."""
@@ -290,7 +269,7 @@ def test_the_engines_block_decodes_what_the_reference_does(params, loop):
     rng = np.random.RandomState(3)
     prompts = [rng.randint(1, 256, (n,)).astype(np.int32) for n in (19, 32, 7, 16, 25, 9)]
     kw = {"fused": dict(), "stepwise": dict(fused=False), "async": dict(async_loop=True)}[loop]
-    engine, tokens = run_engine(serving_lm(params), prompts, 9, **kw)
+    engine, tokens = run_engine(lm, prompts, 9, **kw)
     for prompt, got in zip(prompts, tokens):
         assert len(got) == 9
         np.testing.assert_array_equal(got, greedy_by_the_reference(params, prompt, got)[0])
@@ -299,16 +278,15 @@ def test_the_engines_block_decodes_what_the_reference_does(params, loop):
     assert stats["ssm_scan_positions"] >= stats["ssm_scan_tokens"]
 
 
-def test_an_insert_into_a_running_batch_leaves_the_other_rows_alone(params):
+def test_an_insert_into_a_running_batch_leaves_the_other_rows_alone(lm):
     """(e) rows 0 and 1 decode; after two steps a third request is inserted
     into slot 2. Their logits are bit-identical to a run without it."""
     world()
-    lm = serving_lm(params)
     lens = np.asarray([19, 12])
 
     def run(with_insert):
         session = lm.start_session()
-        got = [np.asarray(lm.insert(session, np.arange(2), padded(range(2), lens), lengths=lens,
+        got = [np.asarray(lm.insert(session, np.arange(2), tiny.padded(IDS, range(2), lens), lengths=lens,
                                     reserve_tokens=STEPS + 1))]
         for t in range(STEPS):
             if with_insert and t == 2:
@@ -322,11 +300,10 @@ def test_an_insert_into_a_running_batch_leaves_the_other_rows_alone(params):
     np.testing.assert_array_equal(run(True), run(False))
 
 
-def test_a_reused_slot_starts_from_zero(params, want):
+def test_a_reused_slot_starts_from_zero(lm, want):
     """(f) slot 0 serves one request, is retired, and serves another: the
     second sees no trace of the first."""
     world()
-    lm = serving_lm(params)
     session = lm.start_session()
     with jax.default_matmul_precision("highest"):
         lm.insert(session, np.asarray([0]), IDS[3:4, :30], lengths=np.asarray([30]),
@@ -341,17 +318,17 @@ def test_a_reused_slot_starts_from_zero(params, want):
             tok = np.zeros((4,), np.int32)
             tok[0] = IDS[0, n + t]
             got.append(np.asarray(lm.step(session, tok))[:1])
-    assert distance(np.stack(got), at_cached(want[:1], [n])) <= TOL
+    assert distance(np.stack(got), tiny.at_cached(want[:1], [n], STEPS)) <= TOL
 
 
-def test_a_chunked_extend_equals_one_insert(params):
+def test_a_chunked_extend_equals_one_insert(params, lm):
     """(g) a prompt admitted in chunks of 16 continues from the state its last
     chunk left: the same tokens as one insert, and the reference's."""
     world()
     rng = np.random.RandomState(4)
     prompts = [rng.randint(1, 256, (n,)).astype(np.int32) for n in (30, 13, 32)]
-    _, whole = run_engine(serving_lm(params), prompts, 6)
-    engine, chunked = run_engine(serving_lm(params), prompts, 6, prefill_chunk_tokens=16)
+    _, whole = run_engine(lm, prompts, 6)
+    engine, chunked = run_engine(lm, prompts, 6, prefill_chunk_tokens=16)
     assert engine.stats["chunk_program_calls"] > 0
     for prompt, a, b in zip(prompts, whole, chunked):
         np.testing.assert_array_equal(a, b)
@@ -370,7 +347,7 @@ def test_wrong_serving_fails(params, want, wrong, monkeypatch):
     lm = serving_lm(params)
     if wrong == "padding_scanned":
         lm.wants_live = False
-        assert distance(insert_then_step(lm, [19, 32, 7]), at_cached(want, [19, 32, 7])) > 10 * TOL
+        assert distance(insert_then_step(lm, [19, 32, 7]), tiny.at_cached(want, [19, 32, 7], STEPS)) > 10 * TOL
         return
     monkeypatch.setattr(causal_lm, "_state_rows", lambda leaf, slots, starts: leaf[:, slots])
     session = lm.start_session()

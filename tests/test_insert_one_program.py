@@ -24,8 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
+from tests import tiny
 from tests.helpers import count_factory_calls, dispatch_counts
 from neuronx_distributed_tpu.inference import CausalLM, Sampler, ServeEngine
 from neuronx_distributed_tpu.inference.causal_lm import FirstToken
@@ -42,22 +42,16 @@ MODELS = {
                                    top_k=2)), OlmoeForCausalLM),
 }
 B, BUCKET, PAGE = 8, 16, 4
-_PARAMS, _LMS = {}, {}
 
 
 def _lm(model="dense", paged=True, grammar=False):
     """One compiled stack per (model, cache form, grammar support)."""
-    key = (model, paged, grammar)
-    if key not in _LMS:
-        cfg, cls = MODELS[model]
-        if model not in _PARAMS:
-            _PARAMS[model] = meta.unbox(cls(cfg).init(
-                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
-        _LMS[key] = CausalLM(
-            cfg, _PARAMS[model], cls, buckets=(BUCKET, 32), max_batch=B,
-            **(dict(page_size=PAGE) if paged else {}),
-            **(dict(grammar_slots=3, grammar_states=48) if grammar else {})).compile()
-    return _LMS[key]
+    cfg, cls = MODELS[model]
+    params = tiny.built(("insert_one_program", model), lambda: tiny.make_params(cls, cfg, seed=0))
+    return tiny.built(("insert_one_program", model, paged, grammar), lambda: CausalLM(
+        cfg, params, cls, buckets=(BUCKET, 32), max_batch=B,
+        **(dict(page_size=PAGE) if paged else {}),
+        **(dict(grammar_slots=3, grammar_states=48) if grammar else {})).compile())
 
 
 def _prompts(n, seed, lo=5, hi=BUCKET):

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from flax import linen as nn
-from flax.core import meta
 from jax.sharding import PartitionSpec as PS
 
 from neuronx_distributed_tpu.inference import CausalLM
@@ -41,6 +40,7 @@ from neuronx_distributed_tpu.trainer import (
     initialize_parallel_model,
     neuronx_distributed_config,
 )
+from tests import tiny
 
 L, NPAGES, PAGE, N_KV, HD = 3, 2048, 4, 2, 8
 TINY = dict(
@@ -59,18 +59,13 @@ PROGRAMS = {
 }
 POOL_LEAF_BYTES = L * NPAGES * PAGE * N_KV * HD * 4
 
-_LMS = {}
-
 
 def _lm(model):
-    if model not in _LMS:
-        config_cls, model_cls, over = MODELS[model]
-        cfg = config_cls(**{**TINY, **over})
-        params = meta.unbox(model_cls(cfg).init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
-        _LMS[model] = CausalLM(cfg, params, model_cls, buckets=(8,), max_batch=2,
-                               page_size=PAGE, page_pool_pages=NPAGES)
-    return _LMS[model]
+    config_cls, model_cls, over = MODELS[model]
+    cfg = config_cls(**{**TINY, **over})
+    return tiny.built(("kv_carry_structure", model), lambda: CausalLM(
+        cfg, tiny.make_params(model_cls, cfg, seed=0), model_cls, buckets=(8,), max_batch=2,
+        page_size=PAGE, page_pool_pages=NPAGES))
 
 
 @pytest.mark.parametrize("program", sorted(PROGRAMS))

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import (
     CausalLM,
@@ -31,6 +30,7 @@ from neuronx_distributed_tpu.inference.kv_quant import (
 from neuronx_distributed_tpu.inference.replay import run_trace
 from neuronx_distributed_tpu.inference.partition import leaf_partition_spec
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from tests import tiny
 
 TINY = dict(
     vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2,
@@ -46,9 +46,7 @@ def stack():
     """(fp32-page lm, int8-page lm) over ONE weight set: the first is the
     second's oracle."""
     cfg = LlamaConfig(**TINY)
-    ids = jnp.zeros((1, 8), jnp.int32)
-    params = meta.unbox(
-        LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
 
     def mk(**kw):
         return CausalLM(cfg, params, LlamaForCausalLM, buckets=(8, 16),

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import (
     CausalLM,
@@ -43,6 +42,7 @@ from neuronx_distributed_tpu.inference.paged_cache import (
     TierCorruption,
 )
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from tests import tiny
 
 TINY = dict(
     vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2,
@@ -60,9 +60,7 @@ def stack():
     """(big-pool paged lm — the 'infinite pool' untiered oracle — and a
     small-pool paged lm the tier tests pressure) over ONE weight set."""
     cfg = LlamaConfig(**TINY)
-    ids = jnp.zeros((1, 8), jnp.int32)
-    params = meta.unbox(
-        LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     lm_big = CausalLM(cfg, params, LlamaForCausalLM, buckets=(8, 16),
                       max_batch=3, page_size=PAGE).compile()
     lm_small = CausalLM(cfg, params, LlamaForCausalLM, buckets=(8, 16),

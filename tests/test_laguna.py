@@ -10,6 +10,7 @@ one token a step. Logits, relative to the reference's largest.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +32,8 @@ from neuronx_distributed_tpu.models.laguna import (
 )
 from neuronx_distributed_tpu.moe.layer import MoE
 from neuronx_distributed_tpu.moe.routing import RouterTopK, group_limit
-from neuronx_distributed_tpu.parallel import mesh
+from tests import tiny
+from tests.tiny import distance, padded, world
 
 TOL = 2e-5
 WINDOW, RING, ORIGINAL = 8, 24, 16
@@ -56,27 +58,14 @@ LENS = np.asarray([24, 11])            # both longer than the window
 STEPS = 30                             # row 0 reaches 54: past the ring's 24 twice
 
 
-def world():
-    mesh.destroy_model_parallel()
-    mesh.initialize_model_parallel(tensor_model_parallel_size=1, devices=jax.devices()[:1])
-
-
-def distance(got, want, scale=None):
-    return float(np.abs(np.asarray(got) - np.asarray(want)).max()
-                 / (scale or np.abs(np.asarray(want)).max()))
+serving_lm = functools.partial(tiny.serving_lm, LagunaForCausalLM, cfg=LagunaConfig(**TINY),
+                               buckets=(16, 32), page_size=16, prefix_cache=False)
 
 
 @pytest.fixture(scope="module")
 def params():
     world()
-    return meta.unbox(LagunaForCausalLM(LagunaConfig(**TINY)).init(
-        jax.random.PRNGKey(0), jnp.asarray(IDS[:1, :8])))["params"]
-
-
-def serving_lm(params, **kw):
-    kw = {**dict(buckets=(16, 32), max_batch=4, page_size=16, prefix_cache=False), **kw}
-    cfg = LagunaConfig(**dict(TINY, **kw.pop("config", {})))
-    return CausalLM(cfg, params, LagunaForCausalLM, **kw)
+    return tiny.make_params(LagunaForCausalLM, LagunaConfig(**TINY), seed=0)
 
 
 @pytest.fixture(scope="module")
@@ -91,14 +80,6 @@ def want(params):
     return np.asarray(reference.forward(params, jnp.asarray(IDS[:2]), SIZES))
 
 
-def padded(rows, lens, ids=None):
-    ids = IDS if ids is None else ids
-    prompts = np.zeros((len(rows), int(max(lens))), np.int32)
-    for i, (r, n) in enumerate(zip(rows, lens)):
-        prompts[i, :n] = ids[r, :n]
-    return prompts
-
-
 @pytest.fixture(scope="module")
 def served(lm):
     """Rows 0 and 1 inserted at LENS into slots 0 and 1, then STEPS
@@ -106,7 +87,7 @@ def served(lm):
     (STEPS, 2, V))``."""
     world()
     session = lm.start_session()
-    first = np.asarray(lm.insert(session, np.arange(2), padded([0, 1], LENS), lengths=LENS,
+    first = np.asarray(lm.insert(session, np.arange(2), padded(IDS, [0, 1], LENS), lengths=LENS,
                                  reserve_tokens=STEPS + 2))
     steps = []
     for t in range(STEPS):
@@ -170,7 +151,7 @@ def test_a_dead_rows_ring_is_left_alone(lm, params):
     active keeps every byte (its ring is its next tenant's or nobody's)."""
     world()
     session = lm.start_session()
-    lm.insert(session, np.arange(2), padded([0, 1], LENS), lengths=LENS, reserve_tokens=8)
+    lm.insert(session, np.arange(2), padded(IDS, [0, 1], LENS), lengths=LENS, reserve_tokens=8)
 
     def ring(slot):
         return np.asarray(next(leaf for path, leaf in
@@ -201,7 +182,8 @@ LIVE_ROWS = {
 @pytest.fixture(scope="module")
 def rung_lm(params):
     world()
-    return serving_lm(params, config=dict(max_seq_len=4096), buckets=(64,), max_batch=8).compile()
+    return serving_lm(params, cfg=LagunaConfig(**dict(TINY, max_seq_len=4096)), buckets=(64,),
+                      max_batch=8).compile()
 
 
 @pytest.fixture(scope="module")
@@ -221,7 +203,7 @@ def test_every_rung_reads_its_rows_windows_and_writes_no_dead_rows_ring(
     live = LIVE_ROWS[case]
     dead = sorted(set(range(8)) - set(live))
     session = rung_lm.start_session()
-    rung_lm.insert(session, np.arange(8), padded(range(8), RUNG_LENS, RUNG_IDS),
+    rung_lm.insert(session, np.arange(8), padded(RUNG_IDS, range(8), RUNG_LENS),
                    lengths=RUNG_LENS, reserve_tokens=RUNG_STEPS + 2)
     if dead:
         rung_lm.retire(session, dead)
@@ -382,7 +364,7 @@ def test_the_leaves_are_stacked_by_kind_and_a_ring_does_not_grow_with_the_table(
     assert sizes["window_bytes"] == 2 * 3 * 4 * RING * 2 * 8 * 4 and "state_bytes" not in sizes
     assert lm.slot_rows == ("window_key", "window_value") and lm.wants_live
     assert not lm.slot_rows_continue and lm.walk_sums == 5 and not lm.scans
-    longer = serving_lm(params, config=dict(max_seq_len=512)).kv_cache_bytes()
+    longer = serving_lm(params, cfg=LagunaConfig(**dict(TINY, max_seq_len=512))).kv_cache_bytes()
     assert longer["window_bytes"] == sizes["window_bytes"] and longer["kv_bytes"] > sizes["kv_bytes"]
 
 
@@ -428,7 +410,7 @@ def test_the_rung_holds_the_live_rows_at_a_long_table(params):
     """At 512 slots the walk has rungs (1 and 4 rows of 4): one live row reads
     ONE ring a window layer-step, and still agrees with the reference."""
     world()
-    lm = serving_lm(params, config=dict(max_seq_len=512), buckets=(32,)).compile()
+    lm = serving_lm(params, cfg=LagunaConfig(**dict(TINY, max_seq_len=512)), buckets=(32,)).compile()
     engine = ServeEngine(lm, rng=jax.random.key(1))
     engine.submit(IDS[0, :20], max_new_tokens=12, arrival_block=engine.blocks)
     while engine.step_block():
@@ -489,8 +471,7 @@ def test_the_generic_decode_path_refuses_a_window(params):
     cfg = LlamaConfig(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=1,
                       num_heads=4, num_kv_heads=2, max_seq_len=64, dtype=jnp.float32,
                       sliding_window=8, use_flash_attention=False, remat_policy=None)
-    weights = meta.unbox(LlamaForCausalLM(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    weights = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     with pytest.raises(ValueError, match="ring a slot"):
         CausalLM(cfg, weights, LlamaForCausalLM, buckets=(16,), max_batch=2, page_size=16).compile()
 
@@ -633,6 +614,8 @@ def test_a_config_without_a_window_lowers_its_insert_to_the_parents_text():
     cfg = LlamaConfig(vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2,
                       num_heads=4, num_kv_heads=2, max_seq_len=256, dtype=jnp.float32,
                       use_flash_attention=True, remat_policy=None)
+    # eager, as when the text was taken: the lowered arguments carry the
+    # shardings an eager ``unbox`` pins on the scanned leaves (two dense layers: 2 s)
     weights = meta.unbox(LlamaForCausalLM(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
     lm = CausalLM(cfg, weights, LlamaForCausalLM, buckets=(128,), max_batch=2, page_size=16)
@@ -663,8 +646,7 @@ def test_a_long_insert_hands_on_the_held_picks_alone():
     world()
     cfg = dict(TINY, num_experts=4, max_seq_len=512)
     ids = np.random.RandomState(3).randint(1, 128, (1, 200)).astype(np.int32)
-    tree = meta.unbox(LagunaForCausalLM(LagunaConfig(**cfg)).init(
-        jax.random.PRNGKey(2), jnp.asarray(ids[:, :8])))["params"]
+    tree = tiny.make_params(LagunaForCausalLM, LagunaConfig(**cfg), seed=2)
     lm = CausalLM(LagunaConfig(**cfg), tree, LagunaForCausalLM, buckets=(256,), max_batch=2,
                   page_size=16, prefix_cache=False)
     session = lm.start_session()

@@ -32,7 +32,8 @@ from neuronx_distributed_tpu.models.granite_hybrid import (
     GraniteHybridConfig,
     GraniteHybridForCausalLM,
 )
-from neuronx_distributed_tpu.parallel import mesh
+from tests import tiny
+from tests.tiny import world
 
 LATENT = dict(vocab_size=128, hidden_size=32, intermediate_size=48, num_layers=2, num_heads=4,
               num_kv_heads=4, kv_lora_rank=16, q_lora_rank=24, qk_nope_head_dim=8,
@@ -51,29 +52,17 @@ SMALL_POOL = 13     # 3 scratch + 10 allocatable: tests/test_kv_tier.py's pressu
 TIER = 32
 
 
-def world():
-    mesh.destroy_model_parallel()
-    mesh.initialize_model_parallel(tensor_model_parallel_size=1, devices=jax.devices()[:1])
-
-
-_LMS = {}
-
-
 def latent_lm(dtype, pool_pages=None):
     """A tiny DeepSeek-V2 (a dense layer's scan, then an expert layer's) behind
     a pool of ``pool_pages`` (None: room for every row); one weight set a
     dtype, every lm built once."""
     world()
-    if (dtype, "params") not in _LMS:
-        cfg = DeepseekV2Config(**LATENT, dtype=DTYPES[dtype], param_dtype=DTYPES[dtype])
-        _LMS[dtype, "params"] = cfg, meta.unbox(DeepseekV2ForCausalLM(cfg).init(
-            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))["params"]
-    if (dtype, pool_pages) not in _LMS:
-        cfg, params = _LMS[dtype, "params"]
-        _LMS[dtype, pool_pages] = CausalLM(
-            cfg, params, DeepseekV2ForCausalLM, buckets=(16, 32), max_batch=ROWS, page_size=PAGE,
-            page_pool_pages=pool_pages).compile()
-    return _LMS[dtype, pool_pages]
+    cfg = DeepseekV2Config(**LATENT, dtype=DTYPES[dtype], param_dtype=DTYPES[dtype])
+    params = tiny.built(("latent_page_io", dtype), lambda: tiny.make_params(
+        DeepseekV2ForCausalLM, cfg, seed=0))
+    return tiny.built(("latent_page_io", dtype, pool_pages), lambda: CausalLM(
+        cfg, params, DeepseekV2ForCausalLM, buckets=(16, 32), max_batch=ROWS, page_size=PAGE,
+        page_pool_pages=pool_pages).compile())
 
 
 def family(seed, tails, tail=8):

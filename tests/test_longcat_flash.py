@@ -15,7 +15,7 @@ logits within 2e-5 of the reference's largest; the right mathematics reads
 4e-7 to 6e-7. Each wrong mathematics below names the margin it has to clear.
 """
 
-import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -23,10 +23,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from flax import linen as nn
-from flax.core import meta
 
 from benchmark.reference import longcat_flash as reference
-from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
+from neuronx_distributed_tpu.inference import ServeEngine
 from neuronx_distributed_tpu.models.llama import KVLayerView
 from neuronx_distributed_tpu.models.longcat_flash import (
     LongcatFlashConfig,
@@ -37,7 +36,8 @@ from neuronx_distributed_tpu.models.longcat_flash import (
 )
 from neuronx_distributed_tpu.moe.layer import MoE
 from neuronx_distributed_tpu.moe.routing import RouterTopK
-from neuronx_distributed_tpu.parallel import mesh
+from tests import tiny
+from tests.tiny import IDS, LENS, STEPS, at_cached, cached_logits, distance, world
 
 TOL = 2e-5
 TINY = dict(vocab_size=512, hidden_size=64, intermediate_size=96, num_layers=2, num_heads=4,
@@ -51,49 +51,26 @@ SIZES = {"rms_norm_eps": 1e-5, "rope_theta": 1e7, "hidden_size": 64, "q_lora_ran
          "mla_scale_q_lora": True, "mla_scale_kv_lora": True, "moe_topk": 6,
          "routed_scaling_factor": 6.0, "router_experts": 16, "zero_expert_num": 8,
          "experts_held_first": 4}
-IDS = np.random.RandomState(0).randint(1, 512, (3, 24)).astype(np.int32)
-LENS = np.asarray([18, 12, 15])
-STEPS = 6
+full_forward = functools.partial(tiny.full_forward, LongcatFlashForCausalLM)
+serving_lm = functools.partial(tiny.serving_lm, LongcatFlashForCausalLM,
+                               cfg=LongcatFlashConfig(**TINY))
 
 
-def world():
-    mesh.destroy_model_parallel()
-    mesh.initialize_model_parallel(tensor_model_parallel_size=1, devices=jax.devices()[:1])
-
-
-def make_params(cfg):
-    params = meta.unbox(LongcatFlashForCausalLM(cfg).init(jax.random.key(1), jnp.asarray(IDS)))[
-        "params"]
-
-    def shake(path, a):
-        name = jax.tree_util.keystr(path)
-        if "norm" in name:
-            return a * (1.0 + 0.3 * jax.random.normal(jax.random.key(len(name)), a.shape))
-        if "e_score_correction_bias" in name:   # softmax scores over 24 sit near 0.04
-            return 0.05 * jax.random.normal(jax.random.key(7), a.shape)
-        return a
-
-    return jax.tree.map(np.asarray, jax.tree_util.tree_map_with_path(shake, params))
-
-
-def distance(got, want):
-    return float(np.abs(np.asarray(got) - np.asarray(want)).max() / np.abs(want).max())
+def shake(name, a):
+    if "e_score_correction_bias" in name:   # softmax scores over 24 sit near 0.04
+        return 0.05 * jax.random.normal(jax.random.key(7), a.shape)
+    return tiny.shake_norms(name, a)
 
 
 @pytest.fixture(scope="module")
 def params():
     world()
-    return make_params(LongcatFlashConfig(**TINY))
+    return tiny.make_params(LongcatFlashForCausalLM, LongcatFlashConfig(**TINY), IDS, shake)
 
 
 @pytest.fixture(scope="module")
 def want(params):
     return np.asarray(reference.forward(params, jnp.asarray(IDS), SIZES))
-
-
-def full_forward(cfg, params, cls=LongcatFlashForCausalLM):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(cls(cfg).apply({"params": params}, jnp.asarray(IDS)))
 
 
 def test_preset_is_the_published_configuration():
@@ -141,7 +118,7 @@ def test_full_forward_equals_the_reference(params, want, held):
         assert distance(full_forward(LongcatFlashConfig(**TINY), params), want) <= TOL
         return
     cfg = LongcatFlashConfig(**dict(TINY, num_experts=16, experts_held_first=0, router_experts=None))
-    uncut = make_params(cfg)
+    uncut = tiny.make_params(LongcatFlashForCausalLM, cfg, IDS, shake)
     sizes = dict(SIZES, experts_held_first=0)
     assert distance(full_forward(cfg, uncut),
                     reference.forward(uncut, jnp.asarray(IDS), sizes)) <= TOL
@@ -204,7 +181,7 @@ def test_wrong_mathematics_fails(params, want, wrong):
         block = {**params["model"]["layers"]["block"],
                  "moe": {**params["model"]["layers"]["block"]["moe"], "router": router}}
         tree = {**params, "model": {**params["model"], "layers": {"block": block}}}
-    got = full_forward(cfg, tree, _wrong_model(**layer))
+    got = tiny.full_forward(_wrong_model(**layer), cfg, tree)
     assert distance(got, want) > margin > 100 * TOL
 
 
@@ -245,33 +222,6 @@ def test_what_the_layer_does_not_hold_is_refused(refused):
 
 # ------------------------------------------------------------- the serving path
 
-def serving_lm(params, cfg=None, page_size=8, model=LongcatFlashForCausalLM, **kw):
-    cfg = dataclasses.replace(cfg or LongcatFlashConfig(**TINY), moe_mode="capacity_factor")
-    return CausalLM(cfg, params, model, buckets=(32,), max_batch=4,
-                    page_size=page_size, prefix_cache=bool(page_size), **kw)
-
-
-def cached_logits(lm):
-    rows = len(LENS)
-    prompts = np.zeros((rows, int(LENS.max())), np.int32)
-    for i, n in enumerate(LENS):
-        prompts[i, :n] = IDS[i, :n]
-    session = lm.start_session()
-    kw = dict(reserve_tokens=STEPS + 1) if lm.paged else {}
-    with jax.default_matmul_precision("highest"):
-        got = [np.asarray(lm.insert(session, np.arange(rows), prompts, lengths=LENS, **kw))]
-        for t in range(STEPS):
-            tok = np.zeros((lm.max_batch,), np.int32)
-            tok[:rows] = IDS[np.arange(rows), LENS + t]
-            got.append(np.asarray(lm.step(session, tok))[:rows])
-    return np.stack(got), session
-
-
-def at_cached(want):
-    pick = LENS[:, None] - 1 + np.arange(STEPS + 1)[None, :]
-    return want[np.arange(len(LENS))[:, None], pick].transpose(1, 0, 2)
-
-
 @pytest.mark.parametrize("cache", ["paged", "slab"])
 def test_insert_and_decode_through_both_sub_layers_leaves_equal_the_reference(params, want, cache):
     """Prefill in the expanded form, then every decoded position in the
@@ -279,9 +229,9 @@ def test_insert_and_decode_through_both_sub_layers_leaves_equal_the_reference(pa
     reference's full forward (which never takes the absorbed form)."""
     world()
     with jax.default_matmul_precision("highest"):
-        lm = serving_lm(params, page_size=8 if cache == "paged" else None).compile()
-    got, session = cached_logits(lm)
-    assert distance(got, at_cached(want)) <= TOL
+        lm = tiny.compiled_lm(serving_lm, params, cache)
+    session = lm.start_session()
+    assert distance(cached_logits(lm, session=session), at_cached(want)) <= TOL
     if cache == "paged":
         # (touched, assigned, layer calls, every pick, identity picks, the passes' three)
         _, assigned, calls, routed, zero, *_ = np.asarray(session.insert_routing)
@@ -295,8 +245,8 @@ def test_sub_layer_1_reading_sub_layer_0s_leaf_fails(params, want):
     (0.94 of the reference's largest logit where the right leaves read 6e-7)."""
     world()
     with jax.default_matmul_precision("highest"):
-        lm = serving_lm(params, model=_wrong_model(leaf=(0, 0))).compile()
-    assert distance(cached_logits(lm)[0], at_cached(want)) > 0.45 > 100 * TOL
+        lm = tiny.serving_lm(_wrong_model(leaf=(0, 0)), params, LongcatFlashConfig(**TINY)).compile()
+    assert distance(cached_logits(lm), at_cached(want)) > 0.45 > 100 * TOL
 
 
 def test_serve_engine_counts_the_picks_that_cost_nothing_and_hits_a_latent_prefix(params):
@@ -308,9 +258,9 @@ def test_serve_engine_counts_the_picks_that_cost_nothing_and_hits_a_latent_prefi
     prompt = IDS[0, :20]
     longer = np.concatenate([prompt[:16], IDS[1, :6]])
     with jax.default_matmul_precision("highest"):
-        alone = serving_lm(params, page_size=None)       # generate() is the slab path's
+        alone = tiny.compiled_lm(serving_lm, params, "slab")   # generate() is the slab path's
         solo = [alone.generate(p[None], STEPS + 1).tokens[0] for p in (prompt, longer)]
-        lm = serving_lm(params).compile()
+        lm = tiny.compiled_lm(serving_lm, params)
         engine = ServeEngine(lm, block_steps=4, rng=jax.random.key(0))
         ids = []
         for p in (prompt, longer):      # one row live at a time

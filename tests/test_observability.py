@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
 from neuronx_distributed_tpu.inference.engine import _STAT_KEYS
@@ -49,6 +48,7 @@ from neuronx_distributed_tpu.observability import (
     validate_chrome_trace,
     validate_incident_bundle,
 )
+from tests import tiny
 
 TINY = dict(
     vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2,
@@ -61,9 +61,7 @@ K = 4
 @pytest.fixture(scope="module")
 def lm():
     cfg = LlamaConfig(**TINY)
-    ids = jnp.zeros((1, 8), jnp.int32)
-    params = meta.unbox(
-        LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     return CausalLM(cfg, params, LlamaForCausalLM, buckets=(8, 16),
                     max_batch=3).compile()
 
@@ -574,9 +572,7 @@ def test_multilora_observability_lanes_and_attribution():
     from neuronx_distributed_tpu.models.llama import LlamaForCausalLM
 
     cfg = LlamaConfig(**TINY)
-    ids0 = jnp.zeros((1, 8), jnp.int32)
-    params = meta.unbox(
-        LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids0))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     lm_l = CausalLM(cfg, params, LlamaForCausalLM, buckets=(8,),
                     max_batch=2, lora_rank=2, lora_slots=2).compile()
     acfg = LoraConfig(r=2, lora_alpha=4.0)

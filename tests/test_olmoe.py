@@ -14,19 +14,22 @@ weights moves the logits by 34 times that and leaving QK-norm out by 2 700
 times (``test_wrong_mathematics_fails``); the right mathematics sits at 3e-7.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from flax import linen as nn
-from flax.core import meta
 
 from benchmark.reference import olmoe as reference
-from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
+from neuronx_distributed_tpu.inference import ServeEngine
 from neuronx_distributed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM, dbrx
 from neuronx_distributed_tpu.models.olmoe import OlmoeConfig, OlmoeForCausalLM, olmoe_1b_7b
 from neuronx_distributed_tpu.parallel import mesh
 from neuronx_distributed_tpu.parallel.partitioning import specs_to_shardings
+from tests import tiny
+from tests.tiny import IDS, LENS, STEPS, at_cached, cached_logits, distance, world
 
 TOL = 1e-4
 TINY = dict(vocab_size=512, hidden_size=64, intermediate_size=32, num_layers=2, num_heads=4,
@@ -34,48 +37,21 @@ TINY = dict(vocab_size=512, hidden_size=64, intermediate_size=32, num_layers=2, 
             use_flash_attention=False, remat_policy=None, moe_mode="all_experts")
 SIZES = {"rms_norm_eps": 1e-5, "rope_theta": 10000.0, "num_experts_per_tok": 4,
          "norm_topk_prob": False}
-IDS = np.random.RandomState(0).randint(1, 512, (3, 24)).astype(np.int32)
-LENS = np.asarray([18, 12, 15])
-STEPS = 6
-
-
-def world(tp=1):
-    mesh.destroy_model_parallel()
-    mesh.initialize_model_parallel(tensor_model_parallel_size=tp, devices=jax.devices()[:tp])
-
-
-def make_params(cfg, model_cls=OlmoeForCausalLM):
-    """Host parameters of ``cfg``: the program's initialisers from a fixed
-    key, every norm scale multiplied by a seeded factor near one."""
-    params = meta.unbox(model_cls(cfg).init(jax.random.key(1), jnp.asarray(IDS)))["params"]
-
-    def shake(path, a):
-        name = jax.tree_util.keystr(path)
-        if "norm" in name:
-            return a * (1.0 + 0.3 * jax.random.normal(jax.random.key(len(name)), a.shape))
-        return a
-
-    return jax.tree.map(np.asarray, jax.tree_util.tree_map_with_path(shake, params))
-
-
-def distance(got, want):
-    return float(np.abs(np.asarray(got) - np.asarray(want)).max() / np.abs(want).max())
+full_forward = functools.partial(tiny.full_forward, OlmoeForCausalLM)
+# served with every expert computed, as the forward is: what is compared is the cache
+serving_lm = functools.partial(tiny.serving_lm, OlmoeForCausalLM, cfg=OlmoeConfig(**TINY),
+                               moe_mode="all_experts")
 
 
 @pytest.fixture(scope="module")
 def params():
     world()
-    return make_params(OlmoeConfig(**TINY))
+    return tiny.make_params(OlmoeForCausalLM, OlmoeConfig(**TINY), IDS, tiny.shake_norms)
 
 
 @pytest.fixture(scope="module")
 def want(params):
     return np.asarray(reference.forward(params, jnp.asarray(IDS), SIZES))
-
-
-def full_forward(cfg, params, model_cls=OlmoeForCausalLM):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(model_cls(cfg).apply({"params": params}, jnp.asarray(IDS)))
 
 
 def test_preset_is_the_published_configuration():
@@ -103,39 +79,11 @@ def test_wrong_mathematics_fails(params, want, wrong):                          
         assert distance(got, np.asarray(renorm)) <= TOL
 
 
-def serving_lm(params, cfg=None, **kw):
-    return CausalLM(cfg or OlmoeConfig(**TINY), params, OlmoeForCausalLM, buckets=(32,),
-                    max_batch=4, page_size=8, prefix_cache=True, **kw)
-
-
-def cached_logits(lm):
-    """Prefill of IDS[:, :LENS] then STEPS teacher-forced decode steps
-    through the paged cache: logits (STEPS + 1, rows, vocab)."""
-    rows = len(LENS)
-    prompts = np.zeros((rows, int(LENS.max())), np.int32)
-    for i, n in enumerate(LENS):
-        prompts[i, :n] = IDS[i, :n]
-    session = lm.start_session()
-    with jax.default_matmul_precision("highest"):
-        got = [np.asarray(lm.insert(session, np.arange(rows), prompts, lengths=LENS,
-                                    reserve_tokens=STEPS + 1))]
-        for t in range(STEPS):
-            tok = np.zeros((lm.max_batch,), np.int32)
-            tok[:rows] = IDS[np.arange(rows), LENS + t]
-            got.append(np.asarray(lm.step(session, tok))[:rows])
-    return np.stack(got)
-
-
-def reference_at_cached_positions(want):
-    pick = LENS[:, None] - 1 + np.arange(STEPS + 1)[None, :]
-    return want[np.arange(len(LENS))[:, None], pick].transpose(1, 0, 2)
-
-
 def test_prefill_and_decode_through_the_paged_cache_equal_the_reference(params, want):   # (b)
     world()
     with jax.default_matmul_precision("highest"):
-        lm = serving_lm(params).compile()
-    assert distance(cached_logits(lm), reference_at_cached_positions(want)) <= TOL
+        lm = tiny.compiled_lm(serving_lm, params)
+    assert distance(cached_logits(lm), at_cached(want)) <= TOL
 
 
 def test_serve_engine_fused_blocks_follow_the_reference(params):                 # (b)
@@ -145,7 +93,7 @@ def test_serve_engine_fused_blocks_follow_the_reference(params):                
     to within the tolerance."""
     world()
     with jax.default_matmul_precision("highest"):
-        lm = serving_lm(params).compile()
+        lm = tiny.compiled_lm(serving_lm, params)
         engine = ServeEngine(lm, block_steps=4, rng=jax.random.key(0))
         prompts = [IDS[i, :n] for i, n in enumerate(LENS)]
         ids = [engine.submit(p, max_new_tokens=STEPS + 1, arrival_block=0) for p in prompts]
@@ -177,7 +125,7 @@ def test_tensor_parallel_logits_equal_tp1(params, want, tp):                    
             sharded, jnp.asarray(IDS)))
         assert distance(got, want) <= TOL
         lm = serving_lm(sharded).compile()
-    assert distance(cached_logits(lm), reference_at_cached_positions(want)) <= TOL
+    assert distance(cached_logits(lm), at_cached(want)) <= TOL
 
 
 OFF = {
@@ -192,10 +140,10 @@ def test_switched_off_there_is_no_parameter_and_no_op(name):                    
     world()
     cfg = OFF[name]
     assert not cfg.qk_norm and cfg.norm_topk_prob
-    off = make_params(cfg, MixtralForCausalLM)
+    off = tiny.make_params(MixtralForCausalLM, cfg, IDS, tiny.shake_norms)
     leaves = [jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_leaves_with_path(off)]
     assert not [n for n in leaves if "q_norm" in n or "k_norm" in n]
-    lm = CausalLM(cfg, off, MixtralForCausalLM, buckets=(32,), max_batch=4, page_size=8)
+    lm = tiny.serving_lm(MixtralForCausalLM, off, cfg, moe_mode="all_experts")
     # (switched on, tests/test_scope_names.py reads the scope back from OLMoE's step)
     assert "qk_norm" not in lm.compile_session_decode_fused(4).as_text()
 
@@ -241,8 +189,7 @@ def test_a_dense_model_counts_nothing_and_returns_what_it_did():
     world()
     dense = {k: v for k, v in TINY.items() if k not in ("num_experts", "top_k", "moe_mode")}
     cfg = LlamaConfig(**dense)
-    weights = meta.unbox(LlamaForCausalLM(cfg).init(jax.random.key(1), jnp.asarray(IDS)))["params"]
-    lm = CausalLM(cfg, weights, LlamaForCausalLM, buckets=(32,), max_batch=4, page_size=8)
+    lm = tiny.serving_lm(LlamaForCausalLM, tiny.make_params(LlamaForCausalLM, cfg, IDS), cfg)
     assert not lm.moe_stats
     assert len(jax.tree.leaves(lm.compile_session_decode_fused(4).out_info)) == len(
         jax.tree.leaves(lm._cache_avals())) + 5     # rows, how far it read

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
 from neuronx_distributed_tpu.inference.engine import Request
@@ -42,6 +41,7 @@ from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "scripts"))
 import soak as soak_mod  # noqa: E402
+from tests import tiny  # noqa: E402
 
 
 # --------------------------------------------------------------- references
@@ -257,9 +257,7 @@ TINY = dict(
 @pytest.fixture(scope="module")
 def real_lm():
     cfg = LlamaConfig(**TINY, page_size=4, page_pool_pages=40)
-    ids = jnp.zeros((1, 8), jnp.int32)
-    params = meta.unbox(
-        LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     return CausalLM(cfg, params, LlamaForCausalLM, buckets=(8, 16),
                     max_batch=3).compile()
 

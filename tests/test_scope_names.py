@@ -16,10 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from benchmark import trace_parts
-from neuronx_distributed_tpu.inference import CausalLM
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.trainer import (
     create_train_state,
@@ -28,6 +26,7 @@ from neuronx_distributed_tpu.trainer import (
     make_train_step,
     neuronx_distributed_config,
 )
+from tests import tiny
 
 # scope names by the file that opens them (PERF.md section 3 lists the same)
 SCOPES = {
@@ -120,18 +119,17 @@ def unnamed_share(parts) -> float:
 
 @pytest.fixture(scope="module")
 def params():
-    cfg = LlamaConfig(**TINY)
-    return meta.unbox(LlamaForCausalLM(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    return tiny.make_params(LlamaForCausalLM, LlamaConfig(**TINY), seed=0)
 
 
-def serving_lm(params):
-    return CausalLM(LlamaConfig(**TINY), params, LlamaForCausalLM, buckets=(128,), max_batch=2,
-                    page_size=16)
+def lm_of(model_cls, cfg, params=None, **kw):
+    """Two slots behind pages of 16 and one bucket of 128; seeded weights unless given."""
+    params = tiny.make_params(model_cls, cfg, seed=0) if params is None else params
+    return tiny.serving_lm(model_cls, params, cfg, buckets=(128,), max_batch=2, page_size=16, **kw)
 
 
 def test_fused_decode_names_its_regions(params):
-    lm = serving_lm(params)
+    lm = lm_of(LlamaForCausalLM, LlamaConfig(**TINY), params)
     components, parts = census(lm.compile_session_decode_fused(4))
     want = SCOPES["inference/causal_lm.py fused_fn"] + ["kv_write", "attend", "kv_gather"]
     assert set(want) <= components
@@ -152,8 +150,7 @@ def test_the_walk_keeps_kv_gather_and_attend_on_the_ops_that_do_the_work(params,
     the loop's body or the switch's branches, and are still ``kv_gather`` and
     ``attend`` to ``trace_parts.py``; the bound's own arithmetic is the
     attention's, and the counter of what was read is ``bookkeeping``."""
-    lm = CausalLM(LlamaConfig(**dict(TINY, max_seq_len=seq)), params, LlamaForCausalLM,
-                  buckets=(128,), max_batch=2, page_size=16)
+    lm = lm_of(LlamaForCausalLM, LlamaConfig(**dict(TINY, max_seq_len=seq)), params)
     text = lm.compile_session_decode_fused(4).as_text()
     found = collections.Counter()
     for line in text.splitlines():
@@ -181,9 +178,7 @@ def test_olmoe_names_qk_norm_router_and_experts(program):
 
     cfg = OlmoeConfig(**dict(TINY, num_kv_heads=4, intermediate_size=32, num_experts=16,
                              top_k=4, use_flash_attention=False))
-    olmoe = meta.unbox(OlmoeForCausalLM(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
-    lm = CausalLM(cfg, olmoe, OlmoeForCausalLM, buckets=(128,), max_batch=2, page_size=16)
+    lm = lm_of(OlmoeForCausalLM, cfg)
     compiled = (lm.compile_session_decode_fused(4) if program == "fused_decode"
                 else lm._paged_insert_programs(2, 128))
     components, parts = census(compiled)
@@ -209,9 +204,7 @@ def test_deepseek_v2_names_latent_attention_router_groups_and_shared_expert(prog
         qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8, moe_intermediate_size=32,
         router_experts=16, num_experts=4, n_group=4, topk_group=2, top_k=4,
         use_flash_attention=False))
-    weights = meta.unbox(DeepseekV2ForCausalLM(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
-    lm = CausalLM(cfg, weights, DeepseekV2ForCausalLM, buckets=(128,), max_batch=2, page_size=16)
+    lm = lm_of(DeepseekV2ForCausalLM, cfg)
     decode = program == "fused_decode"
     compiled = (lm.compile_session_decode_fused(4) if decode
                 else lm._paged_insert_programs(2, 128))
@@ -241,10 +234,7 @@ def test_granite_names_the_mixers_stages_and_the_step_kernel(program):
     cfg = GraniteHybridConfig(**dict(
         TINY, num_layers=4, layer_types=["mamba", "attention"] * 2, mamba_n_heads=8,
         mamba_d_head=8, mamba_d_state=16, mamba_chunk_size=8, use_flash_attention=False))
-    weights = meta.unbox(GraniteHybridForCausalLM(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
-    lm = CausalLM(cfg, weights, GraniteHybridForCausalLM, buckets=(128,), max_batch=2,
-                  page_size=16, prefix_cache=False)
+    lm = lm_of(GraniteHybridForCausalLM, cfg, prefix_cache=False)
     decode = program == "fused_decode"
     compiled = (lm.compile_session_decode_fused(4) if decode
                 else lm._paged_insert_programs(2, 128))
@@ -282,10 +272,7 @@ def test_laguna_names_the_ring_the_window_read_the_gate_and_both_ropes(program):
                                     original_max_position_embeddings=64, attention_factor=1.2,
                                     partial_rotary_factor=0.5),
                          SLIDING: dict(rope_type="default", rope_theta=1e4)}))
-    weights = meta.unbox(LagunaForCausalLM(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
-    lm = CausalLM(cfg, weights, LagunaForCausalLM, buckets=(128,), max_batch=2, page_size=16,
-                  prefix_cache=False)
+    lm = lm_of(LagunaForCausalLM, cfg, prefix_cache=False)
     decode = program == "fused_decode"
     compiled = (lm.compile_session_decode_fused(4) if decode
                 else lm._paged_insert_programs(2, 128))
@@ -318,9 +305,7 @@ def test_longcat_flash_names_the_branch_the_identity_sum_the_bias_and_the_scales
         TINY, num_kv_heads=4, kv_lora_rank=16, q_lora_rank=24, qk_nope_head_dim=8,
         qk_rope_head_dim=8, v_head_dim=8, moe_intermediate_size=32, router_experts=16,
         num_experts=4, zero_experts=8, top_k=6, use_flash_attention=False))
-    weights = meta.unbox(LongcatFlashForCausalLM(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
-    lm = CausalLM(cfg, weights, LongcatFlashForCausalLM, buckets=(128,), max_batch=2, page_size=16)
+    lm = lm_of(LongcatFlashForCausalLM, cfg)
     decode = program == "fused_decode"
     compiled = (lm.compile_session_decode_fused(4) if decode
                 else lm._paged_insert_programs(2, 128))
@@ -361,9 +346,7 @@ def test_deepseek_v32_names_the_indexer_the_scores_and_the_choice(program):
         moe_intermediate_size=32, router_experts=16, num_experts=4, n_group=4, topk_group=2,
         top_k=4, index_topk=32, index_n_heads=2, index_head_dim=16, index_block_q=64,
         max_seq_len=512, use_flash_attention=False))
-    weights = meta.unbox(DeepseekV32ForCausalLM(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
-    lm = CausalLM(cfg, weights, DeepseekV32ForCausalLM, buckets=(128,), max_batch=2, page_size=16)
+    lm = lm_of(DeepseekV32ForCausalLM, cfg)
     decode = program == "fused_decode"
     compiled = (lm.compile_session_decode_fused(4) if decode
                 else lm._paged_insert_programs(2, 128))
@@ -390,12 +373,12 @@ def test_deepseek_v32_names_the_indexer_the_scores_and_the_choice(program):
 
 
 def test_dense_decode_has_no_qk_norm_scope(params):
-    components, parts = census(serving_lm(params).compile_session_decode_fused(4))
+    components, parts = census(lm_of(LlamaForCausalLM, LlamaConfig(**TINY), params).compile_session_decode_fused(4))
     assert "qk_norm" not in components and parts["router"] == 0 and parts["experts"] == 0
 
 
 def test_paged_insert_names_its_regions(params):
-    components, parts = census(serving_lm(params)._paged_insert_programs(2, 128))
+    components, parts = census(lm_of(LlamaForCausalLM, LlamaConfig(**TINY), params)._paged_insert_programs(2, 128))
     want = (SCOPES["inference/causal_lm.py insert_fn"]
             + SCOPES["models/llama.py _decode_attention"] + ["flash_fwd"])
     assert set(want) <= components

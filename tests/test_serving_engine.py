@@ -22,11 +22,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM, Sampler, ServeEngine
 from neuronx_distributed_tpu.inference.replay import run_trace, synthetic_trace
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from tests import tiny
 
 TINY = dict(
     vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2,
@@ -38,9 +38,7 @@ K = 4  # the one fused block size tier-1 compiles
 
 def _make_lm(max_batch=3, buckets=(8, 16), seed=0, **over):
     cfg = LlamaConfig(**{**TINY, **over})
-    ids = jnp.zeros((1, 8), jnp.int32)
-    params = meta.unbox(
-        LlamaForCausalLM(cfg).init(jax.random.PRNGKey(seed), ids))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=seed)
     return CausalLM(cfg, params, LlamaForCausalLM, buckets=buckets,
                     max_batch=max_batch).compile()
 

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import (
     CausalLM,
@@ -39,6 +38,7 @@ from neuronx_distributed_tpu.inference import (
 )
 from neuronx_distributed_tpu.inference.replay import run_trace, synthetic_trace
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from tests import tiny
 
 TINY = dict(
     vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2,
@@ -57,9 +57,7 @@ CHAOS_PLAN = dict(seed=1, pool_exhaust_prob=0.3, pool_storm_len=2,
 def stack():
     """(config, params, contiguous lm, paged lm) over ONE weight set."""
     cfg = LlamaConfig(**TINY)
-    ids = jnp.zeros((1, 8), jnp.int32)
-    params = meta.unbox(
-        LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     lm_c = CausalLM(cfg, params, LlamaForCausalLM, buckets=(8, 16),
                     max_batch=3).compile()
     lm_p = CausalLM(cfg, params, LlamaForCausalLM, buckets=(8, 16),

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from neuronx_distributed_tpu.inference import CausalLM, Sampler, ServeEngine
 from neuronx_distributed_tpu.inference.faults import FaultPlan
@@ -45,6 +44,7 @@ from neuronx_distributed_tpu.inference.grammar import (
 )
 from neuronx_distributed_tpu.inference.router import Router
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from tests import tiny
 
 TINY = dict(
     vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=2,
@@ -66,9 +66,7 @@ SPECS = {"gnum": {"regex": NUM_RE}, "gab": {"regex": AB_RE},
 @pytest.fixture(scope="module")
 def base():
     cfg = LlamaConfig(**TINY)
-    ids = jnp.zeros((1, 8), jnp.int32)
-    params = meta.unbox(
-        LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids))["params"]
+    params = tiny.make_params(LlamaForCausalLM, cfg, seed=0)
     return cfg, params
 
 

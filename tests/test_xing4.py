@@ -16,15 +16,15 @@ No "the shares add up" test is owed: every routed expert is held, no share is ta
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from benchmark.reference import xing4 as reference
-from neuronx_distributed_tpu.inference import CausalLM, ServeEngine
+from neuronx_distributed_tpu.inference import ServeEngine
 from neuronx_distributed_tpu.models import xing4
 from neuronx_distributed_tpu.models.deepseek_v2 import DeepseekV2Config, DeepseekV2ForCausalLM
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
@@ -32,7 +32,8 @@ from neuronx_distributed_tpu.models.llama_pipeline import PipelinedLlama
 from neuronx_distributed_tpu.models.longcat_flash import LongcatFlashConfig, LongcatFlashForCausalLM
 from neuronx_distributed_tpu.models.xing4 import Xing4Config, Xing4ForCausalLM
 from neuronx_distributed_tpu.ops.stream_mix import StreamMix, sinkhorn_planes
-from neuronx_distributed_tpu.parallel import mesh
+from tests import tiny
+from tests.tiny import IDS, at_cached, cached_logits, distance, world
 
 TOL = 2e-5
 YARN = {"type": "yarn", "factor": 64, "original_max_position_embeddings": 16, "beta_fast": 32,
@@ -49,50 +50,26 @@ SIZES = {"rms_norm_eps": 1e-6, "rope_theta": 10000.0, "rope_scaling": YARN,
          "routed_scaling_factor": 2.0, "norm_topk_prob": True, "hc_mult": 4,
          "hc_sinkhorn_iters": 20, "hc_eps": 1e-6, "mhc_h_res_clamp_min": -30,
          "mhc_h_res_clamp_max": 30}
-IDS = np.random.RandomState(0).randint(1, 512, (3, 24)).astype(np.int32)
-LENS = np.asarray([18, 12, 15])
-STEPS = 6
 SUB_BLOCKS = 2 * 3          # stream mixes a token passes: two a layer, three layers
+full_forward = functools.partial(tiny.full_forward, Xing4ForCausalLM)
+serving_lm = functools.partial(tiny.serving_lm, Xing4ForCausalLM, cfg=Xing4Config(**TINY))
 
 
-def world():
-    mesh.destroy_model_parallel()
-    mesh.initialize_model_parallel(tensor_model_parallel_size=1, devices=jax.devices()[:1])
-
-
-def make_params(cfg):
-    params = meta.unbox(Xing4ForCausalLM(cfg).init(jax.random.key(1), jnp.asarray(IDS)))["params"]
-
-    def shake(path, a):
-        name = jax.tree_util.keystr(path)
-        noise = jax.random.normal(jax.random.key(len(name)), a.shape)
-        if "e_score_correction_bias" in name:
-            return a + 0.1 * noise
-        if "norm" in name:
-            return a * (1.0 + 0.3 * noise)
-        return a
-
-    return jax.tree.map(np.asarray, jax.tree_util.tree_map_with_path(shake, params))
-
-
-def distance(got, want):
-    return float(np.abs(np.asarray(got) - np.asarray(want)).max() / np.abs(want).max())
+def shake(name, a):
+    if "e_score_correction_bias" in name:
+        return a + 0.1 * tiny.noise(name, a)
+    return tiny.shake_norms(name, a)
 
 
 @pytest.fixture(scope="module")
 def params():
     world()
-    return make_params(Xing4Config(**TINY))
+    return tiny.make_params(Xing4ForCausalLM, Xing4Config(**TINY), IDS, shake)
 
 
 @pytest.fixture(scope="module")
 def want(params):
     return np.asarray(reference.forward(params, jnp.asarray(IDS), SIZES))
-
-
-def full_forward(cfg, params, ids=IDS):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(Xing4ForCausalLM(cfg).apply({"params": params}, jnp.asarray(ids)))
 
 
 def test_preset_is_the_published_configuration():
@@ -176,11 +153,12 @@ def test_wrong_mathematics_fails(params, want, got, wrong, monkeypatch):
     cannot agree with it."""
     name, planted = WRONG[wrong]
     monkeypatch.setattr(reference, name, planted)
-    MIX_COEFF.clear_cache()     # the one jitted piece that looks a part (``sinkhorn``) up when traced
-    try:
-        faulty = np.asarray(reference.forward(params, jnp.asarray(IDS), SIZES))
-    finally:
-        MIX_COEFF.clear_cache()
+    if name == "sinkhorn":
+        # ``mix_coeff`` is jitted and looks ``sinkhorn`` up when TRACED: a jit of a
+        # function made for this case traces anew, whatever the worker traced before
+        monkeypatch.setattr(reference, "mix_coeff", jax.jit(
+            lambda *args: MIX_COEFF.__wrapped__(*args), static_argnums=(2, 3, 4, 5)))
+    faulty = np.asarray(reference.forward(params, jnp.asarray(IDS), SIZES))
     assert distance(faulty, want) > 100 * TOL
     assert distance(got, want) <= TOL < 100 * TOL < distance(got, faulty)
 
@@ -299,27 +277,6 @@ def test_the_seeded_mix_is_not_the_uniform_one(params):
 
 # ------------------------------------------------------------- the serving path
 
-def serving_lm(params, cfg=None, page_size=8, buckets=(32,), **kw):
-    cfg = dataclasses.replace(cfg or Xing4Config(**TINY), moe_mode="capacity_factor")
-    return CausalLM(cfg, params, Xing4ForCausalLM, buckets=buckets, max_batch=4,
-                    page_size=page_size, prefix_cache=True, **kw)
-
-
-def cached_logits(lm, session, ids=IDS, lens=LENS, steps=STEPS):
-    rows = len(lens)
-    prompts = np.zeros((rows, int(lens.max())), np.int32)
-    for i, n in enumerate(lens):
-        prompts[i, :n] = ids[i, :n]
-    kw = dict(reserve_tokens=steps + 1) if lm.paged else {}
-    with jax.default_matmul_precision("highest"):
-        got = [np.asarray(lm.insert(session, np.arange(rows), prompts, lengths=lens, **kw))]
-        for t in range(steps):
-            tok = np.zeros((lm.max_batch,), np.int32)
-            tok[:rows] = ids[np.arange(rows), lens + t]
-            got.append(np.asarray(lm.step(session, tok))[:rows])
-    return np.stack(got)
-
-
 @pytest.mark.parametrize("cache", ["paged", "slab"])
 def test_insert_and_decode_through_the_latent_cache_equal_the_reference(params, want, cache):
     """Prefill, then every decoded position in the absorbed form with the
@@ -327,11 +284,9 @@ def test_insert_and_decode_through_the_latent_cache_equal_the_reference(params, 
     fourth prompt then shares a page of row 0's prefix and reads the same."""
     world()
     with jax.default_matmul_precision("highest"):
-        lm = serving_lm(params, page_size=8 if cache == "paged" else None).compile()
+        lm = tiny.compiled_lm(serving_lm, params, cache)
     session = lm.start_session()
-    pick = LENS[:, None] - 1 + np.arange(STEPS + 1)[None, :]
-    at_cached = want[np.arange(len(LENS))[:, None], pick].transpose(1, 0, 2)
-    assert distance(cached_logits(lm, session), at_cached) <= TOL
+    assert distance(cached_logits(lm, session=session), at_cached(want)) <= TOL
     if cache == "slab":
         return
     shared = np.concatenate([IDS[0, :16], np.random.RandomState(9).randint(1, 512, (7,))]).astype(np.int32)
@@ -355,9 +310,9 @@ def test_serve_engine_hits_a_prefix_parks_resumes_and_counts_the_mixes(params, t
                 for n in (5, 9)]
     budget = 2 * 4 + 3
     with jax.default_matmul_precision("highest"):
-        alone = serving_lm(params, page_size=None)       # generate() is the slab path's
+        alone = tiny.compiled_lm(serving_lm, params, "slab")   # generate() is the slab path's
         solo = [alone.generate(p[None], budget).tokens[0] for p in prompts]
-        lm = serving_lm(params).compile()
+        lm = tiny.compiled_lm(serving_lm, params)
         engine = ServeEngine(lm, block_steps=4, rng=jax.random.key(0),
                              park_dir=str(tmp_path / "park"))
         ids = [engine.submit(p, max_new_tokens=budget, arrival_block=0) for p in prompts[:4]]
@@ -395,8 +350,7 @@ def test_serve_engine_hits_a_prefix_parks_resumes_and_counts_the_mixes(params, t
 # -------------------------------------------------------------- the refusals
 
 def _under_tp(params):
-    mesh.destroy_model_parallel()
-    mesh.initialize_model_parallel(tensor_model_parallel_size=2, devices=jax.devices()[:2])
+    world(tp=2)
     try:
         Xing4ForCausalLM(Xing4Config(**TINY)).apply({"params": params}, jnp.asarray(IDS))
     finally:
@@ -462,10 +416,10 @@ def test_without_hc_mult_the_decode_block_holds_nothing_of_the_mix(family, monke
         return step(self, carry, *args, **kw)
 
     monkeypatch.setattr(llama._LayerStep, "__call__", watching)
-    params = meta.unbox(model(cfg).init(jax.random.key(0), jnp.asarray(IDS)))["params"]
+    params = tiny.make_params(model, cfg, IDS, seed=0)
     names = {jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_flatten_with_path(params)[0]}
     assert not any("mix" in n or "phi" in n for n in names)
-    lm = CausalLM(cfg, params, model, buckets=(32,), max_batch=4, page_size=8)
+    lm = tiny.serving_lm(model, params, cfg)
     lowered = []
     monkeypatch.setattr(jax.stages.Lowered, "compile",
                         lambda self, *a, **k: lowered.append(self.as_text(debug_info=True)) or 1 / 0)

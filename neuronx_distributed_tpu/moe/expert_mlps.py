@@ -249,15 +249,20 @@ def _grouped_whole(x, weight, order, place, group_sizes, live, layer, gate, up, 
     a = grouped_matmul(xs, (gate, up) if glu else (gate,), layer, visits, tm,
                        _silu_gated if glu else nn.gelu, interpret=interpret)
     out = grouped_matmul(a, (down,), layer, visits, tm, interpret=interpret)
-    # back to (token, choice) order; the rows of no group hold whatever the
-    # kernel's buffer held, so they are selected away, not scaled
-    out = out.at[place].get(mode="promise_in_bounds", unique_indices=True
-                            ).reshape(T, top_k, H)
-    # (under ``share`` a choice of an absent expert sits in no group either)
-    real = (live[:, None, None] & (weight > 0)[:, :, None]) if share else live[:, None, None]
-    out = jnp.where(real, out, 0)
-    return jnp.einsum("tkh,tk->th", out.astype(jnp.float32),
-                      weight.astype(jnp.float32))
+    # back to the tokens in CHOICE-major order, (top_k, T, H): the leading
+    # axis splits for nothing and its float32 sum is one fusion over the
+    # gather's rows as they lie. As (T, top_k, H) the few choices sit on the
+    # axis the TPU tiles and the array is copied whole into that tiling
+    # (112 MiB a Xing layer of an 8 x 512 insert; PERF.md, PR 61)
+    out = out.at[place.reshape(T, top_k).T.reshape(T * top_k)].get(
+        mode="promise_in_bounds", unique_indices=True).reshape(top_k, T, H)
+    # the rows of no group hold whatever the kernel's buffer held, so they are
+    # selected away, not scaled (under ``share`` a choice of an absent expert
+    # sits in no group either)
+    real = (live[None, :] & (weight.T > 0)) if share else live[None, :]
+    out = jnp.where(real[:, :, None], out, 0)
+    return jnp.einsum("kth,kt->th", out.astype(jnp.float32),
+                      weight.T.astype(jnp.float32))
 
 
 def _grouped_share(x, weight, order, place, group_sizes, live, layer, gate, up, down,
@@ -279,7 +284,12 @@ def _grouped_share(x, weight, order, place, group_sizes, live, layer, gate, up, 
     selected away (never scaled: its index points at some row of the buffer),
     all added in ONE fusion. XLA fuses no gather into a reduction, so one
     gather of ``(top_k, T, H)`` is written out whole; the slots' read the same
-    bytes in pieces and cost the same (2.32 against 2.30 ms)."""
+    bytes in pieces and cost the same (2.32 against 2.30 ms). The whole form
+    (:func:`_grouped_whole`) reads it so too: its one gather IS written out,
+    choice-major, and one fusion sums the leading axis of ``(top_k, T, H)``
+    (token-major the compiler copies the gather into ``(T, top_k, H)``'s
+    tiling first; its slots taken one by one in Python make two fusions with
+    a float32 ``(T, H)`` array between them: PERF.md, PR 61)."""
     T, k = weight.shape
     E = group_sizes.shape[0]
     x = x.astype(dtype)

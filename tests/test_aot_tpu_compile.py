@@ -303,6 +303,34 @@ def _moe_config(family, **kw):
     return preset(**kw), cls
 
 
+def _compiled_moe_step(chip, family, width, **kw):
+    """``(cfg, compiled)`` of two scanned layers of a MoE family at its
+    published widths, bf16, pages of 16, batch 8, ``max_seq_len`` 1024: the
+    model's part of a decode step (``width`` 1) or of an 8 x ``width`` paged
+    insert, told which tokens are real as the serving programs tell it."""
+    b, s_max, page = 8, 1024, 16
+    preset, cls = _moe_config(
+        family, num_layers=2, max_seq_len=s_max, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, **kw)
+    cfg = dataclasses.replace(preset, decode=True, remat_policy=None,
+                              page_size=page,
+                              page_pool_pages=b * s_max // page + b)
+    model = cls(cfg)
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        meta.unbox(jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((b, 1), jnp.int32)))))
+
+    def step(params, cache, ids, live):
+        return model.apply({"params": params, "cache": cache}, ids,
+                           live=live, mutable=["cache"])
+
+    return cfg, jax.jit(step, donate_argnums=(1,)).lower(
+        variables["params"], variables["cache"],
+        jax.ShapeDtypeStruct((b, width), jnp.int32, sharding=chip),
+        jax.ShapeDtypeStruct((b, width), jnp.bool_, sharding=chip)).compile()
+
+
 @pytest.mark.parametrize("width", [1, 512], ids=["decode_step", "insert_8x512"])
 @pytest.mark.parametrize("family", ["olmoe", "mixtral"])
 def test_moe_serving_runs_the_grouped_kernel_and_holds_less(chip, family, width):
@@ -322,29 +350,10 @@ def test_moe_serving_runs_the_grouped_kernel_and_holds_less(chip, family, width)
     temporaries are no larger than those of the same program with
     ``moe_mode="all_experts"``, which is what every serving program ran
     before PR 29 (MiB, grouped / all_experts: printed below)."""
-    b, s_max, page = 8, 1024, 16
+    b = 8
     temporaries = {}
     for moe_mode in ("capacity_factor", "all_experts"):
-        preset, cls = _moe_config(
-            family, num_layers=2, max_seq_len=s_max, dtype=jnp.bfloat16,
-            param_dtype=jnp.bfloat16, moe_mode=moe_mode)
-        cfg = dataclasses.replace(preset, decode=True, remat_policy=None,
-                                  page_size=page,
-                                  page_pool_pages=b * s_max // page + b)
-        model = cls(cfg)
-        variables = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
-            meta.unbox(jax.eval_shape(lambda: model.init(
-                jax.random.key(0), jnp.zeros((b, 1), jnp.int32)))))
-
-        def step(params, cache, ids, live):
-            return model.apply({"params": params, "cache": cache}, ids,
-                               live=live, mutable=["cache"])
-
-        compiled = jax.jit(step, donate_argnums=(1,)).lower(
-            variables["params"], variables["cache"],
-            jax.ShapeDtypeStruct((b, width), jnp.int32, sharding=chip),
-            jax.ShapeDtypeStruct((b, width), jnp.bool_, sharding=chip)).compile()
+        cfg, compiled = _compiled_moe_step(chip, family, width, moe_mode=moe_mode)
         text = compiled.as_text()
         if family == "olmoe":
             assert "qk_norm" in text
@@ -366,6 +375,28 @@ def test_moe_serving_runs_the_grouped_kernel_and_holds_less(chip, family, width)
     if width > 1:   # less than ONE array of the all-experts activations
         assert temporaries["capacity_factor"] < (
             cfg.num_experts * b * width * cfg.intermediate_size * 2)
+
+
+@pytest.mark.parametrize("top_k", [2, 4], ids=["top2", "top4"])
+def test_an_insert_that_holds_every_expert_writes_no_retiled_picks(chip, top_k):
+    """The 8 x 512 insert at Mixtral's widths, top-2 as published and top-4
+    (Xing's and DBRX's): ``_grouped_whole`` sums a token's picks over the
+    LEADING axis of the output gather's ``(top_k, T, H)`` rows. Summed as
+    ``(T, top_k, H)`` the few choices lie on the axis the TPU tiles, and the
+    compiler wrote the whole array anew in a ``T(2,128)`` / ``T(4,128)``
+    tiling before the sum: a ``reshape`` of 64 MiB a Mixtral layer, 112 MiB a
+    Xing layer, 3.5 % of ``xing4.0-29b-a4b.score``'s device time (PERF.md,
+    PR 61). No op of the compiled program has that shape (the text
+    ``scripts/big_ops.py`` reads), the two gathers and the float32 sum's
+    ``(T, H)`` result are still there."""
+    cfg, compiled = _compiled_moe_step(chip, "mixtral", 512, top_k=top_k)
+    text = compiled.as_text()
+    T, H = 8 * 512, cfg.hidden_size
+    assert text.count("tpu_custom_call") >= 2
+    assert f"bf16[{T * top_k},{H}]" in text and f"[{T},{H}]" in text
+    retiled = [line.split(" = ")[0].strip() for line in text.splitlines()
+               if re.search(rf"= \w+\[{T},{top_k},{H}\]", line)]
+    assert not retiled, retiled
 
 
 def test_mixtral_insert_holds_no_logits_but_the_last_positions(chip):

@@ -323,6 +323,15 @@ def test_group_sizes_sum_to_the_live_assignments(E, k, T, masked):
 
 # --- a held share of a wider router's picks: the compact passes -------------
 
+def _combine_of(picks, routed, rs):
+    """``combine (T, routed)`` float32: a weight in 0.1-1.0 at each of a
+    token's ``picks (T, k)``, zero elsewhere."""
+    combine = np.zeros((len(picks), routed), np.float32)
+    np.put_along_axis(combine, picks, rs.uniform(0.1, 1.0, picks.shape).astype(np.float32),
+                      axis=1)
+    return combine
+
+
 def _share_case(case):
     """``(T, k, held, routed, combine (T, held), live (T,) or None)`` of a
     grouped call that holds ``held`` of ``routed`` experts: each token picks
@@ -340,9 +349,7 @@ def _share_case(case):
         elif case == "sliced":              # three slices of GROUPED_TOKENS (patched to 512)
             T = 1300
         picks = np.stack([rs.permutation(routed)[:k] for _ in range(T)])
-    combine = np.zeros((T, routed), np.float32)
-    np.put_along_axis(combine, picks, rs.uniform(0.1, 1.0, (T, k)).astype(np.float32), axis=1)
-    return T, k, held, routed, combine[:, :held], live
+    return T, k, held, routed, _combine_of(picks, routed, rs)[:, :held], live
 
 
 @pytest.mark.parametrize("case", ["an_eighth_here", "all_here", "none_here",
@@ -398,14 +405,15 @@ def test_a_held_share_goes_by_compact_passes(case, monkeypatch):
     assert chosen.sum() <= multiplied <= handled + 64 * held * sum(passes)
 
 
-# sha1 of ``_grouped_experts``' lowered text at the parent of PR 51 (commit
-# b0ce4da, computed there by the same lines): a layer that holds every expert,
-# and a share's call of one tile, are the programs they were.
+# sha1 of ``_grouped_experts``' lowered text (computed by the same lines at
+# PR 61, whose choice-major combine is the one change to ``_grouped_whole``
+# since PR 51's parent): a layer that holds every expert, and a share's call
+# of one tile, are ONE program each, whatever is done to ``_grouped_share``.
 PARENTS_GROUPED = {
-    (512, 2, 8, None): "609ef73d254e78bd455b4e0f2a24d6827c5df565",
-    (1024, 8, 64, None): "85bd3caa1c611009efd702a673e99b0c19925352",
-    (8, 4, 4, 32): "f8e1034140f8ab20782c5a8a46f82daf51e56768",
-    (16, 4, 4, 32): "8474b466f3f11a3690e13801398b1ec8bb85961a",
+    (512, 2, 8, None): "b2f6b63fa241f0c1fb49d8832c5a10512d033f14",
+    (1024, 8, 64, None): "0bcae1d22426f83500ab9dda853bf5e198b6adb8",
+    (8, 4, 4, 32): "79152dcc941782c99c8b9618e92712cae1454212",
+    (16, 4, 4, 32): "c70888689409e6a4ab96ac5b47c532f9515054c1",
 }
 
 
@@ -446,6 +454,88 @@ def test_row_bound_is_twice_the_share_and_a_tile_at_least(tokens, top_k, held, r
     assert got == bound and (tokens * top_k) % got == 0
     if routed and got < tokens * top_k:
         assert got >= 256 and got * routed >= 2 * tokens * top_k * held
+
+
+# --- the combine of a list taken whole: choice-major, one float32 fusion ----
+
+def _picks(T, k, E, routed, seed):
+    """``combine (T, E)`` float32 of tokens that pick ``k`` of ``routed``
+    experts at random, the first ``E`` of them held here."""
+    rs = np.random.RandomState(seed)
+    picks = np.stack([rs.permutation(routed)[:k] for _ in range(T)])
+    return _combine_of(picks, routed, rs)[:, :E]
+
+
+@pytest.mark.parametrize("share", [False, True], ids=["all_held", "half_held"])
+@pytest.mark.parametrize("E,k", [(8, 2), (64, 4), (64, 8)], ids=["mixtral", "xing", "olmoe"])
+def test_the_whole_lists_combine_is_the_token_major_sum(E, k, share, monkeypatch):
+    """``_grouped_whole`` brings the down kernel's rows back in CHOICE-major
+    order and sums the leading axis (PR 61: no ``(T, top_k, H)`` array, whose
+    few choices sit on the axis the TPU tiles). Against the parent's
+    arithmetic written out here, the token-major gather contracted
+    ``tkh,tk->th`` in float32, on ONE buffer that stands in for the kernel's
+    output: equal to float32 rounding (the reduction's order is the
+    compiler's). A third of the tokens are dead and, with half the router's
+    experts held, a token's picks that fell elsewhere have weight zero: the
+    rows of no group hold NaN, and a row that was scaled and not selected
+    away would carry it into the sum."""
+    from neuronx_distributed_tpu.kernels.grouped_matmul import row_tile
+    from neuronx_distributed_tpu.moe import expert_mlps
+
+    T, H = 256, 128
+    combine = jnp.asarray(_picks(T, k, E, 2 * E if share else E, seed=E + k))
+    live = np.random.RandomState(5).uniform(size=T) < 0.67
+    weight, order, place, sizes = expert_mlps.sort_by_expert(combine, k, jnp.asarray(live), share)
+    n = int(sizes.sum())
+    assert n == int(((np.asarray(combine) > 0) & live[:, None]).sum()) and 0 < n < T * k
+    assert expert_mlps.row_bound(T, k, E, 2 * E if share else None) == T * k
+    _, rows = row_tile(T * k, E)
+    buffer = jax.random.normal(jax.random.PRNGKey(2), (rows, H), jnp.float32)
+    buffer = buffer.at[n:].set(jnp.nan).astype(jnp.bfloat16)        # the rows of no group
+    monkeypatch.setattr(expert_mlps, "grouped_matmul", lambda *a, **kw: buffer)
+    x = jnp.zeros((T, H), jnp.bfloat16)
+    got = expert_mlps._grouped_whole(
+        x, weight, order, place, sizes, jnp.asarray(live), jnp.int32(0), None, None, None,
+        T * k, True, jnp.dtype(jnp.bfloat16), True, share)
+
+    rows_back = np.asarray(buffer.astype(jnp.float32))[np.asarray(place)].reshape(T, k, H)
+    real = live[:, None] & ((np.asarray(weight) > 0) if share else np.ones((T, k), bool))
+    assert np.isnan(rows_back[~real]).all() and np.isfinite(rows_back[real]).all()
+    want = np.einsum("tkh,tk->th", np.where(real[:, :, None], rows_back, 0),
+                     np.asarray(weight, np.float32))
+    got = np.asarray(got)
+    assert got.dtype == np.float32 and got.shape == (T, H) and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=0, atol=4 * k * 2.0 ** -24 * np.abs(want).max())
+    assert (got[~live] == 0).all() and np.abs(got[live]).max() > 0
+
+
+def test_a_list_taken_whole_and_by_passes_agree():
+    """A share's list of four passes (``_grouped_share``, 512 rows a pass)
+    and the same list taken whole (``_grouped_whole``): the same rows out of
+    the same kernels, summed slot by slot there and over the leading axis
+    here, so equal to float32 rounding and not bit for bit."""
+    from neuronx_distributed_tpu.moe import expert_mlps
+
+    T, k, E, routed, H, I = 512, 4, 4, 32, 32, 64
+    combine = jnp.asarray(_picks(T, k, E, routed, seed=11))
+    live = jnp.asarray(np.random.RandomState(6).uniform(size=T) < 0.8)
+    keys = jax.random.split(jax.random.PRNGKey(4), 4)
+    x = jax.random.normal(keys[0], (T, H), jnp.float32).astype(jnp.bfloat16)
+    gate, up = (jax.random.normal(key, (1, E, H, I), jnp.float32).astype(jnp.bfloat16) * 0.2
+                for key in keys[1:3])
+    down = jax.random.normal(keys[3], (1, E, I, H), jnp.float32).astype(jnp.bfloat16) * 0.2
+    bound = expert_mlps.row_bound(T, k, E, routed)
+    assert bound == 512 < T * k
+    sorted_list = expert_mlps.sort_by_expert(combine, k, live, True)
+    whole, by_passes = (
+        np.asarray(form(x, *sorted_list, live, jnp.int32(0), gate, up, down, rows, True,
+                        jnp.dtype(jnp.bfloat16), True, True))
+        for form, rows in ((expert_mlps._grouped_whole, T * k),
+                           (expert_mlps._grouped_share, bound)))
+    assert whole.dtype == by_passes.dtype == np.float32 and np.abs(whole).max() > 0
+    np.testing.assert_allclose(whole, by_passes, rtol=0,
+                               atol=4 * k * 2.0 ** -24 * np.abs(whole).max())
+    assert (whole[~np.asarray(live)] == 0).all()
 
 
 def test_ep_sharded_checkpoint_roundtrip(tmp_path):
